@@ -7,8 +7,8 @@ is the same train step under the dp/fsdp mesh (__graft_entry__.py).
 
 Run: python bench_qlora.py [--steps N]
 Prints ONE JSON line {"metric", "value", "unit", ...} like bench.py.
-(Not driver-run: bench.py stays the headline; this is the training-side
-evidence.)
+Needs a TPU: without one it prints `{"ok": false, ...}` and exits
+non-zero.
 """
 
 from __future__ import annotations
@@ -21,20 +21,14 @@ import time
 
 def main() -> None:
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from bench import _probe_backend
+    from bench import chip_peaks, model_flops_per_token, require_tpu
 
-    if not _probe_backend():
-        print("bench_qlora: backend unresponsive; falling back to CPU",
-              file=sys.stderr)
-        os.environ["JAX_PLATFORMS"] = "cpu"
+    device = require_tpu("bench_qlora")
     import jax
-
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
 
     from bigdl_tpu.config import enable_compilation_cache
 
-    enable_compilation_cache()   # reuse compiles across windows
+    enable_compilation_cache()
     import jax.numpy as jnp
     import optax
 
@@ -42,20 +36,18 @@ def main() -> None:
     from bigdl_tpu.qlora import LoraConfig, attach_lora, \
         lora_trainable_mask
     from bigdl_tpu.training import make_lora_train_step, partition
-    from bigdl_tpu.utils.testing import LLAMA2_7B, TINY_LLAMA, \
-        random_llama_params
+    from bigdl_tpu.utils.testing import LLAMA2_7B, random_llama_params
 
     steps = 8
     if "--steps" in sys.argv:
         steps = int(sys.argv[sys.argv.index("--steps") + 1])
 
-    on_tpu = jax.default_backend() == "tpu"
-    cfg = LLAMA2_7B if on_tpu else TINY_LLAMA
-    # TPU config mirrors the reference alpaca-qlora recipe behind the
-    # 21-min number (qlora_finetune_llama2_7b_pvc_1550_4_card.sh:
-    # micro_batch_size 8; alpaca_qlora_finetuning.py: cutoff_len 256)
-    # so the projection below compares like-for-like
-    batch, seq = (8, 256) if on_tpu else (1, 64)
+    cfg = LLAMA2_7B
+    # mirrors the reference alpaca-qlora recipe behind the 21-min number
+    # (qlora_finetune_llama2_7b_pvc_1550_4_card.sh: micro_batch_size 8;
+    # alpaca_qlora_finetuning.py: cutoff_len 256) so the projection
+    # below compares like-for-like
+    batch, seq = 8, 256
 
     from bigdl_tpu.transformers.model import _maybe_mxu_layout
 
@@ -87,36 +79,32 @@ def main() -> None:
     # physics floor (poisoned-buffer guard, same rationale as bench.py):
     # fwd+bwd >= 2x forward matmul FLOPs; timings below what the MXU
     # could do at 100% utilization mean the runtime did not execute
-    from bench import chip_peaks, model_flops_per_token
-
     flops_tok = model_flops_per_token(cfg)
-    peak_tflops = chip_peaks()[0]
+    peak_tflops = chip_peaks(device["kind"])[0]
     floor_ms = 2 * batch * seq * flops_tok / (peak_tflops * 1e12) * 1e3 * 0.5
     import math
 
-    poisoned = on_tpu and (per_step_ms < floor_ms
-                           or not math.isfinite(float(loss)))
+    poisoned = (per_step_ms < floor_ms
+                or not math.isfinite(float(loss)))
 
     out = {
-        # a CPU fallback must not carry the 7B-on-TPU metric name
-        "metric": ("llama2_7b_qlora_step_time" if on_tpu
-                   else "cpu_fallback_smoke_qlora_step_time"),
+        "metric": "llama2_7b_qlora_step_time",
         "value": round(per_step_ms, 2),
         "unit": "ms",
-        "valid": bool(on_tpu) and not poisoned,
+        "valid": not poisoned,
         "tokens_per_s": round(tokens_per_s, 1),
         "batch": batch,
         "seq_len": seq,
         "lora_rank": 16,
-        "backend": jax.default_backend(),
-        "model": "llama2-7b" if on_tpu else "tiny-llama(cpu-fallback)",
+        "device": device,
+        "model": "llama2-7b",
         "loss": float(loss),
     }
     if poisoned:
         out["note"] = (f"step time beat the physics floor "
                        f"({floor_ms:.0f}ms) or loss not finite — "
                        f"runtime did not execute (poisoned buffers)")
-    if on_tpu and not poisoned:
+    if not poisoned:
         # BASELINE.md target: Alpaca QLoRA in < 21 min on 8 chips.
         # Sample count and epochs come from the reference recipe the
         # number was published for (alpaca_qlora_finetuning.py:
@@ -129,6 +117,8 @@ def main() -> None:
             steps_total * per_step_ms / 1e3 / 60, 1)
         out["alpaca_target_minutes"] = 21.0
     print(json.dumps(out))
+    if poisoned:
+        raise SystemExit(1)
 
 
 if __name__ == "__main__":

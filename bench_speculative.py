@@ -15,8 +15,8 @@ projected speedup at a realistic 80% acceptance, computed from the
 measured round timings.
 
 Run: python bench_speculative.py  — prints ONE JSON line like bench.py.
-(Not driver-run: bench.py stays the headline; this is the VERDICT r4 #9
-on-chip evidence.)
+Needs a TPU: without one it prints `{"ok": false, ...}` and exits
+non-zero.
 """
 
 from __future__ import annotations
@@ -29,35 +29,24 @@ import time
 
 def main() -> None:
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from bench import _probe_backend, chip_peaks
+    from bench import chip_peaks, require_tpu
 
-    backend = _probe_backend()
-    if backend is None:
-        print("bench_speculative: backend unresponsive; falling back to "
-              "CPU", file=sys.stderr)
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        backend = "cpu"
+    device = require_tpu("bench_speculative")
     import jax
-
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
 
     from bigdl_tpu.config import enable_compilation_cache
 
-    enable_compilation_cache()   # reuse compiles across windows
+    enable_compilation_cache()
     import jax.numpy as jnp
     import numpy as np
 
     from bigdl_tpu.generation import generate_on_device
     from bigdl_tpu.models import llama as llama_mod
     from bigdl_tpu.speculative import (SpecStats, prompt_lookup_generate, speculative_generate)
-    from bigdl_tpu.utils.testing import (LLAMA2_7B, TINY_LLAMA,
-                                         random_llama_params)
+    from bigdl_tpu.utils.testing import LLAMA2_7B, random_llama_params
 
-    on_tpu = jax.default_backend() == "tpu"
-    cfg = LLAMA2_7B if on_tpu else TINY_LLAMA
-    prompt_len, new_tokens, max_seq = (256, 128, 1024) if on_tpu \
-        else (16, 16, 64)
+    cfg = LLAMA2_7B
+    prompt_len, new_tokens, max_seq = 256, 128, 1024
     gamma = 4
 
     target = random_llama_params(cfg, qtype="sym_int8", seed=0)
@@ -115,9 +104,9 @@ def main() -> None:
     # per-round time below weight_bytes/BW is real
     wb = sum(getattr(l, "nbytes", l.nbytes)
              for l in jax.tree_util.tree_leaves(target))
-    _, peak_gbps = chip_peaks()
+    _, peak_gbps = chip_peaks(device["kind"])
     floor_round_ms = wb / (peak_gbps * 1e9) * 1e3 * 0.8
-    valid = bool(on_tpu and round_ms > floor_round_ms and spec_s > 0)
+    valid = bool(round_ms > floor_round_ms and spec_s > 0)
 
     # prompt-lookup leg: n-gram drafts, NO draft model (beyond both the
     # reference and the draft-model path above) — repetition-heavy
@@ -142,7 +131,7 @@ def main() -> None:
     lookup_s, lstats = best_of(lookup_run)
     lookup_ms = lookup_s / new_tokens * 1e3
     lookup_round_ms = lookup_s / max(lstats.rounds, 1) * 1e3
-    lookup_valid = bool(on_tpu and lookup_round_ms > floor_round_ms)
+    lookup_valid = bool(lookup_round_ms > floor_round_ms)
 
     rec = {
         "metric": "llama2_7b_selfspec_decode_speedup",
@@ -150,7 +139,7 @@ def main() -> None:
         "unit": "x",
         "vs_baseline": round(speedup / 1.3, 3),   # reference ~30% claim
         "valid": valid,
-        "backend": "tpu" if on_tpu else "cpu",
+        "device": device,
         "plain_ms_per_token": round(plain_ms, 3),
         "spec_ms_per_token": round(spec_ms, 3),
         "gamma": gamma,
@@ -163,7 +152,7 @@ def main() -> None:
                  "acceptance"),
         "prompt_len": prompt_len,
         "decode_steps": new_tokens,
-        "model": "llama2-7b" if on_tpu else "tiny-llama(cpu-fallback)",
+        "model": "llama2-7b",
         "prompt_lookup": {
             "ms_per_token": round(lookup_ms, 3),
             "speedup_vs_plain": round(plain_ms / lookup_ms, 3)
@@ -177,6 +166,8 @@ def main() -> None:
         },
     }
     print(json.dumps(rec))
+    if not (valid and lookup_valid):
+        raise SystemExit(1)
 
 
 if __name__ == "__main__":

@@ -7,10 +7,10 @@ serving via env in transformers/loader.py:43-77). Here the model already
 owns its generate loop, so the wrapper simply drives it with a
 GenerationStats collector and reads device memory stats from JAX.
 
-Note on TPU timing: a tunneled/remote device pays a fixed dispatch+readback
-cost per host sync; `rest_cost_mean` measured around a host-step loop
-includes it. For kernel-true numbers use `timed_decode` (K steps inside one
-jit, differenced) — the same technique bench.py uses.
+Note on TPU timing: every host sync pays a dispatch + readback cost;
+`rest_cost_mean` measured around a host-step loop includes it. For
+device-only numbers use `timed_decode` (K steps inside one jit,
+differenced) — the same technique bench.py uses.
 """
 
 from __future__ import annotations

@@ -100,6 +100,9 @@ def _generate(model, tokenizer, text, args, history=None):
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    from bigdl_tpu.config import enable_compilation_cache
+
+    enable_compilation_cache()
     model, tokenizer = _load(args)
 
     if args.prompt is not None:

@@ -29,8 +29,10 @@ def main(argv=None) -> int:
                          "weighted quantization (ultra-low-bit qtypes)")
     args = ap.parse_args(argv)
 
+    from bigdl_tpu.config import enable_compilation_cache
     from bigdl_tpu.transformers.model import AutoModelForCausalLM
 
+    enable_compilation_cache()
     model = AutoModelForCausalLM.from_pretrained(
         args.model, load_in_low_bit=args.outtype, imatrix=args.imatrix)
 

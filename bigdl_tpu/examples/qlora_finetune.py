@@ -73,11 +73,13 @@ def main(argv=None) -> int:
     import jax.numpy as jnp
     import optax
 
+    from bigdl_tpu.config import enable_compilation_cache
     from bigdl_tpu.qlora import LoraConfig, attach_lora, lora_trainable_mask
     from bigdl_tpu.relora import relora_restart
     from bigdl_tpu.training import make_lora_train_step, partition, combine
     from bigdl_tpu.transformers.model import AutoModelForCausalLM
 
+    enable_compilation_cache()
     # split projection layout: the LoRA targets name q_proj/k_proj/...
     model = AutoModelForCausalLM.from_pretrained(
         args.base_model, load_in_low_bit=args.low_bit,

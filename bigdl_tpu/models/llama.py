@@ -432,11 +432,11 @@ def _moe_mlp(hidden, lp, cfg: LlamaConfig):
     if n * cfg.num_experts_per_tok <= cfg.num_local_experts:
         from bigdl_tpu.ops.matmul import vmapped_pallas_ok
 
-        # fused kernels under vmap are gated by eager probes covering
+        # fused kernels under vmap are gated by compile probes covering
         # EVERY (qtype, geometry) the gather actually runs — mixed_*
-        # policies can land different qtypes per projection — (compile
-        # failures degrade to the XLA matmul, never crash a jit); dense
-        # expert stacks never hit pallas
+        # policies can land different qtypes per projection; a kernel
+        # the compiler refuses raises (ops/probing.py). Off-TPU and
+        # dense expert stacks never hit pallas
         ff = cfg.intermediate_size
         probes = []
         for leaf, kk, nn in ((lp.get("experts_gate"), d, ff),

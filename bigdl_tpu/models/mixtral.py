@@ -3,14 +3,15 @@
 TPU-native re-design of the reference's Mixtral path (reference
 transformers/models/mixtral.py: `mixtral_moeblock_forward` at :79-138 — a
 Python loop over experts with a `.cpu().tolist()` host sync to pick the
-top-k on decode, which is unacceptable on TPU). Here expert dispatch is a
-one-hot einsum combine with NO host sync and no data-dependent shapes:
+top-k on decode, which is unacceptable on TPU). Here expert dispatch has
+NO host sync and no data-dependent shapes:
 
-- All experts are evaluated and combined with routing weights
-  (`combine[n,e]`), the standard dense-MoE formulation that XLA maps onto
-  batched MXU matmuls. With int4-packed experts the full-expert weight read
-  is the same byte count as reading 2 bf16 experts, so even decode stays
-  HBM-reasonable; a top-k-gathering Pallas kernel is the planned upgrade.
+- The MoE MLP is `models/llama._moe_mlp`, which picks by token count:
+  few tokens in flight (decode) gather only the CHOSEN experts' weights
+  per token; prefill runs the sorted ragged Pallas dispatch
+  (`ops/pallas/moe_dispatch.py`) where it applies, and otherwise the
+  dense formulation — all experts evaluated and combined with routing
+  weights (`combine[n,e]`), which XLA maps onto batched MXU matmuls.
 - Expert weights are stacked [L, E, K, N] (layer, expert leading axes on
   every QTensor leaf), so the `ep` mesh axis shards axis E and `tp` shards
   N — XLA inserts the all-to-all/psum (SURVEY.md §2.2: the reference has NO

@@ -28,6 +28,34 @@ class FamilyAdapter:
     # (b) prompt padding in the Generator (state cannot mask pads).
     is_recurrent: bool = False
 
+    # What the serving engine asks of a family beyond the fields above is
+    # declared by the MODULE that owns `forward` (models/llama.py:
+    # SUPPORTS_SCALED_KV, SUPPORTS_PAGED_KV, forward_paged,
+    # new_paged_cache), and every adapter that routes through that
+    # forward has it. Without these a model loaded by from_pretrained
+    # could be served with neither an int8/int4 nor a paged cache —
+    # only hand-built test families carried the attributes.
+    def _forward_module(self, name: str, default=None):
+        import sys
+
+        return getattr(sys.modules[self.forward.__module__], name, default)
+
+    @property
+    def SUPPORTS_SCALED_KV(self) -> bool:  # noqa: N802 — module's name
+        return bool(self._forward_module("SUPPORTS_SCALED_KV", False))
+
+    @property
+    def SUPPORTS_PAGED_KV(self) -> bool:  # noqa: N802
+        return bool(self._forward_module("SUPPORTS_PAGED_KV", False))
+
+    @property
+    def forward_paged(self) -> Optional[Callable]:
+        return self._forward_module("forward_paged")
+
+    @property
+    def new_paged_cache(self) -> Optional[Callable]:
+        return self._forward_module("new_paged_cache")
+
 
 _REGISTRY: Dict[str, Any] = {}
 

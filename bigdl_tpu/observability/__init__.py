@@ -44,8 +44,8 @@ standard library — tests/test_observability.py enforces it):
   source for ``bench.py``'s efficiency block, the engine's live
   ``bigdl_tpu_roofline_util{phase}`` / ``decode_ideal_ms`` gauges and
   compile_watch's per-jit cost annotation). Chip peaks come from
-  ``$BIGDL_TPU_PEAK_BF16_TFLOPS`` / ``$BIGDL_TPU_PEAK_HBM_GBPS``
-  (v5e datasheet defaults).
+  ``roofline.CHIP_PEAKS``, keyed by the device kind JAX reports; an
+  unknown kind exports no roofline gauges.
 - ``sentinel``: ``PerfSentinel`` — dwell-gated perf-regression
   detection over decode ms/token, roofline util and dispatch overhead
   EWMAs vs a rolling baseline persisted at ``$BIGDL_TPU_PERF_HISTORY``

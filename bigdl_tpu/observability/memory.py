@@ -9,8 +9,8 @@ views:
 - **static**: exact packed bytes per registered allocation, grouped by
   kind ("weights", "kv_cache", "lora", "optimizer", ...). Producers
   register at build/allocation time (the serving engine registers its
-  params and batched KV cache; ``Generator``/``tpu_onchip`` register
-  theirs) with the same byte conventions the allocators use — int4 at
+  params and batched KV cache; ``Generator`` registers
+  its own) with the same byte conventions the allocators use — int4 at
   two codes per byte, scale planes counted separately — so
   ``static_report()`` matches allocated ``nbytes`` exactly.
 - **live**: ``device.memory_stats()`` (``bytes_in_use``,

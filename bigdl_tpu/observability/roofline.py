@@ -8,7 +8,8 @@ Decode on one chip is HBM-bandwidth-bound: every token reads the whole
 packed weight set plus the live KV slice, so the honest efficiency
 number is bytes-moved / (latency x peak-BW). Prefill is compute-bound,
 so its number is model FLOPs / (latency x peak-FLOPs) — classic MFU.
-Chip peaks are v5e datasheet values, env-overridable for other chips.
+Chip peaks come from ONE table keyed by the device kind JAX reports
+(``CHIP_PEAKS``); a device that is not in it has no roofline.
 
 Import contract: **stdlib only** (``tests/test_observability.py``
 enforces that importing ``bigdl_tpu.observability`` pulls in no heavy
@@ -20,10 +21,10 @@ deps). Model configs are duck-typed: anything with ``hidden_size``,
 
 from __future__ import annotations
 
-import os
 from typing import Dict, Optional, Tuple
 
 __all__ = [
+    "CHIP_PEAKS",
     "KV_ELT_BYTES",
     "attn_flops_per_token",
     "attribution",
@@ -50,12 +51,30 @@ _SCALED_KV_DTYPES = ("int8", "int4")
 _SCALE_ELT_BYTES = 4.0  # fp32 scale per (token, head) plane
 
 
-def chip_peaks() -> Tuple[float, float]:
-    """(peak_bf16_tflops, peak_hbm_gbps) — v5e datasheet defaults,
-    env-overridable for other chips. One definition for the bench
-    floors, the efficiency block, bench_qlora and the live gauges."""
-    return (float(os.environ.get("BIGDL_TPU_PEAK_BF16_TFLOPS", "197")),
-            float(os.environ.get("BIGDL_TPU_PEAK_HBM_GBPS", "819")))
+# device_kind (as ``jax.devices()[0].device_kind`` reports it) ->
+# (peak bf16 TFLOP/s, peak HBM GB/s) of ONE chip.
+CHIP_PEAKS: Dict[str, Tuple[float, float]] = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
+    "TPU v5 lite": (197.0, 819.0),
+}
+
+
+def chip_peaks(device_kind: Optional[str] = None) -> Tuple[float, float]:
+    """(peak_bf16_tflops, peak_hbm_gbps) of ``device_kind``, default the
+    kind of this process's first device. One definition for the bench
+    floors, the efficiency block, bench_qlora and the live gauges. A
+    kind that is not in ``CHIP_PEAKS`` raises LookupError: a roofline
+    share against another chip's peaks is not a number."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise LookupError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(CHIP_PEAKS)})") from None
 
 
 def model_flops_per_token(cfg) -> int:
@@ -93,9 +112,20 @@ def kv_bytes_per_token(cfg, seq_len: int,
     return bytes_
 
 
+def _decode_work(cfg, weight_bytes: int, seq_len: int,
+                 kv_cache_dtype: str, batch: int) -> Tuple[float, float]:
+    """(flops, hbm_bytes) of one decode step — counts from shapes, no
+    chip peaks involved."""
+    flops = float(batch) * (model_flops_per_token(cfg)
+                            + attn_flops_per_token(cfg, seq_len))
+    hbm_bytes = float(weight_bytes) + float(batch) * kv_bytes_per_token(
+        cfg, seq_len, kv_cache_dtype)
+    return flops, hbm_bytes
+
+
 def decode_costs(cfg, weight_bytes: int, seq_len: int,
-                 kv_cache_dtype: str = "bf16",
-                 batch: int = 1) -> Dict[str, float]:
+                 kv_cache_dtype: str = "bf16", batch: int = 1,
+                 device_kind: Optional[str] = None) -> Dict[str, float]:
     """Analytical cost of one decode step at cache length ``seq_len``:
 
     - ``flops``: matmul + attention-over-cache FLOPs (per batch row)
@@ -103,11 +133,9 @@ def decode_costs(cfg, weight_bytes: int, seq_len: int,
       the live KV slice per row
     - ``ideal_ms``: bandwidth-bound floor for the step at peak HBM BW
     """
-    _, peak_gbps = chip_peaks()
-    flops = float(batch) * (model_flops_per_token(cfg)
-                            + attn_flops_per_token(cfg, seq_len))
-    hbm_bytes = float(weight_bytes) + float(batch) * kv_bytes_per_token(
-        cfg, seq_len, kv_cache_dtype)
+    _, peak_gbps = chip_peaks(device_kind)
+    flops, hbm_bytes = _decode_work(cfg, weight_bytes, seq_len,
+                                    kv_cache_dtype, batch)
     ideal_ms = hbm_bytes / (peak_gbps * 1e9) * 1e3
     return {"flops": flops, "hbm_bytes": hbm_bytes, "ideal_ms": ideal_ms}
 
@@ -126,8 +154,9 @@ def prefill_costs(cfg, prompt_len: int,
 
 
 def efficiency(cfg, weight_bytes: int, prompt_len: int, steps: int,
-               first_ms: float, next_ms: float) -> dict:
-    """MFU + HBM-roofline utilization (VERDICT r2 #2) — the exact
+               first_ms: float, next_ms: float,
+               device_kind: Optional[str] = None) -> dict:
+    """MFU + HBM-roofline utilization — the exact
     numbers ``bench.py`` prints in every headline record (it imports
     this; ``tests/test_perf_observability.py`` asserts identity on the
     r05 fixture so bench and live gauges cannot drift).
@@ -137,7 +166,7 @@ def efficiency(cfg, weight_bytes: int, prompt_len: int, steps: int,
     keeps the bench's bf16-cache accounting (the headline lane decodes
     against a bf16 cache) — kv-dtype-aware live gauges go through
     :func:`decode_costs` instead."""
-    peak_tflops, peak_gbps = chip_peaks()
+    peak_tflops, peak_gbps = chip_peaks(device_kind)
 
     l_ = cfg.num_hidden_layers
     h, hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.hd
@@ -171,13 +200,15 @@ def efficiency(cfg, weight_bytes: int, prompt_len: int, steps: int,
 
 def attribution(cfg, weight_bytes: int, prompt_len: int, steps: int,
                 first_ms: float, next_ms: float,
-                kv_cache_dtype: str = "bf16") -> dict:
+                kv_cache_dtype: str = "bf16",
+                device_kind: Optional[str] = None) -> dict:
     """Per-phase roofline attribution block embedded in bench JSON:
     analytical FLOPs / HBM bytes / ideal ms next to the measured ms, so
     a bench record carries *why* a phase is slow, not just that it is."""
-    peak_tflops, peak_gbps = chip_peaks()
+    peak_tflops, peak_gbps = chip_peaks(device_kind)
     s_mid = prompt_len + steps // 2
-    dec = decode_costs(cfg, weight_bytes, s_mid, kv_cache_dtype)
+    dec = decode_costs(cfg, weight_bytes, s_mid, kv_cache_dtype,
+                       device_kind=device_kind)
     pre = prefill_costs(cfg, prompt_len)
     prefill_ideal_ms = pre["flops"] / (peak_tflops * 1e12) * 1e3
     return {
@@ -208,16 +239,15 @@ def jit_costs(cfg, weight_bytes: int, max_batch: int, max_seq: int,
     compile_watch cost annotation (the "top offenders" view ranks jits
     by bytes moved). Worst-case shapes: decode at full cache, prefill
     at one bucket."""
-    dec = decode_costs(cfg, weight_bytes, max_seq, kv_cache_dtype,
-                       batch=max_batch)
+    dec_flops, dec_bytes = _decode_work(cfg, weight_bytes, max_seq,
+                                        kv_cache_dtype, max_batch)
     pre = prefill_costs(cfg, prefill_bucket)
     kv_full = float(max_batch) * kv_bytes_per_token(
         cfg, max_seq, kv_cache_dtype)
     costs: Dict[str, Dict[str, float]] = {
-        "engine_decode": {"flops": dec["flops"],
-                          "hbm_bytes": dec["hbm_bytes"]},
-        "engine_decode_resident": {"flops": dec["flops"],
-                                   "hbm_bytes": dec["hbm_bytes"]},
+        "engine_decode": {"flops": dec_flops, "hbm_bytes": dec_bytes},
+        "engine_decode_resident": {"flops": dec_flops,
+                                   "hbm_bytes": dec_bytes},
         "engine_prefill": {"flops": pre["flops"],
                            "hbm_bytes": float(weight_bytes)},
         # insert touches one row's KV planes; argmax/sample/health are

@@ -239,8 +239,8 @@ def _q_matmul_dispatch(x: jax.Array, w: QTensor, be: str) -> jax.Array:
                       and not under_spmd(x, *jax.tree_util.tree_leaves(w)))
         if be == "auto" and use_pallas:
             # prefill-class M: the dequant kernel is VPU-bound while the
-            # XLA dequantize-then-matmul plan rides the MXU (on-chip A/B
-            # in RuntimeFlags.matmul_pallas_max_m's docstring)
+            # XLA dequantize-then-matmul plan rides the MXU (see
+            # RuntimeFlags.matmul_pallas_max_m)
             m = _rows(x)
             use_pallas = m <= flags().matmul_pallas_max_m
             if use_pallas:
@@ -248,9 +248,8 @@ def _q_matmul_dispatch(x: jax.Array, w: QTensor, be: str) -> jax.Array:
                     GEMV_MAX_M, matmul_kernel_compiles)
 
                 if m > GEMV_MAX_M:
-                    # the generic tiles were the ONE unprobed Pallas
-                    # path — a Mosaic rejection there crashed the whole
-                    # forced-all-M bench lane instead of degrading
+                    # generic tiles: probed per geometry like the GEMV
+                    # variants (False here = no legal tiling, a rule)
                     from bigdl_tpu.ops.quant import get_qtype
 
                     kp = w.scale.shape[0] * get_qtype(w.qtype).block_size
@@ -264,79 +263,66 @@ def _q_matmul_dispatch(x: jax.Array, w: QTensor, be: str) -> jax.Array:
 
                 return q_matmul_pallas_impl(x, w)
             except NotImplementedError:
+                # raised while tracing, before any compile: the shape
+                # has no legal tiling — a rule, like the ones below
                 if be == "pallas":
                     raise
-        if on_tpu and w.qtype in _FUSED_XLA_QTYPES and _rows(x) <= 32:
-            # decode-shaped call that could not take the Pallas kernel
-            # (SPMD tracing, failed probe): fuse the dequant into the
-            # dot rather than materializing the full bf16 weight
-            return _q_matmul_xla_fused(x, w)
+        if on_tpu:
+            # XLA by design (prefill-class M, GSPMD-sharded operands, a
+            # qtype or tiling the kernels do not cover): a dispatch
+            # rule, counted apart from probe outcomes
+            from bigdl_tpu.ops.probing import record_dispatch_rule
+
+            record_dispatch_rule("matmul")
+            if w.qtype in _FUSED_XLA_QTYPES and _rows(x) <= 32:
+                # decode-shaped: fuse the dequant into the dot rather
+                # than materializing the full bf16 weight
+                return _q_matmul_xla_fused(x, w)
         return _q_matmul_xla(x, w)
     raise ValueError(f"unknown matmul backend {be!r}")
 
 
-_VMAPPED_PALLAS: dict = {}
+_VMAPPED_PALLAS: set = set()
 
 
 def vmapped_pallas_ok(qtype: str, k: int = 256, n: int = 256) -> bool:
-    """Eager probe PER (qtype, K, N-tile): does a vmapped, dynamically-
-    indexed q_matmul_pallas compile on this backend for this format at
-    this geometry? Gates the MoE decode gather path's use of the fused
-    kernel (models/llama.py `_moe_mlp`): pallas_call's batching rule,
-    dynamic expert indexing, the qtype's dequant branch, and the REAL
-    tile classes are what that path runs (Mosaic rejections are
-    geometry-dependent). The stand-in keeps the full K (the GEMV x/scale
-    residency depends on it) but only ONE N tile — probing the full
-    [K, N] would allocate hundreds of MB next to a resident model."""
+    """Compile probe PER (qtype, K, N-tile) for a vmapped, dynamically-
+    indexed q_matmul_pallas (contract in ops/probing.py: True, or
+    `KernelProbeError`). Gates the MoE decode gather path's use of the
+    fused kernel (models/llama.py `_moe_mlp`): pallas_call's batching
+    rule, dynamic expert indexing, the qtype's dequant branch, and the
+    REAL tile classes are what that path runs (Mosaic rejections are
+    geometry-dependent). False only by RULE: not a TPU target, or a
+    qtype the kernel does not cover. The stand-in keeps the full K (the
+    GEMV x/scale residency depends on it) but only ONE N tile."""
     from bigdl_tpu.config import flags as _flags, target_is_tpu
 
     if not (target_is_tpu() and qtype in _PALLAS_QTYPES):
         return False
     from bigdl_tpu.ops.pallas.dequant_matmul import (_gemv_tiles,
                                                      q_matmul_pallas)
-    from bigdl_tpu.ops.quant import get_qtype, quantize
+    from bigdl_tpu.ops.quant import get_qtype
 
-    if _flags().aot_target == "tpu":   # AOT lowering: trust the dispatch
+    if _flags().aot_target == "tpu":   # AOT lowering: the caller compiles
         return True
     tiles = _gemv_tiles(get_qtype(qtype), k, n)
     if tiles is not None:
         n = tiles[1]
-    key = (qtype, k, n)
-    hit = _VMAPPED_PALLAS.get(key)
-    if hit is not None:
-        return hit
-    try:
-        from bigdl_tpu.ops.probing import (probe_compile, quant_struct,
-                                           stacked_struct)
+    from bigdl_tpu.ops.probing import (probe_kernel, quant_struct,
+                                       stacked_struct)
 
-        # compile-only AOT probe (see ops/probing.py) — safe inside the
-        # caller's jit trace, allocates nothing on device
-        stack = stacked_struct(quant_struct(k, n, qtype), 2)
+    def probe_fn(idx, x, ws):
+        def per(i, row):
+            wi = jax.tree.map(lambda a: a[i], ws)
+            return q_matmul_pallas(row[None], wi)[0]
 
-        def probe_fn(idx, x, ws):
-            def per(i, row):
-                wi = jax.tree.map(lambda a: a[i], ws)
-                return q_matmul_pallas(row[None], wi)[0]
+        return jax.vmap(per)(idx, x)
 
-            return jax.vmap(per)(idx, x)
-
-        probe_compile(probe_fn,
-                      jax.ShapeDtypeStruct((2,), jnp.int32),
-                      jax.ShapeDtypeStruct((2, k), jnp.bfloat16), stack)
-        ok = True
-    except Exception as e:
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "vmapped pallas_call unavailable for %s at (K=%d, N=%d) "
-            "(%s: %s); MoE decode gather uses the XLA matmul", qtype,
-            k, n, type(e).__name__, e)
-        ok = False
-    from bigdl_tpu.ops.probing import record_probe_result
-
-    record_probe_result("vmapped_gemm", ok)
-    _VMAPPED_PALLAS[key] = ok
-    return ok
+    return probe_kernel(
+        "vmapped_gemm", _VMAPPED_PALLAS, (qtype, k, n), probe_fn,
+        jax.ShapeDtypeStruct((2,), jnp.int32),
+        jax.ShapeDtypeStruct((2, k), jnp.bfloat16),
+        stacked_struct(quant_struct(k, n, qtype), 2))
 
 
 def _zero_cotangent(leaf):

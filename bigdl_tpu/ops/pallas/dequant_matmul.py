@@ -33,13 +33,9 @@ from bigdl_tpu.ops.quant import QTensor, get_qtype
 from bigdl_tpu.ops.codebooks import CODEBOOKS
 
 
-# renamed TPUCompilerParams -> CompilerParams across jax versions
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 # generic grid is (M/bm, N/bn, K/bk): M and N tiles are independent,
 # only the K sweep carries the accumulator
-_GENERIC_SEMANTICS = _CompilerParams(
+_GENERIC_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
@@ -237,7 +233,7 @@ def _gemv_kernel_mxu(x3_ref, data_ref, scale_ref, out_ref, acc_ref, *,
 
     The canonical split-block layout costs ~6 i32 VPU ops per weight to
     unpack (widen/mask/shift/concat) — at 7B decode that chain, not HBM,
-    set the 30 ms/token floor (BENCH_r04: 18% of roofline). jnp.int4
+    is the suspected floor (not measured on today's code). jnp.int4
     arrays are bit-packed by XLA (same HBM bytes) and loaded natively by
     Mosaic, so per-weight work drops to ONE convert feeding the batched
     dot; scales fold onto the [rows, M, bn] partials exactly like
@@ -326,7 +322,7 @@ def _matmul_tiles(qt, kp: int, n: int, bk_cands,
     Mosaic's scale-plane tiling (`_scale_rows_ok`); naively halving bk to
     fit VMEM can break it — e.g. the full-K tile for a tp=4 shard of
     ff=11008 (K=2752, an 86-row scale plane, legal only as ONE block)
-    halves to 43 rows and falls off the kernel entirely (VERDICT r3 #4).
+    halves to 43 rows and falls off the kernel entirely.
     So search the whole (bk, bn) grid, shrinking bn before bk, and keep
     the largest legal product (ties favor the earlier = wider bn).
 
@@ -358,7 +354,7 @@ def _gemv_tiles(qt, kp: int, n: int, mp: int = 16):
                          bm=mp)
 
 
-_gemv_probe_cache: dict = {}
+_gemv_probe_cache: set = set()
 
 # decode-GEMV M ceiling: the serving engine's decode batch. One padded
 # sublane tile (mp=16) covers bs<=16; bs 17-32 pads to TWO sublane tiles
@@ -373,10 +369,10 @@ def _gemv_mp(m: int) -> int:
 
 def gemv_kernel_compiles(qtype: str, kp: int, n: int,
                          variant: str = "std", m: int = 1) -> bool:
-    """Eager per-geometry probe for the decode-GEMV variant (same
-    contract as ops/attention._kernel_compiles): compiles the REAL tile
-    classes on a stand-in sized (kp, bn) so a Mosaic rejection degrades
-    to the generic tiling instead of crashing a jitted decode.
+    """Per-geometry compile probe for the decode-GEMV variant (contract
+    in ops/probing.py: True, or `KernelProbeError`): compiles the REAL
+    tile classes on a stand-in sized (kp, bn). False only by RULE — the
+    shape has no legal GEMV tiling and takes the generic tiles.
     `variant`: "std" | "fold" | "mxu" | "mxu8" (see the kernel bodies).
     `m` only selects the padded row class (16 vs 32)."""
     qt = get_qtype(qtype)
@@ -386,52 +382,30 @@ def gemv_kernel_compiles(qtype: str, kp: int, n: int,
         return False
     from bigdl_tpu.config import flags as _flags
 
-    if _flags().aot_target == "tpu":   # AOT lowering: trust the dispatch
+    if _flags().aot_target == "tpu":   # AOT lowering: the caller compiles
         return True
     bk, bn = tiles
-    key = (qtype, kp, bn, bk, variant, mp)
-    hit = _gemv_probe_cache.get(key)
-    if hit is not None:
-        return hit
-    try:
-        from bigdl_tpu.ops.probing import probe_compile, quant_struct
+    from bigdl_tpu.ops.probing import probe_kernel, quant_struct
 
-        mxu = variant in ("mxu", "mxu8")
-        # compile-only AOT probe (see ops/probing.py) — safe inside the
-        # caller's jit trace, allocates nothing on device
-        probe_compile(
-            lambda xx, ww: _q_gemv_pallas(xx, ww, qt, mp, kp, bn, False,
-                                          jnp.bfloat16, variant=variant),
-            jax.ShapeDtypeStruct((mp, kp), jnp.bfloat16),
-            quant_struct(kp, bn, qtype, mxu=mxu))
-        ok = True
-    except Exception as e:
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "pallas decode-GEMV variant %s unavailable for (K=%d, N=%d, "
-            "%s) — %s: %s; using the generic tiles", variant, kp, n,
-            qtype, type(e).__name__, e)
-        ok = False
-    from bigdl_tpu.ops.probing import record_probe_result
-
-    record_probe_result(f"gemv_{variant}", ok)
-    _gemv_probe_cache[key] = ok
-    return ok
+    return probe_kernel(
+        f"gemv_{variant}", _gemv_probe_cache,
+        (qtype, kp, bn, bk, variant, mp),
+        lambda xx, ww: _q_gemv_pallas(xx, ww, qt, mp, kp, bn, False,
+                                      jnp.bfloat16, variant=variant),
+        jax.ShapeDtypeStruct((mp, kp), jnp.bfloat16),
+        quant_struct(kp, bn, qtype,
+                     mxu=variant in ("mxu", "mxuflat", "mxu8")))
 
 
-_matmul_probe_cache: dict = {}
+_matmul_probe_cache: set = set()
 
 
 def matmul_kernel_compiles(qtype: str, m: int, kp: int, n: int,
                            mxu: bool = False) -> bool:
-    """Eager per-geometry probe for the GENERIC tiled kernel. The bench
-    lane `pallas-all-m` (matmul_pallas_max_m=4096) crashed the whole
-    lane when a prefill-class tile hit a Mosaic rejection — the generic
-    path had no probe, unlike the GEMV variants and attention. Auto
-    dispatch now consults this so an unhappy geometry degrades to the
-    XLA matmul instead of dying inside a jitted forward. Keyed by the
-    padded bm class, not the raw M."""
+    """Per-geometry compile probe for the GENERIC tiled kernel (same
+    contract as `gemv_kernel_compiles`). False only by RULE — no legal
+    tiling, the XLA matmul serves the shape. Keyed by the padded bm
+    class, not the raw M."""
     qt = get_qtype(qtype)
     bm, mp = _generic_bm(m)
     tiles = _matmul_tiles(qt, kp, n,
@@ -440,34 +414,17 @@ def matmul_kernel_compiles(qtype: str, m: int, kp: int, n: int,
         return False
     from bigdl_tpu.config import flags as _flags
 
-    if _flags().aot_target == "tpu":   # AOT lowering: trust the dispatch
+    if _flags().aot_target == "tpu":   # AOT lowering: the caller compiles
         return True
-    key = (qtype, bm, kp, n, bool(mxu))
-    hit = _matmul_probe_cache.get(key)
-    if hit is not None:
-        return hit
-    try:
-        from bigdl_tpu.ops.probing import probe_compile, quant_struct
+    from bigdl_tpu.ops.probing import probe_kernel, quant_struct
 
-        probe_compile(
-            lambda xx, ww: _q_matmul_generic(xx, ww, qt, bm, kp, n, False,
-                                             jnp.bfloat16),
-            jax.ShapeDtypeStruct((bm, kp), jnp.bfloat16),
-            quant_struct(kp, n, qtype, mxu=mxu))
-        ok = True
-    except Exception as e:
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "pallas generic matmul unavailable for (M=%d, K=%d, N=%d, %s)"
-            " — %s: %s; using the XLA matmul", m, kp, n, qtype,
-            type(e).__name__, e)
-        ok = False
-    from bigdl_tpu.ops.probing import record_probe_result
-
-    record_probe_result("matmul_generic", ok)
-    _matmul_probe_cache[key] = ok
-    return ok
+    return probe_kernel(
+        "matmul_generic", _matmul_probe_cache,
+        (qtype, bm, kp, n, bool(mxu)),
+        lambda xx, ww: _q_matmul_generic(xx, ww, qt, bm, kp, n, False,
+                                         jnp.bfloat16),
+        jax.ShapeDtypeStruct((bm, kp), jnp.bfloat16),
+        quant_struct(kp, n, qtype, mxu=mxu))
 
 
 def _q_gemv_pallas(x2: jax.Array, w: QTensor, qt, m: int, kp: int, n: int,
@@ -572,7 +529,7 @@ def _q_gemv_pallas(x2: jax.Array, w: QTensor, qt, m: int, kp: int, n: int,
         # N tiles are independent; only the K sweep carries the
         # accumulator — telling Mosaic lets it software-pipeline the
         # packed-data stream across j boundaries
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(*operands)
     return y[:m]

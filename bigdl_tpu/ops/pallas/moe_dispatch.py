@@ -164,58 +164,36 @@ def ragged_expert_matmul(x: jax.Array,          # [Np, K] (tile-padded)
     )(tile_expert, x2, *operands)
 
 
-_probe_cache: dict = {}
+_probe_cache: set = set()
 
 
 def ragged_kernel_compiles(qtype: Optional[str], k: int, n: int) -> bool:
-    """Eager per-geometry compile probe (same pattern as
-    ops/attention._kernel_compiles): verifies tileability of the REAL
-    (K, N) first, then compiles the kernel with the real tile classes on
-    a small stand-in (K = 2 tiles, N = 1 tile, E = 2) so a Mosaic
-    rejection degrades to the dense combine instead of crashing a jitted
-    forward."""
+    """Per-geometry compile probe (contract in ops/probing.py: True, or
+    `KernelProbeError`): verifies tileability of the REAL (K, N) first
+    — False there is a RULE, the dense combine serves the shape — then
+    compiles the kernel with the real tile classes on a small stand-in
+    (K = 2 tiles, N = 1 tile, E = 2)."""
     tiles = _ragged_tiles(qtype, k, n)
     if tiles is None:
         return False
     from bigdl_tpu.config import flags as _flags
 
-    if _flags().aot_target == "tpu":   # AOT lowering: trust the dispatch
+    if _flags().aot_target == "tpu":   # AOT lowering: the caller compiles
         return True
     bk, bn = tiles
-    key = (qtype, bk, bn)
-    hit = _probe_cache.get(key)
-    if hit is not None:
-        return hit
-    try:
-        from bigdl_tpu.ops.probing import (probe_compile, quant_struct,
-                                           stacked_struct)
+    from bigdl_tpu.ops.probing import (probe_kernel, quant_struct,
+                                       stacked_struct)
 
-        # compile-only AOT probe (see ops/probing.py) — safe inside the
-        # caller's jit trace, allocates nothing on device
-        t = TOKEN_TILE
-        kd = min(2 * bk, k if qtype is None else -(-k // bk) * bk)
-        kd = kd - kd % bk or bk
-        if qtype is None:
-            w = jax.ShapeDtypeStruct((2, kd, bn), jnp.bfloat16)
-        else:
-            w = stacked_struct(quant_struct(kd, bn, qtype), 2)
-        probe_compile(ragged_expert_matmul,
-                      jax.ShapeDtypeStruct((t, kd), jnp.bfloat16), w,
-                      jax.ShapeDtypeStruct((1,), jnp.int32))
-        ok = True
-    except Exception as e:
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "ragged MoE dispatch kernel unavailable for (K=%d, N=%d, %s) "
-            "(%s: %s); using the dense combine path", k, n, qtype,
-            type(e).__name__, e)
-        ok = False
-    from bigdl_tpu.ops.probing import record_probe_result
-
-    record_probe_result("moe_ragged", ok)
-    _probe_cache[key] = ok
-    return ok
+    kd = min(2 * bk, k if qtype is None else -(-k // bk) * bk)
+    kd = kd - kd % bk or bk
+    if qtype is None:
+        w = jax.ShapeDtypeStruct((2, kd, bn), jnp.bfloat16)
+    else:
+        w = stacked_struct(quant_struct(kd, bn, qtype), 2)
+    return probe_kernel(
+        "moe_ragged", _probe_cache, (qtype, bk, bn), ragged_expert_matmul,
+        jax.ShapeDtypeStruct((TOKEN_TILE, kd), jnp.bfloat16), w,
+        jax.ShapeDtypeStruct((1,), jnp.int32))
 
 
 def moe_mlp_ragged(

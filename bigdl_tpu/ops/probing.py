@@ -1,24 +1,28 @@
 """Compile-only kernel probes.
 
 Per-geometry dispatch probes (ops/attention._kernel_compiles,
-ops/pallas/dequant_matmul.gemv_kernel_compiles, ops/matmul.
-vmapped_pallas_ok, ops/pallas/moe_dispatch.ragged_kernel_compiles) must
-answer "does Mosaic accept this kernel at this geometry?" from INSIDE a
-model's outer jit trace, without crashing it.
+ops/pallas/dequant_matmul.gemv_kernel_compiles and
+matmul_kernel_compiles, ops/matmul.vmapped_pallas_ok,
+ops/pallas/moe_dispatch.ragged_kernel_compiles) compile the kernel a
+dispatch site is about to use, at that site's geometry, from INSIDE a
+model's outer jit trace.
 
-The round-2 probes executed a tiny concrete call under
-`jax.ensure_compile_time_eval()`. On a live TPU that shortcut routes the
-pallas kernel-body trace into the eager evaluator, where grid primitives
-have no eval rule — every probe died with "Evaluation rule for
-'program_id' not implemented" and silently pinned every geometry to XLA
-(caught on-chip, round 3: the first real-hardware bench ran 0 of 4
-kernel families).
+They run only where the kernel is the designed choice: the live backend
+is a TPU and dispatch is "auto". There a kernel Mosaic refuses is a
+defect, so a failed probe RAISES `KernelProbeError` carrying the
+compiler's message (and counts one `outcome="fallback"` first, so the
+scrape shows it). An earlier contract logged a warning and pinned the
+geometry to XLA; the first full chip bench then ran 0 of 4 kernel
+families and reported success. Shapes for which XLA is the designed
+choice (prefill-class M, GSPMD-sharded operands) never reach a probe —
+dispatch counts them with `record_dispatch_rule` under their own label.
 
-AOT lower+compile from abstract `ShapeDtypeStruct`s fixes it and is
-strictly better: nothing executes, no device buffers are allocated next
-to a resident multi-GB model, and the fresh `jax.jit(...).lower()`
-trace is independent of any ambient trace, so no tracer ever leaks in
-or out.
+The probe is AOT lower+compile from abstract `ShapeDtypeStruct`s:
+nothing executes, no device buffers are allocated next to a resident
+multi-GB model, and the fresh `jax.jit(...).lower()` trace is
+independent of any ambient trace, so no tracer leaks in or out. (A tiny
+concrete call under `jax.ensure_compile_time_eval()` does not work: on
+a live TPU grid primitives have no eager eval rule.)
 """
 
 from __future__ import annotations
@@ -27,33 +31,69 @@ import jax
 import jax.numpy as jnp
 
 
+class KernelProbeError(RuntimeError):
+    """The TPU compiler refused a Pallas kernel that auto dispatch
+    selected for this geometry."""
+
+
+def _probe_counter():
+    from bigdl_tpu.observability.metrics import default_registry
+
+    return default_registry().counter(
+        "bigdl_tpu_kernel_probe_total",
+        "Kernel dispatch outcomes per kernel: compile probe passed "
+        "(compiled), probe refused by the compiler (fallback; raises), "
+        "XLA chosen by a dispatch rule (xla_by_rule).",
+        labelnames=("kernel", "outcome"))
+
+
 def record_probe_result(kernel: str, ok: bool) -> None:
     """Count a probe outcome in the observability registry
-    (bigdl_tpu_kernel_probe_total{kernel, outcome="compiled"|"fallback"}).
-    Every dispatch-site probe calls this exactly once per new geometry,
-    making the round-3 failure class — every kernel silently pinned to
-    XLA — visible on /metrics."""
-    try:
-        from bigdl_tpu.observability.metrics import default_registry
+    (bigdl_tpu_kernel_probe_total{kernel, outcome="compiled"|"fallback"}),
+    once per new geometry."""
+    _probe_counter().labels(kernel, "compiled" if ok else "fallback").inc()
 
-        default_registry().counter(
-            "bigdl_tpu_kernel_probe_total",
-            "Kernel compile-probe outcomes "
-            "(compiled vs XLA fallback) per kernel.",
-            labelnames=("kernel", "outcome"),
-        ).labels(kernel, "compiled" if ok else "fallback").inc()
-    except Exception:
-        pass  # telemetry must never break dispatch
+
+def record_dispatch_rule(kernel: str) -> None:
+    """Count a dispatch that took XLA BY DESIGN (M above
+    matmul_pallas_max_m, operands sharded under GSPMD) — a rule, not a
+    probe outcome, so `outcome="fallback"` keeps meaning "the compiler
+    refused a kernel". Trace-time counts, like the probes."""
+    _probe_counter().labels(kernel, "xla_by_rule").inc()
 
 
 def probe_compile(fn, *arg_structs) -> None:
     """AOT-compile `fn` for the ambient backend from abstract shapes.
+    Raises whatever the lowering/compilation raises. Safe while tracing
+    an outer jit: only ShapeDtypeStructs cross the boundary.
 
-    Raises whatever the lowering/compilation raises (the caller's
-    probe classifies it permanent vs transient). Safe while tracing an
-    outer jit: only ShapeDtypeStructs cross the boundary.
+    The structs are rebuilt from (shape, dtype) alone: one made inside
+    a shard_map body (`quant_struct`'s eval_shape) carries a sharding
+    over that trace's ABSTRACT mesh, and lowering a fresh jit from it
+    fails ("only AbstractMesh exists in a jitted computation") — met on
+    the four-chip smoke, where every probe of the explicit-TP path used
+    to die this way and pin the shards to XLA without a word.
     """
+    arg_structs = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype), arg_structs)
     jax.jit(fn).lower(*arg_structs).compile()
+
+
+def probe_kernel(kernel: str, cache: set, key, fn, *arg_structs) -> bool:
+    """Compile `fn` once per `key`; True when the compiler accepts it
+    (remembered in `cache`), `KernelProbeError` when it does not."""
+    if key in cache:
+        return True
+    try:
+        probe_compile(fn, *arg_structs)
+    except Exception as e:  # noqa: BLE001 — Mosaic/XLA raise many types
+        record_probe_result(kernel, False)
+        raise KernelProbeError(
+            f"pallas {kernel} kernel refused by the TPU compiler at "
+            f"geometry {key}: {type(e).__name__}: {e}") from e
+    record_probe_result(kernel, True)
+    cache.add(key)
+    return True
 
 
 def stacked_struct(tree, n: int):

@@ -30,12 +30,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:                                    # jax >= 0.5
-    from jax import shard_map as _shard_map
-    _REP_KW = {"check_vma": False}
-except ImportError:                     # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _REP_KW = {"check_rep": False}
 
 
 def _chunk_scores(q, k, scale, logits_soft_cap):
@@ -115,12 +109,8 @@ def ring_attention(
     l0 = jnp.zeros((nb, b, hkv, g, bq), jnp.float32)
     # the loop body makes these device-varying (they depend on axis_index);
     # mark the initial values accordingly for shard_map's vma tracking.
-    # jax < 0.5 has no lax.pcast and no vma tracking (its shard_map runs
-    # with check_rep=False, see parallel/cp.py _REP_KW): skip the cast.
-    _pcast = getattr(lax, "pcast", None)
-    if _pcast is not None:
-        o0, m0, l0 = (_pcast(x, (axis_name,), to="varying")
-                      for x in (o0, m0, l0))
+    o0, m0, l0 = (lax.pcast(x, (axis_name,), to="varying")
+                  for x in (o0, m0, l0))
 
     perm = [(i, (i + 1) % n) for i in range(n)]
 
@@ -183,6 +173,6 @@ def sp_attention(
                            logits_soft_cap=logits_soft_cap,
                            sliding_window=sliding_window)
     spec = P(None, axis, None, None)
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        **_REP_KW)(q, k, v)
+        check_vma=False)(q, k, v)

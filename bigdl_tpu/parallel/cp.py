@@ -42,12 +42,6 @@ _WARNED_CP_SCALED = False    # one warning per process for int8/int4 CP
 from bigdl_tpu.ops.ring import ring_attention
 from bigdl_tpu.ops.rope import apply_rope, rope_cos_sin
 
-try:
-    from jax import shard_map as _shard_map
-    _REP_KW = {"check_vma": False}
-except ImportError:                        # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _REP_KW = {"check_rep": False}
 
 
 def _check_cfg(cfg) -> None:
@@ -138,9 +132,9 @@ def _prefill_fn(cfg, mesh, axis, s, max_seq, compute_dtype):
 
     spec_tok = P(None, axis)
     spec_cache = P(None, None, axis)
-    return tracked_jit("cp_prefill", _shard_map(
+    return tracked_jit("cp_prefill", jax.shard_map(
         local, mesh=mesh, in_specs=(P(), spec_tok),
-        out_specs=(P(), spec_cache, spec_cache), **_REP_KW))
+        out_specs=(P(), spec_cache, spec_cache), check_vma=False))
 
 
 def cp_decode_step(
@@ -240,10 +234,10 @@ def _decode_fn(cfg, mesh, axis, compute_dtype):
         return lg, ck2, cv2
 
     spec_cache = P(None, None, axis)
-    return tracked_jit("cp_decode_step", _shard_map(
+    return tracked_jit("cp_decode_step", jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(), spec_cache, spec_cache, P()),
-        out_specs=(P(), spec_cache, spec_cache), **_REP_KW),
+        out_specs=(P(), spec_cache, spec_cache), check_vma=False),
         donate_argnums=(2, 3))
 
 
@@ -369,10 +363,10 @@ def _extend_fn(cfg, mesh, axis, c, compute_dtype):
         return lg, ck2, cv2
 
     spec_cache = P(None, None, axis)
-    return tracked_jit("cp_prefill_chunk", _shard_map(
+    return tracked_jit("cp_prefill_chunk", jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(), spec_cache, spec_cache, P(), P()),
-        out_specs=(P(), spec_cache, spec_cache), **_REP_KW),
+        out_specs=(P(), spec_cache, spec_cache), check_vma=False),
         donate_argnums=(2, 3))
 
 

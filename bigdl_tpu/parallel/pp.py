@@ -168,15 +168,8 @@ def _pp_apply(params, cfg, tokens, mesh, num_microbatches, compute_dtype,
             return lax.psum(local * is_last, "pp")
         return lax.psum(logits * is_last, "pp")
 
-    try:
-        from jax import shard_map
-        rep_kw = {"check_vma": False}
-    except ImportError:                    # older jax
-        from jax.experimental.shard_map import shard_map
-        rep_kw = {"check_rep": False}
-
-    fn = shard_map(body, mesh=mesh, in_specs=tuple(specs),
-                   out_specs=P(), **rep_kw)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(specs),
+                       out_specs=P(), check_vma=False)
     return fn(*args)
 
 

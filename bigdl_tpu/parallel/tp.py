@@ -44,12 +44,6 @@ from bigdl_tpu.ops.kvcache import KVCache
 from bigdl_tpu.ops.matmul import linear
 from bigdl_tpu.parallel.sharding import llama_param_specs
 
-try:
-    from jax import shard_map as _shard_map
-    _REP_KW = {"check_vma": False}
-except ImportError:                        # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _REP_KW = {"check_rep": False}
 
 
 def _tp_cfg(cfg, n: int, axis: str = "tp"):
@@ -136,7 +130,7 @@ def _ff_padded(ff: int, n: int, block: int = 128) -> int:
     128-lane multiple AND a quant-block multiple. An unaligned shard
     (e.g. 11008/4 = 2752, which is 21.5 x 128) can never satisfy the
     Pallas matmul's bn tiling, so the whole MLP would decode on the slow
-    XLA dequant path (VERDICT r3 #4); and block-256 qtypes (k-quants,
+    XLA dequant path; and block-256 qtypes (k-quants,
     iqx) additionally need the down-proj's per-shard K to be a 256
     multiple, or the plane-row scaling in `_pad_ff_leaf` produces
     inconsistent shapes for odd shard counts (r4 advice). Zero-padding
@@ -400,13 +394,13 @@ def _tp_fn(cfg, mesh, axis):
     # pytree uses the PARAM SHAPE tree, built lazily at first call
     def run(params, tokens, cache):
         pspecs = tp_param_specs(params, mesh, axis=axis)
-        f = _shard_map(
+        f = jax.shard_map(
             fwd, mesh=mesh,
             in_specs=(pspecs, P(), tp_cache_specs(axis),
                       tp_cache_specs(axis),
                       P()),
             out_specs=(P(), tp_cache_specs(axis), tp_cache_specs(axis)),
-            **_REP_KW)
+            check_vma=False)
         lg, ck, cv = f(params, tokens, cache.k, cache.v, cache.pos)
         return lg, KVCache(ck, cv, cache.pos + tokens.shape[1])
 

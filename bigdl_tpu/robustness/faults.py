@@ -159,7 +159,7 @@ _STR_PARAMS = ("gate", "point")
 class InjectedFault(RuntimeError):
     """A fault raised by the injection harness. ``transient`` mirrors
     what the recovery code assumes about real-world analogs (XLA
-    transfer hiccups, tunnel resets): retrying may succeed."""
+    transfer hiccups, runtime resets): retrying may succeed."""
 
     def __init__(self, kind: str, point: str, step: int):
         super().__init__(f"injected {kind} at {point} (step {step})")

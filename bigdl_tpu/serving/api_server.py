@@ -1692,6 +1692,10 @@ def main():
     args = ap.parse_args()
     role = resolve_replica_role(args.role)
 
+    from bigdl_tpu.config import (default_kv_cache_dtype,
+                                  enable_compilation_cache)
+
+    enable_compilation_cache()
     tokenizer = None
     if args.tiny_random:
         from bigdl_tpu.utils.testing import tiny_random_model
@@ -1722,6 +1726,11 @@ def main():
     # prefix cache off unless opted in elsewhere
     engine = LLMEngine(model, EngineConfig(
         max_batch=args.max_batch, max_seq=args.max_seq,
+        # the dtype the load resolved from $BIGDL_TPU_KV_CACHE_DTYPE —
+        # left at EngineConfig's default, the server cached in bf16
+        # whatever the environment asked for
+        kv_cache_dtype=getattr(model, "kv_cache_dtype", None)
+        or default_kv_cache_dtype(),
         prefix_cache_entries=32 if role == "prefill" else 0,
         kv_page_size=args.kv_page_size, kv_pages=args.kv_pages,
         prefix_sharing=args.prefix_sharing))

@@ -1659,7 +1659,7 @@ class Router:
         """``POST /v1/admin/profiler``: fan a time-boxed jax.profiler
         capture out to every routable replica SIMULTANEOUSLY (the
         interesting regressions are fleet-synchronized: a noisy
-        neighbor, a tunnel hiccup, a bad deploy hits every replica in
+        neighbor, a host stall, a bad deploy hits every replica in
         the same second). Each replica captures into its own subdir of
         ``log_dir`` and auto-stops at ``duration_sec`` (clamped to
         ``BIGDL_TPU_PROFILER_MAX_SEC``) via the profiler watchdog — no

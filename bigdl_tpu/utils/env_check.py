@@ -28,7 +28,6 @@ KNOWN_ENV = (
     "BIGDL_TPU_BROWNOUT_LOW",
     "BIGDL_TPU_CANARY_NLL_TOL",
     "BIGDL_TPU_CANARY_SEC",
-    "BIGDL_TPU_COMPILE_CACHE",
     "BIGDL_TPU_COMPILE_MEMORY",
     "BIGDL_TPU_DECODE_RESIDENT",
     "BIGDL_TPU_DISABLE_NATIVE",
@@ -58,8 +57,6 @@ KNOWN_ENV = (
     "BIGDL_TPU_MOE_DISPATCH",
     "BIGDL_TPU_MXU_LAYOUT",
     "BIGDL_TPU_NATIVE_CACHE",
-    "BIGDL_TPU_PEAK_BF16_TFLOPS",
-    "BIGDL_TPU_PEAK_HBM_GBPS",
     "BIGDL_TPU_PERF_HISTORY",
     "BIGDL_TPU_POSTMORTEM_DIR",
     "BIGDL_TPU_PREFIX_SHARING",

@@ -1,7 +1,6 @@
 """AOT compilation of every Pallas kernel + full model programs for v5e.
 
-VERDICT r2 #1: the kernels were interpret-verified only — nothing had ever
-been through a real Mosaic lowering. This suite compiles them for an
+Interpret mode never takes a kernel through a real Mosaic lowering. This suite compiles them for an
 OFFLINE v5e topology (jax.experimental.topologies + local libtpu; no chip
 needed), so "should work on TPU" becomes "compiles for TPU" in CI.
 
@@ -154,7 +153,7 @@ def test_dequant_generic_i4_compiles(v5e, aot_flags):
     (2816, 4096),    # down-proj row shard
 ])
 def test_dequant_gemv_compiles_tp4_shards(v5e, aot_flags, k, n):
-    """VERDICT r3 #4: ALL FOUR llama2-7B matmul shapes at tp=4 must
+    """ALL FOUR llama2-7B matmul shapes at tp=4 must
     dispatch to the decode-GEMV kernel (with pad_ff_for_tp's ff
     lane-padding, 11008 -> 11264). Before the joint (bk, bn) tile
     search, the down-proj shard (K=2752) fell off the kernel entirely."""
@@ -195,6 +194,104 @@ def test_decode_attention_compiles(v5e, aot_flags, b, s, h, hkv, hd, kvdt):
         lambda qq, kk, vv, pp: decode_attention_pallas(
             qq, kk, vv, pp, hd ** -0.5),
         _sds(q, dev), _sds(kv, dev), _sds(kv, dev), _sds(pos, dev))
+    assert _has_mosaic_call(comp)
+
+
+def _scale_planes(shape, dev):
+    sc = jax.ShapeDtypeStruct(shape, jnp.float32)
+    return _sds(sc, dev), _sds(sc, dev)
+
+
+@pytest.mark.parametrize("b,s,kvdt", [
+    (1, 2048, "int8"), (8, 2048, "int8"),      # resident body, scaled
+    (1, 2048, "int4"), (8, 2048, "int4"),
+    (1, 16384, "int8"), (1, 16384, "int4"),    # S-blocked body, scaled
+])
+def test_decode_attention_scaled_kv_compiles(v5e, aot_flags, b, s, kvdt):
+    """Block-scaled int8/int4 KV at Mistral-7B GQA 32/8: codes plus
+    f32 (token, head) scale planes, dequantized in the kernel — distinct
+    Mosaic programs from the bf16/fp8 bodies above."""
+    from bigdl_tpu.ops.pallas.decode_attention import decode_attention_pallas
+
+    dev = v5e.devices[0]
+    h, hkv, hd = 32, 8, 128
+    q = jax.ShapeDtypeStruct((b, 1, h, hd), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, s, hkv, hd), jnp.dtype(kvdt))
+    pos = jax.ShapeDtypeStruct((b,), jnp.int32)
+    ks, vs = _scale_planes((b, s, hkv), dev)
+    comp = _compile(
+        lambda qq, kk, vv, pp, ks_, vs_: decode_attention_pallas(
+            qq, kk, vv, pp, hd ** -0.5, k_scale=ks_, v_scale=vs_),
+        _sds(q, dev), _sds(kv, dev), _sds(kv, dev), _sds(pos, dev), ks, vs)
+    assert _has_mosaic_call(comp)
+
+
+@pytest.mark.parametrize("b,kvdt", [
+    (1, "bfloat16"), (8, "bfloat16"), (8, "float8_e5m2"),
+    (1, "int8"), (8, "int8"), (8, "int4"),
+])
+def test_paged_decode_attention_compiles(v5e, aot_flags, b, kvdt):
+    """The block-table kernel (ops/pallas/paged_decode_attention) at
+    Mistral-7B GQA 32/8, hd 128, 128-position pages over a max_seq-2048
+    arena: K/V index_maps dereference the prefetched block table."""
+    from bigdl_tpu.ops.pallas.paged_decode_attention import (
+        paged_decode_attention_pallas)
+
+    dev = v5e.devices[0]
+    h, hkv, hd, ps, np_ = 32, 8, 128, 128, 16
+    pages = 8 * np_ + 1
+    q = jax.ShapeDtypeStruct((b, 1, h, hd), jnp.bfloat16)
+    arena = jax.ShapeDtypeStruct((pages, ps, hkv, hd), jnp.dtype(kvdt))
+    bt = jax.ShapeDtypeStruct((b, np_), jnp.int32)
+    pos = jax.ShapeDtypeStruct((b,), jnp.int32)
+    args = [_sds(q, dev), _sds(arena, dev), _sds(arena, dev),
+            _sds(bt, dev), _sds(pos, dev)]
+    if kvdt in ("int8", "int4"):
+        args += list(_scale_planes((pages, ps, hkv), dev))
+    comp = _compile(
+        lambda qq, kk, vv, bb, pp, ks=None, vs=None:
+        paged_decode_attention_pallas(qq, kk, vv, bb, pp, hd ** -0.5,
+                                      k_scale=ks, v_scale=vs), *args)
+    assert _has_mosaic_call(comp)
+
+
+# Mistral-7B sym_int4 decode matmuls, merged layout: qkv, o, gate_up,
+# down, lm_head
+MISTRAL_7B_GEMV = [(4096, 6144), (4096, 4096), (4096, 28672),
+                   (14336, 4096), (4096, 32000)]
+
+
+@pytest.mark.parametrize("mxu", [False, True], ids=["canonical", "mxu"])
+@pytest.mark.parametrize("k,n", MISTRAL_7B_GEMV)
+def test_xla_fused_gemv_compiles(v5e, k, n, mxu):
+    """The decode-shaped XLA path with the dequant fused into the dot
+    (ops/matmul._q_matmul_xla_fused) — what serves a decode matmul the
+    Pallas kernel is not chosen for — at both weight layouts."""
+    from bigdl_tpu.ops.matmul import q_matmul
+    from bigdl_tpu.ops.probing import quant_struct
+
+    dev = v5e.devices[0]
+    x = jax.ShapeDtypeStruct((8, k), jnp.bfloat16)
+    comp = _compile(lambda xx, ww: q_matmul(xx, ww, backend="xla_fused"),
+                    _sds(x, dev), _sds(quant_struct(k, n, "sym_int4",
+                                                    mxu=mxu), dev))
+    ma = comp.memory_analysis()
+    # fused: no full bf16 dequant of the weight (2*K*N bytes) in temp
+    assert ma.temp_size_in_bytes < k * n
+
+
+@pytest.mark.parametrize("k,n", MISTRAL_7B_GEMV)
+def test_q_matmul_auto_dispatch_is_mosaic_at_mistral_shapes(v5e, aot_flags,
+                                                            k, n):
+    """`auto` dispatch at decode M on the shipped (prepacked) layout
+    lowers to the Pallas GEMV at every Mistral-7B shape."""
+    from bigdl_tpu.ops.matmul import q_matmul
+    from bigdl_tpu.ops.probing import quant_struct
+
+    dev = v5e.devices[0]
+    x = jax.ShapeDtypeStruct((8, k), jnp.bfloat16)
+    comp = _compile(q_matmul, _sds(x, dev),
+                    _sds(quant_struct(k, n, "sym_int4", mxu=True), dev))
     assert _has_mosaic_call(comp)
 
 
@@ -361,6 +458,49 @@ def test_llama7b_decode_fp8_cache_compiles(v5e, aot_flags):
     comp = _compile(lambda p, i, c: M.forward(p, cfg, i, c),
                     params, ids, cache)
     assert _has_mosaic_call(comp)
+
+
+def test_engine_decode_resident_step_compiles(v5e, aot_flags):
+    """One whole serving decode step AS THE ENGINE BUILDS IT
+    (engine_decode_resident: layer scan + health + sampling in one
+    executable) for a registry-built Mistral-7B at published width,
+    merged + prepacked like a from_pretrained load, batch 8 over a
+    max_seq-2048 slab. Shapes only: the engine never sees an array."""
+    from bigdl_tpu.models import llama as M
+    from bigdl_tpu.models.registry import get_family
+    from bigdl_tpu.ops.quant import prepack_tree
+    from bigdl_tpu.serving import EngineConfig, LLMEngine
+    from bigdl_tpu.smoke import MISTRAL_7B_HF
+    from bigdl_tpu.utils.testing import random_llama_params
+
+    dev = v5e.devices[0]
+    family = get_family("MistralForCausalLM", MISTRAL_7B_HF)
+    cfg = family.config_from_hf(MISTRAL_7B_HF)
+
+    class Model:
+        params = jax.eval_shape(lambda: prepack_tree(M.merge_projections(
+            random_llama_params(cfg, "sym_int4"), cfg))[0])
+        config, hf_config, qtype = cfg, MISTRAL_7B_HF, "sym_int4"
+
+    Model.family = family
+    b = 8
+    eng = LLMEngine(Model, EngineConfig(max_batch=b, max_seq=2048,
+                                        sentinel=False, quality=False))
+    i32 = _sds(jax.ShapeDtypeStruct((b,), jnp.int32), dev)
+    f32 = _sds(jax.ShapeDtypeStruct((b,), jnp.float32), dev)
+    comp = eng._decode_resident.lower(
+        _sds(eng.params, dev), i32,
+        _sds(jax.eval_shape(lambda: eng.cache), dev),
+        f32, i32, f32, i32, i32, all_greedy=True,
+        with_quality=False).compile()
+    assert _has_mosaic_call(comp), (
+        "engine decode step compiled WITHOUT any Mosaic kernel")
+    # GEMV x5 (qkv, o, gate_up, down, lm_head) + decode attention
+    assert comp.as_text().count("tpu_custom_call") >= 6
+    ma = comp.memory_analysis()
+    live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert 5e9 < live < 8e9      # ~4.3 GB weights + ~2.1 GB KV slab
 
 
 def test_vmapped_gemv_compiles(v5e, aot_flags):
@@ -551,7 +691,7 @@ def test_explicit_tp_kernels_compile_v5e_mesh(v5e, aot_flags):
 
 
 def test_explicit_tp_moe_compiles_v5e_mesh(v5e, aot_flags):
-    """VERDICT r4 #8: mixtral-geometry MoE under explicit TP must
+    """Mixtral-geometry MoE under explicit TP must
     compile for the real v5e topology with Mosaic kernels AND the
     all-reduce — expert ff sharded across tp, psum on the partial
     expert outputs (8x7B geometry at 2 layers to bound compile time)."""
@@ -571,7 +711,7 @@ def test_explicit_tp_moe_compiles_v5e_mesh(v5e, aot_flags):
 
 
 def test_explicit_tp_parallel_residual_compiles_v5e_mesh(v5e, aot_flags):
-    """VERDICT r3 #6: a falcon-style (parallel-residual, shared input
+    """A falcon-style (parallel-residual, shared input
     norm, non-gated gelu MLP) family must compile for the real v5e
     topology under explicit TP with Mosaic kernels AND the all-reduce —
     these families previously could never use Pallas kernels

@@ -5,7 +5,7 @@ utils/profiling.py, and their engine wiring).
 Four invariants from the PR that introduced them:
 
 1. **Bench identity** — ``roofline.efficiency`` reproduces the exact
-   numbers the r05 bench fixture printed (bench.py imports the same
+   numbers a fixed bench fixture printed (bench.py imports the same
    function, so bench output and live gauges cannot drift), and the
    live-gauge formula (``decode_costs``) agrees with the bench
    ``decode_hbm_roofline_util`` formula to 4 decimals for a bf16
@@ -48,6 +48,18 @@ def _restore_flags():
     config_mod._flags = snap
 
 
+@pytest.fixture(autouse=True)
+def _v5e_peaks(monkeypatch):
+    """These tests pin v5e numbers: give the CPU the tests run on the
+    v5e's row of the peaks table (an unknown kind has no roofline —
+    tests/test_chip_smoke.py covers that side)."""
+    import jax
+
+    monkeypatch.setitem(roofline.CHIP_PEAKS,
+                        jax.devices()[0].device_kind,
+                        roofline.CHIP_PEAKS["TPU v5 lite"])
+
+
 # ---------------------------------------------------------------------------
 # analytical model vs the bench fixture
 
@@ -64,41 +76,40 @@ class _Llama7B:
     num_hidden_layers = 32
 
 
-# the r05 sym_int4 headline: weight_bytes measured from the live param
-# pytree, first/next token latencies from the bench record the cached
-# roofline block was computed from
-_R05_WEIGHT_BYTES = 3979157504
-_R05_PROMPT, _R05_STEPS = 1024, 64
-_R05_FIRST_MS, _R05_NEXT_MS = 109.301, 28.607
+# fixture inputs: a llama2-7B sym_int4 weight-byte count and a pair of
+# first/next token latencies; the expected outputs below pin the formulas
+_FIX_WEIGHT_BYTES = 3979157504
+_FIX_PROMPT, _FIX_STEPS = 1024, 64
+_FIX_FIRST_MS, _FIX_NEXT_MS = 109.301, 28.607
 
 
-def test_efficiency_reproduces_r05_fixture():
+def test_efficiency_reproduces_fixture():
     """The exact fixture numbers: bench.py now imports this function,
     so a drift here is a drift in every headline bench record."""
-    out = roofline.efficiency(_Llama7B, _R05_WEIGHT_BYTES, _R05_PROMPT,
-                              _R05_STEPS, _R05_FIRST_MS, _R05_NEXT_MS)
+    out = roofline.efficiency(_Llama7B, _FIX_WEIGHT_BYTES, _FIX_PROMPT,
+                              _FIX_STEPS, _FIX_FIRST_MS, _FIX_NEXT_MS)
     assert out["decode_hbm_roofline_util"] == 0.1935
     assert out["decode_ideal_ms"] == 5.534561
     assert out["decode_mfu"] == 0.00244
     assert out["prefill_mfu"] == 0.6412
-    assert out["weight_bytes"] == _R05_WEIGHT_BYTES
+    assert out["weight_bytes"] == _FIX_WEIGHT_BYTES
 
 
 def test_bench_efficiency_delegates_to_roofline():
     """bench.py's `_efficiency` is the same function, value-identical
     (the old inline math is gone)."""
     bench = pytest.importorskip("bench")
-    want = roofline.efficiency(_Llama7B, _R05_WEIGHT_BYTES, _R05_PROMPT,
-                               _R05_STEPS, _R05_FIRST_MS, _R05_NEXT_MS)
-    got = bench._efficiency(_Llama7B, _R05_WEIGHT_BYTES, _R05_PROMPT,
-                            _R05_STEPS, _R05_FIRST_MS, _R05_NEXT_MS)
+    want = roofline.efficiency(_Llama7B, _FIX_WEIGHT_BYTES, _FIX_PROMPT,
+                               _FIX_STEPS, _FIX_FIRST_MS, _FIX_NEXT_MS)
+    got = bench._efficiency(_Llama7B, _FIX_WEIGHT_BYTES, _FIX_PROMPT,
+                            _FIX_STEPS, _FIX_FIRST_MS, _FIX_NEXT_MS)
     assert got == want
 
 
 def test_bench_roofline_block_embeds_attribution():
     bench = pytest.importorskip("bench")
-    rec = bench._roofline_block(_Llama7B, _R05_WEIGHT_BYTES, _R05_PROMPT,
-                                _R05_STEPS, _R05_FIRST_MS, _R05_NEXT_MS)
+    rec = bench._roofline_block(_Llama7B, _FIX_WEIGHT_BYTES, _FIX_PROMPT,
+                                _FIX_STEPS, _FIX_FIRST_MS, _FIX_NEXT_MS)
     assert rec["decode_hbm_roofline_util"] == 0.1935
     attr = rec["roofline"]
     assert attr["decode"]["ideal_ms"] == pytest.approx(5.534561, abs=1e-6)
@@ -111,13 +122,13 @@ def test_decode_costs_agree_with_bench_formula():
     """The live gauge path (`decode_costs`, kv-dtype aware) and the
     bench formula (`efficiency`, bf16 cache) compute the same ideal ms
     — and hence the same util to 4 decimals — for bf16 at batch 1."""
-    s_mid = _R05_PROMPT + _R05_STEPS // 2
-    costs = roofline.decode_costs(_Llama7B, _R05_WEIGHT_BYTES, s_mid,
+    s_mid = _FIX_PROMPT + _FIX_STEPS // 2
+    costs = roofline.decode_costs(_Llama7B, _FIX_WEIGHT_BYTES, s_mid,
                                   kv_cache_dtype="bf16", batch=1)
-    eff = roofline.efficiency(_Llama7B, _R05_WEIGHT_BYTES, _R05_PROMPT,
-                              _R05_STEPS, _R05_FIRST_MS, _R05_NEXT_MS)
+    eff = roofline.efficiency(_Llama7B, _FIX_WEIGHT_BYTES, _FIX_PROMPT,
+                              _FIX_STEPS, _FIX_FIRST_MS, _FIX_NEXT_MS)
     assert round(costs["ideal_ms"], 6) == eff["decode_ideal_ms"]
-    assert (round(costs["ideal_ms"] / _R05_NEXT_MS, 4)
+    assert (round(costs["ideal_ms"] / _FIX_NEXT_MS, 4)
             == eff["decode_hbm_roofline_util"])
 
 
@@ -138,7 +149,7 @@ def test_kv_bytes_per_dtype(dtype, elt):
 
 def test_decode_costs_scale_with_batch_and_kv_dtype():
     cfg = _Llama7B
-    w = _R05_WEIGHT_BYTES
+    w = _FIX_WEIGHT_BYTES
     bf16 = roofline.decode_costs(cfg, w, 512, "bf16", batch=1)
     fp8 = roofline.decode_costs(cfg, w, 512, "fp8_e5m2", batch=1)
     b4 = roofline.decode_costs(cfg, w, 512, "bf16", batch=4)
@@ -152,20 +163,20 @@ def test_decode_costs_scale_with_batch_and_kv_dtype():
     assert b4["flops"] == pytest.approx(4 * bf16["flops"])
 
 
-def test_chip_peaks_env_override(monkeypatch):
-    monkeypatch.setenv("BIGDL_TPU_PEAK_HBM_GBPS", "1640")
-    monkeypatch.setenv("BIGDL_TPU_PEAK_BF16_TFLOPS", "394")
-    assert roofline.chip_peaks() == (394.0, 1640.0)
-    half = roofline.decode_costs(_Llama7B, _R05_WEIGHT_BYTES, 512)
-    monkeypatch.delenv("BIGDL_TPU_PEAK_HBM_GBPS")
-    monkeypatch.delenv("BIGDL_TPU_PEAK_BF16_TFLOPS")
-    full = roofline.decode_costs(_Llama7B, _R05_WEIGHT_BYTES, 512)
+def test_chip_peaks_come_from_the_table(monkeypatch):
+    assert roofline.chip_peaks("TPU v5 lite") == (197.0, 819.0)
+    monkeypatch.setitem(roofline.CHIP_PEAKS, "twice a v5e",
+                        (394.0, 1640.0))
+    half = roofline.decode_costs(_Llama7B, _FIX_WEIGHT_BYTES, 512,
+                                 device_kind="twice a v5e")
+    full = roofline.decode_costs(_Llama7B, _FIX_WEIGHT_BYTES, 512,
+                                 device_kind="TPU v5 lite")
     assert half["ideal_ms"] == pytest.approx(
         full["ideal_ms"] * 819.0 / 1640.0)
 
 
 def test_jit_costs_cover_tracked_jits():
-    costs = roofline.jit_costs(_Llama7B, _R05_WEIGHT_BYTES,
+    costs = roofline.jit_costs(_Llama7B, _FIX_WEIGHT_BYTES,
                                max_batch=4, max_seq=1024,
                                prefill_bucket=256)
     for name in ("engine_decode", "engine_decode_resident",

@@ -1,6 +1,6 @@
 """Multi-chip QLoRA: frozen-INT4 base + LoRA adapters over a dp x tp mesh.
 
-VERDICT r2 #3: the v5p-8 21-minute recipe (reference example/GPU/
+The v5p-8 21-minute recipe (reference example/GPU/
 LLM-Finetuning/QLoRA/alpaca-qlora, mpirun + DeepSpeed ZeRO-2 over 8
 cards) existed only as single-device tests plus a dense-weights dryrun.
 This file runs the REAL config on the 8-CPU virtual mesh: sym_int4

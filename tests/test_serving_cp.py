@@ -1,4 +1,4 @@
-"""Engine-level context-parallel serving (VERDICT r2 #8): a prompt
+"""Engine-level context-parallel serving: a prompt
 longer than one slot's max_seq admits anyway — its KV shards over the
 mesh (parallel/cp.py) while the batched slots keep serving."""
 

@@ -1,4 +1,4 @@
-"""Serving sampler breadth + scheduler preemption (VERDICT r2 #5).
+"""Serving sampler breadth + scheduler preemption.
 
 Reference parity targets: vllm/sampling_params.py (penalties, n, best_of,
 logprobs, seed) and vllm/core/scheduler.py:52-66 (preemption by recompute

@@ -180,7 +180,7 @@ ROUTER_COUNTERS = {
 
 # host dispatch overhead of the decode step (bench_serving
 # "critical_path" block, EWMA of dispatch-return time per step): the
-# tunnel-overhead number the paper optimizes, so it gets its own
+# host-overhead number the paper optimizes, so it gets its own
 # (tighter) --max-dispatch-regress-pct ratchet, lower-is-better
 DISPATCH_METRICS = {
     "dispatch_overhead_ms": "lower",
