@@ -1,0 +1,382 @@
+"""Runner of the serving cells (traffic kinds ``open`` and ``closed``).
+
+This process holds the chip. It builds the model on the device from the
+seed, composes ``LLMEngine(EngineConfig(...))`` and ``OpenAIServer`` as
+the program's ``api_server.main`` does (``from_pretrained`` is the one
+step skipped) and listens on 127.0.0.1. The load comes from a child
+process (``loadgen.py``) over real HTTP. End-to-end numbers are taken
+from the child's records, on the client side; per-layer numbers from the
+server's ``/metrics`` text at the window's edges and from a profiler
+trace of a short stretch inside the window (``--trace 1`` only).
+"""
+
+from __future__ import annotations
+
+import json
+import queue
+import subprocess
+import sys
+import threading
+import time
+from pathlib import Path
+from typing import Any, Dict, Optional
+
+from harness import common, costs, promtext, reference, stats, weights
+
+STEP_SPAN = "engine_step"
+REF_PROMPT_TOKENS = 32
+LOADGEN = Path(__file__).resolve().parent / "loadgen.py"
+
+
+def _reference_check(config, canonical, seed: int) -> Dict[str, Any]:
+    """Reference logits of one seeded prompt, from the canonical tree."""
+    import numpy as np
+
+    vocab = int(config["reference"]["vocab"])
+    ids = np.random.default_rng([seed & 0xFFFFFFFF, 13]).integers(
+        1, vocab, REF_PROMPT_TOKENS)
+    quant = {"qtype": config["quant"], "block": config["quant_block"]}
+    ref = np.asarray(reference.last_logits(
+        canonical, config["reference"], quant, [int(x) for x in ids]))
+    return {"ids": ids, "logits": ref}
+
+
+def _program_logits(model, ids, kv_cache_dtype: str):
+    """Last-position logits of the program's prefill for ``ids``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    family, cfg = model.family, model.config
+    cache = family.new_cache(cfg, 1, 128, kv_cache_dtype)
+    fn = jax.jit(family.prefill, static_argnums=1)
+    lg, _ = fn(model.params, cfg, jnp.asarray(ids, jnp.int32)[None, :],
+               cache)
+    return np.asarray(lg, np.float32).reshape(-1)
+
+
+class _ChildLines:
+    """The child's standard output, line by line, with a time limit."""
+
+    def __init__(self, proc):
+        self.q: "queue.Queue[Optional[str]]" = queue.Queue()
+        self.t = threading.Thread(target=self._pump, args=(proc,),
+                                  daemon=True)
+        self.t.start()
+
+    def _pump(self, proc):
+        for line in proc.stdout:
+            self.q.put(line)
+        self.q.put(None)
+
+    def event(self, name: str, timeout: float) -> Dict[str, Any]:
+        deadline = time.monotonic() + timeout
+        while True:
+            left = deadline - time.monotonic()
+            if left <= 0:
+                raise RuntimeError(f"load generator: no {name!r} event "
+                                   f"within {timeout:.0f} s")
+            try:
+                line = self.q.get(timeout=left)
+            except queue.Empty:
+                continue
+            if line is None:
+                raise RuntimeError(f"load generator ended before "
+                                   f"{name!r}")
+            try:
+                obj = json.loads(line)
+            except ValueError:
+                continue
+            if obj.get("event") == name:
+                return obj
+
+
+def _decode_kv_bytes(records, dims, kv_dtype: str, a: float, b: float
+                     ) -> float:
+    """Cache bytes the decode steps of ``[a, b)`` had to read: for each
+    token a client received then, the cache of its request at that
+    token's position."""
+    total = 0.0
+    for r in records:
+        got = 0
+        for t, k in r.get("chunks", []):
+            if a <= t < b:
+                for j in range(k):
+                    total += costs.kv_bytes_per_token(
+                        dims, r["prompt_tokens"] + got + j, kv_dtype)
+            got += k
+    return total
+
+
+def _start_loadgen(port: int, traffic, seed: int, seconds: float,
+                   vocab: int, out_dir: Path, tag: str, err_file):
+    """Start the generator on ``traffic`` and wait until its warm-up is
+    done. Returns the child, its line reader, the warm-up's report and
+    the path its records will be written to."""
+    plan_path = out_dir / f"{tag}plan.json"
+    results_path = out_dir / f"{tag}records.json"
+    with open(plan_path, "w") as f:
+        json.dump({"traffic": traffic, "seed": seed, "seconds": seconds,
+                   "vocab": vocab}, f)
+    child = subprocess.Popen(
+        [sys.executable, str(LOADGEN), "--port", str(port),
+         "--plan", str(plan_path), "--out", str(results_path)],
+        stdin=subprocess.PIPE, stdout=subprocess.PIPE,
+        stderr=err_file, text=True)
+    lines = _ChildLines(child)
+    try:
+        warm = lines.event("warm", timeout=1100.0)
+    except BaseException:
+        _stop(child)
+        raise
+    return child, lines, warm, results_path
+
+
+def _open_window(child) -> float:
+    """Tell the generator when the window starts; returns that time."""
+    t0 = time.monotonic() + 0.25
+    child.stdin.write(json.dumps({"t0": t0}) + "\n")
+    child.stdin.flush()
+    return t0
+
+
+def _records(child, lines, traffic, results_path: Path) -> Dict[str, Any]:
+    """Wait for the generator's end (the window's drain) and read what
+    it recorded."""
+    drain = float(traffic.get("drain_seconds", 30.0))
+    lines.event("done", timeout=drain * 2 + 60.0)
+    child.wait(timeout=30.0)
+    with open(results_path) as f:
+        return json.load(f)
+
+
+def _stop(child) -> None:
+    if child is not None and child.poll() is None:
+        child.kill()
+        child.wait()
+
+
+def _sweep(engine, port: int, traffic, rates, seed: int, seconds: float,
+           vocab: int, out_dir: Path, err_file) -> None:
+    """The builder's one-off search for the knee: the same server, one
+    window per rate, each printed on a line of its own. The backlog at
+    a window's end (queue depth, and how long the drain took) and the
+    failures say whether the rate was sustained."""
+    import copy
+
+    for i, rate in enumerate(rates):
+        tr = copy.deepcopy(traffic)
+        tr["arrivals"]["rate_rps"] = float(rate)
+        child, lines, _, results_path = _start_loadgen(
+            port, tr, seed + i, seconds, vocab, out_dir, f"sweep{i}_",
+            err_file)
+        try:
+            t0 = _open_window(child)
+            time.sleep(max(0.0, t0 + seconds - time.monotonic()))
+            snap = promtext.parse(engine.registry.render())
+            res = _records(child, lines, tr, results_path)
+        finally:
+            _stop(child)
+        m = stats.serving_metrics(res["records"], res["t0"], seconds)
+        common.note(
+            info="sweep", rate_rps=rate, attempted=m["attempted"],
+            failed=m["failed"], ttft_p50_ms=m["ttft_p50_ms"],
+            ttft_p90_ms=m["ttft_p90_ms"], itl_p95_ms=m["itl_p95_ms"],
+            output_tokens_per_s=m["output_tokens_per_s"],
+            queue_depth_at_end=promtext.total(snap, "bigdl_tpu_queue_depth"),
+            slots_at_end=promtext.total(snap, "bigdl_tpu_slot_occupancy"),
+            drained_s=res["drained_s"], late_p99_ms=m["late_p99_ms"])
+
+
+def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
+        t_process: float, out_dir: Path, device: Dict[str, Any],
+        peaks: Optional[Dict[str, float]],
+        sweep_rates: Optional[list] = None) -> Dict[str, Any]:
+    import jax
+
+    watch = common.CompileWatch().install()
+    marks = {"devices_ready_s": time.monotonic() - t_process}
+    config, traffic = cell.config, cell.traffic
+    eng_cfg = dict(config["engine"])
+    kv_dtype = eng_cfg.get("kv_cache_dtype", "bf16")
+    dims = costs.Dims.from_config(config)
+
+    from bigdl_tpu.serving.api_server import OpenAIServer
+    from bigdl_tpu.serving.engine import EngineConfig, LLMEngine
+
+    t_build = time.monotonic()
+    ref_box: Dict[str, Any] = {}
+    model, build_stages = weights.build_model(
+        config, seed, merge=True,
+        with_canonical=lambda canonical, cfg: ref_box.update(
+            _reference_check(config, canonical, seed)))
+    prog_logits = _program_logits(model, ref_box["ids"], kv_dtype)
+    rel = reference.relative_l2(prog_logits, ref_box["logits"])
+    tol = reference.tolerance(dims.num_hidden_layers, kv_dtype)
+    build_s = time.monotonic() - t_build
+
+    overload = eng_cfg.pop("overload", None)
+    if overload is not None:
+        from bigdl_tpu.serving.overload import OverloadConfig
+
+        eng_cfg["overload"] = OverloadConfig(**overload)
+    engine = LLMEngine(model, EngineConfig(prefix_cache_entries=0,
+                                           **eng_cfg))
+    if trace:
+        inner = engine.step
+
+        def traced_step():
+            with jax.profiler.TraceAnnotation(STEP_SPAN):
+                return inner()
+
+        engine.step = traced_step
+    marks["model_and_checks_s"] = time.monotonic() - t_process
+    server = OpenAIServer(engine, None)
+    httpd = server.serve("127.0.0.1", 0, background=True)
+    marks["serving_s"] = time.monotonic() - t_process
+    port = httpd.server_address[1]
+    child = None
+    err_file = open(out_dir / "loadgen.stderr", "w")
+    try:
+        if sweep_rates:
+            _sweep(engine, port, traffic, sweep_rates, seed, seconds,
+                   dims.vocab_size, out_dir, err_file)
+        child, lines, warm, results_path = _start_loadgen(
+            port, traffic, seed, seconds, dims.vocab_size, out_dir, "",
+            err_file)
+
+        def counters():
+            return promtext.parse(engine.registry.render())
+
+        c_setup = watch.snapshot()
+        t0 = _open_window(child)
+        setup_s = t0 - t_process
+        snap0 = counters()
+        w0 = watch.snapshot()
+
+        trace_dir = out_dir / "trace"
+        trace_ab = None
+        occupancy = []
+        if trace:
+            tr_start = min(float(traffic.get("trace_start_s", 6.0)),
+                           seconds * 0.4)
+            tr_len = min(float(traffic.get("trace_seconds", 3.0)),
+                         seconds * 0.4)
+            time.sleep(max(0.0, t0 + tr_start - time.monotonic()))
+            common.start_trace(trace_dir)
+            a = time.monotonic()
+            time.sleep(tr_len)
+            b = time.monotonic()
+            jax.profiler.stop_trace()
+            trace_ab = (a, b)
+        while time.monotonic() < t0 + seconds:
+            if trace:
+                v = promtext.total(counters(), "bigdl_tpu_slot_occupancy")
+                if v is not None:
+                    occupancy.append(v)
+            time.sleep(min(1.0, max(0.0, t0 + seconds - time.monotonic())))
+        snap1 = counters()
+        w1 = watch.snapshot()
+        mem_peak = common.memory_peak_bytes()
+
+        res = _records(child, lines, traffic, results_path)
+        snap2 = counters()
+        stats_json = engine.stats_snapshot()
+    finally:
+        _stop(child)
+        err_file.close()
+        server.shutdown()
+        httpd.server_close()
+
+    records = res["records"]
+    m = stats.serving_metrics(records, res["t0"], seconds)
+    wrong_length = sum(1 for r in records
+                       if r.get("error") and "asked" in r["error"])
+    tracked_compiles = promtext.delta(snap0, snap1,
+                                      "bigdl_tpu_jit_compiles_total") or 0.0
+    jax_compiles = watch.programs_between(w0, w1)
+    quarantined = promtext.total(
+        snap2, "bigdl_tpu_requests_quarantined_total") or 0.0
+    retries = promtext.total(snap2, "bigdl_tpu_step_retries_total") or 0.0
+    fallbacks = promtext.total(snap2, "bigdl_tpu_kernel_probe_total",
+                               {"outcome": "fallback"}) or 0.0
+    brownout = max(promtext.total(s, "bigdl_tpu_brownout_level") or 0.0
+                   for s in (snap0, snap1, snap2))
+    shed = promtext.total(snap2, "bigdl_tpu_requests_shed_total") or 0.0
+    preempted = promtext.delta(snap0, snap2,
+                               "bigdl_tpu_preemptions_total") or 0.0
+    pb, pa = res["probe_before"], res["probe_after"]
+    checks = {
+        "warmup_all_ok": warm["failed"] == 0,
+        "exact_token_counts": wrong_length == 0,
+        "no_compile_in_window": tracked_compiles == 0 and jax_compiles == 0,
+        "no_quarantine": quarantined == 0,
+        "no_step_retry": retries == 0,
+        "no_fallback_probe": fallbacks == 0,
+        "no_brownout_no_shed": brownout == 0 and shed == 0,
+        "probe_repeats": bool(pb["ok"] and pa["ok"]
+                              and pb["tokens"] == pa["tokens"]),
+        "reference_within_tolerance": rel <= tol,
+    }
+    values = dict(m)
+    values["setup_s"] = setup_s
+    common.note(
+        info="run", workload=cell.name, seed=seed, seconds=seconds,
+        checks=checks, reference_rel_l2=rel, reference_tolerance=tol,
+        samples={"ttft": m["n_ttft"], "gaps": m["n_gaps"],
+                 "tokens_in_window": m["tokens_in_window"],
+                 "highest_ttft_percentile_with_10_beyond":
+                     stats.highest_supported_percentile(m["n_ttft"])},
+        client={k: m[k] for k in ("ttft_mean_ms", "ttft_p50_ms",
+                                  "ttft_p90_ms", "ttft_ms",
+                                  "itl_p50_ms", "itl_p95_ms",
+                                  "output_tokens_per_s")},
+        generator_late_ms={"p50": m["late_p50_ms"], "p99": m["late_p99_ms"]},
+        warmup={"requests": warm["requests"], "failed": warm["failed"],
+                "seconds": warm["seconds"], "errors": warm["errors"]},
+        setup={"build_and_reference_s": build_s, "setup_s": setup_s,
+               "marks": marks,
+               "build_stages": build_stages,
+               "backend_compiles": c_setup["backend_compiles"],
+               "cache_hits": c_setup["cache_hits"],
+               "backend_compile_s": c_setup["backend_seconds"]},
+        window_compiles={"tracked": tracked_compiles, "jax": jax_compiles},
+        drained_s=res["drained_s"], preemptions=preempted,
+        errors=sorted({r["error"] for r in records if r["error"]})[:5],
+        compile_table={
+            k: {"compiles": v.get("compiles"), "total_s": v.get("total_s")}
+            for k, v in (stats_json.get("compile_table") or {}).items()
+            if v.get("compiles")})
+
+    dev = dict(device)
+    dev["memory_peak_bytes"] = mem_peak
+    result: Dict[str, Any] = {
+        "correct": all(checks.values()),
+        "attempted": m["attempted"], "failed": m["failed"], "device": dev,
+    }
+    if not trace:
+        if tiny:
+            # a CPU run yields no time and no rate: counts only
+            values = {}
+        result["metrics"] = common.select_end_to_end(cell, values)
+        return result
+
+    work = {
+        "linear_weight_bytes": costs.linear_weight_bytes(
+            dims, config["quant"], int(config["quant_block"])),
+    }
+    if trace_ab is not None:
+        work["decode_kv_bytes"] = _decode_kv_bytes(
+            records, dims, kv_dtype, *trace_ab)
+    obs = {
+        "counters_start": snap0, "counters_end": snap1, "client": m,
+        "memory_peak_bytes": mem_peak or None,
+        "device_kind": device["kind"] if not tiny else None,
+        "peaks": peaks, "work": work,
+    }
+    common.traced_metrics(
+        cell, result, obs, trace_dir if trace_ab is not None else None,
+        STEP_SPAN, tiny, out_dir,
+        slot_occupancy_mean=(sum(occupancy) / len(occupancy)
+                             if occupancy else None), work=work)
+    return result
