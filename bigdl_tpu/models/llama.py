@@ -45,6 +45,7 @@ from bigdl_tpu.ops.rope import (apply_rope, rope_cos_sin, rope_freqs,
                                 scaled_rope_freqs)
 
 
+@jax.named_scope("lm_head")
 def _lm_head(x, params, cfg):
     """Final projection (tied or separate), f32 logits, optional softcap."""
     from bigdl_tpu.ops.quant import QTensor
@@ -526,6 +527,7 @@ def _moe_mlp(hidden, lp, cfg: LlamaConfig):
     return y.reshape(b, t, d)
 
 
+@jax.named_scope("mlp")
 def _mlp(hidden, lp, cfg: LlamaConfig, record=None):
     if "router" in lp:
         if record is not None:
@@ -586,83 +588,75 @@ def _attn_block(hidden, lp, cfg: LlamaConfig, cos, sin, slopes,
     if cfg.alt_sliding_window and sw is not None and lidx is not None:
         # gemma2: sliding attention on even layers, global on odd
         sw = jnp.where(lidx % 2 == 0, sw, jnp.int32(1 << 30))
-    if "qkv_proj" in lp:
-        if record is not None:
-            record("qkv_proj", hidden)
-        q, k, v = _split_qkv(
-            linear(hidden, lp["qkv_proj"], lp.get("qkv_proj_bias")),
-            b, sq, h, hkv, hd)
-    else:
-        if record is not None:
-            record("q_proj", hidden)
-            record("k_proj", hidden)
-            record("v_proj", hidden)
-        q = linear(hidden, lp["q_proj"], lp.get("q_proj_bias")).reshape(
-            b, sq, h, hd)
-        k = linear(hidden, lp["k_proj"], lp.get("k_proj_bias")).reshape(
-            b, sq, hkv, hd)
-        v = linear(hidden, lp["v_proj"], lp.get("v_proj_bias")).reshape(
-            b, sq, hkv, hd)
-    if cfg.use_rope:
-        q = apply_rope(q, cos, sin, interleaved=cfg.rope_interleaved)
-        k = apply_rope(k, cos, sin, interleaved=cfg.rope_interleaved)
+    with jax.named_scope("attn.qkv"):
+        if "qkv_proj" in lp:
+            if record is not None:
+                record("qkv_proj", hidden)
+            q, k, v = _split_qkv(
+                linear(hidden, lp["qkv_proj"], lp.get("qkv_proj_bias")),
+                b, sq, h, hkv, hd)
+        else:
+            if record is not None:
+                record("q_proj", hidden)
+                record("k_proj", hidden)
+                record("v_proj", hidden)
+            q = linear(hidden, lp["q_proj"],
+                       lp.get("q_proj_bias")).reshape(b, sq, h, hd)
+            k = linear(hidden, lp["k_proj"],
+                       lp.get("k_proj_bias")).reshape(b, sq, hkv, hd)
+            v = linear(hidden, lp["v_proj"],
+                       lp.get("v_proj_bias")).reshape(b, sq, hkv, hd)
+        if cfg.use_rope:
+            q = apply_rope(q, cos, sin, interleaved=cfg.rope_interleaved)
+            k = apply_rope(k, cos, sin, interleaved=cfg.rope_interleaved)
 
-    if cache_ctx is not None and block_tables is not None:
+    out = None
+    if cache_ctx is not None:
         ck, cv, cks, cvs, clidx, pos = cache_ctx
-        if cks is not None:
-            ck, cv, cks, cvs = paged_update_layer(
-                ck, cv, clidx, k, v, pos, block_tables, cks, cvs)
-            kq = lax.dynamic_index_in_dim(ck, clidx, 0, keepdims=False)
-            vq = lax.dynamic_index_in_dim(cv, clidx, 0, keepdims=False)
-            ksc = lax.dynamic_index_in_dim(cks, clidx, 0, keepdims=False)
-            vsc = lax.dynamic_index_in_dim(cvs, clidx, 0, keepdims=False)
-            attn = sdp_attention_paged(q, kq, vq, block_tables, pos,
-                                       scale=scale, sliding_window=sw,
-                                       logits_soft_cap=cfg.attn_soft_cap,
-                                       alibi_slopes=slopes,
-                                       k_scale=ksc, v_scale=vsc)
-        else:
-            ck, cv = paged_update_layer(ck, cv, clidx, k, v, pos,
-                                        block_tables)
-            kf = lax.dynamic_index_in_dim(ck, clidx, 0, keepdims=False)
-            vf = lax.dynamic_index_in_dim(cv, clidx, 0, keepdims=False)
-            attn = sdp_attention_paged(q, kf, vf, block_tables, pos,
-                                       scale=scale, sliding_window=sw,
-                                       logits_soft_cap=cfg.attn_soft_cap,
-                                       alibi_slopes=slopes)
+        # with scale planes (block-scaled storage) the append quantizes
+        # and returns them too; attention then gets raw codes + scale
+        # planes so the dequant fuses into the kernels
+        with jax.named_scope("attn.kv_update"):
+            if block_tables is not None:
+                written = paged_update_layer(ck, cv, clidx, k, v, pos,
+                                             block_tables, cks, cvs)
+            else:
+                written = update_layer(ck, cv, clidx, k, v, pos, cks, cvs)
+            if cks is not None:
+                ck, cv, cks, cvs = written
+            else:
+                ck, cv = written
         out = (ck, cv, cks, cvs)
-    elif cache_ctx is not None:
-        ck, cv, cks, cvs, clidx, pos = cache_ctx
-        if cks is not None:
-            # block-scaled storage: quantize-on-append, then hand raw
-            # codes + scale planes to the attention dispatch so the
-            # dequant fuses into the kernels
-            ck, cv, cks, cvs = update_layer(ck, cv, clidx, k, v, pos,
-                                            cks, cvs)
+    with jax.named_scope("attn.core"):
+        attn_kw = dict(scale=scale, sliding_window=sw,
+                       logits_soft_cap=cfg.attn_soft_cap,
+                       alibi_slopes=slopes)
+        if cache_ctx is None:
+            attn = sdp_attention(q, k, v, jnp.zeros((), jnp.int32),
+                                 **attn_kw)
+        elif block_tables is not None:
+            kl = lax.dynamic_index_in_dim(ck, clidx, 0, keepdims=False)
+            vl = lax.dynamic_index_in_dim(cv, clidx, 0, keepdims=False)
+            if cks is not None:
+                attn_kw.update(
+                    k_scale=lax.dynamic_index_in_dim(cks, clidx, 0,
+                                                     keepdims=False),
+                    v_scale=lax.dynamic_index_in_dim(cvs, clidx, 0,
+                                                     keepdims=False))
+            attn = sdp_attention_paged(q, kl, vl, block_tables, pos,
+                                       **attn_kw)
+        elif cks is not None:
             kq, vq, ksc, vsc = read_layer_quantized(ck, cv, cks, cvs, clidx)
-            attn = sdp_attention(q, kq, vq, pos, scale=scale,
-                                 sliding_window=sw,
-                                 logits_soft_cap=cfg.attn_soft_cap,
-                                 alibi_slopes=slopes,
-                                 k_scale=ksc, v_scale=vsc)
+            attn = sdp_attention(q, kq, vq, pos, k_scale=ksc, v_scale=vsc,
+                                 **attn_kw)
         else:
-            ck, cv = update_layer(ck, cv, clidx, k, v, pos)
             kf, vf = read_layer(ck, cv, clidx)
-            attn = sdp_attention(q, kf, vf, pos, scale=scale,
-                                 sliding_window=sw,
-                                 logits_soft_cap=cfg.attn_soft_cap,
-                                 alibi_slopes=slopes)
-        out = (ck, cv, cks, cvs)
-    else:
-        attn = sdp_attention(q, k, v, jnp.zeros((), jnp.int32), scale=scale,
-                             sliding_window=sw,
-                             logits_soft_cap=cfg.attn_soft_cap,
-                             alibi_slopes=slopes)
-        out = None
+            attn = sdp_attention(q, kf, vf, pos, **attn_kw)
     attn = attn.reshape(b, sq, h * hd)
     if record is not None:
         record("o_proj", attn)
-    return linear(attn, lp["o_proj"], lp.get("o_proj_bias")), out
+    with jax.named_scope("attn.out"):
+        return linear(attn, lp["o_proj"], lp.get("o_proj_bias")), out
 
 
 def _decoder_layer(x, lp, cfg: LlamaConfig, cos, sin, slopes,

@@ -15,7 +15,11 @@ standard library — tests/test_observability.py enforces it):
   appended as JSONL to ``$BIGDL_TPU_EVENT_LOG`` (size-rotated at
   ``$BIGDL_TPU_EVENT_LOG_MAX_BYTES``, keeping
   ``$BIGDL_TPU_EVENT_LOG_KEEP`` rolled files ``.1`` .. ``.N``);
-  ``GET /v1/stats`` serves the snapshot.
+  ``GET /v1/stats`` serves the snapshot. ``PhaseClock`` is the
+  engine step's one instrument: each part of ``LLMEngine.step`` is a
+  profiler span (``engine.<phase>``, trace-only children ``cache.*``,
+  ``observe.*``, ``admission.wait``) and a share of one
+  ``bigdl_tpu_step_phase_seconds{phase}`` sample per step.
 - ``disttrace``: fleet-wide distributed tracing — W3C-style
   ``traceparent`` propagation (router -> replica -> engine -> KV-handoff
   target), a thread-safe ``SpanRecorder`` of completed spans per
@@ -81,7 +85,12 @@ Metric name -> engine field map (see also serving/engine.py):
 ==========================================  ===============================
 metric                                      source
 ==========================================  ===============================
-bigdl_tpu_request_phase_seconds{phase=...}  RequestSpan queue/prefill/decode
+bigdl_tpu_request_phase_seconds{phase=...}  RequestSpan queue/prefill/decode;
+                                            ingest: api_server handler
+bigdl_tpu_step_phase_seconds{phase=...}     tracing.PhaseClock in LLMEngine.step
+bigdl_tpu_prefill_chunks_total              LLMEngine._admission_step
+bigdl_tpu_prefill_tokens_total{kind}        LLMEngine._admission_step
+bigdl_tpu_stream_delivery_seconds           api_server stream handler
 bigdl_tpu_ttft_seconds                      RequestSpan.ttft_s
 bigdl_tpu_tpot_seconds                      LLMEngine.step() decode timing
 bigdl_tpu_slot_occupancy                    len(LLMEngine._slots)
@@ -197,6 +206,7 @@ from bigdl_tpu.observability.disttrace import (
     trace_sampled,
 )
 from bigdl_tpu.observability.tracing import (
+    PhaseClock,
     RequestSpan,
     RequestTracer,
     resolve_event_log_keep,
@@ -253,6 +263,7 @@ __all__ = [
     "MetricFamily",
     "MetricsRegistry",
     "default_registry",
+    "PhaseClock",
     "RequestSpan",
     "RequestTracer",
     "resolve_event_log_keep",

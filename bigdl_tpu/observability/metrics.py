@@ -22,7 +22,14 @@ module import order.
 Canonical serving metric names (emitted by serving/engine.py; see that
 module and observability/__init__ for the field mapping):
 
-    bigdl_tpu_request_phase_seconds{phase=queue|prefill|decode}  histogram
+    bigdl_tpu_request_phase_seconds{phase=ingest|queue|prefill|decode}
+                                                                 histogram
+    bigdl_tpu_step_phase_seconds{phase=queue_wait|prefill (per request),
+        sweep|admission|observe|cache (per working step),
+        dispatch|device|sample|emit|host (per step that decoded)} histogram
+    bigdl_tpu_prefill_chunks_total                               counter
+    bigdl_tpu_prefill_tokens_total{kind=prompt|padding}          counter
+    bigdl_tpu_stream_delivery_seconds (serving/api_server.py)    histogram
     bigdl_tpu_ttft_seconds                                       histogram
     bigdl_tpu_tpot_seconds                                       histogram
     bigdl_tpu_slot_occupancy / bigdl_tpu_queue_depth             gauge

@@ -17,7 +17,13 @@ own timestamps. Spans live in the tracer's in-memory ring buffer
 configured — explicitly or via ``BIGDL_TPU_EVENT_LOG`` — every event is
 appended to a JSONL file for offline analysis.
 
-Stdlib-only by design (see observability/metrics.py).
+``PhaseClock`` is the engine step's one instrument: ``phase(name)``
+opens a profiler span, reads the host clock at both ends and adds the
+duration to the step's total for ``name``; ``end()`` observes each
+total once into ``bigdl_tpu_step_phase_seconds{phase=<name>}``.
+
+Stdlib-only by design (see observability/metrics.py): the profiler's
+annotation factory is handed in by the engine.
 """
 
 from __future__ import annotations
@@ -28,7 +34,7 @@ import json
 import os
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 
 def resolve_event_log_max_bytes(value=None):
@@ -360,3 +366,89 @@ class RequestTracer:
             done = [s.to_dict() for s in
                     list(self._finished)[-max(recent, 0):]]
         return {"active": active, "recent": done}
+
+
+# -- the engine step's phase clock -------------------------------------------
+
+# Labels of bigdl_tpu_step_phase_seconds that PhaseClock.end() observes,
+# by population: one sample on every working step (what
+# bigdl_tpu_engine_steps_total counts), or one on every step that
+# decoded. ``cache`` is derived: the sum of the step's ``cache.*`` child
+# spans; so is ``host``: the step's wall less ``device``.
+WORKING_STEP_PHASES = ("sweep", "admission", "observe", "cache")
+DECODE_STEP_PHASES = ("dispatch", "device", "sample", "emit", "host")
+_DERIVED_FROM_CHILDREN = frozenset({"cache"})
+
+
+class _Phase:
+    __slots__ = ("_clock", "_key", "_span", "_t0")
+
+    def __init__(self, clock: "PhaseClock", key: Optional[str], span):
+        self._clock = clock
+        self._key = key
+        self._span = span
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        if self._key is not None:
+            totals = self._clock._totals
+            totals[self._key] = totals.get(self._key, 0.0) + dt
+        self._span.__exit__(*exc)
+        return False
+
+
+class PhaseClock:
+    """Spans and per-step totals of the parts of ``LLMEngine.step``.
+
+    ``phase(name)`` is a top-level phase: a profiler span
+    ``engine.<name>`` and a share of this step's total for ``name``.
+    ``phase(name, child=True)`` is trace-only: a span under the dotted
+    name given, with no label of its own; a ``cache.*`` child also adds
+    to the derived ``cache`` label's total. ``annotation(name)`` makes
+    the span (``utils.profiling.annotate``: this module imports no
+    jax). Used from the engine thread only.
+    """
+
+    def __init__(self, histogram, annotation: Callable[[str], object]):
+        self._annotation = annotation
+        self._totals: Dict[str, float] = {}
+        self._t_begin = time.perf_counter()
+        # made here, so that every label renders from scrape 1
+        self._samples = {name: histogram.labels(name) for name in
+                         WORKING_STEP_PHASES + DECODE_STEP_PHASES}
+
+    def begin(self) -> None:
+        """Start of a step: forget the last step's totals."""
+        self._totals.clear()
+        self._t_begin = time.perf_counter()
+
+    def phase(self, name: str, child: bool = False) -> _Phase:
+        if child:
+            head = name.partition(".")[0]
+            return _Phase(
+                self, head if head in _DERIVED_FROM_CHILDREN else None,
+                self._annotation(name))
+        return _Phase(self, name, self._annotation("engine." + name))
+
+    def seconds(self, name: str) -> float:
+        """This step's total for ``name`` so far."""
+        return self._totals.get(name, 0.0)
+
+    def end(self, worked: bool) -> None:
+        """End of a step: one sample per phase of the step's
+        population (a step that ran the ``device`` phase decoded); an
+        idle step observes nothing."""
+        totals = self._totals
+        if "device" in totals:
+            wall = time.perf_counter() - self._t_begin
+            totals["host"] = max(wall - totals.get("device", 0.0), 0.0)
+            for name in DECODE_STEP_PHASES:
+                self._samples[name].observe(totals.get(name, 0.0))
+        if worked:
+            for name in WORKING_STEP_PHASES:
+                self._samples[name].observe(totals.get(name, 0.0))

@@ -147,7 +147,8 @@ def _q_matmul_xla(x: jax.Array, w: QTensor) -> jax.Array:
         y = _q_matmul_xla_chunked(x, w, min_elems=_DECODE_CHUNK_ELEMS)
         if y is not None:
             return y
-    dense = dequantize(w, dtype=jnp.bfloat16)
+    with jax.named_scope("dequant"):
+        dense = dequantize(w, dtype=jnp.bfloat16)
     y = jnp.dot(
         x.astype(jnp.bfloat16), dense, preferred_element_type=jnp.float32
     )

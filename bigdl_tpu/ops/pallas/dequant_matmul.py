@@ -520,6 +520,7 @@ def _q_gemv_pallas(x2: jax.Array, w: QTensor, qt, m: int, kp: int, n: int,
             in_specs.append(scale_spec)
     y = pl.pallas_call(
         kernel,
+        name=f"qmatmul_gemv_{w.qtype}",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_spec,
@@ -623,12 +624,14 @@ def _q_matmul_generic(x2: jax.Array, w: QTensor, qt, m: int, kp: int,
     out_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
     out_shape = jax.ShapeDtypeStruct((mp, n), out_dtype)
     scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
+    gemm_name = f"qmatmul_gemm_{w.qtype}"    # the kernel's trace name
 
     if w.data.dtype == jnp.int4:
         data_spec = pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))
         kernel = functools.partial(_kernel_i4, block=b, bk=bk, bn=bn, nk=nk)
         y = pl.pallas_call(
             kernel,
+            name=gemm_name,
             grid=grid,
             in_specs=[x_spec, data_spec, scale_spec],
             out_specs=out_spec,
@@ -648,6 +651,7 @@ def _q_matmul_generic(x2: jax.Array, w: QTensor, qt, m: int, kp: int,
                 bk=bk, bn=bn, nk=nk)
             y = pl.pallas_call(
                 kernel,
+                name=gemm_name,
                 grid=grid,
                 in_specs=[x_spec, data_spec, scale_spec, scale_spec],
                 out_specs=out_spec,
@@ -662,6 +666,7 @@ def _q_matmul_generic(x2: jax.Array, w: QTensor, qt, m: int, kp: int,
                 bk=bk, bn=bn, nk=nk)
             y = pl.pallas_call(
                 kernel,
+                name=gemm_name,
                 grid=grid,
                 in_specs=[x_spec, data_spec, scale_spec],
                 out_specs=out_spec,
@@ -675,6 +680,7 @@ def _q_matmul_generic(x2: jax.Array, w: QTensor, qt, m: int, kp: int,
         kernel = functools.partial(_kernel_int8, block=b, bk=bk, bn=bn, nk=nk)
         y = pl.pallas_call(
             kernel,
+            name=gemm_name,
             grid=grid,
             in_specs=[x_spec, data_spec, scale_spec],
             out_specs=out_spec,

@@ -159,7 +159,8 @@ def ragged_expert_matmul(x: jax.Array,          # [Np, K] (tile-padded)
         scratch_shapes=scratch,
     )
     return pl.pallas_call(
-        kernel, grid_spec=grid_spec, out_shape=out_shape,
+        kernel, name="moe_ragged_matmul", grid_spec=grid_spec,
+        out_shape=out_shape,
         interpret=interpret,
     )(tile_expert, x2, *operands)
 

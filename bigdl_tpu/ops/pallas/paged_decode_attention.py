@@ -197,6 +197,7 @@ def paged_decode_attention_pallas(
                      v_scale.astype(jnp.float32))
     out = pl.pallas_call(
         kernel,
+        name="paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, gp, hd), q.dtype),
         interpret=interpret,

@@ -258,6 +258,7 @@ def _pfa_impl(
     )
     out = pl.pallas_call(
         kernel,
+        name="prefill_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, s, hd), q.dtype),
         interpret=interpret,

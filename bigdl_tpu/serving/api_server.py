@@ -67,6 +67,7 @@ from bigdl_tpu.serving.overload import RequestShed
 from bigdl_tpu.serving.wire import (REJECT_REASONS, WireError,
                                     corrupt_frame, frame_payload,
                                     is_framed, unframe_payload)
+from bigdl_tpu.utils.profiling import annotate
 
 #: engine finish reasons that map to HTTP 504 (the request ran out of
 #: time: its own deadline, or the server's drain window closed on it)
@@ -245,7 +246,10 @@ class _EngineLoop:
                 traceback.print_exc()
                 did = False
             if not did:
-                self._wake.wait(timeout=0.01)
+                # a span of its own, so that a profiler capture tells
+                # "no work" from "host busy" on the engine thread
+                with annotate("engine_loop.wait"):
+                    self._wake.wait(timeout=0.01)
                 self._wake.clear()
 
     def notify(self):
@@ -408,6 +412,18 @@ class OpenAIServer:
             "bigdl_tpu_requests_cancelled_total",
             "requests aborted because the client disconnected",
             ["path"])
+        # the engine-to-wire leg: a token's way from the engine's
+        # _push_output to its SSE chunk written and flushed (the
+        # handler's 2 ms output poll, the GIL, detokenization, the
+        # socket write), and a request's way from its request line to
+        # the engine's queue
+        self._m_stream_delivery = engine.registry.histogram(
+            "bigdl_tpu_stream_delivery_seconds",
+            "Streaming: engine output pushed to its SSE chunk written "
+            "and flushed, by the oldest output each chunk carries.")
+        self._m_ingest = engine.registry.histogram(   # the engine's family
+            "bigdl_tpu_request_phase_seconds",
+            labelnames=("phase",)).labels("ingest")
         # optional /v1/embeddings backend: a BertEmbedder (transformers/
         # embedder.py) served next to the LLM — the reference serves
         # embeddings through its langchain wrapper and FastChat worker;
@@ -518,6 +534,10 @@ class OpenAIServer:
         # requests decode once at the end
         live_decode = bool(stop_strs) or stream_cb is not None
         cancelled = [False]          # cancel_cb fired (at most once)
+        # index -> push time of the oldest output whose text no chunk
+        # has carried yet / of the newest output (a held-back tail)
+        oldest_push: dict = {}
+        newest_push: dict = {}
         if seed_ids:
             # a resumed (migrated-in) request: the engine only emits
             # tokens generated since the claim, but the client is owed
@@ -558,6 +578,11 @@ class OpenAIServer:
                 try:
                     stream_cb(full[start:upto], idx)
                     emitted[idx] = upto
+                    t_push = (oldest_push.pop(idx, None)
+                              or newest_push.get(idx))
+                    if t_push:
+                        self._m_stream_delivery.observe(
+                            time.perf_counter() - t_push)
                 except OSError:
                     # client went away mid-stream: free the slot (the
                     # abort also drops the prompt's prefix-cache
@@ -638,6 +663,8 @@ class OpenAIServer:
                     if o.logprobs:
                         out_lps.setdefault(idx, []).extend(o.logprobs)
                 if live_decode and o.new_token_ids and idx not in stopped:
+                    oldest_push.setdefault(idx, o.t_push)
+                    newest_push[idx] = o.t_push
                     det = detoks.get(idx)
                     if det is None:
                         det = detoks[idx] = _IncrementalDetok(
@@ -988,6 +1015,12 @@ class OpenAIServer:
         class Handler(BaseHTTPRequestHandler):
             def log_message(self, *a):   # quiet
                 pass
+
+            def parse_request(self):
+                # called with the request line just read: the ingest
+                # phase of a completion counts from here
+                self._t_request = time.perf_counter()
+                return super().parse_request()
 
             def _json(self, code: int, obj: dict, headers=()):
                 body = json.dumps(obj).encode()
@@ -1462,6 +1495,8 @@ class OpenAIServer:
                 else:
                     server.engine.add_request(rid, ids, params,
                                               trace=trace)
+                server._m_ingest.observe(
+                    time.perf_counter() - self._t_request)
                 server.loop.notify()
 
                 if body.get("stream"):

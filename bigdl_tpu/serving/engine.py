@@ -66,7 +66,7 @@ from bigdl_tpu.observability.memory import MemoryLedger, tree_nbytes
 from bigdl_tpu.observability.metrics import RATIO_BUCKETS, default_registry
 from bigdl_tpu.observability.slo import SLOTracker
 from bigdl_tpu.observability.stats import ewma as stats_ewma
-from bigdl_tpu.observability.tracing import RequestTracer
+from bigdl_tpu.observability.tracing import PhaseClock, RequestTracer
 from bigdl_tpu.observability.usage import UsageLedger
 from bigdl_tpu.ops.kvcache import (KVCache, init_cache, kv_cache_bytes,
                                    kv_cache_nbytes,
@@ -82,6 +82,7 @@ from bigdl_tpu.serving.overload import (QOS_CLASSES, SHED_REASONS,
                                         OverloadConfig, OverloadController,
                                         RequestShed)
 from bigdl_tpu.serving.pagepool import PagePool, RadixCache
+from bigdl_tpu.utils.profiling import annotate
 
 
 class EngineDraining(RuntimeError):
@@ -198,6 +199,9 @@ class RequestOutput:
     # structured failure detail for finish_reason "error" (quarantine):
     # {"reason", "request_id"[, "type", "message"]}
     error: Optional[dict] = None
+    # time.perf_counter() when _push_output took it: the start of the
+    # API server's bigdl_tpu_stream_delivery_seconds
+    t_push: float = 0.0
 
 
 @dataclasses.dataclass
@@ -415,6 +419,7 @@ class _Admission:
     new_pages: Optional[List[int]] = None
 
 
+@jax.named_scope("sampler")
 def _device_sample_rows(lg, temps, top_ks, top_ps, seeds, poss):
     """Batched on-device sampler body: temperature / top-k / top-p via
     gumbel-max, one seeded stream per row. Shared by the standalone
@@ -946,18 +951,29 @@ class LLMEngine:
         m = self.registry
         self._m_phase = m.histogram(
             "bigdl_tpu_request_phase_seconds",
-            "Per-request phase latency (queue wait, prefill, decode).",
+            "Per-request phase latency: ingest (API server: request "
+            "line read to add_request returned), queue wait, prefill, "
+            "decode.",
             labelnames=("phase",))
-        for ph in ("queue", "prefill", "decode"):   # render from scrape 1
+        for ph in ("ingest", "queue", "prefill", "decode"):
+            # render from scrape 1
             self._m_phase.labels(ph)
         self._m_step_phase = m.histogram(
             "bigdl_tpu_step_phase_seconds",
-            "Engine step critical-path decomposition: per-request "
-            "queue_wait/prefill, per-step host dispatch vs device "
-            "compute (blocked block_until_ready on the decode result).",
+            "Engine step critical-path decomposition. Per request: "
+            "queue_wait, prefill. One sample per working step: sweep, "
+            "admission, observe, cache (the step's cache.* spans). One "
+            "per step that decoded: dispatch (host, to the decode "
+            "call's return), device (blocked block_until_ready on the "
+            "decode result), sample, emit, host (step wall less "
+            "device).",
             labelnames=("phase",))
-        for ph in ("queue_wait", "prefill", "dispatch", "device"):
+        for ph in ("queue_wait", "prefill"):
             self._m_step_phase.labels(ph)   # render from scrape 1
+        # the step's phase clock: every part of step() is a span on the
+        # profiler's clock (engine.<phase>, cache.*, observe.*,
+        # admission.wait) and a share of one histogram sample per step
+        self.phases = PhaseClock(self._m_step_phase, annotate)
         self._m_ttft = m.histogram(
             "bigdl_tpu_ttft_seconds",
             "Time to first token: arrival to first sampled token.")
@@ -988,12 +1004,20 @@ class LLMEngine:
         self._m_tokens = m.counter(
             "bigdl_tpu_tokens_generated_total",
             "Tokens emitted to clients.")
-        self._m_handoff_staged = m.counter(
-            "bigdl_tpu_handoff_staged_total",
-            "Remote KV-handoff snapshots staged into the prefix cache.")
+        self._m_prefill_chunks = m.counter(
+            "bigdl_tpu_prefill_chunks_total",
+            "Prefill chunks dispatched by admission (at most one per "
+            "step).")
+        self._m_prefill_tokens = m.counter(
+            "bigdl_tpu_prefill_tokens_total",
+            "Tokens of the dispatched prefill chunks: prompt tokens, "
+            "and the padding that fills the rest of a chunk's width.",
+            labelnames=("kind",))
+        for kd in ("prompt", "padding"):  # render from scrape 1
+            self._m_prefill_tokens.labels(kd)
         # live-migration observability: outcomes, source-side wall
-        # time, and the migrated-vs-recomputed token ledger the bench
-        # rolling-restart lane and tools/bench_diff.py gate on
+        # time, and the tokens a committed migration preserved (the
+        # recomputed count rides /v1/stats "migration" only)
         self._m_migrations = m.counter(
             "bigdl_tpu_migrations_total",
             "Live sequence migrations by outcome (bench_diff gates "
@@ -1011,11 +1035,6 @@ class LLMEngine:
             "Generated-so-far tokens preserved across committed "
             "migrations (decode work NOT thrown away by a drain, "
             "rolling restart, or scale-down).")
-        self._m_recomputed_tokens = m.counter(
-            "bigdl_tpu_recomputed_tokens_total",
-            "Generated-so-far tokens whose KV must be recomputed "
-            "because a failed migration had no staged copy to fall "
-            "back on (bench_diff gates this lower-is-better).")
         # pre-register the families fed by ops/probing.py and
         # speculative.py so /metrics exposes them before the first
         # probe or speculative round runs in this process
@@ -1085,9 +1104,6 @@ class LLMEngine:
             "Brownout degradation level (0 healthy ... 3 shedding "
             "batch QoS at admission).")
         self._m_brownout.set(0)
-        self._m_tenant_queued = m.gauge(
-            "bigdl_tpu_tenant_queue_depth",
-            "Queued requests per tenant.", labelnames=("tenant",))
         self._m_tenant_reqs = m.counter(
             "bigdl_tpu_tenant_requests_total",
             "Per-tenant admission outcomes.",
@@ -1724,6 +1740,9 @@ class LLMEngine:
         self.faults.raise_point("prefill", self._step_idx)
         logits, a.cache1 = self._prefill(
             self.params, jnp.asarray(padded), a.cache1)
+        self._m_prefill_chunks.inc()
+        self._m_prefill_tokens.labels("prompt").inc(len(part))
+        self._m_prefill_tokens.labels("padding").inc(chunk - len(part))
         start = a.consumed
         a.consumed += chunk
 
@@ -1754,7 +1773,8 @@ class LLMEngine:
         a row changed — steady-state decode reuses the resident array
         (no per-token H2D of page indices)."""
         if self._bt_dirty:
-            self._bt_dev = jnp.asarray(self._bt_np)
+            with self.phases.phase("cache.block_table", child=True):
+                self._bt_dev = jnp.asarray(self._bt_np)
             self._bt_dirty = False
         return self._bt_dev
 
@@ -1798,7 +1818,8 @@ class LLMEngine:
                 request_id=req.request_id, consumed=consumed,
                 n_pages=keep)
         elif self.radix is not None:
-            matched, pages = self.radix.match(prompt)
+            with self.phases.phase("cache.radix_match", child=True):
+                matched, pages = self.radix.match(prompt)
             # the seeded length must stay aligned to both the prefill
             # chunk and the page size (powers of two: lcm == max), and
             # the final prompt token must run to produce logits
@@ -1808,12 +1829,13 @@ class LLMEngine:
             shared = pages[:consumed // ps]
         want = min(plen + req.params.max_tokens, ce.max_seq)
         n_new = -(-want // ps) - len(shared)
-        new = self.pool.alloc(n_new)
-        if new is None and self.radix is not None:
-            # reclaim idle radix leaves (LRU-first; a page a live slot
-            # maps is never an eviction candidate) and retry once
-            self.radix.evict(n_new - self.pool.num_free)
+        with self.phases.phase("cache.page_alloc", child=True):
             new = self.pool.alloc(n_new)
+            if new is None and self.radix is not None:
+                # reclaim idle radix leaves (LRU-first; a page a live
+                # slot maps is never an eviction candidate), retry once
+                self.radix.evict(n_new - self.pool.num_free)
+                new = self.pool.alloc(n_new)
         if new is None:
             if owned:
                 # give the claimed pages back; the deferred re-admission
@@ -1864,9 +1886,10 @@ class LLMEngine:
             jnp.asarray(idx, jnp.int32), jnp.asarray(plen, jnp.int32))
         if self.radix is not None:
             n_prompt_pages = -(-plen // ps)
-            self.radix.insert(
-                a.req.prompt_token_ids,
-                [int(p) for p in self._bt_np[idx, :n_prompt_pages]])
+            with self.phases.phase("cache.radix_insert", child=True):
+                self.radix.insert(
+                    a.req.prompt_token_ids,
+                    [int(p) for p in self._bt_np[idx, :n_prompt_pages]])
         return cache
 
     def _cow_step(self, active: List[int]) -> None:
@@ -2015,7 +2038,6 @@ class LLMEngine:
                 self._prefix_index_add(key)
             self._prefix_cache[key] = entry
             self._handoff_keys.append(key)
-            self._m_handoff_staged.inc()
             seed_shape = tuple(entry[0].shape)
             self.flight.record("handoff_staged", step=self._step_idx,
                                prompt_len=len(key),
@@ -2356,7 +2378,6 @@ class LLMEngine:
                 # generated-so-far tail from tokens
                 self._mig["recomputed_tokens_total"] += \
                     meta["n_generated"]
-                self._m_recomputed_tokens.inc(meta["n_generated"])
             self.waiting.append(resumed)
             self._mig_inc("local_resume")
             self.tracer.preempted(rid)
@@ -2678,15 +2699,21 @@ class LLMEngine:
         p = s.req.params
         if s.counts is None and s.n_logprobs < 0:
             pos = s.req.generated_offset     # position 0 of this resume
-            tok = int(np.asarray(self._sample_device(
+            tok_dev = self._sample_device(
                 lg_dev,
                 jnp.asarray([p.temperature], jnp.float32),
                 jnp.asarray([p.top_k], jnp.int32),
                 jnp.asarray([p.top_p], jnp.float32),
                 jnp.asarray([s.dev_seed], jnp.int32),
-                jnp.asarray([pos], jnp.int32)))[0])
+                jnp.asarray([pos], jnp.int32))
+            # the one blocking fetch of admission: the host waits here
+            # for every prefill chunk queued ahead of the sampler
+            with self.phases.phase("admission.wait", child=True):
+                tok = int(np.asarray(tok_dev)[0])
             return tok, None
-        return self._sample_host(np.asarray(lg_dev)[0], s)
+        with self.phases.phase("admission.wait", child=True):
+            lg = np.asarray(lg_dev)[0]
+        return self._sample_host(lg, s)
 
     def _sample_host(self, logits: np.ndarray, s: _Slot
                      ) -> Tuple[int, Optional[LogprobEntry]]:
@@ -2768,6 +2795,7 @@ class LLMEngine:
         Oversampled children (best_of > n) buffer until all candidates
         finish, then the n best by mean logprob are re-emitted as choices
         0..n-1."""
+        out.t_push = time.perf_counter()
         link = self._children.get(rid)
         if link is None:
             with self._lock:
@@ -2903,13 +2931,6 @@ class LLMEngine:
         # brownout ladder: one pressure sample per working step (the
         # overload_storm fault overrides the measured signal here)
         self._update_brownout()
-        tq: Dict[str, int] = {}
-        for q in (self.waiting, self._cp_waiting):
-            for r in q:
-                t = getattr(r.params, "tenant", None) or "default"
-                tq[t] = tq.get(t, 0) + 1
-        for t in self.overload.tenants:
-            self._m_tenant_queued.labels(t).set(tq.get(t, 0))
         # hbm gauges: the ledger throttles its own device poll
         # ($BIGDL_TPU_MEMORY_POLL_SEC), so per-step publish is cheap
         self.ledger.publish(self.registry)
@@ -3863,6 +3884,8 @@ class LLMEngine:
         # timer brackets the whole step, not just the device call
         t_step0 = time.perf_counter()
         self._pending_perf = None
+        ph = self.phases.phase
+        self.phases.begin()
         try:
             self.faults.raise_point("step", self._step_idx)
             if self.has_unfinished():
@@ -3877,21 +3900,54 @@ class LLMEngine:
         except Exception as e:
             return self._on_step_failure(e)
         self._consec_failures = 0
-        # burn-rate evaluation: throttled to the spec's eval_sec, runs
-        # on idle steps too so alerts recover without traffic
-        self.slo.maybe_evaluate()
-        if self._pending_perf is not None:
-            n_active, seq_len = self._pending_perf
-            self._pending_perf = None
-            self._perf_observe(time.perf_counter() - t_step0,
-                               n_active, seq_len)
-        # periodic teacher-forced NLL probe (off by default: probe
-        # period 0 keeps the pure-decode dispatch count untouched)
-        if did:
-            self._maybe_quality_probe()
+        with ph("observe"):
+            # burn-rate evaluation: throttled to the spec's eval_sec,
+            # runs on idle steps too so alerts recover without traffic
+            with ph("observe.slo", child=True):
+                self.slo.maybe_evaluate()
+            if self._pending_perf is not None:
+                n_active, seq_len = self._pending_perf
+                self._pending_perf = None
+                with ph("observe.perf", child=True):
+                    self._perf_observe(time.perf_counter() - t_step0,
+                                       n_active, seq_len)
+            # periodic teacher-forced NLL probe (off by default: probe
+            # period 0 keeps the pure-decode dispatch count untouched)
+            if did:
+                with ph("observe.probe", child=True):
+                    self._maybe_quality_probe()
+        self.phases.end(worked=did)
         return did
 
     def _step_inner(self) -> bool:
+        """The phases of one step, contiguous and in this order: sweep,
+        admission, then (with an active slot) dispatch, device, sample,
+        emit, observe. Each is a span and a share of one
+        bigdl_tpu_step_phase_seconds sample (self.phases)."""
+        ph = self.phases.phase
+        with ph("sweep"):
+            mig_did, cp_did = self._sweep_step()
+
+        # admission: at most ONE prefill chunk per step — a long prompt
+        # admits across several steps while decodes keep flowing
+        with ph("admission"):
+            self._admission_step()
+
+        active = [i for i, s in enumerate(self.slots) if s.active]
+        if not active:
+            did = cp_did or mig_did or self._admitting is not None
+            with ph("observe"):
+                self._observe_step(
+                    ("admit" if self._admitting is not None else "cp")
+                    if did else None, 0)
+            return did
+        return self._decode_step(active)
+
+    def _sweep_step(self) -> Tuple[bool, bool]:
+        """What runs ahead of admission on every step: aborts, the
+        migration step, deadlines, drain, the stall guard and the
+        context-parallel lane. Returns whether the migration step and
+        the CP lane did work."""
         # aborts
         for i, s in enumerate(self.slots):
             if s.active and s.req.request_id in self._abort:
@@ -3961,33 +4017,26 @@ class LLMEngine:
         cp_did = False
         if self._cp_mesh is not None:
             cp_did = self._cp_step()
+        return mig_did, cp_did
 
-        # admission: at most ONE prefill chunk per step — a long prompt
-        # admits across several steps while decodes keep flowing
-        self._admission_step()
-
-        active = [i for i, s in enumerate(self.slots) if s.active]
-        if not active:
-            did = cp_did or mig_did or self._admitting is not None
-            if did:
-                self._m_steps.inc()
-                self._flight_step("admit" if self._admitting is not None
-                                  else "cp", 0)
+    def _observe_step(self, flight_phase: Optional[str],
+                      n_active: int) -> None:
+        """The close of every ``_step_inner`` path, inside the observe
+        phase: count a working step (``flight_phase`` names what it
+        did; None for an idle one), leave its flight breadcrumb,
+        refresh the gauges."""
+        ph = self.phases.phase
+        if flight_phase is not None:
+            self._m_steps.inc()
+            with ph("observe.flight", child=True):
+                self._flight_step(flight_phase, n_active)
+        with ph("observe.gauges", child=True):
             self._update_gauges()
-            return did
 
-        t_decode0 = time.perf_counter()
-        t_wall0 = time.time()
-        tokens = np.zeros((self.cfg_engine.max_batch,), np.int32)
-        for i in active:
-            tokens[i] = self.slots[i].last_token
-        # mean live cache depth for the roofline sample, captured while
-        # every active slot's request is still attached (_check_done
-        # frees finishing slots before the step timing lands)
-        perf_seq_len = max(1, sum(
-            len(self.slots[i].req.prompt_token_ids)
-            + len(self.slots[i].generated)
-            for i in active) // len(active))
+    def _decode_step(self, active: List[int]) -> bool:
+        """One batched decode step for the ``active`` slots."""
+        ce = self.cfg_engine
+        ph = self.phases.phase
 
         def simple(s: _Slot) -> bool:
             # no penalty counts, no logprobs: the device sampler covers
@@ -4011,114 +4060,137 @@ class LLMEngine:
                 poss[i] = s.req.generated_offset + len(s.generated)
             return temps, top_ks, top_ps, seeds, poss
 
-        # resident fast path: when every active slot is device-samplable
-        # and no fault clause is live (poison_rows edits logits on the
-        # host side), forward + health + sampling run as ONE dispatch —
-        # the [B, V] logits never exist outside the executable
-        resident = (decode_resident_enabled()
-                    and not self._paged
-                    and not self.faults.enabled
-                    and all(simple(self.slots[i]) for i in active))
         toks = None
         finite_host = None
-        logits_dev = None
+        toks_dev = finite_dev = qrows_dev = logits_dev = None
         qrows = None        # [B, 3] chosen_lp/entropy/top1_margin (f32)
-        if resident:
-            temps, top_ks, top_ps, seeds, poss = gather_params(active)
-            all_greedy = all(
-                self.slots[i].req.params.temperature <= 0.0
-                for i in active)
-            toks_dev, finite_dev, self.cache, qrows_dev = \
-                self._decode_resident(
+        t_decode0 = time.perf_counter()
+        t_wall0 = time.time()
+        # dispatch vs device split: the time to the decode call's
+        # return is pure host work (trace + transfer enqueue); the
+        # blocked wait on the step result is device compute
+        with ph("dispatch"):
+            tokens = np.zeros((self.cfg_engine.max_batch,), np.int32)
+            for i in active:
+                tokens[i] = self.slots[i].last_token
+            # mean live cache depth for the roofline sample, captured
+            # while every active slot's request is still attached
+            # (_check_done frees finishing slots before the step timing
+            # lands)
+            perf_seq_len = max(1, sum(
+                len(self.slots[i].req.prompt_token_ids)
+                + len(self.slots[i].generated)
+                for i in active) // len(active))
+
+            # resident fast path: when every active slot is
+            # device-samplable and no fault clause is live (poison_rows
+            # edits logits on the host side), forward + health +
+            # sampling run as ONE dispatch — the [B, V] logits never
+            # exist outside the executable
+            resident = (decode_resident_enabled()
+                        and not self._paged
+                        and not self.faults.enabled
+                        and all(simple(self.slots[i]) for i in active))
+            if resident:
+                temps, top_ks, top_ps, seeds, poss = gather_params(active)
+                all_greedy = all(
+                    self.slots[i].req.params.temperature <= 0.0
+                    for i in active)
+                toks_dev, finite_dev, self.cache, qrows_dev = \
+                    self._decode_resident(
+                        self.params, jnp.asarray(tokens), self.cache,
+                        jnp.asarray(temps), jnp.asarray(top_ks),
+                        jnp.asarray(top_ps), jnp.asarray(seeds),
+                        jnp.asarray(poss), all_greedy=all_greedy,
+                        with_quality=self._use_quality)
+            elif self._paged:
+                # CoW barrier first (shared write pages get private
+                # copies), then one block-table-driven decode dispatch
+                with ph("cache.cow", child=True):
+                    self._cow_step(active)
+                logits_dev, self.cache = self._decode_paged(
                     self.params, jnp.asarray(tokens), self.cache,
-                    jnp.asarray(temps), jnp.asarray(top_ks),
+                    self._bt())
+            else:
+                logits_dev, self.cache = self._decode(
+                    self.params, jnp.asarray(tokens), self.cache)
+        with ph("device"):
+            jax.block_until_ready(  # graftlint: disable=step-host-sync
+                toks_dev if resident else logits_dev)
+        dispatch_s = self.phases.seconds("dispatch")
+        device_s = self.phases.seconds("device")
+
+        with ph("sample"):
+            if resident:
+                toks = np.asarray(toks_dev)
+                finite_host = np.asarray(finite_dev)
+                if qrows_dev is not None:
+                    qrows = np.asarray(qrows_dev)
+            else:
+                # fault injection: poison selected rows with NaN AFTER
+                # the decode — other rows' values are untouched, so
+                # healthy neighbors stay byte-identical to a fault-free
+                # run (the resident path is gated off whenever fault
+                # clauses exist)
+                bad = self.faults.poison_rows(self._step_idx, active)
+                if bad:
+                    logits_dev = logits_dev.at[jnp.asarray(bad)].set(
+                        jnp.nan)
+                # logit_drift: a finite bias on ONE vocab column of the
+                # drifted rows — argmax changes (silent wrong tokens at
+                # full speed) while the isfinite health check below
+                # stays green; only a golden-canary replay can notice
+                drows, dbias = self.faults.drift_rows(self._step_idx,
+                                                      active)
+                if drows:
+                    logits_dev = logits_dev.at[
+                        jnp.asarray(drows), 0].add(dbias)
+
+            # per-slot logits health check: a NaN/Inf row fails ONE
+            # request (quarantine, structured error) while the rest of
+            # the batch keeps decoding — blast-radius isolation for
+            # numeric blowups
+            if ce.logits_health_check:
+                finite = (finite_host if finite_host is not None
+                          else np.asarray(self._health(logits_dev)))
+                sick = [i for i in active if not bool(finite[i])]
+                if sick:
+                    for i in sick:
+                        self._quarantine_slot(i, "nan_logits")
+                    active = [i for i in active if i not in sick]
+
+            simple_rows = [i for i in active if simple(self.slots[i])]
+            complex_rows = [i for i in active
+                            if not simple(self.slots[i])]
+            if resident or not active:
+                pass      # tokens already sampled inside the fused step
+            elif simple_rows and all(
+                    self.slots[i].req.params.temperature <= 0.0
+                    for i in simple_rows):
+                # all-greedy fast path: one fused argmax, no
+                # sampling-param transfers (the default-traffic hot
+                # path)
+                toks = np.asarray(self._argmax(logits_dev))
+            elif simple_rows:
+                temps, top_ks, top_ps, seeds, poss = gather_params(
+                    simple_rows)
+                # runs for EVERY batch containing a simple slot (not
+                # only all-simple ones): a seeded request must sample
+                # from the same stream whether or not a
+                # penalties/logprobs request happens to share the batch
+                toks = np.asarray(self._sample_device(
+                    logits_dev, jnp.asarray(temps), jnp.asarray(top_ks),
                     jnp.asarray(top_ps), jnp.asarray(seeds),
-                    jnp.asarray(poss), all_greedy=all_greedy,
-                    with_quality=self._use_quality)
-            # dispatch vs device split: dispatch-return time is pure
-            # host work (trace + transfer enqueue); the blocked wait on
-            # the step result is device compute
-            t_dispatch = time.perf_counter()
-            jax.block_until_ready(toks_dev)  # graftlint: disable=step-host-sync
-            toks = np.asarray(toks_dev)
-            finite_host = np.asarray(finite_dev)
-            if qrows_dev is not None:
-                qrows = np.asarray(qrows_dev)
-        elif self._paged:
-            # CoW barrier first (shared write pages get private
-            # copies), then one block-table-driven decode dispatch
-            self._cow_step(active)
-            logits_dev, self.cache = self._decode_paged(
-                self.params, jnp.asarray(tokens), self.cache,
-                self._bt())
-            t_dispatch = time.perf_counter()
-            jax.block_until_ready(logits_dev)  # graftlint: disable=step-host-sync
-        else:
-            logits_dev, self.cache = self._decode(
-                self.params, jnp.asarray(tokens), self.cache)
-            t_dispatch = time.perf_counter()
-            jax.block_until_ready(logits_dev)  # graftlint: disable=step-host-sync
-        dispatch_s = t_dispatch - t_decode0
-        device_s = time.perf_counter() - t_dispatch
-        self._m_step_phase.labels("dispatch").observe(dispatch_s)
-        self._m_step_phase.labels("device").observe(device_s)
-
-        # fault injection: poison selected rows with NaN AFTER the
-        # decode — other rows' values are untouched, so healthy
-        # neighbors stay byte-identical to a fault-free run (the
-        # resident path is gated off whenever fault clauses exist)
-        if not resident:
-            bad = self.faults.poison_rows(self._step_idx, active)
-            if bad:
-                logits_dev = logits_dev.at[jnp.asarray(bad)].set(jnp.nan)
-            # logit_drift: a finite bias on ONE vocab column of the
-            # drifted rows — argmax changes (silent wrong tokens at
-            # full speed) while the isfinite health check below stays
-            # green; only a golden-canary replay can notice
-            drows, dbias = self.faults.drift_rows(self._step_idx, active)
-            if drows:
-                logits_dev = logits_dev.at[
-                    jnp.asarray(drows), 0].add(dbias)
-
-        # per-slot logits health check: a NaN/Inf row fails ONE request
-        # (quarantine, structured error) while the rest of the batch
-        # keeps decoding — blast-radius isolation for numeric blowups
-        if ce.logits_health_check:
-            finite = (finite_host if finite_host is not None
-                      else np.asarray(self._health(logits_dev)))
-            sick = [i for i in active if not bool(finite[i])]
-            if sick:
-                for i in sick:
-                    self._quarantine_slot(i, "nan_logits")
-                active = [i for i in active if i not in sick]
-            if not active:
-                self._m_steps.inc()
-                self._flight_step("decode", 0)
-                self._update_gauges()
-                return True
-
-        simple_rows = [i for i in active if simple(self.slots[i])]
-        complex_rows = [i for i in active if not simple(self.slots[i])]
-        if resident:
-            pass          # tokens already sampled inside the fused step
-        elif simple_rows and all(
-                self.slots[i].req.params.temperature <= 0.0
-                for i in simple_rows):
-            # all-greedy fast path: one fused argmax, no sampling-param
-            # transfers (the default-traffic hot path)
-            toks = np.asarray(self._argmax(logits_dev))
-        elif simple_rows:
-            temps, top_ks, top_ps, seeds, poss = gather_params(
-                simple_rows)
-            # runs for EVERY batch containing a simple slot (not only
-            # all-simple ones): a seeded request must sample from the
-            # same stream whether or not a penalties/logprobs request
-            # happens to share the batch
-            toks = np.asarray(self._sample_device(
-                logits_dev, jnp.asarray(temps), jnp.asarray(top_ks),
-                jnp.asarray(top_ps), jnp.asarray(seeds),
-                jnp.asarray(poss)))
-        logits = np.asarray(logits_dev) if complex_rows else None
+                    jnp.asarray(poss)))
+            logits = np.asarray(logits_dev) if complex_rows else None
+            # the step's device outputs end here, inside a phase: the
+            # release of their buffers is host time with an owner (left
+            # to the frame's exit it fell between two phases)
+            toks_dev = finite_dev = qrows_dev = logits_dev = None
+        if not active:          # every row was sick
+            with ph("observe"):
+                self._observe_step("decode", 0)
+            return True
 
         def pick(i):
             if simple(self.slots[i]):
@@ -4134,68 +4206,72 @@ class LLMEngine:
         # (slot, tok, is_repeat, qos) captured BEFORE _check_done can
         # free the slot — the quality-telemetry feed for this step
         q_meta: List[Tuple[int, int, bool, str]] = []
-        for i in active:
-            s = self.slots[i]
-            tok, lp = pick(i)
-            repeat = bool(s.generated) and s.generated[-1] == tok
-            s.last_token = tok
-            s.generated.append(tok)
-            r = s.req
-            if r is not None:
-                step_qos.append(r.params.qos or "standard")
-                if self._use_quality:
-                    q_meta.append((i, tok, repeat,
-                                   r.params.qos or "standard"))
-            if r is not None and r.trace is not None:
-                sp = self.tracer.get(r.request_id)
-                traced.setdefault(
-                    r.trace[0],
-                    (r.request_id,
-                     sp.trace_span if sp is not None else None))
-            self._emit(s, lp)
-            self._check_done(i)
-        # live quality telemetry: resident steps hand over the fused
-        # [B, 3] block (zero extra dispatches); host-sampled steps
-        # reuse the logits array that the complex rows already pulled.
-        # Simple-row non-resident batches keep their logits on-device
-        # — telemetry never adds a transfer the step didn't make.
-        if q_meta:
-            if qrows is None and logits is not None:
-                qrows = self._host_quality_rows(logits, q_meta)
-            if qrows is not None:
-                self._quality_observe(qrows, q_meta)
-        # one batched step advances EVERY active stream one token, so
-        # step wall time IS each stream's time-per-output-token
-        dt = time.perf_counter() - t_decode0
-        self._m_tpot.observe(dt)
-        # every active stream advanced one token this step, so the
-        # step wall time is each stream's TPOT sample for its QoS class
-        for q in step_qos:
-            self.slo.observe_tpot(q, dt)
-        # EWMA + observed floor feed the queue-wait admission test and
-        # the brownout latency-inflation signal
-        self._tpot_ewma = stats_ewma(self._tpot_ewma or None, dt)
-        if self._tpot_floor is None or self._tpot_ewma < self._tpot_floor:
-            self._tpot_floor = self._tpot_ewma
-        self._dispatch_ewma = stats_ewma(
-            self._dispatch_ewma or None, dispatch_s)
-        # stage the roofline/sentinel sample for step() to finalize
-        # with the FULL step wall time (fault sleeps happen before this
-        # method's timing bracket)
-        if active:
+        with ph("emit"):
+            for i in active:
+                s = self.slots[i]
+                tok, lp = pick(i)
+                repeat = bool(s.generated) and s.generated[-1] == tok
+                s.last_token = tok
+                s.generated.append(tok)
+                r = s.req
+                if r is not None:
+                    step_qos.append(r.params.qos or "standard")
+                    if self._use_quality:
+                        q_meta.append((i, tok, repeat,
+                                       r.params.qos or "standard"))
+                if r is not None and r.trace is not None:
+                    sp = self.tracer.get(r.request_id)
+                    traced.setdefault(
+                        r.trace[0],
+                        (r.request_id,
+                         sp.trace_span if sp is not None else None))
+                self._emit(s, lp)
+                self._check_done(i)
+        with ph("observe"):
+            # live quality telemetry: resident steps hand over the
+            # fused [B, 3] block (zero extra dispatches); host-sampled
+            # steps reuse the logits array that the complex rows
+            # already pulled. Simple-row non-resident batches keep
+            # their logits on-device — telemetry never adds a transfer
+            # the step didn't make.
+            if q_meta:
+                with ph("observe.quality", child=True):
+                    if qrows is None and logits is not None:
+                        qrows = self._host_quality_rows(logits, q_meta)
+                    if qrows is not None:
+                        self._quality_observe(qrows, q_meta)
+            with ph("observe.slo", child=True):
+                # one batched step advances EVERY active stream one
+                # token, so step wall time IS each stream's
+                # time-per-output-token
+                dt = time.perf_counter() - t_decode0
+                self._m_tpot.observe(dt)
+                # ... and each stream's TPOT sample for its QoS class
+                for q in step_qos:
+                    self.slo.observe_tpot(q, dt)
+                # EWMA + observed floor feed the queue-wait admission
+                # test and the brownout latency-inflation signal
+                self._tpot_ewma = stats_ewma(self._tpot_ewma or None, dt)
+                if (self._tpot_floor is None
+                        or self._tpot_ewma < self._tpot_floor):
+                    self._tpot_floor = self._tpot_ewma
+                self._dispatch_ewma = stats_ewma(
+                    self._dispatch_ewma or None, dispatch_s)
+            # stage the roofline/sentinel sample for step() to finalize
+            # with the FULL step wall time (fault sleeps happen before
+            # this method's timing bracket)
             self._pending_perf = (len(active), perf_seq_len)
-        # one decode_step span per distinct trace among active slots
-        for tid, (rid, parent_sid) in traced.items():
-            self.spans.record(
-                "decode_step", tid,
-                parent_id=parent_sid,
-                t_start=t_wall0, t_end=t_wall0 + dt,
-                step=self._step_idx, request_id=rid,
-                dispatch_ms=round(dispatch_s * 1000.0, 3),
-                device_ms=round(device_s * 1000.0, 3))
-        self._m_steps.inc()
-        self._flight_step("decode", len(active))
-        self._update_gauges()
+            # one decode_step span per distinct trace among active slots
+            with ph("observe.spans", child=True):
+                for tid, (rid, parent_sid) in traced.items():
+                    self.spans.record(
+                        "decode_step", tid,
+                        parent_id=parent_sid,
+                        t_start=t_wall0, t_end=t_wall0 + dt,
+                        step=self._step_idx, request_id=rid,
+                        dispatch_ms=round(dispatch_s * 1000.0, 3),
+                        device_ms=round(device_s * 1000.0, 3))
+            self._observe_step("decode", len(active))
         return True
 
     def _flight_step(self, phase: str, n_active: int) -> None:

@@ -226,12 +226,12 @@ def profiler_status() -> dict:
         return out
 
 
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named region that shows up on the trace timeline (TraceAnnotation)
-    AND works as a no-op grouping label outside a trace."""
-    with jax.profiler.TraceAnnotation(name):
-        yield
+def annotate(name: str) -> "jax.profiler.TraceAnnotation":
+    """Named region (``with annotate("x"):``) that shows up on the trace
+    timeline (TraceAnnotation) AND works as a no-op grouping label
+    outside a trace: with no profiler running, entering it is a flag
+    test. The engine's PhaseClock takes this as its span factory."""
+    return jax.profiler.TraceAnnotation(name)
 
 
 class StepTimer:
