@@ -30,8 +30,7 @@ from jax import lax
 from bigdl_tpu.models.bert import _masked_attention
 from bigdl_tpu.ops.attention import sdp_attention
 from bigdl_tpu.ops.kvcache import KVCache, init_cache as init_kv, \
-    reject_scaled_kv, \
-    read_layer, update_layer
+    reject_scaled_kv, update_layer
 from bigdl_tpu.ops.matmul import linear
 from bigdl_tpu.ops.norms import layer_norm
 
@@ -238,8 +237,7 @@ def decode_step(
         v = linear(x, lp["v_proj"], lp.get("v_proj_bias")).reshape(
             b, sq, h, hd)
         ck, cv = update_layer(ck, cv, li, k, v, pos)
-        kf, vf = read_layer(ck, cv, li)
-        a = sdp_attention(q, kf, vf, pos).reshape(b, sq, h * hd)
+        a = sdp_attention(q, ck, cv, pos, layer=li).reshape(b, sq, h * hd)
         a = linear(a, lp["o_proj"], lp.get("o_proj_bias"))
         x = layer_norm(x + a, lp["ln1"], lp["ln1_bias"], eps)
 

@@ -34,8 +34,7 @@ from jax import lax
 import functools
 
 from bigdl_tpu.ops.attention import sdp_attention, sdp_attention_paged
-from bigdl_tpu.ops.kvcache import (KVCache, init_cache, read_layer,
-                                   read_layer_quantized, update_layer)
+from bigdl_tpu.ops.kvcache import KVCache, init_cache, update_layer
 from bigdl_tpu.ops.paged import (PagedKVCache, init_paged_cache,
                                  paged_update_layer)
 from bigdl_tpu.ops.matmul import linear
@@ -645,13 +644,12 @@ def _attn_block(hidden, lp, cfg: LlamaConfig, cos, sin, slopes,
                                                      keepdims=False))
             attn = sdp_attention_paged(q, kl, vl, block_tables, pos,
                                        **attn_kw)
-        elif cks is not None:
-            kq, vq, ksc, vsc = read_layer_quantized(ck, cv, cks, cvs, clidx)
-            attn = sdp_attention(q, kq, vq, pos, k_scale=ksc, v_scale=vsc,
-                                 **attn_kw)
         else:
-            kf, vf = read_layer(ck, cv, clidx)
-            attn = sdp_attention(q, kf, vf, pos, **attn_kw)
+            # the stack and the layer index, not a slice: decode attention
+            # reads the layer where it lies (raw codes + scale planes for
+            # block-scaled storage, so the dequant fuses into the kernel)
+            attn = sdp_attention(q, ck, cv, pos, k_scale=cks, v_scale=cvs,
+                                 layer=clidx, **attn_kw)
     attn = attn.reshape(b, sq, h * hd)
     if record is not None:
         record("o_proj", attn)

@@ -33,8 +33,7 @@ from jax import lax
 from bigdl_tpu.models import llama as llama_mod
 from bigdl_tpu.models.llama import LlamaConfig
 from bigdl_tpu.ops.attention import sdp_attention
-from bigdl_tpu.ops.kvcache import (KVCache, read_layer,
-                                   read_layer_quantized, update_layer)
+from bigdl_tpu.ops.kvcache import KVCache, update_layer
 from bigdl_tpu.ops.matmul import linear, q_matmul
 from bigdl_tpu.ops.norms import rms_norm
 from bigdl_tpu.ops.quant import QTensor
@@ -92,15 +91,10 @@ def _layer_step(cfg: MixtralConfig, carry, xs):
 
     if cks is not None:   # block-scaled int8/int4 storage (see llama)
         ck, cv, cks, cvs = update_layer(ck, cv, lidx, k, v, pos, cks, cvs)
-        kq, vq, ksc, vsc = read_layer_quantized(ck, cv, cks, cvs, lidx)
-        attn = sdp_attention(q, kq, vq, pos,
-                             sliding_window=cfg.sliding_window,
-                             k_scale=ksc, v_scale=vsc)
     else:
         ck, cv = update_layer(ck, cv, lidx, k, v, pos)
-        kf, vf = read_layer(ck, cv, lidx)
-        attn = sdp_attention(q, kf, vf, pos,
-                             sliding_window=cfg.sliding_window)
+    attn = sdp_attention(q, ck, cv, pos, sliding_window=cfg.sliding_window,
+                         k_scale=cks, v_scale=cvs, layer=lidx)
     x = x + linear(attn.reshape(b, sq, h * hd), lp["o_proj"])
 
     hidden = rms_norm(x, lp["post_attention_layernorm"], cfg.rms_norm_eps)

@@ -33,8 +33,7 @@ from jax import lax
 
 from bigdl_tpu.ops.attention import sdp_attention
 from bigdl_tpu.ops.kvcache import KVCache, init_cache as init_kv, \
-    reject_scaled_kv, \
-    read_layer, update_layer
+    reject_scaled_kv, update_layer
 from bigdl_tpu.ops.matmul import linear
 from bigdl_tpu.ops.norms import layer_norm
 
@@ -199,8 +198,7 @@ def _dec_layer(x, lp, cfg: WhisperConfig, ck, cv, cross_k, cross_v,
     v = linear(hidden, lp["v_proj"], lp.get("v_proj_bias")).reshape(
         b, sq, h, hd)
     ck, cv = update_layer(ck, cv, lidx, k, v, pos)
-    kf, vf = read_layer(ck, cv, lidx)
-    attn = sdp_attention(q, kf, vf, pos).reshape(b, sq, h * hd)
+    attn = sdp_attention(q, ck, cv, pos, layer=lidx).reshape(b, sq, h * hd)
     x = x + linear(attn, lp["o_proj"], lp.get("o_proj_bias"))
 
     hidden = layer_norm(x, lp["ln_cross"], lp["ln_cross_bias"],

@@ -38,8 +38,7 @@ from bigdl_tpu.models import llama as M
 from bigdl_tpu.models.llama import LlamaConfig
 from bigdl_tpu.ops.attention import sdp_attention
 from bigdl_tpu.ops.kvcache import KVCache, init_cache as init_kv, \
-    reject_scaled_kv, \
-    read_layer, update_layer
+    reject_scaled_kv, update_layer
 from bigdl_tpu.ops.matmul import linear
 from bigdl_tpu.ops.norms import layer_norm, rms_norm
 from bigdl_tpu.ops.rope import apply_rope, rope_cos_sin
@@ -142,8 +141,7 @@ def _layer(x, lp, cfg, cos, sin, ck, cv, lidx, pos, hist):
     k = apply_rope(k, cos, sin)
 
     ck, cv = update_layer(ck, cv, lidx, k, v, pos)
-    kf, vf = read_layer(ck, cv, lidx)
-    attn = sdp_attention(q, kf, vf, pos).reshape(b, sq, h * hd)
+    attn = sdp_attention(q, ck, cv, pos, layer=lidx).reshape(b, sq, h * hd)
     x = x + linear(attn, lp["o_proj"])
 
     hidden2 = rms_norm(x, lp["post_attention_layernorm"], eps)

@@ -88,10 +88,13 @@ def _kernel_compiles(kind: str, h: int, hkv: int, hd: int, sq: int,
             return kernel(q_, k_, v_, b_, p_, hd ** -0.5,
                           k_scale=ks, v_scale=vs)
     else:
-        kv = jax.ShapeDtypeStruct((1, skv, hkv, hd), kdt)
+        # the decode kernel takes the [L, B, S, Hkv, hd] stack; prefill
+        # one layer
+        lead = (1, 1) if kind == "decode" else (1,)
+        kv = jax.ShapeDtypeStruct(lead + (skv, hkv, hd), kdt)
         structs = [jax.ShapeDtypeStruct((1, sq, h, hd), jnp.bfloat16),
                    kv, kv, jax.ShapeDtypeStruct((), i32)]
-        sc = jax.ShapeDtypeStruct((1, skv, hkv), f32)
+        sc = jax.ShapeDtypeStruct(lead + (skv, hkv), f32)
 
         def fn(q_, k_, v_, p_, ks=None, vs=None):
             return kernel(q_, k_, v_, p_, hd ** -0.5,
@@ -126,6 +129,8 @@ def sdp_attention(
     backend: Optional[str] = None,   # overrides flags().attention_backend
     k_scale: Optional[jax.Array] = None,   # [B, Skv, Hkv] f32: int8/int4
     v_scale: Optional[jax.Array] = None,   # codes' per-(token, head) scales
+    layer: Optional[jax.Array] = None,     # k/v (and scales) are the whole
+                                           # [L, ...] stack; attend this layer
 ) -> jax.Array:
     """Causal SDP against a (possibly partially-filled) KV cache.
 
@@ -139,9 +144,14 @@ def sdp_attention(
     Block-scaled KV (kv_cache_dtype int8/int4): pass the raw code planes
     as k/v plus their scale planes — the kernels dequantize in-register;
     the XLA fallback upcasts codes * scales before the einsums.
+
+    With `layer`, k/v (and the scale planes) are the cache's whole
+    `[L, B, Skv, ...]` stack: the decode kernel addresses the layer in
+    place (no slab-sized slice inside a layer scan); every other path
+    slices it here.
     """
     b, sq, h, d = q.shape
-    skv, hkv = k.shape[1], k.shape[2]
+    skv, hkv = k.shape[-3], k.shape[-2]
     g = h // hkv
     if scale is None:
         scale = d ** -0.5
@@ -167,19 +177,24 @@ def sdp_attention(
             q, k, v, q_pos, scale, logits_soft_cap, sliding_window,
             alibi_slopes, k_scale)
         on_tpu = target_is_tpu()
-        if supported and be == "pallas":
+        if supported and (be == "pallas" or on_tpu and _kernel_compiles(
+                "decode", h, hkv, d, 1, skv, str(k.dtype))):
             if quant_name:
                 _note_dequant_path(quant_name, "fused")
-            return decode_attention_pallas(q, k, v, q_pos, float(scale),
-                                           interpret=not on_tpu,
-                                           k_scale=k_scale, v_scale=v_scale)
-        if supported and on_tpu and _kernel_compiles(
-                "decode", h, hkv, d, 1, skv, str(k.dtype)):
-            if quant_name:
-                _note_dequant_path(quant_name, "fused")
-            return decode_attention_pallas(q, k, v, q_pos, float(scale),
-                                           k_scale=k_scale, v_scale=v_scale)
-
+            if layer is None:        # one layer held: a stack of one
+                k, v = k[None], v[None]
+                if k_scale is not None:
+                    k_scale, v_scale = k_scale[None], v_scale[None]
+            return decode_attention_pallas(
+                q, k, v, q_pos, float(scale), interpret=not on_tpu,
+                k_scale=k_scale, v_scale=v_scale,
+                layer=0 if layer is None else layer)
+    if layer is not None:       # every other path works on the one layer
+        k, v, k_scale, v_scale = (
+            x if x is None else jax.lax.dynamic_index_in_dim(
+                x, layer, 0, keepdims=False)
+            for x in (k, v, k_scale, v_scale))
+    if be in ("auto", "pallas"):
         from bigdl_tpu.ops.pallas.prefill_attention import (
             prefill_attention_pallas, prefill_attention_supported)
 

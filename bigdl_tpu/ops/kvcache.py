@@ -30,8 +30,12 @@ scale-free dtypes) so the code planes keep the exact cache layout the
 attention kernels already stream.
 
 Layout: [num_layers, batch, max_seq, kv_heads, head_dim] — the whole stack is
-one array per K/V so a `lax.scan` over layers can carry it and update layer
-slices in place (donated buffers alias, so there is no copy in the hot loop).
+one array per K/V so a `lax.scan` over layers can carry it. In place means
+addressed on the stack: `update_layer` writes its rows at `[layer, ...]` and
+decode attention reads block `(layer, b, s_block)` of the stack. Taking a layer
+out by value (`dynamic_index_in_dim`) inside the scan is a copy of that layer's
+whole slab on the chip, donated buffers or not: eight such copies per layer
+were 41-46 % of the serving cells' device time (PERF.md, PR 26).
 """
 
 from __future__ import annotations
@@ -218,8 +222,11 @@ def update_layer(
     """Write k_new/v_new into layer `layer` at sequence offset `pos`.
 
     `pos` may be a vector of per-batch offsets (continuous-batching serving:
-    every slot decodes at its own depth). Returns the updated full-stack
-    arrays; under jit with donated inputs this lowers to in-place updates.
+    every slot decodes at its own depth): one scatter of the B x S_new new
+    rows per plane; a row past the end of the cache is dropped. A scalar
+    `pos` is one `dynamic_update_slice` per plane. Both address the stack
+    itself, so with donated inputs only the new rows move. Returns the
+    updated full-stack arrays.
 
     With scale planes (`cache_ks`/`cache_vs`, int8/int4 storage) the new
     values are quantized on append — one absmax scale per written [D]
@@ -234,29 +241,19 @@ def update_layer(
         k_new = k_new.astype(cache_k.dtype)
         v_new = v_new.astype(cache_v.dtype)
     if getattr(pos, "ndim", 0) == 1:
-        def write(c_b, n_b, p):           # [S,H,D], [S_new,H,D]
-            return jax.lax.dynamic_update_slice(c_b, n_b, (p, 0, 0))
+        # row i of slot b lands at [layer, b, pos[b] + i]
+        b, s_new = k_new.shape[:2]
+        slot = jnp.arange(b, dtype=jnp.int32)[:, None]
+        at = pos[:, None] + jnp.arange(s_new, dtype=jnp.int32)[None, :]
 
-        def write2(c_b, n_b, p):          # [S,H], [S_new,H] scale planes
-            return jax.lax.dynamic_update_slice(c_b, n_b, (p, 0))
+        def put(stack, new):
+            return stack.at[layer, slot, at].set(
+                new, indices_are_sorted=True, unique_indices=True)
 
-        ck_l = jax.lax.dynamic_index_in_dim(cache_k, layer, 0, keepdims=False)
-        cv_l = jax.lax.dynamic_index_in_dim(cache_v, layer, 0, keepdims=False)
-        ck_l = jax.vmap(write)(ck_l, k_new, pos)
-        cv_l = jax.vmap(write)(cv_l, v_new, pos)
-        ck = jax.lax.dynamic_update_index_in_dim(cache_k, ck_l, layer, 0)
-        cv = jax.lax.dynamic_update_index_in_dim(cache_v, cv_l, layer, 0)
+        ck, cv = put(cache_k, k_new), put(cache_v, v_new)
         if not scaled:
             return ck, cv
-        ks_l = jax.lax.dynamic_index_in_dim(cache_ks, layer, 0,
-                                            keepdims=False)
-        vs_l = jax.lax.dynamic_index_in_dim(cache_vs, layer, 0,
-                                            keepdims=False)
-        ks_l = jax.vmap(write2)(ks_l, ks_new, pos)
-        vs_l = jax.vmap(write2)(vs_l, vs_new, pos)
-        return (ck, cv,
-                jax.lax.dynamic_update_index_in_dim(cache_ks, ks_l, layer, 0),
-                jax.lax.dynamic_update_index_in_dim(cache_vs, vs_l, layer, 0))
+        return ck, cv, put(cache_ks, ks_new), put(cache_vs, vs_new)
     idx = (layer, 0, pos, 0, 0)
     ck = jax.lax.dynamic_update_slice(cache_k, k_new[None], idx)
     cv = jax.lax.dynamic_update_slice(cache_v, v_new[None], idx)
@@ -277,8 +274,10 @@ def read_layer(
     cache_vs: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Full-length K/V for one layer, upcast (and dequantized when scale
-    planes are given) from storage dtype — the XLA fallback path. The
-    fused kernels take codes + scales directly via `read_layer_quantized`."""
+    planes are given) from storage dtype, BY VALUE: a copy of the layer's
+    slab. For callers that need the dense layer (GLM's own attention);
+    cached attention goes through `sdp_attention(.., layer=)`, which hands
+    the decode kernel the stack and slices only on the XLA path."""
     k = jax.lax.dynamic_index_in_dim(cache_k, layer, 0, keepdims=False)
     v = jax.lax.dynamic_index_in_dim(cache_v, layer, 0, keepdims=False)
     if cache_ks is not None:
@@ -287,23 +286,6 @@ def read_layer(
         return (dequantize_kv(k, ks, compute_dtype),
                 dequantize_kv(v, vs, compute_dtype))
     return k.astype(compute_dtype), v.astype(compute_dtype)
-
-
-def read_layer_quantized(
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    cache_ks: jax.Array,
-    cache_vs: jax.Array,
-    layer: jax.Array | int,
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """One layer's raw codes + scales (no dequantization) — feed these to
-    `sdp_attention(.., k_scale=, v_scale=)` so the upcast happens inside
-    the fused kernels."""
-    k = jax.lax.dynamic_index_in_dim(cache_k, layer, 0, keepdims=False)
-    v = jax.lax.dynamic_index_in_dim(cache_v, layer, 0, keepdims=False)
-    ks = jax.lax.dynamic_index_in_dim(cache_ks, layer, 0, keepdims=False)
-    vs = jax.lax.dynamic_index_in_dim(cache_vs, layer, 0, keepdims=False)
-    return k, v, ks, vs
 
 
 def _logical_nbytes(a: jax.Array) -> int:

@@ -7,15 +7,28 @@ models/utils.py:315-355).
 
 Decode attention is memory-bound: the whole KV cache is read to produce one
 token. The XLA fallback computes scores/softmax/values as separate fusions
-with an [B,H,1,S] intermediate round-trip; this kernel walks each (batch,
-kv-head) pair once — K and V stream HBM->VMEM exactly one time, the
-scores/softmax/combine never leave VMEM, and FP8 caches upcast in-register
-(the reference needs dedicated fp8 GEMM kernels for the same effect).
+with an [B,H,1,S] intermediate round-trip; this kernel streams K and V
+HBM->VMEM exactly one time, the scores/softmax/combine never leave VMEM,
+and FP8 caches upcast in-register (the reference needs dedicated fp8 GEMM
+kernels for the same effect).
 
-Shapes: q [B, 1, H, hd]; cache k/v [B, S, Hkv, hd] (bf16 or float8_e5m2);
-pos int32 scalar or per-slot [B] (continuous batching). GQA queries ride
-the sublane axis: each grid step computes the whole G = H/Hkv query group
-against its kv head with one [G, hd] x [hd, S] MXU pass.
+Shapes: q [B, 1, H, hd]; cache k/v the whole STACK [L, B, S, Hkv, hd]
+(bf16, float8_e5m2, or int8/int4 codes with [L, B, S, Hkv] f32 scale
+planes) plus the layer index; pos int32 scalar or per-slot [B] (continuous
+batching). The layer is addressed where it lies: `layer` is a prefetched
+scalar and the BlockSpec index map picks block `(layer, b, s_block, 0, 0)`,
+so no slice of the stack is ever materialized. A caller that holds one
+layer passes `k[None]` and layer 0 (a bitcast).
+
+The block arrives in the layout the cache has, `[sb, Hkv, hd]` — on the
+chip the stack is tiled over its last two dims `(Hkv, hd)`, so a per-head
+`[S, hd]` view does not exist without a relayout copy of the layer
+(measured: `[B, S, Hkv*hd]` reshapes cost as much as the attention
+itself). Instead the block is read as `sb * Hkv` rows of `hd` and ALL H
+query heads meet all rows in one `[H, hd] x [hd, sb*Hkv]` MXU pass; a
+constant additive mask keeps, for query head i, only the rows of its own
+kv head i // G. The MXU does Hkv times the needed work and is still not
+the bound: its time follows the rows streamed, i.e. the bytes read.
 """
 
 from __future__ import annotations
@@ -24,87 +37,52 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-# above this cache length the whole-S tiles exceed VMEM (k+v bf16 at
-# 8k x 128 is 4MB; 16MB/core) — switch to the S-blocked online-softmax
-# sweep (same state machine as the prefill flash kernel, one query row)
-_RESIDENT_MAX = 4096
 _NEG_INF = -1e30
+# rows (sb * Hkv) of one K or V block: 4096 x 128 bf16 is 1 MB a block,
+# four in flight (K, V, double-buffered), scores [H, 4096] f32 in VMEM
+_BLOCK_ROWS = 4096
 
 
-def _kernel_blocked(pos_ref, q_ref, k_ref, v_ref, out_ref,
-                    m_ref, l_ref, acc_ref, *, scale, sb, ns, gp):
-    b = pl.program_id(0)
-    sj = pl.program_id(2)
-    pos = pos_ref[b]
-
-    @pl.when(sj == 0)
-    def _():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.bfloat16)              # [Gp, hd]
-    k = k_ref[0].astype(jnp.bfloat16)                 # [sb, hd]
-    v = v_ref[0].astype(jnp.bfloat16)
-
-    s_ = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale    # [Gp, sb]
-    ids = sj * sb + jax.lax.broadcasted_iota(jnp.int32, (gp, sb), 1)
-    s_ = jnp.where(ids <= pos, s_, _NEG_INF)
-
-    m_prev = m_ref[:, :1]
-    m_cur = jnp.max(s_, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s_ - m_new)
-    l_ref[:] = jnp.broadcast_to(
-        l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
-        l_ref.shape)
-    pv = jax.lax.dot_general(
-        p.astype(jnp.bfloat16), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    acc_ref[:] = acc_ref[:] * corr + pv
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-
-    @pl.when(sj == ns - 1)
-    def _():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        out_ref[0, 0] = (acc_ref[:] / l).astype(out_ref.dtype)
+def _s_block(s: int, hkv: int) -> int:
+    """Largest 128-multiple power-of-two split of S whose block holds at
+    most _BLOCK_ROWS (s, head) rows. 128 positions at least, so above 32
+    kv heads a block is larger: 5120 rows at Hkv 40 (1.3 MB a block,
+    scores [48, 5120] f32; compiles and runs on the v5e, PERF.md PR 26)."""
+    sb = 128
+    while sb * 2 * hkv <= _BLOCK_ROWS and s % (sb * 2) == 0:
+        sb *= 2
+    return sb
 
 
-def _kernel(pos_ref, q_ref, k_ref, v_ref, out_ref, *, scale, s, gp):
-    b = pl.program_id(0)
-    pos = pos_ref[b]
+def _stack_in_place(k) -> bool:
+    """Whether the chip stores the [L, B, S, Hkv, hd] stack tiled over
+    (Hkv, hd), which is what the kernel's blocks read. It does when hd
+    fills the lanes and a position's heads fill at least one 32-bit
+    sublane word (bf16 from 2 heads, 8-bit from 4, int4 from 8); below
+    that it puts S inside the tile (AOT compiles for v5e, every dtype at
+    Hkv 1-16 and hd 64 / 128 / 256: tests/test_aot_tpu.py)."""
+    bits = 4 if k.dtype == jnp.int4 else 8 * k.dtype.itemsize
+    return k.shape[-1] % 128 == 0 and k.shape[-2] * bits >= 32
 
-    q = q_ref[0, 0].astype(jnp.bfloat16)              # [Gp, hd]
-    # K/V arrive as [B, S, Hkv*hd] views blocked (1, S, hd) per kv head —
-    # Mosaic requires the last two BLOCK dims be (8,128)-tileable, which a
-    # [.., S, 1, hd] per-head block is not (the 1 sits second-to-last)
-    k = k_ref[0].astype(jnp.bfloat16)                 # [S, hd]
-    v = v_ref[0].astype(jnp.bfloat16)                 # [S, hd]
 
-    scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale    # [Gp, S]
-    ids = jax.lax.broadcasted_iota(jnp.int32, (gp, s), 1)
-    scores = jnp.where(ids <= pos, scores, -jnp.inf)
-
-    m = jnp.max(scores, axis=-1, keepdims=True)
-    p = jnp.exp(scores - m)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    out = jax.lax.dot_general(
-        p.astype(jnp.bfloat16), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) / l        # [Gp, hd]
-    out_ref[0, 0] = out.astype(out_ref.dtype)
+def _own_head_bias(hp: int, g: int, hkv: int, rows: int) -> np.ndarray:
+    """[Hp, rows] f32 additive mask, a constant of the geometry: query
+    head i attends kv head i // G, so the rows of every other head
+    (row c belongs to head c % Hkv) are pushed out of its softmax.
+    Padded query rows keep some head's rows and are sliced off."""
+    own = (np.arange(hp)[:, None] // g % hkv
+           == np.arange(rows)[None, :] % hkv)
+    return np.where(own, 0.0, _NEG_INF).astype(np.float32)
 
 
 def _head_scales(sc_ref, hi, n, hkv):
-    """Extract one kv head's scale column [n, 1] from a [1, n, Hkv] block.
+    """Extract one kv head's scale column [n, 1] from a [1, n, Hkv] block
+    (the per-head bodies of the paged-decode and prefill kernels).
 
     Scale planes ride full-Hkv in the lane axis (an [.., n, 1] per-head
     block would put 1 in the lanes); the column select is a one-hot
@@ -124,42 +102,42 @@ def _dequant_rows(codes_ref, sc, dt=jnp.bfloat16):
             * sc).astype(dt)
 
 
-def _kernel_scaled(pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, out_ref,
-                   *, scale, s, gp, hkv):
-    """Resident kernel over int8/int4 codes: per-(token, head) scales
-    fold into the K/V ROWS in-register (one [S, 1] broadcast each) before
-    the two dots — codes only ever upcast in-register, the f32 scale
-    planes stream once, and no dequantized copy touches HBM."""
-    b = pl.program_id(0)
-    hi = pl.program_id(1)
-    pos = pos_ref[b]
+def _rows(x_ref, sc_ref):
+    """One [sb, Hkv, hd] block as [sb*Hkv, hd] bf16 rows, (s, head) order.
 
-    q = q_ref[0, 0].astype(jnp.bfloat16)              # [Gp, hd]
-    k = _dequant_rows(k_ref, _head_scales(ks_ref, hi, s, hkv))  # [S, hd]
-    v = _dequant_rows(v_ref, _head_scales(vs_ref, hi, s, hkv))
-
-    scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale    # [Gp, S]
-    ids = jax.lax.broadcasted_iota(jnp.int32, (gp, s), 1)
-    scores = jnp.where(ids <= pos, scores, -jnp.inf)
-
-    m = jnp.max(scores, axis=-1, keepdims=True)
-    p = jnp.exp(scores - m)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    out = jax.lax.dot_general(
-        p.astype(jnp.bfloat16), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) / l        # [Gp, hd]
-    out_ref[0, 0] = out.astype(out_ref.dtype)
+    With a scale block (int8/int4 codes) the rows are dequantized
+    in-register as `_dequant_rows` does. The block is `[Hkv, sb]`,
+    positions in the lanes, which is how the chip stores an
+    [.., S, Hkv] f32 plane of few heads; the (token, head) scale must
+    sit in the SUBLANES next to its row: a transpose, then a one-hot
+    select and a keepdims lane reduction (a rank-2 [sb, Hkv] ->
+    [sb, Hkv, 1] reshape trips Mosaic's layout inference)."""
+    sb, hkv, hd = x_ref.shape
+    x = x_ref[...]
+    if sc_ref is None:
+        x = x.astype(jnp.float32)
+    else:
+        eye = (jax.lax.broadcasted_iota(jnp.int32, (sb, hkv, hkv), 1)
+               == jax.lax.broadcasted_iota(jnp.int32, (sb, hkv, hkv), 2))
+        sc = jnp.sum(jnp.where(eye, sc_ref[...].T[:, None, :], 0.0),
+                     axis=2, keepdims=True)               # [sb, Hkv, 1]
+        x = x.astype(jnp.bfloat16).astype(jnp.float32) * sc
+    # f32 [sb, Hkv, hd] -> [sb*Hkv, hd] keeps every (8, 128) tile whole
+    return x.reshape(sb * hkv, hd).astype(jnp.bfloat16)
 
 
-def _kernel_blocked_scaled(pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                           out_ref, m_ref, l_ref, acc_ref,
-                           *, scale, sb, ns, gp, hkv):
-    b = pl.program_id(0)
-    hi = pl.program_id(1)
-    sj = pl.program_id(2)
-    pos = pos_ref[b]
+def _kernel(layer_ref, pos_ref, q_ref, bias_ref, k_ref, v_ref, *rest,
+            scale, sb, ns, hkv, scaled):
+    """One (slot, S-block) step of the online-softmax sweep (the state
+    machine of the prefill flash kernel, one query row per head)."""
+    if scaled:
+        ks_ref, vs_ref, out_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        out_ref, m_ref, l_ref, acc_ref = rest
+        ks_ref = vs_ref = None
+    del layer_ref                     # consumed by the index maps
+    sj = pl.program_id(1)
+    pos = pos_ref[pl.program_id(0)]
 
     @pl.when(sj == 0)
     def _():
@@ -167,20 +145,22 @@ def _kernel_blocked_scaled(pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.bfloat16)              # [Gp, hd]
-    k = _dequant_rows(k_ref, _head_scales(ks_ref, hi, sb, hkv))  # [sb, hd]
-    v = _dequant_rows(v_ref, _head_scales(vs_ref, hi, sb, hkv))
+    q = q_ref[...].astype(jnp.bfloat16)               # [Hp, hd]
+    k = _rows(k_ref, ks_ref)                          # [sb*Hkv, hd]
+    v = _rows(v_ref, vs_ref)
 
     s_ = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale    # [Gp, sb]
-    ids = sj * sb + jax.lax.broadcasted_iota(jnp.int32, (gp, sb), 1)
-    s_ = jnp.where(ids <= pos, s_, _NEG_INF)
+        preferred_element_type=jnp.float32) * scale + bias_ref[...]
+    # column c is (position sj*sb + c // Hkv, head c % Hkv): live while
+    # its position is <= pos
+    col = jax.lax.broadcasted_iota(jnp.int32, s_.shape, 1)
+    s_ = jnp.where(col < (pos + 1 - sj * sb) * hkv, s_, _NEG_INF)
 
     m_prev = m_ref[:, :1]
-    m_cur = jnp.max(s_, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
+    m_new = jnp.maximum(m_prev, jnp.max(s_, axis=-1, keepdims=True))
     corr = jnp.exp(m_prev - m_new)
+    # a row of another head sits >= 1e30 under the running max: exp -> 0
     p = jnp.exp(s_ - m_new)
     l_ref[:] = jnp.broadcast_to(
         l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
@@ -194,108 +174,89 @@ def _kernel_blocked_scaled(pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
     @pl.when(sj == ns - 1)
     def _():
         l = jnp.maximum(l_ref[:, :1], 1e-30)
-        out_ref[0, 0] = (acc_ref[:] / l).astype(out_ref.dtype)
+        out_ref[...] = (acc_ref[:] / l).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def decode_attention_pallas(
     q: jax.Array,          # [B, 1, H, hd]
-    k: jax.Array,          # [B, S, Hkv, hd] bf16 | float8_e5m2 | int8 | int4
+    k: jax.Array,          # [L, B, S, Hkv, hd] bf16|float8_e5m2|int8|int4
     v: jax.Array,
     q_pos: jax.Array,      # scalar int32 or [B] int32
     scale: float,
     interpret: bool = False,
-    k_scale=None,          # [B, S, Hkv] f32 (int8/int4 codes), else None
+    k_scale=None,          # [L, B, S, Hkv] f32 (int8/int4 codes), else None
     v_scale=None,
+    layer=0,               # int32 scalar: which layer of the stack
 ) -> jax.Array:
-    """Fused decode SDP. Returns [B, 1, H, hd] in q.dtype."""
+    """Fused decode SDP over layer `layer` of the stack. Returns
+    [B, 1, H, hd] in q.dtype."""
     b, sq, h, hd = q.shape
-    s, hkv = k.shape[1], k.shape[2]
+    s, hkv = k.shape[2], k.shape[3]
     if sq != 1:
         raise NotImplementedError("decode kernel handles Sq == 1 only")
     scaled = k_scale is not None
+    if k.shape[0] > 1 and not _stack_in_place(k):
+        # XLA would re-lay ALL layers out for this call, every call: hand
+        # over the one layer, whose relayout is what is left
+        k, v, k_scale, v_scale = (
+            x if x is None else jax.lax.dynamic_slice_in_dim(x, layer, 1)
+            for x in (k, v, k_scale, v_scale))
+        layer = 0
     g = h // hkv
-    gp = max(16, -(-g // 8) * 8)      # pad query group to a clean sublane run
+    hp = -(-h // 16) * 16             # query heads ride the sublanes
+    sb = _s_block(s, hkv)
+    ns = s // sb
 
-    qr = q.reshape(b, hkv, g, hd)
-    if gp != g:
-        qr = jnp.pad(qr, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
-    # flatten heads into the lane axis so the per-head block is
-    # (1, S, hd) — see the kernel comment; the reshape is free on the
-    # contiguous [B, S, Hkv, hd] cache layout
-    k2 = k.reshape(b, s, hkv * hd)
-    v2 = v.reshape(b, s, hkv * hd)
+    qr = q.reshape(b, h, hd)
+    if hp != h:
+        qr = jnp.pad(qr, ((0, 0), (0, hp - h), (0, 0)))
+    bias = jnp.asarray(_own_head_bias(hp, g, hkv, sb * hkv))
 
     pos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    q_spec = pl.BlockSpec((1, 1, gp, hd),
-                          lambda bi, hi, *r: (bi, hi, 0, 0))
-    if s > _RESIDENT_MAX:
-        sb = 512 if s % 512 == 0 else 128
-        ns = s // sb
-        in_specs = [
-            q_spec,
-            pl.BlockSpec((1, sb, hd),
-                         lambda bi, hi, sj, pos_ref: (bi, sj, hi)),
-            pl.BlockSpec((1, sb, hd),
-                         lambda bi, hi, sj, pos_ref: (bi, sj, hi)),
-        ]
-        if scaled:
-            # scale planes ride full-Hkv in the lanes (see _head_scales)
-            sc_spec = pl.BlockSpec((1, sb, hkv),
-                                   lambda bi, hi, sj, pos_ref: (bi, sj, 0))
-            in_specs += [sc_spec, sc_spec]
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, hkv, ns),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, gp, hd), lambda bi, hi, sj, pos_ref: (bi, hi, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((gp, 128), jnp.float32),
-                pltpu.VMEM((gp, 128), jnp.float32),
-                pltpu.VMEM((gp, hd), jnp.float32),
-            ],
-        )
-        kernel = (functools.partial(_kernel_blocked_scaled, scale=scale,
-                                    sb=sb, ns=ns, gp=gp, hkv=hkv)
-                  if scaled else
-                  functools.partial(_kernel_blocked, scale=scale, sb=sb,
-                                    ns=ns, gp=gp))
-    else:
-        in_specs = [
-            q_spec,
-            pl.BlockSpec((1, s, hd), lambda bi, hi, pos_ref: (bi, 0, hi)),
-            pl.BlockSpec((1, s, hd), lambda bi, hi, pos_ref: (bi, 0, hi)),
-        ]
-        if scaled:
-            sc_spec = pl.BlockSpec((1, s, hkv),
-                                   lambda bi, hi, pos_ref: (bi, 0, 0))
-            in_specs += [sc_spec, sc_spec]
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, hkv),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, gp, hd),
-                                   lambda bi, hi, pos_ref: (bi, hi, 0, 0)),
-        )
-        kernel = (functools.partial(_kernel_scaled, scale=scale, s=s,
-                                    gp=gp, hkv=hkv)
-                  if scaled else
-                  functools.partial(_kernel, scale=scale, s=s, gp=gp))
-    operands = (pos, qr, k2, v2)
+    q_spec = pl.BlockSpec((None, hp, hd), lambda bi, sj, *_: (bi, 0, 0))
+    kv_spec = pl.BlockSpec(
+        (None, None, sb, hkv, hd),
+        lambda bi, sj, lyr_ref, pos_ref: (lyr_ref[0], bi, sj, 0, 0))
+    in_specs = [
+        q_spec,
+        pl.BlockSpec((hp, sb * hkv), lambda bi, sj, *_: (0, 0)),
+        kv_spec, kv_spec,
+    ]
+    operands = (lyr, pos, qr, bias, k, v)
     if scaled:
-        operands += (k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32))
+        # [L, B, Hkv, S]: a bitcast of the plane as the chip stores it
+        # (see _rows)
+        sc_spec = pl.BlockSpec(
+            (None, None, hkv, sb),
+            lambda bi, sj, lyr_ref, pos_ref: (lyr_ref[0], bi, 0, sj))
+        in_specs += [sc_spec, sc_spec]
+        operands += tuple(jnp.swapaxes(x.astype(jnp.float32), -1, -2)
+                          for x in (k_scale, v_scale))
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_kernel, scale=scale, sb=sb, ns=ns, hkv=hkv,
+                          scaled=scaled),
         name="decode_attention",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, gp, hd), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, ns),
+            in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((hp, 128), jnp.float32),
+                pltpu.VMEM((hp, 128), jnp.float32),
+                pltpu.VMEM((hp, hd), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, hp, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
 
-    return out[:, :, :g, :].reshape(b, 1, h, hd)
+    return out[:, :h, :].reshape(b, 1, h, hd)
 
 
 def attention_geometry_ok(q, k, logits_soft_cap, sliding_window,
@@ -308,7 +269,7 @@ def attention_geometry_ok(q, k, logits_soft_cap, sliding_window,
     if logits_soft_cap is not None or sliding_window is not None:
         return False
     h, hd = q.shape[2], q.shape[3]
-    s, hkv = k.shape[1], k.shape[2]
+    s, hkv = k.shape[-3], k.shape[-2]     # one layer or the [L, ...] stack
     if h % hkv != 0 or hd % 64 != 0 or s % 128 != 0:
         return False
     if k.dtype in (jnp.bfloat16, jnp.float8_e5m2):

@@ -84,6 +84,10 @@ from bigdl_tpu.serving.overload import (QOS_CLASSES, SHED_REASONS,
 from bigdl_tpu.serving.pagepool import PagePool, RadixCache
 from bigdl_tpu.utils.profiling import annotate
 
+# decode-step EWMA over its floor at which the brownout ladder's
+# latency-inflation signal reads 1.0
+_INFLATION_SATURATES = 3.0
+
 
 class EngineDraining(RuntimeError):
     """Raised by ``add_request`` while the engine drains (SIGTERM /
@@ -648,10 +652,13 @@ class LLMEngine:
                 brownout_high=0.85, brownout_low=0.6,
                 max_queue_depth=ce.max_queue_depth or 256,
                 max_queue_bytes=64 << 20))
-        # decode-step latency EWMA + its observed floor: the queue-wait
-        # admission test and the brownout latency-inflation signal
+        # decode-step latency: the EWMA of every step is the queue-wait
+        # admission test's estimate; the EWMA and its observed floor
+        # over the steps that measure the decode alone are the brownout
+        # latency-inflation signal (_overload_pressure)
         self._tpot_ewma = 0.0
-        self._tpot_floor: Optional[float] = None
+        self._decode_ewma = 0.0
+        self._decode_floor: Optional[float] = None
         # host-dispatch share of the decode step (dispatch-return vs
         # blocked block_until_ready, measured every step): the
         # attribution denominator for
@@ -1557,16 +1564,17 @@ class LLMEngine:
     def _overload_pressure(self) -> float:
         """Measured pressure in [0, 1]: worst of queue-depth ratio,
         memory-ledger headroom exhaustion, and decode-step latency
-        inflation over its observed floor (3x the floor saturates)."""
+        inflation over its observed floor (3x the floor saturates;
+        `_decode_step` says which steps count, and for how much)."""
         p = ((len(self.waiting) + len(self._cp_waiting))
              / max(1, self.overload.cfg.max_queue_depth))
         hr = self.ledger.headroom()
         hb, lim = hr.get("headroom_bytes"), hr.get("bytes_limit")
         if hb is not None and lim:
             p = max(p, 1.0 - hb / lim)
-        if self._tpot_floor and self._tpot_ewma > self._tpot_floor:
-            p = max(p, min(1.0, (self._tpot_ewma / self._tpot_floor
-                                 - 1.0) / 2.0))
+        if self._decode_floor and self._decode_ewma > self._decode_floor:
+            p = max(p, (self._decode_ewma / self._decode_floor - 1.0)
+                    / (_INFLATION_SATURATES - 1.0))
         return min(1.0, max(0.0, p))
 
     def _update_brownout(self) -> None:
@@ -4249,12 +4257,26 @@ class LLMEngine:
                 # ... and each stream's TPOT sample for its QoS class
                 for q in step_qos:
                     self.slo.observe_tpot(q, dt)
-                # EWMA + observed floor feed the queue-wait admission
-                # test and the brownout latency-inflation signal
+                # the queue-wait admission test's estimate: every step
                 self._tpot_ewma = stats_ewma(self._tpot_ewma or None, dt)
-                if (self._tpot_floor is None
-                        or self._tpot_ewma < self._tpot_floor):
-                    self._tpot_floor = self._tpot_ewma
+                # the brownout latency-inflation signal: EWMA over its
+                # observed floor, of the steps that measure the decode
+                # alone. A decode dispatched behind an admission chunk
+                # still in flight measures the chunk too, i.e. how long
+                # the prompt is (a chunk is 3 decodes at 7B, PERF.md PR
+                # 26); a last chunk was waited for before the decode
+                # went out. A sample counts for no more than the ratio
+                # at which the signal saturates: one step of many
+                # floors (an executable's first load) is not inflation,
+                # a run of them still fills the signal
+                if self._admitting is None:
+                    self._decode_ewma = stats_ewma(
+                        self._decode_ewma or None,
+                        min(dt, _INFLATION_SATURATES
+                            * (self._decode_floor or dt)))
+                    if (self._decode_floor is None
+                            or self._decode_ewma < self._decode_floor):
+                        self._decode_floor = self._decode_ewma
                 self._dispatch_ewma = stats_ewma(
                     self._dispatch_ewma or None, dispatch_s)
             # stage the roofline/sentinel sample for step() to finalize

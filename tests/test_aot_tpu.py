@@ -16,6 +16,7 @@ Compiled-memory figures are recorded in PARITY.md.
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -179,8 +180,8 @@ def test_dequant_gemv_compiles_tp4_shards(v5e, aot_flags, k, n):
     (1, 2048, 32, 8, 128, "float8_e5m2"),   # fp8 KV cache
     (8, 1024, 32, 8, 128, "bfloat16"),      # batched serving decode
     (1, 4096, 40, 40, 128, "bfloat16"),     # 13B-class long cache
-    (1, 16384, 32, 8, 128, "bfloat16"),     # 16k: S-blocked flash sweep
-    (1, 32768, 32, 8, 128, "float8_e5m2"),  # 32k fp8 KV, blocked
+    (1, 16384, 32, 8, 128, "bfloat16"),     # 16k: 32 S blocks
+    (1, 32768, 32, 8, 128, "float8_e5m2"),  # 32k fp8 KV
 ])
 def test_decode_attention_compiles(v5e, aot_flags, b, s, h, hkv, hd, kvdt):
     from bigdl_tpu.ops.pallas.decode_attention import decode_attention_pallas
@@ -188,7 +189,7 @@ def test_decode_attention_compiles(v5e, aot_flags, b, s, h, hkv, hd, kvdt):
     dev = v5e.devices[0]
     kdt = jnp.dtype(kvdt)
     q = jax.ShapeDtypeStruct((b, 1, h, hd), jnp.bfloat16)
-    kv = jax.ShapeDtypeStruct((b, s, hkv, hd), kdt)
+    kv = jax.ShapeDtypeStruct((1, b, s, hkv, hd), kdt)    # a stack of one
     pos = jax.ShapeDtypeStruct((), jnp.int32)
     comp = _compile(
         lambda qq, kk, vv, pp: decode_attention_pallas(
@@ -203,27 +204,98 @@ def _scale_planes(shape, dev):
 
 
 @pytest.mark.parametrize("b,s,kvdt", [
-    (1, 2048, "int8"), (8, 2048, "int8"),      # resident body, scaled
+    (1, 2048, "int8"), (8, 2048, "int8"),
     (1, 2048, "int4"), (8, 2048, "int4"),
-    (1, 16384, "int8"), (1, 16384, "int4"),    # S-blocked body, scaled
+    (1, 16384, "int8"), (1, 16384, "int4"),
 ])
 def test_decode_attention_scaled_kv_compiles(v5e, aot_flags, b, s, kvdt):
     """Block-scaled int8/int4 KV at Mistral-7B GQA 32/8: codes plus
-    f32 (token, head) scale planes, dequantized in the kernel — distinct
-    Mosaic programs from the bf16/fp8 bodies above."""
+    f32 (token, head) scale planes, dequantized in the kernel — a
+    distinct Mosaic program from the bf16/fp8 one above."""
     from bigdl_tpu.ops.pallas.decode_attention import decode_attention_pallas
 
     dev = v5e.devices[0]
     h, hkv, hd = 32, 8, 128
     q = jax.ShapeDtypeStruct((b, 1, h, hd), jnp.bfloat16)
-    kv = jax.ShapeDtypeStruct((b, s, hkv, hd), jnp.dtype(kvdt))
+    kv = jax.ShapeDtypeStruct((1, b, s, hkv, hd), jnp.dtype(kvdt))
     pos = jax.ShapeDtypeStruct((b,), jnp.int32)
-    ks, vs = _scale_planes((b, s, hkv), dev)
+    ks, vs = _scale_planes((1, b, s, hkv), dev)
     comp = _compile(
         lambda qq, kk, vv, pp, ks_, vs_: decode_attention_pallas(
             qq, kk, vv, pp, hd ** -0.5, k_scale=ks_, v_scale=vs_),
         _sds(q, dev), _sds(kv, dev), _sds(kv, dev), _sds(pos, dev), ks, vs)
     assert _has_mosaic_call(comp)
+
+
+def _moves_of(txt, shapes):
+    """Instructions of a compiled program that MATERIALIZE one of
+    `shapes` (a copy, a fusion, a reshape, a slice, an update: anything
+    but a view or the plumbing of tuples and loops)."""
+    import re
+
+    views = ("parameter", "get-tuple-element", "bitcast", "tuple", "while",
+             "conditional", "call", "copy-start", "copy-done", "constant")
+    found = []
+    for m in re.finditer(
+            r"^\s*(?:ROOT )?(%[\w.\-]+) = \(?\w+\[([\d,]*)\]\S* ([\w\-]+)\(",
+            txt, re.M):
+        name, dims, op = m.groups()
+        if op not in views and tuple(
+                int(d) for d in dims.split(",") if d) in shapes:
+            found.append(f"{name} = [{dims}] {op}")
+    return found
+
+
+@pytest.mark.parametrize("layers,b,s,hkv,hd,kvdt,in_place", [
+    (32, 32, 2048, 8, 128, "bfloat16", True),   # the Mistral cells' slab
+    (32, 32, 2048, 8, 128, "int8", True),       # the same, scale planes
+    (32, 32, 2048, 8, 128, "int4", True),
+    (32, 32, 2048, 8, 128, "float8_e5m2", True),
+    (4, 4, 8192, 8, 128, "bfloat16", True),     # long cache
+    (4, 4, 8192, 8, 128, "int8", True),
+    (4, 4, 2048, 2, 128, "bfloat16", True),     # ChatGLM2's 2 kv groups
+    (4, 4, 2048, 4, 128, "int8", True),
+    # a position's heads under one 32-bit word, or hd under the lanes:
+    # the chip tiles such a stack with S inside, the wrapper slices
+    (4, 4, 2048, 2, 128, "int8", False),
+    (4, 4, 2048, 8, 64, "bfloat16", False),
+])
+def test_decode_attention_over_stack_in_place(v5e, aot_flags, layers, b, s,
+                                              hkv, hd, kvdt, in_place):
+    """The kernel takes the cache's whole [L, B, S, Hkv, hd] stack and a
+    traced layer index. Where the chip tiles the stack over (Hkv, hd)
+    (`_stack_in_place`) neither the stack, nor a layer of it, nor a
+    scale plane is copied, sliced or reshaped on the way in; elsewhere
+    one layer is, and never the stack."""
+    from bigdl_tpu.ops.pallas.decode_attention import (
+        _stack_in_place, decode_attention_pallas)
+
+    dev = v5e.devices[0]
+    h = 32
+    q = _sds(jax.ShapeDtypeStruct((b, 1, h, hd), jnp.bfloat16), dev)
+    kv = _sds(jax.ShapeDtypeStruct((layers, b, s, hkv, hd),
+                                   jnp.dtype(kvdt)), dev)
+    assert _stack_in_place(kv) == in_place
+    pos = _sds(jax.ShapeDtypeStruct((b,), jnp.int32), dev)
+    lyr = _sds(jax.ShapeDtypeStruct((), jnp.int32), dev)
+    scales = ()
+    if kvdt in ("int8", "int4"):
+        scales = _scale_planes((layers, b, s, hkv), dev)
+    comp = _compile(
+        lambda qq, kk, vv, pp, ll, *sc: decode_attention_pallas(
+            qq, kk, vv, pp, hd ** -0.5, layer=ll,
+            **(dict(k_scale=sc[0], v_scale=sc[1]) if sc else {})),
+        q, kv, kv, pos, lyr, *scales)
+    assert _has_mosaic_call(comp)
+    stack = {(layers, b, s, hkv, hd), (layers, b, s, hkv),
+             (layers, b, hkv, s)}
+    layer = {(b, s, hkv, hd), (1, b, s, hkv, hd), (b, s, hkv * hd),
+             (b, s, hkv), (b, hkv, s), (1, b, s, hkv), (1, b, hkv, s)}
+    txt = comp.as_text()
+    assert not _moves_of(txt, stack)
+    assert bool(_moves_of(txt, layer)) != in_place, _moves_of(txt, layer)
+    if in_place:
+        assert comp.memory_analysis().temp_size_in_bytes < 4 << 20
 
 
 @pytest.mark.parametrize("b,kvdt", [
@@ -460,12 +532,25 @@ def test_llama7b_decode_fp8_cache_compiles(v5e, aot_flags):
     assert _has_mosaic_call(comp)
 
 
-def test_engine_decode_resident_step_compiles(v5e, aot_flags):
+@pytest.mark.parametrize("b", [8, 32])
+def test_engine_decode_resident_step_compiles(v5e, aot_flags, b):
     """One whole serving decode step AS THE ENGINE BUILDS IT
     (engine_decode_resident: layer scan + health + sampling in one
     executable) for a registry-built Mistral-7B at published width,
-    merged + prepacked like a from_pretrained load, batch 8 over a
-    max_seq-2048 slab. Shapes only: the engine never sees an array."""
+    merged + prepacked like a from_pretrained load, over a max_seq-2048
+    slab of 8 slots and of the benchmark cells' 32. Shapes only: the
+    engine never sees an array.
+
+    Structural guard: inside the layer scan the KV stack is addressed
+    where it lies. No instruction materializes a layer's slab (a slice
+    taken out of the scan carry, a reshape of it for the kernel, a copy)
+    and no stack-sized result is fed by a slab-sized update (a layer
+    written back whole). `memory_analysis()` does not see such copies
+    (their buffers are reused), so the guard reads the compiled text; on
+    the chip they were eight ops per layer per step, 41-46 % of the
+    serving cells' device time (PERF.md, PR 26)."""
+    import re
+
     from bigdl_tpu.models import llama as M
     from bigdl_tpu.models.registry import get_family
     from bigdl_tpu.ops.quant import prepack_tree
@@ -483,8 +568,8 @@ def test_engine_decode_resident_step_compiles(v5e, aot_flags):
         config, hf_config, qtype = cfg, MISTRAL_7B_HF, "sym_int4"
 
     Model.family = family
-    b = 8
-    eng = LLMEngine(Model, EngineConfig(max_batch=b, max_seq=2048,
+    s = 2048
+    eng = LLMEngine(Model, EngineConfig(max_batch=b, max_seq=s,
                                         sentinel=False, quality=False))
     i32 = _sds(jax.ShapeDtypeStruct((b,), jnp.int32), dev)
     f32 = _sds(jax.ShapeDtypeStruct((b,), jnp.float32), dev)
@@ -495,12 +580,37 @@ def test_engine_decode_resident_step_compiles(v5e, aot_flags):
         with_quality=False).compile()
     assert _has_mosaic_call(comp), (
         "engine decode step compiled WITHOUT any Mosaic kernel")
+    txt = comp.as_text()
     # GEMV x5 (qkv, o, gate_up, down, lm_head) + decode attention
-    assert comp.as_text().count("tpu_custom_call") >= 6
+    assert txt.count("tpu_custom_call") >= 6
     ma = comp.memory_analysis()
     live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
-    assert 5e9 < live < 8e9      # ~4.3 GB weights + ~2.1 GB KV slab
+    kv_gb = 2 * 32 * b * s * 8 * 128 * 2 / 1e9    # ~2.1 GB at 8 slots
+    assert 4.0 + kv_gb < live / 1e9 < 5.9 + kv_gb   # + ~4.3 GB weights
+
+    layers, hkv, hd = 32, 8, 128
+    slab = b * s * hkv * hd
+    moved = _moves_of(txt, {(b, s, hkv, hd), (1, b, s, hkv, hd),
+                            (b, s, hkv * hd)})
+    assert not moved, f"a layer's slab is materialized: {moved}"
+    # a stack-sized result may only take in the new rows: none of its
+    # operands (beside the stack itself) is as large as a layer
+    sizes = {m.group(1): int(np.prod([int(d) for d in m.group(2).split(",")
+                                      if d] or [1]))
+             for m in re.finditer(
+                 r"(%[\w.\-]+)(?: =|:) \(?\w+\[([\d,]*)\]", txt)}
+    stack = f"[{layers},{b},{s},{hkv},{hd}]"
+    for m in re.finditer(
+            r"^\s*(?:ROOT )?(%[\w.\-]+) = \w+" + re.escape(stack)
+            + r"\S* (fusion|scatter|dynamic-update-slice|copy)\((.*?)\)",
+            txt, re.M):
+        name, op, args = m.groups()
+        fed = [a for a in re.findall(r"%[\w.\-]+", args)
+               if slab <= sizes.get(a, 0) < layers * slab]
+        assert op != "copy" and not fed, (
+            f"{name} ({op}) writes a layer-sized operand {fed} into the "
+            f"stack")
 
 
 def test_vmapped_gemv_compiles(v5e, aot_flags):

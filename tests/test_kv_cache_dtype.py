@@ -175,6 +175,89 @@ def test_append_read_per_slot_positions():
     assert np.abs(kd[1, 76]).max() == 0.0
 
 
+@pytest.mark.parametrize("s_new", [1, 4])
+@pytest.mark.parametrize("name", ["bf16", "fp8_e5m2", "int8", "int4"])
+def test_per_slot_update_equals_per_row_reference(name, s_new):
+    """Per-slot `update_layer` (one scatter on the stack) against a plain
+    per-row loop: middle layer, unequal offsets, every storage dtype; all
+    other rows, layers and scales keep their bits."""
+    layers, b, s, hkv, hd, layer = 3, 3, 64, 2, 64, 1
+    rng = np.random.default_rng(21)
+    cache = kvc.init_cache(layers, b, s, hkv, hd, kv_cache_dtype=name,
+                           per_slot_pos=True)
+    scaled = cache.k_scale is not None
+
+    def noise(a):       # a cache that is NOT zero: untouched must mean equal
+        return jnp.asarray(rng.integers(-3, 4, a.shape), a.dtype)
+
+    planes = [noise(cache.k), noise(cache.v)]
+    if scaled:
+        planes += [noise(cache.k_scale), noise(cache.v_scale)]
+    kn = jnp.asarray(rng.standard_normal((b, s_new, hkv, hd)), jnp.bfloat16)
+    vn = jnp.asarray(rng.standard_normal((b, s_new, hkv, hd)), jnp.bfloat16)
+    pos = jnp.asarray([7, 0, s - s_new], jnp.int32)
+    # op by op, like the reference below: under one jit XLA may turn the
+    # scale's division into a reciprocal multiply (one ulp of a scale)
+    got = kvc.update_layer(planes[0], planes[1], jnp.int32(layer),
+                           kn, vn, pos, *planes[2:])
+    assert len(got) == len(planes)
+
+    if scaled:
+        (kq, ksc), (vq, vsc) = (kvc.quantize_kv(kn, cache.k.dtype),
+                                kvc.quantize_kv(vn, cache.v.dtype))
+        rows = [kq, vq, ksc, vsc]
+    else:
+        rows = [kn.astype(cache.k.dtype), vn.astype(cache.v.dtype)]
+    for plane, new, out in zip(planes, rows, got):
+        want = np.array(plane.astype(jnp.float32))
+        for bi in range(b):
+            for i in range(s_new):
+                want[layer, bi, int(pos[bi]) + i] = np.asarray(
+                    new[bi, i].astype(jnp.float32))
+        assert out.dtype == plane.dtype
+        np.testing.assert_array_equal(
+            np.asarray(out.astype(jnp.float32)), want)
+
+
+@pytest.mark.parametrize("name", ["bf16", "int8"])
+def test_per_slot_update_drops_rows_past_the_end(name):
+    """A slot whose `pos + S_new` runs past the cache: the rows that fit
+    land, the rest are dropped (the scatter's out-of-bounds mode; its
+    indices stay unique and sorted), and no other row of any plane moves.
+    The engine never asks for it (a slot at max_seq is finished first)."""
+    layers, b, s, hkv, hd, layer, s_new = 2, 3, 32, 2, 64, 1, 4
+    rng = np.random.default_rng(22)
+    cache = kvc.init_cache(layers, b, s, hkv, hd, kv_cache_dtype=name,
+                           per_slot_pos=True)
+    planes = [jnp.asarray(rng.integers(-3, 4, a.shape), a.dtype)
+              for a in (cache.k, cache.v, cache.k_scale, cache.v_scale)
+              if a is not None]
+    kn = jnp.asarray(rng.standard_normal((b, s_new, hkv, hd)), jnp.bfloat16)
+    pos = jnp.asarray([5, s - 2, s], jnp.int32)   # fits, half fits, none
+    # op by op, as above (a scale's ulp under one jit)
+    got = kvc.update_layer(planes[0], planes[1], jnp.int32(layer),
+                           kn, kn, pos, *planes[2:])
+    alone = kvc.update_layer(
+        *(p_[:, :1] for p_ in planes[:2]), jnp.int32(layer), kn[:1], kn[:1],
+        pos[:1], *(p_[:, :1] for p_ in planes[2:]))
+    for plane, out, one in zip(planes, got, alone):
+        want = np.array(plane.astype(jnp.float32))
+        out = np.asarray(out.astype(jnp.float32))
+        new = np.asarray(one.astype(jnp.float32))[layer, 0, 5:5 + s_new]
+        want[layer, 0, 5:5 + s_new] = new     # the rows as slot 0 stored them
+        want[layer, 1, s - 2:] = out[layer, 1, s - 2:]
+        np.testing.assert_array_equal(out, want)
+    # slot 1's two rows that fit are rows 0 and 1 of ITS new block
+    k_out = np.asarray(got[0].astype(jnp.float32))
+    if name == "bf16":
+        np.testing.assert_array_equal(
+            k_out[layer, 1, s - 2:], np.asarray(kn[1, :2], np.float32))
+    else:
+        codes, _ = kvc.quantize_kv(kn, cache.k.dtype)
+        np.testing.assert_array_equal(
+            k_out[layer, 1, s - 2:], np.asarray(codes[1, :2], np.float32))
+
+
 # -- fused dequant kernels vs XLA -------------------------------------------
 
 @pytest.mark.parametrize("name", ["int8", "int4"])
@@ -184,8 +267,9 @@ def test_decode_resident_scaled(name, h, hkv, hd):
     kq, ks = kvc.quantize_kv(k, kvc.KV_CACHE_DTYPES[name])
     vq, vs = kvc.quantize_kv(v, kvc.KV_CACHE_DTYPES[name])
     pos = jnp.asarray(97, jnp.int32)
-    got = DA.decode_attention_pallas(q, kq, vq, pos, hd ** -0.5,
-                                     interpret=True, k_scale=ks, v_scale=vs)
+    got = DA.decode_attention_pallas(q, kq[None], vq[None], pos, hd ** -0.5,
+                                     interpret=True, k_scale=ks[None],
+                                     v_scale=vs[None])
     ref = _xla_ref(q, kq, vq, pos, k_scale=ks, v_scale=vs)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(ref, np.float32),
@@ -198,16 +282,16 @@ def test_decode_resident_scaled(name, h, hkv, hd):
 
 @pytest.mark.parametrize("name", ["int8", "int4"])
 def test_decode_blocked_scaled(name, monkeypatch):
-    monkeypatch.setattr(DA, "_RESIDENT_MAX", 256)
+    monkeypatch.setattr(DA, "_BLOCK_ROWS", 256)
     s = 768 if name == "int8" else 896   # distinct shapes: fresh traces
     q, k, v = _mk(2, s, 4, 2, 64, seed=12)
     kq, ks = kvc.quantize_kv(k, kvc.KV_CACHE_DTYPES[name])
     vq, vs = kvc.quantize_kv(v, kvc.KV_CACHE_DTYPES[name])
     for pos_v in (s - 1, 300, 0):
         pos = jnp.asarray(pos_v, jnp.int32)
-        got = DA.decode_attention_pallas(q, kq, vq, pos, 64 ** -0.5,
-                                         interpret=True, k_scale=ks,
-                                         v_scale=vs)
+        got = DA.decode_attention_pallas(q, kq[None], vq[None], pos,
+                                         64 ** -0.5, interpret=True,
+                                         k_scale=ks[None], v_scale=vs[None])
         ref = _xla_ref(q, kq, vq, pos, k_scale=ks, v_scale=vs)
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(ref, np.float32),
@@ -216,13 +300,14 @@ def test_decode_blocked_scaled(name, monkeypatch):
 
 
 def test_decode_blocked_scaled_per_slot(monkeypatch):
-    monkeypatch.setattr(DA, "_RESIDENT_MAX", 256)
+    monkeypatch.setattr(DA, "_BLOCK_ROWS", 256)
     q, k, v = _mk(3, 640, 4, 4, 64, seed=13)
     kq, ks = kvc.quantize_kv(k, jnp.int8)
     vq, vs = kvc.quantize_kv(v, jnp.int8)
     pos = jnp.asarray([5, 300, 639], jnp.int32)
-    got = DA.decode_attention_pallas(q, kq, vq, pos, 64 ** -0.5,
-                                     interpret=True, k_scale=ks, v_scale=vs)
+    got = DA.decode_attention_pallas(q, kq[None], vq[None], pos, 64 ** -0.5,
+                                     interpret=True, k_scale=ks[None],
+                                     v_scale=vs[None])
     ref = _xla_ref(q, kq, vq, pos, k_scale=ks, v_scale=vs)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(ref, np.float32),
@@ -234,8 +319,9 @@ def test_decode_resident_scaled_per_slot():
     kq, ks = kvc.quantize_kv(k, jnp.int8)
     vq, vs = kvc.quantize_kv(v, jnp.int8)
     pos = jnp.asarray([9, 127], jnp.int32)
-    got = DA.decode_attention_pallas(q, kq, vq, pos, 64 ** -0.5,
-                                     interpret=True, k_scale=ks, v_scale=vs)
+    got = DA.decode_attention_pallas(q, kq[None], vq[None], pos, 64 ** -0.5,
+                                     interpret=True, k_scale=ks[None],
+                                     v_scale=vs[None])
     ref = _xla_ref(q, kq, vq, pos, k_scale=ks, v_scale=vs)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(ref, np.float32),

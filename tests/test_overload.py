@@ -441,6 +441,83 @@ def test_token_rate_limit_postpaid(model):
     assert ei.value.retry_after_sec >= 1
 
 
+def test_brownout_latency_signal_skips_steps_behind_a_chunk(model):
+    """The latency-inflation signal is EWMA over floor of the decode
+    steps that measure the decode alone. A decode dispatched behind an
+    admission chunk still in flight is a chunk plus a decode (4.4 decodes
+    at 7B on the chip: a long prompt alone would read as overload) and is
+    no sample; the queue-wait EWMA takes every step."""
+    eng = LLMEngine(model, EngineConfig(max_batch=2, max_seq=128,
+                                        prefill_bucket=8, prefill_chunk=8))
+    eng.add_request("a", [1, 2, 3], SamplingParams(max_tokens=24))
+    while not any(s.active for s in eng.slots):
+        eng.step()
+    eng.add_request("b", list(range(1, 41)), SamplingParams(max_tokens=2))
+    behind = clean = 0
+    for _ in range(12):
+        before = (eng._decode_ewma, eng._decode_floor, eng._tpot_ewma)
+        assert eng.step()
+        after = (eng._decode_ewma, eng._decode_floor, eng._tpot_ewma)
+        assert after[2] != before[2]          # every step decoded
+        if eng._admitting is not None:        # "b" is mid-prompt
+            behind += 1
+            assert after[:2] == before[:2]
+        else:
+            clean += 1
+            assert after[0] != before[0]
+    assert behind >= 3 and clean >= 3
+    assert 0 < eng._decode_floor <= eng._decode_ewma
+    # steps behind a chunk ten times the floor move the queue-wait
+    # estimate and not the signal; the decode itself at 3x its floor
+    # saturates it
+    eng._tpot_ewma = 0.40
+    eng._decode_ewma, eng._decode_floor = 0.041, 0.04
+    assert eng._overload_pressure() < 0.05
+    eng._decode_ewma = 0.08
+    assert eng._overload_pressure() == pytest.approx(0.5)
+    eng._decode_ewma = 0.12
+    assert eng._overload_pressure() == 1.0
+
+
+def test_brownout_latency_signal_survives_one_slow_step(model, monkeypatch):
+    """One decode step hundreds of floors long (an executable loaded
+    from the cache in set-up, a compile) counts for 3 floors, the ratio
+    at which the signal saturates: it moves the pressure by a fifth and
+    not over a threshold; sustained slowness still fills the signal."""
+    import bigdl_tpu.serving.engine as E
+
+    eng = LLMEngine(model, EngineConfig(max_batch=1, max_seq=128))
+    eng.add_request("a", [1, 2, 3], SamplingParams(max_tokens=40))
+    for _ in range(12):
+        eng.step()
+    floor = eng._decode_floor
+    assert floor and eng._overload_pressure() < 0.5
+    real = E.time.perf_counter
+    late = [0.0]
+    monkeypatch.setattr(E.time, "perf_counter", lambda: real() + late[0])
+
+    def slow_step(extra):
+        orig = eng._decode_resident
+
+        def held(*a, **k):              # the clock jumps inside the step
+            late[0] += extra
+            return orig(*a, **k)
+        eng._decode_resident = held
+        try:
+            assert eng.step()
+        finally:
+            eng._decode_resident = orig
+
+    before = eng._decode_ewma
+    slow_step(500 * floor)
+    assert eng._tpot_ewma > 50 * floor        # the estimate saw it whole
+    assert eng._decode_ewma <= 0.8 * before + 0.2 * 3.0 * floor + 1e-9
+    assert eng._overload_pressure() < 0.5
+    for _ in range(20):
+        slow_step(5 * floor)
+    assert eng._overload_pressure() > 0.97
+
+
 def test_doomed_queue_wait_shed(model):
     """A request whose deadline cannot outlast the measured backlog is
     rejected at admission instead of timing out in the queue."""

@@ -32,7 +32,7 @@ def test_matches_xla(h, hkv, hd):
         ref = sdp_attention(q, k, v, pos)
     finally:
         set_flags(attention_backend="auto")
-    got = decode_attention_pallas(q, k, v, pos, hd ** -0.5, interpret=True)
+    got = decode_attention_pallas(q, k[None], v[None], pos, hd ** -0.5, interpret=True)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(ref, np.float32),
         rtol=2e-2, atol=2e-2)
@@ -46,7 +46,7 @@ def test_per_slot_positions():
         ref = sdp_attention(q, k, v, pos)
     finally:
         set_flags(attention_backend="auto")
-    got = decode_attention_pallas(q, k, v, pos, 64 ** -0.5, interpret=True)
+    got = decode_attention_pallas(q, k[None], v[None], pos, 64 ** -0.5, interpret=True)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(ref, np.float32),
         rtol=2e-2, atol=2e-2)
@@ -60,7 +60,7 @@ def test_fp8_kv():
         ref = sdp_attention(q, k, v, pos)
     finally:
         set_flags(attention_backend="auto")
-    got = decode_attention_pallas(q, k, v, pos, 64 ** -0.5, interpret=True)
+    got = decode_attention_pallas(q, k[None], v[None], pos, 64 ** -0.5, interpret=True)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(ref, np.float32),
         rtol=6e-2, atol=6e-2)
@@ -70,11 +70,11 @@ def test_mask_strictness():
     """Keys beyond pos must have exactly zero influence."""
     q, k, v = _mk(1, 128, 2, 2, 64, seed=3)
     pos = jnp.asarray(10, jnp.int32)
-    out1 = decode_attention_pallas(q, k, v, pos, 64 ** -0.5, interpret=True)
+    out1 = decode_attention_pallas(q, k[None], v[None], pos, 64 ** -0.5, interpret=True)
     # poison the tail — result must not move
     k2 = k.at[:, 11:].set(100.0)
     v2 = v.at[:, 11:].set(-100.0)
-    out2 = decode_attention_pallas(q, k2, v2, pos, 64 ** -0.5,
+    out2 = decode_attention_pallas(q, k2[None], v2[None], pos, 64 ** -0.5,
                                    interpret=True)
     np.testing.assert_allclose(np.asarray(out1, np.float32),
                                np.asarray(out2, np.float32), rtol=1e-5)
@@ -98,12 +98,12 @@ def test_supported_gate():
 
 
 def test_blocked_long_cache_matches_xla(monkeypatch):
-    """Caches past the VMEM-resident bound take the S-blocked
-    online-softmax sweep; outputs must match the XLA reference
-    (threshold lowered so interpret mode stays fast)."""
+    """A cache of several S blocks (the online-softmax sweep carries
+    its state across them) must match the XLA reference (block shrunk
+    so interpret mode stays fast)."""
     from bigdl_tpu.ops.pallas import decode_attention as DA
 
-    monkeypatch.setattr(DA, "_RESIDENT_MAX", 256)
+    monkeypatch.setattr(DA, "_BLOCK_ROWS", 256)
     q, k, v = _mk(2, 1024, 4, 2, 64, seed=3)
     for pos_v in (999, 300, 0):
         pos = jnp.asarray(pos_v, jnp.int32)
@@ -112,7 +112,7 @@ def test_blocked_long_cache_matches_xla(monkeypatch):
             ref = sdp_attention(q, k, v, pos)
         finally:
             set_flags(attention_backend="auto")
-        got = DA.decode_attention_pallas(q, k, v, pos, 64 ** -0.5,
+        got = DA.decode_attention_pallas(q, k[None], v[None], pos, 64 ** -0.5,
                                          interpret=True)
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(ref, np.float32),
@@ -122,7 +122,7 @@ def test_blocked_long_cache_matches_xla(monkeypatch):
 def test_blocked_per_slot_positions(monkeypatch):
     from bigdl_tpu.ops.pallas import decode_attention as DA
 
-    monkeypatch.setattr(DA, "_RESIDENT_MAX", 256)
+    monkeypatch.setattr(DA, "_BLOCK_ROWS", 256)
     q, k, v = _mk(3, 512, 4, 4, 64, seed=4)
     pos = jnp.asarray([5, 300, 511], jnp.int32)
     try:
@@ -130,8 +130,135 @@ def test_blocked_per_slot_positions(monkeypatch):
         ref = sdp_attention(q, k, v, pos)
     finally:
         set_flags(attention_backend="auto")
-    got = DA.decode_attention_pallas(q, k, v, pos, 64 ** -0.5,
+    got = DA.decode_attention_pallas(q, k[None], v[None], pos, 64 ** -0.5,
                                      interpret=True)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(ref, np.float32),
         rtol=2e-2, atol=2e-2)
+
+
+# -- the kernel over the cache's [L, B, S, Hkv, hd] stack -------------------
+
+_STACK_DTYPES = {"bf16": jnp.bfloat16, "fp8_e5m2": jnp.float8_e5m2,
+                 "int8": jnp.int8, "int4": jnp.int4}
+
+
+def _mk_stack(name, layers, b, s, h, hkv, hd, seed):
+    """q plus a `layers`-deep stack in storage dtype `name` (codes and
+    scale planes for int8/int4, else scale planes None)."""
+    from bigdl_tpu.ops.kvcache import quantize_kv
+
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.standard_normal((b, 1, h, hd)), jnp.bfloat16)
+    k = jnp.asarray(rng.standard_normal((layers, b, s, hkv, hd)),
+                    jnp.float32)
+    v = jnp.asarray(rng.standard_normal((layers, b, s, hkv, hd)),
+                    jnp.float32)
+    dt = _STACK_DTYPES[name]
+    if name in ("int8", "int4"):
+        (k, ks), (v, vs) = quantize_kv(k, dt), quantize_kv(v, dt)
+        return q, k, v, ks, vs
+    return q, k.astype(dt), v.astype(dt), None, None
+
+
+def _xla_layer(q, k, v, ks, vs, layer, pos):
+    one = [None if x is None else x[layer] for x in (k, v, ks, vs)]
+    return sdp_attention(q, one[0], one[1], pos, backend="xla",
+                         k_scale=one[2], v_scale=one[3])
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("name", sorted(_STACK_DTYPES))
+def test_stack_layer_matches_xla(name, layer):
+    """Layer first / middle / last of a stack, per-slot positions: the
+    kernel addresses the layer in place and equals the XLA path on the
+    slice; the same layer passed alone (`k[i][None]`, layer 0) gives the
+    same bits."""
+    q, k, v, ks, vs = _mk_stack(name, 3, 2, 256, 8, 2, 64, seed=20 + layer)
+    pos = jnp.asarray([200, 31], jnp.int32)
+    got = decode_attention_pallas(q, k, v, pos, 64 ** -0.5, interpret=True,
+                                  k_scale=ks, v_scale=vs, layer=layer)
+    ref = _xla_layer(q, k, v, ks, vs, layer, pos)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(ref, np.float32),
+        rtol=2e-2, atol=2e-2)
+    alone = [None if x is None else x[layer][None] for x in (k, v, ks, vs)]
+    same = decode_attention_pallas(q, alone[0], alone[1], pos, 64 ** -0.5,
+                                   interpret=True, k_scale=alone[2],
+                                   v_scale=alone[3])
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(same, np.float32))
+
+
+@pytest.mark.parametrize("name", sorted(_STACK_DTYPES))
+def test_stack_scalar_pos_traced_layer(name):
+    """Scalar position and a TRACED layer index (what a layer scan
+    hands over), through `sdp_attention(.., layer=)`."""
+    import jax
+
+    q, k, v, ks, vs = _mk_stack(name, 3, 2, 256, 4, 4, 64, seed=30)
+    pos = jnp.asarray(97, jnp.int32)
+    got = jax.jit(lambda li: sdp_attention(
+        q, k, v, pos, backend="pallas", k_scale=ks, v_scale=vs,
+        layer=li))(jnp.int32(1))
+    ref = _xla_layer(q, k, v, ks, vs, 1, pos)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(ref, np.float32),
+        rtol=2e-2, atol=2e-2)
+    # the XLA path given the stack slices the same layer
+    xla = sdp_attention(q, k, v, pos, backend="xla", k_scale=ks,
+                        v_scale=vs, layer=jnp.int32(1))
+    np.testing.assert_array_equal(np.asarray(xla, np.float32),
+                                  np.asarray(ref, np.float32))
+
+
+@pytest.mark.parametrize("name", ["bf16", "int8"])
+def test_stack_long_cache(name):
+    """S over 4096 (17 blocks of 256 at Hkv 2), last layer of two."""
+    q, k, v, ks, vs = _mk_stack(name, 2, 1, 4352, 4, 2, 64, seed=40)
+    pos = jnp.asarray([4200], jnp.int32)
+    got = decode_attention_pallas(q, k, v, pos, 64 ** -0.5, interpret=True,
+                                  k_scale=ks, v_scale=vs, layer=1)
+    ref = _xla_layer(q, k, v, ks, vs, 1, pos)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(ref, np.float32),
+        rtol=2e-2, atol=2e-2)
+
+
+def test_engine_slab_decode_kernel_matches_xla_tokens():
+    """8 greedy steps of the engine's slab decode give the same tokens
+    with the kernel (interpret mode, stack + layer index from the layer
+    scan) as with the XLA attention path."""
+    import dataclasses
+
+    from bigdl_tpu.models import llama as llama_mod
+    from bigdl_tpu.serving import EngineConfig, LLMEngine, SamplingParams
+    from bigdl_tpu.utils.testing import TINY_LLAMA, random_llama_params
+
+    cfg = dataclasses.replace(TINY_LLAMA, hidden_size=128,
+                              num_hidden_layers=3, num_attention_heads=2,
+                              num_key_value_heads=1)      # head_dim 64
+
+    class Model:
+        # a seed whose greedy argmax meets no near-tie in these 24 tokens
+        # (the two paths differ in the last bf16 bit of a score)
+        params = random_llama_params(cfg, qtype="sym_int4", seed=0)
+        config = cfg
+        hf_config = {"eos_token_id": None}
+
+        class family:
+            forward = staticmethod(llama_mod.forward)
+            prefill = staticmethod(llama_mod.forward_last_token)
+            new_cache = staticmethod(llama_mod.new_cache)
+
+    prompts = [list(range(1, 9)), list(range(20, 26)), [7, 7, 7]]
+    toks = {}
+    for be in ("xla", "pallas"):
+        set_flags(attention_backend=be)
+        try:
+            eng = LLMEngine(Model, EngineConfig(max_batch=4, max_seq=128))
+            toks[be] = eng.generate(prompts, SamplingParams(max_tokens=8))
+        finally:
+            set_flags(attention_backend="auto")
+    assert toks["pallas"] == toks["xla"]
+    assert all(len(t) == 8 for t in toks["xla"])

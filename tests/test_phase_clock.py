@@ -434,7 +434,7 @@ def test_attention_and_moe_kernels_are_named():
     k = jnp.ones((2, 128, 2, 128), jnp.bfloat16)
     pos = jnp.array([5, 9], jnp.int32)
     assert _pallas_names(
-        lambda: decode_attention_pallas(q, k, k, pos, 0.088,
+        lambda: decode_attention_pallas(q, k[None], k[None], pos, 0.088,
                                         interpret=True)
     ) == ["decode_attention"]
     arena = jnp.ones((8, 128, 2, 128), jnp.bfloat16)
