@@ -1,5 +1,6 @@
-"""What both runners share: the compile watch, the device block and the
-selection of a line's metrics."""
+"""What both runners share: the compile watch, the device block, the
+selection of a line's metrics, and the plain arithmetic of a comparison
+with a reference."""
 
 from __future__ import annotations
 
@@ -7,7 +8,7 @@ import json
 import shutil
 import threading
 from pathlib import Path
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Sequence
 
 
 class CompileWatch:
@@ -65,6 +66,33 @@ def memory_peak_bytes() -> int:
     return peak
 
 
+def free_device() -> None:
+    """Delete every array the process still holds on its devices,
+    whoever refers to it: what runs next gets the whole device."""
+    import gc
+
+    import jax
+
+    gc.collect()
+    for a in jax.live_arrays():
+        a.delete()
+
+
+def report_compared(compared, checks: Dict[str, bool]) -> None:
+    """The run's last lines on standard error: every number that was
+    compared beside its limit, then every check that failed."""
+    import sys
+
+    for name, value, limit in compared:
+        verdict = ("not read" if value is None
+                   else "ok" if value <= limit else "OVER")
+        print(f"compared {name} = {value} limit {limit}: {verdict}",
+              file=sys.stderr)
+    failed = sorted(k for k, ok in checks.items() if not ok)
+    print(f"checks failed: {failed if failed else 'none'}",
+          file=sys.stderr, flush=True)
+
+
 def note(**fields: Any) -> None:
     """One earlier line of standard output (never the last)."""
     print(json.dumps(fields), flush=True)
@@ -78,6 +106,26 @@ def select_end_to_end(cell, values: Dict[str, Optional[float]]
         if v is not None:
             out[m["name"]] = {"value": float(v), "unit": m["unit"]}
     return out
+
+
+def next_token_loss(logits, token_ids: Sequence[int]) -> float:
+    """Mean cross-entropy of position t's logits against token t+1."""
+    import numpy as np
+
+    lg = np.asarray(logits, np.float64)[:-1]
+    tgt = np.asarray(list(token_ids))[1:]
+    lg = lg - lg.max(axis=-1, keepdims=True)
+    logp = lg - np.log(np.exp(lg).sum(axis=-1, keepdims=True))
+    return float(-logp[np.arange(len(tgt)), tgt].mean())
+
+
+def relative_l2(a, b) -> float:
+    """``|a - b| / |b|`` over all entries, in float64 on the host."""
+    import numpy as np
+
+    a = np.asarray(a, np.float64).reshape(-1)
+    b = np.asarray(b, np.float64).reshape(-1)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
 
 
 def start_trace(trace_dir: Path) -> None:

@@ -125,3 +125,42 @@ def train_flops_per_token(cfg, seq_len: int, frozen_base: bool = True
     fwd = model_flops_per_token(cfg) + attn_flops_per_token(
         cfg, max(1, seq_len // 2))
     return fwd * (2.0 if frozen_base else 3.0)
+
+
+def decode_kv_bytes(cfg, records, kv_cache_dtype: str, a: float, b: float
+                    ) -> float:
+    """Cache bytes the decode steps of ``[a, b)`` had to read: for each
+    token a client received then, the cache of its request at that
+    token's position."""
+    total = 0.0
+    for r in records:
+        got = 0
+        for t, k in r.get("chunks", []):
+            if a <= t < b:
+                for j in range(k):
+                    total += kv_bytes_per_token(
+                        cfg, r["prompt_tokens"] + got + j, kv_cache_dtype)
+            got += k
+    return total
+
+
+def serving_work(config: Dict[str, Any], dims: Dims, records,
+                 kv_cache_dtype: str, trace_ab) -> Dict[str, float]:
+    """``obs["work"]`` of a traced serving run, computed from shapes:
+    what the roofline readers divide by, under the keys their files
+    name. ``trace_ab`` is the traced stretch ``(a, b)`` on the records'
+    clock, or None where nothing was traced."""
+    work = {"linear_weight_bytes": linear_weight_bytes(
+        dims, config["quant"], int(config["quant_block"]))}
+    if trace_ab is not None:
+        work["decode_kv_bytes"] = decode_kv_bytes(
+            dims, records, kv_cache_dtype, *trace_ab)
+    return work
+
+
+def training_work(config: Dict[str, Any], dims: Dims,
+                  traffic: Dict[str, Any], tokens_per_step: int
+                  ) -> Dict[str, float]:
+    """``obs["work"]`` of a traced training run."""
+    return {"train_flops_per_step": tokens_per_step
+            * train_flops_per_token(dims, int(traffic["seq_len"]))}

@@ -23,7 +23,6 @@ and the metric is left out of the line.
 
 from __future__ import annotations
 
-import importlib.util
 from pathlib import Path
 from typing import Any, Callable, Dict, Optional
 
@@ -149,12 +148,7 @@ REDUCERS: Dict[str, Callable[[Dict[str, Any], Dict[str, Any]],
 
 def read_metric(path: Path, obs: Dict[str, Any]) -> Optional[float]:
     if path.suffix == ".py":
-        sp = importlib.util.spec_from_file_location(
-            "layer_metric_" + path.stem.replace(".", "_").replace("-", "_"),
-            path)
-        mod = importlib.util.module_from_spec(sp)
-        sp.loader.exec_module(mod)
-        return mod.read(obs)
+        return spec.import_file(f"layer_metrics.{path.stem}", path).read(obs)
     doc = spec.load_json(path)
     try:
         reducer = REDUCERS[doc["reducer"]]

@@ -10,7 +10,8 @@ reads ``{"t0": <time.monotonic() of the window's start>}`` from its
 standard input, sends the window's requests over HTTP to
 ``/v1/completions`` with ``stream=true``, follows each to its end (a
 bounded drain after the window), repeats the probe request, writes the
-records to ``--out`` and prints ``{"event": "done"}``.
+records (with every request's streamed token ids) to ``--out`` and
+prints ``{"event": "done"}``.
 
 Clock: ``time.monotonic()``, which on Linux is one clock for every
 process of the machine.
@@ -44,7 +45,8 @@ def send_request(port: int, req: Dict[str, Any], deadline: float,
         "due": due, "sent": None, "first": None, "chunks": [],
         "expected": req["max_tokens"], "received": 0, "ok": False,
         "error": None, "prompt_tokens": len(req["prompt"]),
-        "tokens": [] if req.get("keep_tokens") else None,
+        "tokens": [], "greedy": req["temperature"] == 0.0,
+        "request": req.get("index"),
     }
     body = json.dumps({
         "prompt": req["prompt"], "max_tokens": req["max_tokens"],
@@ -94,8 +96,7 @@ def send_request(port: int, req: Dict[str, Any], deadline: float,
                     rec["first"] = now
                 rec["chunks"].append([now, len(ids)])
                 rec["received"] += len(ids)
-                if rec["tokens"] is not None:
-                    rec["tokens"].extend(ids)
+                rec["tokens"].extend(ids)
         if not done:
             rec["error"] = "stream closed without [DONE]"
         elif rec["received"] != rec["expected"]:
@@ -179,7 +180,8 @@ def run_closed(port: int, clients: List[List[Dict[str, Any]]], t0: float,
                 "due": None, "sent": time.monotonic(), "first": None,
                 "chunks": [], "expected": 0, "received": 0, "ok": False,
                 "error": "client ran out of requests inside the window",
-                "prompt_tokens": 0, "client": c, "tokens": None})
+                "prompt_tokens": 0, "client": c, "tokens": [],
+                "greedy": False, "request": None})
 
     threads = [threading.Thread(target=client, args=(c,))
                for c in range(len(clients))]
@@ -203,8 +205,11 @@ def main(argv=None) -> int:
     drain = float(traffic.get("drain_seconds", 30.0))
     plan = traffic_mod.window_plan(traffic, seed, seconds, vocab)
     waves = traffic_mod.warmup_plan(traffic, plan, seed, vocab)
-    probe = dict(traffic_mod.probe_request(plan, seed, vocab),
-                 keep_tokens=True)
+    probe = traffic_mod.probe_request(plan, seed, vocab)
+    # a record names its request by its place in the plan, so that the
+    # runner, which can rebuild the plan, finds the prompt again
+    for i, r in enumerate(traffic_mod.all_requests(plan)):
+        r["index"] = i
 
     t_warm = time.monotonic()
     warm_deadline = t_warm + float(spec.get("warmup_limit_s", 900.0))

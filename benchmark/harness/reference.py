@@ -28,6 +28,10 @@ from __future__ import annotations
 import math
 from typing import Any, Dict, Sequence
 
+# plain arithmetic every reference shares; a new reference imports
+# these (and ``unpack_sym_int4``) from here
+from harness.common import next_token_loss, relative_l2  # noqa: F401
+
 
 def unpack_sym_int4(data, scale, block: int):
     """Packed sym_int4 planes -> float32 ``[K, N]``.
@@ -131,9 +135,13 @@ def _layer(x, lp, arch: Dict[str, Any], quant: Dict[str, Any]):
 
 
 def all_logits(params: Dict[str, Any], arch: Dict[str, Any],
-               quant: Dict[str, Any], token_ids: Sequence[int]):
-    """Float32 logits ``[S, V]`` of every position of ``token_ids`` on
-    the canonical (split-projection) tree ``params``."""
+               quant: Dict[str, Any], token_ids: Sequence[int],
+               first: int = 0):
+    """Float32 logits ``[S - first, V]`` of the positions of
+    ``token_ids`` from ``first`` on, on the canonical (split-projection)
+    tree ``params``. Every position attends to the whole sequence before
+    it; ``first`` only spares the output head on positions nobody
+    compares (a 4,800-token prompt ahead of 100 served tokens)."""
     import jax
     import jax.numpy as jnp
 
@@ -150,7 +158,7 @@ def all_logits(params: Dict[str, Any], arch: Dict[str, Any],
         # is baked into the executable as a constant of its size
         head = jax.jit(lambda x, norm, lm_head: _rms_norm(
             x, norm, arch["norm_eps"]) @ _dense(lm_head, quant))
-        return head(x, params["norm"], params["lm_head"])
+        return head(x[first:], params["norm"], params["lm_head"])
 
 
 def last_logits(params: Dict[str, Any], arch: Dict[str, Any],
@@ -159,31 +167,13 @@ def last_logits(params: Dict[str, Any], arch: Dict[str, Any],
     return all_logits(params, arch, quant, token_ids)[-1]
 
 
-def next_token_loss(logits, token_ids: Sequence[int]) -> float:
-    """Mean cross-entropy of position t's logits against token t+1."""
-    import numpy as np
-
-    lg = np.asarray(logits, np.float64)[:-1]
-    tgt = np.asarray(list(token_ids))[1:]
-    lg = lg - lg.max(axis=-1, keepdims=True)
-    logp = lg - np.log(np.exp(lg).sum(axis=-1, keepdims=True))
-    return float(-logp[np.arange(len(tgt)), tgt].mean())
-
-
 SCALED_KV_FACTOR = 1.9
 
 
-def relative_l2(a, b) -> float:
-    """``|a - b| / |b|`` over all entries, in float64 on the host."""
-    import numpy as np
-
-    a = np.asarray(a, np.float64).reshape(-1)
-    b = np.asarray(b, np.float64).reshape(-1)
-    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
-
-
-def tolerance(layers: int, kv_cache_dtype: str) -> float:
-    """Bound on the program's relative L2 distance from this reference.
+def tolerance(config: Dict[str, Any], kv_cache_dtype: str) -> float:
+    """Bound on the program's relative L2 distance from this reference,
+    for the configuration ``config`` (its ``reference`` block gives the
+    depth) with a cache of ``kv_cache_dtype``.
 
     The program keeps activations in bfloat16 (the configuration's
     compute type) and accumulates each matmul in float32, so every layer
@@ -203,6 +193,54 @@ def tolerance(layers: int, kv_cache_dtype: str) -> float:
     a lower precision than the configuration states breaks it: int4
     instead of int8 KV puts a 2**-4 error on every key and value, and
     bfloat16 accumulation of a 4096-long dot product adds
-    2**-9 * sqrt(4096 / 8) per matmul, each several times the bound."""
+    2**-9 * sqrt(4096 / 8) per matmul, each several times the bound.
+
+    Decode through the cache (PR 27: prefill of 32 tokens into a cache
+    of the cell's kind, then 8 forced tokens one at a time) reads what
+    prefill reads: the chip measured 0.053-0.058 through the bf16 slab
+    of 2048 positions beside a prefill of 0.052-0.062 at 32 layers (20
+    runs), and 0.085-0.096 through int8 pages of 128 behind a block
+    table beside a prefill of 0.082-0.101 at 28 layers (18 runs; my
+    chip runs, PR 27), so one bound holds both. The nearest lower
+    precision of the cache reads 0.48-0.61 (fp8_e5m2 for bf16) and
+    0.87-0.95 (int4 for int8), three seeds each."""
+    layers = int(config["reference"]["layers"])
     bound = 2.4 * 2.0 ** -9 * math.sqrt(9.0 * layers)
     return bound * (1.0 if kv_cache_dtype == "bf16" else SCALED_KV_FACTOR)
+
+
+# limit = factor * tolerance(config, kv); the readings behind each
+# factor are in ``served_gap_limits``'s docstring
+SERVED_GAP_FACTORS = {"prefill_gap_max": 6.0, "decode_gap_max": 6.0,
+                      "decode_gap_mean": 0.5}
+
+
+def served_gap_limits(config: Dict[str, Any], kv_cache_dtype: str
+                      ) -> Dict[str, float]:
+    """Limits on what ``served.compare`` reads: how far a served
+    token's reference logit may lie below the reference's best, in
+    standard deviations of the position's logits.
+
+    The logits of a position have nearly zero mean, so a relative L2
+    error of e between program and reference is an error of about e
+    standard deviations on every logit. A greedy server picks the best
+    of ITS logits; the reference then finds that token below its own
+    best by at most the difference of two such errors (standard
+    deviation e * sqrt(2)), and by nothing at all where its two best lie
+    further apart than that. So the gaps go with ``tolerance``. With e
+    about 0.7 of the tolerance, 6 tolerances are 6 standard deviations
+    of that difference: no rounding reaches it, and a token from a wrong
+    row, page or position, the best of unrelated logits, reads 2 to 5
+    standard deviations of the LOGITS, four times the limit and more.
+    The mean gap (most tokens read 0) goes with e squared.
+
+    Readings (my chip runs, PR 27; 4 requests and 256-800 served tokens
+    a run), as prefill's widest / decode's widest / decode's mean.
+    bf16 slab at 32 layers (tolerance 0.0795, limits 0.477 / 0.477 /
+    0.040): sound, 21 runs, at most 0.097 / 0.249 / 0.0114; fp8_e5m2
+    in its place, 3 seeds, at least 0.81 / 2.43 / 0.64. int8 pages at
+    28 layers (tolerance 0.1414, limits 0.848 / 0.848 / 0.071): sound,
+    19 runs, at most 0.355 / 0.414 / 0.0229; int4 in their place, 3
+    seeds, at least 3.14 / 4.26 / 1.73."""
+    tol = tolerance(config, kv_cache_dtype)
+    return {k: f * tol for k, f in SERVED_GAP_FACTORS.items()}

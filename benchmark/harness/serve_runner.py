@@ -8,6 +8,24 @@ process (``loadgen.py``) over real HTTP. End-to-end numbers are taken
 from the child's records, on the client side; per-layer numbers from the
 server's ``/metrics`` text at the window's edges and from a profiler
 trace of a short stretch inside the window (``--trace 1`` only).
+
+The configuration's ``reference``, ``weights`` and ``costs`` modules
+come from the cell (``cell.modules``); none is imported here by name.
+``correct`` rests on the reference twice, both once the window has
+closed and the peak is read, on weights made anew from the seed after
+the program's state is freed. (1) The program's logits of one seeded
+sequence: the family's prefill into a new cache of the cell's kind,
+type and length, then ``DECODE_POSITIONS`` forced tokens one at a time
+through that cache; a relative L2 against the reference's one full
+forward pass, the prefill's position and the decoded ones apart. It is
+what sees a cache of lower precision or an accumulation in bfloat16.
+(2) The tokens the ENGINE streamed for a sample of the window's own
+requests (``served.py``): its prefill programs, its decode step and
+its cache at the timed batch. It is what sees a wrong row, page,
+position or token of the timed path. The first is a forward pass of
+the check's own and costs no set-up; the engine's own logits cannot be
+had without leaving the resident decode step (a request with
+``logprobs`` falls back to host sampling).
 """
 
 from __future__ import annotations
@@ -21,38 +39,88 @@ import time
 from pathlib import Path
 from typing import Any, Dict, Optional
 
-from harness import common, costs, promtext, reference, stats, weights
+from harness import common, promtext, served, stats
+from harness import traffic as traffic_mod
 
 STEP_SPAN = "engine_step"
 REF_PROMPT_TOKENS = 32
+DECODE_POSITIONS = 8
+BROWNOUT = "bigdl_tpu_brownout_level"
 LOADGEN = Path(__file__).resolve().parent / "loadgen.py"
 
 
-def _reference_check(config, canonical, seed: int) -> Dict[str, Any]:
-    """Reference logits of one seeded prompt, from the canonical tree."""
+def check_ids(seed: int, vocab: int):
+    """The seeded sequence of the logits comparison: a prompt of
+    ``REF_PROMPT_TOKENS`` and ``DECODE_POSITIONS`` forced tokens."""
     import numpy as np
 
-    vocab = int(config["reference"]["vocab"])
-    ids = np.random.default_rng([seed & 0xFFFFFFFF, 13]).integers(
-        1, vocab, REF_PROMPT_TOKENS)
-    quant = {"qtype": config["quant"], "block": config["quant_block"]}
-    ref = np.asarray(reference.last_logits(
-        canonical, config["reference"], quant, [int(x) for x in ids]))
-    return {"ids": ids, "logits": ref}
+    return np.random.default_rng([seed & 0xFFFFFFFF, 13]).integers(
+        1, vocab, REF_PROMPT_TOKENS + DECODE_POSITIONS)
 
 
-def _program_logits(model, ids, kv_cache_dtype: str):
-    """Last-position logits of the program's prefill for ``ids``."""
+def prefill_into_cache(model, eng_cfg: Dict[str, Any], prompt, seed: int):
+    """The program's prefill of ``prompt`` (one row) into a new cache of
+    the kind, type and length the cell's engine holds: a slab of
+    ``max_seq`` positions, or pages of ``kv_page_size`` behind a block
+    table in an order drawn from the seed. Returns the last position's
+    logits and the state ``decode_through_cache`` goes on from."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     family, cfg = model.family, model.config
-    cache = family.new_cache(cfg, 1, 128, kv_cache_dtype)
-    fn = jax.jit(family.prefill, static_argnums=1)
-    lg, _ = fn(model.params, cfg, jnp.asarray(ids, jnp.int32)[None, :],
-               cache)
-    return np.asarray(lg, np.float32).reshape(-1)
+    kv = eng_cfg.get("kv_cache_dtype", "bf16")
+    max_seq = int(eng_cfg["max_seq"])
+    page = int(eng_cfg.get("kv_page_size", 0) or 0)
+    toks = jnp.asarray(prompt, jnp.int32)[None, :]
+    if page:
+        n_pages = max_seq // page
+        cache = family.new_paged_cache(cfg, n_pages + 1, page, 1, kv)
+        table = jnp.asarray(1 + np.random.default_rng(
+            [seed & 0xFFFFFFFF, 19]).permutation(n_pages), jnp.int32)[None]
+        fwd = jax.jit(family.forward_paged, static_argnums=1)
+        step = lambda t, c: fwd(model.params, cfg, t, c, table)  # noqa: E731
+    else:
+        cache = family.new_cache(cfg, 1, max_seq, kv)
+        fwd = jax.jit(family.forward, static_argnums=1)
+        step = lambda t, c: fwd(model.params, cfg, t, c)  # noqa: E731
+    lg, cache = step(toks, cache)
+    return np.asarray(lg[0, -1], np.float32), (step, cache)
+
+
+def decode_through_cache(state, forced):
+    """Logits ``[len(forced), V]`` of ``forced`` fed one token at a
+    time through the cache ``prefill_into_cache`` filled."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    step, cache = state
+    rows = []
+    for t in forced:
+        lg, cache = step(jnp.asarray([[int(t)]], jnp.int32), cache)
+        rows.append(np.asarray(lg[0, -1], np.float32))
+    return np.stack(rows)
+
+
+def logits_errors(reference, canonical, arch, quant, ids, prefill_row,
+                  decode_rows) -> Dict[str, float]:
+    """Relative L2 of the program's logits from the reference's ONE full
+    forward pass over ``ids``: the prefill's position, and the decoded
+    positions together."""
+    import numpy as np
+
+    n_prompt = len(ids) - len(decode_rows)
+    ref = np.asarray(reference.all_logits(
+        canonical, arch, quant, [int(x) for x in ids], first=n_prompt - 1))
+    return {"prefill": common.relative_l2(prefill_row, ref[0]),
+            "decode": common.relative_l2(decode_rows, ref[1:])}
+
+
+def _gauge(registry, name: str) -> float:
+    """One gauge of the program's registry, read without rendering the
+    rest: cheap enough for every second of the window."""
+    return float(sum(child.value for fam in registry.families()
+                     if fam.name == name for _, child in fam.children()))
 
 
 class _ChildLines:
@@ -89,23 +157,6 @@ class _ChildLines:
                 continue
             if obj.get("event") == name:
                 return obj
-
-
-def _decode_kv_bytes(records, dims, kv_dtype: str, a: float, b: float
-                     ) -> float:
-    """Cache bytes the decode steps of ``[a, b)`` had to read: for each
-    token a client received then, the cache of its request at that
-    token's position."""
-    total = 0.0
-    for r in records:
-        got = 0
-        for t, k in r.get("chunks", []):
-            if a <= t < b:
-                for j in range(k):
-                    total += costs.kv_bytes_per_token(
-                        dims, r["prompt_tokens"] + got + j, kv_dtype)
-            got += k
-    return total
 
 
 def _start_loadgen(port: int, traffic, seed: int, seconds: float,
@@ -188,6 +239,41 @@ def _sweep(engine, port: int, traffic, rates, seed: int, seconds: float,
             drained_s=res["drained_s"], late_p99_ms=m["late_p99_ms"])
 
 
+def _reference_checks(cell, model, records, seed: int, seconds: float,
+                      dims) -> Dict[str, Any]:
+    """Both comparisons with the reference, once the window has closed
+    and the peak is read: the program's logits of one seeded sequence,
+    prefill and then decode through a cache of the cell's kind; then
+    the program's state goes and the reference gets the device to
+    itself, on weights made anew from the seed, for that sequence and
+    for what the window served."""
+    t_check = time.monotonic()
+    config, traffic = cell.config, cell.traffic
+    reference, weights = cell.modules["reference"], cell.modules["weights"]
+    kv_dtype = config["engine"].get("kv_cache_dtype", "bf16")
+    ids = check_ids(seed, dims.vocab_size)
+    prefill_row, state = prefill_into_cache(
+        model, config["engine"], ids[:REF_PROMPT_TOKENS], seed)
+    decode_rows = decode_through_cache(state, ids[REF_PROMPT_TOKENS:])
+    common.free_device()
+    quant = {"qtype": config["quant"], "block": config["quant_block"]}
+    canonical = weights.canonical_params(config, seed)
+    rel = logits_errors(reference, canonical, config["reference"], quant,
+                        ids, prefill_row, decode_rows)
+    plan = traffic_mod.all_requests(traffic_mod.window_plan(
+        traffic, seed, seconds, dims.vocab_size))
+    samples = [{"prompt": plan[r["request"]]["prompt"],
+                "tokens": [int(x) for x in r["tokens"]]}
+               for r in served.pick_sample(records, seed)]
+    found = served.compare(reference, canonical, config["reference"],
+                           quant, samples)
+    common.free_device()
+    return {"rel": rel, "tolerance": reference.tolerance(config, kv_dtype),
+            "served": found,
+            "limits": reference.served_gap_limits(config, kv_dtype),
+            "seconds": time.monotonic() - t_check}
+
+
 def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
         t_process: float, out_dir: Path, device: Dict[str, Any],
         peaks: Optional[Dict[str, float]],
@@ -197,6 +283,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
     watch = common.CompileWatch().install()
     marks = {"devices_ready_s": time.monotonic() - t_process}
     config, traffic = cell.config, cell.traffic
+    weights, costs = cell.modules["weights"], cell.modules["costs"]
     eng_cfg = dict(config["engine"])
     kv_dtype = eng_cfg.get("kv_cache_dtype", "bf16")
     dims = costs.Dims.from_config(config)
@@ -205,14 +292,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
     from bigdl_tpu.serving.engine import EngineConfig, LLMEngine
 
     t_build = time.monotonic()
-    ref_box: Dict[str, Any] = {}
-    model, build_stages = weights.build_model(
-        config, seed, merge=True,
-        with_canonical=lambda canonical, cfg: ref_box.update(
-            _reference_check(config, canonical, seed)))
-    prog_logits = _program_logits(model, ref_box["ids"], kv_dtype)
-    rel = reference.relative_l2(prog_logits, ref_box["logits"])
-    tol = reference.tolerance(dims.num_hidden_layers, kv_dtype)
+    model, build_stages = weights.build_model(config, seed, merge=True)
     build_s = time.monotonic() - t_build
 
     overload = eng_cfg.pop("overload", None)
@@ -230,7 +310,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
                 return inner()
 
         engine.step = traced_step
-    marks["model_and_checks_s"] = time.monotonic() - t_process
+    marks["model_and_engine_s"] = time.monotonic() - t_process
     server = OpenAIServer(engine, None)
     httpd = server.serve("127.0.0.1", 0, background=True)
     marks["serving_s"] = time.monotonic() - t_process
@@ -257,6 +337,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
         trace_dir = out_dir / "trace"
         trace_ab = None
         occupancy = []
+        brownout_seen = [_gauge(engine.registry, BROWNOUT)]
         if trace:
             tr_start = min(float(traffic.get("trace_start_s", 6.0)),
                            seconds * 0.4)
@@ -270,6 +351,9 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
             jax.profiler.stop_trace()
             trace_ab = (a, b)
         while time.monotonic() < t0 + seconds:
+            # once a second through the window: a brownout that engaged
+            # and recovered inside it fails the run
+            brownout_seen.append(_gauge(engine.registry, BROWNOUT))
             if trace:
                 v = promtext.total(counters(), "bigdl_tpu_slot_occupancy")
                 if v is not None:
@@ -290,6 +374,8 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
 
     records = res["records"]
     m = stats.serving_metrics(records, res["t0"], seconds)
+    del engine, server, httpd      # the engine's cache may go at once
+    ref = _reference_checks(cell, model, records, seed, seconds, dims)
     wrong_length = sum(1 for r in records
                        if r.get("error") and "asked" in r["error"])
     tracked_compiles = promtext.delta(snap0, snap1,
@@ -300,8 +386,8 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
     retries = promtext.total(snap2, "bigdl_tpu_step_retries_total") or 0.0
     fallbacks = promtext.total(snap2, "bigdl_tpu_kernel_probe_total",
                                {"outcome": "fallback"}) or 0.0
-    brownout = max(promtext.total(s, "bigdl_tpu_brownout_level") or 0.0
-                   for s in (snap0, snap1, snap2))
+    brownout = max(brownout_seen + [
+        promtext.total(s, BROWNOUT) or 0.0 for s in (snap0, snap1, snap2)])
     shed = promtext.total(snap2, "bigdl_tpu_requests_shed_total") or 0.0
     preempted = promtext.delta(snap0, snap2,
                                "bigdl_tpu_preemptions_total") or 0.0
@@ -316,13 +402,19 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
         "no_brownout_no_shed": brownout == 0 and shed == 0,
         "probe_repeats": bool(pb["ok"] and pa["ok"]
                               and pb["tokens"] == pa["tokens"]),
-        "reference_within_tolerance": rel <= tol,
+        "reference_within_tolerance":
+            max(ref["rel"].values()) <= ref["tolerance"],
+        "served_tokens_within_reference_gap":
+            served.within(ref["served"], ref["limits"]),
     }
     values = dict(m)
     values["setup_s"] = setup_s
     common.note(
         info="run", workload=cell.name, seed=seed, seconds=seconds,
-        checks=checks, reference_rel_l2=rel, reference_tolerance=tol,
+        checks=checks, reference_rel_l2=ref["rel"],
+        reference_tolerance=ref["tolerance"],
+        served=dict(ref["served"], limits=ref["limits"]),
+        reference_checks_s=ref["seconds"],
         samples={"ttft": m["n_ttft"], "gaps": m["n_gaps"],
                  "tokens_in_window": m["tokens_in_window"],
                  "highest_ttft_percentile_with_10_beyond":
@@ -334,7 +426,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
         generator_late_ms={"p50": m["late_p50_ms"], "p99": m["late_p99_ms"]},
         warmup={"requests": warm["requests"], "failed": warm["failed"],
                 "seconds": warm["seconds"], "errors": warm["errors"]},
-        setup={"build_and_reference_s": build_s, "setup_s": setup_s,
+        setup={"build_s": build_s, "setup_s": setup_s,
                "marks": marks,
                "build_stages": build_stages,
                "backend_compiles": c_setup["backend_compiles"],
@@ -348,6 +440,11 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
             for k, v in (stats_json.get("compile_table") or {}).items()
             if v.get("compiles")})
 
+    common.report_compared(
+        [(f"reference_rel_l2.{k}", v, ref["tolerance"])
+         for k, v in ref["rel"].items()]
+        + [(k, ref["served"].get(k), v) for k, v in ref["limits"].items()],
+        checks)
     dev = dict(device)
     dev["memory_peak_bytes"] = mem_peak
     result: Dict[str, Any] = {
@@ -361,13 +458,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
         result["metrics"] = common.select_end_to_end(cell, values)
         return result
 
-    work = {
-        "linear_weight_bytes": costs.linear_weight_bytes(
-            dims, config["quant"], int(config["quant_block"])),
-    }
-    if trace_ab is not None:
-        work["decode_kv_bytes"] = _decode_kv_bytes(
-            records, dims, kv_dtype, *trace_ab)
+    work = costs.serving_work(config, dims, records, kv_dtype, trace_ab)
     obs = {
         "counters_start": snap0, "counters_end": snap1, "client": m,
         "memory_peak_bytes": mem_peak or None,

@@ -4,11 +4,19 @@ A cell names a configuration and a traffic mix; a per-layer metric names
 itself. Each resolves to a file of that name under ``benchmark/``. A
 name with no file is an error that says which file is missing, so that a
 later PR adds a cell by adding files and entries only.
+
+A configuration's file names the three modules that know its
+architecture, ``"harness": {"reference": <stem>, "weights": <stem>,
+"costs": <stem>}``: stems of files under ``benchmark/harness/``, each
+defaulting to its role's name. The runners take them from the cell and
+import none by name. ``MODULE_CONTRACT`` below is what each must define;
+``harness/__init__.py`` says what each function is given and returns.
 """
 
 from __future__ import annotations
 
 import copy
+import importlib.util
 import json
 import re
 from pathlib import Path
@@ -19,6 +27,14 @@ BENCH_DIR = "benchmark"
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+# role -> what a module in that role defines; a dotted name is an
+# attribute of a class (``Dims.from_config``)
+MODULE_CONTRACT = {
+    "reference": ("all_logits", "tolerance", "served_gap_limits"),
+    "weights": ("build_model", "canonical_params"),
+    "costs": ("Dims.from_config", "kv_bytes_per_token", "serving_work",
+              "training_work"),
+}
 
 
 class SpecError(Exception):
@@ -86,6 +102,40 @@ class Cell:
             self.traffic = deep_update(self.traffic,
                                        self.traffic.get("tiny", {}))
         self.tiny = tiny
+        self.modules = self._load_modules()
+
+    def _load_modules(self) -> Dict[str, Any]:
+        """The configuration's reference, weights and costs modules,
+        loaded from ``benchmark/harness/<stem>.py`` of this tree and
+        held to ``MODULE_CONTRACT``."""
+        named = self.config.get("harness", {})
+        unknown = sorted(set(named) - set(MODULE_CONTRACT))
+        if unknown:
+            raise SpecError(f"config {self.config_name!r}: \"harness\" "
+                            f"names unknown roles {unknown} (known: "
+                            f"{sorted(MODULE_CONTRACT)})")
+        out = {}
+        for role, needs in MODULE_CONTRACT.items():
+            stem = named.get(role, role)
+            if not valid_name(stem):
+                raise SpecError(f"config {self.config_name!r}: harness "
+                                f"{role} module {stem!r} is no valid name")
+            path = self.root / BENCH_DIR / "harness" / f"{stem}.py"
+            if not path.exists():
+                raise SpecError(f"missing file: {path}: config "
+                                f"{self.config_name!r} names it as its "
+                                f"{role} module")
+            mod = import_file(f"harness.{stem}", path)
+            for dotted in needs:
+                obj = mod
+                for part in dotted.split("."):
+                    obj = getattr(obj, part, None)
+                if not callable(obj):
+                    raise SpecError(
+                        f"{path}: the {role} module of config "
+                        f"{self.config_name!r} lacks {dotted}()")
+            out[role] = mod
+        return out
 
     def end_to_end(self) -> List[Dict[str, Any]]:
         return [m for m in self.bench["end_to_end"]
@@ -106,6 +156,28 @@ class Cell:
     def trace_groups(self) -> Dict[str, Dict[str, Any]]:
         base = self.root / BENCH_DIR / "trace_groups"
         return {p.stem: load_json(p) for p in sorted(base.glob("*.json"))}
+
+
+def import_file(name: str, path: Path):
+    """Import ``path`` as module ``name``, once. A file of another tree
+    than this module's own (the tests point ``root`` at copies) goes
+    under a name of its own and never replaces this tree's module."""
+    import sys
+
+    if path.resolve().parent != Path(__file__).resolve().parent:
+        name = f"{name}@{path.resolve().parent}"
+    have = sys.modules.get(name)
+    if have is not None:
+        return have
+    sp = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(sp)
+    sys.modules[name] = mod
+    try:
+        sp.loader.exec_module(mod)
+    except BaseException:
+        del sys.modules[name]
+        raise
+    return mod
 
 
 def peaks_for(device_kind: str, root: Path = ROOT) -> Dict[str, float]:

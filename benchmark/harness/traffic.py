@@ -192,7 +192,7 @@ def window_plan(traffic: Dict[str, Any], seed: int, seconds: float,
     raise ValueError(f"traffic kind {kind!r} has no request plan")
 
 
-def _all_requests(plan: Dict[str, Any]) -> List[Dict[str, Any]]:
+def all_requests(plan: Dict[str, Any]) -> List[Dict[str, Any]]:
     return (plan["requests"] if plan["kind"] == "open"
             else [r for c in plan["clients"] for r in c])
 
@@ -214,7 +214,7 @@ def warmup_plan(traffic: Dict[str, Any], plan: Dict[str, Any], seed: int,
     3. one request of each sampling variant alone, then all variants
        together, so that every decode and sampler variant is compiled.
     """
-    all_reqs = _all_requests(plan)
+    all_reqs = all_requests(plan)
     docs = plan.get("documents")
     waves: List[List[Dict[str, Any]]] = []
     rng = _rng(seed, 9)
@@ -257,7 +257,7 @@ def probe_request(plan: Dict[str, Any], seed: int, vocab: int
                   ) -> Dict[str, Any]:
     """One fixed greedy request, sent in warm-up and again after the
     window: its tokens must be byte-identical."""
-    ref = min(_all_requests(plan), key=lambda r: r["prompt_len"])
+    ref = min(all_requests(plan), key=lambda r: r["prompt_len"])
     docs = plan.get("documents")
     private = _rng(seed, 11).integers(1, vocab, ref["prompt_len"]) \
         .astype(np.int64)

@@ -6,6 +6,9 @@ The window opens after the warm-up steps and closes at the first step
 boundary at or after ``--seconds``; the rate is every token of every
 step that finished in it over its whole length (a window cut in the
 middle of a step of seconds would count 15 or 16 steps by chance).
+
+The configuration's ``reference``, ``weights`` and ``costs`` modules
+come from the cell (``cell.modules``); none is imported here by name.
 """
 
 from __future__ import annotations
@@ -15,11 +18,12 @@ import time
 from pathlib import Path
 from typing import Any, Dict, List, Optional
 
-from harness import common, costs, reference, traffic as traffic_mod, weights
+from harness import common, traffic as traffic_mod
 
 STEP_SPAN = "train_step"
 WARMUP_STEPS = 2
 SAMPLE_TOKENS = 128
+SAMPLE_LOSS_LIMIT = 0.01        # of the reference's loss
 
 
 def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
@@ -37,6 +41,8 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
 
     watch = common.CompileWatch().install()
     config, traffic = cell.config, cell.traffic
+    reference, weights, costs = (cell.modules[k] for k in (
+        "reference", "weights", "costs"))
     tcfg = config["train"]
     dims = costs.Dims.from_config(config)
     batch_np = traffic_mod.train_batch(traffic, seed, dims.vocab_size)
@@ -57,10 +63,10 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
     fwd = jax.jit(model.family.forward_train, static_argnums=1)
     prog = np.asarray(fwd(params, cfg, jnp.asarray([sample], jnp.int32)),
                       np.float32)[0]
-    rel = reference.relative_l2(prog, ref_box["logits"])
-    tol = reference.tolerance(dims.num_hidden_layers, "bf16")
-    ref_loss = reference.next_token_loss(ref_box["logits"], sample)
-    prog_loss = reference.next_token_loss(prog, sample)
+    rel = common.relative_l2(prog, ref_box["logits"])
+    tol = reference.tolerance(config, "bf16")
+    ref_loss = common.next_token_loss(ref_box["logits"], sample)
+    prog_loss = common.next_token_loss(prog, sample)
     del prog, ref_box, fwd
 
     train, frozen = partition(params, lora_trainable_mask(params))
@@ -144,7 +150,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
         "no_compile_in_window": jax_compiles == 0,
         "reference_within_tolerance": rel <= tol,
         "sample_loss_matches_reference":
-            abs(prog_loss - ref_loss) <= 0.01 * abs(ref_loss),
+            abs(prog_loss - ref_loss) <= SAMPLE_LOSS_LIMIT * abs(ref_loss),
     }
     values = {"train_tokens_per_s": steps * tokens_per_step / window_s,
               "setup_s": setup_s}
@@ -162,6 +168,10 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
                "backend_compile_s": c_setup["backend_seconds"]},
         window_compiles={"jax": jax_compiles})
 
+    common.report_compared(
+        [("reference_rel_l2", rel, tol),
+         ("sample_loss_gap", abs(prog_loss - ref_loss),
+          SAMPLE_LOSS_LIMIT * abs(ref_loss))], checks)
     dev = dict(device)
     dev["memory_peak_bytes"] = mem_peak
     result: Dict[str, Any] = {
@@ -175,9 +185,8 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
            "memory_peak_bytes": mem_peak or None,
            "device_kind": device["kind"] if not tiny else None,
            "peaks": peaks,
-           "work": {"train_flops_per_step": tokens_per_step
-                    * costs.train_flops_per_token(
-                        dims, int(traffic["seq_len"]))}}
+           "work": costs.training_work(config, dims, traffic,
+                                       tokens_per_step)}
     common.traced_metrics(cell, result, obs, trace_dir if traced else None,
                           STEP_SPAN, tiny, out_dir)
     return result

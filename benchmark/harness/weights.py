@@ -71,6 +71,23 @@ def build_params(cfg, qtype: str, seed: int, compute_dtype=None
     return jax.jit(build)(key)
 
 
+def _family_config(config: Dict[str, Any]):
+    from bigdl_tpu.models.registry import get_family
+
+    hf = config["hf_config"]
+    family = get_family(hf["architectures"][0], hf)
+    return family, family.config_from_hf(hf), hf
+
+
+def canonical_params(config: Dict[str, Any], seed: int) -> Dict[str, Any]:
+    """The canonical (split-projection) tree of ``seed`` alone, as the
+    reference reads it: what ``build_model`` hands ``with_canonical``.
+    The serving runner calls it once the window has closed and the
+    program's state is freed."""
+    _, cfg, _ = _family_config(config)
+    return build_params(cfg, config["quant"], seed)
+
+
 def build_model(config: Dict[str, Any], seed: int, merge: bool,
                 with_canonical=None):
     """Configuration file -> registry family -> config -> seeded params
@@ -82,12 +99,9 @@ def build_model(config: Dict[str, Any], seed: int, merge: bool,
     stays on the device. Returns the model and the seconds each stage
     took."""
     from bigdl_tpu.models import llama as llama_mod
-    from bigdl_tpu.models.registry import get_family
     from bigdl_tpu.transformers.model import TpuCausalLM
 
-    hf = config["hf_config"]
-    family = get_family(hf["architectures"][0], hf)
-    cfg = family.config_from_hf(hf)
+    family, cfg, hf = _family_config(config)
     import time
 
     import jax

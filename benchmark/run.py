@@ -14,6 +14,15 @@ kind that ``harness/peaks.json`` lacks, it exits non-zero and prints no
 result. ``--tiny`` (toy widths, CPU, interpret-mode kernels) is for
 rehearsals and the tests: it reports ``platform: cpu`` and leaves out
 every time, rate and share of the device.
+
+A cell is files plus entries: ``configs/<config>.json``,
+``traffic/<traffic>.json`` (its ``"runner"`` names
+``harness/<runner>_runner.py``), one reader per per-layer metric under
+``layer_metrics/``, and the entries in ``BENCHMARK.json``. A
+configuration of another architecture also brings its own reference,
+weights and costs modules, which its file names under ``"harness"``
+(``harness/__init__.py`` has the contract of each). The last lines of
+standard error give every number ``correct`` compared beside its limit.
 """
 
 from __future__ import annotations
@@ -48,6 +57,11 @@ def main(argv=None) -> int:
                     help="builder's tool: comma-separated open-loop rates "
                          "to try, one window each, before the cell's own "
                          "window (finds the knee once; no check uses it)")
+    ap.add_argument("--override", default=None,
+                    help="builder's tool: a JSON object laid over the "
+                         "configuration's file, for the control of "
+                         "`correct` (a cache of lower precision); no "
+                         "check uses it")
     ap.add_argument("--root", default=str(ROOT),
                     help="tree that holds BENCHMARK.json and benchmark/ "
                          "(the tests point it at a copy)")
@@ -60,6 +74,9 @@ def main(argv=None) -> int:
     except spec.SpecError as e:
         print(f"benchmark: {e}", file=sys.stderr)
         return 2
+    if args.override:
+        cell.config = spec.deep_update(cell.config,
+                                       json.loads(args.override))
     seconds = (args.seconds if args.seconds is not None
                else float(cell.bench["run_seconds"]))
 

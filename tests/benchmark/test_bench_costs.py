@@ -77,3 +77,41 @@ def test_training_counts_forward_and_activation_gradients_only():
     assert costs.train_flops_per_token(DIMS, 1024) == 2 * fwd
     assert costs.train_flops_per_token(DIMS, 1024, frozen_base=False) \
         == 3 * fwd
+
+
+def test_serving_work_is_what_the_runner_computed_inline():
+    """Pinned by hand at this geometry: the packed linears once, and for
+    every token received in the traced stretch the bf16 cache of its
+    request at that token's position, 131,072 B a position."""
+    records = [
+        # 100-token prompt; tokens 1-2 before the stretch, 3-5 inside
+        {"prompt_tokens": 100, "chunks": [[9.0, 2], [10.5, 3], [12.5, 1]]},
+        # nothing inside
+        {"prompt_tokens": 7, "chunks": [[1.0, 4]]},
+        # a failed request has no chunks
+        {"prompt_tokens": 50},
+    ]
+    work = costs.serving_work(CONFIG, DIMS, records, "bf16", (10.0, 12.0))
+    assert set(work) == {"linear_weight_bytes", "decode_kv_bytes"}
+    assert work["linear_weight_bytes"] == costs.linear_weight_bytes(
+        DIMS, "sym_int4", 32) == (32 * 218_103_808 + 4096 * 32000) * 0.5625
+    assert work["decode_kv_bytes"] == 131_072 * (102 + 103 + 104)
+    # an int8 cache: a byte an element and a float32 scale per head and
+    # plane: 2 * 32 layers * 8 heads * (128 + 4) a position
+    assert costs.serving_work(CONFIG, DIMS, records, "int8", (10.0, 12.0))[
+        "decode_kv_bytes"] == 2 * 32 * 8 * 132 * (102 + 103 + 104)
+    # nothing traced: no cache bytes to divide by
+    assert set(costs.serving_work(CONFIG, DIMS, records, "bf16", None)) == {
+        "linear_weight_bytes"}
+
+
+def test_training_work_is_what_the_runner_computed_inline():
+    work = costs.training_work(CONFIG, DIMS, {"seq_len": 1024}, 8 * 1024)
+    assert set(work) == {"train_flops_per_step"}
+    assert work["train_flops_per_step"] == 8192 * 2 * (
+        costs.model_flops_per_token(DIMS)
+        + costs.attn_flops_per_token(DIMS, 512))
+    # by hand: 32 layers * 2 * 218,103,808 + the head's 2 * 4096 * 32000
+    # matmul operations a token, 32 * 4 * 32 * 128 * 512 of attention
+    assert costs.model_flops_per_token(DIMS) == 14_220_787_712
+    assert costs.attn_flops_per_token(DIMS, 512) == 268_435_456

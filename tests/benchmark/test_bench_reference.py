@@ -49,15 +49,13 @@ def built(request):
 
 def test_prefill_last_logits_agree_with_the_reference(built):
     rel = reference.relative_l2(built["prefill"], built["ref"][-1])
-    tol = reference.tolerance(built["config"]["reference"]["layers"],
-                              built["kv"])
+    tol = reference.tolerance(built["config"], built["kv"])
     assert rel <= tol, (rel, tol)
 
 
 def test_training_forward_agrees_at_every_position(built):
     rel = reference.relative_l2(built["train"], built["ref"])
-    assert rel <= reference.tolerance(
-        built["config"]["reference"]["layers"], "bf16")
+    assert rel <= reference.tolerance(built["config"], "bf16")
     a = reference.next_token_loss(built["train"], built["ids"])
     b = reference.next_token_loss(built["ref"], built["ids"])
     assert a == pytest.approx(b, rel=0.01)
@@ -95,7 +93,7 @@ def test_the_tolerance_catches_a_wrong_model(built, fault):
         if fault == "int4_as_offset_7":
             reference.unpack_sym_int4 = wrong
     rel = reference.relative_l2(built["prefill"], bad)
-    tol = reference.tolerance(arch["layers"], built["kv"])
+    tol = reference.tolerance(built["config"], built["kv"])
     assert rel > tol, (fault, rel, tol)
 
 

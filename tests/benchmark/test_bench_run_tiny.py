@@ -129,6 +129,186 @@ def test_a_new_cell_is_added_by_files_and_entries_only(tmp_path):
     assert run_note["generator_late_ms"]["p99"] is not None
 
 
+WIDE_MODULES = {
+    "wide_reference": (
+        "from harness.reference import (all_logits,  # noqa\n"
+        "                               served_gap_limits, tolerance)\n"),
+    "wide_weights": (
+        "from harness.weights import build_model, canonical_params  # noqa\n"),
+    "wide_costs": (
+        "from harness.costs import (Dims, kv_bytes_per_token,  # noqa\n"
+        "                           training_work)\n"
+        "from harness import costs as _dense\n\n\n"
+        "def serving_work(config, dims, records, kv_cache_dtype, trace_ab):\n"
+        "    work = _dense.serving_work(config, dims, records,\n"
+        "                               kv_cache_dtype, trace_ab)\n"
+        "    work['wide_tokens_served'] = float(\n"
+        "        sum(r.get('received', 0) for r in records))\n"
+        "    return work\n"),
+}
+WIDE_READER = (
+    'LAYER = "engine step"\nSOURCE = "program_counter"\nUNIT = "tokens"\n'
+    'MOVES = "output_tokens_per_s"\n\n\ndef read(obs):\n'
+    '    return (obs.get("work") or {}).get("wide_tokens_served")\n')
+
+
+def test_a_configuration_of_another_architecture_is_files_and_entries_only(
+        tmp_path):
+    """A configuration that names three modules of its own, with a
+    ``.py`` reader of a ``work`` key only its costs module returns and a
+    hidden size that is not 4096 (5120 over 40 heads of 128), joins a
+    copy of the tree by new files and new entries and runs ``--tiny``;
+    no file that was there is touched. It rides on a traffic mix that
+    is there. The guard that the next configuration's PR edits nothing
+    under ``paths``."""
+    root = _copy_tree(tmp_path)
+    before = _digest(root)
+    bench_dir = root / "benchmark"
+    for stem, text in WIDE_MODULES.items():
+        (bench_dir / "harness" / f"{stem}.py").write_text(text)
+    (bench_dir / "layer_metrics" / "wide_tokens_served.py").write_text(
+        WIDE_READER)
+    config = json.loads(
+        (bench_dir / "configs" / "mistral-7b-int4.json").read_text())
+    config["why"] = "hidden 5120 with modules of its own, for the test"
+    config["harness"] = {"reference": "wide_reference",
+                         "weights": "wide_weights", "costs": "wide_costs"}
+    config["hf_config"].update(hidden_size=5120, num_attention_heads=40)
+    config["reference"].update(hidden=5120, heads=40)
+    config["tiny"]["engine"]["max_batch"] = 2
+    (bench_dir / "configs" / "wide-config.json").write_text(
+        json.dumps(config))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({
+        "name": "wide-config", "source": config["source"],
+        "file": "benchmark/configs/wide-config.json", "reduced": [],
+        "why": "another-architecture test"})
+    bench["workloads"].append({
+        "name": "wide-cell", "config": "wide-config",
+        "traffic": "chat-steady", "chips": 1,
+        "why": "another-architecture test"})
+    for m in bench["end_to_end"]:
+        if "workloads" in m and m["name"] != "train_tokens_per_s":
+            m["workloads"].append("wide-cell")
+    bench["per_layer"].append({
+        "name": "wide_tokens_served", "unit": "tokens",
+        "better": "higher", "source": "program_counter",
+        "layer": "engine step", "moves": "output_tokens_per_s",
+        "workloads": ["wide-cell"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    from harness import spec
+
+    assert spec.check_benchmark(bench) == []
+    cell = spec.Cell("wide-cell", root)
+    assert cell.config["reference"]["hidden"] == 5120 != 4096
+    assert cell.layer_metric_file("wide_tokens_served").suffix == ".py"
+    assert {m.__name__.split("@")[0] for m in cell.modules.values()} == {
+        "harness.wide_reference", "harness.wide_weights",
+        "harness.wide_costs"}
+
+    r = _run(root, "--workload", "wide-cell", "--seed", str(2 ** 31 + 27),
+             "--seconds", "2", "--trace", "1", "--tiny")
+    assert r.returncode == 0, r.stderr[-2000:]
+    line = json.loads(r.stdout.strip().splitlines()[-1])
+    assert LINE_KEYS <= set(line)
+    assert line["correct"] is True, r.stdout[-3000:]
+    assert line["failed"] == 0 and line["attempted"] >= 4
+    assert set(line["metrics"]) == {"wide_tokens_served"}
+    assert line["metrics"]["wide_tokens_served"] == {
+        "value": line["metrics"]["wide_tokens_served"]["value"],
+        "unit": "tokens"}
+    assert line["metrics"]["wide_tokens_served"]["value"] > 0
+    after = _digest(root)
+    assert set(after) - set(before) == {
+        "benchmark/configs/wide-config.json",
+        "benchmark/layer_metrics/wide_tokens_served.py",
+        "benchmark/harness/wide_reference.py",
+        "benchmark/harness/wide_weights.py",
+        "benchmark/harness/wide_costs.py"}
+    assert all(after[k] == before[k] for k in before)
+
+
+def test_tiny_run_of_the_paged_cell_compares_each_number_with_its_limit():
+    r = _run(_paths.ROOT, "--workload", "chatglm2-6b-docqa-shared",
+             "--seed", str(2 ** 32 + 5), "--seconds", "2", "--trace", "0",
+             "--tiny")
+    assert r.returncode == 0, r.stderr[-2000:]
+    line = json.loads(r.stdout.strip().splitlines()[-1])
+    assert set(line) == LINE_KEYS
+    assert line["correct"] is True, r.stdout[-3000:]
+    assert line["attempted"] == 6 and line["failed"] == 0
+    assert line["metrics"] == {}          # no time from a CPU run
+    note = [json.loads(x) for x in r.stdout.strip().splitlines()[:-1]
+            if x.startswith("{") and '"info": "run"' in x][0]
+    assert set(note["reference_rel_l2"]) == {"prefill", "decode"}
+    assert 0 < max(note["reference_rel_l2"].values()) \
+        <= note["reference_tolerance"]
+    assert note["served"]["requests"] == 4
+    assert note["served"]["longest"] >= 64      # a document and more
+    # the last lines of standard error: each number beside its limit
+    tail = r.stderr.strip().splitlines()[-6:]
+    assert tail[-1] == "checks failed: none"
+    assert [x.split()[1] for x in tail[:-1]] == [
+        "reference_rel_l2.prefill", "reference_rel_l2.decode",
+        "prefill_gap_max", "decode_gap_max", "decode_gap_mean"]
+    assert all(x.endswith(": ok") for x in tail[:-1])
+
+
+BROKEN_DRIVER = """
+import sys
+sys.path.insert(0, {bench!r})
+sys.path.insert(0, {root!r})
+import run
+from bigdl_tpu.serving import engine
+
+pushed = engine.LLMEngine._push_output
+
+
+def altered(self, rid, out, *a, **kw):
+    # the timed path broken where a token is produced: every token
+    # that leaves the engine is the one after the token it computed
+    out.new_token_ids = [t + 1 if t < 250 else t - 1
+                         for t in out.new_token_ids]
+    return pushed(self, rid, out, *a, **kw)
+
+
+engine.LLMEngine._push_output = altered
+sys.exit(run.main(sys.argv[1:]))
+"""
+
+
+def test_a_run_whose_engine_emits_altered_tokens_is_not_correct(tmp_path):
+    """The whole of a run but the look for a chip, with the timed path
+    broken underneath: every other check still passes (the counts are
+    exact, the probe repeats, nothing compiles, the family's logits
+    agree), and the comparison of what was served says no."""
+    driver = tmp_path / "broken.py"
+    driver.write_text(BROKEN_DRIVER.format(bench=str(_paths.BENCH),
+                                           root=str(_paths.ROOT)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    out_root = _copy_tree(tmp_path)       # the run's files go to a copy
+    r = subprocess.run(
+        [sys.executable, str(driver), "--root", str(out_root),
+         "--workload", "mistral7b-batch-closed", "--seed", "31",
+         "--seconds", "2", "--trace", "0", "--tiny"],
+        cwd=str(tmp_path), env=env, capture_output=True, text=True,
+        timeout=RUN_TIMEOUT_S)
+    assert r.returncode == 0, r.stderr[-2000:]
+    line = json.loads(r.stdout.strip().splitlines()[-1])
+    assert line["correct"] is False
+    note = [json.loads(x) for x in r.stdout.strip().splitlines()[:-1]
+            if x.startswith("{") and '"info": "run"' in x][0]
+    failed = sorted(k for k, ok in note["checks"].items() if not ok)
+    assert failed == ["served_tokens_within_reference_gap"]
+    assert note["served"]["decode_gap_max"] > 1.0
+    assert r.stderr.strip().splitlines()[-1] == (
+        "checks failed: ['served_tokens_within_reference_gap']")
+    assert any(x.startswith("compared decode_gap_max") and
+               x.endswith("OVER") for x in r.stderr.splitlines())
+
+
 def test_tiny_run_of_the_training_runner_prints_the_schema():
     r = _run(_paths.ROOT, "--workload", "mistral7b-qlora-alpaca",
              "--seed", "7", "--seconds", "1", "--trace", "0", "--tiny")

@@ -65,7 +65,7 @@ def test_open_loop_count_and_due_times_fill_the_window(chat):
 def test_lengths_are_clipped_as_the_file_says(name):
     tr = _load(name)
     plan = T.window_plan(tr, 11, 10.0, 32000)
-    reqs = T._all_requests(plan)
+    reqs = T.all_requests(plan)
     p, o = tr["prompt_tokens"], tr["output_tokens"]
     assert all(p["min"] <= r["prompt_len"] <= p["max"] for r in reqs)
     assert all(o["min"] <= r["max_tokens"] <= o["max"] for r in reqs)
