@@ -56,6 +56,13 @@ class FamilyAdapter:
     def new_paged_cache(self) -> Optional[Callable]:
         return self._forward_module("new_paged_cache")
 
+    @property
+    def cache_spec(self) -> Optional[Callable]:
+        """`cfg -> ops.kvcache.CacheSpec`: what the family's layers keep
+        per position, where that is not K and V of `num_key_value_heads
+        x hd` (a latent plane); None for the default."""
+        return self._forward_module("cache_spec")
+
 
 _REGISTRY: Dict[str, Any] = {}
 
@@ -132,6 +139,23 @@ def _register_builtin() -> None:
             prefill=mixtral_mod.forward_last_token,
             forward_train=mixtral_mod.forward_train,
             new_cache=mixtral_mod.new_cache,
+        ))
+
+    from bigdl_tpu.models import deepseek_v2 as deepseek_mod
+
+    # latent attention in the slab, group-limited routed experts with
+    # shared experts; no paged forward (SUPPORTS_PAGED_KV absent), the
+    # latent cache is bf16 only
+    register_family(
+        ["DeepseekV2ForCausalLM"],
+        FamilyAdapter(
+            name="deepseek_v2",
+            config_from_hf=deepseek_mod.DeepseekV2Config.from_hf,
+            convert_params=deepseek_mod.convert_hf_params,
+            forward=deepseek_mod.forward,
+            prefill=deepseek_mod.forward_last_token,
+            forward_train=None,
+            new_cache=deepseek_mod.new_cache,
         ))
 
     from bigdl_tpu.models import rwkv as rwkv_mod

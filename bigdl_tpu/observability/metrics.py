@@ -259,6 +259,28 @@ class MetricsRegistry:
     def __init__(self):
         self._lock = threading.Lock()
         self._families: Dict[str, MetricFamily] = {}
+        self._scrape_hooks: List = []
+
+    def add_scrape_hook(self, hook) -> None:
+        """`hook()` runs at the start of every `render()`: for counters
+        that live on the device and are fetched only when somebody
+        reads them. A hook returning False is dropped (its owner is
+        gone); one that raises is skipped this time."""
+        with self._lock:
+            self._scrape_hooks.append(hook)
+
+    def _run_scrape_hooks(self) -> None:
+        with self._lock:
+            hooks = list(self._scrape_hooks)
+        for hook in hooks:
+            try:
+                alive = hook()
+            except Exception:
+                continue
+            if alive is False:
+                with self._lock:
+                    if hook in self._scrape_hooks:
+                        self._scrape_hooks.remove(hook)
 
     def _get_or_create(self, name: str, help: str, kind: str,
                        labelnames: Sequence[str],
@@ -301,6 +323,7 @@ class MetricsRegistry:
 
     def render(self) -> str:
         """Prometheus text exposition format 0.0.4."""
+        self._run_scrape_hooks()
         out: List[str] = []
         for fam in self.families():
             if fam.help:

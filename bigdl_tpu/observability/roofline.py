@@ -80,7 +80,13 @@ def chip_peaks(device_kind: Optional[str] = None) -> Tuple[float, float]:
 def model_flops_per_token(cfg) -> int:
     """Forward matmul FLOPs per token (qkvo + gated mlp + lm_head; no
     attention-over-cache term). Shared by the physics floors, the
-    efficiency block and bench_qlora so the cost model cannot drift."""
+    efficiency block and bench_qlora so the cost model cannot drift.
+    A family whose layers are not q/k/v/o plus one gated MLP (latent
+    attention, routed experts) counts its own
+    (`cfg.matmul_flops_per_token()`)."""
+    own = getattr(cfg, "matmul_flops_per_token", None)
+    if own is not None:
+        return own()
     d, ff, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
     h, hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.hd
     proj = 2 * (d * h * hd + 2 * d * hkv * hd + h * hd * d)
@@ -89,7 +95,11 @@ def model_flops_per_token(cfg) -> int:
 
 def attn_flops_per_token(cfg, seq_len: int) -> int:
     """Attention-over-cache FLOPs for one decoded token at cache length
-    ``seq_len``: two matmuls (QK^T and PV) over ``seq_len`` keys."""
+    ``seq_len``: two matmuls (QK^T and PV) over ``seq_len`` keys (a
+    latent cache: `cfg.attn_flops_per_cached_token()`, absorbed)."""
+    own = getattr(cfg, "attn_flops_per_cached_token", None)
+    if own is not None:
+        return own() * seq_len
     h, hd = cfg.num_attention_heads, cfg.hd
     return cfg.num_hidden_layers * 2 * 2 * h * hd * seq_len
 
@@ -104,6 +114,11 @@ def kv_bytes_per_token(cfg, seq_len: int,
         raise ValueError(
             f"unknown kv_cache_dtype {kv_cache_dtype!r}; choose from "
             f"{sorted(KV_ELT_BYTES)}")
+    values = getattr(cfg, "kv_values_per_position", None)
+    if values is not None:
+        # the cache counted by its planes: one latent plane of `values`
+        # a position and layer, no scale planes
+        return float(cfg.num_hidden_layers) * seq_len * values * elt
     l_, hkv, hd = (cfg.num_hidden_layers, cfg.num_key_value_heads,
                    cfg.hd)
     bytes_ = 2.0 * l_ * seq_len * hkv * hd * elt

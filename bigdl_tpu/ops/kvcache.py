@@ -29,6 +29,17 @@ separate [L, B, S, Hkv] f32 planes (`k_scale`/`v_scale`, None for the
 scale-free dtypes) so the code planes keep the exact cache layout the
 attention kernels already stream.
 
+A cache DESCRIBES ITS PLANES (`CacheSpec`, `KVCache.planes()`): K and V of
+`[L, B, S, Hkv, hd]` with optional scale planes, or ONE latent plane of
+`[L, B, latent_dim, S]` (multi-head latent attention: the normed compressed
+KV and the shared roped key of a position, 576 values for DeepSeek-V2). The
+latent plane keeps the positions in the LANES: 576 is 4.5 lane tiles, so a
+`[.., S, 576]` plane would be padded to 640 on the chip, while 576 rows of S
+positions tile without padding (576 = 36 x 16 sublanes of bf16). Every plane
+is `[L, B, ...]`; `plane_seq_axis(name)` says where its positions run, and
+the engine's splice, export and ledger code goes through that description
+and names no plane.
+
 Layout: [num_layers, batch, max_seq, kv_heads, head_dim] — the whole stack is
 one array per K/V so a `lax.scan` over layers can carry it. In place means
 addressed on the stack: `update_layer` writes its rows at `[layer, ...]` and
@@ -113,41 +124,177 @@ def kv_dtype_name(storage_dtype) -> str:
     return str(dt)
 
 
+# planes a cache may hold, in the order `planes()` lists them; every one
+# is [L, B, ...] and its positions run along `plane_seq_axis(name)`
+PLANE_NAMES = ("k", "v", "k_scale", "v_scale", "latent")
+_SEQ_AXIS = {"latent": 3}
+# the one storage type a latent plane takes (a quantized latent reads
+# noise at real widths: PERF.md 7, 17)
+LATENT_KV_DTYPES = ("bf16",)
+
+
+def plane_seq_axis(name: str) -> int:
+    """Axis of plane `name` along which the positions run."""
+    return _SEQ_AXIS.get(name, 2)
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheSpec:
+    """What one family's layers keep per position: the declaration a
+    family hands the cache manager (`cache_spec(cfg)` of the module that
+    owns its forward; a family without one keeps K and V of
+    `num_key_value_heads x hd`). kind "kv": K and V planes of
+    `[L, B, S, kv_heads, head_dim]`; kind "latent": one plane of
+    `[L, B, latent_dim, S]`."""
+    kind: str
+    num_layers: int
+    kv_heads: int = 0
+    head_dim: int = 0
+    latent_dim: int = 0
+    # int32 counters a family's forward accumulates on the device
+    # (KVCache.stats); 0 = none
+    stats_len: int = 0
+
+    @property
+    def seq_axis(self) -> int:
+        """Axis of the positions in the first plane `planes()` lists (a
+        host snapshot's `entry[0]`)."""
+        return plane_seq_axis("latent" if self.kind == "latent" else "k")
+
+    def values_per_position(self) -> int:
+        """Cached values of one position of one layer."""
+        if self.kind == "latent":
+            return self.latent_dim
+        return 2 * self.kv_heads * self.head_dim
+
+
+def cache_spec_of(family, cfg) -> CacheSpec:
+    """The `CacheSpec` of `family` for `cfg`."""
+    fn = getattr(family, "cache_spec", None)
+    if fn is not None:
+        return fn(cfg)
+    return CacheSpec("kv", cfg.num_hidden_layers,
+                     cfg.num_key_value_heads, cfg.hd)
+
+
+def reject_non_bf16_latent(spec) -> str:
+    """A latent cache is bf16 only; says so instead of storing codes no
+    kernel of this family reads."""
+    name = resolve_kv_cache_dtype(spec)
+    if name not in LATENT_KV_DTYPES:
+        raise NotImplementedError(
+            f"kv_cache_dtype {name!r} is not supported for a latent "
+            f"(MLA) cache: it is stored in bf16 only (int4 and fp8 read "
+            f"noise at real widths, PERF.md 7 (17); a quantized latent "
+            f"cache is a later issue)")
+    return name
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class KVCache:
-    k: jax.Array    # [L, B, S_max, H_kv, D] storage dtype
-    v: jax.Array    # [L, B, S_max, H_kv, D]
+    k: Optional[jax.Array]    # [L, B, S_max, H_kv, D] storage dtype
+    v: Optional[jax.Array]    # [L, B, S_max, H_kv, D]
     pos: jax.Array  # scalar int32: number of valid positions
     # per-(token, head) f32 dequant scales for int8/int4 storage;
     # None for the scale-free dtypes (bf16 / fp8_e5m2)
     k_scale: Optional[jax.Array] = None   # [L, B, S_max, H_kv] f32
     v_scale: Optional[jax.Array] = None
+    # a latent (MLA) cache holds this one plane and no k / v
+    latent: Optional[jax.Array] = None    # [L, B, latent_dim, S_max]
+    # int32 counters the family's forward adds to on the device (sparse
+    # experts: assignments, experts hit); read at scrape time
+    stats: Optional[jax.Array] = None
 
     def tree_flatten(self):
-        return (self.k, self.v, self.pos, self.k_scale, self.v_scale), None
+        return (self.k, self.v, self.pos, self.k_scale, self.v_scale,
+                self.latent, self.stats), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         return cls(*children)
 
+    def planes(self) -> Dict[str, jax.Array]:
+        """The planes this cache holds, by name (`PLANE_NAMES` order)."""
+        return {n: getattr(self, n) for n in PLANE_NAMES
+                if getattr(self, n) is not None}
+
+    def replace(self, **fields) -> "KVCache":
+        """Same cache with some planes / `pos` / `stats` replaced."""
+        return dataclasses.replace(self, **fields)
+
+    @property
+    def _first(self) -> Tuple[str, jax.Array]:
+        return next(iter(self.planes().items()))
+
     @property
     def max_seq(self) -> int:
-        return self.k.shape[2]
+        name, plane = self._first
+        return plane.shape[plane_seq_axis(name)]
 
     @property
     def num_layers(self) -> int:
-        return self.k.shape[0]
+        return self._first[1].shape[0]
 
     @property
     def kv_dtype(self) -> str:
         """Canonical kv_cache_dtype name of the storage."""
-        return kv_dtype_name(self.k.dtype)
+        return kv_dtype_name(self._first[1].dtype)
 
     def reset_pos(self, pos) -> "KVCache":
         """Same buffers, new validity pointer (generation pad repair /
         speculative rollback)."""
-        return KVCache(self.k, self.v, pos, self.k_scale, self.v_scale)
+        return self.replace(pos=pos)
+
+    def seq_slices(self, length: int, row=None) -> Tuple[jax.Array, ...]:
+        """Every plane cut to its first `length` positions (and to batch
+        row `row`, kept as an axis of 1), in `planes()` order: what the
+        prefix cache, export and migration move."""
+        out = []
+        for name, p in self.planes().items():
+            ax = plane_seq_axis(name)
+            p = jax.lax.slice_in_dim(p, 0, min(length, p.shape[ax]),
+                                     axis=ax)
+            if row is not None:
+                p = jax.lax.slice_in_dim(p, row, row + 1, axis=1)
+            out.append(p)
+        return tuple(out)
+
+    def seeded(self, host_planes, consumed: int) -> "KVCache":
+        """This (empty, scalar-pos) cache with the first `consumed`
+        positions of `host_planes` (numpy, `planes()` order) written in
+        and `pos = consumed`: an admission that starts from a snapshot."""
+        import numpy as np
+
+        new = {}
+        for (name, p), src in zip(self.planes().items(), host_planes):
+            ax = plane_seq_axis(name)
+            buf = np.zeros(p.shape, src.dtype)
+            at = [slice(None)] * p.ndim
+            at[ax] = slice(0, consumed)
+            buf[tuple(at)] = src[tuple(at)]
+            new[name] = jnp.asarray(buf)
+        return self.replace(pos=jnp.asarray(consumed, jnp.int32), **new)
+
+    def spliced(self, one: "KVCache", slot, plen) -> "KVCache":
+        """This batched cache with the 1-row cache `one` written into
+        batch row `slot` of every plane and `pos[slot] = plen`. `one` may
+        be longer (chunk padding): it is cut to this cache's length."""
+        new = {}
+        ones = one.planes()
+        for name, big in self.planes().items():
+            ax = plane_seq_axis(name)
+            src = jax.lax.slice_in_dim(
+                ones[name], 0, min(ones[name].shape[ax], big.shape[ax]),
+                axis=ax)
+            at = [0] * big.ndim
+            at[1] = slot
+            new[name] = jax.lax.dynamic_update_slice(
+                big, src.astype(big.dtype), tuple(at))
+        if self.stats is not None and one.stats is not None:
+            # what the prefill programs counted joins the slab's tally
+            new["stats"] = self.stats + one.stats
+        return self.replace(pos=self.pos.at[slot].set(plen), **new)
 
 
 def init_cache(
@@ -186,6 +333,65 @@ def init_cache(
         k_scale=jnp.zeros(sshape, jnp.float32) if scaled else None,
         v_scale=jnp.zeros(sshape, jnp.float32) if scaled else None,
     )
+
+
+def init_cache_spec(spec: CacheSpec, batch: int, max_seq: int,
+                    kv_cache_dtype=None, per_slot_pos: bool = False,
+                    dtype=jnp.bfloat16) -> KVCache:
+    """Allocate the empty cache `spec` describes (`init_cache` for K and
+    V planes; one bf16 latent plane otherwise)."""
+    if spec.kind != "latent":
+        return init_cache(spec.num_layers, batch, max_seq, spec.kv_heads,
+                          spec.head_dim, dtype=dtype,
+                          per_slot_pos=per_slot_pos,
+                          kv_cache_dtype=resolve_kv_cache_dtype(
+                              kv_cache_dtype))
+    reject_non_bf16_latent(kv_cache_dtype)
+    return KVCache(
+        k=None, v=None,
+        pos=(jnp.zeros((batch,), jnp.int32) if per_slot_pos
+             else jnp.zeros((), jnp.int32)),
+        latent=jnp.zeros((spec.num_layers, batch, spec.latent_dim, max_seq),
+                         dtype),
+        stats=(jnp.zeros((spec.stats_len,), jnp.int32)
+               if spec.stats_len else None))
+
+
+def update_latent(stack: jax.Array, layer, new: jax.Array,
+                  pos: jax.Array) -> jax.Array:
+    """Write `new` `[B, S_new, C]` into layer `layer` of the latent stack
+    `[L, B, C, S_max]` at sequence offset `pos` (scalar, or `[B]` per
+    slot): one `dynamic_update_slice`, or one scatter of the B x S_new
+    new columns, on the stack itself, as `update_layer` does for K and V.
+    A column past the end of the cache is dropped."""
+    new = new.astype(stack.dtype)
+    if getattr(pos, "ndim", 0) == 1:
+        from bigdl_tpu.config import target_is_tpu
+
+        b, s_new = new.shape[:2]
+        if target_is_tpu():
+            # the chip keeps the stack's positions in the lanes; XLA's
+            # scatter of columns re-lays the whole stack out, twice
+            if s_new == 1 and stack.shape[-1] % 128 == 0:
+                from bigdl_tpu.ops.pallas.mla_attention import (
+                    latent_append_pallas)
+
+                return latent_append_pallas(stack, layer, new[:, 0], pos)
+
+            def one(i, st):
+                return jax.lax.dynamic_update_slice(
+                    st, jnp.swapaxes(new[i], 0, 1)[None, None],
+                    (layer, i, 0, pos[i]))
+
+            return jax.lax.fori_loop(0, b, one, stack)
+        slot = jnp.arange(b, dtype=jnp.int32)[:, None]
+        at = pos[:, None] + jnp.arange(s_new, dtype=jnp.int32)[None, :]
+        # the two index arrays sit either side of the slice, so the
+        # updates are [B, S_new, C], as `new` is
+        return stack.at[layer, slot, :, at].set(
+            new, mode="drop", unique_indices=True)
+    return jax.lax.dynamic_update_slice(
+        stack, jnp.swapaxes(new, 1, 2)[None], (layer, 0, 0, pos))
 
 
 def quantize_kv(x: jax.Array, storage_dtype) -> Tuple[jax.Array, jax.Array]:
@@ -318,13 +524,29 @@ def kv_cache_nbytes(num_layers: int, batch: int, max_seq: int,
     return {"codes": codes, "scales": scales, "total": codes + scales}
 
 
+def cache_nbytes(spec: CacheSpec, batch: int, max_seq: int,
+                 kv_cache_dtype: Optional[str] = None) -> Dict[str, int]:
+    """`kv_cache_nbytes` for any `CacheSpec`: the footprint of a would-be
+    cache from its description, equal byte for byte to
+    ``kv_cache_bytes(init_cache_spec(...))``. A latent plane counts as
+    codes."""
+    if spec.kind != "latent":
+        return kv_cache_nbytes(spec.num_layers, batch, max_seq,
+                               spec.kv_heads, spec.head_dim, kv_cache_dtype)
+    name = reject_non_bf16_latent(kv_cache_dtype)
+    codes = (spec.num_layers * batch * max_seq * spec.latent_dim
+             * jnp.dtype(KV_CACHE_DTYPES[name]).itemsize)
+    return {"codes": codes, "scales": 0, "total": codes}
+
+
 def kv_cache_bytes(cache: KVCache) -> Dict[str, int]:
-    """Storage footprint of a cache: codes planes, scale planes, total."""
-    codes = _logical_nbytes(cache.k) + _logical_nbytes(cache.v)
-    scales = 0
-    if cache.k_scale is not None:
-        scales = (_logical_nbytes(cache.k_scale)
-                  + _logical_nbytes(cache.v_scale))
+    """Storage footprint of a cache by its planes: codes (K and V, or the
+    latent plane), scale planes, total."""
+    planes = cache.planes()
+    scales = sum(_logical_nbytes(p) for n, p in planes.items()
+                 if n.endswith("_scale"))
+    codes = sum(_logical_nbytes(p) for n, p in planes.items()
+                if not n.endswith("_scale"))
     return {"codes": codes, "scales": scales, "total": codes + scales}
 
 
@@ -340,10 +562,14 @@ def publish_kv_cache_bytes(cache: KVCache, registry=None) -> Dict[str, int]:
         g = registry.gauge(
             "bigdl_tpu_kv_cache_bytes",
             "KV cache storage bytes by dtype and component "
-            "(codes | scales | total); int4 counted at two codes per byte",
+            "(codes | scales | total, and latent for a latent plane); "
+            "int4 counted at two codes per byte",
             labelnames=("dtype", "component"))
         for comp, val in sizes.items():
             g.labels(cache.kv_dtype, comp).set(float(val))
+        if cache.latent is not None:
+            g.labels(cache.kv_dtype, "latent").set(
+                float(_logical_nbytes(cache.latent)))
     except Exception:
         pass
     return sizes

@@ -1,0 +1,256 @@
+"""The routed expert layer that knows its share.
+
+An expert-parallel deployment gives each chip some of a layer's routed
+experts. This layer is told `(experts_total, first_held, held)`: it
+routes every token over ALL `experts_total` experts exactly as the
+model publishes, and computes the part of the weighted sum that its own
+`held` experts (`first_held .. first_held + held - 1`) give. What the
+other chips' experts would add is left out; with `held == total` it is
+the whole layer. There is no stand-in for absent chips or their
+exchange. The caller adds what every chip computes alike (shared
+experts, the residual).
+
+Routing (`route`): `softmax` scores in float32, then plain top-k
+(`greedy`) or DeepSeek-V2's `group_limited_greedy`: the experts form
+`n_group` groups of consecutive experts, a group scores its best
+expert, the best `topk_group` groups stay, and the top-k is taken among
+their experts. Weights are the chosen scores, renormalised
+(`norm_topk_prob`) or times `routed_scaling_factor`.
+
+Two strategies by token count, both one Pallas kernel
+(`ops/pallas/moe_routed.py`), no host sync, no data-dependent shape:
+
+- up to `DECODE_MAX_TOKENS` tokens: every hit expert multiplies all the
+  tokens (`moe_routed_decode`); an expert no token chose is skipped,
+  bytes and all;
+- above: the sorted ragged dispatch over the held experts
+  (`moe_routed_prefill`).
+
+Where the kernel is not the designed choice (no TPU, a shape that does
+not tile, operands sharded under GSPMD) the dense combine in XLA ops
+runs: every held expert on every token, weighted by the routing.
+
+`RoutedStats` counts, for the `/metrics` counters: token-expert choices
+by whether the expert is held here, and held experts with at least one
+token.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from bigdl_tpu.ops.quant import QTensor
+
+DECODE_MAX_TOKENS = 64
+# order of the int32 counters a forward accumulates (KVCache.stats)
+STATS = ("assignments_held", "assignments_not_held", "experts_hit",
+         "layer_steps")
+
+
+class Share(NamedTuple):
+    """Which of a layer's routed experts this chip holds."""
+    experts_total: int
+    first_held: int
+    held: int
+
+
+def route(logits: jax.Array, top_k: int, *, n_group: int = 1,
+          topk_group: int = 1, method: str = "greedy",
+          scaling_factor: float = 1.0, norm_topk_prob: bool = False):
+    """Router logits `[N, E]` -> (expert ids `[N, k]` int32, weights
+    `[N, k]` float32)."""
+    scores = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    n, e = scores.shape
+    choice = scores
+    if method == "group_limited_greedy":
+        group_best = scores.reshape(n, n_group, e // n_group).max(axis=-1)
+        _, gi = lax.top_k(group_best, topk_group)               # [N, tg]
+        keep = jnp.zeros((n, n_group), bool).at[
+            jnp.arange(n)[:, None], gi].set(True)
+        choice = jnp.where(jnp.repeat(keep, e // n_group, axis=1),
+                           scores, 0.0)
+    elif method != "greedy":
+        raise NotImplementedError(f"topk_method {method!r}")
+    topv, topi = lax.top_k(choice, top_k)
+    if norm_topk_prob and top_k > 1:
+        topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+    else:
+        topv = topv * scaling_factor
+    return topi.astype(jnp.int32), topv
+
+
+def _combine(topi, topw, share: Share):
+    """`[N, held]` float32: the routing weight of each held expert for
+    each token (0 where the token did not choose it), and the mask of
+    choices that fall on held experts."""
+    local = topi - share.first_held
+    mine = (local >= 0) & (local < share.held)
+    onehot = jax.nn.one_hot(jnp.where(mine, local, share.held), share.held,
+                            dtype=jnp.float32)                  # [N, k, held]
+    return jnp.sum(onehot * topw[..., None], axis=1), mine
+
+
+def _kernel_ok(name, stacks, d, ff, t, shared_x) -> bool:
+    """Whether the Pallas kernel is the designed choice here, probing
+    each (K, N) it would run."""
+    from bigdl_tpu.config import flags, target_is_tpu, under_spmd
+    from bigdl_tpu.ops.pallas.moe_routed import routed_kernel_compiles
+
+    leaves = [a for s in stacks for a in jax.tree_util.tree_leaves(s)]
+    if flags().moe_dispatch == "dense" or under_spmd(*leaves):
+        return False
+    for s in stacks:
+        if isinstance(s, QTensor) and (s.qtype != "sym_int4"
+                                       or s.data.dtype != jnp.uint8):
+            return False
+    if not target_is_tpu():
+        return flags().moe_dispatch == "ragged"     # forced: interpret
+    gate, up, down = stacks
+    qn = lambda s: s.qtype if isinstance(s, QTensor) else None  # noqa: E731
+    ok = all(routed_kernel_compiles(name, qn(s), kk, nn, t, sx)
+             for s, kk, nn, sx in ((gate, d, ff, shared_x),
+                                   (up, d, ff, shared_x),
+                                   (down, ff, d, False)))
+    if not ok:
+        from bigdl_tpu.ops.probing import record_dispatch_rule
+
+        record_dispatch_rule(name)
+    return ok
+
+
+def _decode(xf, comb, hit, gate, up, down, layer, act, interpret):
+    """Every hit expert over all the tokens; tiles are held experts,
+    hit ones first."""
+    from bigdl_tpu.ops.pallas.moe_routed import (DECODE_NAME,
+                                                 routed_expert_matmul)
+
+    n, d = xf.shape
+    held = comb.shape[1]
+    order = jnp.argsort(~hit, stable=True).astype(jnp.int32)
+    n_hit = jnp.sum(hit).astype(jnp.int32)
+    npad = -(-n // 16) * 16
+    x1 = jnp.pad(xf, ((0, npad - n), (0, 0)))[None]             # [1, Np, D]
+    mm = lambda x, w, shared: routed_expert_matmul(             # noqa: E731
+        x, w, order, n_hit, layer, name=DECODE_NAME, shared_x=shared,
+        interpret=interpret)
+    live = (jnp.arange(held) < n_hit)[:, None, None]
+    cw = jnp.pad(comb.T[order], ((0, 0), (0, npad - n)))        # [held, Np]
+    h = (act(mm(x1, gate, True).astype(jnp.float32))
+         * mm(x1, up, True).astype(jnp.float32) * cw[..., None])
+    h = jnp.where(live, h, 0.0).astype(xf.dtype)                # [held,Np,F]
+    y = jnp.where(live, mm(h, down, False).astype(jnp.float32), 0.0)
+    return jnp.sum(y, axis=0)[:n].astype(xf.dtype)
+
+
+def _prefill(xf, topi, topw, mine, share, gate, up, down, layer, act,
+             interpret):
+    """Sorted ragged dispatch over the held experts (the scheme of
+    `ops/pallas/moe_dispatch.moe_mlp_ragged`): choices of experts held
+    elsewhere sort last and take no row."""
+    from bigdl_tpu.ops.pallas.moe_routed import (PREFILL_NAME,
+                                                 PREFILL_TOKEN_TILE,
+                                                 routed_expert_matmul)
+
+    n, k = topi.shape
+    held, t = share.held, PREFILL_TOKEN_TILE
+    nk_tot = n * k
+    np_ = -(-(nk_tot + held * (t - 1)) // t) * t    # static worst case
+    flat_e = jnp.where(mine, topi - share.first_held, held).reshape(-1)
+    flat_tok = jnp.repeat(jnp.arange(n, dtype=jnp.int32), k)
+    flat_w = jnp.where(mine, topw, 0.0).reshape(-1)
+
+    order = jnp.argsort(flat_e, stable=True)
+    sorted_e = flat_e[order]
+    counts = jnp.bincount(flat_e, length=held + 1)[:held]
+    padded = -(-counts // t) * t
+    region_end = jnp.cumsum(padded)
+    starts = region_end - padded
+    group_start = jnp.cumsum(counts) - counts
+    is_mine = sorted_e < held
+    e_safe = jnp.minimum(sorted_e, held - 1)
+    dest = jnp.where(is_mine, starts[e_safe] + jnp.arange(nk_tot)
+                     - group_start[e_safe], np_)                # np_: dropped
+    xbuf = jnp.zeros((np_, xf.shape[1]), xf.dtype).at[dest].set(
+        xf[flat_tok[order]], mode="drop")
+    tile_first = jnp.arange(np_ // t, dtype=jnp.int32) * t
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(region_end, tile_first, side="right"),
+        held - 1).astype(jnp.int32)
+    n_active = (region_end[-1] // t).astype(jnp.int32)
+    xt = xbuf.reshape(np_ // t, t, -1)
+    mm = lambda x, w: routed_expert_matmul(                     # noqa: E731
+        x, w, tile_expert, n_active, layer, name=PREFILL_NAME,
+        interpret=interpret)
+    live = (jnp.arange(np_ // t) < n_active)[:, None, None]
+    h = jnp.where(live, act(mm(xt, gate).astype(jnp.float32))
+                  * mm(xt, up).astype(jnp.float32), 0.0).astype(xf.dtype)
+    y = jnp.where(live, mm(h, down), 0).reshape(np_, -1)
+    contrib = (y[jnp.minimum(dest, np_ - 1)].astype(jnp.float32)
+               * flat_w[order][:, None])
+    out = jnp.zeros(xf.shape, jnp.float32).at[flat_tok[order]].add(contrib)
+    return out.astype(xf.dtype)
+
+
+def _dense(xf, comb, gate, up, down, layer, act):
+    """XLA ops: every held expert on every token, weighted."""
+    from bigdl_tpu.ops.matmul import linear
+
+    if layer is not None:
+        gate, up, down = (jax.tree.map(
+            lambda a: lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+            s) for s in (gate, up, down))
+
+    def one(gw, uw, dw):
+        return linear(act(linear(xf, gw)) * linear(xf, uw), dw)
+
+    ys = jax.vmap(one)(gate, up, down)                          # [held, N, D]
+    return jnp.einsum("end,ne->nd", ys.astype(jnp.float32),
+                      comb).astype(xf.dtype)
+
+
+def routed_experts(xf: jax.Array, router_logits: jax.Array,
+                   stacks: Dict[str, Any], share: Share, *, top_k: int,
+                   act, layer=None, **routing):
+    """The held experts' part of the routed sum for tokens `xf` `[N, D]`:
+    `sum_i w_i * expert_{e_i}(x)` over the chosen experts that are held
+    here. `stacks`: `experts_gate` / `experts_up` `[held, D, F]` and
+    `experts_down` `[held, F, D]` (QTensor or dense); with `layer`, each
+    has a leading layer axis and that layer is read where it lies (the
+    kernels take the stack and the index). Returns the
+    `[N, D]` partial result and this call's `STATS` increments."""
+    gate, up, down = (stacks["experts_gate"], stacks["experts_up"],
+                      stacks["experts_down"])
+    n, d = xf.shape
+    ff = (gate.shape[-1] if not isinstance(gate, QTensor)
+          else gate.data.shape[-1])
+    topi, topw = route(router_logits, top_k, **routing)
+    comb, mine = _combine(topi, topw, share)
+    n_mine = jnp.sum(mine).astype(jnp.int32)
+    hit = jnp.any(comb != 0.0, axis=0)                          # [held]
+    stats = jnp.stack([
+        n_mine, jnp.int32(n * top_k) - n_mine,
+        jnp.sum(hit).astype(jnp.int32), jnp.int32(1)])
+    at = 0 if layer is None else layer
+    from bigdl_tpu.config import target_is_tpu
+
+    interpret = not target_is_tpu()
+    if n <= DECODE_MAX_TOKENS:
+        from bigdl_tpu.ops.pallas.moe_routed import DECODE_NAME
+
+        if _kernel_ok(DECODE_NAME, (gate, up, down), d, ff,
+                      -(-n // 16) * 16, True):
+            return _decode(xf, comb, hit, gate, up, down, at, act,
+                           interpret), stats
+    else:
+        from bigdl_tpu.ops.pallas.moe_routed import (PREFILL_NAME,
+                                                     PREFILL_TOKEN_TILE)
+
+        if _kernel_ok(PREFILL_NAME, (gate, up, down), d, ff,
+                      PREFILL_TOKEN_TILE, False):
+            return _prefill(xf, topi, topw, mine, share, gate, up, down,
+                            at, act, interpret), stats
+    return _dense(xf, comb, gate, up, down, layer, act), stats

@@ -100,6 +100,42 @@ def scaled_rope_freqs(
     raise NotImplementedError(f"rope_scaling type {rtype!r} not supported")
 
 
+def deepseek_yarn_freqs(
+    rotary_dim: int,
+    base: float,
+    scaling: dict,
+    max_position_embeddings: int = 4096,
+):
+    """DeepSeek-V2's YaRN: `(inv_freq [rd//2], cos_sin_factor,
+    softmax_scale_factor)`.
+
+    The frequencies are YaRN's (the ramp between `beta_fast` and
+    `beta_slow` over `original_max_position_embeddings`), what
+    `scaled_rope_freqs` returns for `"yarn"`. The magnitudes are not: HF's
+    `DeepseekV2YarnRotaryEmbedding` multiplies cos and sin by
+    `yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)`
+    (1 when the two are equal, as published) and `DeepseekV2Attention`
+    multiplies the softmax scale by `yarn_mscale(factor, mscale_all_dim)`
+    squared, with `yarn_mscale(f, m) = 0.1 * m * ln(f) + 1` for f > 1.
+    Other families' `"yarn"` (`0.1 ln(factor) + 1` on cos and sin) stays
+    as it is."""
+    import math
+
+    inv_freq, _ = scaled_rope_freqs(
+        rotary_dim, base, dict(scaling, rope_type="yarn", type="yarn"),
+        rotary_dim=rotary_dim,
+        max_position_embeddings=max_position_embeddings)
+    factor = float(scaling.get("factor", 1.0))
+
+    def mscale(m):
+        return 1.0 if factor <= 1.0 else 0.1 * m * math.log(factor) + 1.0
+
+    all_dim = float(scaling.get("mscale_all_dim", 0.0))
+    cos_sin = mscale(float(scaling.get("mscale", 1.0))) / mscale(all_dim)
+    softmax = mscale(all_dim) ** 2 if all_dim else 1.0
+    return inv_freq, cos_sin, softmax
+
+
 def rope_cos_sin(
     positions: jax.Array,  # [...] int positions
     inv_freq: jax.Array,   # [rd // 2]

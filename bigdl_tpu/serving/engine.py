@@ -68,8 +68,8 @@ from bigdl_tpu.observability.slo import SLOTracker
 from bigdl_tpu.observability.stats import ewma as stats_ewma
 from bigdl_tpu.observability.tracing import PhaseClock, RequestTracer
 from bigdl_tpu.observability.usage import UsageLedger
-from bigdl_tpu.ops.kvcache import (KVCache, init_cache, kv_cache_bytes,
-                                   kv_cache_nbytes,
+from bigdl_tpu.ops.kvcache import (KVCache, cache_nbytes, cache_spec_of,
+                                   init_cache_spec, kv_cache_bytes,
                                    publish_kv_cache_bytes,
                                    resolve_kv_cache_dtype)
 from bigdl_tpu.ops.paged import (NULL_PAGE, PagedKVCache, cow_copy_pages,
@@ -500,6 +500,10 @@ class LLMEngine:
                 f"the {self.family.name!r} family uses a custom cache "
                 f"({type(probe_cache).__name__}) the slot engine cannot "
                 "splice — serve it through model.generate() instead")
+        # what the family's layers keep per position (K and V planes, or
+        # one latent plane): every cache here is made, spliced, measured
+        # and exported through this description
+        self._cache_spec = cache_spec_of(self.family, self.cfg)
         self.eos_token_id = None
         hf = getattr(model, "hf_config", None) or {}
         eos = hf.get("eos_token_id")
@@ -562,9 +566,8 @@ class LLMEngine:
         else:
             self._pages_per_seq = 0
             self._num_pages = 0
-            self.cache = init_cache(
-                self.cfg.num_hidden_layers, B, ce.max_seq,
-                self.cfg.num_key_value_heads, self.cfg.hd,
+            self.cache = init_cache_spec(
+                self._cache_spec, B, ce.max_seq,
                 kv_cache_dtype=self.kv_cache_dtype, per_slot_pos=True)
 
         self.slots = [_Slot() for _ in range(B)]
@@ -776,24 +779,9 @@ class LLMEngine:
         def insert(cache: KVCache, cache1: KVCache, slot, plen):
             # the private cache may be chunk-padded past max_seq; the
             # tail holds only pad garbage (plen <= max_seq is enforced
-            # at add_request), so clip the splice statically
-            max_s = cache.k.shape[2]
-            k1 = cache1.k[:, :, :max_s]
-            v1 = cache1.v[:, :, :max_s]
-            k = jax.lax.dynamic_update_slice(
-                cache.k, k1.astype(cache.k.dtype), (0, slot, 0, 0, 0))
-            v = jax.lax.dynamic_update_slice(
-                cache.v, v1.astype(cache.v.dtype), (0, slot, 0, 0, 0))
-            ks = vs = None
-            if cache.k_scale is not None:
-                ks = jax.lax.dynamic_update_slice(
-                    cache.k_scale, cache1.k_scale[:, :, :max_s],
-                    (0, slot, 0, 0))
-                vs = jax.lax.dynamic_update_slice(
-                    cache.v_scale, cache1.v_scale[:, :, :max_s],
-                    (0, slot, 0, 0))
-            pos = cache.pos.at[slot].set(plen)
-            return KVCache(k, v, pos, ks, vs)
+            # at add_request): every plane is cut to the slab's length
+            # and written at the slot, whatever planes the cache holds
+            return cache.spliced(cache1, slot, plen)
 
         self._insert = insert
 
@@ -1152,6 +1140,8 @@ class LLMEngine:
                 self._kv_bytes_per_page * self._pages_per_seq)
         else:
             publish_kv_cache_bytes(self.cache, m)
+            if self.cache.stats is not None:
+                self._init_moe_counters(m)
             # static ledger entries: params (packed, QTensor/int4-aware)
             # and the batched KV cache; per-slot bytes drive the
             # admission cost
@@ -1607,10 +1597,8 @@ class LLMEngine:
         bucket = self._bucket(prompt_len)
         chunk = min(self._chunk, bucket)
         alloc = -(-bucket // chunk) * chunk
-        return kv_cache_nbytes(
-            self.cfg.num_hidden_layers, 1, alloc,
-            self.cfg.num_key_value_heads, self.cfg.hd,
-            self.kv_cache_dtype)["total"]
+        return cache_nbytes(self._cache_spec, 1, alloc,
+                            self.kv_cache_dtype)["total"]
 
     def _admission_step(self) -> None:
         """Advance chunked admission by AT MOST one chunk (bounds the
@@ -1689,10 +1677,8 @@ class LLMEngine:
                 if paged_adm is None:
                     return
                 consumed, shared_pages, new_pages = paged_adm
-            cache1 = init_cache(
-                self.cfg.num_hidden_layers, 1, alloc,
-                self.cfg.num_key_value_heads, self.cfg.hd,
-                kv_cache_dtype=self.kv_cache_dtype)
+            cache1 = init_cache_spec(self._cache_spec, 1, alloc,
+                                     kv_cache_dtype=self.kv_cache_dtype)
             if self._paged:
                 if consumed:
                     cache1 = self._seed_pages(
@@ -1703,23 +1689,7 @@ class LLMEngine:
                 consumed, seed_kv = self._seed_from_prefix_cache(
                     req.prompt_token_ids, chunk)
                 if consumed:
-                    k_np, v_np = seed_kv[0], seed_kv[1]
-                    kb = np.zeros(cache1.k.shape, k_np.dtype)
-                    vb = np.zeros_like(kb)
-                    kb[:, :, :consumed] = k_np[:, :, :consumed]
-                    vb[:, :, :consumed] = v_np[:, :, :consumed]
-                    ksb = vsb = None
-                    if cache1.k_scale is not None:
-                        ks_np, vs_np = seed_kv[2], seed_kv[3]
-                        ksb = np.zeros(cache1.k_scale.shape, np.float32)
-                        vsb = np.zeros_like(ksb)
-                        ksb[:, :, :consumed] = ks_np[:, :, :consumed]
-                        vsb[:, :, :consumed] = vs_np[:, :, :consumed]
-                        ksb = jnp.asarray(ksb)
-                        vsb = jnp.asarray(vsb)
-                    cache1 = KVCache(jnp.asarray(kb), jnp.asarray(vb),
-                                     jnp.asarray(consumed, jnp.int32),
-                                     ksb, vsb)
+                    cache1 = cache1.seeded(seed_kv, consumed)
             a = self._admitting = _Admission(req, free, bucket, consumed,
                                              cache1, chunk,
                                              shared_pages=shared_pages,
@@ -1882,7 +1852,7 @@ class LLMEngine:
         # shared pages must NOT be rewritten (a concurrent reader of
         # those pages stays byte-identical), and chunk padding past the
         # allocated pages has nowhere to live — both go to the null page
-        cap = min(a.cache1.k.shape[2], self.cfg_engine.max_seq)
+        cap = min(a.cache1.max_seq, self.cfg_engine.max_seq)
         write_row = np.zeros((self._pages_per_seq,), np.int64)
         write_row[:len(row)] = row
         write_row[:len(shared)] = NULL_PAGE
@@ -2049,7 +2019,7 @@ class LLMEngine:
             seed_shape = tuple(entry[0].shape)
             self.flight.record("handoff_staged", step=self._step_idx,
                                prompt_len=len(key),
-                               seed_tokens=seed_shape[2])
+                               seed_tokens=seed_shape[self._cache_spec.seq_axis])
         # bound retention by the EXPLICIT handoff knob, never by
         # prefix_cache_entries: prefix_cache_entries == 0 means the
         # operator turned local prefix caching OFF, and the old
@@ -2267,13 +2237,10 @@ class LLMEngine:
                     np.ascontiguousarray(np.asarray(p)[:, :, :kv_len])  # graftlint: disable=step-host-sync
                     for p in dev)
             else:
-                c = self.cache
-                srcs = (c.k, c.v) + ((c.k_scale, c.v_scale)
-                                     if c.k_scale is not None else ())
                 planes = tuple(
                     np.ascontiguousarray(  # graftlint: disable=step-host-sync
-                        np.asarray(p[:, idx:idx + 1, :kv_len]))  # graftlint: disable=step-host-sync
-                    for p in srcs)
+                        np.asarray(p))  # graftlint: disable=step-host-sync
+                    for p in self.cache.seq_slices(kv_len, row=idx))
         except Exception as e:
             # export must never kill the step loop: leave the sequence
             # running (the sender times out; the request finishes here)
@@ -2573,7 +2540,7 @@ class LLMEngine:
         self._prefix_cache[best_key] = entry
         # snapshots are truncated to prefix_cache_max_tokens; never seed
         # past what was actually stored
-        best = min(best, entry[0].shape[2])
+        best = min(best, entry[0].shape[self._cache_spec.seq_axis])
         best -= best % chunk
         if best <= 0:
             return 0, None
@@ -2612,10 +2579,7 @@ class LLMEngine:
         entry = self._prefix_cache.pop(key, None)
         if entry is None:
             keep = min(len(prompt), ce.prefix_cache_max_tokens)
-            planes = [cache1.k[:, :, :keep], cache1.v[:, :, :keep]]
-            if cache1.k_scale is not None:
-                planes += [cache1.k_scale[:, :, :keep],
-                           cache1.v_scale[:, :, :keep]]
+            planes = cache1.seq_slices(keep)
             for p in planes:
                 try:
                     p.copy_to_host_async()
@@ -2932,6 +2896,69 @@ class LLMEngine:
                              if span is not None else 0))
         self.flight.record("finish", step=self._step_idx, request_id=rid,
                            reason=reason, n_generated=n_generated)
+
+    def _init_moe_counters(self, m) -> None:
+        """Counters of a family with routed experts. Its forward adds
+        to a small int32 leaf carried with the cache (`KVCache.stats`,
+        order `ops/moe_routed.STATS`) inside the decode and prefill
+        programs; nothing is fetched in a step. The leaf is read when
+        the registry is rendered (a scrape hook) and the counters move
+        by what it gained since the last read."""
+        import weakref
+
+        self._m_moe_assign = m.counter(
+            "bigdl_tpu_moe_assignments_total",
+            "Token-expert choices of the routed layers, by whether the "
+            "chosen expert is held on this chip.", labelnames=("held",))
+        self._m_moe_hit = m.counter(
+            "bigdl_tpu_moe_experts_hit_total",
+            "Held experts that at least one token chose, summed over "
+            "routed layers and program runs.")
+        self._m_moe_layer_steps = m.counter(
+            "bigdl_tpu_moe_layer_steps_total",
+            "Routed layers run, summed over program runs (decode steps "
+            "and prefill chunks).")
+        for v in ("yes", "no"):
+            self._m_moe_assign.labels(v)
+        self._moe_lock = threading.Lock()
+        with self._moe_lock:
+            self._moe_seen = np.zeros((4,), np.uint32)
+        ref = weakref.ref(self)
+
+        def hook():
+            eng = ref()
+            if eng is None:
+                return False
+            eng._scrape_moe_stats()
+            return True
+
+        m.add_scrape_hook(hook)
+
+    def _scrape_moe_stats(self) -> None:
+        """Read the device tally and move the counters. Any thread: the
+        leaf may be donated to a step that is being dispatched this
+        instant, whose result then replaces it; read again."""
+        vals = None
+        for _ in range(8):
+            stats = getattr(self.cache, "stats", None)
+            if stats is None:
+                return
+            try:
+                # audited: at scrape time only, never in a step
+                vals = np.asarray(stats).astype(np.uint32)  # graftlint: disable=step-host-sync
+                break
+            except RuntimeError:
+                time.sleep(0.002)
+        if vals is None:
+            return
+        with self._moe_lock:
+            # uint32 differences: the int32 tally may wrap
+            d = (vals - self._moe_seen).astype(np.uint32)
+            self._moe_seen = vals
+        self._m_moe_assign.labels("yes").inc(float(d[0]))
+        self._m_moe_assign.labels("no").inc(float(d[1]))
+        self._m_moe_hit.inc(float(d[2]))
+        self._m_moe_layer_steps.inc(float(d[3]))
 
     def _update_gauges(self) -> None:
         self._m_occupancy.set(sum(1 for s in self.slots if s.active))

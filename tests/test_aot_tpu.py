@@ -1002,3 +1002,80 @@ def test_llama70b_qlora_step_tp4_fits_v5e_mesh(v5e, aot_flags):
     per_chip = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
                 + ma.output_size_in_bytes)
     assert per_chip < 16e9, f"{per_chip / 1e9:.2f} GB exceeds one v5e"
+
+
+# ------------------------------------------------ DeepSeek-V2 (PR 28)
+
+def test_mla_decode_attention_compiles_on_the_stack_in_place(v5e, aot_flags):
+    """128 query heads over the 576-wide latent rows of 32 slots x 4096
+    positions, the [20, ...] stack as operand and the layer a
+    prefetched scalar: a Mosaic call, and no copy of a layer's slab
+    (temp bytes stay far under one layer's 151 MB)."""
+    from bigdl_tpu.ops.pallas.mla_attention import (
+        mla_decode_attention_pallas)
+
+    dev = v5e.devices[0]
+    b, h, c, r, s, layers = 32, 128, 512, 64, 4096, 20
+    bf = jnp.bfloat16
+    comp = _compile(
+        lambda qc, qpe, lat, pos, lyr: mla_decode_attention_pallas(
+            qc, qpe, lat, pos, 192 ** -0.5, layer=lyr),
+        _sds(jax.ShapeDtypeStruct((b, h, c), bf), dev),
+        _sds(jax.ShapeDtypeStruct((b, h, r), bf), dev),
+        _sds(jax.ShapeDtypeStruct((layers, b, c + r, s), bf), dev),
+        _sds(jax.ShapeDtypeStruct((b,), jnp.int32), dev),
+        _sds(jax.ShapeDtypeStruct((), jnp.int32), dev))
+    assert _has_mosaic_call(comp)
+    mem = comp.memory_analysis()
+    # the chip stores [.., 576, 4096] bf16 without padding
+    assert mem.argument_size_in_bytes < layers * b * (c + r) * s * 2 * 1.01
+    assert mem.temp_size_in_bytes < 16 * 2 ** 20
+
+
+def test_latent_append_compiles_in_place(v5e, aot_flags):
+    from bigdl_tpu.ops.pallas.mla_attention import latent_append_pallas
+
+    dev = v5e.devices[0]
+    stack = jax.ShapeDtypeStruct((20, 32, 576, 4096), jnp.bfloat16)
+    comp = jax.jit(
+        lambda st, new, pos, lyr: latent_append_pallas(st, lyr, new, pos),
+        donate_argnums=(0,)).lower(
+        _sds(stack, dev),
+        _sds(jax.ShapeDtypeStruct((32, 576), jnp.bfloat16), dev),
+        _sds(jax.ShapeDtypeStruct((32,), jnp.int32), dev),
+        _sds(jax.ShapeDtypeStruct((), jnp.int32), dev)).compile()
+    assert _has_mosaic_call(comp)
+    mem = comp.memory_analysis()
+    assert mem.alias_size_in_bytes >= 20 * 32 * 576 * 4096 * 2
+    assert mem.temp_size_in_bytes < 16 * 2 ** 20
+
+
+@pytest.mark.parametrize("name,t,shared,k,n", [
+    ("moe_routed_decode", 32, True, 5120, 1536),
+    ("moe_routed_decode", 32, False, 1536, 5120),
+    # the benchmark's layer check decodes 8 one-token slots (one tile)
+    ("moe_routed_decode", 16, True, 5120, 1536),
+    ("moe_routed_decode", 16, False, 1536, 5120),
+    ("moe_routed_prefill", 128, False, 5120, 1536),
+    ("moe_routed_prefill", 128, False, 1536, 5120),
+])
+def test_routed_expert_kernel_compiles_on_the_layer_stack(
+        v5e, aot_flags, name, t, shared, k, n):
+    """The routed kernel at the published expert widths over a [19, 20,
+    ...] int4 stack addressed by layer: no copy of a layer's experts."""
+    from bigdl_tpu.ops.pallas.moe_routed import routed_expert_matmul
+    from bigdl_tpu.ops.probing import quant_struct, stacked_struct
+
+    dev = v5e.devices[0]
+    tiles = 20 if name.endswith("decode") else 32
+    w = stacked_struct(stacked_struct(quant_struct(k, n, "sym_int4"), 20), 19)
+    x = jax.ShapeDtypeStruct((1 if shared else tiles, t, k), jnp.bfloat16)
+    comp = _compile(
+        lambda xx, ww, te, na, lyr: routed_expert_matmul(
+            xx, ww, te, na, lyr, name=name, shared_x=shared),
+        _sds(x, dev), _sds(w, dev),
+        _sds(jax.ShapeDtypeStruct((tiles,), jnp.int32), dev),
+        _sds(jax.ShapeDtypeStruct((), jnp.int32), dev),
+        _sds(jax.ShapeDtypeStruct((), jnp.int32), dev))
+    assert _has_mosaic_call(comp)
+    assert comp.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
