@@ -89,9 +89,9 @@ MAX_SEQ = 2048
 CONFIG_TIMEOUT_S = int(os.environ.get("BENCH_CONFIG_TIMEOUT_S", "900"))
 
 # (label, flag overrides) — the dispatch configurations to A/B on TPU.
-# "pallas+gemv" is the shipped default: Pallas kernels at decode-class M,
-# XLA matmul above matmul_pallas_max_m (prefill). "pallas-all-m" forces
-# the dequant kernel at every M to re-check that threshold on chip.
+# "pallas+gemv" is the shipped default: Pallas kernels up to
+# matmul_pallas_max_m rows (decode and prefill chunks), the XLA matmul
+# above. "pallas-all-m" forces the dequant kernel at every M.
 AB_CONFIGS = [
     ("pallas+gemv", dict(matmul_backend="auto", attention_backend="auto",
                          matmul_gemv="auto")),

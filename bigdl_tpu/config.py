@@ -38,12 +38,16 @@ class RuntimeFlags:
     # A/B switch
     matmul_gemv: str = "auto"
     # In "auto" matmul dispatch, batch rows above this go to the XLA
-    # matmul instead of the Pallas dequant kernel: the in-kernel dequant
-    # is VPU-bound, so at MXU-bound (prefill-class) M the
-    # dequantize-then-matmul XLA plan is expected to win. The threshold
-    # has no measurement on today's code (ROADMAP S5). Forced "pallas"
-    # mode ignores this.
-    matmul_pallas_max_m: int = 128
+    # dequantize-then-dot plan instead of the Pallas dequant kernel: the
+    # crossover measured on a v5e (tools/qmatmul_ab.py; PERF.md 6, PR 29:
+    # sym_int4 in the int4-dtype layout, device time per layer of
+    # Mistral-7B's four linears, kernel / XLA): 0.92 / 2.22 ms at 256
+    # rows, 1.62 / 2.36 at 512, 3.10 / 3.50 at 1024, 5.88 / 5.86 at 2048,
+    # 23.3 / 21.3 at 8192. The kernel dequantizes a weight tile once per
+    # 256 rows, in VMEM; XLA once per call, through float32 and bf16
+    # copies of the layer in HBM, then runs the MXU at its peak. Forced
+    # "pallas" mode ignores this.
+    matmul_pallas_max_m: int = 1024
     # MoE prefill dispatch: "auto" (sorted ragged kernel on TPU, dense
     # combine elsewhere), "ragged" (force, incl. interpret), "dense"
     moe_dispatch: str = "auto"
@@ -116,7 +120,7 @@ class RuntimeFlags:
                 "BIGDL_TPU_ATTENTION_BACKEND", "auto"),
             matmul_gemv=os.environ.get("BIGDL_TPU_MATMUL_GEMV", "auto"),
             matmul_pallas_max_m=int(os.environ.get(
-                "BIGDL_TPU_MATMUL_PALLAS_MAX_M", "128")),
+                "BIGDL_TPU_MATMUL_PALLAS_MAX_M", "1024")),
             moe_dispatch=os.environ.get("BIGDL_TPU_MOE_DISPATCH", "auto"),
             mxu_layout=os.environ.get("BIGDL_TPU_MXU_LAYOUT", "auto"),
             prepack=_tristate_env("BIGDL_TPU_PREPACK",

@@ -6,13 +6,17 @@ TPU-native equivalent of the reference's dequant-matmul kernels
 low_bit_linear.py:418-453).
 
 Two execution paths:
-- **XLA fallback** (`_q_matmul_xla`): dequantize to x.dtype then `jnp.dot`.
-  Works on any backend (CPU tests, interpret mode). XLA fuses the dequant
-  into the matmul's operand read on TPU reasonably well for prefill shapes.
+- **XLA fallback** (`_q_matmul_xla`): dequantize to bf16 then `jnp.dot`.
+  Works on any backend (CPU tests, interpret mode). On a v5e XLA fuses
+  the dequant into the dot's operand for weights up to about 25 M
+  elements; a larger one (an MLP projection) it writes to HBM as float32
+  and again as bf16 before the dot (PERF.md 6, PR 29).
 - **Pallas kernel** (`bigdl_tpu.ops.pallas.dequant_matmul`): streams the
-  *packed* int4/int8 blocks HBM->VMEM and unpacks in-kernel, so decode
-  (GEMV-like, memory-bound) reads ~K*N/2 bytes instead of 2*K*N. Selected
-  automatically on TPU for supported qtypes.
+  *packed* int4/int8 blocks HBM->VMEM and dequantizes in-kernel, so decode
+  (GEMV-like, memory-bound) reads ~K*N/2 bytes instead of 2*K*N and a
+  prefill chunk's GEMM writes no dense copy of the weights. Selected
+  automatically on TPU for supported qtypes, for rows up to
+  `RuntimeFlags.matmul_pallas_max_m`.
 
 The public entry is `q_matmul(x, w)` where `w` is a QTensor of logical shape
 [K, N] (contraction-major; see ops/quant.py) and x is [..., K].
@@ -239,9 +243,11 @@ def _q_matmul_dispatch(x: jax.Array, w: QTensor, be: str) -> jax.Array:
         use_pallas = (w.qtype in _PALLAS_QTYPES and on_tpu
                       and not under_spmd(x, *jax.tree_util.tree_leaves(w)))
         if be == "auto" and use_pallas:
-            # prefill-class M: the dequant kernel is VPU-bound while the
-            # XLA dequantize-then-matmul plan rides the MXU (see
-            # RuntimeFlags.matmul_pallas_max_m)
+            # rows past the crossover measured on the chip: the kernel
+            # dequantizes each weight tile once per 256 rows, the XLA
+            # plan once per call and then runs the MXU at its peak (1024
+            # rows: 3.10 against 3.50 ms a Mistral layer, 2048: a tie,
+            # 8192: 23.3 against 21.3; RuntimeFlags.matmul_pallas_max_m)
             m = _rows(x)
             use_pallas = m <= flags().matmul_pallas_max_m
             if use_pallas:
@@ -269,9 +275,9 @@ def _q_matmul_dispatch(x: jax.Array, w: QTensor, be: str) -> jax.Array:
                 if be == "pallas":
                     raise
         if on_tpu:
-            # XLA by design (prefill-class M, GSPMD-sharded operands, a
-            # qtype or tiling the kernels do not cover): a dispatch
-            # rule, counted apart from probe outcomes
+            # XLA by design (rows past the crossover, GSPMD-sharded
+            # operands, a qtype or tiling the kernels do not cover): a
+            # dispatch rule, counted apart from probe outcomes
             from bigdl_tpu.ops.probing import record_dispatch_rule
 
             record_dispatch_rule("matmul")

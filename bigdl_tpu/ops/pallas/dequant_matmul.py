@@ -18,6 +18,10 @@ Layout contract (see ops/quant.py):
   int8: data int8 [Kp, N]
 
 Grid: (M/bm, N/bn, K/bk), K innermost, f32 accumulation in VMEM scratch.
+At a prefill chunk's 256 rows (one row tile) each weight tile is read
+from HBM once, as packed codes, and dequantized once: XLA's plan for the
+same linear writes the layer to HBM as float32 and again as bf16 first
+(per-shape chip table: PERF.md section 6, PR 29).
 """
 
 from __future__ import annotations
@@ -34,9 +38,21 @@ from bigdl_tpu.ops.codebooks import CODEBOOKS
 
 
 # generic grid is (M/bm, N/bn, K/bk): M and N tiles are independent,
-# only the K sweep carries the accumulator
+# only the K sweep carries the accumulator. The scoped-VMEM limit is
+# raised over Mosaic's 16 MiB default: a (2048, 512) weight tile lives
+# there as int4, float32 and bf16 at once (about 14 MB with the x tile
+# and the accumulator; a v5e has 128 MiB)
 _GENERIC_SEMANTICS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary"))
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=64 * 1024 * 1024)
+
+# `_matmul_tiles` budget of the generic path: the largest streaming
+# tile it admits at bm = 256 is (2048, 512). On the chip (my chip run,
+# PR 29; [4096, 28672] at 256 rows) the kernel took 0.393 ms at the
+# 4 MB budget's (1024, 512) and 0.370 ms at (2048, 512); the MXU's
+# least is 0.305
+_GENERIC_BUDGET = 8 * 1024 * 1024
+_GENERIC_BK = (2048, 1024, 512, 256, 128, 64, 32)
 
 
 def _pick_tile(dim: int, candidates) -> int:
@@ -106,35 +122,62 @@ def _accumulate(x_tile, w, out_ref, acc_ref, nk, k_axis: int = 2):
         out_ref[:] = acc_ref[:].astype(out_ref.dtype)
 
 
+def _gemm_step(x_ref, out_ref, acc_ref, nk, dequant):
+    """One K step of the generic grid: `dequant()` gives the weight tile
+    as bf16 [bk, bn]. The accumulator is zeroed BEFORE the tile is
+    dequantized: a `pl.when` between the dequant and the dot ends the
+    basic block, and Mosaic then runs the VPU's dequant and the MXU's
+    passes one after the other instead of interleaved ([4096, 28672] at
+    256 rows, (1024, 512) tiles: 0.600 ms that way round, 0.393 ms this;
+    my chip run, PR 29)."""
+    k = pl.program_id(2)
+
+    @pl.when(k == 0)
+    def _():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    acc_ref[:] += jax.lax.dot_general(
+        x_ref[:], dequant(), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+    @pl.when(k == nk - 1)
+    def _():
+        out_ref[:] = acc_ref[:].astype(out_ref.dtype)
+
+
 def _kernel_4bit(x_ref, data_ref, scale_ref, *rest, block, kind, codebook,
                  bk, bn, nk):
     if kind == "asym":
         zero_ref, out_ref, acc_ref = rest
-        zero = zero_ref[:]
     else:
-        (out_ref, acc_ref), zero = rest, None
-    codes = _unpack_tile(data_ref[:], block, bk, bn)
-    w = _dequant_tile(codes, scale_ref[:], zero, kind, codebook, bk, bn)
-    _accumulate(x_ref[:], w, out_ref, acc_ref, nk)
+        (out_ref, acc_ref), zero_ref = rest, None
+
+    def dequant():
+        codes = _unpack_tile(data_ref[:], block, bk, bn)
+        zero = zero_ref[:] if zero_ref is not None else None
+        return _dequant_tile(codes, scale_ref[:], zero, kind, codebook,
+                             bk, bn)
+
+    _gemm_step(x_ref, out_ref, acc_ref, nk, dequant)
 
 
-def _kernel_int8(x_ref, data_ref, scale_ref, out_ref, acc_ref, *,
-                 block, bk, bn, nk):
-    s = scale_ref[:].astype(jnp.float32)[:, None, :]
-    vals = data_ref[:].astype(jnp.float32).reshape(bk // block, block, bn) * s
-    w = vals.reshape(bk, bn).astype(jnp.bfloat16)
-    _accumulate(x_ref[:], w, out_ref, acc_ref, nk)
-
-
-def _kernel_i4(x_ref, data_ref, scale_ref, out_ref, acc_ref, *,
-               block, bk, bn, nk):
-    """Generic-tile body for the MXU (int4-dtype) layout: native int4
-    load, one convert, per-weight scale — no nibble unpack chain."""
+def _scaled_codes(data_ref, scale_ref, block, bk, bn):
+    """Integer codes [bk, bn] x their block scales, in float32, rounded
+    to bf16: the arithmetic of `_q_matmul_xla`'s dequantize."""
     s = scale_ref[:].astype(jnp.float32)[:, None, :]
     codes = data_ref[:].astype(jnp.int8).astype(jnp.float32)
-    w = (codes.reshape(bk // block, block, bn) * s) \
+    return (codes.reshape(bk // block, block, bn) * s) \
         .reshape(bk, bn).astype(jnp.bfloat16)
-    _accumulate(x_ref[:], w, out_ref, acc_ref, nk)
+
+
+def _kernel_int(x_ref, data_ref, scale_ref, out_ref, acc_ref, *,
+                block, bk, bn, nk):
+    """Generic-tile body for integer-dtype codes: sym_int8, and sym_int4
+    in the MXU (int4-dtype) layout — native int4 load, one convert,
+    per-weight scale, no nibble unpack chain."""
+    _gemm_step(x_ref, out_ref, acc_ref, nk,
+               lambda: _scaled_codes(data_ref, scale_ref, block, bk, bn))
 
 
 def _gemv_kernel(x_ref, data_ref, scale_ref, *rest, block, kind, codebook,
@@ -354,6 +397,24 @@ def _gemv_tiles(qt, kp: int, n: int, mp: int = 16):
                          bm=mp)
 
 
+def _generic_tiles(qt, kp: int, n: int, bm: int):
+    # joint (bk, bn) search keeps the working set (data tile + unpacked
+    # w tile + x tile + accumulator) in VMEM without sacrificing
+    # scale-plane legality
+    cands = [*_GENERIC_BK, kp]
+    if bm <= GEMV_MAX_M:
+        # decode rows that the GEMV could not tile: the tiles (and, with
+        # no legal one, the XLA-fused plan) they had
+        return _matmul_tiles(qt, kp, n, cands, bm=bm)
+    # a K that no smaller tile divides legally (ChatGLM2's 13696 = 2^7 x
+    # 107: no bk with an 8-row scale block) takes ONE full-K tile under
+    # twice the budget, (13696, 128): 0.224 ms against XLA's 0.689 at 256
+    # rows (my chip run, PR 29)
+    return (_matmul_tiles(qt, kp, n, cands, budget=_GENERIC_BUDGET, bm=bm)
+            or _matmul_tiles(qt, kp, n, [kp], budget=2 * _GENERIC_BUDGET,
+                             bm=bm))
+
+
 _gemv_probe_cache: set = set()
 
 # decode-GEMV M ceiling: the serving engine's decode batch. One padded
@@ -408,8 +469,7 @@ def matmul_kernel_compiles(qtype: str, m: int, kp: int, n: int,
     class, not the raw M."""
     qt = get_qtype(qtype)
     bm, mp = _generic_bm(m)
-    tiles = _matmul_tiles(qt, kp, n,
-                          [2048, 1024, 512, 256, 128, 64, 32, kp], bm=bm)
+    tiles = _generic_tiles(qt, kp, n, bm)
     if tiles is None:
         return False
     from bigdl_tpu.config import flags as _flags
@@ -587,13 +647,20 @@ def q_matmul_pallas_impl(x: jax.Array, w: QTensor, *,
     return y.reshape(*batch_shape, n)
 
 
+# generic-path row tile ceiling: a prefill chunk's rows
+# (EngineConfig.prefill_chunk) in one tile
+GEMM_MAX_BM = 256
+
+
 def _generic_bm(m: int):
-    """Generic-path row tile class: (bm, mp) with mp the padded M."""
-    bm = _pick_tile(m, [256, 128, 64, 32, 16])
-    if bm:
-        return bm, m
-    mp = m + ((-m) % 16)
-    return (_pick_tile(mp, [256, 128, 64, 32, 16]) or mp), mp
+    """Generic-path row tile class: (bm, mp) with mp the padded M. Every
+    row tile dequantizes each weight tile again, so M is covered by as
+    FEW tiles of at most GEMM_MAX_BM rows as will do (sublane multiples
+    of 16): 200 rows are one tile of 208, not thirteen of 16."""
+    tiles = -(-m // GEMM_MAX_BM)
+    bm = -(-m // tiles)
+    bm += -bm % 16
+    return bm, tiles * bm
 
 
 def _q_matmul_generic(x2: jax.Array, w: QTensor, qt, m: int, kp: int,
@@ -606,11 +673,7 @@ def _q_matmul_generic(x2: jax.Array, w: QTensor, qt, m: int, kp: int,
     if mp != m:
         x2 = jax.lax.pad(x2, jnp.zeros((), x2.dtype),
                          ((0, mp - m, 0), (0, 0, 0)))
-    # joint (bk, bn) search keeps the working set (data tile + unpacked
-    # w tile + x tile + accumulator) in VMEM without sacrificing
-    # scale-plane legality
-    tiles = _matmul_tiles(qt, kp, n,
-                          [2048, 1024, 512, 256, 128, 64, 32, kp], bm=bm)
+    tiles = _generic_tiles(qt, kp, n, bm)
     if tiles is None:
         raise NotImplementedError(f"shapes not tileable: K={kp} N={n}")
     bk, bn = tiles
@@ -624,71 +687,34 @@ def _q_matmul_generic(x2: jax.Array, w: QTensor, qt, m: int, kp: int,
     out_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
     out_shape = jax.ShapeDtypeStruct((mp, n), out_dtype)
     scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
-    gemm_name = f"qmatmul_gemm_{w.qtype}"    # the kernel's trace name
 
-    if w.data.dtype == jnp.int4:
-        data_spec = pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))
-        kernel = functools.partial(_kernel_i4, block=b, bk=bk, bn=bn, nk=nk)
-        y = pl.pallas_call(
-            kernel,
-            name=gemm_name,
-            grid=grid,
-            in_specs=[x_spec, data_spec, scale_spec],
-            out_specs=out_spec,
-            out_shape=out_shape,
-            scratch_shapes=scratch,
-            interpret=interpret,
-            compiler_params=_GENERIC_SEMANTICS,
-        )(x2, w.data, w.scale)
-    elif qt.storage_bits == 4:
-        data_spec = pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j))
+    operands = [x2, w.data, w.scale]
+    in_specs = [x_spec, None, scale_spec]
+    if w.data.dtype in (jnp.int4, jnp.int8):    # integer codes, unpacked
+        in_specs[1] = pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))
+        kernel = functools.partial(_kernel_int, block=b, bk=bk, bn=bn, nk=nk)
+    else:                                       # split-block nibbles
+        in_specs[1] = pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j))
         codebook = None
         if qt.kind == "codebook":
             codebook = [float(v) for v in CODEBOOKS[qt.codebook]]
+        kernel = functools.partial(
+            _kernel_4bit, block=b, kind=qt.kind, codebook=codebook,
+            bk=bk, bn=bn, nk=nk)
         if qt.kind == "asym":
-            kernel = functools.partial(
-                _kernel_4bit, block=b, kind="asym", codebook=None,
-                bk=bk, bn=bn, nk=nk)
-            y = pl.pallas_call(
-                kernel,
-                name=gemm_name,
-                grid=grid,
-                in_specs=[x_spec, data_spec, scale_spec, scale_spec],
-                out_specs=out_spec,
-                out_shape=out_shape,
-                scratch_shapes=scratch,
-                interpret=interpret,
-                compiler_params=_GENERIC_SEMANTICS,
-            )(x2, w.data, w.scale, w.zero)
-        else:
-            kernel = functools.partial(
-                _kernel_4bit, block=b, kind=qt.kind, codebook=codebook,
-                bk=bk, bn=bn, nk=nk)
-            y = pl.pallas_call(
-                kernel,
-                name=gemm_name,
-                grid=grid,
-                in_specs=[x_spec, data_spec, scale_spec],
-                out_specs=out_spec,
-                out_shape=out_shape,
-                scratch_shapes=scratch,
-                interpret=interpret,
-                compiler_params=_GENERIC_SEMANTICS,
-            )(x2, w.data, w.scale)
-    else:  # int8 sym
-        data_spec = pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))
-        kernel = functools.partial(_kernel_int8, block=b, bk=bk, bn=bn, nk=nk)
-        y = pl.pallas_call(
-            kernel,
-            name=gemm_name,
-            grid=grid,
-            in_specs=[x_spec, data_spec, scale_spec],
-            out_specs=out_spec,
-            out_shape=out_shape,
-            scratch_shapes=scratch,
-            interpret=interpret,
-            compiler_params=_GENERIC_SEMANTICS,
-        )(x2, w.data, w.scale)
+            operands.append(w.zero)
+            in_specs.append(scale_spec)
+    y = pl.pallas_call(
+        kernel,
+        name=f"qmatmul_gemm_{w.qtype}",    # the kernel's trace name
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_spec,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        interpret=interpret,
+        compiler_params=_GENERIC_SEMANTICS,
+    )(*operands)
 
     if mp != m:
         y = y[:m]

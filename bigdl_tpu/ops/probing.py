@@ -14,8 +14,9 @@ compiler's message (and counts one `outcome="fallback"` first, so the
 scrape shows it). An earlier contract logged a warning and pinned the
 geometry to XLA; the first full chip bench then ran 0 of 4 kernel
 families and reported success. Shapes for which XLA is the designed
-choice (prefill-class M, GSPMD-sharded operands) never reach a probe —
-dispatch counts them with `record_dispatch_rule` under their own label.
+choice (rows past the measured crossover, GSPMD-sharded operands) never
+reach a probe — dispatch counts them with `record_dispatch_rule` under
+their own label.
 
 The probe is AOT lower+compile from abstract `ShapeDtypeStruct`s:
 nothing executes, no device buffers are allocated next to a resident
@@ -55,7 +56,7 @@ def record_probe_result(kernel: str, ok: bool) -> None:
 
 
 def record_dispatch_rule(kernel: str) -> None:
-    """Count a dispatch that took XLA BY DESIGN (M above
+    """Count a dispatch that took XLA BY DESIGN (rows above
     matmul_pallas_max_m, operands sharded under GSPMD) — a rule, not a
     probe outcome, so `outcome="fallback"` keeps meaning "the compiler
     refused a kernel". Trace-time counts, like the probes."""

@@ -147,6 +147,40 @@ def test_dequant_generic_i4_compiles(v5e, aot_flags):
     assert _has_mosaic_call(comp)
 
 
+# [K, N] of every quantized linear a prefill chunk (M = 256) meets in
+# the benchmark's three configurations
+PREFILL_CHUNK_LINEARS = [
+    (4096, 6144), (4096, 4096), (4096, 28672), (14336, 4096),   # Mistral-7B
+    (4096, 4608), (4096, 27392), (13696, 4096),                 # ChatGLM2-6B
+    (5120, 1536), (1536, 24576), (5120, 640), (16384, 5120),    # DeepSeek-V2
+    (5120, 3072), (3072, 5120), (5120, 12288), (12288, 5120),
+]
+
+
+@pytest.mark.parametrize("k,n", PREFILL_CHUNK_LINEARS + [(32224, 4096)])
+def test_dequant_gemm_compiles_at_a_prefill_chunk(v5e, aot_flags, k, n):
+    """The int4-dtype GEMM (`qmatmul_gemm_sym_int4`) at M = 256, as auto
+    dispatch sends a chunk's linears to it. ChatGLM2's K = 13696 = 2^7 x
+    107 (no bk with an 8-row scale block divides it) takes one full-K
+    tile. A K with no legal tiling at all (32224 = 2^5 x 1007: the
+    full-K tile is over the VMEM budget) is XLA's by RULE: the probe
+    says so before anything compiles."""
+    from bigdl_tpu.ops.matmul import q_matmul
+    from bigdl_tpu.ops.pallas.dequant_matmul import matmul_kernel_compiles
+    from bigdl_tpu.ops.probing import quant_struct
+
+    dev = v5e.devices[0]
+    wq = quant_struct(k, n, "sym_int4", mxu=True)
+    x = jax.ShapeDtypeStruct((1, 256, k), jnp.bfloat16)
+    comp = _compile(lambda xx, ww: q_matmul(xx, ww),
+                    _sds(x, dev), _sds(wq, dev))
+    if k == 32224:
+        assert not matmul_kernel_compiles("sym_int4", 256, k, n, mxu=True)
+        assert not _has_mosaic_call(comp)
+    else:
+        assert "qmatmul_gemm_sym_int4" in comp.as_text()
+
+
 @pytest.mark.parametrize("k,n", [
     (4096, 1024),    # q/k/v column shard (also o-proj local K)
     (1024, 4096),    # o-proj row shard
@@ -532,6 +566,30 @@ def test_llama7b_decode_fp8_cache_compiles(v5e, aot_flags):
     assert _has_mosaic_call(comp)
 
 
+def _mistral7b_engine(b, s):
+    """`LLMEngine` over a registry-built Mistral-7B at published width,
+    merged + prepacked like a from_pretrained load. Shapes only: the
+    engine never sees an array."""
+    from bigdl_tpu.models import llama as M
+    from bigdl_tpu.models.registry import get_family
+    from bigdl_tpu.ops.quant import prepack_tree
+    from bigdl_tpu.serving import EngineConfig, LLMEngine
+    from bigdl_tpu.smoke import MISTRAL_7B_HF
+    from bigdl_tpu.utils.testing import random_llama_params
+
+    family = get_family("MistralForCausalLM", MISTRAL_7B_HF)
+    cfg = family.config_from_hf(MISTRAL_7B_HF)
+
+    class Model:
+        params = jax.eval_shape(lambda: prepack_tree(M.merge_projections(
+            random_llama_params(cfg, "sym_int4"), cfg))[0])
+        config, hf_config, qtype = cfg, MISTRAL_7B_HF, "sym_int4"
+
+    Model.family = family
+    return LLMEngine(Model, EngineConfig(max_batch=b, max_seq=s,
+                                         sentinel=False, quality=False))
+
+
 @pytest.mark.parametrize("b", [8, 32])
 def test_engine_decode_resident_step_compiles(v5e, aot_flags, b):
     """One whole serving decode step AS THE ENGINE BUILDS IT
@@ -551,26 +609,9 @@ def test_engine_decode_resident_step_compiles(v5e, aot_flags, b):
     serving cells' device time (PERF.md, PR 26)."""
     import re
 
-    from bigdl_tpu.models import llama as M
-    from bigdl_tpu.models.registry import get_family
-    from bigdl_tpu.ops.quant import prepack_tree
-    from bigdl_tpu.serving import EngineConfig, LLMEngine
-    from bigdl_tpu.smoke import MISTRAL_7B_HF
-    from bigdl_tpu.utils.testing import random_llama_params
-
     dev = v5e.devices[0]
-    family = get_family("MistralForCausalLM", MISTRAL_7B_HF)
-    cfg = family.config_from_hf(MISTRAL_7B_HF)
-
-    class Model:
-        params = jax.eval_shape(lambda: prepack_tree(M.merge_projections(
-            random_llama_params(cfg, "sym_int4"), cfg))[0])
-        config, hf_config, qtype = cfg, MISTRAL_7B_HF, "sym_int4"
-
-    Model.family = family
     s = 2048
-    eng = LLMEngine(Model, EngineConfig(max_batch=b, max_seq=s,
-                                        sentinel=False, quality=False))
+    eng = _mistral7b_engine(b, s)
     i32 = _sds(jax.ShapeDtypeStruct((b,), jnp.int32), dev)
     f32 = _sds(jax.ShapeDtypeStruct((b,), jnp.float32), dev)
     comp = eng._decode_resident.lower(
@@ -611,6 +652,34 @@ def test_engine_decode_resident_step_compiles(v5e, aot_flags, b):
         assert op != "copy" and not fed, (
             f"{name} ({op}) writes a layer-sized operand {fed} into the "
             f"stack")
+
+
+def test_engine_prefill_chunk_dequantizes_in_vmem(v5e, aot_flags):
+    """One whole prefill chunk AS THE ENGINE BUILDS IT (`engine_prefill`,
+    256 rows into a private 1024-position cache) for the same
+    Mistral-7B: every int4 linear of the layer scan is the Pallas GEMM
+    that dequantizes a weight tile in VMEM, and no float32 (or bf16)
+    copy of a layer's weights is written to HBM. On the chip those
+    copies were 78 of a chunk's 112 ms (PERF.md, PR 29)."""
+    import re
+
+    from bigdl_tpu.ops.kvcache import init_cache_spec
+
+    dev = v5e.devices[0]
+    eng = _mistral7b_engine(8, 2048)
+    chunk = eng._chunk
+    assert chunk == 256
+    cache1 = jax.eval_shape(lambda: init_cache_spec(
+        eng._cache_spec, 1, 1024, kv_cache_dtype=eng.kv_cache_dtype))
+    tokens = jax.ShapeDtypeStruct((1, chunk), jnp.int32)
+    txt = eng._prefill.lower(_sds(eng.params, dev), _sds(tokens, dev),
+                             _sds(cache1, dev)).compile().as_text()
+    # qkv, o, gate_up, down in the layer scan
+    assert txt.count("qmatmul_gemm_sym_int4") >= 4
+    dense = re.findall(
+        r"(?:f32|bf16)\[(?:1,)?(?:4096,6144|4096,4096|4096,28672|14336,4096"
+        r"|\d+,32,(?:6144|4096|28672))\]", txt)
+    assert not dense, f"a layer's weights are dequantized in HBM: {dense[:3]}"
 
 
 def test_vmapped_gemv_compiles(v5e, aot_flags):

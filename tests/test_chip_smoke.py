@@ -216,8 +216,8 @@ def test_cpu_auto_takes_xla_without_probing(monkeypatch):
 
 
 def test_xla_by_design_is_counted_apart_from_fallbacks(monkeypatch):
-    """Prefill-class M on a TPU is a dispatch RULE: its own label, no
-    probe, no `fallback`."""
+    """Rows past the measured crossover (`matmul_pallas_max_m`) on a TPU
+    are a dispatch RULE: their own label, no probe, no `fallback`."""
     from bigdl_tpu import config
     from bigdl_tpu.observability.metrics import default_registry
     from bigdl_tpu.ops import probing
@@ -229,8 +229,8 @@ def test_xla_by_design_is_counted_apart_from_fallbacks(monkeypatch):
     key = 'bigdl_tpu_kernel_probe_total{kernel="matmul",' \
           'outcome="xla_by_rule"}'
     n0 = default_registry().summary().get(key, 0)
-    y = q_matmul(jnp.ones((512, 256), jnp.bfloat16), _quant(256, 256))
-    assert y.shape == (512, 256)
+    y = q_matmul(jnp.ones((2048, 256), jnp.bfloat16), _quant(256, 256))
+    assert y.shape == (2048, 256)
     assert default_registry().summary()[key] == n0 + 1
 
 

@@ -69,30 +69,48 @@ def test_q_matmul_under_jit():
 
 
 def test_auto_dispatch_m_threshold(monkeypatch):
-    """Auto dispatch sends decode-class M to the Pallas dequant kernel and
-    prefill-class M to the XLA matmul (matmul_pallas_max_m; thresholds
-    from the first on-chip A/B — see RuntimeFlags docstring)."""
+    """Auto dispatch on a TPU target: decode rows, a prefill chunk's 256
+    rows and every row count up to the crossover measured on the chip
+    (`RuntimeFlags.matmul_pallas_max_m`) take the Pallas kernel; rows
+    above it (QLoRA's 8192-row forward) take the XLA
+    dequantize-then-dot plan and are counted as a rule. A forced backend
+    ignores the row count either way."""
     import bigdl_tpu.ops.pallas.dequant_matmul as dq
-    from bigdl_tpu.config import set_flags
+    import bigdl_tpu.ops.probing as probing
+    from bigdl_tpu.config import flags, set_flags
     from bigdl_tpu.ops.matmul import _q_matmul_xla
 
-    w = quantize(_rand((64, 64)) * 0.05, "sym_int4")
-    seen = []
+    w = quantize(_rand((64, 128)) * 0.05, "sym_int4")
+    seen, ruled = [], []
 
     def fake_impl(x, wq, **kw):
         seen.append(int(x.shape[0]))
         return _q_matmul_xla(x, wq)
 
     monkeypatch.setattr(dq, "q_matmul_pallas_impl", fake_impl)
-    set_flags(aot_target="tpu", matmul_pallas_max_m=128)
+    monkeypatch.setattr(probing, "record_dispatch_rule", ruled.append)
+    crossover = flags().matmul_pallas_max_m
+    assert 256 <= crossover < 8192
+    ones = lambda m: jnp.ones((m, 64), jnp.bfloat16)  # noqa: E731
+    set_flags(aot_target="tpu")
     try:
-        q_matmul(jnp.ones((8, 64), jnp.bfloat16), w)     # decode-class
-        q_matmul(jnp.ones((512, 64), jnp.bfloat16), w)   # prefill-class
-        # forced pallas ignores the threshold
-        q_matmul(jnp.ones((512, 64), jnp.bfloat16), w, backend="pallas")
+        for m in (8, 32, 200, 256, crossover):        # the kernel's
+            q_matmul(ones(m), w)
+        assert seen == [8, 32, 200, 256, crossover] and not ruled
+        for m in (crossover + 1, 8192):               # XLA's, by rule
+            q_matmul(ones(m), w)
+        assert seen == [8, 32, 200, 256, crossover]
+        assert ruled == ["matmul", "matmul"]
+        # forced backends ignore the row count
+        q_matmul(ones(8192), w, backend="pallas")
+        q_matmul(ones(8), w, backend="xla")
+        assert seen[-1] == 8192 and len(seen) == 6 and len(ruled) == 2
+        # the flag moves the crossover
+        set_flags(matmul_pallas_max_m=128)
+        q_matmul(ones(256), w)
+        assert len(seen) == 6 and len(ruled) == 3
     finally:
-        set_flags(aot_target=None, matmul_pallas_max_m=128)
-    assert seen == [8, 512]
+        set_flags(aot_target=None, matmul_pallas_max_m=crossover)
 
 
 @pytest.mark.parametrize("qtype", ["q2_k", "iq2_xxs", "iq1_s"])

@@ -20,13 +20,24 @@ def _rand(shape, seed=0):
 
 
 @pytest.mark.parametrize(
-    "qtype", ["sym_int4", "asym_int4", "nf4", "nf3", "fp4", "sym_int8"])
-@pytest.mark.parametrize("m", [1, 16, 64])
+    "qtype", ["sym_int4", "sym_int4:mxu", "asym_int4", "nf4", "nf3", "fp4",
+              "sym_int8"])
+@pytest.mark.parametrize("m", [1, 16, 64, 200, 256])
 def test_pallas_matches_xla(qtype, m):
+    """Decode rows (GEMV bodies), generic tiles, a prefill chunk's 256
+    rows (one `bm` = 256 row tile) and a ragged 200 (padded to 208) —
+    `sym_int4` in the canonical packing and, as `:mxu`, in the int4-dtype
+    layout a TPU load gives it."""
+    from bigdl_tpu.ops.quant import to_mxu_layout
+
+    qtype, _, layout = qtype.partition(":")
     k, n = 256, 128
     x = _rand((m, k), seed=1) * 0.3
     w = _rand((k, n), seed=2) * 0.1
     qt = quantize(w, qtype)
+    if layout == "mxu":
+        qt = to_mxu_layout(qt)
+        assert qt.data.dtype == jnp.int4
     got = q_matmul_pallas(x, qt, interpret=True)
     want = _q_matmul_xla(x, qt)
     assert got.shape == (m, n)
