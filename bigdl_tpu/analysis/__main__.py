@@ -1,6 +1,6 @@
 """graftlint CLI: ``python -m bigdl_tpu.analysis [options] [paths]``.
 
-Exit codes (bench_diff-style, usable as a raw CI gate):
+Exit codes (usable as a raw CI gate):
 
 * ``0`` — clean: no findings outside the baseline.
 * ``1`` — new findings (printed one per line, ``path:line: rule: ...``).

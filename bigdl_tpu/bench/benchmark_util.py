@@ -10,7 +10,7 @@ GenerationStats collector and reads device memory stats from JAX.
 Note on TPU timing: every host sync pays a dispatch + readback cost;
 `rest_cost_mean` measured around a host-step loop includes it. For
 device-only numbers use `timed_decode` (K steps inside one jit,
-differenced) — the same technique bench.py uses.
+differenced).
 """
 
 from __future__ import annotations

@@ -28,38 +28,16 @@ class RuntimeFlags:
     matmul_backend: str = "auto"
     # decode-attention dispatch, same values (ops/pallas/decode_attention)
     attention_backend: str = "auto"
-    # decode GEMV (M<=16) kernel variant: "auto" (MXU body when the
-    # weights carry the int4-dtype layout, else the standard body),
-    # "fold" (scale-folded body over the canonical packing), "mxuflat"
-    # (int4-dtype load + per-weight scale + one flat full-K MXU dot),
-    # "mxu8" (q8 activations against int4/int8 weights on the MXU's
-    # int8 path — 2x bf16 throughput, q8 rounding on activations),
-    # "off" (route small-M through the generic tiles) — the on-chip
-    # A/B switch
-    matmul_gemv: str = "auto"
-    # In "auto" matmul dispatch, batch rows above this go to the XLA
-    # dequantize-then-dot plan instead of the Pallas dequant kernel: the
-    # crossover measured on a v5e (tools/qmatmul_ab.py; PERF.md 6, PR 29:
-    # sym_int4 in the int4-dtype layout, device time per layer of
-    # Mistral-7B's four linears, kernel / XLA): 0.92 / 2.22 ms at 256
-    # rows, 1.62 / 2.36 at 512, 3.10 / 3.50 at 1024, 5.88 / 5.86 at 2048,
-    # 23.3 / 21.3 at 8192. The kernel dequantizes a weight tile once per
-    # 256 rows, in VMEM; XLA once per call, through float32 and bf16
-    # copies of the layer in HBM, then runs the MXU at its peak. Forced
-    # "pallas" mode ignores this.
-    matmul_pallas_max_m: int = 1024
     # MoE prefill dispatch: "auto" (sorted ragged kernel on TPU, dense
     # combine elsewhere), "ragged" (force, incl. interpret), "dense"
     moe_dispatch: str = "auto"
-    # sym_int4 weight storage at model load: "auto" (int4-dtype MXU
-    # layout on TPU — native Mosaic int4 loads instead of the VPU
-    # nibble-unpack chain; canonical split-block elsewhere), "on", "off"
-    mxu_layout: str = "auto"
     # load-time weight prepacking (ops/quant.prepack_tree): "auto"
     # (retile QTensor planes into the kernel layout when the target is
-    # TPU — subsumes mxu_layout), "on" (force the retile anywhere),
-    # "off" (keep the canonical split-block planes). Applied ONCE at
-    # checkpoint load; save_low_bit always writes canonical planes.
+    # TPU: sym_int4 to int4-dtype codes, which Mosaic loads natively
+    # instead of running the VPU nibble-unpack chain), "on" (force the
+    # retile anywhere), "off" (keep the canonical split-block planes).
+    # Applied ONCE at checkpoint load; save_low_bit always writes
+    # canonical planes.
     prepack: str = "auto"
     # resident single-dispatch decode step: fuse forward + sampling +
     # EOS bookkeeping into ONE tracked_jit per token so the serving
@@ -118,11 +96,7 @@ class RuntimeFlags:
             matmul_backend=os.environ.get("BIGDL_TPU_MATMUL_BACKEND", "auto"),
             attention_backend=os.environ.get(
                 "BIGDL_TPU_ATTENTION_BACKEND", "auto"),
-            matmul_gemv=os.environ.get("BIGDL_TPU_MATMUL_GEMV", "auto"),
-            matmul_pallas_max_m=int(os.environ.get(
-                "BIGDL_TPU_MATMUL_PALLAS_MAX_M", "1024")),
             moe_dispatch=os.environ.get("BIGDL_TPU_MOE_DISPATCH", "auto"),
-            mxu_layout=os.environ.get("BIGDL_TPU_MXU_LAYOUT", "auto"),
             prepack=_tristate_env("BIGDL_TPU_PREPACK",
                                   lambda s: resolve_prepack(s)),
             decode_resident=_tristate_env(

@@ -45,7 +45,7 @@ standard library — tests/test_observability.py enforces it):
   ``memory_report()`` rolls the snapshot plus the compile table's peak
   temp bytes into the bench JSON records.
 - ``roofline``: the analytical FLOPs / HBM-bytes cost model (single
-  source for ``bench.py``'s efficiency block, the engine's live
+  source for the offline efficiency block, the engine's live
   ``bigdl_tpu_roofline_util{phase}`` / ``decode_ideal_ms`` gauges and
   compile_watch's per-jit cost annotation). Chip peaks come from
   ``roofline.CHIP_PEAKS``, keyed by the device kind JAX reports; an

@@ -336,8 +336,8 @@ def reset_default_ledger() -> None:
 
 
 def memory_report(ledger: Optional[MemoryLedger] = None) -> dict:
-    """The bench-embeddable memory report: a ledger snapshot plus flat
-    headline scalars tools/bench_diff.py can compare across runs —
+    """The embeddable memory report: a ledger snapshot plus flat
+    headline scalars that compare across runs —
     ``hbm_static_total_bytes`` (registered allocations),
     ``hbm_device_peak_bytes`` (live peak, absent on CPU), and
     ``jit_peak_temp_bytes`` (largest per-executable scratch from the

@@ -22,8 +22,7 @@ roofline:
   decode path's live chosen-token NLL / entropy / top-1 margin. A
   trip becomes a ``quality_regression`` flight event + postmortem +
   bounded auto-capture and a nonzero
-  ``bigdl_tpu_quality_regression_total`` (zero-gated by
-  tools/bench_diff.py).
+  ``bigdl_tpu_quality_regression_total``.
 
 * **Golden Δppl baselines** — the refreshed per-format deltas from
   ACCURACY.md, exposed as :data:`GOLDEN_PPL_DELTA` /

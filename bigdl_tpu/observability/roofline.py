@@ -1,6 +1,6 @@
 """Analytical roofline cost model — the single source of truth for
-FLOPs / HBM-bytes math shared by ``bench.py`` (offline efficiency
-block), the serving engine (live ``bigdl_tpu_roofline_util{phase}`` /
+FLOPs / HBM-bytes math shared by the offline efficiency block
+(``efficiency``), the serving engine (live ``bigdl_tpu_roofline_util{phase}`` /
 ``decode_ideal_ms`` gauges), compile_watch (per-jit cost annotation)
 and the perf-regression sentinel.
 
@@ -61,8 +61,8 @@ CHIP_PEAKS: Dict[str, Tuple[float, float]] = {
 
 def chip_peaks(device_kind: Optional[str] = None) -> Tuple[float, float]:
     """(peak_bf16_tflops, peak_hbm_gbps) of ``device_kind``, default the
-    kind of this process's first device. One definition for the bench
-    floors, the efficiency block, bench_qlora and the live gauges. A
+    kind of this process's first device. One definition for the
+    efficiency block and the live gauges. A
     kind that is not in ``CHIP_PEAKS`` raises LookupError: a roofline
     share against another chip's peaks is not a number."""
     if device_kind is None:
@@ -79,8 +79,8 @@ def chip_peaks(device_kind: Optional[str] = None) -> Tuple[float, float]:
 
 def model_flops_per_token(cfg) -> int:
     """Forward matmul FLOPs per token (qkvo + gated mlp + lm_head; no
-    attention-over-cache term). Shared by the physics floors, the
-    efficiency block and bench_qlora so the cost model cannot drift.
+    attention-over-cache term). Shared by the efficiency block and the
+    live gauges so the cost model cannot drift.
     A family whose layers are not q/k/v/o plus one gated MLP (latent
     attention, routed experts) counts its own
     (`cfg.matmul_flops_per_token()`)."""
@@ -171,10 +171,9 @@ def prefill_costs(cfg, prompt_len: int,
 def efficiency(cfg, weight_bytes: int, prompt_len: int, steps: int,
                first_ms: float, next_ms: float,
                device_kind: Optional[str] = None) -> dict:
-    """MFU + HBM-roofline utilization — the exact
-    numbers ``bench.py`` prints in every headline record (it imports
-    this; ``tests/test_perf_observability.py`` asserts identity on the
-    r05 fixture so bench and live gauges cannot drift).
+    """MFU + HBM-roofline utilization of one measured (first, next)
+    token latency pair (``tests/test_perf_observability.py`` pins the
+    formulas on a fixture and asserts the live gauges agree).
 
     ``weight_bytes`` is measured from the live param pytree in the
     config subprocess and passed through. The KV term deliberately

@@ -7,8 +7,8 @@ hand-rolled in three places with three subtly different behaviors:
   percentile (numpy's default method, without numpy),
 * ``observability/sentinel`` baseline seeding — classic median
   (mean-of-two-middles on even length),
-* ``bench_serving`` lane stats — ``np.percentile`` with the default
-  (linear) interpolation.
+* the benchmark's latency percentiles — ``np.percentile`` with the
+  default (linear) interpolation.
 
 All three are the SAME function: ``np.percentile``'s default "linear"
 method reduces to mean-of-two-middles at q=0.5, so ``median(xs)``

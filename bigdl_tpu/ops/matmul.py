@@ -14,9 +14,10 @@ Two execution paths:
 - **Pallas kernel** (`bigdl_tpu.ops.pallas.dequant_matmul`): streams the
   *packed* int4/int8 blocks HBM->VMEM and dequantizes in-kernel, so decode
   (GEMV-like, memory-bound) reads ~K*N/2 bytes instead of 2*K*N and a
-  prefill chunk's GEMM writes no dense copy of the weights. Selected
-  automatically on TPU for supported qtypes, for rows up to
-  `RuntimeFlags.matmul_pallas_max_m`.
+  prefill chunk's GEMM writes no dense copy of the weights.
+
+Which of them a call takes, and at which tiles, is `select_matmul`'s
+answer: one function of what the call can see.
 
 The public entry is `q_matmul(x, w)` where `w` is a QTensor of logical shape
 [K, N] (contraction-major; see ops/quant.py) and x is [..., K].
@@ -25,21 +26,16 @@ The public entry is `q_matmul(x, w)` where `w` is a QTensor of logical shape
 from __future__ import annotations
 
 import functools
-import os
-from typing import Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from bigdl_tpu.ops.quant import QTensor, dequantize_impl as dequantize
+from bigdl_tpu.ops.quant import (QTensor, dequantize_impl as dequantize,
+                                 get_qtype)
 
-# Kernel backend selection:
-#   "auto"   — Pallas on TPU when supported, else XLA fallback
-#   "xla"    — always dequant + dot
-#   "pallas" — force Pallas (errors if unsupported)
-_BACKEND_ENV = "BIGDL_TPU_MATMUL_BACKEND"
-
-# qtypes the Pallas dequant-matmul kernel supports today.
+# qtypes the Pallas dequant-matmul kernels cover (sym / asym / codebook
+# codes of 4 bits, sym codes of 8)
 _PALLAS_QTYPES = frozenset({"sym_int4", "asym_int4", "nf4", "fp4", "nf3", "sym_int8"})
 
 
@@ -50,8 +46,6 @@ def _backend() -> str:
     return flags().matmul_backend
 
 
-
-
 # formats whose XLA dequant materializes several full-size f32
 # intermediates (codebook gathers, sign planes, sub-scale expansions):
 # left unchunked, ONE 7B-class weight costs gigabytes of temp — a
@@ -59,6 +53,8 @@ def _backend() -> str:
 # 16 GB v5e despite only 12.8 GB of packed weights
 _HEAVY_DECODE_QTYPES = frozenset(
     ("q2_k", "iq2_xxs", "iq2_xs", "iq1_s", "iq1_m"))
+# ... and the weight size from which they are chunked
+_HEAVY_CHUNK_ELEMS = 1 << 24
 
 
 def _chunk_count(n: int, target_cols: int = 1024) -> int:
@@ -97,11 +93,12 @@ def _chunk_planes(w: QTensor, min_elems: int, target_cols: int):
 
 
 def _q_matmul_xla_chunked(x: jax.Array, w: QTensor,
-                          min_elems: int = 1 << 24,
+                          min_elems: int = 0,
                           target_cols: int = 1024):
     """Dequantize+dot in N-chunks under lax.map so XLA reuses one
-    chunk's decode buffers instead of materializing them all at once.
-    Returns None when chunking is not applicable/worthwhile."""
+    chunk's decode buffers instead of materializing them all at once
+    (when it is worthwhile is `_xla_plan`'s rule). Returns None when N
+    does not split."""
     prep = _chunk_planes(w, min_elems, target_cols)
     if prep is None:
         return None
@@ -129,28 +126,8 @@ def _rows(x: jax.Array) -> int:
     return m
 
 
-# one 7B-class weight (4096 x 11008 and up); decode-shaped calls against
-# anything this large get the bounded-temp chunked plan
-_DECODE_CHUNK_ELEMS = 1 << 25
-
-
 def _q_matmul_xla(x: jax.Array, w: QTensor) -> jax.Array:
-    if w.qtype in _HEAVY_DECODE_QTYPES:
-        y = _q_matmul_xla_chunked(x, w)
-        if y is not None:
-            return y
-    elif _rows(x) <= 16 and w.shape[0] * w.shape[1] >= _DECODE_CHUNK_ELEMS:
-        # decode against a 7B-class weight: the dense plan materializes
-        # the FULL bf16 dequant (2*K*N bytes of temp) per layer — across
-        # a scanned 32-layer decode XLA kept several alive at once and
-        # the forced-XLA bench lane died in RESOURCE_EXHAUSTED before
-        # producing a number. Chunking over N bounds the live temp to
-        # one chunk; over-N splits leave every dot column's K-reduction
-        # untouched, so the result is bitwise identical to the dense
-        # plan (prefill M is unaffected either way).
-        y = _q_matmul_xla_chunked(x, w, min_elems=_DECODE_CHUNK_ELEMS)
-        if y is not None:
-            return y
+    """The dense XLA plan: dequantize, then dot."""
     with jax.named_scope("dequant"):
         dense = dequantize(w, dtype=jnp.bfloat16)
     y = jnp.dot(
@@ -170,7 +147,7 @@ def _q_matmul_xla_fused(x: jax.Array, w: QTensor) -> jax.Array:
     The plain fallback computes dequantize(W) -> [K, N] bf16 -> dot: the
     scale multiply touches all K*N weights and the scale-expanded bf16
     weight is a full-size temp. Scales factor out of the contraction
-    (same algebra as the Pallas `_gemv_kernel_fold`):
+    (same algebra as the Pallas `_gemv_kernel_mxu`):
 
         y[m, n] = sum_r s[r, n] * sum_{j in block r} x[m, r, j] c[r, j, n]
                   (+ sum_r z[r, n] * sum_j x[m, r, j]   for asym)
@@ -180,9 +157,9 @@ def _q_matmul_xla_fused(x: jax.Array, w: QTensor) -> jax.Array:
     the [K/B, M, N] block partials in f32 — per-weight work drops to the
     unpack+convert, and at decode M the partial stack is megabytes, not
     the 2*K*N of a dense dequant. Used on TPU for decode-shaped calls
-    when the Pallas kernel is unavailable (unprobed geometry, SPMD
+    when the Pallas kernel is unavailable (no legal tiling, SPMD
     tracing), or forced via backend="xla_fused"."""
-    from bigdl_tpu.ops.quant import _unpack4, get_qtype
+    from bigdl_tpu.ops.quant import _unpack4
     from bigdl_tpu.ops.codebooks import CODEBOOKS
 
     qt = get_qtype(w.qtype)
@@ -229,64 +206,168 @@ def _q_matmul_xla_fused(x: jax.Array, w: QTensor) -> jax.Array:
     return y.astype(x.dtype).reshape(*batch_shape, n)
 
 
-def _q_matmul_dispatch(x: jax.Array, w: QTensor, be: str) -> jax.Array:
-    if be == "xla":
-        return _q_matmul_xla(x, w)
-    if be == "xla_fused":
-        if w.qtype in _FUSED_XLA_QTYPES:
-            return _q_matmul_xla_fused(x, w)
-        return _q_matmul_xla(x, w)
-    if be in ("auto", "pallas"):
-        from bigdl_tpu.config import flags, target_is_tpu, under_spmd
+# The plans a quantized linear can take (`select_matmul` picks one):
+GEMV_MXU = "gemv_mxu"        # decode GEMV, int4-dtype layout
+GEMV_STD = "gemv_std"        # decode GEMV, canonical packing (and int8)
+GEMM = "gemm"                # the tiled GEMM of a prefill chunk
+XLA_FUSED = "xla_fused"      # decode rows, dequant fused into the dot
+XLA_CHUNKED = "xla_chunked"  # dequantize + dot in N-chunks, bounded temp
+XLA = "xla"                  # dequantize, then dot
 
-        on_tpu = target_is_tpu()
-        use_pallas = (w.qtype in _PALLAS_QTYPES and on_tpu
-                      and not under_spmd(x, *jax.tree_util.tree_leaves(w)))
-        if be == "auto" and use_pallas:
-            # rows past the crossover measured on the chip: the kernel
-            # dequantizes each weight tile once per 256 rows, the XLA
-            # plan once per call and then runs the MXU at its peak (1024
-            # rows: 3.10 against 3.50 ms a Mistral layer, 2048: a tie,
-            # 8192: 23.3 against 21.3; RuntimeFlags.matmul_pallas_max_m)
-            m = _rows(x)
-            use_pallas = m <= flags().matmul_pallas_max_m
-            if use_pallas:
-                from bigdl_tpu.ops.pallas.dequant_matmul import (
-                    GEMV_MAX_M, matmul_kernel_compiles)
 
-                if m > GEMV_MAX_M:
-                    # generic tiles: probed per geometry like the GEMV
-                    # variants (False here = no legal tiling, a rule)
-                    from bigdl_tpu.ops.quant import get_qtype
+class MatmulPlan(NamedTuple):
+    kind: str
+    tiles: Optional[Tuple[int, int]] = None   # (bk, bn) of a kernel plan
 
-                    kp = w.scale.shape[0] * get_qtype(w.qtype).block_size
-                    use_pallas = matmul_kernel_compiles(
-                        w.qtype, m, kp, w.shape[1],
-                        mxu=w.data.dtype == jnp.int4)
-        if be == "pallas" or use_pallas:
-            try:
-                from bigdl_tpu.ops.pallas.dequant_matmul import (
-                    q_matmul_pallas_impl)
 
-                return q_matmul_pallas_impl(x, w)
-            except NotImplementedError:
-                # raised while tracing, before any compile: the shape
-                # has no legal tiling — a rule, like the ones below
-                if be == "pallas":
-                    raise
-        if on_tpu:
-            # XLA by design (rows past the crossover, GSPMD-sharded
-            # operands, a qtype or tiling the kernels do not cover): a
-            # dispatch rule, counted apart from probe outcomes
-            from bigdl_tpu.ops.probing import record_dispatch_rule
+# Rows past this take the XLA dequantize-then-dot plan in "auto": the
+# crossover measured on a v5e (tools/qmatmul_ab.py; PERF.md 6, PR 29:
+# sym_int4 in the int4-dtype layout, device time per layer of
+# Mistral-7B's four linears, kernel / XLA): 0.92 / 2.22 ms at 256 rows,
+# 1.62 / 2.36 at 512, 3.10 / 3.50 at 1024, 5.88 / 5.86 at 2048, 23.3 /
+# 21.3 at 8192. The kernel dequantizes a weight tile once per 256 rows,
+# in VMEM; XLA once per call, through float32 and bf16 copies of the
+# layer in HBM, then runs the MXU at its peak.
+PALLAS_MAX_ROWS = 1024
 
-            record_dispatch_rule("matmul")
-            if w.qtype in _FUSED_XLA_QTYPES and _rows(x) <= 32:
-                # decode-shaped: fuse the dequant into the dot rather
-                # than materializing the full bf16 weight
-                return _q_matmul_xla_fused(x, w)
-        return _q_matmul_xla(x, w)
-    raise ValueError(f"unknown matmul backend {be!r}")
+# one 7B-class weight (4096 x 11008 and up); decode-shaped calls against
+# anything this large get the bounded-temp chunked plan
+_DECODE_CHUNK_ELEMS = 1 << 25
+_DECODE_CHUNK_ROWS = 16
+
+
+def _xla_plan(qtype: str, rows: int, kp: int, n: int) -> MatmulPlan:
+    if qtype in _HEAVY_DECODE_QTYPES:
+        min_elems = _HEAVY_CHUNK_ELEMS
+    elif rows <= _DECODE_CHUNK_ROWS:
+        # decode against a 7B-class weight: the dense plan materializes
+        # the FULL bf16 dequant (2*K*N bytes of temp) per layer — across
+        # a scanned 32-layer decode XLA kept several alive at once and a
+        # forced-XLA run died in RESOURCE_EXHAUSTED. Chunking over N
+        # bounds the live temp to one chunk; over-N splits leave every
+        # dot column's K-reduction untouched, so the result is bitwise
+        # identical to the dense plan
+        min_elems = _DECODE_CHUNK_ELEMS
+    else:
+        return MatmulPlan(XLA)
+    if kp * n >= min_elems and _chunk_count(n) > 1:
+        return MatmulPlan(XLA_CHUNKED)
+    return MatmulPlan(XLA)
+
+
+def kernel_plan(qtype: str, rows: int, kp: int, n: int,
+                int4_layout: bool) -> Optional[MatmulPlan]:
+    """The Pallas plan at this geometry, or None: a qtype the kernels do
+    not cover, or a shape with no legal tiling."""
+    from bigdl_tpu.ops.pallas.dequant_matmul import (GEMV_MAX_M, gemm_tiles,
+                                                     gemv_tiles)
+
+    if qtype not in _PALLAS_QTYPES:
+        return None
+    qt = get_qtype(qtype)
+    if rows <= GEMV_MAX_M:
+        kind = GEMV_MXU if int4_layout else GEMV_STD
+        tiles = gemv_tiles(qt, kp, n, rows)
+    else:
+        kind, tiles = GEMM, gemm_tiles(qt, kp, n, rows)
+    return None if tiles is None else MatmulPlan(kind, tiles)
+
+
+def select_matmul(qtype: str, rows: int, kp: int, n: int, *,
+                  int4_layout: bool, spmd: bool, tpu: bool,
+                  backend: str = "auto") -> MatmulPlan:
+    """THE choice of plan for x [rows, K] @ W [K, N], from what a call
+    can see: the qtype, the rows, K padded to the quant block (`kp`), N,
+    whether the codes are in the int4-dtype layout a TPU load gives
+    sym_int4 (`quant.to_mxu_layout`), whether the operands are sharded
+    under GSPMD (`config.under_spmd`; Mosaic kernels cannot be
+    partitioned) and whether the target is a TPU. Pure: no flag, no
+    probe, no device.
+
+    "auto" on a TPU: the Pallas kernel for the qtypes it covers up to
+    PALLAS_MAX_ROWS rows where a tiling is legal — the GEMV to
+    GEMV_MAX_M rows (its body follows from the layout), the GEMM above;
+    else XLA with the dequant fused into the dot at decode rows, in
+    N-chunks where the dense plan's temporaries would not fit, dense
+    otherwise. Off a TPU: XLA, never fused. A forced `backend` takes
+    its plan whatever the target and the rows: "xla", "xla_fused" (the
+    qtypes it covers; XLA for the rest), "pallas" — which raises
+    NotImplementedError where the kernel has no legal tiling or does
+    not cover the qtype."""
+    if backend not in ("auto", "xla", "xla_fused", "pallas"):
+        raise ValueError(f"unknown matmul backend {backend!r}")
+    from bigdl_tpu.ops.pallas.dequant_matmul import GEMV_MAX_M
+
+    fusable = qtype in _FUSED_XLA_QTYPES
+    if backend == "xla_fused" and fusable:
+        return MatmulPlan(XLA_FUSED)
+    if backend == "pallas" or (backend == "auto" and tpu and not spmd
+                               and rows <= PALLAS_MAX_ROWS):
+        plan = kernel_plan(qtype, rows, kp, n, int4_layout)
+        if plan is not None:
+            return plan
+        if backend == "pallas":
+            raise NotImplementedError(
+                f"no Pallas plan for {qtype} [{kp}, {n}] at {rows} rows")
+    if backend == "auto" and tpu and fusable and rows <= GEMV_MAX_M:
+        # decode rows the kernel does not take (no legal tiling, GSPMD):
+        # fuse the dequant into the dot rather than materializing the
+        # full bf16 weight
+        return MatmulPlan(XLA_FUSED)
+    return _xla_plan(qtype, rows, kp, n)
+
+
+def _q_matmul_dispatch(x: jax.Array, w: QTensor, be: str,
+                       interpret: bool = False) -> jax.Array:
+    """Ask `select_matmul`, probe the kernel it chose (auto on a live
+    TPU: a kernel the compiler refuses raises, ops/probing.py) and run
+    the plan."""
+    from bigdl_tpu.config import target_is_tpu, under_spmd
+    from bigdl_tpu.ops.pallas import dequant_matmul as dq
+
+    rows, (k, n) = _rows(x), w.shape
+    block = get_qtype(w.qtype).block_size
+    kp = -(-k // block) * block
+    int4 = w.data.dtype == jnp.int4
+    tpu = target_is_tpu()
+    plan = select_matmul(
+        w.qtype, rows, kp, n, int4_layout=int4, tpu=tpu, backend=be,
+        spmd=under_spmd(x, *jax.tree_util.tree_leaves(w)))
+    if plan.tiles is not None:
+        if be == "auto":
+            if plan.kind == GEMM:
+                dq.matmul_kernel_compiles(w.qtype, rows, kp, n, plan.tiles,
+                                          mxu=int4)
+            else:
+                dq.gemv_kernel_compiles(w.qtype, kp, plan.tiles, m=rows,
+                                        mxu=int4)
+        return dq.q_matmul_kernel(x, w, plan.kind != GEMM, plan.tiles,
+                                  interpret=interpret)
+    if be == "auto" and tpu:
+        # XLA by design (rows past the crossover, GSPMD-sharded
+        # operands, a qtype or tiling the kernels do not cover): a
+        # dispatch rule, counted apart from probe outcomes
+        from bigdl_tpu.ops.probing import record_dispatch_rule
+
+        record_dispatch_rule("matmul")
+    if plan.kind == XLA_FUSED:
+        return _q_matmul_xla_fused(x, w)
+    if plan.kind == XLA_CHUNKED:
+        return _q_matmul_xla_chunked(x, w)
+    return _q_matmul_xla(x, w)
+
+
+def q_matmul_pallas_impl(x: jax.Array, w: QTensor, *,
+                         interpret: bool = False) -> jax.Array:
+    """x [..., K] @ quantized W [K, N] through the Pallas kernel,
+    forced (`backend="pallas"`), with `interpret` for a CPU. Unjitted:
+    see `dequant_matmul.q_matmul_kernel`."""
+    return _q_matmul_dispatch(x, w, "pallas", interpret)
+
+
+# jitted entry for standalone callers (tests, probes, tools)
+q_matmul_pallas = functools.partial(
+    jax.jit, static_argnames=("interpret",))(q_matmul_pallas_impl)
 
 
 _VMAPPED_PALLAS: set = set()
@@ -306,13 +387,11 @@ def vmapped_pallas_ok(qtype: str, k: int = 256, n: int = 256) -> bool:
 
     if not (target_is_tpu() and qtype in _PALLAS_QTYPES):
         return False
-    from bigdl_tpu.ops.pallas.dequant_matmul import (_gemv_tiles,
-                                                     q_matmul_pallas)
-    from bigdl_tpu.ops.quant import get_qtype
+    from bigdl_tpu.ops.pallas.dequant_matmul import gemv_tiles
 
     if _flags().aot_target == "tpu":   # AOT lowering: the caller compiles
         return True
-    tiles = _gemv_tiles(get_qtype(qtype), k, n)
+    tiles = gemv_tiles(get_qtype(qtype), k, n)
     if tiles is not None:
         n = tiles[1]
     from bigdl_tpu.ops.probing import (probe_kernel, quant_struct,
@@ -367,7 +446,7 @@ def _q_matmul_bwd(be, w, dy):
 
 
 def _q_matmul_bwd_chunked(dy: jax.Array, w: QTensor,
-                          min_elems: int = 1 << 24,
+                          min_elems: int = _HEAVY_CHUNK_ELEMS,
                           target_cols: int = 1024):
     """dx = dy @ W^T accumulated over the same N-chunks as the forward,
     so heavy-decode formats keep their bounded-temp guarantee under AD

@@ -208,80 +208,27 @@ def _gemv_kernel(x_ref, data_ref, scale_ref, *rest, block, kind, codebook,
                 k_axis=1)
 
 
-def _gemv_kernel_fold(x3_ref, data_ref, scale_ref, out_ref, acc_ref, *,
-                      block, kind, codebook, bk, bn, nk, bits):
-    """Scale-FOLDED decode-GEMV body (sym/codebook formats).
+def _gemv_kernel_mxu(x3_ref, data_ref, scale_ref, out_ref, acc_ref, *,
+                     block, bk, bn, nk):
+    """Decode-GEMV body for the int4-dtype layout (scale-folded).
 
-    The standard kernel multiplies every weight by its block scale before
-    the matmul — a per-weight VPU multiply plus a bf16 rounding of each
-    dequantized weight. Scales factor out of the contraction:
+    The canonical split-block layout costs ~6 i32 VPU ops per weight to
+    unpack (widen/mask/shift/concat). jnp.int4 arrays are bit-packed by
+    XLA (same HBM bytes) and loaded natively by Mosaic, so per-weight
+    work drops to ONE convert feeding a dot batched over scale blocks,
+    and the scales factor out of the contraction:
 
         y[m, n] = sum_r scale[r, n] * sum_{k in block r} x[m, k] c[k, n]
 
-    so this variant feeds the MXU the RAW (shifted/LUT) codes as one
-    batched-over-blocks dot_general and applies scales to the [rows, M,
-    bn] partials in f32 — per-weight work drops to unpack+shift+convert,
-    and the scale multiply touches M/block as many elements. For INTEGER
-    codes the numerics are strictly better than the standard path (codes
-    exact in bf16, scale applied once in f32: ~0.4% vs ~14% max-rel
-    against the exact-f32 dequant at 7B K); codebook formats still round
-    the LUT values to bf16 for the MXU, so their accuracy merely ties
-    the standard body. Asym formats keep the standard kernel (the
-    zero-point adds a rank-1 correction term not worth the fuss).
+    so they multiply the [rows, M, bn] partials in f32 (integer codes
+    are exact in bf16; the scale is applied once).
 
     x arrives PRE-SPLIT as [K/block, M, block] (host-side reshape +
     transpose): splitting x's lane dimension inside the kernel is a
     Mosaic "unsupported shape cast" (caught by the AOT suite), and the
     batch (scale-block) axis must sit at the SAME position in both dot
     operands — the chip-side Mosaic rejects lhs-batch-at-1/rhs-batch-
-    at-0 with "batch dims must be equal" (seen live 2026-08-02; the
-    offline Mosaic accepted it, a version skew the AOT gate can't
-    see)."""
-    k = pl.program_id(1)
-    rows = bk // block
-
-    @pl.when(k == 0)
-    def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    if bits == 4:
-        codes = _unpack_tile(data_ref[:], block, bk, bn)  # [rows, B, bn]
-        if kind == "codebook":
-            c = codes
-            tbl = list(codebook) + [0.0] * (16 - len(codebook))
-            vals = jnp.full(c.shape, tbl[0], jnp.float32)
-            for i in range(1, 16):
-                vals = jnp.where(c == i, tbl[i], vals)
-            cb = vals.astype(jnp.bfloat16)
-        else:                                    # sym int4
-            cb = (codes.astype(jnp.float32) - 8.0).astype(jnp.bfloat16)
-    else:                                        # sym int8
-        cb = data_ref[:].reshape(rows, block, bn).astype(jnp.bfloat16)
-
-    # batched over scale blocks: [rows, M, B] x [rows, B, bn]
-    part = jax.lax.dot_general(
-        x3_ref[:], cb, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)      # [rows, M, bn]
-    s = scale_ref[:].astype(jnp.float32)         # [rows, bn]
-    acc_ref[:] += jnp.sum(part * s[:, None, :], axis=0)
-
-    @pl.when(k == nk - 1)
-    def _():
-        out_ref[:] = acc_ref[:].astype(out_ref.dtype)
-
-
-def _gemv_kernel_mxu(x3_ref, data_ref, scale_ref, out_ref, acc_ref, *,
-                     block, bk, bn, nk):
-    """MXU-layout decode GEMV (int4/int8-dtype weights, scale-folded).
-
-    The canonical split-block layout costs ~6 i32 VPU ops per weight to
-    unpack (widen/mask/shift/concat) — at 7B decode that chain, not HBM,
-    is the suspected floor (not measured on today's code). jnp.int4
-    arrays are bit-packed by XLA (same HBM bytes) and loaded natively by
-    Mosaic, so per-weight work drops to ONE convert feeding the batched
-    dot; scales fold onto the [rows, M, bn] partials exactly like
-    `_gemv_kernel_fold` (same numerics class: integer codes exact in
-    bf16, scale applied once in f32)."""
+    at-0 with "batch dims must be equal"."""
     k = pl.program_id(1)
     rows = bk // block
 
@@ -295,53 +242,6 @@ def _gemv_kernel_mxu(x3_ref, data_ref, scale_ref, out_ref, acc_ref, *,
         preferred_element_type=jnp.float32)          # [rows, M, bn]
     s = scale_ref[:].astype(jnp.float32)             # [rows, bn]
     acc_ref[:] += jnp.sum(part * s[:, None, :], axis=0)
-
-    @pl.when(k == nk - 1)
-    def _():
-        out_ref[:] = acc_ref[:].astype(out_ref.dtype)
-
-
-def _gemv_kernel_mxuflat(x_ref, data_ref, scale_ref, out_ref, acc_ref, *,
-                         block, bk, bn, nk):
-    """Flat-dot MXU-layout body: int4 native load, per-weight scale
-    (2-3 VPU ops/weight vs the canonical unpack chain's ~8), then ONE
-    [mp, bk] x [bk, bn] bf16 dot at full K contraction — maximum MXU
-    shape efficiency. The A/B discriminator vs `_gemv_kernel_mxu`:
-    r4 on-chip numbers showed fold (batched dot, fewer VPU ops) TYING
-    std (flat dot, more VPU ops) at 30 ms, so which resource binds —
-    VPU convert throughput or the batched-dot's short-K MXU passes —
-    is an open question only silicon can answer."""
-    k = pl.program_id(1)
-    s = scale_ref[:].astype(jnp.float32)[:, None, :]
-    codes = data_ref[:].astype(jnp.int8).astype(jnp.float32)
-    w = (codes.reshape(bk // block, block, bn) * s) \
-        .reshape(bk, bn).astype(jnp.bfloat16)
-    _accumulate(x_ref[:, pl.ds(k * bk, bk)], w, out_ref, acc_ref, nk,
-                k_axis=1)
-
-
-def _gemv_kernel_mxu8(x3_ref, sxt_ref, data_ref, scale_ref, out_ref,
-                      acc_ref, *, block, bk, bn, nk):
-    """int8-activation variant: per-block q8 activations against the
-    int4/int8 weights on the MXU's int8 path (2x the bf16 throughput),
-    llama.cpp's q4_0 x q8_0 structure on TPU. The int32 block partials
-    are exact; both scales (weight s[r, n], activation sx[m, r]) apply
-    in f32 on the partials."""
-    k = pl.program_id(1)
-    rows = bk // block
-
-    @pl.when(k == 0)
-    def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    cb = data_ref[:].astype(jnp.int8).reshape(rows, block, bn)
-    part = jax.lax.dot_general(
-        x3_ref[:], cb, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.int32)            # [rows, M, bn]
-    s = scale_ref[:].astype(jnp.float32)             # [rows, bn]
-    sxt = sxt_ref[:].astype(jnp.float32)             # [rows, M]
-    scaled = part.astype(jnp.float32) * s[:, None, :]
-    acc_ref[:] += jnp.sum(scaled * sxt[:, :, None], axis=0)
 
     @pl.when(k == nk - 1)
     def _():
@@ -372,8 +272,8 @@ def _matmul_tiles(qt, kp: int, n: int, bk_cands,
     The budget accounts the M-dependent terms too (x tile bm*bk bf16 +
     f32 accumulator bm*bn): at decode bm=16 they are noise, but at
     prefill-class bm=256 they rival the streamed weight tile — ignoring
-    them let a forced all-M run (bench lane `pallas-all-m`) pick tiles
-    whose working set overflowed VMEM at 7B geometry."""
+    them let a forced all-M run pick tiles whose working set overflowed
+    VMEM at 7B geometry."""
     b = qt.block_size
     best = None
     for bn in (512, 256, 128):
@@ -390,22 +290,48 @@ def _matmul_tiles(qt, kp: int, n: int, bk_cands,
     return best
 
 
-def _gemv_tiles(qt, kp: int, n: int, mp: int = 16):
+# decode-GEMV M ceiling: the serving engine's decode batch. One padded
+# sublane tile (mp=16) covers bs<=16; bs 17-32 pads to TWO sublane tiles
+# (mp=32) — the x tile and accumulator double but stay VMEM-noise, and
+# decode remains HBM-bound so the pad FLOPs are free.
+GEMV_MAX_M = 32
+
+# GEMM row tile ceiling: a prefill chunk's rows
+# (EngineConfig.prefill_chunk) in one tile
+GEMM_MAX_BM = 256
+
+
+def _gemv_mp(m: int) -> int:
+    return 16 if m <= 16 else 32
+
+
+def _generic_bm(m: int):
+    """GEMM row tile class: (bm, mp) with mp the padded M. Every row
+    tile dequantizes each weight tile again, so M is covered by as FEW
+    tiles of at most GEMM_MAX_BM rows as will do (sublane multiples of
+    16): 200 rows are one tile of 208, not thirteen of 16."""
+    tiles = -(-m // GEMM_MAX_BM)
+    bm = -(-m // tiles)
+    bm += -bm % 16
+    return bm, tiles * bm
+
+
+def gemv_tiles(qt, kp: int, n: int, m: int = 1):
+    """(bk, bn) of the decode GEMV at `m` <= GEMV_MAX_M rows, or None
+    when the shape has no legal tiling (ChatGLM2's K = 13696: no bk with
+    an 8-row scale block divides it and the full K is over the budget)."""
     # kp itself is always legal (block dims == array dims), VMEM permitting
     return _matmul_tiles(qt, kp, n,
                          [4096, 2048, 1024, 512, 256, 128, 64, 32, kp],
-                         bm=mp)
+                         bm=_gemv_mp(m))
 
 
-def _generic_tiles(qt, kp: int, n: int, bm: int):
-    # joint (bk, bn) search keeps the working set (data tile + unpacked
-    # w tile + x tile + accumulator) in VMEM without sacrificing
-    # scale-plane legality
+def gemm_tiles(qt, kp: int, n: int, m: int):
+    """(bk, bn) of the GEMM at `m` rows, or None: the joint (bk, bn)
+    search keeps the working set (data tile + unpacked w tile + x tile +
+    accumulator) in VMEM without sacrificing scale-plane legality."""
+    bm = _generic_bm(m)[0]
     cands = [*_GENERIC_BK, kp]
-    if bm <= GEMV_MAX_M:
-        # decode rows that the GEMV could not tile: the tiles (and, with
-        # no legal one, the XLA-fused plan) they had
-        return _matmul_tiles(qt, kp, n, cands, bm=bm)
     # a K that no smaller tile divides legally (ChatGLM2's 13696 = 2^7 x
     # 107: no bk with an 8-row scale block) takes ONE full-K tile under
     # twice the budget, (13696, 128): 0.224 ms against XLA's 0.689 at 256
@@ -416,176 +342,107 @@ def _generic_tiles(qt, kp: int, n: int, bm: int):
 
 
 _gemv_probe_cache: set = set()
-
-# decode-GEMV M ceiling: the serving engine's decode batch. One padded
-# sublane tile (mp=16) covers bs<=16; bs 17-32 pads to TWO sublane tiles
-# (mp=32) — the x tile and accumulator double but stay VMEM-noise, and
-# decode remains HBM-bound so the pad FLOPs are free.
-GEMV_MAX_M = 32
+_matmul_probe_cache: set = set()
 
 
-def _gemv_mp(m: int) -> int:
-    return 16 if m <= 16 else 32
-
-
-def gemv_kernel_compiles(qtype: str, kp: int, n: int,
-                         variant: str = "std", m: int = 1) -> bool:
-    """Per-geometry compile probe for the decode-GEMV variant (contract
-    in ops/probing.py: True, or `KernelProbeError`): compiles the REAL
-    tile classes on a stand-in sized (kp, bn). False only by RULE — the
-    shape has no legal GEMV tiling and takes the generic tiles.
-    `variant`: "std" | "fold" | "mxu" | "mxu8" (see the kernel bodies).
-    `m` only selects the padded row class (16 vs 32)."""
-    qt = get_qtype(qtype)
-    mp = _gemv_mp(m)
-    tiles = _gemv_tiles(qt, kp, n, mp)
-    if tiles is None:
-        return False
+def gemv_kernel_compiles(qtype: str, kp: int, tiles, m: int = 1,
+                         mxu: bool = False) -> bool:
+    """Compile probe of the decode GEMV at one geometry (contract in
+    ops/probing.py: True, or `KernelProbeError`): compiles the REAL tile
+    classes `tiles` = (bk, bn) on a stand-in sized (kp, bn). `mxu` says
+    the codes are in the int4-dtype layout (the body follows from it);
+    `m` only selects the padded row class (16 or 32)."""
     from bigdl_tpu.config import flags as _flags
 
     if _flags().aot_target == "tpu":   # AOT lowering: the caller compiles
         return True
+    qt = get_qtype(qtype)
+    mp = _gemv_mp(m)
     bk, bn = tiles
+    variant = "mxu" if mxu else "std"
     from bigdl_tpu.ops.probing import probe_kernel, quant_struct
 
     return probe_kernel(
         f"gemv_{variant}", _gemv_probe_cache,
         (qtype, kp, bn, bk, variant, mp),
-        lambda xx, ww: _q_gemv_pallas(xx, ww, qt, mp, kp, bn, False,
-                                      jnp.bfloat16, variant=variant),
+        lambda xx, ww: _q_gemv_pallas(xx, ww, qt, mp, kp, bn, tiles, False,
+                                      jnp.bfloat16),
         jax.ShapeDtypeStruct((mp, kp), jnp.bfloat16),
-        quant_struct(kp, bn, qtype,
-                     mxu=variant in ("mxu", "mxuflat", "mxu8")))
+        quant_struct(kp, bn, qtype, mxu=mxu))
 
 
-_matmul_probe_cache: set = set()
-
-
-def matmul_kernel_compiles(qtype: str, m: int, kp: int, n: int,
+def matmul_kernel_compiles(qtype: str, m: int, kp: int, n: int, tiles,
                            mxu: bool = False) -> bool:
-    """Per-geometry compile probe for the GENERIC tiled kernel (same
-    contract as `gemv_kernel_compiles`). False only by RULE — no legal
-    tiling, the XLA matmul serves the shape. Keyed by the padded bm
-    class, not the raw M."""
-    qt = get_qtype(qtype)
-    bm, mp = _generic_bm(m)
-    tiles = _generic_tiles(qt, kp, n, bm)
-    if tiles is None:
-        return False
+    """Compile probe of the GEMM (same contract as
+    `gemv_kernel_compiles`). Keyed by the padded bm class, not the raw
+    M."""
     from bigdl_tpu.config import flags as _flags
 
     if _flags().aot_target == "tpu":   # AOT lowering: the caller compiles
         return True
+    qt = get_qtype(qtype)
+    bm = _generic_bm(m)[0]
     from bigdl_tpu.ops.probing import probe_kernel, quant_struct
 
     return probe_kernel(
         "matmul_generic", _matmul_probe_cache,
         (qtype, bm, kp, n, bool(mxu)),
-        lambda xx, ww: _q_matmul_generic(xx, ww, qt, bm, kp, n, False,
-                                         jnp.bfloat16),
+        lambda xx, ww: _q_matmul_generic(xx, ww, qt, bm, kp, n, tiles,
+                                         False, jnp.bfloat16),
         jax.ShapeDtypeStruct((bm, kp), jnp.bfloat16),
         quant_struct(kp, n, qtype, mxu=mxu))
 
 
 def _q_gemv_pallas(x2: jax.Array, w: QTensor, qt, m: int, kp: int, n: int,
-                   interpret: bool, out_dtype=None, variant: str = "std"):
+                   tiles, interpret: bool, out_dtype=None):
     """bs<=GEMV_MAX_M decode GEMV (the reference's `linear_fp16_esimd`
     decode GEMV role, low_bit_linear.py:744-745). M pads to one 16-row
-    sublane tile (two for bs 17-32); x [mp, K] and the scale column
-    block are VMEM-resident for the whole K sweep, the grid drops the M
-    axis, and bn/bk maximize the streaming tile. FLOP overhead of the
-    pad is irrelevant — decode is HBM-bound.
-    `variant`: "std" (unpack + per-weight scale), "fold" (scale-folded
-    batched dot over the packed layout), "mxu"/"mxu8" (int4-dtype
-    weights; see `_gemv_kernel_mxu`/`_gemv_kernel_mxu8`)."""
+    sublane tile (two for bs 17-32); x [mp, K] is VMEM-resident for the
+    whole K sweep, the grid drops the M axis, and `tiles` = (bk, bn)
+    maximize the streaming tile. FLOP overhead of the pad is irrelevant
+    — decode is HBM-bound. The body follows from the codes' dtype:
+    int4-dtype codes take `_gemv_kernel_mxu`, the canonical packing (and
+    int8) `_gemv_kernel`."""
     mp = _gemv_mp(m)
     if x2.shape[0] != mp:
         x2 = jax.lax.pad(x2, jnp.zeros((), x2.dtype),
                          ((0, mp - x2.shape[0], 0), (0, 0, 0)))
     b = qt.block_size
-    tiles = _gemv_tiles(qt, kp, n, mp)
-    if tiles is None:
-        raise NotImplementedError(f"shapes not tileable: K={kp} N={n}")
     bk, bn = tiles
     nk = kp // bk
-    grid = (n // bn, nk)
-
-    x_spec = pl.BlockSpec((mp, kp), lambda j, k: (0, 0))      # resident
     scale_spec = pl.BlockSpec((bk // b, bn), lambda j, k: (k, j))
-    out_spec = pl.BlockSpec((mp, bn), lambda j, k: (0, j))
-    out_shape = jax.ShapeDtypeStruct((mp, n), out_dtype or x2.dtype)
-    scratch = [pltpu.VMEM((mp, bn), jnp.float32)]
-
-    codebook = None
-    if qt.kind == "codebook":
-        codebook = [float(v) for v in CODEBOOKS[qt.codebook]]
-    bits = qt.storage_bits
-
-    if variant in ("mxu", "mxuflat", "mxu8"):
-        if w.data.dtype not in (jnp.int4, jnp.int8):
-            raise NotImplementedError(
-                f"{variant} GEMV needs int4/int8-dtype weights "
-                f"(got {w.data.dtype}); apply quant.to_mxu_layout")
-        data_spec = pl.BlockSpec((bk, bn), lambda j, k: (k, j))
-        # x pre-split per scale block OUTSIDE the kernel (lane-dim
-        # reshapes inside are a Mosaic unsupported shape cast), blocks
-        # leading so the batched dot's batch dims align (see
-        # _gemv_kernel_fold docstring)
-        x3 = x2.reshape(mp, kp // b, b).transpose(1, 0, 2)
-        x3_spec = pl.BlockSpec((bk // b, mp, b), lambda j, k: (k, 0, 0))
-        if variant == "mxuflat":
-            kernel = functools.partial(
-                _gemv_kernel_mxuflat, block=b, bk=bk, bn=bn, nk=nk)
-            operands = [x2, w.data, w.scale]
-            in_specs = [x_spec, data_spec, scale_spec]
-        elif variant == "mxu":
-            kernel = functools.partial(
-                _gemv_kernel_mxu, block=b, bk=bk, bn=bn, nk=nk)
-            operands = [x3, w.data, w.scale]
-            in_specs = [x3_spec, data_spec, scale_spec]
-        else:
-            # per-block q8 activation quantization (VPU work over just
-            # M x K elements, fused into the surrounding jit by XLA)
-            xf = x3.astype(jnp.float32)
-            amax = jnp.max(jnp.abs(xf), axis=-1)              # [K/b, mp]
-            sxt = amax * (1.0 / 127.0)
-            inv = jnp.where(sxt == 0, 0.0,
-                            1.0 / jnp.where(sxt == 0, 1.0, sxt))
-            xq = jnp.round(xf * inv[..., None]).astype(jnp.int8)
-            sxt_spec = pl.BlockSpec((bk // b, mp), lambda j, k: (k, 0))
-            kernel = functools.partial(
-                _gemv_kernel_mxu8, block=b, bk=bk, bn=bn, nk=nk)
-            operands = [xq, sxt, w.data, w.scale]
-            in_specs = [x3_spec, sxt_spec, data_spec, scale_spec]
-    elif variant == "fold" and qt.kind != "asym":
+    if w.data.dtype == jnp.int4:
         kernel = functools.partial(
-            _gemv_kernel_fold, block=b, kind=qt.kind, codebook=codebook,
-            bk=bk, bn=bn, nk=nk, bits=bits)
-        data_spec = pl.BlockSpec((bk // 2 if bits == 4 else bk, bn),
-                                 lambda j, k: (k, j))
+            _gemv_kernel_mxu, block=b, bk=bk, bn=bn, nk=nk)
+        # x pre-split per scale block OUTSIDE the kernel, blocks leading
+        # (see the body's docstring)
         operands = [x2.reshape(mp, kp // b, b).transpose(1, 0, 2),
                     w.data, w.scale]
         in_specs = [pl.BlockSpec((bk // b, mp, b), lambda j, k: (k, 0, 0)),
-                    data_spec, scale_spec]
+                    pl.BlockSpec((bk, bn), lambda j, k: (k, j)), scale_spec]
     else:
+        codebook = None
+        if qt.kind == "codebook":
+            codebook = [float(v) for v in CODEBOOKS[qt.codebook]]
+        bits = qt.storage_bits
         kernel = functools.partial(
             _gemv_kernel, block=b, kind=qt.kind, codebook=codebook,
             bk=bk, bn=bn, nk=nk, bits=bits)
-        data_spec = pl.BlockSpec((bk // 2 if bits == 4 else bk, bn),
-                                 lambda j, k: (k, j))
         operands = [x2, w.data, w.scale]
-        in_specs = [x_spec, data_spec, scale_spec]
+        in_specs = [pl.BlockSpec((mp, kp), lambda j, k: (0, 0)),  # resident
+                    pl.BlockSpec((bk // 2 if bits == 4 else bk, bn),
+                                 lambda j, k: (k, j)), scale_spec]
         if qt.kind == "asym":
             operands.append(w.zero)
             in_specs.append(scale_spec)
     y = pl.pallas_call(
         kernel,
         name=f"qmatmul_gemv_{w.qtype}",
-        grid=grid,
+        grid=(n // bn, nk),
         in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
+        out_specs=pl.BlockSpec((mp, bn), lambda j, k: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((mp, n), out_dtype or x2.dtype),
+        scratch_shapes=[pltpu.VMEM((mp, bn), jnp.float32)],
         interpret=interpret,
         # N tiles are independent; only the K sweep carries the
         # accumulator — telling Mosaic lets it software-pipeline the
@@ -596,88 +453,17 @@ def _q_gemv_pallas(x2: jax.Array, w: QTensor, qt, m: int, kp: int, n: int,
     return y[:m]
 
 
-def q_matmul_pallas_impl(x: jax.Array, w: QTensor, *,
-                         interpret: bool = False) -> jax.Array:
-    """x [..., K] @ quantized W [K, N] -> [..., N] via a fused Pallas
-    kernel. Unjitted body: model forwards call this inside their own
-    jit (a nested jit's closed_call fails to lower inside shard_map's
-    Manual-mesh trace — caught by the explicit-TP AOT test)."""
-    qt = get_qtype(w.qtype)
-    if qt.kind not in ("sym", "asym", "codebook") or qt.storage_bits not in (4, 8):
-        raise NotImplementedError(f"pallas kernel does not support {w.qtype}")
-    if qt.storage_bits == 8 and qt.kind != "sym":
-        raise NotImplementedError(f"pallas kernel does not support {w.qtype}")
-
-    batch_shape = x.shape[:-1]
-    klog, n = w.shape
-    kp = w.scale.shape[0] * qt.block_size
-    m = 1
-    for d in batch_shape:
-        m *= d
-    x2 = x.reshape(m, klog).astype(jnp.bfloat16)
-    if kp != klog:
-        x2 = jax.lax.pad(x2, jnp.zeros((), x2.dtype),
-                         ((0, 0, 0), (0, kp - klog, 0)))
-
-    from bigdl_tpu.config import flags
-
-    gv = flags().matmul_gemv
-    if gv == "mxu8" and w.data.dtype in (jnp.int4, jnp.int8) \
-            and qt.kind == "sym":
-        variant = "mxu8"
-    elif gv == "mxuflat" and w.data.dtype == jnp.int4:
-        variant = "mxuflat"
-    elif gv in ("auto", "mxu", "fold") and w.data.dtype == jnp.int4:
-        variant = "mxu"          # int4-dtype layout: always the MXU body
-    elif gv == "fold" and qt.kind != "asym":
-        variant = "fold"
-    else:
-        variant = "std"
-    if m <= GEMV_MAX_M and gv != "off" and (
-            interpret or gemv_kernel_compiles(w.qtype, kp, n,
-                                              variant=variant, m=m)):
-        try:
-            y = _q_gemv_pallas(x2, w, qt, m, kp, n, interpret,
-                               out_dtype=x.dtype, variant=variant)
-            return y.reshape(*batch_shape, n)
-        except NotImplementedError:
-            pass      # fall through to the generic tiling
-
-    y = _q_matmul_generic(x2, w, qt, m, kp, n, interpret, x.dtype)
-    return y.reshape(*batch_shape, n)
-
-
-# generic-path row tile ceiling: a prefill chunk's rows
-# (EngineConfig.prefill_chunk) in one tile
-GEMM_MAX_BM = 256
-
-
-def _generic_bm(m: int):
-    """Generic-path row tile class: (bm, mp) with mp the padded M. Every
-    row tile dequantizes each weight tile again, so M is covered by as
-    FEW tiles of at most GEMM_MAX_BM rows as will do (sublane multiples
-    of 16): 200 rows are one tile of 208, not thirteen of 16."""
-    tiles = -(-m // GEMM_MAX_BM)
-    bm = -(-m // tiles)
-    bm += -bm % 16
-    return bm, tiles * bm
-
-
 def _q_matmul_generic(x2: jax.Array, w: QTensor, qt, m: int, kp: int,
-                      n: int, interpret: bool, out_dtype) -> jax.Array:
-    """Generic-tile kernel dispatch: x2 [m, kp] bf16 (already K-padded)
-    against quantized W — grid (M/bm, N/bn, K/bk). Probed per geometry
-    by `matmul_kernel_compiles`."""
+                      n: int, tiles, interpret: bool,
+                      out_dtype) -> jax.Array:
+    """The GEMM: x2 [m, kp] bf16 (already K-padded) against quantized W
+    — grid (M/bm, N/bn, K/bk) at `tiles` = (bk, bn)."""
     # pad M up to a bf16-tileable multiple (min sublane 16)
     bm, mp = _generic_bm(m)
     if mp != m:
         x2 = jax.lax.pad(x2, jnp.zeros((), x2.dtype),
                          ((0, mp - m, 0), (0, 0, 0)))
-    tiles = _generic_tiles(qt, kp, n, bm)
-    if tiles is None:
-        raise NotImplementedError(f"shapes not tileable: K={kp} N={n}")
     bk, bn = tiles
-
     nk = kp // bk
     grid = (mp // bm, n // bn, nk)
     b = qt.block_size
@@ -721,7 +507,25 @@ def _q_matmul_generic(x2: jax.Array, w: QTensor, qt, m: int, kp: int,
     return y
 
 
-# public jitted entry (standalone callers, probes, benchmarks); model
-# dispatch uses the unjitted impl — see its docstring
-q_matmul_pallas = functools.partial(
-    jax.jit, static_argnames=("interpret",))(q_matmul_pallas_impl)
+def q_matmul_kernel(x: jax.Array, w: QTensor, gemv: bool, tiles, *,
+                    interpret: bool = False) -> jax.Array:
+    """x [..., K] @ quantized W [K, N] -> [..., N] through the kernel
+    that `ops/matmul.select_matmul` chose: the decode GEMV (`gemv`) or
+    the GEMM, at its `tiles`. Unjitted: model forwards call this inside
+    their own jit (a nested jit's closed_call fails to lower inside
+    shard_map's Manual-mesh trace — caught by the explicit-TP AOT
+    test)."""
+    qt = get_qtype(w.qtype)
+    batch_shape = x.shape[:-1]
+    klog, n = w.shape
+    kp = w.scale.shape[0] * qt.block_size
+    m = 1
+    for d in batch_shape:
+        m *= d
+    x2 = x.reshape(m, klog).astype(jnp.bfloat16)
+    if kp != klog:
+        x2 = jax.lax.pad(x2, jnp.zeros((), x2.dtype),
+                         ((0, 0, 0), (0, kp - klog, 0)))
+    call = _q_gemv_pallas if gemv else _q_matmul_generic
+    y = call(x2, w, qt, m, kp, n, tiles, interpret, x.dtype)
+    return y.reshape(*batch_shape, n)

@@ -57,7 +57,7 @@ def record_probe_result(kernel: str, ok: bool) -> None:
 
 def record_dispatch_rule(kernel: str) -> None:
     """Count a dispatch that took XLA BY DESIGN (rows above
-    matmul_pallas_max_m, operands sharded under GSPMD) — a rule, not a
+    `matmul.PALLAS_MAX_ROWS`, operands sharded under GSPMD) — a rule, not a
     probe outcome, so `outcome="fallback"` keeps meaning "the compiler
     refused a kernel". Trace-time counts, like the probes."""
     _probe_counter().labels(kernel, "xla_by_rule").inc()

@@ -889,7 +889,7 @@ def dequantize_impl(qt: QTensor, dtype=jnp.bfloat16) -> jax.Array:
 
     Unjitted body: model forwards reach this inside their own jit, and a
     nested jit's closed_call fails to lower inside shard_map's Manual-
-    mesh AOT trace (see ops/pallas/dequant_matmul.q_matmul_pallas_impl).
+    mesh AOT trace (see ops/pallas/dequant_matmul.q_matmul_kernel).
     The jitted public alias `dequantize` is defined below for eager
     callers (conversion, tests)."""
     t = qt.qt
@@ -1183,26 +1183,21 @@ def prepack_tree(tree, mode: Optional[str] = None):
     load (transformers/model.py); `save_low_bit` always repacks to the
     canonical split-block interchange format via `tree_from_mxu_layout`.
 
-    `mode`: "auto" (prepack when the compute target is TPU), "on",
-    "off"; defaults to flags().prepack (BIGDL_TPU_PREPACK). Subsumes
-    the older mxu_layout knob — either knob set to "off" disables,
-    and either set to "on" forces the retile even off-TPU (the CPU
-    fallbacks read both layouts, so "on" stays testable anywhere).
+    `mode`: "auto" (prepack when the compute target is TPU), "on"
+    (force the retile even off-TPU: the CPU fallbacks read both
+    layouts, so "on" stays testable anywhere), "off"; defaults to
+    flags().prepack (BIGDL_TPU_PREPACK).
 
     Returns (tree, report): report is a plain-JSON dict (mode, applied,
-    qtensor/converted counts, packed bytes) that the memory ledger and
-    the bench's `prepack` block record, so a failed or skipped retile
-    is visible in every perf artifact instead of silently changing
-    which kernel variant the A/B numbers measured."""
+    qtensor/converted counts, packed bytes) that the memory ledger
+    records, so a failed or skipped retile is visible instead of
+    silently changing which GEMV body a deployment runs."""
     from bigdl_tpu.config import flags, resolve_prepack, target_is_tpu
 
-    f = flags()
-    mode = resolve_prepack(mode) if mode is not None else f.prepack
+    mode = resolve_prepack(mode) if mode is not None else flags().prepack
     report: dict = {"mode": mode, "applied": False,
                     "qtensors": 0, "converted": 0, "bytes_packed": 0}
-    off = mode == "off" or f.mxu_layout == "off"
-    force = mode == "on" or f.mxu_layout == "on"
-    if off or (not force and not target_is_tpu()):
+    if mode == "off" or (mode != "on" and not target_is_tpu()):
         return tree, report
 
     is_q = lambda x: isinstance(x, QTensor)  # noqa: E731

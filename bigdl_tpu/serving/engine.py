@@ -666,7 +666,7 @@ class LLMEngine:
         # blocked block_until_ready, measured every step): the
         # attribution denominator for
         # the decode roofline gap, surfaced as stats_snapshot()
-        # dispatch_overhead_ms and ratcheted by tools/bench_diff.py
+        # dispatch_overhead_ms
         self._dispatch_ewma = 0.0
         # recent finish timestamps -> measured drain rate (Retry-After)
         self._finish_times: "collections.deque[float]" = \
@@ -1015,8 +1015,7 @@ class LLMEngine:
         # recomputed count rides /v1/stats "migration" only)
         self._m_migrations = m.counter(
             "bigdl_tpu_migrations_total",
-            "Live sequence migrations by outcome (bench_diff gates "
-            "outcome=\"failed\" lower-is-better).",
+            "Live sequence migrations by outcome.",
             labelnames=("outcome",))
         for oc in MIGRATION_OUTCOMES:    # render from scrape 1
             self._m_migrations.labels(oc)
@@ -1054,8 +1053,7 @@ class LLMEngine:
         self._m_pool_exhausted = m.counter(
             "bigdl_tpu_page_pool_exhausted_total",
             "KV page-pool allocation failures (admissions deferred on "
-            "pages, copy-on-write eviction fallbacks). bench_diff "
-            "gates this lower-is-better.")
+            "pages, copy-on-write eviction fallbacks).")
         self._m_radix_lookups = m.counter(
             "bigdl_tpu_prefix_radix_lookups_total",
             "Radix prefix-tree lookups at admission, by outcome.",
@@ -1156,9 +1154,9 @@ class LLMEngine:
 
         # -- live roofline attribution + perf-regression sentinel
         # (observability/roofline.py + sentinel.py). The decode gauge is
-        # the bench decode_hbm_roofline_util formula evaluated each
-        # working step from the measured step wall time; tests assert
-        # 4-decimal agreement with bench.py's offline math.
+        # roofline.efficiency's decode_hbm_roofline_util formula
+        # evaluated each working step from the measured step wall time;
+        # tests assert 4-decimal agreement with the offline math.
         # A device kind without published peaks (roofline.CHIP_PEAKS)
         # exports NO roofline gauges: a share of another chip's roof is
         # not a number.
@@ -1181,8 +1179,7 @@ class LLMEngine:
                 "(weights + live KV over peak HBM GB/s).")
         self._m_perf_regress = m.counter(
             "bigdl_tpu_perf_regression_total",
-            "Sentinel trips by regressed metric "
-            "(tools/bench_diff.py gates this at 0).",
+            "Sentinel trips by regressed metric.",
             labelnames=("metric",))
         from bigdl_tpu.observability.sentinel import METRICS as \
             _SENTINEL_METRICS
@@ -1249,8 +1246,7 @@ class LLMEngine:
             "(nats/token).")
         self._m_q_regress = m.counter(
             "bigdl_tpu_quality_regression_total",
-            "QualitySentinel trips by regressed metric "
-            "(tools/bench_diff.py gates this at 0).",
+            "QualitySentinel trips by regressed metric.",
             labelnames=("metric",))
         for mt in QUALITY_METRICS:         # render from scrape 1
             self._m_q_regress.labels(mt)

@@ -61,12 +61,6 @@ def _prepack(params: Any):
     return prepack_tree(params)
 
 
-def _maybe_mxu_layout(params: Any) -> Any:
-    """Back-compat shim over `_prepack` (report dropped) — the prepack
-    flag subsumes the older mxu_layout knob."""
-    return _prepack(params)[0]
-
-
 def _maybe_merge(params: Any, cfg: Any, family: FamilyAdapter,
                  enable: bool) -> Any:
     """Apply merged-QKV / merged-gate-up weight surgery (the reference's
@@ -609,7 +603,7 @@ class _BaseAutoModelClass:
                 # tree — the draft decode is the latency-critical loop
                 model.draft_params = model.params
             else:
-                model.draft_params = _maybe_mxu_layout(_maybe_merge(
+                model.draft_params, _ = _prepack(_maybe_merge(
                     family.convert_params(
                         iter_hf_tensors(path), cfg, qtype="sym_int4",
                         modules_to_not_convert=tuple(

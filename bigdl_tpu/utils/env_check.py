@@ -45,8 +45,6 @@ KNOWN_ENV = (
     "BIGDL_TPU_KV_PAGE_SIZE",
     "BIGDL_TPU_LIVE_MIGRATION",
     "BIGDL_TPU_MATMUL_BACKEND",
-    "BIGDL_TPU_MATMUL_GEMV",
-    "BIGDL_TPU_MATMUL_PALLAS_MAX_M",
     "BIGDL_TPU_MAX_QUEUE_BYTES",
     "BIGDL_TPU_MAX_QUEUE_DEPTH",
     "BIGDL_TPU_MAX_SEQ",
@@ -55,7 +53,6 @@ KNOWN_ENV = (
     "BIGDL_TPU_MIGRATE_TARGETS",
     "BIGDL_TPU_MIGRATE_TIMEOUT_MS",
     "BIGDL_TPU_MOE_DISPATCH",
-    "BIGDL_TPU_MXU_LAYOUT",
     "BIGDL_TPU_NATIVE_CACHE",
     "BIGDL_TPU_PERF_HISTORY",
     "BIGDL_TPU_POSTMORTEM_DIR",

@@ -3,7 +3,7 @@
 Weights are generated *on device* with JAX PRNG and quantized tensor by
 tensor, so building a 7B-parameter INT4 model for latency benchmarking
 never materializes the float model on host (the benchmark analog of the
-reference's low_cpu_mem_usage loading; metric defined by BASELINE.md).
+reference's low_cpu_mem_usage loading).
 """
 
 from __future__ import annotations

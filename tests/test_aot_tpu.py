@@ -66,7 +66,7 @@ GEMM_QTYPES = ["sym_int4", "asym_int4", "nf4", "fp4", "nf3", "sym_int8"]
 
 @pytest.mark.parametrize("qtype", GEMM_QTYPES)
 def test_dequant_matmul_generic_compiles(v5e, aot_flags, qtype):
-    from bigdl_tpu.ops.pallas.dequant_matmul import q_matmul_pallas
+    from bigdl_tpu.ops.matmul import q_matmul_pallas
     from bigdl_tpu.ops.quant import quantize
 
     dev = v5e.devices[0]
@@ -84,7 +84,8 @@ def test_dequant_matmul_generic_compiles(v5e, aot_flags, qtype):
 def test_dequant_gemv_compiles(v5e, aot_flags, qtype, n):
     """The decode-GEMV variant (M<=16, x/scales VMEM-resident) at
     llama-7B decode geometries — called directly, bypassing the probe."""
-    from bigdl_tpu.ops.pallas.dequant_matmul import _q_gemv_pallas
+    from bigdl_tpu.ops.pallas.dequant_matmul import (_q_gemv_pallas,
+                                                     gemv_tiles)
     from bigdl_tpu.ops.quant import get_qtype, quantize
 
     dev = v5e.devices[0]
@@ -94,30 +95,23 @@ def test_dequant_gemv_compiles(v5e, aot_flags, qtype, n):
         lambda: quantize(jnp.zeros((k, n), jnp.float32), qtype))
     x = jax.ShapeDtypeStruct((1, k), jnp.bfloat16)
     comp = _compile(
-        lambda xx, ww: _q_gemv_pallas(xx, ww, qt, 1, k, n, False, xx.dtype),
+        lambda xx, ww: _q_gemv_pallas(xx, ww, qt, 1, k, n,
+                                      gemv_tiles(qt, k, n), False, xx.dtype),
         _sds(x, dev), _sds(wq, dev))
     assert _has_mosaic_call(comp)
-    # scale-folded body (raw codes on the MXU, scales on the partials)
-    if qt.kind != "asym":
-        comp = _compile(
-            lambda xx, ww: _q_gemv_pallas(xx, ww, qt, 1, k, n, False,
-                                          xx.dtype, variant="fold"),
-            _sds(x, dev), _sds(wq, dev))
-        assert _has_mosaic_call(comp)
 
 
-@pytest.mark.parametrize("variant", ["mxu", "mxuflat", "mxu8"])
 @pytest.mark.parametrize("k,n", [
     (4096, 12288),   # merged QKV (7B, fused q+k+v)
     (4096, 22016),   # merged gate-up
     (11008, 4096),   # down-proj
     (4096, 4096),    # o-proj
 ])
-def test_dequant_gemv_mxu_compiles(v5e, aot_flags, variant, k, n):
-    """r5: the MXU-layout GEMV (int4-dtype weights, native Mosaic int4
-    load — no VPU nibble unpack) at all four 7B merged decode shapes,
-    both the bf16 body and the int8-activation body."""
-    from bigdl_tpu.ops.pallas.dequant_matmul import _q_gemv_pallas
+def test_dequant_gemv_mxu_compiles(v5e, aot_flags, k, n):
+    """The int4-dtype-layout GEMV (native Mosaic int4 load — no VPU
+    nibble unpack) at all four 7B merged decode shapes."""
+    from bigdl_tpu.ops.pallas.dequant_matmul import (_q_gemv_pallas,
+                                                     gemv_tiles)
     from bigdl_tpu.ops.probing import quant_struct
     from bigdl_tpu.ops.quant import get_qtype
 
@@ -127,8 +121,8 @@ def test_dequant_gemv_mxu_compiles(v5e, aot_flags, variant, k, n):
     assert wq.data.dtype == jnp.int4
     x = jax.ShapeDtypeStruct((1, k), jnp.bfloat16)
     comp = _compile(
-        lambda xx, ww: _q_gemv_pallas(xx, ww, qt, 1, k, n, False,
-                                      xx.dtype, variant=variant),
+        lambda xx, ww: _q_gemv_pallas(xx, ww, qt, 1, k, n,
+                                      gemv_tiles(qt, k, n), False, xx.dtype),
         _sds(x, dev), _sds(wq, dev))
     assert _has_mosaic_call(comp)
 
@@ -136,7 +130,7 @@ def test_dequant_gemv_mxu_compiles(v5e, aot_flags, variant, k, n):
 def test_dequant_generic_i4_compiles(v5e, aot_flags):
     """Generic-tile body for the int4-dtype layout (prefill-class M
     under forced-pallas dispatch)."""
-    from bigdl_tpu.ops.pallas.dequant_matmul import q_matmul_pallas
+    from bigdl_tpu.ops.matmul import q_matmul_pallas
     from bigdl_tpu.ops.probing import quant_struct
 
     dev = v5e.devices[0]
@@ -163,10 +157,9 @@ def test_dequant_gemm_compiles_at_a_prefill_chunk(v5e, aot_flags, k, n):
     dispatch sends a chunk's linears to it. ChatGLM2's K = 13696 = 2^7 x
     107 (no bk with an 8-row scale block divides it) takes one full-K
     tile. A K with no legal tiling at all (32224 = 2^5 x 1007: the
-    full-K tile is over the VMEM budget) is XLA's by RULE: the probe
-    says so before anything compiles."""
-    from bigdl_tpu.ops.matmul import q_matmul
-    from bigdl_tpu.ops.pallas.dequant_matmul import matmul_kernel_compiles
+    full-K tile is over the VMEM budget) is XLA's by RULE: the tile
+    search says so before anything compiles."""
+    from bigdl_tpu.ops.matmul import kernel_plan, q_matmul
     from bigdl_tpu.ops.probing import quant_struct
 
     dev = v5e.devices[0]
@@ -175,7 +168,7 @@ def test_dequant_gemm_compiles_at_a_prefill_chunk(v5e, aot_flags, k, n):
     comp = _compile(lambda xx, ww: q_matmul(xx, ww),
                     _sds(x, dev), _sds(wq, dev))
     if k == 32224:
-        assert not matmul_kernel_compiles("sym_int4", 256, k, n, mxu=True)
+        assert kernel_plan("sym_int4", 256, k, n, True) is None
         assert not _has_mosaic_call(comp)
     else:
         assert "qmatmul_gemm_sym_int4" in comp.as_text()
@@ -192,18 +185,20 @@ def test_dequant_gemv_compiles_tp4_shards(v5e, aot_flags, k, n):
     dispatch to the decode-GEMV kernel (with pad_ff_for_tp's ff
     lane-padding, 11008 -> 11264). Before the joint (bk, bn) tile
     search, the down-proj shard (K=2752) fell off the kernel entirely."""
-    from bigdl_tpu.ops.pallas.dequant_matmul import (_gemv_tiles,
-                                                     _q_gemv_pallas)
+    from bigdl_tpu.ops.pallas.dequant_matmul import (_q_gemv_pallas,
+                                                     gemv_tiles)
     from bigdl_tpu.ops.quant import get_qtype, quantize
 
     dev = v5e.devices[0]
     qt = get_qtype("sym_int4")
-    assert _gemv_tiles(qt, k, n) is not None, "shape not kernel-eligible"
+    tiles = gemv_tiles(qt, k, n)
+    assert tiles is not None, "shape not kernel-eligible"
     wq = jax.eval_shape(
         lambda: quantize(jnp.zeros((k, n), jnp.float32), "sym_int4"))
     x = jax.ShapeDtypeStruct((1, k), jnp.bfloat16)
     comp = _compile(
-        lambda xx, ww: _q_gemv_pallas(xx, ww, qt, 1, k, n, False, xx.dtype),
+        lambda xx, ww: _q_gemv_pallas(xx, ww, qt, 1, k, n, tiles, False,
+                                      xx.dtype),
         _sds(x, dev), _sds(wq, dev))
     assert _has_mosaic_call(comp)
 
@@ -511,25 +506,18 @@ def test_llama7b_merged_projections_compile(v5e, aot_flags, sq, mxu):
     arrays through the lax.scan layer stack and the M-routed dispatch —
     i.e. the exact program the 08:03 live window timed out on."""
     from bigdl_tpu.models import llama as M
-    from bigdl_tpu.transformers.model import _maybe_mxu_layout
+    from bigdl_tpu.ops.quant import prepack_tree
     from bigdl_tpu.utils.testing import LLAMA2_7B, random_llama_params
 
     dev = v5e.devices[0]
     cfg = LLAMA2_7B
-    from bigdl_tpu.config import flags
-
-    prev = flags().mxu_layout
-    set_flags(mxu_layout="on" if mxu else "off")   # pin: no ambient env
-    try:
-        params = _sds(jax.eval_shape(
-            lambda: _maybe_mxu_layout(M.merge_projections(
-                random_llama_params(cfg, "sym_int4"), cfg))), dev)
-    finally:
-        set_flags(mxu_layout=prev)
+    prepack = "on" if mxu else "off"               # pin: no ambient env
+    params = _sds(jax.eval_shape(
+        lambda: prepack_tree(M.merge_projections(
+            random_llama_params(cfg, "sym_int4"), cfg), prepack)[0]), dev)
     flat = jax.tree_util.tree_leaves(params)
     has_int4 = any(a.dtype == jnp.int4 for a in flat)
-    assert has_int4 == mxu, \
-        f"mxu_layout={'on' if mxu else 'off'} but int4 planes={has_int4}"
+    assert has_int4 == mxu, f"prepack={prepack} but int4 planes={has_int4}"
     cache = _sds(jax.eval_shape(lambda: M.new_cache(cfg, 1, 2048)), dev)
     ids = _sds(jax.ShapeDtypeStruct((1, sq), jnp.int32), dev)
     comp = _compile(
@@ -686,7 +674,7 @@ def test_vmapped_gemv_compiles(v5e, aot_flags):
     """MoE decode gathers per-token expert weights and runs the matmul
     under vmap with dynamic indexing — pallas_call's batching rule must
     lower for v5e too (the vmapped_pallas_ok probe's real path)."""
-    from bigdl_tpu.ops.pallas.dequant_matmul import q_matmul_pallas
+    from bigdl_tpu.ops.matmul import q_matmul_pallas
     from bigdl_tpu.ops.quant import quantize
 
     dev = v5e.devices[0]

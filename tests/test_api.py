@@ -255,12 +255,12 @@ def test_mxu_layout_save_roundtrip(tiny_hf_dir, tmp_path):
     from bigdl_tpu.ops.quant import QTensor
     from bigdl_tpu.transformers import AutoModelForCausalLM
 
-    set_flags(mxu_layout="on")
+    set_flags(prepack="on")
     try:
         m1 = AutoModelForCausalLM.from_pretrained(
             tiny_hf_dir, load_in_4bit=True, max_seq=64)
     finally:
-        set_flags(mxu_layout="auto")
+        set_flags(prepack="auto")
     # the layout actually applied (int4-dtype planes present)
     datas = [leaf.data.dtype for leaf in jax.tree_util.tree_leaves(
         m1.params, is_leaf=lambda x: isinstance(x, QTensor))

@@ -125,10 +125,8 @@ def test_chunked_xla_matches_dense():
 @pytest.mark.parametrize("qtype", ["sym_int4", "sym_int8", "nf4"])
 @pytest.mark.parametrize("m", [17, 32])
 def test_gemv_wide_m_matches_xla(qtype, m):
-    from bigdl_tpu.ops.pallas.dequant_matmul import (
-        GEMV_MAX_M,
-        q_matmul_pallas,
-    )
+    from bigdl_tpu.ops.matmul import q_matmul_pallas
+    from bigdl_tpu.ops.pallas.dequant_matmul import GEMV_MAX_M
 
     assert m <= GEMV_MAX_M
     k, n = 512, 256

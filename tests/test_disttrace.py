@@ -298,8 +298,7 @@ def test_engine_spans_and_phase_decomposition(model):
         assert s["attrs"]["request_id"] == "tr-1"
 
     # the step-phase histograms and the dispatch EWMA populate without
-    # any trace attached — bench_serving's critical_path block reads
-    # these from a traceless wave
+    # any trace attached
     summ = eng.registry.summary()
     for ph in ("queue_wait", "prefill", "dispatch", "device"):
         key = 'bigdl_tpu_step_phase_seconds{phase="%s"}' % ph

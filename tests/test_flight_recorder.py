@@ -2,14 +2,12 @@
 bounds, tracked_jit compile counting (cache hits vs new shapes, storm
 warning), postmortem dumps on injected step exceptions and stall-guard
 trips, the /v1/debug/dump and /v1/profiler/status endpoints, event-log
-rotation, StepTimer interpolated percentiles, and the bench_diff CLI."""
+rotation, StepTimer interpolated percentiles."""
 
 import glob
 import json
 import os
 import signal
-import subprocess
-import sys
 import urllib.request
 
 import pytest
@@ -22,8 +20,6 @@ from bigdl_tpu.observability import (FlightRecorder, MetricsRegistry,
                                      tracked_jit, validate_postmortem_dir)
 from bigdl_tpu.serving import EngineConfig, LLMEngine, SamplingParams
 from bigdl_tpu.utils.testing import TINY_LLAMA, random_llama_params
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class FakeModel:
@@ -396,54 +392,3 @@ def test_steptimer_interpolated_percentiles():
     single = StepTimer()
     single.record("one", 0.005)
     assert single.summary()["one"]["p99_ms"] == pytest.approx(5.0)
-
-
-# ---------------------------------------------------------------------------
-# bench_diff CLI
-# ---------------------------------------------------------------------------
-
-def _run_bench_diff(*argv):
-    return subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "bench_diff.py"),
-         *argv],
-        capture_output=True, text=True)
-
-
-def test_bench_diff_detects_regression(tmp_path):
-    old = tmp_path / "old.json"
-    new = tmp_path / "new.json"
-    old.write_text(json.dumps({
-        "first_token_ms": 100.0, "next_token_ms": 10.0,
-        "kv_cache_bytes": 1000, "serving_tokens_per_s": 50.0}))
-    new.write_text(json.dumps({
-        "first_token_ms": 101.0, "next_token_ms": 14.0,   # +40%: regression
-        "kv_cache_bytes": 1000, "serving_tokens_per_s": 51.0}))
-    r = _run_bench_diff(str(old), str(new), "--threshold", "5")
-    assert r.returncode == 1, r.stdout + r.stderr
-    assert "REGRESSION" in r.stdout and "next_token_ms" in r.stdout
-
-    # within threshold: clean exit
-    r = _run_bench_diff(str(old), str(new), "--threshold", "50")
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "no regressions" in r.stdout
-
-
-def test_bench_diff_throughput_direction_and_wrapper(tmp_path):
-    old = tmp_path / "old.json"
-    new = tmp_path / "new.json"
-    # wrapper form (the BENCH_r*.json driver format), throughput DOWN
-    old.write_text(json.dumps({
-        "n": 1, "cmd": "bench", "rc": 0, "tail": "",
-        "parsed": {"serving_tokens_per_s": 100.0,
-                   "first_token_ms": 50.0}}))
-    new.write_text(json.dumps({
-        "n": 2, "cmd": "bench", "rc": 0, "tail": "",
-        "parsed": {"serving_tokens_per_s": 60.0,     # -40%: regression
-                   "first_token_ms": 49.0}}))
-    r = _run_bench_diff(str(old), str(new))
-    assert r.returncode == 1
-    assert "serving_tokens_per_s" in r.stdout
-
-    # unreadable input: usage error, distinct from "regression found"
-    r = _run_bench_diff(str(old), str(tmp_path / "missing.json"))
-    assert r.returncode == 2

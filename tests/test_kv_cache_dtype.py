@@ -5,8 +5,6 @@ plumbing (deprecated boolean alias, env validation), and the serving
 engine end-to-end (including prefix-cache seeding of quantized caches)."""
 
 import dataclasses
-import os
-import sys
 import warnings
 
 import numpy as np
@@ -578,17 +576,3 @@ def test_from_pretrained_kwarg_conflict_free(tmp_path):
     assert m.kv_cache_dtype == "fp8_e5m2" and m.kv_quantized
     m = TpuCausalLM({}, None, object(), {}, None)
     assert m.kv_cache_dtype == "bf16" and not m.kv_quantized
-
-
-def test_bench_kv_sweep_flag_parsing():
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-
-    assert bench._parse_kv_sweep([]) is None
-    assert bench._parse_kv_sweep(
-        ["--kv-cache-dtype", "bf16,int8"]) == ["bf16", "int8"]
-    assert bench._parse_kv_sweep(
-        ["--kv-cache-dtype=int4"]) == ["int4"]
-    with pytest.raises(ValueError):
-        bench._parse_kv_sweep(["--kv-cache-dtype", "int2"])

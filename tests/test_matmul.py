@@ -71,25 +71,26 @@ def test_q_matmul_under_jit():
 def test_auto_dispatch_m_threshold(monkeypatch):
     """Auto dispatch on a TPU target: decode rows, a prefill chunk's 256
     rows and every row count up to the crossover measured on the chip
-    (`RuntimeFlags.matmul_pallas_max_m`) take the Pallas kernel; rows
-    above it (QLoRA's 8192-row forward) take the XLA
-    dequantize-then-dot plan and are counted as a rule. A forced backend
-    ignores the row count either way."""
+    (`matmul.PALLAS_MAX_ROWS`) take the Pallas kernel; rows above it
+    (QLoRA's 8192-row forward) take the XLA dequantize-then-dot plan and
+    are counted as a rule. A forced backend ignores the row count either
+    way."""
     import bigdl_tpu.ops.pallas.dequant_matmul as dq
     import bigdl_tpu.ops.probing as probing
-    from bigdl_tpu.config import flags, set_flags
-    from bigdl_tpu.ops.matmul import _q_matmul_xla
+    from bigdl_tpu.config import set_flags
+    from bigdl_tpu.ops.matmul import PALLAS_MAX_ROWS, _q_matmul_xla
 
     w = quantize(_rand((64, 128)) * 0.05, "sym_int4")
     seen, ruled = [], []
 
-    def fake_impl(x, wq, **kw):
+    def fake_kernel(x, wq, gemv, tiles, **kw):
+        assert gemv == (x.shape[0] <= dq.GEMV_MAX_M) and tiles == (64, 128)
         seen.append(int(x.shape[0]))
         return _q_matmul_xla(x, wq)
 
-    monkeypatch.setattr(dq, "q_matmul_pallas_impl", fake_impl)
+    monkeypatch.setattr(dq, "q_matmul_kernel", fake_kernel)
     monkeypatch.setattr(probing, "record_dispatch_rule", ruled.append)
-    crossover = flags().matmul_pallas_max_m
+    crossover = PALLAS_MAX_ROWS
     assert 256 <= crossover < 8192
     ones = lambda m: jnp.ones((m, 64), jnp.bfloat16)  # noqa: E731
     set_flags(aot_target="tpu")
@@ -105,12 +106,117 @@ def test_auto_dispatch_m_threshold(monkeypatch):
         q_matmul(ones(8192), w, backend="pallas")
         q_matmul(ones(8), w, backend="xla")
         assert seen[-1] == 8192 and len(seen) == 6 and len(ruled) == 2
-        # the flag moves the crossover
-        set_flags(matmul_pallas_max_m=128)
-        q_matmul(ones(256), w)
-        assert len(seen) == 6 and len(ruled) == 3
     finally:
-        set_flags(aot_target=None, matmul_pallas_max_m=crossover)
+        set_flags(aot_target=None)
+
+
+# What the PARENT of the PR that wrote `select_matmul` (07c3ea7) chose,
+# read from its `_q_matmul_dispatch` under aot_target="tpu", at every
+# quantized linear of the benchmark's three configurations
+# (tools/qmatmul_ab.SHAPES) in the layout a TPU load gives sym_int4:
+# [K, N] -> the plan at 8 rows (decode), at 256 (a prefill chunk). At
+# 8192 rows (QLoRA's forward) every one was plain XLA. ChatGLM2's
+# K = 13696 = 2^7 x 107 has no GEMV tiling (fused XLA) and one full-K
+# GEMM tile.
+_PARENT_CHOSE = {
+    (4096, 6144): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
+    (4096, 4096): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
+    (4096, 28672): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
+    (14336, 4096): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
+    (4096, 4608): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
+    (4096, 27392): (('gemv_mxu', (4096, 256)), ('gemm', (4096, 256))),
+    (13696, 4096): (('xla_fused', None), ('gemm', (13696, 128))),
+    (5120, 1536): (('gemv_mxu', (5120, 256)), ('gemm', (5120, 256))),
+    (1536, 24576): (('gemv_mxu', (1536, 512)), ('gemm', (1536, 512))),
+    (5120, 640): (('gemv_mxu', (5120, 128)), ('gemm', (5120, 128))),
+    (16384, 5120): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
+    (5120, 3072): (('gemv_mxu', (5120, 256)), ('gemm', (5120, 256))),
+    (3072, 5120): (('gemv_mxu', (3072, 256)), ('gemm', (3072, 512))),
+    (5120, 12288): (('gemv_mxu', (5120, 256)), ('gemm', (5120, 256))),
+    (12288, 5120): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
+}
+_TPU = dict(int4_layout=True, spmd=False, tpu=True)
+_CANON = dict(_TPU, int4_layout=False)
+# the rules, one case each: (qtype, rows, K, N, what the call sees, plan)
+_RULES = [
+    # the GEMV's last row and the GEMM's first; the crossover
+    ("sym_int4", 32, 4096, 4096, _TPU, ("gemv_mxu", (2048, 512))),
+    ("sym_int4", 33, 4096, 4096, _TPU, ("gemm", (4096, 512))),
+    ("sym_int4", 1024, 4096, 4096, _TPU, ("gemm", (2048, 512))),
+    ("sym_int4", 1025, 4096, 4096, _TPU, ("xla", None)),
+    # the body follows from the layout: canonical packing, codebooks,
+    # zero points and int8 take the std body
+    ("sym_int4", 8, 4096, 4096, _CANON, ("gemv_std", (2048, 512))),
+    ("nf4", 8, 4096, 4096, _CANON, ("gemv_std", (2048, 512))),
+    ("asym_int4", 8, 4096, 4096, _CANON, ("gemv_std", (2048, 512))),
+    ("sym_int8", 8, 4096, 4096, _CANON, ("gemv_std", (2048, 512))),
+    ("nf4", 256, 4096, 4096, _CANON, ("gemm", (2048, 512))),
+    # a tp=4 shard of ff = 11008: legal only as ONE full-K block
+    ("sym_int4", 8, 2752, 4096, _CANON, ("gemv_std", (2752, 256))),
+    # no legal tiling: fused XLA at decode rows for the qtypes it
+    # covers, chunked for the rest against a 7B-class weight, dense above
+    ("nf4", 8, 13696, 4096, _CANON, ("xla_fused", None)),
+    ("fp4", 8, 13696, 4096, _CANON, ("xla_chunked", None)),
+    ("sym_int4", 256, 32224, 4096, _TPU, ("xla", None)),
+    # qtypes the kernels do not cover: the heavy ones chunked, the
+    # others chunked at decode rows against a 7B-class weight
+    ("q2_k", 8, 4096, 11008, _CANON, ("xla_chunked", None)),
+    ("q2_k", 256, 4096, 11008, _CANON, ("xla_chunked", None)),
+    ("sym_int5", 8, 4096, 11008, _CANON, ("xla_chunked", None)),
+    ("sym_int5", 64, 4096, 11008, _CANON, ("xla", None)),
+    ("sym_int5", 8, 4096, 4096, _CANON, ("xla", None)),
+    # GSPMD operands: Mosaic kernels cannot be partitioned
+    ("sym_int4", 8, 4096, 4096, dict(_TPU, spmd=True), ("xla_fused", None)),
+    ("sym_int4", 256, 4096, 4096, dict(_TPU, spmd=True), ("xla", None)),
+    # a CPU: XLA, never fused
+    ("sym_int4", 8, 4096, 4096, dict(_TPU, tpu=False), ("xla", None)),
+    ("sym_int4", 256, 4096, 4096, dict(_TPU, tpu=False), ("xla", None)),
+    # forced backends take their plan whatever the target and the rows
+    ("sym_int4", 8192, 4096, 4096, dict(_TPU, tpu=False, backend="pallas"),
+     ("gemm", (2048, 512))),
+    ("sym_int4", 8, 4096, 4096, dict(_TPU, backend="xla"), ("xla", None)),
+    ("sym_int4", 256, 4096, 4096, dict(_TPU, backend="xla_fused"),
+     ("xla_fused", None)),
+    ("q2_k", 8, 4096, 4096, dict(_CANON, backend="xla_fused"),
+     ("xla_chunked", None)),
+    # ... and forced "pallas" raises where the kernel has no plan
+    ("sym_int4", 8, 13696, 4096, dict(_TPU, backend="pallas"), None),
+    ("sym_int5", 8, 4096, 4096, dict(_CANON, backend="pallas"), None),
+]
+
+
+@pytest.mark.parametrize("qtype,rows,k,n,sees,want", [
+    ("sym_int4", rows, k, n, _TPU, plan)
+    for (k, n), at in _PARENT_CHOSE.items()
+    for rows, plan in zip((8, 256, 8192), (*at, ("xla", None)))] + _RULES)
+def test_kernel_selection_table(qtype, rows, k, n, sees, want):
+    """`select_matmul` is the one place a quantized linear's plan is
+    chosen, from what the call can see; at every linear of the three
+    benchmark configurations, at decode, chunk and training rows, it
+    chooses what the parent's flags and fall-throughs chose."""
+    from bigdl_tpu.ops.matmul import MatmulPlan, select_matmul
+
+    if want is None:
+        with pytest.raises(NotImplementedError, match="no Pallas plan"):
+            select_matmul(qtype, rows, k, n, **sees)
+        return
+    assert select_matmul(qtype, rows, k, n, **sees) == MatmulPlan(*want)
+
+
+def test_selection_table_covers_every_benchmark_linear(monkeypatch):
+    import importlib.util
+    import pathlib
+    import sys
+
+    monkeypatch.setattr(sys, "path", list(sys.path))   # the tool extends it
+    spec = importlib.util.spec_from_file_location(
+        "qmatmul_ab", pathlib.Path(__file__).parents[1] / "tools"
+        / "qmatmul_ab.py")
+    ab = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ab)
+    assert set(_PARENT_CHOSE) == {s for fam in ab.SHAPES.values()
+                                  for s in fam}
+    assert len(_PARENT_CHOSE) == 15
 
 
 @pytest.mark.parametrize("qtype", ["q2_k", "iq2_xxs", "iq1_s"])

@@ -2,7 +2,7 @@
 live-telemetry fallback contracts, compile-table memory capture,
 headroom-aware admission (defer then resume, deterministically, via an
 injected stats provider), the /v1/memory endpoint, postmortem memory
-snapshots, and bench_diff's memory comparison."""
+snapshots."""
 
 import json
 import threading
@@ -354,38 +354,3 @@ def test_v1_memory_endpoint(model):
     finally:
         server.shutdown()
 
-
-# -- bench_diff memory comparison -----------------------------------------
-
-
-def test_bench_diff_memory_scalars(tmp_path):
-    import sys
-    sys.path.insert(0, str(__import__("pathlib").Path(
-        __file__).resolve().parent.parent / "tools"))
-    try:
-        import bench_diff
-    finally:
-        sys.path.pop(0)
-
-    old = {"first_token_ms": 10.0,
-           "memory": {"hbm_static_total_bytes": 1000,
-                      "hbm_device_peak_bytes": 2000,
-                      "static": {"by_kind": {"weights": 1000}}}}
-    new = {"first_token_ms": 10.0,
-           "memory": {"hbm_static_total_bytes": 1200,
-                      "hbm_device_peak_bytes": 2000}}
-    fo = bench_diff.flatten_metrics(old)
-    fn = bench_diff.flatten_metrics(new)
-    # nested snapshot dicts are NOT compared, headline scalars are
-    assert "memory.hbm_static_total_bytes" in fo
-    assert not any("by_kind" in k for k in fo)
-    # 20% static growth passes a loose HBM threshold, fails a tight one
-    _, reg = bench_diff.diff(fo, fn, 5.0, hbm_threshold_pct=25.0)
-    assert reg == []
-    _, reg = bench_diff.diff(fo, fn, 5.0, hbm_threshold_pct=10.0)
-    assert reg == ["memory.hbm_static_total_bytes"]
-    # a record missing the memory block entirely still compares
-    op, np_ = tmp_path / "o.json", tmp_path / "n.json"
-    op.write_text(json.dumps(old))
-    np_.write_text(json.dumps({"first_token_ms": 10.2}))
-    assert bench_diff.main([str(op), str(np_)]) == 0

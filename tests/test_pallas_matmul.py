@@ -10,8 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bigdl_tpu.ops.matmul import _q_matmul_xla
-from bigdl_tpu.ops.pallas.dequant_matmul import q_matmul_pallas
+from bigdl_tpu.ops.matmul import _q_matmul_xla, q_matmul_pallas
 from bigdl_tpu.ops.quant import quantize
 
 
@@ -76,21 +75,20 @@ def test_pallas_large_k_tiling():
 @pytest.mark.parametrize(
     "qtype", ["sym_int4", "asym_int4", "nf4", "sym_int8"])
 def test_gemv_variant_matches_generic(qtype):
-    """The decode-GEMV specialization (m<=16) must match the generic
-    tiling bit-for-bit-close across qtypes and multi-tile K."""
-    from bigdl_tpu.config import set_flags
+    """The decode-GEMV specialization (m<=32) must match the GEMM's
+    tiling (called directly: no rule sends decode rows there)
+    bit-for-bit-close across qtypes and multi-tile K."""
+    from bigdl_tpu.ops.pallas.dequant_matmul import (_q_matmul_generic,
+                                                     gemm_tiles)
+    from bigdl_tpu.ops.quant import get_qtype
 
     k, n = 1024, 256
     x = _rand((1, k), seed=7) * 0.3
     qt = quantize(_rand((k, n), seed=8) * 0.1, qtype)
-    try:
-        got = q_matmul_pallas(x, qt, interpret=True)       # gemv (auto)
-        set_flags(matmul_gemv="off")
-        jax.clear_caches()       # flags are read at trace time
-        want = q_matmul_pallas(x, qt, interpret=True)      # generic tiles
-    finally:
-        set_flags(matmul_gemv="auto")
-        jax.clear_caches()
+    got = q_matmul_pallas(x, qt, interpret=True)           # gemv
+    want = _q_matmul_generic(
+        x.astype(jnp.bfloat16), qt, get_qtype(qtype), 1, k, n,
+        gemm_tiles(get_qtype(qtype), k, n, 1), True, x.dtype)
     # different tile sweeps accumulate bf16 products in different orders
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
@@ -111,13 +109,9 @@ def test_gemv_padded_k():
     )
 
 
-@pytest.mark.parametrize("gv", ["auto", "mxuflat", "mxu8"])
-def test_gemv_mxu_layout_matches_reference(gv):
-    """r5 MXU layout: int4-dtype weights through the native-load GEMV
-    bodies (bf16 fold under 'auto', int8-activation under 'mxu8') must
-    match the dequant reference. mxu8 quantizes activations to q8 per
-    block, so its tolerance is the q8 rounding band, not exactness."""
-    from bigdl_tpu.config import set_flags
+def test_gemv_mxu_layout_matches_reference():
+    """Int4-dtype weights through the native-load GEMV body must match
+    the dequant reference."""
     from bigdl_tpu.ops.quant import to_mxu_layout, from_mxu_layout
 
     k, n = 1024, 256
@@ -128,18 +122,11 @@ def test_gemv_mxu_layout_matches_reference(gv):
     # round trip is bit-exact
     np.testing.assert_array_equal(
         np.asarray(from_mxu_layout(qm).data), np.asarray(qt.data))
-    try:
-        set_flags(matmul_gemv=gv)
-        jax.clear_caches()       # flags are read at trace time
-        got = q_matmul_pallas(x, qm, interpret=True)
-    finally:
-        set_flags(matmul_gemv="auto")
-        jax.clear_caches()
+    got = q_matmul_pallas(x, qm, interpret=True)
     want = _q_matmul_xla(x, qt)
-    tol = 6e-2 if gv == "mxu8" else 3e-2
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
-        rtol=tol, atol=tol,
+        rtol=3e-2, atol=3e-2,
     )
 
 
@@ -191,28 +178,3 @@ def test_mxu_layout_layer_stacked():
         qt, data=jnp.stack([jnp.stack([qt.data] * 2)] * 3),
         scale=jnp.stack([jnp.stack([qt.scale] * 2)] * 3))
     assert to_mxu_layout(experts) is experts
-
-
-@pytest.mark.parametrize(
-    "qtype", ["sym_int4", "nf4", "sym_int8", "asym_int4"])
-def test_gemv_fold_variant_matches_reference(qtype):
-    """The scale-folded GEMV body (raw codes on the MXU, scales applied
-    to per-block partials) must match the dequant reference; asym
-    formats silently keep the standard body under matmul_gemv=fold."""
-    from bigdl_tpu.config import set_flags
-
-    k, n = 1024, 256
-    x = _rand((1, k), seed=11) * 0.3
-    qt = quantize(_rand((k, n), seed=12) * 0.1, qtype)
-    try:
-        set_flags(matmul_gemv="fold")
-        jax.clear_caches()       # flags are read at trace time
-        got = q_matmul_pallas(x, qt, interpret=True)
-    finally:
-        set_flags(matmul_gemv="auto")
-        jax.clear_caches()
-    want = _q_matmul_xla(x, qt)
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32),
-        rtol=3e-2, atol=3e-2,
-    )

@@ -4,12 +4,10 @@ utils/profiling.py, and their engine wiring).
 
 Four invariants from the PR that introduced them:
 
-1. **Bench identity** — ``roofline.efficiency`` reproduces the exact
-   numbers a fixed bench fixture printed (bench.py imports the same
-   function, so bench output and live gauges cannot drift), and the
-   live-gauge formula (``decode_costs``) agrees with the bench
-   ``decode_hbm_roofline_util`` formula to 4 decimals for a bf16
-   cache at batch 1.
+1. **Formula identity** — ``roofline.efficiency`` reproduces the
+   exact numbers of a fixed fixture, and the live-gauge formula
+   (``decode_costs``) agrees with its ``decode_hbm_roofline_util``
+   to 4 decimals for a bf16 cache at batch 1.
 2. **Sentinel state machine** — trips after N consecutive
    past-threshold steps, recovers with hysteresis dwell, loads its
    baseline from (and appends to) the size-rotated perf-history JSONL,
@@ -61,11 +59,11 @@ def _v5e_peaks(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# analytical model vs the bench fixture
+# analytical model vs the fixture
 
 
 class _Llama7B:
-    """LLaMA-2-7B dims, as bench.py's LLAMA2_7B config carries them."""
+    """LLaMA-2-7B dims."""
 
     hidden_size = 4096
     intermediate_size = 11008
@@ -84,8 +82,7 @@ _FIX_FIRST_MS, _FIX_NEXT_MS = 109.301, 28.607
 
 
 def test_efficiency_reproduces_fixture():
-    """The exact fixture numbers: bench.py now imports this function,
-    so a drift here is a drift in every headline bench record."""
+    """The exact fixture numbers pin the formulas."""
     out = roofline.efficiency(_Llama7B, _FIX_WEIGHT_BYTES, _FIX_PROMPT,
                               _FIX_STEPS, _FIX_FIRST_MS, _FIX_NEXT_MS)
     assert out["decode_hbm_roofline_util"] == 0.1935
@@ -93,29 +90,6 @@ def test_efficiency_reproduces_fixture():
     assert out["decode_mfu"] == 0.00244
     assert out["prefill_mfu"] == 0.6412
     assert out["weight_bytes"] == _FIX_WEIGHT_BYTES
-
-
-def test_bench_efficiency_delegates_to_roofline():
-    """bench.py's `_efficiency` is the same function, value-identical
-    (the old inline math is gone)."""
-    bench = pytest.importorskip("bench")
-    want = roofline.efficiency(_Llama7B, _FIX_WEIGHT_BYTES, _FIX_PROMPT,
-                               _FIX_STEPS, _FIX_FIRST_MS, _FIX_NEXT_MS)
-    got = bench._efficiency(_Llama7B, _FIX_WEIGHT_BYTES, _FIX_PROMPT,
-                            _FIX_STEPS, _FIX_FIRST_MS, _FIX_NEXT_MS)
-    assert got == want
-
-
-def test_bench_roofline_block_embeds_attribution():
-    bench = pytest.importorskip("bench")
-    rec = bench._roofline_block(_Llama7B, _FIX_WEIGHT_BYTES, _FIX_PROMPT,
-                                _FIX_STEPS, _FIX_FIRST_MS, _FIX_NEXT_MS)
-    assert rec["decode_hbm_roofline_util"] == 0.1935
-    attr = rec["roofline"]
-    assert attr["decode"]["ideal_ms"] == pytest.approx(5.534561, abs=1e-6)
-    assert attr["decode"]["hbm_roofline_util"] == 0.1935
-    assert attr["prefill"]["mfu"] == 0.6412
-    assert attr["peak_hbm_gbps"] > 0
 
 
 def test_decode_costs_agree_with_bench_formula():
@@ -542,25 +516,3 @@ def test_slow_step_chaos_trips_sentinel_and_captures(
     assert "perf_recovered" in events
     snap = eng.sentinel.snapshot()
     assert snap["trips"] == 1 and snap["recoveries"] == 1
-
-
-def test_perf_regression_counter_is_zero_gated_in_bench_diff():
-    """CI gate: any nonzero bigdl_tpu_perf_regression_total in a bench
-    counters block fails tools/bench_diff.py even if the old record
-    never exported the counter."""
-    from tools.bench_diff import ZERO_COUNTERS, diff
-
-    assert "bigdl_tpu_perf_regression_total" in ZERO_COUNTERS
-    name = ("serving.counters."
-            'bigdl_tpu_perf_regression_total{metric="decode_ms"}')
-    # nonzero in the candidate regresses even with a matching baseline
-    _, regressions = diff({name: (2.0, "lower")},
-                          {name: (2.0, "lower")}, 5.0)
-    assert name in regressions
-    # candidate-only (baseline predates the sentinel) still fails
-    _, regressions = diff({}, {name: (1.0, "lower")}, 5.0)
-    assert name in regressions
-    # exactly zero stays green
-    _, regressions = diff({name: (0.0, "lower")},
-                          {name: (0.0, "lower")}, 5.0)
-    assert name not in regressions

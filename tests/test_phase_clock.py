@@ -400,7 +400,7 @@ def _pallas_names(fn, *args):
 ])
 def test_dequant_matmul_kernels_are_named_by_shape_class_and_qtype(
         qtype, m, want):
-    from bigdl_tpu.ops.pallas.dequant_matmul import q_matmul_pallas_impl
+    from bigdl_tpu.ops.matmul import q_matmul_pallas_impl
     from bigdl_tpu.ops.quant import quantize
 
     w = quantize(jnp.ones((256, 256), jnp.float32), qtype)
@@ -410,7 +410,7 @@ def test_dequant_matmul_kernels_are_named_by_shape_class_and_qtype(
 
 
 def test_the_int4_dtype_layout_names_its_generic_kernel_too():
-    from bigdl_tpu.ops.pallas.dequant_matmul import q_matmul_pallas_impl
+    from bigdl_tpu.ops.matmul import q_matmul_pallas_impl
     from bigdl_tpu.ops.quant import quantize, to_mxu_layout
 
     w = to_mxu_layout(quantize(jnp.ones((256, 256), jnp.float32),

@@ -535,38 +535,6 @@ def test_logit_drift_chaos_trips_quality_sentinel(
     assert snap["trips"] == 1 and snap["recoveries"] == 1
 
 
-def test_quality_counter_is_zero_gated_in_bench_diff():
-    """CI gate: any nonzero bigdl_tpu_quality_regression_total in a
-    bench counters block fails tools/bench_diff.py, and the quality
-    block's nll_delta_vs_bf16 only ratchets DOWN."""
-    from tools.bench_diff import ZERO_COUNTERS, diff, flatten_metrics
-
-    assert "bigdl_tpu_quality_regression_total" in ZERO_COUNTERS
-    name = ("serving.counters."
-            'bigdl_tpu_quality_regression_total{metric="probe_nll"}')
-    _, regressions = diff({name: (1.0, "lower")},
-                          {name: (1.0, "lower")}, 5.0)
-    assert name in regressions
-    _, regressions = diff({}, {name: (1.0, "lower")}, 5.0)
-    assert name in regressions
-    _, regressions = diff({name: (0.0, "lower")},
-                          {name: (0.0, "lower")}, 5.0)
-    assert name not in regressions
-
-    # the NLL ratchet: flattened from the quality block, lower-only
-    flat = flatten_metrics(
-        {"quality": {"qtype": "q2_k", "nll_delta_vs_bf16": 0.00995}})
-    assert flat == {"quality.nll_delta_vs_bf16": (0.00995, "lower")}
-    old = {"quality.nll_delta_vs_bf16": (0.010, "lower")}
-    # 2% default tolerance: a 50% jump regresses, a shrink passes
-    _, regressions = diff(
-        old, {"quality.nll_delta_vs_bf16": (0.015, "lower")}, 5.0)
-    assert "quality.nll_delta_vs_bf16" in regressions
-    _, regressions = diff(
-        old, {"quality.nll_delta_vs_bf16": (0.005, "lower")}, 5.0)
-    assert "quality.nll_delta_vs_bf16" not in regressions
-
-
 # ---------------------------------------------------------------------------
 # NLL-tolerance canary mode (stub router — no processes)
 

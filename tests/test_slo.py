@@ -14,8 +14,7 @@ Coverage map (ISSUE 18):
 - ``CanaryProber`` — golden record/compare against a stub router,
   mismatch quarantine, transport-error tolerance;
 - ``logit_drift`` fault — parse validation + sticky ``drift_rows``;
-- satellite gates — ``stats.percentile`` ≡ ``np.percentile`` and the
-  ``bench_diff`` SLO/canary zero-gates.
+- satellite gate — ``stats.percentile`` ≡ ``np.percentile``.
 """
 
 from __future__ import annotations
@@ -547,35 +546,6 @@ def test_stats_percentile_matches_numpy():
     for q in (0.0, 0.25, 0.5, 0.9, 0.99, 1.0):
         assert percentile(data, q) == float(np.percentile(data, q * 100))
     assert percentile([7.0], 0.5) == 7.0
-
-
-def test_bench_diff_slo_gates():
-    from bench_diff import (
-        METRIC_DIRECTIONS,
-        ROUTER_COUNTERS,
-        ZERO_COUNTERS,
-        diff,
-    )
-    assert METRIC_DIRECTIONS["slo_burn_rate_max"] == "lower"
-    assert METRIC_DIRECTIONS["slo_compliance_ttft"] == "higher"
-    assert METRIC_DIRECTIONS["slo_compliance_tpot"] == "higher"
-    assert ROUTER_COUNTERS["canary_failures"] == "lower"
-    assert "slo_alerts" in ZERO_COUNTERS
-    assert "canary_failures" in ZERO_COUNTERS
-    # the overload lane's burn is recorded but deliberately ungated
-    assert "slo_burn_rate_overload" not in METRIC_DIRECTIONS
-
-    # any nonzero candidate value trips the gate, even inside threshold
-    _, reg = diff({"serve.slo_alerts": (0.0, "lower")},
-                  {"serve.slo_alerts": (1.0, "lower")}, 1000.0)
-    assert reg == ["serve.slo_alerts"]
-    # candidate-only zero-gated counters still fail
-    _, reg = diff({}, {"router.canary_failures": (2.0, "lower")}, 5.0)
-    assert reg == ["router.canary_failures"]
-    # zero stays green
-    _, reg = diff({"serve.slo_alerts": (0.0, "lower")},
-                  {"serve.slo_alerts": (0.0, "lower")}, 5.0)
-    assert reg == []
 
 
 # ---------------------------------------------------------------------------

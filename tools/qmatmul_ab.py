@@ -1,6 +1,6 @@
 """Quantized linear on the chip: the Pallas dequant GEMM against the XLA
 dequantize-then-dot plan, per shape and row count, device time from a
-profiler trace. The table behind `RuntimeFlags.matmul_pallas_max_m`.
+profiler trace. The table behind `ops/matmul.PALLAS_MAX_ROWS`.
 
     chiprun -- python3 tools/qmatmul_ab.py [--shapes mistral,chatglm2,deepseek]
         [--rows 256,512,1024,2048,8192] [--qtype sym_int4] [--prepack on|off]
@@ -130,8 +130,7 @@ def main(argv=None) -> int:
     if jax.devices()[0].platform != "tpu":
         print(json.dumps({"ok": False, "why": "no TPU"}))
         return 3
-    from bigdl_tpu.ops.matmul import q_matmul
-    from bigdl_tpu.ops.pallas.dequant_matmul import matmul_kernel_compiles
+    from bigdl_tpu.ops.matmul import kernel_plan, q_matmul
     from bigdl_tpu.ops.quant import get_qtype
 
     rows = [int(r) for r in args.rows.split(",")]
@@ -146,8 +145,8 @@ def main(argv=None) -> int:
         for m in rows:
             x = jax.random.normal(jax.random.PRNGKey(m), (m, k), jnp.bfloat16)
             for be in ("xla", "pallas"):
-                if be == "pallas" and not matmul_kernel_compiles(
-                        args.qtype, m, kp, n, mxu=mxu):
+                if be == "pallas" and kernel_plan(
+                        args.qtype, m, kp, n, mxu) is None:
                     continue                      # no legal tiling: a rule
                 fn = program(
                     lambda a, w, be=be: q_matmul(a, w, backend=be),
