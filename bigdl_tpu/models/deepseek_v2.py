@@ -379,7 +379,9 @@ def forward(
     last_only: bool = False,
 ) -> Tuple[jax.Array, KVCache]:
     b, sq = tokens.shape
-    pos = cache.pos
+    # serving marks an empty slot with -1: here it is a slot at 0, whose
+    # one block the latent kernels read and write as they always did
+    pos = jnp.maximum(cache.pos, 0)
     x = embedding_lookup(params["embed_tokens"], tokens, compute_dtype)
     cos, sin, scale = _rope_tables(cfg, pos, sq)
     eps = cfg.rms_norm_eps

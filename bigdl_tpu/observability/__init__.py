@@ -90,6 +90,9 @@ bigdl_tpu_request_phase_seconds{phase=...}  RequestSpan queue/prefill/decode;
 bigdl_tpu_step_phase_seconds{phase=...}     tracing.PhaseClock in LLMEngine.step
 bigdl_tpu_prefill_chunks_total              LLMEngine._admission_step
 bigdl_tpu_prefill_tokens_total{kind}        LLMEngine._admission_step
+bigdl_tpu_decode_attn_blocks_total{kind}    LLMEngine._decode_step (slab K/V
+                                            cache): decode_attention's
+                                            blocks_read / slab_blocks
 bigdl_tpu_stream_delivery_seconds           api_server stream handler
 bigdl_tpu_ttft_seconds                      RequestSpan.ttft_s
 bigdl_tpu_tpot_seconds                      LLMEngine.step() decode timing

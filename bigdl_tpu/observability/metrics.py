@@ -29,6 +29,7 @@ module and observability/__init__ for the field mapping):
         dispatch|device|sample|emit|host (per step that decoded)} histogram
     bigdl_tpu_prefill_chunks_total                               counter
     bigdl_tpu_prefill_tokens_total{kind=prompt|padding}          counter
+    bigdl_tpu_decode_attn_blocks_total{kind=read|slab}           counter
     bigdl_tpu_stream_delivery_seconds (serving/api_server.py)    histogram
     bigdl_tpu_ttft_seconds                                       histogram
     bigdl_tpu_tpot_seconds                                       histogram

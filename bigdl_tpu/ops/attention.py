@@ -136,6 +136,9 @@ def sdp_attention(
 
     Query i attends keys j where j <= q_pos + i (and within the sliding
     window if set). Returns [B, Sq, H, D] in q.dtype. Softmax in f32.
+    A per-slot `q_pos` below 0 marks a slot that holds nothing (serving's
+    empty slots): its row is finite and means nothing — zeros from the
+    decode kernel, which multiplies no key for it; the XLA ops read key 0.
 
     Decode (Sq=1) on TPU dispatches to the fused Pallas kernel
     (ops/pallas/decode_attention — the reference's `sdp_fp8`/ESIMD
@@ -255,8 +258,10 @@ def sdp_attention(
 
     k_ids = jnp.arange(skv, dtype=jnp.int32)                 # [Skv]
     if getattr(q_pos, "ndim", 0) == 1:
-        # per-slot positions (continuous batching): [B, Sq, Skv] mask
-        q_ids = q_pos[:, None] + jnp.arange(sq, dtype=jnp.int32)[None, :]
+        # per-slot positions (continuous batching): [B, Sq, Skv] mask;
+        # an empty slot (below 0) keeps key 0, so its softmax is finite
+        q_ids = (jnp.maximum(q_pos, 0)[:, None]
+                 + jnp.arange(sq, dtype=jnp.int32)[None, :])
         mask = k_ids[None, None, :] <= q_ids[:, :, None]
         if sliding_window is not None:
             mask &= k_ids[None, None, :] > q_ids[:, :, None] - sliding_window

@@ -429,7 +429,8 @@ def update_layer(
 
     `pos` may be a vector of per-batch offsets (continuous-batching serving:
     every slot decodes at its own depth): one scatter of the B x S_new new
-    rows per plane; a row past the end of the cache is dropped. A scalar
+    rows per plane; a row past the end of the cache is dropped, and a slot
+    at a negative offset (serving: it holds no request) writes at 0. A scalar
     `pos` is one `dynamic_update_slice` per plane. Both address the stack
     itself, so with donated inputs only the new rows move. Returns the
     updated full-stack arrays.
@@ -450,7 +451,8 @@ def update_layer(
         # row i of slot b lands at [layer, b, pos[b] + i]
         b, s_new = k_new.shape[:2]
         slot = jnp.arange(b, dtype=jnp.int32)[:, None]
-        at = pos[:, None] + jnp.arange(s_new, dtype=jnp.int32)[None, :]
+        at = (jnp.maximum(pos, 0)[:, None]
+              + jnp.arange(s_new, dtype=jnp.int32)[None, :])
 
         def put(stack, new):
             return stack.at[layer, slot, at].set(

@@ -29,6 +29,15 @@ query heads meet all rows in one `[H, hd] x [hd, sb*Hkv]` MXU pass; a
 constant additive mask keeps, for query head i, only the rows of its own
 kv head i // G. The MXU does Hkv times the needed work and is still not
 the bound: its time follows the rows streamed, i.e. the bytes read.
+
+Only what is live is read. The sweep over a slot's S-blocks stops at the
+block that holds its `pos`: the steps past it compute nothing and their
+index map names the last live block again, which the pipeline does not
+fetch twice (the latent kernel's pattern, `mla_attention.py`). A slot
+whose `pos` is negative holds nothing: no block of it is multiplied and
+its output is zeros; its steps name its first block, which is fetched
+once (eliding that fetch too is ROADMAP S2b). `blocks_read` is that rule
+as plain integers, for the engine's counter.
 """
 
 from __future__ import annotations
@@ -57,6 +66,23 @@ def _s_block(s: int, hkv: int) -> int:
     while sb * 2 * hkv <= _BLOCK_ROWS and s % (sb * 2) == 0:
         sb *= 2
     return sb
+
+
+def blocks_read(positions, s: int, hkv: int) -> int:
+    """S-blocks of one layer the kernel fetches for K (as many again
+    for V) when the slots at `positions` (plain ints, the query's own
+    position in each; below 0 for an empty slot) decode over an `S`-long
+    cache of `hkv` heads: block `sb` of a slot is read while
+    `sb * _s_block <= pos`, and the first block always."""
+    sb = _s_block(s, hkv)
+    return sum(min(max(int(p), 0) // sb, s // sb - 1) + 1
+               for p in positions)
+
+
+def slab_blocks(batch: int, s: int, hkv: int) -> int:
+    """S-blocks of one layer of the whole slab: what `blocks_read`
+    counts when every slot is full."""
+    return batch * (s // _s_block(s, hkv))
 
 
 def _stack_in_place(k) -> bool:
@@ -126,6 +152,15 @@ def _rows(x_ref, sc_ref):
     return x.reshape(sb * hkv, hd).astype(jnp.bfloat16)
 
 
+def _named_block(pos_ref, bi, sj, sb: int, ns: int):
+    """S-block of slot `bi` that grid step (bi, sj) names. Past the
+    slot's last live block it is that block again: no new fetch. An
+    empty slot (`pos` < 0) names its first block throughout; a `pos`
+    past the end (a caller that lets an idle slot's position run on)
+    reads the slot whole."""
+    return jnp.minimum(sj, jnp.clip(pos_ref[bi] // sb, 0, ns - 1))
+
+
 def _kernel(layer_ref, pos_ref, q_ref, bias_ref, k_ref, v_ref, *rest,
             scale, sb, ns, hkv, scaled):
     """One (slot, S-block) step of the online-softmax sweep (the state
@@ -145,31 +180,36 @@ def _kernel(layer_ref, pos_ref, q_ref, bias_ref, k_ref, v_ref, *rest,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[...].astype(jnp.bfloat16)               # [Hp, hd]
-    k = _rows(k_ref, ks_ref)                          # [sb*Hkv, hd]
-    v = _rows(v_ref, vs_ref)
+    # a block wholly past pos would add p = 0 under corr = 1: nothing.
+    # An empty slot (pos < 0) runs no block and writes zeros
+    @pl.when(sj * sb <= pos)
+    def _():
+        q = q_ref[...].astype(jnp.bfloat16)               # [Hp, hd]
+        k = _rows(k_ref, ks_ref)                          # [sb*Hkv, hd]
+        v = _rows(v_ref, vs_ref)
 
-    s_ = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale + bias_ref[...]
-    # column c is (position sj*sb + c // Hkv, head c % Hkv): live while
-    # its position is <= pos
-    col = jax.lax.broadcasted_iota(jnp.int32, s_.shape, 1)
-    s_ = jnp.where(col < (pos + 1 - sj * sb) * hkv, s_, _NEG_INF)
+        s_ = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale + bias_ref[...]
+        # column c is (position sj*sb + c // Hkv, head c % Hkv): live
+        # while its position is <= pos
+        col = jax.lax.broadcasted_iota(jnp.int32, s_.shape, 1)
+        s_ = jnp.where(col < (pos + 1 - sj * sb) * hkv, s_, _NEG_INF)
 
-    m_prev = m_ref[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s_, axis=-1, keepdims=True))
-    corr = jnp.exp(m_prev - m_new)
-    # a row of another head sits >= 1e30 under the running max: exp -> 0
-    p = jnp.exp(s_ - m_new)
-    l_ref[:] = jnp.broadcast_to(
-        l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
-        l_ref.shape)
-    pv = jax.lax.dot_general(
-        p.astype(jnp.bfloat16), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    acc_ref[:] = acc_ref[:] * corr + pv
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s_, axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        # a row of another head sits >= 1e30 under the running max:
+        # exp -> 0
+        p = jnp.exp(s_ - m_new)
+        l_ref[:] = jnp.broadcast_to(
+            l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
+            l_ref.shape)
+        pv = jax.lax.dot_general(
+            p.astype(jnp.bfloat16), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        acc_ref[:] = acc_ref[:] * corr + pv
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
 
     @pl.when(sj == ns - 1)
     def _():
@@ -182,7 +222,7 @@ def decode_attention_pallas(
     q: jax.Array,          # [B, 1, H, hd]
     k: jax.Array,          # [L, B, S, Hkv, hd] bf16|float8_e5m2|int8|int4
     v: jax.Array,
-    q_pos: jax.Array,      # scalar int32 or [B] int32
+    q_pos: jax.Array,      # scalar int32 or [B] int32; < 0: an empty slot
     scale: float,
     interpret: bool = False,
     k_scale=None,          # [L, B, S, Hkv] f32 (int8/int4 codes), else None
@@ -216,10 +256,11 @@ def decode_attention_pallas(
     pos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
     lyr = jnp.asarray(layer, jnp.int32).reshape(1)
 
+    def kv_index(bi, sj, lyr_ref, pos_ref):
+        return lyr_ref[0], bi, _named_block(pos_ref, bi, sj, sb, ns), 0, 0
+
     q_spec = pl.BlockSpec((None, hp, hd), lambda bi, sj, *_: (bi, 0, 0))
-    kv_spec = pl.BlockSpec(
-        (None, None, sb, hkv, hd),
-        lambda bi, sj, lyr_ref, pos_ref: (lyr_ref[0], bi, sj, 0, 0))
+    kv_spec = pl.BlockSpec((None, None, sb, hkv, hd), kv_index)
     in_specs = [
         q_spec,
         pl.BlockSpec((hp, sb * hkv), lambda bi, sj, *_: (0, 0)),
@@ -227,11 +268,12 @@ def decode_attention_pallas(
     ]
     operands = (lyr, pos, qr, bias, k, v)
     if scaled:
+        def sc_index(bi, sj, lyr_ref, pos_ref):
+            return lyr_ref[0], bi, 0, _named_block(pos_ref, bi, sj, sb, ns)
+
         # [L, B, Hkv, S]: a bitcast of the plane as the chip stores it
         # (see _rows)
-        sc_spec = pl.BlockSpec(
-            (None, None, hkv, sb),
-            lambda bi, sj, lyr_ref, pos_ref: (lyr_ref[0], bi, 0, sj))
+        sc_spec = pl.BlockSpec((None, None, hkv, sb), sc_index)
         in_specs += [sc_spec, sc_spec]
         operands += tuple(jnp.swapaxes(x.astype(jnp.float32), -1, -2)
                           for x in (k_scale, v_scale))

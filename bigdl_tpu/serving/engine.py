@@ -14,9 +14,12 @@ fully static:
   home for its whole lifetime; admission = prefill into the slot,
   completion = slot freed (pos reset), nothing ever re-pads or copies KV.
 - ONE compiled decode executable for the whole engine lifetime: tokens
-  [max_batch, 1] + cache -> logits. Finished/empty slots decode garbage
+  [max_batch] + cache -> logits. Finished/empty slots decode garbage
   that is never read — the FLOP cost of static shapes, repaid by zero
-  recompiles and an always-full MXU batch.
+  recompiles and an always-full MXU batch. Their token is -1, which is
+  how the program knows them: an empty slot's position goes into the
+  forward as -1 (decode attention multiplies nothing for it) and stays 0 in
+  the cache, so an idle row does not deepen.
 - Prefill is compiled per prompt-length bucket and writes K/V straight
   into the batched cache at the slot index.
 - Scheduling is FCFS admission (the reference's FixedWindowScheduler
@@ -72,6 +75,7 @@ from bigdl_tpu.ops.kvcache import (KVCache, cache_nbytes, cache_spec_of,
                                    init_cache_spec, kv_cache_bytes,
                                    publish_kv_cache_bytes,
                                    resolve_kv_cache_dtype)
+from bigdl_tpu.ops.pallas.decode_attention import blocks_read, slab_blocks
 from bigdl_tpu.ops.paged import (NULL_PAGE, PagedKVCache, cow_copy_pages,
                                  gather_pages_dense, paged_cache_bytes,
                                  publish_paged_cache_bytes)
@@ -693,13 +697,24 @@ class LLMEngine:
 
         fwd = self.family.forward
 
-        @functools.partial(tracked_jit, "engine_decode",
-                           registry=self.registry, donate_argnums=(2,))
-        def decode(params, tokens, cache):   # tokens [B] int32
-            logits, cache = fwd(params, self.cfg, tokens[:, None], cache)
-            return logits[:, -1, :], cache
+        def decode_forward(params, tokens, cache):
+            """One token a slot through the family's forward; `tokens`
+            [B] int32, -1 in a slot that holds no request. Such a slot
+            goes in at position -1, which the slab's append writes at
+            row 0 and decode attention reads as "nothing cached"
+            (ops/pallas/decode_attention.py: no block of it is
+            multiplied), and comes back at 0 rather than one deeper
+            every step."""
+            live = tokens >= 0
+            logits, out = fwd(
+                params, self.cfg, jnp.maximum(tokens, 0)[:, None],
+                cache.replace(pos=jnp.where(live, cache.pos, -1)))
+            return logits[:, -1, :], out.replace(
+                pos=jnp.where(live, out.pos, 0))
 
-        self._decode = decode
+        self._decode = tracked_jit(
+            "engine_decode", decode_forward, registry=self.registry,
+            donate_argnums=(2,))
         # greedy fast path: one fused argmax, [B] ints to the host
         self._argmax = tracked_jit(
             "engine_argmax",
@@ -745,8 +760,7 @@ class LLMEngine:
         def decode_resident(params, tokens, cache, temps, top_ks,
                             top_ps, seeds, poss, *, all_greedy,
                             with_quality=False):
-            logits, cache = fwd(params, self.cfg, tokens[:, None], cache)
-            lg = logits[:, -1, :]
+            lg, cache = decode_forward(params, tokens, cache)
             finite = jnp.isfinite(lg).all(axis=-1)
             if all_greedy:
                 toks = jnp.argmax(lg, axis=-1).astype(jnp.int32)
@@ -810,9 +824,11 @@ class LLMEngine:
                                registry=self.registry,
                                donate_argnums=(2,))
             def decode_paged(params, tokens, cache, block_tables):
+                # an empty slot's -1 is token 0 here, as it always was:
+                # its block table is the null page
                 logits, cache = fwd_paged(
-                    params, self.cfg, tokens[:, None], cache,
-                    block_tables, last_only=True)
+                    params, self.cfg, jnp.maximum(tokens, 0)[:, None],
+                    cache, block_tables, last_only=True)
                 return logits[:, -1, :], cache
 
             self._decode_paged = decode_paged
@@ -999,6 +1015,21 @@ class LLMEngine:
         self._m_tokens = m.counter(
             "bigdl_tpu_tokens_generated_total",
             "Tokens emitted to clients.")
+        self._m_attn_blocks = m.counter(
+            "bigdl_tpu_decode_attn_blocks_total",
+            "S-blocks of K the slab decode-attention kernel names in a "
+            "decode step, all layers: kind=read those it fetches (up to "
+            "each live slot's position, the first of an empty slot), "
+            "kind=slab those the whole slab holds.",
+            labelnames=("kind",))
+        # (layers, blocks of one layer's slab, S, kv heads) of a K/V slab
+        # cache; None for a paged or a latent one, whose kernels have
+        # block rules of their own
+        self._attn_blocks = None
+        if not self._paged and self.cache.k is not None:
+            _, b_, s_, hkv_ = self.cache.k.shape[:4]
+            self._attn_blocks = (self.cache.num_layers,
+                                 slab_blocks(b_, s_, hkv_), s_, hkv_)
         self._m_prefill_chunks = m.counter(
             "bigdl_tpu_prefill_chunks_total",
             "Prefill chunks dispatched by admission (at most one per "
@@ -4101,17 +4132,28 @@ class LLMEngine:
         # return is pure host work (trace + transfer enqueue); the
         # blocked wait on the step result is device compute
         with ph("dispatch"):
-            tokens = np.zeros((self.cfg_engine.max_batch,), np.int32)
+            # -1: the slot holds no request (decode_forward)
+            tokens = np.full((self.cfg_engine.max_batch,), -1, np.int32)
             for i in active:
                 tokens[i] = self.slots[i].last_token
-            # mean live cache depth for the roofline sample, captured
-            # while every active slot's request is still attached
-            # (_check_done frees finishing slots before the step timing
-            # lands)
-            perf_seq_len = max(1, sum(
-                len(self.slots[i].req.prompt_token_ids)
-                + len(self.slots[i].generated)
-                for i in active) // len(active))
+            # tokens each active slot will hold after this step (its
+            # query sits one below), captured while every slot's request
+            # is still attached (_check_done frees finishing slots
+            # before the step timing lands)
+            depths = [len(self.slots[i].req.prompt_token_ids)
+                      + len(self.slots[i].generated) for i in active]
+            # mean live cache depth for the roofline sample
+            perf_seq_len = max(1, sum(depths) // len(active))
+            if self._attn_blocks is not None:
+                # what the slab decode kernel fetches this step, by its
+                # own rule from the positions the host already knows
+                layers, slab, s_max, hkv = self._attn_blocks
+                at = [-1] * len(tokens)          # -1: an empty slot
+                for i, d in zip(active, depths):
+                    at[i] = d - 1
+                self._m_attn_blocks.labels("read").inc(
+                    layers * blocks_read(at, s_max, hkv))
+                self._m_attn_blocks.labels("slab").inc(layers * slab)
 
             # resident fast path: when every active slot is
             # device-samplable and no fault clause is live (poison_rows

@@ -325,6 +325,85 @@ def test_engine_legacy_multi_dispatch_still_works(tiny_params):
     assert dt.get("engine_decode", 0) == 3
 
 
+def _attn_blocks_counter(eng, kind):
+    return eng._m_attn_blocks.labels(kind).value
+
+
+@pytest.mark.parametrize("attention", ["xla", "pallas"])
+@pytest.mark.parametrize("resident", ["on", "off"])
+def test_engine_empty_slot_stays_at_zero(resident, attention, monkeypatch):
+    """Both slab decode programs (`engine_decode_resident`, and
+    `engine_decode` + sampler), over the XLA ops and over the decode
+    kernel (interpret mode): a finished request's slot stays at position
+    0 while another slot decodes on, the survivor's tokens are those of
+    a run where it was alone, and every decode step adds to
+    `bigdl_tpu_decode_attn_blocks_total` exactly what the kernel's block
+    rule gives for the slots' positions (-1 for an empty one), times
+    layers. Blocks of 128
+    positions, so the survivor crosses from its first block into its
+    second."""
+    from bigdl_tpu.ops.pallas import decode_attention as DA
+    from bigdl_tpu.ops.pallas.decode_attention import blocks_read, slab_blocks
+    from bigdl_tpu.serving import EngineConfig, LLMEngine, SamplingParams
+
+    set_flags(decode_resident=resident, attention_backend=attention)
+    # head_dim 128 and two kv heads: a geometry the kernel takes, on the
+    # stack in place
+    cfg = dataclasses.replace(TINY_LLAMA, hidden_size=256,
+                              num_hidden_layers=3, num_attention_heads=2,
+                              num_key_value_heads=2)
+    model = FakeModel(random_llama_params(cfg, qtype="sym_int4", seed=0),
+                      cfg)
+    b, s = 3, 256
+    layers, hkv = cfg.num_hidden_layers, cfg.num_key_value_heads
+    monkeypatch.setattr(DA, "_BLOCK_ROWS", 128 * hkv)
+    long_prompt, n_long = [1 + i % 50 for i in range(120)], 16
+
+    def engine():
+        return LLMEngine(model, EngineConfig(max_batch=b, max_seq=s))
+
+    alone = engine().generate([long_prompt],
+                              SamplingParams(max_tokens=n_long))[0]
+
+    eng = engine()
+    eng.add_request("short", [7, 3, 99, 5], SamplingParams(max_tokens=3))
+    eng.add_request("long", long_prompt, SamplingParams(max_tokens=n_long))
+    got, short_slot, idle_steps = [], None, 0
+    while len(got) < n_long:
+        live = {i: len(sl.req.prompt_token_ids) + len(sl.generated) - 1
+                for i, sl in enumerate(eng.slots) if sl.active}
+        before = {k: _attn_blocks_counter(eng, k) for k in ("read", "slab")}
+        pos_before = np.asarray(eng.cache.pos)
+        # a step that admits decodes slots this loop did not see live
+        settled = not eng.waiting and eng._admitting is None
+        eng.step()
+        for out in eng.get_outputs("long"):
+            got.extend(out.new_token_ids)
+        for i, sl in enumerate(eng.slots):
+            if sl.req is not None and sl.req.request_id == "short":
+                short_slot = i
+        if settled:
+            # the host's positions are the cache's own
+            for i, p in live.items():
+                assert pos_before[i] == p
+            assert (_attn_blocks_counter(eng, "read") - before["read"]
+                    == layers * blocks_read(
+                        [live.get(i, -1) for i in range(b)], s, hkv))
+            assert (_attn_blocks_counter(eng, "slab") - before["slab"]
+                    == layers * slab_blocks(b, s, hkv))
+        pos = np.asarray(eng.cache.pos)
+        for i, sl in enumerate(eng.slots):
+            if not sl.active and settled:
+                assert pos[i] == 0, (i, pos)
+        if short_slot is not None and not eng.slots[short_slot].active:
+            idle_steps += 1
+    assert idle_steps >= 10
+    assert got == alone
+    assert slab_blocks(b, s, hkv) == 2 * b
+    assert _attn_blocks_counter(eng, "read") < _attn_blocks_counter(
+        eng, "slab")
+
+
 # ---------------------------------------------------------------------------
 # speculative draft path: greedy identity holds under either flag
 

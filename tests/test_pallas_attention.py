@@ -225,6 +225,188 @@ def test_stack_long_cache(name):
         rtol=2e-2, atol=2e-2)
 
 
+# -- only what is live is read: blocks past pos, empty slots ----------------
+
+
+def _parent_decode(q, k, v, pos, scale, k_scale, v_scale, layer):
+    """The kernel as it was before it followed `pos` (PR 30's body and
+    index maps): every (slot, S-block) fetched and multiplied, masked
+    afterwards. The reference the live rows must equal bit for bit."""
+    import functools
+
+    import jax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from bigdl_tpu.ops.pallas import decode_attention as DA
+
+    b, _, h, hd = q.shape
+    s, hkv = k.shape[2], k.shape[3]
+    scaled = k_scale is not None
+    hp = -(-h // 16) * 16
+    sb = DA._s_block(s, hkv)
+    ns = s // sb
+
+    def kernel(layer_ref, pos_ref, q_ref, bias_ref, k_ref, v_ref, *rest):
+        if scaled:
+            ks_ref, vs_ref, out_ref, m_ref, l_ref, acc_ref = rest
+        else:
+            out_ref, m_ref, l_ref, acc_ref = rest
+            ks_ref = vs_ref = None
+        sj = pl.program_id(1)
+        p_ = pos_ref[pl.program_id(0)]
+
+        @pl.when(sj == 0)
+        def _():
+            m_ref[:] = jnp.full_like(m_ref, DA._NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+        qq = q_ref[...].astype(jnp.bfloat16)
+        kk = DA._rows(k_ref, ks_ref)
+        vv = DA._rows(v_ref, vs_ref)
+        s_ = jax.lax.dot_general(
+            qq, kk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale + bias_ref[...]
+        col = jax.lax.broadcasted_iota(jnp.int32, s_.shape, 1)
+        s_ = jnp.where(col < (p_ + 1 - sj * sb) * hkv, s_, DA._NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s_, axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s_ - m_new)
+        l_ref[:] = jnp.broadcast_to(
+            l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
+            l_ref.shape)
+        pv = jax.lax.dot_general(
+            p.astype(jnp.bfloat16), vv, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        acc_ref[:] = acc_ref[:] * corr + pv
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+
+        @pl.when(sj == ns - 1)
+        def _():
+            l = jnp.maximum(l_ref[:, :1], 1e-30)
+            out_ref[...] = (acc_ref[:] / l).astype(out_ref.dtype)
+
+    qr = jnp.pad(q.reshape(b, h, hd), ((0, 0), (0, hp - h), (0, 0)))
+    bias = jnp.asarray(DA._own_head_bias(hp, h // hkv, hkv, sb * hkv))
+    q_spec = pl.BlockSpec((None, hp, hd), lambda bi, sj, *_: (bi, 0, 0))
+    kv_spec = pl.BlockSpec(
+        (None, None, sb, hkv, hd),
+        lambda bi, sj, lyr_ref, pos_ref: (lyr_ref[0], bi, sj, 0, 0))
+    in_specs = [q_spec,
+                pl.BlockSpec((hp, sb * hkv), lambda bi, sj, *_: (0, 0)),
+                kv_spec, kv_spec]
+    operands = (jnp.asarray(layer, jnp.int32).reshape(1),
+                jnp.asarray(pos, jnp.int32), qr, bias, k, v)
+    if scaled:
+        sc_spec = pl.BlockSpec(
+            (None, None, hkv, sb),
+            lambda bi, sj, lyr_ref, pos_ref: (lyr_ref[0], bi, 0, sj))
+        in_specs += [sc_spec, sc_spec]
+        operands += tuple(jnp.swapaxes(x.astype(jnp.float32), -1, -2)
+                          for x in (k_scale, v_scale))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b, ns), in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((hp, 128), jnp.float32),
+                            pltpu.VMEM((hp, 128), jnp.float32),
+                            pltpu.VMEM((hp, hd), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, hp, hd), q.dtype),
+        interpret=True,
+    )(*operands)
+    return out[:, :h, :].reshape(b, 1, h, hd)
+
+
+_SB, _S = 128, 512        # four blocks a slot (with _BLOCK_ROWS shrunk)
+# per-slot positions that straddle the block edges; -1 is an empty slot
+# (first, in the middle, two in a row, last), 3000 an idle slot whose
+# position ran on past S (a caller that does not pin it)
+_LIVE_CASES = {
+    "edges": [-1, 0, 1, -1, -1, _SB - 1, _SB, -1, _SB + 1, _S - 1, 3000,
+              -1],
+    "led-by-empties": [-1, -1, -1, 2 * _SB, -1, 3000, -1, -1, _S - 1, 0],
+    "all-live": [0, _SB - 1, _SB, _SB + 1, 3 * _SB - 1, _S - 1],
+    "all-empty": [-1, -1, -1],
+}
+
+
+def _plant_garbage(name, k, v, ks, vs, pos):
+    """Large finite values everywhere the kernel must not look: past
+    `pos` in a live slot, everywhere in an empty one, in every layer."""
+    s = k.shape[2]
+    dead = (np.arange(s)[None, :]
+            > np.asarray(pos)[:, None])[None, :, :, None]      # [1,B,S,1]
+    big = {"bf16": 3e4, "fp8_e5m2": 3e4, "int8": 127, "int4": 7}[name]
+
+    def put(x, val):
+        if x is None:
+            return None
+        m = dead if x.ndim == 4 else dead[..., None]
+        return jnp.where(m, jnp.asarray(val, jnp.float32).astype(x.dtype), x)
+
+    return put(k, big), put(v, -big), put(ks, 1e3), put(vs, 1e3)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("case", sorted(_LIVE_CASES))
+@pytest.mark.parametrize("name", sorted(_STACK_DTYPES))
+def test_reads_only_what_is_live(monkeypatch, name, case, layer):
+    """The sweep stops at a slot's last live block and no block of an
+    empty slot (pos < 0) is multiplied, and nothing a live slot returns
+    changes: bit for bit the rows of the kernel that read everything,
+    whatever lies past `pos` and in the empty slots; the XLA path within
+    its tolerance; zeros for an empty slot. The index maps name exactly
+    `blocks_read` different blocks, in range."""
+    from bigdl_tpu.ops.pallas import decode_attention as DA
+
+    hkv = 2
+    monkeypatch.setattr(DA, "_BLOCK_ROWS", _SB * hkv)
+    pos_l = _LIVE_CASES[case]
+    b = len(pos_l)
+    assert DA._s_block(_S, hkv) == _SB
+    q, k, v, ks, vs = _mk_stack(name, 3, b, _S, 4, hkv, 128,
+                                seed=50 + layer)
+    pos = jnp.asarray(pos_l, jnp.int32)
+    live = np.asarray(pos_l) >= 0
+    scale = 128 ** -0.5
+
+    gk, gv, gks, gvs = _plant_garbage(name, k, v, ks, vs, pos)
+    got = np.asarray(DA.decode_attention_pallas(
+        q, gk, gv, pos, scale, interpret=True, k_scale=gks, v_scale=gvs,
+        layer=layer), np.float32)
+    assert np.isfinite(got).all()
+    assert not got[~live].any()          # an empty slot: zeros
+
+    if live.any():
+        # the parent has no empty slots: give it position 0 there
+        ref = np.asarray(_parent_decode(
+            q, k, v, jnp.maximum(pos, 0), scale, ks, vs, layer), np.float32)
+        np.testing.assert_array_equal(got[live], ref[live])
+        xla = np.asarray(_xla_layer(q, k, v, ks, vs, layer, pos),
+                         np.float32)
+        np.testing.assert_allclose(got[live], xla[live],
+                                   rtol=2e-2, atol=2e-2)
+
+    # the index maps, step by step over the grid: in range, a live
+    # block its own, and a new block exactly where `blocks_read` counts
+    # one (an empty slot's first block is fetched and not multiplied)
+    ns = _S // _SB
+    named = []
+    for bi in range(b):
+        for sj in range(ns):
+            blk = int(DA._named_block(np.asarray(pos_l), bi, sj, _SB, ns))
+            assert 0 <= blk < ns
+            named.append((bi, blk))
+            if live[bi] and sj * _SB <= pos_l[bi]:
+                assert blk == sj
+    fetched = 1 + sum(a != b_ for a, b_ in zip(named, named[1:]))
+    assert fetched == DA.blocks_read(pos_l, _S, hkv)
+    assert DA.slab_blocks(b, _S, hkv) == len(named)
+
+
 def test_engine_slab_decode_kernel_matches_xla_tokens():
     """8 greedy steps of the engine's slab decode give the same tokens
     with the kernel (interpret mode, stack + layer index from the layer
