@@ -238,28 +238,50 @@ def _mask(sq: int, s: int, pos):
     return (k_ids[None, None, :] <= q_ids[:, :, None])[:, None]
 
 
-def _attention(x, lp, cfg, lat, lidx, pos, cos, sin, scale):
-    """One layer's MLA on `x` `[B, sq, D]`; returns the attention output
-    (before the residual) and the latent stack with this layer's new
-    rows written."""
+def mla_project(x, lp, cfg, cos, sin, q_rescale=None, kv_rescale=None):
+    """The projections of one MLA layer on the normed `x` `[B, sq, D]`:
+    `(q_nope, q_pe, new, c_q)`, the query's two parts per head, the
+    `[B, sq, C + R]` rows a latent cache keeps of these positions (the
+    normed `c_kv` and the roped `k_pe`) and the normed compressed query
+    (None without a `q_lora_rank`). `cfg` gives the sizes (`DeepseekV2
+    Config`, or another family's view of one kind of its layers);
+    `q_rescale` / `kv_rescale` multiply the two latent norms' outputs
+    where a family aligns their variance."""
     b, sq, _ = x.shape
     h, c, r = cfg.num_attention_heads, cfg.kv_lora_rank, cfg.qk_rope_head_dim
-    nope, vd, eps = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.rms_norm_eps
+    nope, eps = cfg.qk_nope_head_dim, cfg.rms_norm_eps
+    c_q = None
     with jax.named_scope("mla.q"):
         if cfg.q_lora_rank is None:
             q = linear(x, lp["q_proj"])
         else:
-            q = linear(rms_norm(linear(x, lp["q_a_proj"]),
-                                lp["q_a_layernorm"], eps), lp["q_b_proj"])
+            c_q = rms_norm(linear(x, lp["q_a_proj"]), lp["q_a_layernorm"],
+                           eps)
+            if q_rescale is not None:
+                c_q = (c_q * q_rescale).astype(x.dtype)
+            q = linear(c_q, lp["q_b_proj"])
         q = q.reshape(b, sq, h, nope + r)
         q_nope = q[..., :nope]
         q_pe = apply_rope(q[..., nope:], cos, sin, interleaved=True)
     with jax.named_scope("mla.kv_latent"):
         kv = linear(x, lp["kv_a_proj"])
+        c_kv = rms_norm(kv[..., :c], lp["kv_a_layernorm"], eps)
+        if kv_rescale is not None:
+            c_kv = (c_kv * kv_rescale).astype(x.dtype)
         new = jnp.concatenate(
-            [rms_norm(kv[..., :c], lp["kv_a_layernorm"], eps),
-             apply_rope(kv[..., c:c + r], cos, sin, interleaved=True)],
+            [c_kv, apply_rope(kv[..., c:c + r], cos, sin, interleaved=True)],
             axis=-1)
+    return q_nope, q_pe, new, c_q
+
+
+def _attention(x, lp, cfg, lat, lidx, pos, cos, sin, scale):
+    """One layer's MLA on `x` `[B, sq, D]`; returns the attention output
+    (before the residual) and the latent stack with this layer's new
+    rows written."""
+    b, sq, _ = x.shape
+    h, c, vd = cfg.num_attention_heads, cfg.kv_lora_rank, cfg.v_head_dim
+    q_nope, q_pe, new, _ = mla_project(x, lp, cfg, cos, sin)
+    with jax.named_scope("mla.kv_latent"):
         lat = update_latent(lat, lidx, new, pos)
     w_uk, w_uv = lp["w_uk"], lp["w_uv"]       # [H, nope, C], [H, C, v]
     if _absorb(cfg, sq):
@@ -338,17 +360,24 @@ def swiglu(x, gate, up, down):
     return linear(jax.nn.silu(linear(x, gate)) * linear(x, up), down)
 
 
-def _routing(cfg: DeepseekV2Config) -> Dict[str, Any]:
-    return dict(top_k=cfg.num_experts_per_tok, n_group=cfg.n_group,
-                topk_group=cfg.topk_group, method=cfg.topk_method,
-                scaling_factor=cfg.routed_scaling_factor,
-                norm_topk_prob=cfg.norm_topk_prob)
+def _routing(cfg) -> Dict[str, Any]:
+    """`route`'s arguments from a config; a family whose router scores
+    by sigmoid says so (`scoring_func`)."""
+    out = dict(top_k=cfg.num_experts_per_tok, n_group=cfg.n_group,
+               topk_group=cfg.topk_group, method=cfg.topk_method,
+               scaling_factor=cfg.routed_scaling_factor,
+               norm_topk_prob=cfg.norm_topk_prob)
+    if getattr(cfg, "scoring_func", "softmax") != "softmax":
+        out["scoring"] = cfg.scoring_func
+    return out
 
 
-def moe_block(hidden, lp, experts, layer, cfg: DeepseekV2Config):
+def moe_block(hidden, lp, experts, layer, cfg):
     """Shared experts plus this chip's share of the routed sum;
-    `experts` holds the `[L, held, ...]` stacks, read at `layer`.
-    Returns `[B, sq, D]` and the `STATS` increments."""
+    `experts` holds the `[L, held, ...]` stacks, read at `layer`; `cfg`
+    any config with the routing keys and `share` (this family's, or
+    `models/dots3_note.py`'s). Returns `[B, sq, D]` and the `STATS`
+    increments."""
     b, sq, d = hidden.shape
     xf = hidden.reshape(-1, d)
     with jax.named_scope("moe.router"):
@@ -358,9 +387,11 @@ def moe_block(hidden, lp, experts, layer, cfg: DeepseekV2Config):
                          lp["router"].astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
     with jax.named_scope("moe.routed"):
+        routing = _routing(cfg)
+        if "router_bias" in lp:     # `noaux_tc`: chooses, does not weigh
+            routing["bias"] = lp["router_bias"]
         y, stats = routed_experts(xf, logits, experts, cfg.share,
-                                  act=jax.nn.silu, layer=layer,
-                                  **_routing(cfg))
+                                  act=jax.nn.silu, layer=layer, **routing)
     with jax.named_scope("moe.shared"):
         y = y + swiglu(xf, lp["shared_gate"], lp["shared_up"],
                        lp["shared_down"])
@@ -472,12 +503,12 @@ def _pad_n(w, multiple: int = 128):
         shape=(w.shape[0], w.shape[1] + pad))
 
 
-def prepare_params(params: Dict[str, Any], cfg: DeepseekV2Config,
-                   compute_dtype=jnp.bfloat16) -> Dict[str, Any]:
-    """The canonical tree as `forward` serves it: `kv_b_proj` becomes
-    `w_uk` `[H, nope, C]` and `w_uv` `[H, C, v]` in bf16 (a layer at a
-    time), `kv_a_proj` gets its N padded to a lane multiple. A tree
-    that is already prepared passes through."""
+def prepare_attention(layers: Dict[str, Any], cfg,
+                      compute_dtype=jnp.bfloat16) -> Dict[str, Any]:
+    """One stack of layers' attention leaves as they are served:
+    `kv_b_proj` becomes `w_uk` `[H, nope, C]` and `w_uv` `[H, C, v]` in
+    bf16 (a layer at a time), `kv_a_proj` gets its N padded to a lane
+    multiple. `cfg` gives the sizes (`mla_project`'s)."""
     h, c = cfg.num_attention_heads, cfg.kv_lora_rank
     nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
 
@@ -487,16 +518,22 @@ def prepare_params(params: Dict[str, Any], cfg: DeepseekV2Config,
         return (jnp.transpose(w[..., :nope], (1, 2, 0)).astype(compute_dtype),
                 jnp.transpose(w[..., nope:], (1, 0, 2)).astype(compute_dtype))
 
+    layers = dict(layers)
+    layers["w_uk"], layers["w_uv"] = lax.map(split, layers.pop("kv_b_proj"))
+    layers["kv_a_proj"] = _pad_n(layers["kv_a_proj"])
+    return layers
+
+
+def prepare_params(params: Dict[str, Any], cfg: DeepseekV2Config,
+                   compute_dtype=jnp.bfloat16) -> Dict[str, Any]:
+    """The canonical tree as `forward` serves it (`prepare_attention`
+    on both stacks). A tree that is already prepared passes through."""
     out = dict(params)
     for group in ("dense_layers", "moe_layers"):
         layers = params.get(group)
         if layers is None or "kv_b_proj" not in layers:
             continue
-        layers = dict(layers)
-        layers["w_uk"], layers["w_uv"] = lax.map(split,
-                                                 layers.pop("kv_b_proj"))
-        layers["kv_a_proj"] = _pad_n(layers["kv_a_proj"])
-        out[group] = layers
+        out[group] = prepare_attention(layers, cfg, compute_dtype)
     return out
 
 

@@ -158,6 +158,23 @@ def _register_builtin() -> None:
             new_cache=deepseek_mod.new_cache,
         ))
 
+    from bigdl_tpu.models import dots3_note as dots3_mod
+
+    # latent attention of two kinds (sparse full layers with an index-key
+    # plane, window layers in a ring), head-wise gate, sigmoid
+    # bias-corrected router; slab only, bf16 planes only
+    register_family(
+        ["Dots3NoteForCausalLM"],
+        FamilyAdapter(
+            name="dots3_note",
+            config_from_hf=dots3_mod.Dots3NoteConfig.from_hf,
+            convert_params=dots3_mod.convert_hf_params,
+            forward=dots3_mod.forward,
+            prefill=dots3_mod.forward_last_token,
+            forward_train=None,
+            new_cache=dots3_mod.new_cache,
+        ))
+
     from bigdl_tpu.models import rwkv as rwkv_mod
 
     def rwkv_adapter(version: int) -> FamilyAdapter:

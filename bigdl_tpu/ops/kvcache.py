@@ -40,6 +40,20 @@ is `[L, B, ...]`; `plane_seq_axis(name)` says where its positions run, and
 the engine's splice, export and ledger code goes through that description
 and names no plane.
 
+A spec LISTS its planes (`PlaneSpec`: name, layers, what a position holds,
+full length or ring), and allocation, splice, admission cost, the ledger
+and the byte gauges follow the list. A model with two kinds of attention
+layer keeps planes of different depths and lengths side by side
+(`models/dots3_note.py`): `latent` `[Lf, B, 576, S]` and `index`
+`[Lf, B, 128, S]` (the sparse-attention indexer's keys) for its full
+layers, and `window` `[Lw, B, 1088, ring]` for its window layers, a RING:
+position `p` lives in column `p % ring`, the ring is at least the window
+long, and its length does not follow `max_seq`. A ring is whole or
+nothing: it is spliced, exported and counted at its full length, and a
+snapshot of it is the state at the length it was taken and at no shorter
+one (`seeded` refuses it; the engine's host prefix cache refuses such a
+spec).
+
 Layout: [num_layers, batch, max_seq, kv_heads, head_dim] — the whole stack is
 one array per K/V so a `lax.scan` over layers can carry it. In place means
 addressed on the stack: `update_layer` writes its rows at `[layer, ...]` and
@@ -126,8 +140,10 @@ def kv_dtype_name(storage_dtype) -> str:
 
 # planes a cache may hold, in the order `planes()` lists them; every one
 # is [L, B, ...] and its positions run along `plane_seq_axis(name)`
-PLANE_NAMES = ("k", "v", "k_scale", "v_scale", "latent")
-_SEQ_AXIS = {"latent": 3}
+PLANE_NAMES = ("k", "v", "k_scale", "v_scale", "latent", "index", "window")
+_SEQ_AXIS = {"latent": 3, "index": 3, "window": 3}
+# planes that are rings (module docstring)
+RING_PLANES = ("window",)
 # the one storage type a latent plane takes (a quantized latent reads
 # noise at real widths: PERF.md 7, 17)
 LATENT_KV_DTYPES = ("bf16",)
@@ -139,13 +155,33 @@ def plane_seq_axis(name: str) -> int:
 
 
 @dataclasses.dataclass(frozen=True)
+class PlaneSpec:
+    """One plane of a cache: the `KVCache` field it fills, the layers
+    it stacks, what one position holds (`(kv_heads, head_dim)` of K or
+    V, `(kv_heads,)` of a scale plane, `(width,)` of a plane that keeps
+    its positions in the lanes) and, for a ring, how many positions it
+    keeps (0: the cache's full length)."""
+    name: str
+    layers: int
+    dims: Tuple[int, ...]
+    ring: int = 0
+
+    def shape(self, batch: int, max_seq: int) -> Tuple[int, ...]:
+        n = self.ring or max_seq
+        if plane_seq_axis(self.name) == 3:
+            return (self.layers, batch) + self.dims + (n,)
+        return (self.layers, batch, n) + self.dims
+
+
+@dataclasses.dataclass(frozen=True)
 class CacheSpec:
     """What one family's layers keep per position: the declaration a
     family hands the cache manager (`cache_spec(cfg)` of the module that
     owns its forward; a family without one keeps K and V of
     `num_key_value_heads x hd`). kind "kv": K and V planes of
     `[L, B, S, kv_heads, head_dim]`; kind "latent": one plane of
-    `[L, B, latent_dim, S]`."""
+    `[L, B, latent_dim, S]`, or the planes `planes` lists (bf16, as a
+    latent plane is)."""
     kind: str
     num_layers: int
     kv_heads: int = 0
@@ -154,6 +190,40 @@ class CacheSpec:
     # int32 counters a family's forward accumulates on the device
     # (KVCache.stats); 0 = none
     stats_len: int = 0
+    # a family with planes of several depths or lengths lists them
+    planes: Tuple[PlaneSpec, ...] = ()
+
+    def plane_specs(self, kv_cache_dtype=None) -> Tuple[PlaneSpec, ...]:
+        """The planes a cache of this spec and storage type holds, in
+        `PLANE_NAMES` order."""
+        if self.kind == "latent":
+            reject_non_bf16_latent(kv_cache_dtype)
+            return self.planes or (
+                PlaneSpec("latent", self.num_layers, (self.latent_dim,)),)
+        kv = (self.kv_heads, self.head_dim)
+        out = (PlaneSpec("k", self.num_layers, kv),
+               PlaneSpec("v", self.num_layers, kv))
+        if resolve_kv_cache_dtype(kv_cache_dtype) in SCALED_KV_DTYPES:
+            out += (PlaneSpec("k_scale", self.num_layers, kv[:1]),
+                    PlaneSpec("v_scale", self.num_layers, kv[:1]))
+        return out
+
+    @property
+    def has_ring(self) -> bool:
+        return any(p.ring for p in self.planes)
+
+    def unrolled(self) -> "CacheSpec":
+        """This spec with every ring at the cache's full length, its
+        positions in order: what a PRIVATE prefill cache and
+        `generate()` hold. A prompt is right-padded to a chunk or a
+        bucket, and the padding's rows, written past the prompt, would
+        overwrite live columns of a ring (harmless garbage in a plane
+        that keeps every position). `KVCache.spliced` rolls the last
+        positions into the slab's ring. A spec without a ring is itself."""
+        if not self.has_ring:
+            return self
+        return dataclasses.replace(self, planes=tuple(
+            dataclasses.replace(p, ring=0) for p in self.planes))
 
     @property
     def seq_axis(self) -> int:
@@ -162,7 +232,8 @@ class CacheSpec:
         return plane_seq_axis("latent" if self.kind == "latent" else "k")
 
     def values_per_position(self) -> int:
-        """Cached values of one position of one layer."""
+        """Cached values of one position of one layer (of the first
+        plane's layers, where the planes differ)."""
         if self.kind == "latent":
             return self.latent_dim
         return 2 * self.kv_heads * self.head_dim
@@ -205,10 +276,14 @@ class KVCache:
     # int32 counters the family's forward adds to on the device (sparse
     # experts: assignments, experts hit); read at scrape time
     stats: Optional[jax.Array] = None
+    # sparse attention's index keys of the layers that keep `latent`
+    index: Optional[jax.Array] = None     # [L, B, index_dim, S_max]
+    # the window layers' latent rows, a ring (module docstring)
+    window: Optional[jax.Array] = None    # [Lw, B, window_dim, ring]
 
     def tree_flatten(self):
         return (self.k, self.v, self.pos, self.k_scale, self.v_scale,
-                self.latent, self.stats), None
+                self.latent, self.stats, self.index, self.window), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -249,12 +324,14 @@ class KVCache:
     def seq_slices(self, length: int, row=None) -> Tuple[jax.Array, ...]:
         """Every plane cut to its first `length` positions (and to batch
         row `row`, kept as an axis of 1), in `planes()` order: what the
-        prefix cache, export and migration move."""
+        prefix cache, export and migration move. A ring is never cut:
+        it comes whole, and is the state at this cache's own `pos`."""
         out = []
         for name, p in self.planes().items():
             ax = plane_seq_axis(name)
-            p = jax.lax.slice_in_dim(p, 0, min(length, p.shape[ax]),
-                                     axis=ax)
+            if name not in RING_PLANES:
+                p = jax.lax.slice_in_dim(p, 0, min(length, p.shape[ax]),
+                                         axis=ax)
             if row is not None:
                 p = jax.lax.slice_in_dim(p, row, row + 1, axis=1)
             out.append(p)
@@ -266,6 +343,11 @@ class KVCache:
         and `pos = consumed`: an admission that starts from a snapshot."""
         import numpy as np
 
+        if any(n in RING_PLANES for n in self.planes()):
+            raise NotImplementedError(
+                "a cache with a ring plane cannot start from a prefix "
+                "snapshot: the ring holds the last positions of the length "
+                "it was taken at, not those of a shorter prefix")
         new = {}
         for (name, p), src in zip(self.planes().items(), host_planes):
             ax = plane_seq_axis(name)
@@ -279,14 +361,28 @@ class KVCache:
     def spliced(self, one: "KVCache", slot, plen) -> "KVCache":
         """This batched cache with the 1-row cache `one` written into
         batch row `slot` of every plane and `pos[slot] = plen`. `one` may
-        be longer (chunk padding): it is cut to this cache's length."""
+        be longer (chunk padding): it is cut to this cache's length. A
+        ring of this cache takes the last positions before `plen` of
+        `one`'s plane, which keeps its positions in order
+        (`CacheSpec.unrolled`): column j gets the position `plen - 1 -
+        ((plen - 1 - j) mod ring)` (nothing it will be read for where
+        that is negative)."""
         new = {}
         ones = one.planes()
         for name, big in self.planes().items():
             ax = plane_seq_axis(name)
-            src = jax.lax.slice_in_dim(
-                ones[name], 0, min(ones[name].shape[ax], big.shape[ax]),
-                axis=ax)
+            if name in RING_PLANES and ones[name].shape[ax] != big.shape[ax]:
+                ring = big.shape[ax]
+                last = jnp.asarray(plen, jnp.int32) - 1
+                at = last - jnp.mod(
+                    last - jnp.arange(ring, dtype=jnp.int32), ring)
+                src = jnp.take(
+                    ones[name],
+                    jnp.clip(at, 0, ones[name].shape[ax] - 1), axis=ax)
+            else:
+                src = jax.lax.slice_in_dim(
+                    ones[name], 0, min(ones[name].shape[ax], big.shape[ax]),
+                    axis=ax)
             at = [0] * big.ndim
             at[1] = slot
             new[name] = jax.lax.dynamic_update_slice(
@@ -308,53 +404,40 @@ def init_cache(
     per_slot_pos: bool = False,
     kv_cache_dtype: Optional[str] = None,
 ) -> KVCache:
-    """Allocate an empty cache.
-
-    `kv_cache_dtype` picks the storage ("bf16" | "fp8_e5m2" | "int8" |
-    "int4"); `quantized` is the deprecated boolean alias (True ->
-    "fp8_e5m2") and, for plumbing convenience, also accepts a dtype
-    name string directly.
-
-    per_slot_pos=True gives every batch row its own position counter —
-    the continuous-batching layout (each serving slot decodes at its own
-    depth, the capability the reference's vLLM port builds from per-seq
-    KV dicts, vllm/model_executor/models/bigdl_model.py:88-139)."""
-    name = resolve_kv_cache_dtype(
-        kv_cache_dtype if kv_cache_dtype is not None else quantized)
-    dt = dtype if name == "bf16" else KV_CACHE_DTYPES[name]
-    shape = (num_layers, batch, max_seq, kv_heads, head_dim)
-    scaled = name in SCALED_KV_DTYPES
-    sshape = (num_layers, batch, max_seq, kv_heads)
-    return KVCache(
-        k=jnp.zeros(shape, dt),
-        v=jnp.zeros(shape, dt),
-        pos=(jnp.zeros((batch,), jnp.int32) if per_slot_pos
-             else jnp.zeros((), jnp.int32)),
-        k_scale=jnp.zeros(sshape, jnp.float32) if scaled else None,
-        v_scale=jnp.zeros(sshape, jnp.float32) if scaled else None,
-    )
+    """An empty K/V cache from its geometry: `init_cache_spec` of the
+    "kv" spec. `quantized` is the deprecated boolean alias of
+    `kv_cache_dtype` (True -> "fp8_e5m2") and also accepts a dtype name."""
+    return init_cache_spec(
+        CacheSpec("kv", num_layers, kv_heads, head_dim), batch, max_seq,
+        kv_cache_dtype=resolve_kv_cache_dtype(
+            kv_cache_dtype if kv_cache_dtype is not None else quantized),
+        per_slot_pos=per_slot_pos, dtype=dtype)
 
 
 def init_cache_spec(spec: CacheSpec, batch: int, max_seq: int,
                     kv_cache_dtype=None, per_slot_pos: bool = False,
                     dtype=jnp.bfloat16) -> KVCache:
-    """Allocate the empty cache `spec` describes (`init_cache` for K and
-    V planes; one bf16 latent plane otherwise)."""
-    if spec.kind != "latent":
-        return init_cache(spec.num_layers, batch, max_seq, spec.kv_heads,
-                          spec.head_dim, dtype=dtype,
-                          per_slot_pos=per_slot_pos,
-                          kv_cache_dtype=resolve_kv_cache_dtype(
-                              kv_cache_dtype))
-    reject_non_bf16_latent(kv_cache_dtype)
+    """Allocate the empty cache `spec` describes, plane by plane.
+
+    `kv_cache_dtype` picks the storage of the code planes ("bf16" ->
+    `dtype`, "fp8_e5m2", "int8", "int4"; scale planes are float32).
+    per_slot_pos=True gives every batch row its own position counter —
+    the continuous-batching layout (each serving slot decodes at its own
+    depth, the capability the reference's vLLM port builds from per-seq
+    KV dicts, vllm/model_executor/models/bigdl_model.py:88-139)."""
+    name = resolve_kv_cache_dtype(kv_cache_dtype)
+    dt = dtype if name == "bf16" else KV_CACHE_DTYPES[name]
+    planes = {
+        p.name: jnp.zeros(p.shape(batch, max_seq),
+                          jnp.float32 if p.name.endswith("_scale") else dt)
+        for p in spec.plane_specs(name)}
     return KVCache(
-        k=None, v=None,
+        k=planes.pop("k", None), v=planes.pop("v", None),
         pos=(jnp.zeros((batch,), jnp.int32) if per_slot_pos
              else jnp.zeros((), jnp.int32)),
-        latent=jnp.zeros((spec.num_layers, batch, spec.latent_dim, max_seq),
-                         dtype),
         stats=(jnp.zeros((spec.stats_len,), jnp.int32)
-               if spec.stats_len else None))
+               if spec.stats_len else None),
+        **planes)
 
 
 def update_latent(stack: jax.Array, layer, new: jax.Array,
@@ -392,6 +475,28 @@ def update_latent(stack: jax.Array, layer, new: jax.Array,
             new, mode="drop", unique_indices=True)
     return jax.lax.dynamic_update_slice(
         stack, jnp.swapaxes(new, 1, 2)[None], (layer, 0, 0, pos))
+
+
+def update_ring(stack: jax.Array, layer, new: jax.Array,
+                pos: jax.Array) -> jax.Array:
+    """Write `new` `[B, S_new, C]` into layer `layer` of the RING stack
+    `[L, B, C, ring]`: row i of slot b lands in column `(pos[b] + i) %
+    ring`. Of more rows than the ring keeps only the last `ring` are
+    written (the earlier ones would be overwritten). One decoded row a
+    slot goes through `update_latent` at `pos % ring` (its append kernel
+    on the chip); a chunk is one scatter of its columns, on a stack that
+    is a private prefill cache's and small."""
+    ring = stack.shape[-1]
+    b, s_new = new.shape[:2]
+    posv = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
+    if s_new == 1:
+        return update_latent(stack, layer, new, posv % ring)
+    keep = min(s_new, ring)
+    at = (posv[:, None] + (s_new - keep)
+          + jnp.arange(keep, dtype=jnp.int32)[None, :]) % ring
+    slot = jnp.arange(b, dtype=jnp.int32)[:, None]
+    return stack.at[layer, slot, :, at].set(
+        new[:, s_new - keep:].astype(stack.dtype), unique_indices=True)
 
 
 def quantize_kv(x: jax.Array, storage_dtype) -> Tuple[jax.Array, jax.Array]:
@@ -507,38 +612,33 @@ def _logical_nbytes(a: jax.Array) -> int:
 def kv_cache_nbytes(num_layers: int, batch: int, max_seq: int,
                     kv_heads: int, head_dim: int,
                     kv_cache_dtype: Optional[str] = None) -> Dict[str, int]:
-    """Storage footprint of a WOULD-BE cache, computed from its
-    geometry without allocating anything — byte-for-byte identical to
-    ``kv_cache_bytes(init_cache(...))`` (the memory ledger and the
-    engine's admission-cost estimate depend on that exactness; tests
-    assert it). Same components: codes planes, scale planes, total."""
-    name = resolve_kv_cache_dtype(kv_cache_dtype)
-    dt = jnp.dtype(KV_CACHE_DTYPES[name])
-    n = num_layers * batch * max_seq * kv_heads * head_dim
-    if name == "int4":
-        codes = 2 * (-(-n // 2))       # k + v, two codes per byte each
-    else:
-        codes = 2 * n * dt.itemsize
-    scales = 0
-    if name in SCALED_KV_DTYPES:
-        scales = 2 * num_layers * batch * max_seq * kv_heads \
-            * jnp.dtype(jnp.float32).itemsize
-    return {"codes": codes, "scales": scales, "total": codes + scales}
+    """`cache_nbytes` of a K/V cache from its geometry."""
+    return cache_nbytes(CacheSpec("kv", num_layers, kv_heads, head_dim),
+                        batch, max_seq, kv_cache_dtype)
 
 
 def cache_nbytes(spec: CacheSpec, batch: int, max_seq: int,
                  kv_cache_dtype: Optional[str] = None) -> Dict[str, int]:
-    """`kv_cache_nbytes` for any `CacheSpec`: the footprint of a would-be
-    cache from its description, equal byte for byte to
-    ``kv_cache_bytes(init_cache_spec(...))``. A latent plane counts as
-    codes."""
-    if spec.kind != "latent":
-        return kv_cache_nbytes(spec.num_layers, batch, max_seq,
-                               spec.kv_heads, spec.head_dim, kv_cache_dtype)
-    name = reject_non_bf16_latent(kv_cache_dtype)
-    codes = (spec.num_layers * batch * max_seq * spec.latent_dim
-             * jnp.dtype(KV_CACHE_DTYPES[name]).itemsize)
-    return {"codes": codes, "scales": 0, "total": codes}
+    """Storage footprint of a WOULD-BE cache, computed from the planes
+    its spec lists without allocating anything — byte-for-byte identical
+    to ``kv_cache_bytes(init_cache_spec(...))`` (the memory ledger and
+    the engine's admission-cost estimate depend on that exactness; tests
+    assert it). Components: code planes (K and V, or the latent, index
+    and window planes; int4 at two codes per byte), scale planes, total."""
+    name = resolve_kv_cache_dtype(kv_cache_dtype)
+    item = jnp.dtype(KV_CACHE_DTYPES[name]).itemsize
+    codes = scales = 0
+    for p in spec.plane_specs(name):
+        n = 1
+        for d in p.shape(batch, max_seq):
+            n *= d
+        if p.name.endswith("_scale"):
+            scales += n * jnp.dtype(jnp.float32).itemsize
+        elif name == "int4":
+            codes += -(-n // 2)
+        else:
+            codes += n * item
+    return {"codes": codes, "scales": scales, "total": codes + scales}
 
 
 def kv_cache_bytes(cache: KVCache) -> Dict[str, int]:
@@ -564,14 +664,16 @@ def publish_kv_cache_bytes(cache: KVCache, registry=None) -> Dict[str, int]:
         g = registry.gauge(
             "bigdl_tpu_kv_cache_bytes",
             "KV cache storage bytes by dtype and component "
-            "(codes | scales | total, and latent for a latent plane); "
-            "int4 counted at two codes per byte",
+            "(codes | scales | total, and latent | index | window for "
+            "such a plane); int4 counted at two codes per byte",
             labelnames=("dtype", "component"))
         for comp, val in sizes.items():
             g.labels(cache.kv_dtype, comp).set(float(val))
-        if cache.latent is not None:
-            g.labels(cache.kv_dtype, "latent").set(
-                float(_logical_nbytes(cache.latent)))
+        for comp in ("latent", "index", "window"):
+            plane = getattr(cache, comp)
+            if plane is not None:
+                g.labels(cache.kv_dtype, comp).set(
+                    float(_logical_nbytes(plane)))
     except Exception:
         pass
     return sizes

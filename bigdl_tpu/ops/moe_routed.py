@@ -15,7 +15,12 @@ Routing (`route`): `softmax` scores in float32, then plain top-k
 `n_group` groups of consecutive experts, a group scores its best
 expert, the best `topk_group` groups stay, and the top-k is taken among
 their experts. Weights are the chosen scores, renormalised
-(`norm_topk_prob`) or times `routed_scaling_factor`.
+(`norm_topk_prob`) or times `routed_scaling_factor`. With `scoring`
+"sigmoid" the scores are `sigmoid(logits)`, and `noaux_tc` (DeepSeek-V3's
+bias-corrected choice, one routing group) takes the top-k of `scores +
+bias`, a per-expert correction that chooses and does not weigh: the
+weights are the chosen experts' own scores, renormalised
+(`norm_topk_prob`) and times `routed_scaling_factor`.
 
 Two strategies by token count, both one Pallas kernel
 (`ops/pallas/moe_routed.py`), no host sync, no data-dependent shape:
@@ -60,11 +65,25 @@ class Share(NamedTuple):
 
 def route(logits: jax.Array, top_k: int, *, n_group: int = 1,
           topk_group: int = 1, method: str = "greedy",
-          scaling_factor: float = 1.0, norm_topk_prob: bool = False):
+          scaling_factor: float = 1.0, norm_topk_prob: bool = False,
+          scoring: str = "softmax", bias=None):
     """Router logits `[N, E]` -> (expert ids `[N, k]` int32, weights
-    `[N, k]` float32)."""
-    scores = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    `[N, k]` float32). `bias` `[E]`: `noaux_tc`'s correction."""
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    else:
+        raise NotImplementedError(f"scoring_func {scoring!r}")
     n, e = scores.shape
+    if method == "noaux_tc":
+        if n_group != 1:
+            raise NotImplementedError("noaux_tc over several groups")
+        _, topi = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        topv = jnp.take_along_axis(scores, topi, axis=-1)
+        if norm_topk_prob and top_k > 1:
+            topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+        return topi.astype(jnp.int32), topv * scaling_factor
     choice = scores
     if method == "group_limited_greedy":
         group_best = scores.reshape(n, n_group, e // n_group).max(axis=-1)

@@ -62,50 +62,73 @@ def mla_decode_supported(q_c, q_pe, latent) -> bool:
             and _s_block(latent.shape[-1]) > 0)
 
 
-def _kernel(layer_ref, pos_ref, qc_ref, qpe_ref, lat_ref, out_ref,
-            m_ref, l_ref, acc_ref, *, scale, sb, ns, c):
-    """One (slot, S-block) step of the online-softmax sweep."""
-    del layer_ref                     # consumed by the index maps
-    sj = pl.program_id(1)
-    pos = pos_ref[pl.program_id(0)]
-
+def sweep_init(sj, m_ref, l_ref, acc_ref):
+    """Reset the online-softmax state at a slot's first block."""
     @pl.when(sj == 0)
     def _():
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(sj * sb <= pos)          # a block wholly past pos: nothing
-    def _():
-        ckv = lat_ref[:c, :]                              # [C, sb]
-        kpe = lat_ref[c:, :]                              # [R, sb]
-        s_ = (jax.lax.dot_general(
-            qc_ref[...], ckv, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-            + jax.lax.dot_general(
-                qpe_ref[...], kpe, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)) * scale
-        col = jax.lax.broadcasted_iota(jnp.int32, s_.shape, 1)
-        s_ = jnp.where(col <= pos - sj * sb, s_, _NEG_INF)
 
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s_, axis=-1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s_ - m_new)
-        l_ref[:] = jnp.broadcast_to(
-            l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
-            l_ref.shape)
-        # the same block again, now as the value: [H, sb] x [C, sb]^T
-        pv = jax.lax.dot_general(
-            p.astype(jnp.bfloat16), ckv, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * corr + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+def sweep_block(qc_ref, qpe_ref, lat_ref, m_ref, l_ref, acc_ref, live, *,
+                scale, c):
+    """One block of the latent plane through the online softmax: its
+    scores against every head's query, masked by `live(shape)` (True
+    where a column may be attended), and the same block again as the
+    value. A block with no live column leaves unit weights behind that
+    the first live block's correction (`exp(-1e30 - m)`, exactly 0)
+    wipes out; every sweep of a slot that holds a position has one."""
+    ckv = lat_ref[:c, :]                              # [C, sb]
+    kpe = lat_ref[c:, :]                              # [R, sb]
+    s_ = (jax.lax.dot_general(
+        qc_ref[...], ckv, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+        + jax.lax.dot_general(
+            qpe_ref[...], kpe, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)) * scale
+    s_ = jnp.where(live(s_.shape), s_, _NEG_INF)
 
+    m_prev = m_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s_, axis=-1, keepdims=True))
+    corr = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s_ - m_new)
+    l_ref[:] = jnp.broadcast_to(
+        l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
+        l_ref.shape)
+    # the same block again, now as the value: [H, sb] x [C, sb]^T
+    pv = jax.lax.dot_general(
+        p.astype(jnp.bfloat16), ckv, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    acc_ref[:] = acc_ref[:] * corr + pv
+    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+
+
+def sweep_finish(sj, ns, out_ref, l_ref, acc_ref):
     @pl.when(sj == ns - 1)
     def _():
         l = jnp.maximum(l_ref[:, :1], 1e-30)
         out_ref[...] = (acc_ref[:] / l).astype(out_ref.dtype)
+
+
+def _kernel(layer_ref, pos_ref, qc_ref, qpe_ref, lat_ref, out_ref,
+            m_ref, l_ref, acc_ref, *, scale, sb, ns, c):
+    """One (slot, S-block) step of the online-softmax sweep."""
+    del layer_ref                     # consumed by the index maps
+    sj = pl.program_id(1)
+    pos = pos_ref[pl.program_id(0)]
+    sweep_init(sj, m_ref, l_ref, acc_ref)
+
+    @pl.when(sj * sb <= pos)          # a block wholly past pos: nothing
+    def _():
+        def live(shape):
+            col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            return col <= pos - sj * sb
+
+        sweep_block(qc_ref, qpe_ref, lat_ref, m_ref, l_ref, acc_ref, live,
+                    scale=scale, c=c)
+
+    sweep_finish(sj, ns, out_ref, l_ref, acc_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))

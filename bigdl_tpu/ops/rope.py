@@ -145,6 +145,15 @@ def rope_cos_sin(
     return jnp.cos(angles), jnp.sin(angles)
 
 
+def rope_tables(positions: jax.Array, kinds) -> dict:
+    """cos/sin tables of SEVERAL rotary geometries in one model, for the
+    same positions: `kinds` maps a name to `(rotary_dim, theta)` (layers
+    of two kinds, each with its own base); returns `{name: (cos, sin)}`,
+    each `[..., rotary_dim // 2]`."""
+    return {name: rope_cos_sin(positions, rope_freqs(rd, theta))
+            for name, (rd, theta) in kinds.items()}
+
+
 def _rotate_half(x: jax.Array) -> jax.Array:
     h = x.shape[-1] // 2
     return jnp.concatenate([-x[..., h:], x[..., :h]], axis=-1)

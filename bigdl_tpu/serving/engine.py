@@ -508,6 +508,15 @@ class LLMEngine:
         # one latent plane): every cache here is made, spliced, measured
         # and exported through this description
         self._cache_spec = cache_spec_of(self.family, self.cfg)
+        if (self._cache_spec.has_ring
+                and self.cfg_engine.prefix_cache_entries > 0):
+            raise ValueError(
+                f"prefix_cache_entries="
+                f"{self.cfg_engine.prefix_cache_entries}: the "
+                f"{self.family.name!r} family keeps a ring plane (a window "
+                "layer's last positions), and a snapshot of a ring is the "
+                "state at the length it was taken, not at a shorter "
+                "prefix; serve it with prefix_cache_entries=0")
         self.eos_token_id = None
         hf = getattr(model, "hf_config", None) or {}
         eos = hf.get("eos_token_id")
@@ -1030,6 +1039,19 @@ class LLMEngine:
             _, b_, s_, hkv_ = self.cache.k.shape[:4]
             self._attn_blocks = (self.cache.num_layers,
                                  slab_blocks(b_, s_, hkv_), s_, hkv_)
+        # a family whose full layers select their keys (an index plane):
+        # (full layers, positions a query selects at most); else None
+        self._dsa = None
+        if not self._paged and self.cache.index is not None:
+            self._dsa = (int(self.cache.index.shape[0]),
+                         int(self.cfg.index_topk))
+            self._m_dsa_positions = m.counter(
+                "bigdl_tpu_dsa_positions_total",
+                "Cached positions of the sparse-attention layers in a "
+                "decode step, all full layers and live slots: kind=live "
+                "those a query could attend (its own counted), "
+                "kind=selected those its selection keeps (at most "
+                "index_topk a query and layer).", labelnames=("kind",))
         self._m_prefill_chunks = m.counter(
             "bigdl_tpu_prefill_chunks_total",
             "Prefill chunks dispatched by admission (at most one per "
@@ -1624,7 +1646,7 @@ class LLMEngine:
         bucket = self._bucket(prompt_len)
         chunk = min(self._chunk, bucket)
         alloc = -(-bucket // chunk) * chunk
-        return cache_nbytes(self._cache_spec, 1, alloc,
+        return cache_nbytes(self._cache_spec.unrolled(), 1, alloc,
                             self.kv_cache_dtype)["total"]
 
     def _admission_step(self) -> None:
@@ -1704,7 +1726,9 @@ class LLMEngine:
                 if paged_adm is None:
                     return
                 consumed, shared_pages, new_pages = paged_adm
-            cache1 = init_cache_spec(self._cache_spec, 1, alloc,
+            # a ring of the slab is a plane in position order here: the
+            # chunk's padding must not overwrite live columns
+            cache1 = init_cache_spec(self._cache_spec.unrolled(), 1, alloc,
                                      kv_cache_dtype=self.kv_cache_dtype)
             if self._paged:
                 if consumed:
@@ -4122,6 +4146,15 @@ class LLMEngine:
                 poss[i] = s.req.generated_offset + len(s.generated)
             return temps, top_ks, top_ps, seeds, poss
 
+        if self._dsa is not None:
+            # what the selection keeps this step, by its own rule from
+            # the positions the host already knows (outside the phases)
+            n_full, topk = self._dsa
+            held = [len(self.slots[i].req.prompt_token_ids)
+                    + len(self.slots[i].generated) for i in active]
+            self._m_dsa_positions.labels("live").inc(n_full * sum(held))
+            self._m_dsa_positions.labels("selected").inc(
+                n_full * sum(min(d, topk) for d in held))
         toks = None
         finite_host = None
         toks_dev = finite_dev = qrows_dev = logits_dev = None

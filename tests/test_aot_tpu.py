@@ -1136,3 +1136,121 @@ def test_routed_expert_kernel_compiles_on_the_layer_stack(
         _sds(jax.ShapeDtypeStruct((), jnp.int32), dev))
     assert _has_mosaic_call(comp)
     assert comp.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
+
+
+# -- dots3-note (PR 33): sparse attention over a latent cache, a ring ------
+
+DOTS3 = dict(b=8, s=16384, full=5, win=9, ring=640)
+
+
+@pytest.mark.parametrize("kernel", ["dsa_index_score", "dsa_select",
+                                    "sparse_mla_decode", "window_mla_decode",
+                                    "ring_append", "index_append"])
+def test_dots3_note_decode_kernels_compile_at_published_widths(
+        v5e, aot_flags, kernel):
+    """Each new kernel of the sparse-attention family for one v5e at the
+    published widths over the cell's slab (8 slots x 16384 positions; 64
+    index heads x 128; 128 heads over 576-wide rows with a selection
+    mask; 64 heads over the 1088-wide ring of 640 with window 513; the
+    append kernel on the ring and on the index plane), the stack as
+    operand and the layer a prefetched scalar: a Mosaic call and no copy
+    of a layer's plane."""
+    from bigdl_tpu.ops.pallas import dsa_attention as K
+    from bigdl_tpu.ops.pallas.mla_attention import latent_append_pallas
+
+    dev = v5e.devices[0]
+    b, s, ring = DOTS3["b"], DOTS3["s"], DOTS3["ring"]
+    bf, i32 = jnp.bfloat16, jnp.int32
+
+    def sd(shape, dt=bf):
+        return _sds(jax.ShapeDtypeStruct(shape, dt), dev)
+
+    pos, lyr = sd((b,), i32), sd((), i32)
+    if kernel == "dsa_index_score":
+        comp = _compile(
+            lambda q, w, ix, p, ly: K.dsa_index_score_pallas(q, w, ix, p,
+                                                             layer=ly),
+            sd((b, 64, 128)), sd((b, 64), jnp.float32),
+            sd((DOTS3["full"], b, 128, s)), pos, lyr)
+    elif kernel == "dsa_select":
+        comp = _compile(lambda sc: K.dsa_select_pallas(sc, 2048),
+                        sd((b, s), jnp.float32))
+    elif kernel == "sparse_mla_decode":
+        comp = _compile(
+            lambda qc, qp, lat, p, m, ly: K.sparse_mla_decode_pallas(
+                qc, qp, lat, p, m, 192 ** -0.5, layer=ly),
+            sd((b, 128, 512)), sd((b, 128, 64)),
+            sd((DOTS3["full"], b, 576, s)), pos, sd((b, s), jnp.bool_), lyr)
+    elif kernel == "window_mla_decode":
+        comp = _compile(
+            lambda qc, qp, lat, p, ly: K.window_mla_decode_pallas(
+                qc, qp, lat, p, 256 ** -0.5, 513, layer=ly),
+            sd((b, 64, 1024)), sd((b, 64, 64)),
+            sd((DOTS3["win"], b, 1088, ring)), pos, lyr)
+    else:
+        shape = ((DOTS3["win"], b, 1088, ring) if kernel == "ring_append"
+                 else (DOTS3["full"], b, 128, s))
+        comp = jax.jit(
+            lambda st, new, p, ly: latent_append_pallas(st, ly, new, p),
+            donate_argnums=(0,)).lower(
+            sd(shape), sd((b, shape[2])), pos, lyr).compile()
+    assert _has_mosaic_call(comp)
+    assert comp.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
+
+
+def test_dots3_note_engine_programs_compile_and_fit(v5e, aot_flags):
+    """The engine's resident decode step for the cell's configuration (14
+    layers at published widths, 8 slots x 16384, shapes only): every new
+    kernel is in it, no instruction materializes a layer of a plane, and
+    arguments plus temporaries stay under 11 GB of the chip's 16."""
+    import json
+    import re
+    import sys
+    from pathlib import Path
+
+    bench = Path(__file__).resolve().parents[1] / "benchmark"
+    sys.path[:0] = [str(bench)]
+    from harness import weights_dots3_note as weights
+    from harness.weights import _family_config
+
+    from bigdl_tpu.models import dots3_note
+    from bigdl_tpu.ops.quant import prepack_tree
+    from bigdl_tpu.serving import EngineConfig, LLMEngine
+
+    doc = json.loads(
+        (bench / "configs" / "dots3-note-ep8-int4.json").read_text())
+    family, cfg, hf = _family_config(doc)
+
+    class Model:
+        params = jax.eval_shape(lambda: prepack_tree(
+            dots3_note.prepare_params(
+                weights.build_params(cfg, "sym_int4", 1), cfg), "on")[0])
+        config, hf_config, qtype = cfg, hf, "sym_int4"
+
+    Model.family = family
+    dev = v5e.devices[0]
+    b = doc["engine"]["max_batch"]
+    eng = LLMEngine(Model, EngineConfig(
+        max_batch=b, max_seq=doc["engine"]["max_seq"],
+        prefill_chunk=doc["engine"]["prefill_chunk"], sentinel=False,
+        quality=False))
+    i32 = _sds(jax.ShapeDtypeStruct((b,), jnp.int32), dev)
+    f32 = _sds(jax.ShapeDtypeStruct((b,), jnp.float32), dev)
+    comp = eng._decode_resident.lower(
+        _sds(eng.params, dev), i32,
+        _sds(jax.eval_shape(lambda: eng.cache), dev),
+        f32, i32, f32, i32, i32, all_greedy=True,
+        with_quality=False).compile()
+    txt = comp.as_text()
+    for name in ("dsa_index_score", "dsa_select", "sparse_mla_decode",
+                 "window_mla_decode", "mla_latent_append",
+                 "moe_routed_decode"):
+        assert name in txt, name
+    ma = comp.memory_analysis()
+    live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert 8.0e9 < live < 11e9, live / 1e9    # 8.30 GB weights + slab
+    moved = re.findall(
+        r"= \w+\[(?:\d+,)?8,(?:576|128|1088),(?:16384|640)\]\S* "
+        r"(?:copy|fusion|dynamic-slice)\(", txt)
+    assert not moved, f"a layer of a cache plane is materialized: {moved}"
