@@ -44,4 +44,35 @@ entries only; nothing that is here needs an edit):
    file where none fits, and the entries in ``BENCHMARK.json``.
 ``tests/benchmark/test_bench_run_tiny.py`` adds such a configuration
 to a copy of the tree and runs it.
+
+A new cell's time budget (PR 38; the driver stops a run at 360 s, and a
+run that is stopped loses the PR, whoever's it is):
+
+- its whole run, process start to the result line (``wall_s`` on the
+  ``"info": "run"`` note line and on the last line of standard error,
+  beside ``common.RUN_BUDGET_S``), ends within 270 s warm in the median
+  of six seeds and within 300 s in EVERY warm run, traced or not; its PR
+  reports the note line's ``phases`` in PERF.md, cold and warm;
+- what runs after the window (``check_a_program``, ``canonical_tree``,
+  ``layer_check``, ``check_a_reference``, ``check_b_served``) takes at
+  most 75 s warm. ``canonical_params`` is called ONCE, and the tree it
+  returns serves every comparison. Nothing after the window may
+  compile in a warm run: ``served.compare`` pads every request of a
+  sample to ONE length (``served.pad_length`` of the longest request
+  the traffic can ask for, not of what a run finished), because a
+  reference compiles a set of programs per length, a minute each at
+  14k tokens;
+- a configuration's own layer check (``checks_<family>.layer_check``,
+  run inside ``canonical_params``) covers each KIND of layer once, at
+  sizes where its mechanisms bind, never every layer of the cut; its
+  programs are jitted once per kind; it leaves ``{"seconds", "within",
+  "compared": [(name, value, limit[, "floor"])]}`` on the tree under
+  ``"layer_check"``, which the runner charges to the ``layer_check``
+  phase, prints among the compared numbers and holds ``correct`` to;
+- warm-up is the traffic generator's (``traffic.warmup_plan``: one
+  prompt for each class of prompt the window holds, then the sampling
+  variants), and it is set-up, inside the same budget: in the one
+  long-context cell its prompts cost about 15 s of a 67 s warm-up, the
+  rest is loading the engine's programs (PERF.md 2, PR 38), so keep a
+  cell's buckets few before its prompts.
 """

@@ -216,9 +216,12 @@ def layer_check(config: Dict[str, Any], canonical: Dict[str, Any],
     """The check of ``config`` on the canonical tree of ``seed``: the
     program's blocks (or ``stand_in``) against the reference's, with the
     limits and the verdict."""
+    import time
+
     from harness import reference_deepseek_v2 as reference
     from harness.weights import _family_config
 
+    t_start = time.monotonic()
     arch, eng = config["reference"], config["engine"]
     quant = {"qtype": config["quant"], "block": config["quant_block"]}
     max_seq = int(eng["max_seq"])
@@ -232,24 +235,21 @@ def layer_check(config: Dict[str, Any], canonical: Dict[str, Any],
     out["limits"] = reference.layer_limits(config)
     out["within"] = all(out["found"][k] <= v
                         for k, v in out["limits"].items())
+    out["seconds"] = time.monotonic() - t_start
     return out
 
 
-def report(check: Dict[str, Any]) -> None:
-    """A note line with every layer's reading, and each compared number
-    beside its limit on standard error, as the harness prints its own."""
-    import sys
-
+def report(check: Dict[str, Any]) -> list:
+    """A note line with every layer's reading; returns each compared
+    number beside its limit, ``(name, value, limit)``, for
+    ``common.print_compared`` or the runner's last lines."""
     from harness import common
 
     common.note(info="layer_check", found=check["found"],
                 limits=check["limits"], within=check["within"],
-                layers=check["layers"])
-    for k, limit in check["limits"].items():
-        v = check["found"][k]
-        print(f"compared layer_rel_l2.{k} = {v} limit {limit}: "
-              f"{'ok' if v <= limit else 'OVER'}", file=sys.stderr,
-              flush=True)
+                seconds=check["seconds"], layers=check["layers"])
+    return [(f"layer_rel_l2.{k}", check["found"][k], limit)
+            for k, limit in check["limits"].items()]
 
 
 def main(argv=None) -> int:
@@ -294,11 +294,11 @@ def main(argv=None) -> int:
     check = layer_check(
         config, canonical, args.seed,
         stand_in=LowerPrecisionBlocks(arch, quant, canonical, lower))
-    report(check)
     verdicts = {"reference_within_tolerance": max(rel.values()) <= tol,
                 "layers_within_limits": check["within"]}
     common.report_compared(
-        [(f"reference_rel_l2.{k}", v, tol) for k, v in rel.items()],
+        report(check)
+        + [(f"reference_rel_l2.{k}", v, tol) for k, v in rel.items()],
         verdicts)
     print(json.dumps({"control": args.latent_dtype, "seed": args.seed,
                       "reference_rel_l2": rel, "reference_tolerance": tol,

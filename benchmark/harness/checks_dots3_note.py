@@ -14,9 +14,24 @@ that is not the top. This check can: each block of each layer gets the
 reference's own input, rounded to bfloat16 so that both sides read the
 same numbers, and its output is held to the reference's for that input.
 
+Which layers (PR 38; all 14 before, four to five windows' time after
+every window). One of every KIND the cut holds, ``checked_layers``: the
+leading layers up to the end of the first whole period, that is the
+dense layer 0, layer 1 (full attention, experts), the window layers
+with experts that follow and the full layer with its indexer and
+experts that closes the period: layers 0-5 of the 14. The later periods
+repeat these kinds shape for shape (one leaf a layer; the jitted
+programs are one per kind and serve every layer of it), on other seeded
+weights of the same distribution; the reference's stream is carried as
+far as the last layer checked and no further.
+
 What runs, at sizes where the mechanisms bind. A seeded sequence of
 ``PREFILL_ROWS + DECODE_ROWS`` tokens (4,096 + 8: twice ``index_topk``,
-eight windows) walks the reference; at every layer
+eight windows; ``prefill_rows`` is held above ``index_topk`` plus one
+chunk, so that the selection drops positions inside a chunk and in
+decode, and above the ring, so that it wraps: the smallest whole number
+of 1024-row chunks that does is 4) walks the reference; at every layer
+checked
 
 - attention: the program's ``attention_block`` prefills the first rows
   in the cell's chunks (``engine.prefill_chunk``) into a NEW one-layer
@@ -42,7 +57,7 @@ eight windows) walks the reference; at every layer
   batch of one-token slots; ``swiglu`` for the dense layer.
 
 Compared: each reading against ``reference_dots3_note.layer_limits``,
-the LARGEST over the layers (the smallest for the overlap). ``stand_in``
+the LARGEST over the layers checked (the smallest for the overlap). ``stand_in``
 puts something else in the program's place through the same comparison:
 the reference with a planted fault or a lower precision (``CONTROLS``).
 
@@ -74,6 +89,19 @@ CONTROLS = {
 
 def prefill_rows(max_seq: int) -> int:
     return min(PREFILL_ROWS, max_seq // 2)
+
+
+def checked_layers(arch: Dict[str, Any]) -> range:
+    """The layers the check covers: from layer 0 to the end of the first
+    whole period, the first full-attention layer that follows a window
+    layer (every layer where no window layer is followed by one). They
+    hold every kind of ``reference.layer_stack``: attention full and
+    window, feed-forward dense and routed."""
+    types = list(arch["layer_types"])[:int(arch["layers"])]
+    for i in range(1, len(types)):
+        if types[i] == FULL and types[i - 1] == WINDOW:
+            return range(i + 1)
+    return range(len(types))
 
 
 def check_ids(seed: int, vocab: int, n: int):
@@ -160,7 +188,7 @@ class ProgramBlocks:
             out, cache, probe = self._attn(lp, y[None, a:b], cache, kind,
                                            sel)
             rows.append(np.asarray(out[0], np.float32))
-            if probe:
+            if probe and given is None:     # a given selection is not read
                 scores.append(np.asarray(probe["index_scores"][0])[:, :n])
                 picked.append(np.asarray(probe["selected"][0])[:, :n])
         got = {"out": np.concatenate(rows)}
@@ -242,9 +270,9 @@ class AlteredReference:
 def layer_errors(blocks, canonical: Dict[str, Any], arch: Dict[str, Any],
                  quant: Dict[str, Any], ids, n_prefill: int
                  ) -> Dict[str, Any]:
-    """``blocks`` against the reference's blocks on the same inputs: for
-    each reading the largest over the layers (``index_overlap_min``: the
-    smallest), and every layer's."""
+    """``blocks`` against the reference's blocks on the same inputs, in
+    ``checked_layers``: for each reading the largest over those layers
+    (``index_overlap_min``: the smallest), and every layer's."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -279,7 +307,10 @@ def layer_errors(blocks, canonical: Dict[str, Any], arch: Dict[str, Any],
 
     x = canonical["embed_tokens"][jnp.asarray(list(ids), jnp.int32)].astype(
         jnp.float32)
+    covered = checked_layers(arch)
     for i, kind, lp, ex in reference.layer_stack(canonical, arch):
+        if i not in covered:
+            break        # the stream goes no further than the check
         y = ref(norm, x, lp["input_layernorm"])
         a, probe = ref(attn, y.astype(jnp.float32), lp, kind)
         got = blocks.attention(i, kind, y)
@@ -303,7 +334,8 @@ def layer_errors(blocks, canonical: Dict[str, Any], arch: Dict[str, Any],
         x = x + f
     found = {k: (min(v) if k == "index_overlap_min" else max(v))
              for k, v in per_layer.items()}
-    return {"found": found, "layers": per_layer}
+    return {"found": found, "layers": per_layer,
+            "checked_layers": list(covered)}
 
 
 def _within(found, limits) -> bool:
@@ -317,9 +349,12 @@ def layer_check(config: Dict[str, Any], canonical: Dict[str, Any],
     """The check of ``config`` on the canonical tree of ``seed``: the
     program's blocks (or ``stand_in``) against the reference's, with the
     limits and the verdict."""
+    import time
+
     from harness import reference_dots3_note as reference
     from harness.weights import _family_config
 
+    t_start = time.monotonic()
     arch, eng = config["reference"], config["engine"]
     quant = {"qtype": config["quant"], "block": config["quant_block"]}
     max_seq = int(eng["max_seq"])
@@ -333,27 +368,26 @@ def layer_check(config: Dict[str, Any], canonical: Dict[str, Any],
     out = layer_errors(stand_in, canonical, arch, quant, ids, n_prefill)
     out["limits"] = reference.layer_limits(config)
     out["within"] = _within(out["found"], out["limits"])
+    out["seconds"] = time.monotonic() - t_start
     return out
 
 
-def report(check: Dict[str, Any]) -> None:
-    """A note line with every layer's reading, and each compared number
-    beside its limit on standard error, as the harness prints its own."""
-    import sys
-
+def report(check: Dict[str, Any]) -> list:
+    """A note line with every checked layer's reading; returns each
+    compared number beside its limit, ``(name, value, limit[,
+    "floor"])``, for ``common.print_compared`` or the runner's last
+    lines."""
     from harness import common
 
     common.note(info="layer_check", found=check["found"],
                 limits=check["limits"], within=check["within"],
-                layers=check["layers"])
-    for k, limit in check["limits"].items():
-        v = check["found"].get(k)
-        floor = k == "index_overlap_min"
-        ok = v is not None and (v >= limit if floor else v <= limit)
-        name = k if k.startswith("index_") else f"layer_rel_l2.{k}"
-        print(f"compared {name} = {v} {'floor' if floor else 'limit'} "
-              f"{limit}: {'ok' if ok else 'OVER'}", file=sys.stderr,
-              flush=True)
+                checked_layers=check["checked_layers"],
+                seconds=check["seconds"], layers=check["layers"])
+    return [(k, check["found"].get(k), limit, "floor")
+            if k == "index_overlap_min" else
+            (k if k.startswith("index_") else f"layer_rel_l2.{k}",
+             check["found"].get(k), limit)
+            for k, limit in check["limits"].items()]
 
 
 def main(argv=None) -> int:
@@ -373,7 +407,7 @@ def main(argv=None) -> int:
     ap.add_argument("--tiny", action="store_true")
     args = ap.parse_args(argv)
 
-    from harness import spec, weights_dots3_note as weights
+    from harness import common, spec, weights_dots3_note as weights
 
     config = json.loads(
         (here / "configs" / f"{args.config}.json").read_text())
@@ -385,11 +419,13 @@ def main(argv=None) -> int:
     sound = None
     if not args.skip_sound:
         check = layer_check(config, canonical, args.seed)
-        report(check)
+        common.print_compared(report(check))
         sound = check["within"]
         print(json.dumps({"control": None, "seed": args.seed,
                           "found": check["found"],
                           "limits": check["limits"],
+                          "checked_layers": check["checked_layers"],
+                          "seconds": check["seconds"],
                           "within": check["within"]}), flush=True)
     refused = {}
     for name in [c for c in args.controls.split(",") if c]:
@@ -401,6 +437,7 @@ def main(argv=None) -> int:
         refused[name] = not check["within"]
         print(json.dumps({"control": name, "seed": args.seed,
                           "found": check["found"], "over": over,
+                          "seconds": check["seconds"],
                           "within": check["within"]}), flush=True)
     print(json.dumps({"seed": args.seed, "sound_within": sound,
                       "controls_refused": refused,

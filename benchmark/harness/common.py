@@ -6,9 +6,60 @@ from __future__ import annotations
 
 import json
 import shutil
+import sys
 import threading
+import time
 from pathlib import Path
 from typing import Any, Dict, Optional, Sequence
+
+# A run's whole wall time, process start to the result line, is held to
+# this: five sixths of the 360 s after which the driver stops a run. A
+# run over it is still reported and still ``correct``; it is only said.
+RUN_BUDGET_S = 300.0
+# Where a run's wall time goes, in the order it is spent. A runner laps
+# the phases it has; every name is on the note line, 0.0 where a cell
+# has no such phase.
+PHASES = ("devices_ready", "weights", "engine_and_server", "warmup",
+          "window", "drain", "check_a_program", "canonical_tree",
+          "layer_check", "check_a_reference", "check_b_served",
+          "trace_reduction", "exit")
+
+
+class WallClock:
+    """Consecutive laps from the process's start: each lap charges the
+    time since the last one to a phase, so the phases add up to the
+    wall time whatever runs between them."""
+
+    def __init__(self, t_process: float):
+        self.t_process = self._last = t_process
+        self.phases: Dict[str, float] = {k: 0.0 for k in PHASES}
+
+    def lap(self, phase: str) -> None:
+        now = time.monotonic()
+        self.phases[phase] += now - self._last
+        self._last = now
+
+    def move(self, seconds: float, src: str, dst: str) -> None:
+        """Charge ``seconds`` of ``src`` to ``dst``: a part of a lap that
+        a configuration's module timed itself."""
+        seconds = min(max(0.0, float(seconds)), self.phases[src])
+        self.phases[src] -= seconds
+        self.phases[dst] += seconds
+
+    def close(self) -> Dict[str, Any]:
+        """``{"wall_s", "phases"}`` for the note line; what is left
+        since the last lap is ``exit``."""
+        self.lap("exit")
+        return {"wall_s": self._last - self.t_process,
+                "phases": dict(self.phases)}
+
+
+def report_wall(wall_s: float) -> None:
+    """The run's last line on standard error: its wall time beside the
+    harness's budget. Said, not judged: ``correct`` does not read it."""
+    print(f"wall_s = {wall_s} budget {RUN_BUDGET_S}: "
+          f"{'ok' if wall_s <= RUN_BUDGET_S else 'OVER'}",
+          file=sys.stderr, flush=True)
 
 
 class CompileWatch:
@@ -78,19 +129,36 @@ def free_device() -> None:
         a.delete()
 
 
-def report_compared(compared, checks: Dict[str, bool]) -> None:
-    """The run's last lines on standard error: every number that was
-    compared beside its limit, then every check that failed."""
-    import sys
+def _verdict(value, limit, floor: bool) -> str:
+    if value is None:
+        return "not read"
+    return "ok" if (value >= limit if floor else value <= limit) else "OVER"
 
-    for name, value, limit in compared:
-        verdict = ("not read" if value is None
-                   else "ok" if value <= limit else "OVER")
-        print(f"compared {name} = {value} limit {limit}: {verdict}",
-              file=sys.stderr)
+
+def print_compared(compared) -> Dict[str, Dict[str, Any]]:
+    """Each ``(name, value, limit)`` on a line of standard error; a
+    fourth entry ``"floor"`` makes the limit a lower one. Returns the
+    same numbers as the result line carries them."""
+    out = {}
+    for name, value, limit, *kind in compared:
+        floor = bool(kind) and kind[0] == "floor"
+        print(f"compared {name} = {value} {'floor' if floor else 'limit'} "
+              f"{limit}: {_verdict(value, limit, floor)}", file=sys.stderr,
+              flush=True)
+        out[name] = {"value": value, "floor" if floor else "limit": limit}
+    return out
+
+
+def report_compared(compared, checks: Dict[str, bool]
+                    ) -> Dict[str, Dict[str, Any]]:
+    """The run's last lines on standard error: every number that was
+    compared beside its limit, then every check that failed. Returns
+    the numbers for the result line's ``compared`` key."""
+    out = print_compared(compared)
     failed = sorted(k for k, ok in checks.items() if not ok)
     print(f"checks failed: {failed if failed else 'none'}",
           file=sys.stderr, flush=True)
+    return out
 
 
 def note(**fields: Any) -> None:

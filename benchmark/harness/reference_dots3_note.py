@@ -41,7 +41,14 @@ product: no score changes). Rows go in blocks of ``ROW_BLOCK`` (their
 index scores, selection, attention and feed-forward), heads in groups
 (queries, keys and values of one group at a time from the two latents)
 and the experts one at a time, so that a 14k-token request fits beside
-the weights at the published widths.
+the weights at the published widths. Two products that are zero by the
+model's own definition are not made (PR 38: this pass over four
+requests of up to 14.5k tokens was the longest part of a run): an
+expert runs on the rows that chose it (``feed_forward``), a window
+layer's row block meets the keys of its window, not all of them
+(``attention``), and a full layer's rows, their index scores and their
+selection stop at the last key a run of row blocks can see
+(``_causal_groups``).
 
 ``alter`` plants a fault or a lower precision for the controls of
 ``checks_dots3_note`` (``select: "first"``, ``window: n``, ``gate:
@@ -59,6 +66,7 @@ from harness.reference import (next_token_loss, relative_l2,  # noqa: F401
 
 HEAD_GROUP = 8        # heads whose [rows, S] scores are live together
 ROW_BLOCK = 512
+CAUSAL_GROUPS = 4     # runs of row blocks that stop at their last row's key
 FULL, WINDOW = "full_attention", "sliding_attention"
 INDEX_NORM_EPS = 1e-6
 
@@ -90,6 +98,16 @@ def _row_blocks(s: int) -> int:
     return ROW_BLOCK if s % ROW_BLOCK == 0 else s
 
 
+def _causal_groups(blocks: int):
+    """``(first, past-last)`` row blocks of up to ``CAUSAL_GROUPS`` runs.
+    No row of a run sees a key past the run's last row, so a run's
+    scores stop there: a quarter, a half, three quarters and all of the
+    keys in place of all of them four times."""
+    g = max(1, min(CAUSAL_GROUPS, blocks))
+    cuts = [round(i * blocks / g) for i in range(g + 1)]
+    return [(a, b) for a, b in zip(cuts, cuts[1:]) if b > a]
+
+
 def index_scores(y, c_q, lp, arch, quant):
     """``I`` ``[S, S]`` float32, ``-inf`` above the diagonal."""
     import jax
@@ -108,23 +126,29 @@ def index_scores(y, c_q, lp, arch, quant):
     k_i = _rope(k_i[:, None, :], pos, theta, rd)[:, 0]             # [S, di]
     w = (y @ _dense(lp["index_w_proj"], quant)) * (hi ** -0.5 * di ** -0.5)
     g = math.gcd(hi, HEAD_GROUP)
-
-    def heads(args):
-        qg, wg = args                                   # [S, g, di], [S, g]
-        return jnp.einsum("sg,sgt->st", wg, jax.nn.relu(
-            jnp.einsum("sgd,td->sgt", qg, k_i)))
-
-    def rows(args):
-        qb, wb = args                                   # [rb, hi, .]
-        rb = qb.shape[0]
-        parts = jax.lax.map(heads, (
-            jnp.moveaxis(qb.reshape(rb, hi // g, g, di), 1, 0),
-            jnp.moveaxis(wb.reshape(rb, hi // g, g), 1, 0)))
-        return parts.sum(axis=0)
-
     rb = _row_blocks(s)
-    tot = jax.lax.map(rows, (q_i.reshape(s // rb, rb, hi, di),
-                             w.reshape(s // rb, rb, hi))).reshape(s, s)
+    runs = []
+    for lo, past in _causal_groups(s // rb):
+        keys = k_i[:past * rb]              # none past the run's last row
+
+        def heads(args, keys=keys):
+            qg, wg = args                               # [S, g, di], [S, g]
+            return jnp.einsum("sg,sgt->st", wg, jax.nn.relu(
+                jnp.einsum("sgd,td->sgt", qg, keys)))
+
+        def rows(args, heads=heads):
+            qb, wb = args                               # [rb, hi, .]
+            parts = jax.lax.map(heads, (
+                jnp.moveaxis(qb.reshape(rb, hi // g, g, di), 1, 0),
+                jnp.moveaxis(wb.reshape(rb, hi // g, g), 1, 0)))
+            return parts.sum(axis=0)
+
+        run = jax.lax.map(rows, (
+            q_i[lo * rb:past * rb].reshape(past - lo, rb, hi, di),
+            w[lo * rb:past * rb].reshape(past - lo, rb, hi)))
+        runs.append(jnp.pad(run.reshape(-1, past * rb),
+                            ((0, 0), (0, s - past * rb))))
+    tot = jnp.concatenate(runs)
     return jnp.where(pos[None, :] <= pos[:, None], tot, -jnp.inf)
 
 
@@ -138,16 +162,21 @@ def select(scores, topk: int, how: str = "top"):
     live = scores > -jnp.inf
     if how == "first":
         return live & (jnp.arange(s)[None, :] < topk)
-    k = min(topk, s)
-
-    def rows(blk):
-        _, idx = jax.lax.top_k(blk, k)
-        return jnp.zeros(blk.shape, bool).at[
-            jnp.arange(blk.shape[0])[:, None], idx].set(True)
-
     rb = _row_blocks(s)
-    picked = jax.lax.map(rows, scores.reshape(s // rb, rb, s)).reshape(s, s)
-    return picked & live
+    runs = []
+    for lo, past in _causal_groups(s // rb):
+        ext = past * rb                  # every later score is -inf here
+        k = min(topk, ext)
+
+        def rows(blk, k=k):
+            _, idx = jax.lax.top_k(blk, k)
+            return jnp.zeros(blk.shape, bool).at[
+                jnp.arange(blk.shape[0])[:, None], idx].set(True)
+
+        run = jax.lax.map(rows, scores[lo * rb:ext, :ext].reshape(
+            past - lo, rb, ext))
+        runs.append(jnp.pad(run.reshape(-1, ext), ((0, 0), (0, s - ext))))
+    return jnp.concatenate(runs) & live
 
 
 def attention(y, lp, arch, quant, kind: str, alter=None, given=None,
@@ -197,6 +226,14 @@ def attention(y, lp, arch, quant, kind: str, alter=None, given=None,
     g = math.gcd(h, HEAD_GROUP)
     rb = _row_blocks(s)
     ok_blocks = allowed.reshape(s // rb, rb, s)
+    # A window layer's row block sees its own rows' keys and the window
+    # before them: ``span`` keys from the block's start on hold every
+    # allowed one, so the others are never multiplied (the mask still
+    # decides inside the span). A full layer's rows go in causal runs.
+    span = s
+    if kind != FULL:
+        span = min(s, rb + -(-(window - 1) // rb) * rb)
+    groups = _causal_groups(s // rb) if kind == FULL else [(0, s // rb)]
 
     def heads(args):
         """One group of heads: its queries, keys and values from the two
@@ -205,20 +242,30 @@ def attention(y, lp, arch, quant, kind: str, alter=None, given=None,
         q = jnp.einsum("sq,qgd->sgd", c_q, w_qb)
         q_pe = _rope(q[..., nope:], pos, theta, r)
         kvb = jnp.einsum("sc,cgd->sgd", c_kv, w_kvb)
-        k_nope, v = kvb[..., :nope], kvb[..., nope:]
+        runs = []
+        for lo, past in groups:
+            ext = past * rb              # a full layer: causal, no key past
+            sp = min(span, ext)
+            kn, kp, vv = kvb[:ext, :, :nope], k_pe[:ext], kvb[:ext, :, nope:]
 
-        def rows(rargs):
-            qn, qp, ok = rargs                         # [rb, g, .], [rb, S]
-            sc = (jnp.einsum("sgd,tgd->gst", qn, k_nope)
-                  + jnp.einsum("sgr,tr->gst", qp, k_pe)) * scale
-            probs = jax.nn.softmax(jnp.where(ok[None], sc, -jnp.inf),
-                                   axis=-1)
-            return jnp.einsum("gst,tgd->sgd", probs, v)
+            def rows(rargs, kn=kn, kp=kp, vv=vv, sp=sp):
+                qn, qp, ok, t0 = rargs              # [rb, g, .], [rb, ext], []
+                kn, kp, vv, ok = (
+                    jax.lax.dynamic_slice_in_dim(a, t0, sp, ax)
+                    for a, ax in ((kn, 0), (kp, 0), (vv, 0), (ok, 1)))
+                sc = (jnp.einsum("sgd,tgd->gst", qn, kn)
+                      + jnp.einsum("sgr,tr->gst", qp, kp)) * scale
+                probs = jax.nn.softmax(jnp.where(ok[None], sc, -jnp.inf),
+                                       axis=-1)
+                return jnp.einsum("gst,tgd->sgd", probs, vv)
 
-        out = jax.lax.map(rows, (q[..., :nope].reshape(s // rb, rb, g, nope),
-                                 q_pe.reshape(s // rb, rb, g, r),
-                                 ok_blocks))
-        return out.reshape(s, g, vd)
+            starts = jnp.clip(jnp.arange(lo, past) * rb + rb - sp, 0,
+                              ext - sp)
+            runs.append(jax.lax.map(rows, (
+                q[lo * rb:ext, :, :nope].reshape(past - lo, rb, g, nope),
+                q_pe[lo * rb:ext].reshape(past - lo, rb, g, r),
+                ok_blocks[lo:past, :, :ext], starts)))
+        return jnp.concatenate(runs).reshape(s, g, vd)
 
     def by_group(w, width):
         w = _dense(w, quant)
@@ -265,11 +312,27 @@ def route(scores, bias, arch: Dict[str, Any]):
         jnp.arange(s)[:, None], topi].set(topv)
 
 
+def expert_capacity(rows: int, arch: Dict[str, Any]) -> int:
+    """Rows an expert's own pass holds: twice what a uniform router
+    sends it, in whole 128s; ``rows`` (every row, the plain sum) where
+    that is no fewer."""
+    even = rows * int(arch["experts_per_tok"]) / int(arch["experts_total"])
+    return min(rows, -(-int(2 * even) // 128) * 128)
+
+
 def feed_forward(h, lp, experts, arch: Dict[str, Any], quant: Dict[str, Any],
-                 alter=None):
+                 alter=None, capacity: Optional[int] = None):
     """The feed-forward block on the normed ``h`` ``[S, D]``: dense where
     ``lp`` holds ``gate_proj``; else the shared expert plus the held
-    experts' part of the routed sum (``experts``: this layer's stacks)."""
+    experts' part of the routed sum (``experts``: this layer's stacks).
+
+    An expert's term is ``w[:, e] * SwiGLU_e(h)``, and ``w[:, e]`` is 0
+    on every row that did not choose it (31 of 32 rows at the published
+    sizes), so each expert runs on the ``capacity`` rows it was chosen
+    by, gathered, and its outputs are added back to those rows: the
+    same sum, a sixteenth of the products. An expert chosen by more rows
+    than ``capacity`` takes the plain form over every row, so no row is
+    ever dropped."""
     import jax
     import jax.numpy as jnp
 
@@ -283,12 +346,26 @@ def feed_forward(h, lp, experts, arch: Dict[str, Any], quant: Dict[str, Any],
         bias = jnp.zeros_like(bias)
     first, held = int(arch["first_held"]), int(arch["held"])
     weights = route(scores, bias, arch)[:, first:first + held]
+    s = h.shape[0]
+    cap = expert_capacity(s, arch) if capacity is None else int(capacity)
+
+    def every_row(acc, w_col, mats):
+        return acc + w_col[:, None] * _swiglu(h, *mats)
+
+    def chosen_rows(acc, w_col, mats):
+        idx = jnp.nonzero(w_col > 0, size=cap, fill_value=0)[0]
+        took = jnp.arange(cap) < jnp.sum(w_col > 0)     # not the filling
+        out = _swiglu(h[idx], *mats) * jnp.where(took, w_col[idx],
+                                                 0.0)[:, None]
+        return acc.at[idx].add(out)
 
     def one(acc, args):            # the experts one at a time, summed
         w_col, gate, up, down = args
-        return acc + w_col[:, None] * _swiglu(
-            h, _dense(gate, quant), _dense(up, quant),
-            _dense(down, quant)), None
+        mats = (_dense(gate, quant), _dense(up, quant), _dense(down, quant))
+        if cap >= s:
+            return every_row(acc, w_col, mats), None
+        return jax.lax.cond(jnp.sum(w_col > 0) <= cap, chosen_rows,
+                            every_row, acc, w_col, mats), None
 
     routed, _ = jax.lax.scan(one, jnp.zeros_like(h), (
         weights.T, experts["experts_gate"], experts["experts_up"],

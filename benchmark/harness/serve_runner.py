@@ -30,6 +30,7 @@ had without leaving the resident decode step (a request with
 
 from __future__ import annotations
 
+import contextlib
 import json
 import queue
 import subprocess
@@ -240,13 +241,14 @@ def _sweep(engine, port: int, traffic, rates, seed: int, seconds: float,
 
 
 def _reference_checks(cell, model, records, seed: int, seconds: float,
-                      dims) -> Dict[str, Any]:
+                      dims, clock: common.WallClock) -> Dict[str, Any]:
     """Both comparisons with the reference, once the window has closed
     and the peak is read: the program's logits of one seeded sequence,
     prefill and then decode through a cache of the cell's kind; then
     the program's state goes and the reference gets the device to
-    itself, on weights made anew from the seed, for that sequence and
-    for what the window served."""
+    itself, on weights made anew from the seed ONCE, for that sequence,
+    for the configuration's own layer check where it has one, and for
+    what the window served. ``clock`` is charged each part."""
     t_check = time.monotonic()
     config, traffic = cell.config, cell.traffic
     reference, weights = cell.modules["reference"], cell.modules["weights"]
@@ -255,22 +257,35 @@ def _reference_checks(cell, model, records, seed: int, seconds: float,
     prefill_row, state = prefill_into_cache(
         model, config["engine"], ids[:REF_PROMPT_TOKENS], seed)
     decode_rows = decode_through_cache(state, ids[REF_PROMPT_TOKENS:])
+    del state
     common.free_device()
+    clock.lap("check_a_program")
     quant = {"qtype": config["quant"], "block": config["quant_block"]}
     canonical = weights.canonical_params(config, seed)
+    clock.lap("canonical_tree")
+    # a configuration's own check runs inside ``canonical_params`` and
+    # leaves its seconds and its compared numbers on the tree
+    own = canonical.get("layer_check") or {}
+    clock.move(own.get("seconds", 0.0), "canonical_tree", "layer_check")
     rel = logits_errors(reference, canonical, config["reference"], quant,
                         ids, prefill_row, decode_rows)
+    clock.lap("check_a_reference")
     plan = traffic_mod.all_requests(traffic_mod.window_plan(
         traffic, seed, seconds, dims.vocab_size))
     samples = [{"prompt": plan[r["request"]]["prompt"],
                 "tokens": [int(x) for x in r["tokens"]]}
                for r in served.pick_sample(records, seed)]
-    found = served.compare(reference, canonical, config["reference"],
-                           quant, samples)
+    found = served.compare(
+        reference, canonical, config["reference"], quant, samples,
+        longest=max(len(p["prompt"]) + int(p["max_tokens"]) for p in plan))
+    del canonical
     common.free_device()
+    clock.lap("check_b_served")
     return {"rel": rel, "tolerance": reference.tolerance(config, kv_dtype),
             "served": found,
             "limits": reference.served_gap_limits(config, kv_dtype),
+            "own_compared": list(own.get("compared", [])),
+            "own_within": bool(own.get("within", True)),
             "seconds": time.monotonic() - t_check}
 
 
@@ -281,7 +296,9 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
     import jax
 
     watch = common.CompileWatch().install()
-    marks = {"devices_ready_s": time.monotonic() - t_process}
+    clock = common.WallClock(t_process)
+    clock.lap("devices_ready")
+    marks = {"devices_ready_s": clock.phases["devices_ready"]}
     config, traffic = cell.config, cell.traffic
     weights, costs = cell.modules["weights"], cell.modules["costs"]
     eng_cfg = dict(config["engine"])
@@ -294,6 +311,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
     t_build = time.monotonic()
     model, build_stages = weights.build_model(config, seed, merge=True)
     build_s = time.monotonic() - t_build
+    clock.lap("weights")
 
     overload = eng_cfg.pop("overload", None)
     if overload is not None:
@@ -302,19 +320,27 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
         eng_cfg["overload"] = OverloadConfig(**overload)
     engine = LLMEngine(model, EngineConfig(prefix_cache_entries=0,
                                            **eng_cfg))
-    if trace:
-        inner = engine.step
+    # The step is wrapped in every run, traced or not, and the span is
+    # a null context when not: a program that holds a Pallas kernel is
+    # keyed in the compile cache by the call stack it was traced from,
+    # so a wrapper in traced runs only made each side's first traced run
+    # compile both decode programs again (set-up 147 s for 99 s in the
+    # sparse-latent cell: my chip run, PR 38).
+    inner = engine.step
+    span = (jax.profiler.TraceAnnotation if trace
+            else lambda _name: contextlib.nullcontext())
 
-        def traced_step():
-            with jax.profiler.TraceAnnotation(STEP_SPAN):
-                return inner()
+    def wrapped_step():
+        with span(STEP_SPAN):
+            return inner()
 
-        engine.step = traced_step
+    engine.step = wrapped_step
     marks["model_and_engine_s"] = time.monotonic() - t_process
     server = OpenAIServer(engine, None)
     httpd = server.serve("127.0.0.1", 0, background=True)
     marks["serving_s"] = time.monotonic() - t_process
     port = httpd.server_address[1]
+    clock.lap("engine_and_server")
     child = None
     err_file = open(out_dir / "loadgen.stderr", "w")
     try:
@@ -329,6 +355,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
             return promtext.parse(engine.registry.render())
 
         c_setup = watch.snapshot()
+        clock.lap("warmup")
         t0 = _open_window(child)
         setup_s = t0 - t_process
         snap0 = counters()
@@ -362,6 +389,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
         snap1 = counters()
         w1 = watch.snapshot()
         mem_peak = common.memory_peak_bytes()
+        clock.lap("window")
 
         res = _records(child, lines, traffic, results_path)
         snap2 = counters()
@@ -375,7 +403,9 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
     records = res["records"]
     m = stats.serving_metrics(records, res["t0"], seconds)
     del engine, server, httpd      # the engine's cache may go at once
-    ref = _reference_checks(cell, model, records, seed, seconds, dims)
+    clock.lap("drain")
+    ref = _reference_checks(cell, model, records, seed, seconds, dims,
+                            clock)
     wrong_length = sum(1 for r in records
                        if r.get("error") and "asked" in r["error"])
     tracked_compiles = promtext.delta(snap0, snap1,
@@ -406,15 +436,44 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
             max(ref["rel"].values()) <= ref["tolerance"],
         "served_tokens_within_reference_gap":
             served.within(ref["served"], ref["limits"]),
+        "configuration_layer_check": ref["own_within"],
     }
     values = dict(m)
     values["setup_s"] = setup_s
+    dev = dict(device)
+    dev["memory_peak_bytes"] = mem_peak
+    result: Dict[str, Any] = {
+        "correct": all(checks.values()),
+        "attempted": m["attempted"], "failed": m["failed"], "device": dev,
+    }
+    if not trace:
+        # a CPU run yields no time and no rate: counts only
+        result["metrics"] = common.select_end_to_end(
+            cell, {} if tiny else values)
+    else:
+        work = costs.serving_work(config, dims, records, kv_dtype, trace_ab)
+        obs = {
+            "counters_start": snap0, "counters_end": snap1, "client": m,
+            "memory_peak_bytes": mem_peak or None,
+            "device_kind": device["kind"] if not tiny else None,
+            "peaks": peaks, "work": work,
+        }
+        common.traced_metrics(
+            cell, result, obs, trace_dir if trace_ab is not None else None,
+            STEP_SPAN, tiny, out_dir,
+            slot_occupancy_mean=(sum(occupancy) / len(occupancy)
+                                 if occupancy else None), work=work)
+        clock.lap("trace_reduction")
+
+    wall = clock.close()
     common.note(
         info="run", workload=cell.name, seed=seed, seconds=seconds,
         checks=checks, reference_rel_l2=ref["rel"],
         reference_tolerance=ref["tolerance"],
         served=dict(ref["served"], limits=ref["limits"]),
         reference_checks_s=ref["seconds"],
+        wall_s=wall["wall_s"], budget_s=common.RUN_BUDGET_S,
+        phases=wall["phases"],
         samples={"ttft": m["n_ttft"], "gaps": m["n_gaps"],
                  "tokens_in_window": m["tokens_in_window"],
                  "highest_ttft_percentile_with_10_beyond":
@@ -440,34 +499,10 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
             for k, v in (stats_json.get("compile_table") or {}).items()
             if v.get("compiles")})
 
-    common.report_compared(
-        [(f"reference_rel_l2.{k}", v, ref["tolerance"])
-         for k, v in ref["rel"].items()]
+    result["compared"] = common.report_compared(
+        ref["own_compared"]
+        + [(f"reference_rel_l2.{k}", v, ref["tolerance"])
+           for k, v in ref["rel"].items()]
         + [(k, ref["served"].get(k), v) for k, v in ref["limits"].items()],
         checks)
-    dev = dict(device)
-    dev["memory_peak_bytes"] = mem_peak
-    result: Dict[str, Any] = {
-        "correct": all(checks.values()),
-        "attempted": m["attempted"], "failed": m["failed"], "device": dev,
-    }
-    if not trace:
-        if tiny:
-            # a CPU run yields no time and no rate: counts only
-            values = {}
-        result["metrics"] = common.select_end_to_end(cell, values)
-        return result
-
-    work = costs.serving_work(config, dims, records, kv_dtype, trace_ab)
-    obs = {
-        "counters_start": snap0, "counters_end": snap1, "client": m,
-        "memory_peak_bytes": mem_peak or None,
-        "device_kind": device["kind"] if not tiny else None,
-        "peaks": peaks, "work": work,
-    }
-    common.traced_metrics(
-        cell, result, obs, trace_dir if trace_ab is not None else None,
-        STEP_SPAN, tiny, out_dir,
-        slot_occupancy_mean=(sum(occupancy) / len(occupancy)
-                             if occupancy else None), work=work)
     return result

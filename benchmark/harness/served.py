@@ -21,17 +21,31 @@ module is the cell's.
 
 from __future__ import annotations
 
+import time
 from typing import Any, Dict, List, Sequence
 
 import numpy as np
 
 SAMPLE_REQUESTS = 4
-# The reference compiles one program per sequence length, and a sample
-# drawn from the seed has new lengths in every run: sequences are padded
-# at their END to a multiple of PAD_TO (causal attention: no compared
-# position sees the padding), so that a checkout compiles a handful of
-# lengths once and every later run finds them in the cache.
+# The reference compiles one program per sequence length (a minute for
+# each in the long-context cell: my chip run, PR 38), and a sample drawn
+# from the seed has new lengths in every run. So every request of a
+# sample is padded at its END (causal attention: no compared position
+# sees the padding) to ONE length, ``pad_length`` of the longest request
+# the traffic can ask for, the same at every seed: a checkout compiles
+# the reference once, in its first run, and every later run finds it in
+# the cache.
 PAD_TO = 512
+
+
+def pad_length(longest: int) -> int:
+    """The length every request of a sample is padded to: ``longest``
+    rounded up to a quarter of its power-of-two ceiling (at least
+    ``PAD_TO``): coarse, so that it stays where it is when the longest
+    request grows or shrinks by a few hundred tokens, and at most a
+    third over it."""
+    quantum = max(PAD_TO, (1 << max(0, int(longest) - 1).bit_length()) // 4)
+    return -(-int(longest) // quantum) * quantum
 
 
 def pick_sample(records: List[Dict[str, Any]], seed: int,
@@ -57,43 +71,55 @@ def pick_sample(records: List[Dict[str, Any]], seed: int,
 
 def request_gaps(reference, canonical, arch: Dict[str, Any],
                  quant: Dict[str, Any], prompt: Sequence[int],
-                 tokens: Sequence[int]) -> np.ndarray:
+                 tokens: Sequence[int], padded: int = 0) -> np.ndarray:
     """For each served token, how far its reference logit lies below
     the reference's best at that position, in standard deviations of
-    the position's logits."""
+    the position's logits. The sequence is padded to ``padded`` (its own
+    ``pad_length`` where that is more)."""
     seq = [int(x) for x in prompt] + [int(x) for x in tokens[:-1]]
     n = len(tokens)
-    padded = -(-len(seq) // PAD_TO) * PAD_TO
-    # as many rows of logits as hold the served positions wherever the
-    # padding puts them: a whole number of PAD_TO, the same for every
-    # answer of up to PAD_TO tokens
-    first = max(0, padded - PAD_TO * (1 + -(-n // PAD_TO)))
+    padded = max(int(padded), pad_length(len(seq)))
+    # the head runs from a quarter of the padded length on, so that it
+    # has four shapes at most whatever the prompt's length
+    at = len(prompt) - 1
+    quarter = max(PAD_TO, padded // 4)
+    first = at // quarter * quarter
     lg = np.asarray(reference.all_logits(
         canonical, arch, quant, seq + [0] * (padded - len(seq)),
-        first=first), np.float64)
-    at = len(prompt) - 1 - first
-    lg = lg[at:at + n]
+        first=first)[at - first:at - first + n], np.float64)
     chosen = lg[np.arange(n), np.asarray(tokens, np.int64)]
     return (lg.max(axis=-1) - chosen) / np.maximum(lg.std(axis=-1), 1e-30)
 
 
 def compare(reference, canonical, arch: Dict[str, Any],
-            quant: Dict[str, Any], samples: List[Dict[str, Any]]
-            ) -> Dict[str, Any]:
+            quant: Dict[str, Any], samples: List[Dict[str, Any]],
+            longest: int = 0) -> Dict[str, Any]:
     """The numbers that decide, over ``samples`` (each with ``prompt``
     and ``tokens``): the widest gap of a first token (prefill), the
-    widest and the mean gap of the later ones (decode)."""
-    first, later = [], []
+    widest and the mean gap of the later ones (decode). ``longest`` is
+    the longest request the traffic can ask for, prompt and answer:
+    the one length comes from it, not from which requests a run
+    happened to finish (a traced run whose longest request ended past
+    the window compiled a second length: my chip run, PR 38)."""
+    first, later, seconds = [], [], []
+    lengths = [len(s["prompt"]) + len(s["tokens"]) for s in samples]
+    padded = pad_length(max(lengths + [int(longest), 1]))
     for s in samples:
+        t = time.monotonic()
         g = request_gaps(reference, canonical, arch, quant, s["prompt"],
-                         s["tokens"])
+                         s["tokens"], padded)
+        seconds.append(round(time.monotonic() - t, 3))
         first.append(float(g[0]))
         later.extend(float(x) for x in g[1:])
     out: Dict[str, Any] = {
         "requests": len(samples),
+        # what the reference's pass over each request cost, beside its
+        # length: the longest part of a long-context cell's run
+        "lengths": lengths,
+        "padded": padded,
+        "seconds": seconds,
         "served_tokens": len(first) + len(later),
-        "longest": max((len(s["prompt"]) + len(s["tokens"])
-                        for s in samples), default=0),
+        "longest": max(lengths, default=0),
         "reference_best_share": (
             sum(1 for x in first + later if x == 0.0)
             / max(1, len(first) + len(later))),

@@ -40,6 +40,8 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
     from bigdl_tpu.training import make_lora_train_step, partition
 
     watch = common.CompileWatch().install()
+    clock = common.WallClock(t_process)
+    clock.lap("devices_ready")
     config, traffic = cell.config, cell.traffic
     reference, weights, costs = (cell.modules[k] for k in (
         "reference", "weights", "costs"))
@@ -55,6 +57,10 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
         with_canonical=lambda canonical, cfg: ref_box.update(
             logits=np.asarray(reference.all_logits(
                 canonical, config["reference"], quant, sample))))
+    clock.lap("weights")
+    # the reference's pass over the sample ran inside ``build_model``
+    clock.move(build_stages.get("with_canonical_s", 0.0), "weights",
+               "check_a_reference")
     cfg = model.config
     params = attach_lora(model.params, LoraConfig(
         r=int(tcfg["lora_r"]), training_mode=tcfg["training_mode"]))
@@ -68,6 +74,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
     ref_loss = common.next_token_loss(ref_box["logits"], sample)
     prog_loss = common.next_token_loss(prog, sample)
     del prog, ref_box, fwd
+    clock.lap("check_a_program")
 
     train, frozen = partition(params, lora_trainable_mask(params))
     if tcfg["optimizer"] != "adamw":
@@ -111,6 +118,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
         losses.append(one_step())
     before = np.asarray(digest(planes)).tolist()
     c_setup = watch.snapshot()
+    clock.lap("warmup")
 
     t0 = time.monotonic()
     setup_s = t0 - t_process
@@ -141,6 +149,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
     w1 = watch.snapshot()
     after = np.asarray(digest(planes)).tolist()
     mem_peak = common.memory_peak_bytes()
+    clock.lap("window")
 
     jax_compiles = watch.programs_between(w0, w1)
     checks = {
@@ -154,24 +163,6 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
     }
     values = {"train_tokens_per_s": steps * tokens_per_step / window_s,
               "setup_s": setup_s}
-    common.note(
-        info="run", workload=cell.name, seed=seed, seconds=seconds,
-        window_s=window_s, steps=steps, tokens_per_step=tokens_per_step,
-        checks=checks, reference_rel_l2=rel, reference_tolerance=tol,
-        sample_loss={"program": prog_loss, "reference": ref_loss},
-        losses=[losses[0], losses[WARMUP_STEPS], losses[-1]],
-        train_tokens_per_s=values["train_tokens_per_s"],
-        setup={"setup_s": setup_s,
-               "build_stages": build_stages,
-               "backend_compiles": c_setup["backend_compiles"],
-               "cache_hits": c_setup["cache_hits"],
-               "backend_compile_s": c_setup["backend_seconds"]},
-        window_compiles={"jax": jax_compiles})
-
-    common.report_compared(
-        [("reference_rel_l2", rel, tol),
-         ("sample_loss_gap", abs(prog_loss - ref_loss),
-          SAMPLE_LOSS_LIMIT * abs(ref_loss))], checks)
     dev = dict(device)
     dev["memory_peak_bytes"] = mem_peak
     result: Dict[str, Any] = {
@@ -180,13 +171,37 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
     if not trace:
         result["metrics"] = common.select_end_to_end(
             cell, {} if tiny else values)
-        return result
-    obs = {"counters_start": None, "counters_end": None,
-           "memory_peak_bytes": mem_peak or None,
-           "device_kind": device["kind"] if not tiny else None,
-           "peaks": peaks,
-           "work": costs.training_work(config, dims, traffic,
-                                       tokens_per_step)}
-    common.traced_metrics(cell, result, obs, trace_dir if traced else None,
-                          STEP_SPAN, tiny, out_dir)
+    else:
+        obs = {"counters_start": None, "counters_end": None,
+               "memory_peak_bytes": mem_peak or None,
+               "device_kind": device["kind"] if not tiny else None,
+               "peaks": peaks,
+               "work": costs.training_work(config, dims, traffic,
+                                           tokens_per_step)}
+        common.traced_metrics(cell, result, obs,
+                              trace_dir if traced else None, STEP_SPAN,
+                              tiny, out_dir)
+        clock.lap("trace_reduction")
+
+    wall = clock.close()
+    common.note(
+        info="run", workload=cell.name, seed=seed, seconds=seconds,
+        window_s=window_s, steps=steps, tokens_per_step=tokens_per_step,
+        checks=checks, reference_rel_l2=rel, reference_tolerance=tol,
+        sample_loss={"program": prog_loss, "reference": ref_loss},
+        losses=[losses[0], losses[WARMUP_STEPS], losses[-1]],
+        train_tokens_per_s=values["train_tokens_per_s"],
+        wall_s=wall["wall_s"], budget_s=common.RUN_BUDGET_S,
+        phases=wall["phases"],
+        setup={"setup_s": setup_s,
+               "build_stages": build_stages,
+               "backend_compiles": c_setup["backend_compiles"],
+               "cache_hits": c_setup["cache_hits"],
+               "backend_compile_s": c_setup["backend_seconds"]},
+        window_compiles={"jax": jax_compiles})
+
+    result["compared"] = common.report_compared(
+        [("reference_rel_l2", rel, tol),
+         ("sample_loss_gap", abs(prog_loss - ref_loss),
+          SAMPLE_LOSS_LIMIT * abs(ref_loss))], checks)
     return result

@@ -133,7 +133,10 @@ def canonical_params(config: Dict[str, Any], seed: int, check: bool = True
         from harness import checks_deepseek_v2 as checks
 
         found = checks.layer_check(config, canonical, seed)
-        checks.report(found)
+        # rides the tree: ``harness/__init__.py``, "layer_check"
+        canonical["layer_check"] = {"seconds": found["seconds"],
+                                    "within": found["within"],
+                                    "compared": checks.report(found)}
         canonical["refused"] = not found["within"]
     return canonical
 

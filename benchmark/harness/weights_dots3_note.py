@@ -119,16 +119,21 @@ def canonical_params(config: Dict[str, Any], seed: int, check: bool = True
     """The canonical tree of ``seed`` alone, as the reference reads it.
     With ``check`` (the harness's call, once the window has closed) the
     program's blocks are first held to the reference's on that tree,
-    layer by layer (``checks_dots3_note``), every reading printed beside
-    its limit; a tree on which one is over comes back ``refused`` and
-    ``reference_dots3_note.all_logits`` vouches for nothing on it."""
+    layer by layer (``checks_dots3_note``). What it found rides the tree
+    under ``"layer_check"`` (``harness/__init__.py``: seconds, verdict,
+    each reading beside its limit, which the runner prints and holds
+    ``correct`` to); a tree on which one is over also comes back
+    ``refused`` and ``reference_dots3_note.all_logits`` vouches for
+    nothing on it."""
     _, cfg, _ = _family_config(config)
     canonical = build_params(cfg, config["quant"], seed)
     if check:
         from harness import checks_dots3_note as checks
 
         found = checks.layer_check(config, canonical, seed)
-        checks.report(found)
+        canonical["layer_check"] = {"seconds": found["seconds"],
+                                    "within": found["within"],
+                                    "compared": checks.report(found)}
         canonical["refused"] = not found["within"]
     return canonical
 

@@ -4,12 +4,13 @@ prefill chunk.
 Device seconds inside the prefill programs of the traced stretch (trace
 group ``prefill_programs``, the XLA Modules line) over the number of
 times they ran in it, x 1000. A chunk is one run of an ``engine_prefill*``
-program (256 rows in every cell: ``EngineConfig.prefill_chunk``), so this
-is the part of a chunk-carrying step that the chunk itself holds the chip
-for; the step's p95 gap is that plus the decode program and the host.
-A stretch without a prefill program reads nothing: the bursty cell's
-arrivals leave its stretch inside a gap at every seed, and the DeepSeek-V2
-cell's stretch often holds no chunk, so neither lists this metric.
+program (``EngineConfig.prefill_chunk`` rows: 256 in the dense cells, 1024
+in the sparse-latent cell), so this is the part of a chunk-carrying step
+that the chunk itself holds the chip for; the step's p95 gap is that plus
+the decode program and the host. A stretch without a prefill program reads
+nothing: the DeepSeek-V2 cell's stretch often holds no chunk, so it does
+not list this metric. The bursty cell lists it since PR 38 moved its
+stretch into a burst (``traffic/chat-bursty.json``, ``assumed.trace``).
 """
 
 LAYER = "model step"

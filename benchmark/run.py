@@ -6,7 +6,8 @@ Set-up (weights on the device from the seed, the program's engine and
 server, warm-up of every shape), a measured window of ``--seconds``,
 and as the LAST line of standard output one JSON object with
 ``correct``, ``attempted``, ``failed``, ``metrics`` and ``device`` (and
-``breakdown`` with ``--trace 1``). ``--trace 0`` gives the cell's
+``breakdown`` with ``--trace 1``; last of all ``compared``: every number
+``correct`` rests on beside its limit). ``--trace 0`` gives the cell's
 end-to-end metrics, ``--trace 1`` its per-layer metrics.
 
 Without a TPU, with fewer chips than the cell asks for, or on a device
@@ -22,7 +23,11 @@ A cell is files plus entries: ``configs/<config>.json``,
 configuration of another architecture also brings its own reference,
 weights and costs modules, which its file names under ``"harness"``
 (``harness/__init__.py`` has the contract of each). The last lines of
-standard error give every number ``correct`` compared beside its limit.
+standard error give every number ``correct`` compared beside its limit,
+and then the run's wall time, process start to the result line, beside
+the harness's budget (``common.RUN_BUDGET_S``): ``wall_s = <n> budget
+<b>: ok|OVER``. The note line ``"info": "run"`` says where it went
+(``phases``).
 """
 
 from __future__ import annotations
@@ -67,7 +72,7 @@ def main(argv=None) -> int:
                          "(the tests point it at a copy)")
     args = ap.parse_args(argv)
 
-    from harness import spec
+    from harness import common, spec
 
     try:
         cell = spec.Cell(args.workload, Path(args.root), tiny=args.tiny)
@@ -143,7 +148,10 @@ def main(argv=None) -> int:
                                    "metrics", "device")}
     if args.trace and result.get("breakdown"):
         line["breakdown"] = result["breakdown"]
+    # every number ``correct`` compared, beside its limit: the last key
+    line["compared"] = result.get("compared", {})
     print(json.dumps(line), flush=True)
+    common.report_wall(time.monotonic() - T_PROCESS)
     return 0
 
 

@@ -48,8 +48,36 @@ def test_benchmark_json_lists_the_metric_for_the_cells_with_chunks():
             if m["name"] == "prefill_chunk_device_ms"]
     assert (m["layer"], m["source"], m["unit"], m["better"], m["moves"]) == (
         "model step", "device_trace", "ms", "lower", "itl_p95_ms")
-    # not the bursty cell: its arrivals (the same at every seed) leave the
-    # traced stretch, 8-11 s, inside a gap from 0.04 s to 15.99 s
+    # the bursty cell since PR 38: its traced stretch, 16.5-19.5 s, lies
+    # inside the burst whose 19 requests are due from 15.99 s to 17.31 s at
+    # every seed (the old stretch, 8-11 s, lay in the gap from 0.04 s to
+    # 15.99 s and held no chunk); the sparse-latent cell's stretch holds 9
+    # chunks of 1024 rows. Not the DeepSeek-V2 cell: its stretch often
+    # holds none.
     assert m["workloads"] == [
         "mistral7b-chat-steady", "chatglm2-6b-docqa-shared",
-        "mistral7b-batch-closed"]
+        "mistral7b-batch-closed", "mistral7b-chat-bursty",
+        "dots3-ep8-longdoc-closed"]
+
+
+def test_the_bursty_stretch_lies_inside_a_burst_at_every_seed():
+    """The traced stretch of the bursty mix is chosen from its arrivals,
+    which the file's ``order_seed`` fixes: requests arrive just before
+    and inside it, and their prefill chunks, one a step, outlast its
+    first second whatever the run's seed."""
+    from harness import traffic
+
+    mix = json.loads((_paths.BENCH / "traffic" / "chat-bursty.json")
+                     .read_text())
+    start, length = mix["trace_start_s"], mix["trace_seconds"]
+    plans = [traffic.window_plan(mix, seed, 50.0, 32000)
+             for seed in (1, 2 ** 31 + 7)]
+    due = [[r["due"] for r in p["requests"]] for p in plans]
+    assert due[0] == due[1]                      # the file's, not the seed's
+    burst = [r for r in plans[0]["requests"]
+             if start - 1.0 <= r["due"] <= start + length]
+    assert len(burst) >= 12
+    assert sum(start <= r["due"] < start + length for r in burst) >= 6
+    chunks = sum(-(-r["prompt_len"] // 256) for r in burst)
+    assert chunks >= 30                          # seconds of chunk steps
+    assert start <= 0.4 * 50.0                   # serve_runner caps it

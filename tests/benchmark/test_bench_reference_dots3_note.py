@@ -116,6 +116,87 @@ def quarter():
                                             check=False)
 
 
+def test_the_checks_sizes_make_every_mechanism_bind():
+    """From the configuration's file: the rows the layer check prefills
+    outnumber ``index_topk`` plus one chunk (the selection drops
+    positions inside a chunk and in decode) and the ring (it wraps, and
+    the splice lands mid-ring); they are whole chunks; 8 decoded rows."""
+    doc = _doc()
+    for config in (doc, spec.deep_update(doc, doc["tiny"])):
+        eng, hf = config["engine"], config["hf_config"]
+        rows = checks.prefill_rows(int(eng["max_seq"]))
+        chunk = int(eng["prefill_chunk"])
+        ring = int(hf.get("window_ring") or
+                   -(-int(hf["sliding_window_size"]) // 128) * 128)
+        assert rows > int(hf["index_topk"]) + chunk
+        assert rows > ring and rows % ring != 0 or rows > 2 * ring
+        assert rows % chunk == 0 and rows + checks.DECODE_ROWS <= \
+            int(eng["max_seq"])
+        assert int(hf["sliding_window_size"]) < chunk
+    assert checks.DECODE_ROWS == 8
+    assert checks.prefill_rows(int(doc["engine"]["max_seq"])) == 4096
+    assert (int(doc["hf_config"]["index_topk"]),
+            int(doc["engine"]["prefill_chunk"])) == (2048, 1024)
+
+
+def _kinds(arch, layers):
+    """What makes a layer a kind: its attention, and whether its
+    feed-forward is dense or routed."""
+    return {(arch["layer_types"][i],
+             "dense" if i < arch["first_k_dense"] else "routed")
+            for i in layers}
+
+
+def test_the_checked_layers_hold_every_kind_of_the_cut():
+    """One whole period after layer 1, never every layer: the published
+    cut's 14 layers are checked in its first 6 (dense layer 0, layer 1,
+    three window layers with experts, the full layer that closes the
+    period), which hold every kind ``reference.layer_stack`` yields."""
+    doc = _doc()
+    arch = doc["reference"]
+    covered = checks.checked_layers(arch)
+    assert list(covered) == [0, 1, 2, 3, 4, 5] and arch["layers"] == 14
+    stack = [(i, kind) for i, kind, _, _ in reference.layer_stack(
+        {"layers": [None] * arch["layers"], "experts": {}}, arch)]
+    assert _kinds(arch, covered) == _kinds(arch, [i for i, _ in stack]) == {
+        ("full_attention", "dense"), ("full_attention", "routed"),
+        ("sliding_attention", "routed")}
+    # a full layer WITH its indexer and experts, past a window layer
+    assert arch["layer_types"][covered[-1]] == "full_attention"
+    assert arch["layer_types"][covered[-1] - 1] == "sliding_attention"
+    # no window layer: every layer is checked
+    assert list(checks.checked_layers(
+        {"layers": 3, "layer_types": ["full_attention"] * 3})) == [0, 1, 2]
+
+
+def test_the_stream_stops_at_the_last_layer_checked():
+    """A tiny cut of 10 layers (two periods and more): the check reads
+    the first six layers, every reading named as ``layer_limits`` names
+    it, and the reference is carried no further."""
+    config = _tiny(4)
+    types = config["reference"]["layer_types"] + [
+        "sliding_attention", "sliding_attention", "sliding_attention",
+        "full_attention"]
+    config["hf_config"].update(num_hidden_layers=10, layer_types=types)
+    config["reference"].update(layers=10, layer_types=types)
+    seed = 2 ** 31 + 9
+    canonical = weights.canonical_params(config, seed, check=False)
+    assert len(canonical["layers"]) == 10
+    out = checks.layer_check(config, canonical, seed)
+    assert out["checked_layers"] == [0, 1, 2, 3, 4, 5]
+    assert out["within"], out["found"]
+    assert set(out["found"]) == set(reference.layer_limits(config)) \
+        == set(reference.LAYER_LIMITS)
+    assert len(out["layers"]["ffn_decode"]) == 6
+    assert len(out["layers"]["window_attention_prefill"]) == 3
+    assert len(out["layers"]["index_overlap_min"]) == 3
+    assert out["seconds"] > 0
+    names = [c[0] for c in checks.report(out)]
+    assert len(names) == 10 and "index_overlap_min" in names
+    assert [c[3:] for c in checks.report(out)
+            if c[0] == "index_overlap_min"] == [("floor",)]
+
+
 def test_the_layer_check_passes_the_program_on_every_block(quarter):
     """128 rows in chunks of 32 into a private cache, the splice into a
     one-slot slab mid-ring, 8 decoded rows through the slab; index
@@ -167,6 +248,10 @@ def test_canonical_params_marks_the_tree_by_the_layer_check(quarter,
     seed = 2 ** 31 + 9
     passed = weights.canonical_params(config, seed)
     assert passed["refused"] is False
+    own = passed["layer_check"]
+    assert own["within"] is True and own["seconds"] > 0
+    assert [c[0].replace("layer_rel_l2.", "") for c in own["compared"]] \
+        == list(reference.layer_limits(config))
     ids = [3, 5, 7, 9, 11, 13, 15, 17]
     lg = np.asarray(reference.all_logits(passed, config["reference"], QUANT,
                                          ids, first=6))
@@ -176,6 +261,7 @@ def test_canonical_params_marks_the_tree_by_the_layer_check(quarter,
         sound(*a, **k), within=False))
     refused = weights.canonical_params(config, seed)
     assert refused["refused"] is True
+    assert refused["layer_check"]["within"] is False
     assert np.isnan(np.asarray(reference.all_logits(
         refused, config["reference"], QUANT, ids, first=6))).all()
 
@@ -229,6 +315,67 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
         layer["shared_down"]), np.float32)
     total = sum(g - shared_p for g in got) + shared_p
     assert reference.relative_l2(total, uncut) < 0.02
+
+
+def test_the_references_short_cuts_leave_out_only_products_that_are_zero(
+        monkeypatch):
+    """PR 38's three savings in the reference, each against the plain
+    form of the same function on the same input: an expert run on the
+    rows that chose it (capacity 640 of 1,536 rows; and 8, which every
+    expert outgrows, so that it takes every row) against every expert
+    on every row; a window layer's row blocks against the keys of their
+    window (1,024 of 1,536) against all keys; a full layer's rows, index
+    scores and selection in three causal runs (512, 1,024 and 1,536
+    keys) against one run over all keys."""
+    import jax
+    import jax.numpy as jnp
+
+    config = _tiny(4)
+    arch = config["reference"]
+    canonical = weights.canonical_params(config, 7, check=False)
+    x = jax.random.normal(jax.random.PRNGKey(3), (1536, arch["hidden"]),
+                          jnp.float32)
+    layer = canonical["layers"][2]
+    assert arch["layer_types"][2] == "sliding_attention"
+    ex = jax.tree.map(lambda a: a[1], canonical["experts"])
+    assert reference.expert_capacity(14336, _doc()["reference"]) == 896
+    assert reference.expert_capacity(40, _doc()["reference"]) == 40
+    with jax.default_matmul_precision("highest"):
+        plain = np.asarray(reference.feed_forward(x, layer, ex, arch, QUANT,
+                                                  capacity=1536))
+        chosen = jnp.sum(reference.route(
+            jax.nn.sigmoid(x @ layer["router"].astype(jnp.float32)),
+            layer["router_bias"], arch)[:, :4] > 0, axis=0)
+        assert 8 < int(chosen.min()) and int(chosen.max()) <= 640
+        for cap in (640, 8):
+            got = np.asarray(reference.feed_forward(x, layer, ex, arch,
+                                                    QUANT, capacity=cap))
+            assert reference.relative_l2(got, plain) < 1e-6, cap
+        banded = np.asarray(reference.attention(x, layer, arch, QUANT,
+                                                "sliding_attention"))
+        assert reference._causal_groups(29) == [(0, 7), (7, 14), (14, 22),
+                                                (22, 29)]
+        assert reference._causal_groups(3) == [(0, 1), (1, 2), (2, 3)]
+        full, runs = canonical["layers"][1], {}
+        for groups in (4, 1):
+            monkeypatch.setattr(reference, "CAUSAL_GROUPS", groups)
+            probe = {}
+            out = reference.attention(x, full, arch, QUANT, "full_attention",
+                                      probe=probe)
+            runs[groups] = [np.asarray(out)] + [
+                np.asarray(probe[k]) for k in ("index_scores", "selected")]
+        monkeypatch.setattr(reference, "ROW_BLOCK", 1000)   # one block
+        whole = np.asarray(reference.attention(x, layer, arch, QUANT,
+                                               "sliding_attention"))
+    assert reference.relative_l2(banded, whole) < 1e-6
+    assert np.abs(whole).max() > 0
+    assert reference.relative_l2(runs[4][0], runs[1][0]) < 1e-6
+    live = np.isfinite(runs[1][1])
+    assert (np.isfinite(runs[4][1]) == live).all()
+    assert np.allclose(runs[4][1][live], runs[1][1][live], rtol=1e-6,
+                       atol=1e-6)
+    assert (runs[4][2] != runs[1][2]).mean() < 1e-5    # a tie at most
+    assert runs[1][2].sum(axis=1).max() == arch["index"]["topk"] == 16
 
 
 def test_the_references_selection_is_the_top_and_its_window_counts_itself():

@@ -5,6 +5,7 @@ its own; no measured time is asserted."""
 
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -14,7 +15,41 @@ import pytest
 import _paths
 
 RUN_TIMEOUT_S = 420
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+             "compared"}
+WALL_LINE = re.compile(r"^wall_s = (\d+\.\d+) budget 300\.0: (ok|OVER)$")
+
+
+def _compared_lines(r, line=None):
+    """Standard error's lines before its last, which must be the run's
+    wall time beside the harness's budget; with ``line`` (the result)
+    also holds its ``compared`` key, the last there, to what standard
+    error printed."""
+    lines = r.stderr.strip().splitlines()
+    assert WALL_LINE.match(lines[-1]), lines[-1]
+    if line is not None:
+        assert list(line)[-1] == "compared"
+        printed = [x.split()[1] for x in lines if x.startswith("compared ")]
+        assert printed == list(line["compared"])
+        assert all(set(v) in ({"value", "limit"}, {"value", "floor"})
+                   for v in line["compared"].values())
+    return lines[:-1]
+
+
+def _wall(r, note):
+    """The note line's ``wall_s`` and ``phases``: every name of the
+    harness's list, seconds that add up to the wall time, which the
+    last line of standard error repeats a moment later."""
+    from harness import common
+
+    assert list(note["phases"]) == list(common.PHASES)
+    assert all(v >= 0.0 for v in note["phases"].values())
+    assert sum(note["phases"].values()) == pytest.approx(note["wall_s"])
+    assert note["budget_s"] == common.RUN_BUDGET_S == 300.0
+    said = float(WALL_LINE.match(
+        r.stderr.strip().splitlines()[-1]).group(1))
+    assert note["wall_s"] <= said < note["wall_s"] + 5.0
+    return note["phases"]
 
 
 def _run(root, *args, timeout=RUN_TIMEOUT_S, program=True):
@@ -127,6 +162,11 @@ def test_a_new_cell_is_added_by_files_and_entries_only(tmp_path):
     assert run_note["window_compiles"] == {"tracked": 0.0, "jax": 0}
     assert all(run_note["checks"].values())
     assert run_note["generator_late_ms"]["p99"] is not None
+    phases = _wall(r, run_note)
+    # the slab family: every serving phase but a layer check of its own
+    assert all(phases[k] > 0 for k in phases if k != "layer_check")
+    assert phases["layer_check"] == 0.0
+    _compared_lines(r, line)
 
 
 WIDE_MODULES = {
@@ -246,13 +286,21 @@ def test_tiny_run_of_the_paged_cell_compares_each_number_with_its_limit():
         <= note["reference_tolerance"]
     assert note["served"]["requests"] == 4
     assert note["served"]["longest"] >= 64      # a document and more
-    # the last lines of standard error: each number beside its limit
-    tail = r.stderr.strip().splitlines()[-6:]
+    # the last lines of standard error: each number beside its limit,
+    # then the wall time beside its budget
+    tail = _compared_lines(r, line)[-6:]
     assert tail[-1] == "checks failed: none"
     assert [x.split()[1] for x in tail[:-1]] == [
         "reference_rel_l2.prefill", "reference_rel_l2.decode",
         "prefill_gap_max", "decode_gap_max", "decode_gap_mean"]
     assert all(x.endswith(": ok") for x in tail[:-1])
+    phases = _wall(r, note)
+    # untraced: no trace to reduce; the paged family has no layer check
+    assert phases["trace_reduction"] == 0.0 == phases["layer_check"]
+    assert min(phases[k] for k in ("weights", "warmup", "window",
+                                   "check_a_program", "canonical_tree",
+                                   "check_a_reference",
+                                   "check_b_served")) > 0
 
 
 BROKEN_DRIVER = """
@@ -303,7 +351,7 @@ def test_a_run_whose_engine_emits_altered_tokens_is_not_correct(tmp_path):
     failed = sorted(k for k, ok in note["checks"].items() if not ok)
     assert failed == ["served_tokens_within_reference_gap"]
     assert note["served"]["decode_gap_max"] > 1.0
-    assert r.stderr.strip().splitlines()[-1] == (
+    assert _compared_lines(r)[-1] == (
         "checks failed: ['served_tokens_within_reference_gap']")
     assert any(x.startswith("compared decode_gap_max") and
                x.endswith("OVER") for x in r.stderr.splitlines())
@@ -322,6 +370,14 @@ def test_tiny_run_of_the_training_runner_prints_the_schema():
     note = json.loads(r.stdout.strip().splitlines()[-2])
     assert note["checks"]["frozen_base_bit_identical"]
     assert note["checks"]["loss_fell"]
+    phases = _wall(r, note)
+    # the training runner has no server, drain or served comparison
+    assert min(phases[k] for k in ("devices_ready", "weights",
+                                   "check_a_reference", "check_a_program",
+                                   "warmup", "window")) > 0
+    assert phases["drain"] == phases["check_b_served"] == 0.0
+    assert _compared_lines(r, line)[-1] == "checks failed: none"
+    assert list(line["compared"]) == ["reference_rel_l2", "sample_loss_gap"]
 
 
 def test_without_a_chip_it_refuses_to_measure():

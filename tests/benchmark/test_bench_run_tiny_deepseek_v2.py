@@ -6,7 +6,7 @@ only: a CPU run yields no time and no share of the device)."""
 import json
 
 import _paths
-from test_bench_run_tiny import LINE_KEYS, _run
+from test_bench_run_tiny import LINE_KEYS, _compared_lines, _run, _wall
 
 CELL = "deepseekv2-ep8-reason-closed"
 
@@ -37,6 +37,10 @@ def test_tiny_run_of_the_latent_expert_cell_is_correct():
     assert layer["within"] is True and len(layer["found"]) == 4
     assert sum(x.startswith("compared layer_rel_l2.") and x.endswith(": ok")
                for x in r.stderr.splitlines()) == 4
-    tail = r.stderr.strip().splitlines()[-6:]
+    tail = _compared_lines(r, line)[-10:]
     assert tail[-1] == "checks failed: none"
     assert all(x.endswith(": ok") for x in tail[:-1])
+    assert note["checks"]["configuration_layer_check"] is True
+    phases = _wall(r, note)
+    assert all(v > 0 for v in phases.values())
+    assert phases["layer_check"] == layer["seconds"]

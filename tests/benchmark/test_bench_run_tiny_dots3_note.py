@@ -6,7 +6,7 @@ only: a CPU run yields no time and no share of the device)."""
 import json
 
 import _paths
-from test_bench_run_tiny import LINE_KEYS, _run
+from test_bench_run_tiny import LINE_KEYS, _compared_lines, _run, _wall
 
 CELL = "dots3-ep8-longdoc-closed"
 
@@ -36,6 +36,17 @@ def test_tiny_run_of_the_sparse_latent_cell_is_correct():
     layer = [json.loads(x) for x in r.stdout.strip().splitlines()[:-1]
              if x.startswith("{") and '"info": "layer_check"' in x][0]
     assert layer["within"] is True and len(layer["found"]) == 10
-    assert sum(x.startswith("compared ") and x.endswith(": ok")
-               for x in r.stderr.splitlines()) == 15
-    assert r.stderr.strip().splitlines()[-1] == "checks failed: none"
+    assert layer["checked_layers"] == [0, 1, 2, 3, 4, 5]
+    assert note["checks"]["configuration_layer_check"] is True
+    # the layer check's ten numbers and the harness's five, together at
+    # the end of standard error and under the result line's last key
+    tail = _compared_lines(r, line)
+    assert tail[-1] == "checks failed: none"
+    assert all(x.startswith("compared ") and x.endswith(": ok")
+               for x in tail[-16:-1])
+    assert len(line["compared"]) == 15
+    assert line["compared"]["index_overlap_min"]["floor"] == 0.8
+    phases = _wall(r, note)
+    # every phase of a traced serving run, the family's own check too
+    assert all(v > 0 for v in phases.values())
+    assert phases["layer_check"] == layer["seconds"]

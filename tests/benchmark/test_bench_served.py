@@ -138,7 +138,7 @@ def test_what_the_engine_serves_lies_at_the_references_best(
     limits = built["reference"].served_gap_limits(built["config"],
                                                   built["kv"])
     assert found["requests"] == 4 and found["served_tokens"] == 48
-    assert found["longest"] == 132
+    assert found["longest"] == 132 and found["padded"] == 512
     assert set(limits) <= set(found)
     assert served.within(found, limits), (found, limits)
     assert found["reference_best_share"] >= 0.9
@@ -195,6 +195,37 @@ def test_padding_the_sequence_changes_no_compared_position(
             built["config"]["reference"], built["quant"], s["prompt"],
             s["tokens"])
     padded = served.request_gaps(*args)
+    # to the one length of a sample whose longest request is longer
+    assert np.allclose(served.request_gaps(*args, padded=1536), padded,
+                       atol=1e-4)
     monkeypatch.setattr(served, "PAD_TO", 1)
     assert np.allclose(served.request_gaps(*args), padded, atol=1e-4)
     assert padded.shape == (12,)
+
+
+def test_the_one_length_comes_from_the_traffics_longest_request(
+        monkeypatch):
+    """Not from which requests a run happened to finish: a sample whose
+    own longest is 11,917 tokens still goes to the 16,384 of the longest
+    request the traffic can ask for."""
+    seen = []
+    monkeypatch.setattr(
+        served, "request_gaps",
+        lambda *a: seen.append(a[-1]) or np.zeros(len(a[-2])))
+    samples = [{"prompt": [1] * n, "tokens": [2, 3]} for n in (40, 11915)]
+    assert served.compare(None, None, {}, {}, samples,
+                          longest=14336 + 640)["padded"] == 16384
+    assert served.compare(None, None, {}, {}, samples)["padded"] == 12288
+    assert seen == [16384, 16384, 12288, 12288]
+
+
+@pytest.mark.parametrize("longest,padded", [
+    (1, 512), (267, 512), (1660, 2048), (2048, 2048), (4992, 6144),
+    (7892, 8192), (14336 + 128, 16384), (14336 + 640, 16384)])
+def test_a_sample_is_padded_to_one_coarse_length(longest, padded):
+    """Every request of a sample goes to ``pad_length`` of the longest:
+    a quarter of its power-of-two ceiling, so the long-context cell's
+    longest request, 14,336 tokens and its answer, gives 16,384 whatever
+    the answer's length, and the reference compiles once a checkout."""
+    assert served.pad_length(longest) == padded
+    assert longest <= padded < longest * 4 / 3 + served.PAD_TO
