@@ -41,6 +41,18 @@ class FamilyAdapter:
         return getattr(sys.modules[self.forward.__module__], name, default)
 
     @property
+    def rewindable(self) -> bool:
+        """Whether the cache can hold pad tokens past a prompt and be
+        rewound to an earlier position: what the Generator's padding and
+        both kinds of speculation ask. Not a recurrent family (absorbed
+        state), and not one whose forward module says `CACHE_REWINDABLE
+        = False` (a cache that REDUCES positions as it grows: chunked
+        linearized attention). The serving engine batches the latter
+        like any KV family and refuses only `is_recurrent`."""
+        return not self.is_recurrent and bool(
+            self._forward_module("CACHE_REWINDABLE", True))
+
+    @property
     def SUPPORTS_SCALED_KV(self) -> bool:  # noqa: N802 — module's name
         return bool(self._forward_module("SUPPORTS_SCALED_KV", False))
 
@@ -173,6 +185,23 @@ def _register_builtin() -> None:
             prefill=dots3_mod.forward_last_token,
             forward_train=None,
             new_cache=dots3_mod.new_cache,
+        ))
+
+    from bigdl_tpu.models import evabyte as evabyte_mod
+
+    # chunked linearized attention: K/V planes of one window beside a
+    # summary plane of one column a chunk; slab only, bf16 planes only,
+    # eight prediction heads of which head 0 is sampled
+    register_family(
+        ["EvaByteForCausalLM"],
+        FamilyAdapter(
+            name="evabyte",
+            config_from_hf=evabyte_mod.EvaByteConfig.from_hf,
+            convert_params=evabyte_mod.convert_hf_params,
+            forward=evabyte_mod.forward,
+            prefill=evabyte_mod.forward_last_token,
+            forward_train=None,
+            new_cache=evabyte_mod.new_cache,
         ))
 
     from bigdl_tpu.models import rwkv as rwkv_mod

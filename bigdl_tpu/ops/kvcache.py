@@ -54,6 +54,20 @@ snapshot of it is the state at the length it was taken and at no shorter
 one (`seeded` refuses it; the engine's host prefix cache refuses such a
 spec).
 
+A plane may also hold a REDUCTION of positions, one column every `stride`
+of them (`models/evabyte.py`: `sum_k` / `sum_v` `[L, B, S / 16, H, hd]`,
+the learned summary of each 16-position chunk), beside K/V planes of ONE
+window (`win_k` / `win_v` `[L, B, 2048, H, hd]`, `PlaneSpec.window`):
+position `p` lives in column `p % window`, the plane is refilled from
+column 0 at every multiple of the window and only the columns `0 .. p %
+window` are live, so a chunk's right padding inside the window lands on
+columns nobody reads and a private prefill cache keeps the slab's
+geometry (`unrolled` leaves such a plane alone). A cache of such planes
+is the state at the length it was filled to and is valid as a prefix at
+multiples of the window only: `seeded` and the host prefix cache refuse
+it (`CacheSpec.has_strided`). `KVCache.stride` (static) is the stride of
+its strided planes, which is how `max_seq` is read off them.
+
 Layout: [num_layers, batch, max_seq, kv_heads, head_dim] — the whole stack is
 one array per K/V so a `lax.scan` over layers can carry it. In place means
 addressed on the stack: `update_layer` writes its rows at `[layer, ...]` and
@@ -140,10 +154,15 @@ def kv_dtype_name(storage_dtype) -> str:
 
 # planes a cache may hold, in the order `planes()` lists them; every one
 # is [L, B, ...] and its positions run along `plane_seq_axis(name)`
-PLANE_NAMES = ("k", "v", "k_scale", "v_scale", "latent", "index", "window")
+PLANE_NAMES = ("k", "v", "k_scale", "v_scale", "latent", "index", "window",
+               "sum_k", "sum_v", "win_k", "win_v")
 _SEQ_AXIS = {"latent": 3, "index": 3, "window": 3}
 # planes that are rings (module docstring)
 RING_PLANES = ("window",)
+# planes with one column every `stride` positions
+STRIDED_PLANES = ("sum_k", "sum_v")
+# K/V planes of one window, refilled from column 0
+WINDOW_PLANES = ("win_k", "win_v")
 # the one storage type a latent plane takes (a quantized latent reads
 # noise at real widths: PERF.md 7, 17)
 LATENT_KV_DTYPES = ("bf16",)
@@ -160,14 +179,18 @@ class PlaneSpec:
     it stacks, what one position holds (`(kv_heads, head_dim)` of K or
     V, `(kv_heads,)` of a scale plane, `(width,)` of a plane that keeps
     its positions in the lanes) and, for a ring, how many positions it
-    keeps (0: the cache's full length)."""
+    keeps (0: the cache's full length). `window`: the plane keeps one
+    window of that many positions, refilled from column 0 (module
+    docstring); `stride`: one column every so many positions."""
     name: str
     layers: int
     dims: Tuple[int, ...]
     ring: int = 0
+    window: int = 0
+    stride: int = 1
 
     def shape(self, batch: int, max_seq: int) -> Tuple[int, ...]:
-        n = self.ring or max_seq
+        n = self.ring or self.window or -(-max_seq // self.stride)
         if plane_seq_axis(self.name) == 3:
             return (self.layers, batch) + self.dims + (n,)
         return (self.layers, batch, n) + self.dims
@@ -200,6 +223,9 @@ class CacheSpec:
             reject_non_bf16_latent(kv_cache_dtype)
             return self.planes or (
                 PlaneSpec("latent", self.num_layers, (self.latent_dim,)),)
+        if self.planes:
+            reject_non_bf16_strided(kv_cache_dtype)
+            return self.planes
         kv = (self.kv_heads, self.head_dim)
         out = (PlaneSpec("k", self.num_layers, kv),
                PlaneSpec("v", self.num_layers, kv))
@@ -211,6 +237,16 @@ class CacheSpec:
     @property
     def has_ring(self) -> bool:
         return any(p.ring for p in self.planes)
+
+    @property
+    def stride(self) -> int:
+        """Positions one column of the strided planes reduces (1: the
+        spec has none)."""
+        return max([p.stride for p in self.planes], default=1)
+
+    @property
+    def has_strided(self) -> bool:
+        return self.stride > 1
 
     def unrolled(self) -> "CacheSpec":
         """This spec with every ring at the cache's full length, its
@@ -239,6 +275,14 @@ class CacheSpec:
         return 2 * self.kv_heads * self.head_dim
 
 
+SNAPSHOT_REFUSAL = (
+    "a cache with a strided plane (one column a chunk of positions, beside "
+    "K/V planes of one window) cannot start from a prefix snapshot: it is "
+    "the state at the length it was filled to and a valid prefix at "
+    "multiples of the window only (prefix reuse at window boundaries is "
+    "not built)")
+
+
 def cache_spec_of(family, cfg) -> CacheSpec:
     """The `CacheSpec` of `family` for `cfg`."""
     fn = getattr(family, "cache_spec", None)
@@ -261,6 +305,21 @@ def reject_non_bf16_latent(spec) -> str:
     return name
 
 
+def reject_non_bf16_strided(spec) -> str:
+    """A cache of window and summary planes is bf16 only; says so
+    instead of storing codes no kernel of the family reads."""
+    name = resolve_kv_cache_dtype(spec)
+    if name != "bf16":
+        raise NotImplementedError(
+            f"kv_cache_dtype {name!r} is not supported for a cache of "
+            f"window and summary planes (chunked linearized attention): "
+            f"they are stored in bf16 only (a summary is a softmax-weighted "
+            f"mean of 16 keys, and an fp8 or int8 plane fails the layer "
+            f"check, PERF.md 6 PR 39; a quantized window or summary plane "
+            f"is a later issue)")
+    return name
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class KVCache:
@@ -280,14 +339,23 @@ class KVCache:
     index: Optional[jax.Array] = None     # [L, B, index_dim, S_max]
     # the window layers' latent rows, a ring (module docstring)
     window: Optional[jax.Array] = None    # [Lw, B, window_dim, ring]
+    # chunked linearized attention: one learned summary a chunk of
+    # `stride` positions, and the exact K/V of the current window
+    sum_k: Optional[jax.Array] = None     # [L, B, S_max / stride, H, D]
+    sum_v: Optional[jax.Array] = None
+    win_k: Optional[jax.Array] = None     # [L, B, window, H, D]
+    win_v: Optional[jax.Array] = None
+    # static: positions a column of the strided planes reduces
+    stride: int = 1
 
     def tree_flatten(self):
         return (self.k, self.v, self.pos, self.k_scale, self.v_scale,
-                self.latent, self.stats, self.index, self.window), None
+                self.latent, self.stats, self.index, self.window,
+                self.sum_k, self.sum_v, self.win_k, self.win_v), self.stride
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children)
+        return cls(*children, stride=aux or 1)
 
     def planes(self) -> Dict[str, jax.Array]:
         """The planes this cache holds, by name (`PLANE_NAMES` order)."""
@@ -305,7 +373,8 @@ class KVCache:
     @property
     def max_seq(self) -> int:
         name, plane = self._first
-        return plane.shape[plane_seq_axis(name)]
+        n = plane.shape[plane_seq_axis(name)]
+        return n * self.stride if name in STRIDED_PLANES else n
 
     @property
     def num_layers(self) -> int:
@@ -324,13 +393,19 @@ class KVCache:
     def seq_slices(self, length: int, row=None) -> Tuple[jax.Array, ...]:
         """Every plane cut to its first `length` positions (and to batch
         row `row`, kept as an axis of 1), in `planes()` order: what the
-        prefix cache, export and migration move. A ring is never cut:
-        it comes whole, and is the state at this cache's own `pos`."""
+        prefix cache, export and migration move. A ring or a window
+        plane is never cut: it comes whole, and is the state at this
+        cache's own `pos`; a strided plane is cut to the columns of
+        `length` positions."""
         out = []
         for name, p in self.planes().items():
             ax = plane_seq_axis(name)
-            if name not in RING_PLANES:
-                p = jax.lax.slice_in_dim(p, 0, min(length, p.shape[ax]),
+            if name in STRIDED_PLANES:
+                length_ = -(-length // self.stride)
+            else:
+                length_ = length
+            if name not in RING_PLANES + WINDOW_PLANES:
+                p = jax.lax.slice_in_dim(p, 0, min(length_, p.shape[ax]),
                                          axis=ax)
             if row is not None:
                 p = jax.lax.slice_in_dim(p, row, row + 1, axis=1)
@@ -343,6 +418,8 @@ class KVCache:
         and `pos = consumed`: an admission that starts from a snapshot."""
         import numpy as np
 
+        if any(n in STRIDED_PLANES for n in self.planes()):
+            raise NotImplementedError(SNAPSHOT_REFUSAL)
         if any(n in RING_PLANES for n in self.planes()):
             raise NotImplementedError(
                 "a cache with a ring plane cannot start from a prefix "
@@ -431,6 +508,8 @@ def init_cache_spec(spec: CacheSpec, batch: int, max_seq: int,
         p.name: jnp.zeros(p.shape(batch, max_seq),
                           jnp.float32 if p.name.endswith("_scale") else dt)
         for p in spec.plane_specs(name)}
+    if spec.has_strided:
+        planes["stride"] = spec.stride
     return KVCache(
         k=planes.pop("k", None), v=planes.pop("v", None),
         pos=(jnp.zeros((batch,), jnp.int32) if per_slot_pos
@@ -664,16 +743,21 @@ def publish_kv_cache_bytes(cache: KVCache, registry=None) -> Dict[str, int]:
         g = registry.gauge(
             "bigdl_tpu_kv_cache_bytes",
             "KV cache storage bytes by dtype and component "
-            "(codes | scales | total, and latent | index | window for "
-            "such a plane); int4 counted at two codes per byte",
+            "(codes | scales | total, and latent | index | window | "
+            "window_kv | summary for such planes); int4 counted at two "
+            "codes per byte",
             labelnames=("dtype", "component"))
         for comp, val in sizes.items():
             g.labels(cache.kv_dtype, comp).set(float(val))
-        for comp in ("latent", "index", "window"):
-            plane = getattr(cache, comp)
-            if plane is not None:
+        for comp, names in (("latent", ("latent",)), ("index", ("index",)),
+                            ("window", ("window",)),
+                            ("window_kv", ("win_k", "win_v")),
+                            ("summary", ("sum_k", "sum_v"))):
+            held = [getattr(cache, n) for n in names
+                    if getattr(cache, n) is not None]
+            if held:
                 g.labels(cache.kv_dtype, comp).set(
-                    float(_logical_nbytes(plane)))
+                    float(sum(_logical_nbytes(p) for p in held)))
     except Exception:
         pass
     return sizes

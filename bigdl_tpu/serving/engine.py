@@ -71,9 +71,10 @@ from bigdl_tpu.observability.slo import SLOTracker
 from bigdl_tpu.observability.stats import ewma as stats_ewma
 from bigdl_tpu.observability.tracing import PhaseClock, RequestTracer
 from bigdl_tpu.observability.usage import UsageLedger
-from bigdl_tpu.ops.kvcache import (KVCache, cache_nbytes, cache_spec_of,
-                                   init_cache_spec, kv_cache_bytes,
-                                   publish_kv_cache_bytes,
+from bigdl_tpu.ops.eva import rows_read
+from bigdl_tpu.ops.kvcache import (SNAPSHOT_REFUSAL, KVCache, cache_nbytes,
+                                   cache_spec_of, init_cache_spec,
+                                   kv_cache_bytes, publish_kv_cache_bytes,
                                    resolve_kv_cache_dtype)
 from bigdl_tpu.ops.pallas.decode_attention import blocks_read, slab_blocks
 from bigdl_tpu.ops.paged import (NULL_PAGE, PagedKVCache, cow_copy_pages,
@@ -517,6 +518,13 @@ class LLMEngine:
                 "layer's last positions), and a snapshot of a ring is the "
                 "state at the length it was taken, not at a shorter "
                 "prefix; serve it with prefix_cache_entries=0")
+        if (self._cache_spec.has_strided
+                and self.cfg_engine.prefix_cache_entries > 0):
+            raise ValueError(
+                f"prefix_cache_entries="
+                f"{self.cfg_engine.prefix_cache_entries}: the "
+                f"{self.family.name!r} family: {SNAPSHOT_REFUSAL}; serve "
+                "it with prefix_cache_entries=0")
         self.eos_token_id = None
         hf = getattr(model, "hf_config", None) or {}
         eos = hf.get("eos_token_id")
@@ -1052,6 +1060,20 @@ class LLMEngine:
                 "those a query could attend (its own counted), "
                 "kind=selected those its selection keeps (at most "
                 "index_topk a query and layer).", labelnames=("kind",))
+        # a family of chunked linearized attention (window and summary
+        # planes): (window, positions a summary column reduces); else None
+        self._eva = None
+        if not self._paged and self.cache.sum_k is not None:
+            self._eva = (int(self.cache.win_k.shape[2]),
+                         int(self.cache.stride))
+            self._m_eva_rows = m.counter(
+                "bigdl_tpu_eva_rows_total",
+                "Rows a query of chunked linearized attention reads in a "
+                "decode step, one layer's, all live slots: kind=window "
+                "the exact keys of its own window up to itself, "
+                "kind=summary one row a chunk of every earlier window, "
+                "kind=context the positions full attention would read.",
+                labelnames=("kind",))
         self._m_prefill_chunks = m.counter(
             "bigdl_tpu_prefill_chunks_total",
             "Prefill chunks dispatched by admission (at most one per "
@@ -4155,6 +4177,17 @@ class LLMEngine:
             self._m_dsa_positions.labels("live").inc(n_full * sum(held))
             self._m_dsa_positions.labels("selected").inc(
                 n_full * sum(min(d, topk) for d in held))
+        if self._eva is not None:
+            # what the attention kernel reads this step, by its own rule
+            # from the positions the host already knows (outside the
+            # phases)
+            window, stride = self._eva
+            rows = rows_read(
+                [len(self.slots[i].req.prompt_token_ids)
+                 + len(self.slots[i].generated) - 1 for i in active],
+                window, stride)
+            for kd, n in rows.items():
+                self._m_eva_rows.labels(kd).inc(n)
         toks = None
         finite_host = None
         toks_dev = finite_dev = qrows_dev = logits_dev = None

@@ -148,7 +148,7 @@ class TpuCausalLM:
                 max_seq=self.max_seq,
                 kv_cache_dtype=self.kv_cache_dtype,
                 new_cache_fn=self.family.new_cache,
-                recurrent=self.family.is_recurrent,
+                recurrent=not self.family.rewindable,
             )
         return self._generator
 
@@ -191,7 +191,7 @@ class TpuCausalLM:
         # draft model, exact greedy output (beyond the reference)
         if (prompt_lookup and ids.shape[0] == 1 and visual is None
                 and num_beams <= 1 and not do_sample
-                and not self.family.is_recurrent):
+                and self.family.rewindable):
             from bigdl_tpu.speculative import prompt_lookup_generate
 
             new = prompt_lookup_generate(
@@ -593,11 +593,15 @@ class _BaseAutoModelClass:
         if speculative:
             # self-speculation: same checkpoint as a sym_int4 draft
             # (reference model.py:323-331)
-            if family.is_recurrent:
+            if not family.rewindable:
                 raise ValueError(
-                    "speculative=True is not supported for recurrent "
-                    "(RWKV-style) families: verification rollback rewinds "
-                    "a KV cache, and recurrent state cannot be rewound")
+                    f"speculative=True is not supported for the "
+                    f"{family.name!r} family: verification rollback rewinds "
+                    "a KV cache, and recurrent (RWKV-style) state or a "
+                    "cache that reduces positions as it grows (one summary "
+                    "a chunk beside one window of exact keys) cannot be "
+                    "rewound; drafting several tokens a step from "
+                    "prediction heads is not built")
             if cvt_qtype == "sym_int4":
                 # already low-bit: share the (possibly MXU-relayouted)
                 # tree — the draft decode is the latency-critical loop

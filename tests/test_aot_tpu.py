@@ -1254,3 +1254,96 @@ def test_dots3_note_engine_programs_compile_and_fit(v5e, aot_flags):
         r"= \w+\[(?:\d+,)?8,(?:576|128|1088),(?:16384|640)\]\S* "
         r"(?:copy|fusion|dynamic-slice)\(", txt)
     assert not moved, f"a layer of a cache plane is materialized: {moved}"
+
+
+@pytest.mark.parametrize("kernel", ["eva_decode_attention", "eva_summarize"])
+def test_evabyte_decode_kernels_compile_at_published_widths(v5e, aot_flags,
+                                                            kernel):
+    """The two kernels of chunked linearized attention on the stacks
+    where they lie (32 heads of 128, a window of 2048, 512 summary
+    columns, 6 slots): Mosaic takes them, and the summary stacks are
+    rewritten in place."""
+    from bigdl_tpu.ops.pallas import eva_attention as K
+
+    dev = v5e.devices[0]
+    layers, b, w, ns, h, hd = 2, 6, 2048, 512, 32, 128
+
+    def sd(shape, dt=jnp.bfloat16):
+        return _sds(jax.ShapeDtypeStruct(shape, dt), dev)
+
+    win, summ = sd((layers, b, w, h, hd)), sd((layers, b, ns, h, hd))
+    pos = sd((b,), jnp.int32)
+    if kernel == "eva_decode_attention":
+        comp = _compile(
+            lambda q, wk, wv, sk, sv, p: K.eva_decode_attention_pallas(
+                q, wk, wv, sk, sv, p, scale=hd ** -0.5, stride=16, layer=1),
+            sd((b, 1, h, hd)), win, win, summ, summ, pos)
+    else:
+        vec = sd((h, hd), jnp.float32)
+        comp = jax.jit(
+            lambda wk, wv, sk, sv, p, phi, mu: K.eva_summarize_pallas(
+                wk, wv, sk, sv, p, phi, mu, scale=hd ** -0.5, stride=16,
+                layer=1), donate_argnums=(2, 3)).lower(
+            win, win, summ, summ, pos, vec, vec).compile()
+        assert comp.memory_analysis().temp_size_in_bytes < 2 ** 20
+    assert _has_mosaic_call(comp) and kernel in comp.as_text()
+
+
+def test_evabyte_engine_decode_step_compiles_and_fits(v5e, aot_flags):
+    """The engine's resident decode step for the cell's configuration (32
+    layers at published widths, 6 slots x 8192, shapes only): both
+    kernels are in it, no instruction copies a plane, and arguments plus
+    temporaries are the weights and the slab (3.65 + 8.05 GB), under
+    the chip's 16 with the private prefill cache (1.34 GB) beside."""
+    import json
+    import re
+    import sys
+    from pathlib import Path
+
+    bench = Path(__file__).resolve().parents[1] / "benchmark"
+    sys.path[:0] = [str(bench)]
+    from harness import weights_evabyte as weights
+    from harness.weights import _family_config
+
+    from bigdl_tpu.models import evabyte
+    from bigdl_tpu.ops.kvcache import cache_nbytes
+    from bigdl_tpu.ops.quant import prepack_tree
+    from bigdl_tpu.serving import EngineConfig, LLMEngine
+
+    doc = json.loads((bench / "configs" / "evabyte-int4.json").read_text())
+    family, cfg, hf = _family_config(doc)
+
+    class Model:
+        params = jax.eval_shape(lambda: prepack_tree(
+            evabyte.prepare_params(
+                weights.build_params(cfg, "sym_int4", 1), cfg), "on")[0])
+        config, hf_config, qtype = cfg, hf, "sym_int4"
+
+    Model.family = family
+    dev = v5e.devices[0]
+    b = doc["engine"]["max_batch"]
+    eng = LLMEngine(Model, EngineConfig(
+        max_batch=b, max_seq=doc["engine"]["max_seq"],
+        prefill_chunk=doc["engine"]["prefill_chunk"], sentinel=False,
+        quality=False))
+    assert cache_nbytes(eng._cache_spec, b, 8192)["total"] == 8_053_063_680
+    assert eng._admission_cost(6144) == 32 * (2048 + 512) * 16384
+    i32 = _sds(jax.ShapeDtypeStruct((b,), jnp.int32), dev)
+    f32 = _sds(jax.ShapeDtypeStruct((b,), jnp.float32), dev)
+    comp = eng._decode_resident.lower(
+        _sds(eng.params, dev), i32,
+        _sds(jax.eval_shape(lambda: eng.cache), dev),
+        f32, i32, f32, i32, i32, all_greedy=True,
+        with_quality=False).compile()
+    txt = comp.as_text()
+    for name in ("eva_decode_attention", "eva_summarize"):
+        assert name in txt, name
+    ma = comp.memory_analysis()
+    live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert 11.5e9 < live < 12.2e9, live / 1e9
+    assert ma.temp_size_in_bytes < 64 * 2 ** 20
+    moved = re.findall(
+        r"= \w+\[(?:\d+,)?6,(?:2048|512),32,128\]\S* "
+        r"(?:copy|dynamic-slice)\(", txt)
+    assert not moved, f"a plane of the cache is materialized: {moved}"

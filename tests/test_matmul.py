@@ -135,6 +135,17 @@ _PARENT_CHOSE = {
     (5120, 12288): (('gemv_mxu', (5120, 256)), ('gemm', (5120, 256))),
     (12288, 5120): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
 }
+# EvaByte's linears (PR 39; merged q/k/v and gate/up, the down
+# projection, the eight prediction heads' columns): [K, N] -> the plan at
+# 6 rows (its cell's decode step) and at 256 and 1024 (its cell's prefill
+# chunk, and the largest the GEMM takes). Every one takes a kernel;
+# K = 11008 = 2^8 x 43 tiles only in 256-row K blocks.
+_EVABYTE_CHOSE = {
+    (4096, 12288): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
+    (4096, 22016): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
+    (11008, 4096): (('gemv_mxu', (256, 512)), ('gemm', (256, 512))),
+    (4096, 2560): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
+}
 _TPU = dict(int4_layout=True, spmd=False, tpu=True)
 _CANON = dict(_TPU, int4_layout=False)
 # the rules, one case each: (qtype, rows, K, N, what the call sees, plan)
@@ -188,7 +199,10 @@ _RULES = [
 @pytest.mark.parametrize("qtype,rows,k,n,sees,want", [
     ("sym_int4", rows, k, n, _TPU, plan)
     for (k, n), at in _PARENT_CHOSE.items()
-    for rows, plan in zip((8, 256, 8192), (*at, ("xla", None)))] + _RULES)
+    for rows, plan in zip((8, 256, 8192), (*at, ("xla", None)))] + [
+    ("sym_int4", rows, k, n, _TPU, plan)
+    for (k, n), at in _EVABYTE_CHOSE.items()
+    for rows, plan in zip((6, 256, 1024), (*at, at[1]))] + _RULES)
 def test_kernel_selection_table(qtype, rows, k, n, sees, want):
     """`select_matmul` is the one place a quantized linear's plan is
     chosen, from what the call can see; at every linear of the three
