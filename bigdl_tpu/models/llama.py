@@ -576,9 +576,9 @@ def _attn_block(hidden, lp, cfg: LlamaConfig, cos, sin, slopes,
     """QKV + rope + (cached) attention + output projection.
 
     With ``block_tables`` the cache planes in ``cache_ctx`` are page
-    ARENAS (``[L, P, ps, Hkv, D]``): appends scatter through the table
-    and attention reads via `sdp_attention_paged` (fused gather on TPU,
-    XLA take fallback elsewhere)."""
+    ARENAS (the layout of `ops/paged.py`): appends scatter through the
+    table and attention reads via `sdp_attention_paged` (fused gather on
+    TPU, XLA take fallback elsewhere), both on the whole stack."""
     b, sq, _ = hidden.shape
     h, hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.hd
     scale = (cfg.query_pre_attn_scalar ** -0.5
@@ -634,16 +634,11 @@ def _attn_block(hidden, lp, cfg: LlamaConfig, cos, sin, slopes,
             attn = sdp_attention(q, k, v, jnp.zeros((), jnp.int32),
                                  **attn_kw)
         elif block_tables is not None:
-            kl = lax.dynamic_index_in_dim(ck, clidx, 0, keepdims=False)
-            vl = lax.dynamic_index_in_dim(cv, clidx, 0, keepdims=False)
-            if cks is not None:
-                attn_kw.update(
-                    k_scale=lax.dynamic_index_in_dim(cks, clidx, 0,
-                                                     keepdims=False),
-                    v_scale=lax.dynamic_index_in_dim(cvs, clidx, 0,
-                                                     keepdims=False))
-            attn = sdp_attention_paged(q, kl, vl, block_tables, pos,
-                                       **attn_kw)
+            # the arena's stacks and the layer index, as the slab branch:
+            # the block-table kernel reads the layer where it lies
+            attn = sdp_attention_paged(q, ck, cv, block_tables, pos, hkv,
+                                       k_scale=cks, v_scale=cvs,
+                                       layer=clidx, **attn_kw)
         else:
             # the stack and the layer index, not a slice: decode attention
             # reads the layer where it lies (raw codes + scale planes for
@@ -823,7 +818,8 @@ def forward_paged(
         x = x[:, -1:, :]
     x = _norm(x, params["norm"], params.get("norm_bias"), cfg)
     logits = _lm_head(x, params, cfg)
-    return logits, PagedKVCache(ck, cv, pos + sq, cks, cvs)
+    return logits, PagedKVCache(ck, cv, pos + sq, cks, cvs,
+                                cache.kv_heads)
 
 
 def ext_attn_layer(x, lp, cfg: LlamaConfig, cos, sin, attn_fn):

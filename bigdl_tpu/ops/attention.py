@@ -77,15 +77,19 @@ def _kernel_compiles(kind: str, h: int, hkv: int, hd: int, sq: int,
         # paged probe overloads the key slots: sq carries page_size,
         # skv carries the block-table width (logical pages)
         ps, np_ = sq, skv
-        arena = jax.ShapeDtypeStruct((np_ + 1, ps, hkv, hd), kdt)
+        from bigdl_tpu.ops.kvcache import kv_dtype_name as canonical
+        from bigdl_tpu.ops.paged import init_paged_cache
+
+        arena = jax.eval_shape(lambda: init_paged_cache(
+            1, np_ + 1, ps, hkv, hd, 1, kv_cache_dtype=canonical(kdt)))
         structs = [jax.ShapeDtypeStruct((1, 1, h, hd), jnp.bfloat16),
-                   arena, arena,
+                   arena.k, arena.v,
                    jax.ShapeDtypeStruct((1, np_), i32),
                    jax.ShapeDtypeStruct((1,), i32)]
-        sc = jax.ShapeDtypeStruct((np_ + 1, ps, hkv), f32)
+        sc = arena.k_scale
 
         def fn(q_, k_, v_, b_, p_, ks=None, vs=None):
-            return kernel(q_, k_, v_, b_, p_, hd ** -0.5,
+            return kernel(q_, k_, v_, b_, p_, hd ** -0.5, hkv,
                           k_scale=ks, v_scale=vs)
     else:
         # the decode kernel takes the [L, B, S, Hkv, hd] stack; prefill
@@ -282,32 +286,39 @@ def sdp_attention(
 
 def sdp_attention_paged(
     q: jax.Array,             # [B, Sq, H, D] (post-RoPE)
-    arena_k: jax.Array,       # [P, ps, Hkv, D] one layer's page arena
-    arena_v: jax.Array,
+    arena_k: jax.Array,       # [L*Hkv, P, ps, D] the cache's whole stack
+    arena_v: jax.Array,       # (4-bit codes: [L*Hkv, P, ps/8, 8, D])
     block_tables: jax.Array,  # [B, NP] int32 (0 = null page)
     q_pos: jax.Array,         # [B] int32 per-slot positions
+    kv_heads: int,            # Hkv: a layer is that many planes of the stack
     scale: Optional[float] = None,
     logits_soft_cap: Optional[float] = None,
     sliding_window: Optional[int] = None,
     alibi_slopes: Optional[jax.Array] = None,
     backend: Optional[str] = None,
-    k_scale: Optional[jax.Array] = None,   # [P, ps, Hkv] f32 arena scales
+    k_scale: Optional[jax.Array] = None,   # [L, P, Hkv, ps] f32 scales
     v_scale: Optional[jax.Array] = None,
+    layer=0,                  # int32 scalar: attend this layer of the stack
 ) -> jax.Array:
-    """Causal SDP reading K/V through a block table (paged cache).
+    """Causal SDP reading K/V through a block table (paged cache, the
+    layout of `ops/paged.py`).
 
     Decode (Sq=1) on TPU dispatches to the paged Pallas kernel, whose
-    BlockSpec index_maps dereference the prefetched block table — the
-    gather never materializes a dense copy. Everywhere else the fallback
-    the ISSUE names runs: an XLA ``take`` over the table reassembles the
-    dense ``[B, NP * ps, Hkv, D]`` view (shape-identical to the slab
+    BlockSpec index_maps dereference the prefetched layer index and
+    block table — neither the layer nor the gather materializes.
+    Everywhere else the fallback runs: ONE XLA gather over
+    ``stack[layer, block_tables]`` reassembles the dense
+    ``[B, NP * ps, Hkv, D]`` view (shape-identical to the slab
     read, ``NP * ps == max_seq``) and the regular `sdp_attention`
     dispatch finishes the job — so paged decode is byte-identical to
     slab decode wherever both take the XLA path, and the slab decode
     kernel still serves gathered views on TPU when the paged kernel
     cannot lower."""
+    from bigdl_tpu.ops.paged import (_gather_codes, _gather_scales,
+                                     code_page_size)
+
     b, sq, h, d = q.shape
-    ps, hkv = arena_k.shape[1], arena_k.shape[2]
+    hkv, ps = kv_heads, code_page_size(arena_k)
     if scale is None:
         scale = d ** -0.5
     sliding_window = _live_window(sliding_window,
@@ -327,32 +338,31 @@ def sdp_attention_paged(
             paged_decode_attention_pallas, paged_decode_attention_supported)
 
         supported = paged_decode_attention_supported(
-            q, arena_k, logits_soft_cap, sliding_window, alibi_slopes,
+            q, arena_k, hkv, logits_soft_cap, sliding_window, alibi_slopes,
             k_scale)
         on_tpu = target_is_tpu()
         if supported and be == "pallas":
             if quant_name:
                 _note_dequant_path(quant_name, "fused")
             return paged_decode_attention_pallas(
-                q, arena_k, arena_v, block_tables, q_pos, float(scale),
-                interpret=not on_tpu, k_scale=k_scale, v_scale=v_scale)
+                q, arena_k, arena_v, block_tables, q_pos, float(scale), hkv,
+                interpret=not on_tpu, k_scale=k_scale, v_scale=v_scale,
+                layer=layer)
         if supported and on_tpu and _kernel_compiles(
                 "paged_decode", h, hkv, d, ps, block_tables.shape[1],
                 str(arena_k.dtype)):
             if quant_name:
                 _note_dequant_path(quant_name, "fused")
             return paged_decode_attention_pallas(
-                q, arena_k, arena_v, block_tables, q_pos, float(scale),
-                k_scale=k_scale, v_scale=v_scale)
+                q, arena_k, arena_v, block_tables, q_pos, float(scale), hkv,
+                k_scale=k_scale, v_scale=v_scale, layer=layer)
 
-    from bigdl_tpu.ops.paged import _gather_dense
-
-    kd = _gather_dense(arena_k, block_tables)
-    vd = _gather_dense(arena_v, block_tables)
+    kd = _gather_codes(arena_k, layer, block_tables, hkv)
+    vd = _gather_codes(arena_v, layer, block_tables, hkv)
     ksd = vsd = None
     if k_scale is not None:
-        ksd = _gather_dense(k_scale, block_tables)
-        vsd = _gather_dense(v_scale, block_tables)
+        ksd = _gather_scales(k_scale, layer, block_tables)
+        vsd = _gather_scales(v_scale, layer, block_tables)
     return sdp_attention(q, kd, vd, q_pos, scale=scale,
                          logits_soft_cap=logits_soft_cap,
                          sliding_window=sliding_window,

@@ -77,9 +77,9 @@ from bigdl_tpu.ops.kvcache import (SNAPSHOT_REFUSAL, KVCache, cache_nbytes,
                                    kv_cache_bytes, publish_kv_cache_bytes,
                                    resolve_kv_cache_dtype)
 from bigdl_tpu.ops.pallas.decode_attention import blocks_read, slab_blocks
-from bigdl_tpu.ops.paged import (NULL_PAGE, PagedKVCache, cow_copy_pages,
+from bigdl_tpu.ops.paged import (NULL_PAGE, cow_copy_pages,
                                  gather_pages_dense, paged_cache_bytes,
-                                 publish_paged_cache_bytes)
+                                 publish_paged_cache_bytes, splice_pages)
 from bigdl_tpu.robustness import (resolve_drain_timeout_sec,
                                   resolve_request_deadline_ms)
 from bigdl_tpu.robustness.faults import FaultInjector
@@ -542,7 +542,7 @@ class LLMEngine:
                 f"that threads scale planes through its forward; "
                 f"{getattr(self.family, 'name', '?')!r} does not "
                 "(SUPPORTS_SCALED_KV)")
-        # -- paged KV mode: one [P, page_size, H, hd] arena per layer +
+        # -- paged KV mode: one page arena (ops/paged.py keeps its layout) +
         # host-owned block tables instead of the per-slot slab.
         # Explicit EngineConfig values validate loudly here; env-driven
         # values already passed through config.flags() (typos fall back
@@ -851,27 +851,18 @@ class LLMEngine:
             self._decode_paged = decode_paged
 
             # splice a finished admission's private cache1 into the
-            # arena: per-token (page, offset) coordinates are computed
-            # on host ONCE per admission; positions inside the shared
-            # prefix (and chunk padding) point at the null page, the
-            # arena's write sink
+            # arena, whole pages at a time: the page of each logical
+            # page is computed on host ONCE per admission; pages inside
+            # the shared prefix (and of chunk padding) are the null
+            # page, the arena's write sink
             @functools.partial(tracked_jit, "engine_insert_paged",
                                registry=self.registry,
                                donate_argnums=(0,))
-            def insert_paged(cache, cache1, phys, off, slot, plen):
-                cap = phys.shape[0]
-                k = cache.k.at[:, phys, off].set(
-                    cache1.k[:, 0, :cap].astype(cache.k.dtype))
-                v = cache.v.at[:, phys, off].set(
-                    cache1.v[:, 0, :cap].astype(cache.v.dtype))
-                ks = vs = None
-                if cache.k_scale is not None:
-                    ks = cache.k_scale.at[:, phys, off].set(
-                        cache1.k_scale[:, 0, :cap])
-                    vs = cache.v_scale.at[:, phys, off].set(
-                        cache1.v_scale[:, 0, :cap])
-                pos = cache.pos.at[slot].set(plen)
-                return PagedKVCache(k, v, pos, ks, vs)
+            def insert_paged(cache, cache1, pages, slot, plen):
+                cache = splice_pages(
+                    cache, cache1.seq_slices(cache1.max_seq, row=0), pages)
+                return dataclasses.replace(
+                    cache, pos=cache.pos.at[slot].set(plen))
 
             self._insert_paged = insert_paged
 
@@ -881,9 +872,7 @@ class LLMEngine:
                                registry=self.registry,
                                donate_argnums=(0,))
             def seed_pages(cache1, cache, pages, consumed):
-                planes = gather_pages_dense(
-                    cache.k, cache.v, pages,
-                    cache_ks=cache.k_scale, cache_vs=cache.v_scale)
+                planes = gather_pages_dense(cache, pages)
                 k = jax.lax.dynamic_update_slice(
                     cache1.k, planes[0].astype(cache1.k.dtype),
                     (0, 0, 0, 0, 0))
@@ -906,20 +895,9 @@ class LLMEngine:
             # max_batch with null->null self-copies so ONE executable
             # serves every CoW step regardless of how many slots hit
             # their shared tail page simultaneously.
-            @functools.partial(tracked_jit, "engine_cow_pages",
-                               registry=self.registry,
-                               donate_argnums=(0,))
-            def cow_pages(cache, srcs, dsts):
-                planes = cow_copy_pages(
-                    cache.k, cache.v, srcs, dsts,
-                    cache_ks=cache.k_scale, cache_vs=cache.v_scale)
-                if cache.k_scale is not None:
-                    k, v, ks, vs = planes
-                else:
-                    (k, v), ks, vs = planes, None, None
-                return PagedKVCache(k, v, cache.pos, ks, vs)
-
-            self._cow_pages = cow_pages
+            self._cow_pages = tracked_jit(
+                "engine_cow_pages", cow_copy_pages, registry=self.registry,
+                donate_argnums=(0,))
 
         # chunk width must divide the private cache length or the last
         # chunk's dynamic_update_slice would CLAMP its start index and
@@ -1921,19 +1899,17 @@ class LLMEngine:
         self._bt_np[idx, :] = 0
         self._bt_np[idx, :len(row)] = row
         self._bt_dirty = True
-        # per-token scatter coordinates: positions already resident in
-        # shared pages must NOT be rewritten (a concurrent reader of
-        # those pages stays byte-identical), and chunk padding past the
-        # allocated pages has nowhere to live — both go to the null page
+        # the page each logical page of cache1 is written to: pages
+        # already resident in the shared prefix must NOT be rewritten (a
+        # concurrent reader of those pages stays byte-identical), and
+        # chunk padding past the allocated pages has nowhere to live —
+        # both go to the null page
         cap = min(a.cache1.max_seq, self.cfg_engine.max_seq)
-        write_row = np.zeros((self._pages_per_seq,), np.int64)
+        write_row = np.zeros((self._pages_per_seq,), np.int32)
         write_row[:len(row)] = row
         write_row[:len(shared)] = NULL_PAGE
-        t = np.arange(cap)
-        phys = write_row[t // ps].astype(np.int32)
-        off = (t % ps).astype(np.int32)
         cache = self._insert_paged(
-            self.cache, a.cache1, jnp.asarray(phys), jnp.asarray(off),
+            self.cache, a.cache1, jnp.asarray(write_row[:-(-cap // ps)]),
             jnp.asarray(idx, jnp.int32), jnp.asarray(plen, jnp.int32))
         if self.radix is not None:
             n_prompt_pages = -(-plen // ps)
@@ -2300,10 +2276,7 @@ class LLMEngine:
                 pages = [int(p) for p in self._bt_np[idx, :n_pages]]
                 state["page_manifest"] = self.pool.export_pages(pages)
                 dev = gather_pages_dense(
-                    self.cache.k, self.cache.v,
-                    jnp.asarray(np.asarray(pages, np.int32)),
-                    cache_ks=self.cache.k_scale,
-                    cache_vs=self.cache.v_scale)
+                    self.cache, jnp.asarray(np.asarray(pages, np.int32)))
                 # audited: a rare-path migration pulls this sequence's
                 # 2-4 planes once — not a per-token sync
                 planes = tuple(
@@ -2473,27 +2446,14 @@ class LLMEngine:
                 resume_id=resume_id, needed_pages=n,
                 free_pages=self.pool.num_free)
             return False
-        cap = n * ps
-        t = np.arange(cap)
-        row = np.asarray(pages, np.int64)
-        phys = jnp.asarray(row[t // ps].astype(np.int32))
-        off = jnp.asarray((t % ps).astype(np.int32))
-        c = self.cache
-        names = ("k", "v", "k_scale", "v_scale")
-        upd = {}
-        for name, plane in zip(names, planes):
-            arena = getattr(c, name)
-            if arena is None:
-                continue
+        rows = []
+        for plane in planes:
             # audited: plane arrived as host bytes off the wire — this
             # asarray is dtype/view normalization, not a device pull
             plane = np.asarray(plane)  # graftlint: disable=step-host-sync
-            buf = np.zeros((plane.shape[0], cap) + plane.shape[3:],
-                           plane.dtype)
-            buf[:, :kv_len] = plane[:, 0, :kv_len]
-            upd[name] = arena.at[:, phys, off].set(
-                jnp.asarray(buf).astype(arena.dtype))
-        self.cache = dataclasses.replace(c, **upd)
+            rows.append(jnp.asarray(plane[:, :, :kv_len]))
+        self.cache = splice_pages(
+            self.cache, rows, jnp.asarray(np.asarray(pages, np.int32)))
         self._migration_pages[resume_id] = (pages, kv_len,
                                             time.monotonic())
         return True

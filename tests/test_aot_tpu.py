@@ -256,22 +256,36 @@ def test_decode_attention_scaled_kv_compiles(v5e, aot_flags, b, s, kvdt):
     assert _has_mosaic_call(comp)
 
 
-def _moves_of(txt, shapes):
+def _moves_of(txt, shapes, appends_in_place=False):
     """Instructions of a compiled program that MATERIALIZE one of
     `shapes` (a copy, a fusion, a reshape, a slice, an update: anything
-    but a view or the plumbing of tuples and loops)."""
+    but a view or the plumbing of tuples and loops).
+
+    With `appends_in_place`, for a program that WRITES the arrays it is
+    searched for: a scatter, or the fusion around one whose result is
+    its first operand's buffer (`aliasing_operands` names operand 0 and
+    the result), is the append itself, only the new rows move; and a
+    result the compiler keeps in VMEM (`S(1)` in its layout) is its own
+    staging of a small plane, not a copy in HBM."""
     import re
 
     views = ("parameter", "get-tuple-element", "bitcast", "tuple", "while",
              "conditional", "call", "copy-start", "copy-done", "constant")
     found = []
     for m in re.finditer(
-            r"^\s*(?:ROOT )?(%[\w.\-]+) = \(?\w+\[([\d,]*)\]\S* ([\w\-]+)\(",
-            txt, re.M):
-        name, dims, op = m.groups()
-        if op not in views and tuple(
-                int(d) for d in dims.split(",") if d) in shapes:
-            found.append(f"{name} = [{dims}] {op}")
+            r"^\s*(?:ROOT )?(%[\w.\-]+) = \(?\w+\[([\d,]*)\](\S*) "
+            r"([\w\-]+)\((.*)$", txt, re.M):
+        name, dims, layout, op, rest = m.groups()
+        if op in views or tuple(
+                int(d) for d in dims.split(",") if d) not in shapes:
+            continue
+        if appends_in_place and (
+                "S(1)" in layout or op == "scatter" or op == "fusion"
+                and re.search(r'"aliasing_operands":\{"lists":\[\{"indices":'
+                              r'\["0","\d+"\]\}\]\}', rest)
+                and "/scatter" in rest):
+            continue
+        found.append(f"{name} = [{dims}] {op}")
     return found
 
 
@@ -334,26 +348,87 @@ def test_decode_attention_over_stack_in_place(v5e, aot_flags, layers, b, s,
 def test_paged_decode_attention_compiles(v5e, aot_flags, b, kvdt):
     """The block-table kernel (ops/pallas/paged_decode_attention) at
     Mistral-7B GQA 32/8, hd 128, 128-position pages over a max_seq-2048
-    arena: K/V index_maps dereference the prefetched block table."""
+    arena, on the cache's whole stack as `ops/paged.py` keeps it: K/V
+    index_maps dereference the prefetched layer index and block table,
+    and nothing of the arena is moved on the way in."""
     from bigdl_tpu.ops.pallas.paged_decode_attention import (
         paged_decode_attention_pallas)
+    from bigdl_tpu.ops.paged import init_paged_cache
 
     dev = v5e.devices[0]
-    h, hkv, hd, ps, np_ = 32, 8, 128, 128, 16
+    layers, h, hkv, hd, ps, np_ = 4, 32, 8, 128, 128, 16
     pages = 8 * np_ + 1
     q = jax.ShapeDtypeStruct((b, 1, h, hd), jnp.bfloat16)
-    arena = jax.ShapeDtypeStruct((pages, ps, hkv, hd), jnp.dtype(kvdt))
+    cache = _sds(jax.eval_shape(lambda: init_paged_cache(
+        layers, pages, ps, hkv, hd, b, kv_cache_dtype={
+            "bfloat16": "bf16", "float8_e5m2": "fp8_e5m2"}.get(kvdt, kvdt))),
+        dev)
+    assert cache.k.dtype == jnp.dtype(kvdt)
     bt = jax.ShapeDtypeStruct((b, np_), jnp.int32)
-    pos = jax.ShapeDtypeStruct((b,), jnp.int32)
-    args = [_sds(q, dev), _sds(arena, dev), _sds(arena, dev),
-            _sds(bt, dev), _sds(pos, dev)]
-    if kvdt in ("int8", "int4"):
-        args += list(_scale_planes((pages, ps, hkv), dev))
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
     comp = _compile(
-        lambda qq, kk, vv, bb, pp, ks=None, vs=None:
-        paged_decode_attention_pallas(qq, kk, vv, bb, pp, hd ** -0.5,
-                                      k_scale=ks, v_scale=vs), *args)
+        lambda qq, c, bb, ll: paged_decode_attention_pallas(
+            qq, c.k, c.v, bb, c.pos, hd ** -0.5, hkv, k_scale=c.k_scale,
+            v_scale=c.v_scale, layer=ll),
+        _sds(q, dev), cache, _sds(bt, dev), _sds(i32, dev))
     assert _has_mosaic_call(comp)
+    planes = {p.shape for p in (cache.k, cache.k_scale) if p is not None}
+    assert not _moves_of(comp.as_text(), planes | {
+        (hkv,) + cache.k.shape[1:], cache.k.shape[1:]} | {
+            p[1:] for p in planes})
+    assert comp.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
+def _arena_shapes(layers, pages, ps, hkv, hd):
+    """Every shape the arena, a layer of it or a scale plane has or had:
+    the layout at rest (`ops/paged.py`), views of it the kernel could
+    be handed, and the one of before PR 40 (`[L, P, ps, Hkv, hd]`,
+    scales `[L, P, ps, Hkv]`)."""
+    page = [(ps // 8, 8, hd), (ps, hd), (ps, hkv * hd), (ps, hkv, hd),
+            (hkv, ps), (ps, hkv)]
+    return {lead + p for p in page
+            for lead in ((layers * hkv, pages), (layers, pages),
+                         (hkv, pages), (1, pages), (pages,))}
+
+
+@pytest.mark.parametrize("hkv,kv", [
+    (2, "int8"),                    # the docqa cell: ChatGLM2's 2 kv groups
+    (8, "bf16"), (8, "int8"), (8, "int4"), (8, "fp8_e5m2"),
+])
+def test_paged_decode_step_moves_no_arena(v5e, aot_flags, hkv, kv):
+    """One whole paged decode step, `forward_paged` at Sq 1, at the
+    docqa cell's geometry (1280 pages of 128 positions, 32 slots, 64
+    table columns; 4 layers are enough): the append scatters into the
+    stack and the block-table kernel reads the stack, so NO instruction
+    materializes the arena, a layer of it or a scale plane, in the
+    layout at rest or any it had. On the chip those were eight of the
+    ten longest operations of the cell, 0.651 of 2.89 s busy, and 7.2 GB
+    of temporaries at 28 layers (PERF.md 6, PR 40)."""
+    import dataclasses
+
+    from bigdl_tpu.models import llama as M
+    from bigdl_tpu.ops.quant import prepack_tree
+    from bigdl_tpu.utils.testing import LLAMA2_7B, random_llama_params
+
+    dev = v5e.devices[0]
+    layers, pages, ps, b, np_ = 4, 1280, 128, 32, 64
+    cfg = dataclasses.replace(LLAMA2_7B, num_hidden_layers=layers,
+                              num_key_value_heads=hkv)
+    params = _sds(jax.eval_shape(lambda: prepack_tree(M.merge_projections(
+        random_llama_params(cfg, "sym_int4"), cfg))[0]), dev)
+    cache = _sds(jax.eval_shape(
+        lambda: M.new_paged_cache(cfg, pages, ps, b, kv)), dev)
+    ids = _sds(jax.ShapeDtypeStruct((b, 1), jnp.int32), dev)
+    bt = _sds(jax.ShapeDtypeStruct((b, np_), jnp.int32), dev)
+    comp = jax.jit(
+        lambda p, i, c, t: M.forward_paged(p, cfg, i, c, t, last_only=True),
+        donate_argnums=(2,)).lower(params, ids, cache, bt).compile()
+    txt = comp.as_text()
+    assert "paged_decode_attention" in txt and _has_mosaic_call(comp)
+    moved = _moves_of(txt, _arena_shapes(layers, pages, ps, hkv, cfg.hd),
+                      appends_in_place=True)
+    assert not moved, f"the arena is materialized: {moved}"
+    assert comp.memory_analysis().temp_size_in_bytes < 128 << 20
 
 
 # Mistral-7B sym_int4 decode matmuls, merged layout: qkv, o, gate_up,

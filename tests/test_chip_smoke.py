@@ -118,7 +118,7 @@ def _probe_sites():
     x64 = jnp.ones((64, 256), jnp.bfloat16)
     q = jnp.ones((1, 1, 8, 64), jnp.bfloat16)
     kv = jnp.ones((1, 128, 4, 64), jnp.bfloat16)
-    arena = jnp.ones((3, 128, 4, 64), jnp.bfloat16)
+    arena = jnp.ones((4, 3, 128, 64), jnp.bfloat16)     # ops/paged.py
     bt = jnp.zeros((1, 2), jnp.int32)
     pos = jnp.zeros((1,), jnp.int32)
     return {
@@ -127,7 +127,7 @@ def _probe_sites():
         "decode_attention": lambda: attention.sdp_attention(
             q, kv, kv, jnp.zeros((), jnp.int32)),
         "paged_decode_attention": lambda: attention.sdp_attention_paged(
-            q, arena, arena, bt, pos),
+            q, arena, arena, bt, pos, 4),
         "vmapped_gemm": lambda: matmul.vmapped_pallas_ok(
             "sym_int4", 256, 256),
         "moe_ragged": lambda: moe_dispatch.ragged_kernel_compiles(

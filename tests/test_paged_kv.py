@@ -178,26 +178,179 @@ def test_radix_clear_releases_every_ref():
 
 
 def test_cow_copy_preserves_source_page():
+    import dataclasses
+
     import jax.numpy as jnp
 
     from bigdl_tpu.ops.paged import cow_copy_pages, init_paged_cache
 
     cache = init_paged_cache(2, 4, 8, 2, 4, batch=1)
-    k = cache.k.at[:, 1].set(1.0)
-    v = cache.v.at[:, 1].set(2.0)
-    before_k = np.asarray(k).copy()
-    nk, nv = cow_copy_pages(k, v, jnp.asarray([1], jnp.int32),
-                            jnp.asarray([2], jnp.int32))
-    hk, hv = np.asarray(nk), np.asarray(nv)
+    cache = dataclasses.replace(cache, k=cache.k.at[:, 1].set(1.0),
+                                v=cache.v.at[:, 1].set(2.0))
+    before_k = np.asarray(cache.k).copy()
+    new = cow_copy_pages(cache, jnp.asarray([1], jnp.int32),
+                         jnp.asarray([2], jnp.int32))
+    hk, hv = np.asarray(new.k), np.asarray(new.v)
     # the shared source page is bit-untouched; the copy is exact
     assert (hk[:, 1] == before_k[:, 1]).all()
     assert (hk[:, 2] == before_k[:, 1]).all()
     assert (hv[:, 2] == 2.0).all()
     # null->null self-copy (the padding lanes of a batched CoW step)
     # is the identity
-    sk, _ = cow_copy_pages(nk, nv, jnp.asarray([0], jnp.int32),
-                           jnp.asarray([0], jnp.int32))
-    assert (np.asarray(sk) == hk).all()
+    same = cow_copy_pages(new, jnp.asarray([0], jnp.int32),
+                          jnp.asarray([0], jnp.int32))
+    assert (np.asarray(same.k) == hk).all()
+
+
+# ---------------------------------------------------------------------------
+# the layout at rest, through ops/paged.py's accessors only (PR 40): every
+# storage dtype at ChatGLM2's 2 kv groups and at Mistral's 8 heads
+
+_LAYOUTS = [(kv, hkv) for kv in ("bf16", "fp8_e5m2", "int8", "int4")
+            for hkv in (2, 8)]
+
+
+def _filled_arena(kv, hkv, seed, ps=16, hd=8, b=2, np_=4, n=37, layers=2):
+    """A slab cache and a paged one given the same `n` tokens a slot in
+    two appends, the paged one through block tables in permuted order."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from bigdl_tpu.ops.kvcache import init_cache, update_layer
+    from bigdl_tpu.ops.paged import init_paged_cache, paged_update_layer
+
+    rng = np.random.default_rng(seed)
+    slab = init_cache(layers, b, np_ * ps, hkv, hd, kv_cache_dtype=kv)
+    # the null page, a page for every table entry, and one left free
+    cache = init_paged_cache(layers, b * np_ + 2, ps, hkv, hd, b,
+                             kv_cache_dtype=kv)
+    assert (cache.page_size, cache.kv_heads, cache.head_dim) == (ps, hkv, hd)
+    tables = jnp.asarray(
+        1 + rng.permutation(b * np_).reshape(b, np_), jnp.int32)
+    sp = [slab.k, slab.v, slab.k_scale, slab.v_scale]
+    pp = [cache.k, cache.v, cache.k_scale, cache.v_scale]
+    scaled = cache.k_scale is not None
+    for layer in range(layers):
+        for start, stop in ((0, n - 5), (n - 5, n)):   # a prefill, a tail
+            k, v = (jnp.asarray(rng.standard_normal(
+                (b, stop - start, hkv, hd)) * (layer + 1), jnp.bfloat16)
+                for _ in range(2))
+            pos = jnp.full((b,), start, jnp.int32)
+            out = update_layer(sp[0], sp[1], layer, k, v, pos, sp[2], sp[3])
+            sp[:len(out)] = out
+            out = paged_update_layer(pp[0], pp[1], layer, k, v, pos, tables,
+                                     pp[2], pp[3])
+            pp[:len(out)] = out
+            assert len(out) == (4 if scaled else 2)
+    cache = dataclasses.replace(
+        cache, **dict(zip(("k", "v", "k_scale", "v_scale"), pp)))
+    return sp, cache, tables, n
+
+
+def _same(a, b):
+    return (np.asarray(a, np.float32) == np.asarray(b, np.float32)).all()
+
+
+@pytest.mark.parametrize("kv,hkv", _LAYOUTS)
+def test_paged_append_reads_back_the_slab_bit_for_bit(kv, hkv):
+    from bigdl_tpu.ops.kvcache import read_layer
+    from bigdl_tpu.ops.paged import (paged_read_layer,
+                                     paged_read_layer_quantized)
+
+    sp, cache, tables, n = _filled_arena(kv, hkv, seed=1)
+    for layer in range(cache.num_layers):
+        want = read_layer(sp[0], sp[1], layer, cache_ks=sp[2],
+                          cache_vs=sp[3])
+        got = paged_read_layer(cache.k, cache.v, layer, tables,
+                               cache.kv_heads, cache_ks=cache.k_scale,
+                               cache_vs=cache.v_scale)
+        for w, g in zip(want, got):
+            assert g.shape == w.shape and _same(g[:, :n], w[:, :n])
+        if cache.k_scale is not None:        # codes AND scales, undequantized
+            raw = paged_read_layer_quantized(
+                cache.k, cache.v, cache.k_scale, cache.v_scale, layer, tables)
+            for w, g in zip(sp, raw):
+                assert g.shape == w.shape[1:] and g.dtype == w.dtype
+                assert _same(g[:, :n], w[layer, :, :n])
+
+
+@pytest.mark.parametrize("kv,hkv", _LAYOUTS)
+def test_pages_gather_splice_and_cow_agree_on_one_arena(kv, hkv):
+    """`gather_pages_dense` hands out the slab's logical planes,
+    `splice_pages` (the engine's insert and migration import) is its
+    inverse, and `cow_copy_pages` copies a page on every plane."""
+    import jax.numpy as jnp
+
+    from bigdl_tpu.ops.paged import (cow_copy_pages, gather_pages_dense,
+                                     init_paged_cache, splice_pages)
+
+    sp, cache, tables, n = _filled_arena(kv, hkv, seed=2)
+    ps = cache.page_size
+    dense = gather_pages_dense(cache, tables[1])
+    assert len(dense) == (4 if cache.k_scale is not None else 2)
+    for w, g in zip(sp, dense):              # slot 1 of the slab, as stored
+        assert g.shape == (w.shape[0], 1) + w.shape[2:] and g.dtype == w.dtype
+        assert _same(g[:, 0, :n], w[:, 1, :n])
+
+    # splice the dense planes into an EMPTY arena at other pages, in
+    # another order; the page past the n tokens goes to the null page
+    fresh = init_paged_cache(cache.num_layers, cache.num_pages, ps, hkv,
+                             cache.head_dim, 2, kv_cache_dtype=kv)
+    row = np.asarray(tables[0])[::-1].copy()
+    row[-(-n // ps):] = NULL_PAGE
+    spliced = splice_pages(fresh, dense, jnp.asarray(row, jnp.int32))
+    again = gather_pages_dense(spliced, jnp.asarray(row, jnp.int32))
+    for w, g in zip(dense, again):
+        assert _same(g[:, :, :n], w[:, :, :n])
+    untouched = sorted(set(range(1, cache.num_pages)) - set(row.tolist()))
+    for g in gather_pages_dense(spliced, jnp.asarray(untouched, jnp.int32)):
+        assert not np.asarray(g, np.float32).any()
+    # a private cache shorter than its pages (a chunk that does not
+    # divide a page) is padded up to them
+    short = splice_pages(fresh, [p[:, :, :n] for p in dense],
+                         jnp.asarray(row, jnp.int32))
+    for w, g in zip(again, gather_pages_dense(
+            short, jnp.asarray(row, jnp.int32))):
+        assert _same(g[:, :, :n], w[:, :, :n])
+
+    # copy-on-write of slot 1's second page onto the free one: the copy
+    # reads back as the source, the source is untouched
+    src, dst = int(tables[1, 1]), cache.num_pages - 1
+    copied = cow_copy_pages(cache, jnp.asarray([src], jnp.int32),
+                            jnp.asarray([dst], jnp.int32))
+    both = gather_pages_dense(copied, jnp.asarray([src, dst], jnp.int32))
+    for w, g in zip(dense, both):
+        assert _same(g[:, :, :ps], w[:, :, ps:2 * ps])
+        assert _same(g[:, :, ps:], w[:, :, ps:2 * ps])
+
+
+@pytest.mark.parametrize("kv,hkv", _LAYOUTS)
+def test_block_table_kernel_agrees_with_the_xla_gather(kv, hkv):
+    """The Pallas kernel (interpret mode) on the arena's stacks and a
+    layer index against the XLA fallback's one gather of
+    `stack[layer, tables]`, on the same arena: pages of 128 positions
+    in permuted order, a slot deep in its third page and one in its
+    second, the null page behind the rest of both tables."""
+    import jax.numpy as jnp
+
+    from bigdl_tpu.ops.attention import sdp_attention_paged
+
+    sp, cache, tables, n = _filled_arena(kv, hkv, seed=3, ps=128, hd=128,
+                                         np_=3, n=300)
+    tables = tables.at[1, 2].set(NULL_PAGE)
+    rng = np.random.default_rng(4)
+    q = jnp.asarray(rng.standard_normal((2, 1, 16, 128)), jnp.bfloat16)
+    pos = jnp.asarray([n - 1, 140], jnp.int32)
+    for layer in range(cache.num_layers):
+        got, want = (np.asarray(sdp_attention_paged(
+            q, cache.k, cache.v, tables, pos, hkv, backend=be,
+            k_scale=cache.k_scale, v_scale=cache.v_scale,
+            layer=jnp.asarray(layer, jnp.int32)), np.float32)
+            for be in ("pallas", "xla"))
+        assert got.shape == want.shape == (2, 1, 16, 128)
+        # bf16 probabilities and rows on both sides: 2^-8 of the values
+        assert np.linalg.norm(got - want) <= 0.01 * np.linalg.norm(want)
 
 
 # ---------------------------------------------------------------------------

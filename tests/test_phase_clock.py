@@ -437,11 +437,11 @@ def test_attention_and_moe_kernels_are_named():
         lambda: decode_attention_pallas(q, k[None], k[None], pos, 0.088,
                                         interpret=True)
     ) == ["decode_attention"]
-    arena = jnp.ones((8, 128, 2, 128), jnp.bfloat16)
+    arena = jnp.ones((2, 8, 128, 128), jnp.bfloat16)    # ops/paged.py
     bt = jnp.array([[1, 2], [3, 4]], jnp.int32)
     assert _pallas_names(
         lambda: paged_decode_attention_pallas(q, arena, arena, bt, pos,
-                                              0.088, interpret=True)
+                                              0.088, 2, interpret=True)
     ) == ["paged_decode_attention"]
     qp = jnp.ones((1, 128, 4, 128), jnp.bfloat16)
     kp = jnp.ones((1, 128, 2, 128), jnp.bfloat16)
