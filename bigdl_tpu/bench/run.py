@@ -119,7 +119,8 @@ def _bench_serving(model, prompt, out_len, num_trials, warm_up):
     ttft = summary.get("bigdl_tpu_ttft_seconds")
     if isinstance(ttft, dict):
         out["ttft_p50_ms"] = round(ttft["p50"] * 1e3, 3)
-    tpot = summary.get("bigdl_tpu_tpot_seconds")
+    # the steps that carried no prefill chunk: the decode alone
+    tpot = summary.get('bigdl_tpu_tpot_seconds{kind="plain"}')
     if isinstance(tpot, dict):
         out["tpot_p50_ms"] = round(tpot["p50"] * 1e3, 3)
     return out

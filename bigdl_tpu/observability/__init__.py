@@ -18,8 +18,10 @@ standard library — tests/test_observability.py enforces it):
   ``GET /v1/stats`` serves the snapshot. ``PhaseClock`` is the
   engine step's one instrument: each part of ``LLMEngine.step`` is a
   profiler span (``engine.<phase>``, trace-only children ``cache.*``,
-  ``observe.*``, ``admission.wait``) and a share of one
-  ``bigdl_tpu_step_phase_seconds{phase}`` sample per step.
+  ``observe.*``, ``admission.wait``, the puts ``*.h2d`` and the reads
+  ``sample.fetch``) and a share of one
+  ``bigdl_tpu_step_phase_seconds{phase, kind}`` sample per step,
+  ``kind`` saying whether the step dispatched a prefill chunk.
 - ``disttrace``: fleet-wide distributed tracing — W3C-style
   ``traceparent`` propagation (router -> replica -> engine -> KV-handoff
   target), a thread-safe ``SpanRecorder`` of completed spans per
@@ -85,9 +87,11 @@ Metric name -> engine field map (see also serving/engine.py):
 ==========================================  ===============================
 metric                                      source
 ==========================================  ===============================
-bigdl_tpu_request_phase_seconds{phase=...}  RequestSpan queue/prefill/decode;
+bigdl_tpu_request_phase_seconds{phase=...}  RequestSpan decode;
                                             ingest: api_server handler
-bigdl_tpu_step_phase_seconds{phase=...}     tracing.PhaseClock in LLMEngine.step
+bigdl_tpu_step_phase_seconds{phase, kind}   tracing.PhaseClock in LLMEngine.step
+                                            (kind=plain|chunk); RequestSpan
+                                            queue_wait/prefill (kind=admission)
 bigdl_tpu_prefill_chunks_total              LLMEngine._admission_step
 bigdl_tpu_prefill_tokens_total{kind}        LLMEngine._admission_step
 bigdl_tpu_decode_attn_blocks_total{kind}    LLMEngine._decode_step (slab K/V
@@ -95,7 +99,10 @@ bigdl_tpu_decode_attn_blocks_total{kind}    LLMEngine._decode_step (slab K/V
                                             blocks_read / slab_blocks
 bigdl_tpu_stream_delivery_seconds           api_server stream handler
 bigdl_tpu_ttft_seconds                      RequestSpan.ttft_s
-bigdl_tpu_tpot_seconds                      LLMEngine.step() decode timing
+bigdl_tpu_tpot_seconds{kind}                tracing.PhaseClock.end: wall of a
+                                            step that decoded, step() entry
+                                            to return, by kind
+bigdl_tpu_engine_loop_seconds_total{state}  api_server._EngineLoop._run
 bigdl_tpu_slot_occupancy                    len(LLMEngine._slots)
 bigdl_tpu_queue_depth                       len(LLMEngine._queue)
 bigdl_tpu_admissions_total                  LLMEngine._admission_step

@@ -22,17 +22,21 @@ module import order.
 Canonical serving metric names (emitted by serving/engine.py; see that
 module and observability/__init__ for the field mapping):
 
-    bigdl_tpu_request_phase_seconds{phase=ingest|queue|prefill|decode}
-                                                                 histogram
-    bigdl_tpu_step_phase_seconds{phase=queue_wait|prefill (per request),
-        sweep|admission|observe|cache (per working step),
-        dispatch|device|sample|emit|host (per step that decoded)} histogram
+    bigdl_tpu_request_phase_seconds{phase=ingest|decode}         histogram
+    bigdl_tpu_step_phase_seconds{phase=queue_wait|prefill (per request,
+        kind=admission), sweep|admission|observe|cache|h2d|fetch (per
+        working step), dispatch|device|sample|emit|host (per step that
+        decoded); kind=plain|chunk: whether the step dispatched a
+        prefill chunk}                                           histogram
     bigdl_tpu_prefill_chunks_total                               counter
     bigdl_tpu_prefill_tokens_total{kind=prompt|padding}          counter
     bigdl_tpu_decode_attn_blocks_total{kind=read|slab}           counter
     bigdl_tpu_stream_delivery_seconds (serving/api_server.py)    histogram
     bigdl_tpu_ttft_seconds                                       histogram
-    bigdl_tpu_tpot_seconds                                       histogram
+    bigdl_tpu_tpot_seconds{kind=plain|chunk} (wall of a step that
+        decoded, step() entry to return; STEP_WALL_BUCKETS_S)    histogram
+    bigdl_tpu_engine_loop_seconds_total{state=wait|step}
+        (serving/api_server.py)                                  counter
     bigdl_tpu_slot_occupancy / bigdl_tpu_queue_depth             gauge
     bigdl_tpu_admissions_total / bigdl_tpu_preemptions_total     counter
     bigdl_tpu_stall_guard_trips_total                            counter
@@ -62,6 +66,15 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 # log grid keeps every phase resolvable with one bucket list.
 LATENCY_BUCKETS_S: Tuple[float, ...] = tuple(
     round(10.0 ** (e / 3.0), 6) for e in range(-12, 7))
+
+# A decode step's wall (bigdl_tpu_tpot_seconds): forty bounds a decade
+# (ratio 1.0593) from 4 ms to 1 s, where the steps of every served model
+# lie, so that a percentile interpolated inside its bucket is within 3 %
+# of the exact one; a few bounds either side for what falls outside.
+STEP_WALL_BUCKETS_S: Tuple[float, ...] = (
+    (0.0001, 0.001, 0.002)
+    + tuple(round(10.0 ** (e / 40.0), 6) for e in range(-96, 1))
+    + (2.0, 5.0, 10.0, 30.0, 100.0))
 
 # Acceptance-rate style ratios live in [0, 1]; linear decile buckets.
 RATIO_BUCKETS: Tuple[float, ...] = tuple(

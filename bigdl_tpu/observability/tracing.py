@@ -4,12 +4,14 @@ Each request the engine touches gets a ``RequestSpan`` recording the
 timestamps the serving metrics are computed from:
 
     t_enqueued  -> t_admitted          queue wait
-                   (bigdl_tpu_request_phase_seconds{phase="queue"})
+                   (bigdl_tpu_step_phase_seconds{phase="queue_wait"})
     t_admitted  -> t_first_token       prefill latency ({phase="prefill"})
     t_arrival   -> t_first_token       TTFT (bigdl_tpu_ttft_seconds)
-    t_first_token -> t_finished        decode phase ({phase="decode"})
-    decode phase / tokens              TPOT (engine observes per step
-                                       into bigdl_tpu_tpot_seconds)
+    t_first_token -> t_finished        decode phase
+                   (bigdl_tpu_request_phase_seconds{phase="decode"})
+    decode phase / tokens              TPOT (PhaseClock observes the wall
+                                       of every step that decoded into
+                                       bigdl_tpu_tpot_seconds{kind})
 
 plus discrete events (``preempt``, ``resume``, ``finish``) with their
 own timestamps. Spans live in the tracer's in-memory ring buffer
@@ -20,7 +22,8 @@ appended to a JSONL file for offline analysis.
 ``PhaseClock`` is the engine step's one instrument: ``phase(name)``
 opens a profiler span, reads the host clock at both ends and adds the
 duration to the step's total for ``name``; ``end()`` observes each
-total once into ``bigdl_tpu_step_phase_seconds{phase=<name>}``.
+total once into ``bigdl_tpu_step_phase_seconds{phase=<name>, kind}``,
+``kind`` saying whether the step carried a prefill chunk.
 
 Stdlib-only by design (see observability/metrics.py): the profiler's
 annotation factory is handed in by the engine.
@@ -373,11 +376,24 @@ class RequestTracer:
 # Labels of bigdl_tpu_step_phase_seconds that PhaseClock.end() observes,
 # by population: one sample on every working step (what
 # bigdl_tpu_engine_steps_total counts), or one on every step that
-# decoded. ``cache`` is derived: the sum of the step's ``cache.*`` child
-# spans; so is ``host``: the step's wall less ``device``.
-WORKING_STEP_PHASES = ("sweep", "admission", "observe", "cache")
+# decoded. ``cache``, ``h2d`` and ``fetch`` are derived: each the sum of
+# the step's child spans that _CHILD_LABEL gives it; so is ``host``: the
+# step's wall less ``device``.
+WORKING_STEP_PHASES = ("sweep", "admission", "observe", "cache", "h2d",
+                       "fetch")
 DECODE_STEP_PHASES = ("dispatch", "device", "sample", "emit", "host")
-_DERIVED_FROM_CHILDREN = frozenset({"cache"})
+# What a step carried: ``chunk`` when a prefill program was dispatched
+# in it (PhaseClock.mark_chunk), else ``plain``. The samples the engine
+# takes once an admission into the same histogram (queue_wait, prefill)
+# are no step's: they carry ADMISSION_KIND.
+STEP_KINDS = PLAIN, CHUNK = ("plain", "chunk")
+ADMISSION_KIND = "admission"
+# trace-only child spans that also add to a derived label: every
+# ``cache.*`` by its head, a transfer in and a fetch out by name
+_CHILD_LABEL = {"cache": "cache",
+                "dispatch.h2d": "h2d", "admission.h2d": "h2d",
+                "sample.h2d": "h2d",
+                "sample.fetch": "fetch", "admission.wait": "fetch"}
 
 
 class _Phase:
@@ -408,31 +424,45 @@ class PhaseClock:
     ``phase(name)`` is a top-level phase: a profiler span
     ``engine.<name>`` and a share of this step's total for ``name``.
     ``phase(name, child=True)`` is trace-only: a span under the dotted
-    name given, with no label of its own; a ``cache.*`` child also adds
-    to the derived ``cache`` label's total. ``annotation(name)`` makes
-    the span (``utils.profiling.annotate``: this module imports no
-    jax). Used from the engine thread only.
+    name given, with no label of its own; a child that ``_CHILD_LABEL``
+    names (``cache.*``, the transfers in, the fetches out) also adds to
+    that derived label's total. ``mark_chunk()`` says that this step
+    dispatched a prefill program: ``end()`` then observes the step under
+    ``kind="chunk"``, and the wall of a step that decoded into
+    ``step_wall`` under the same kind. ``annotation(name)`` makes the
+    span (``utils.profiling.annotate``: this module imports no jax).
+    Used from the engine thread only.
     """
 
-    def __init__(self, histogram, annotation: Callable[[str], object]):
+    def __init__(self, histogram, step_wall,
+                 annotation: Callable[[str], object]):
         self._annotation = annotation
         self._totals: Dict[str, float] = {}
         self._t_begin = time.perf_counter()
-        # made here, so that every label renders from scrape 1
-        self._samples = {name: histogram.labels(name) for name in
-                         WORKING_STEP_PHASES + DECODE_STEP_PHASES}
+        self._kind = PLAIN
+        # made here, so that every pair of a label and a kind renders
+        # from scrape 1 and a step looks nothing up
+        self._samples = {
+            kind: {name: histogram.labels(name, kind) for name in
+                   WORKING_STEP_PHASES + DECODE_STEP_PHASES}
+            for kind in STEP_KINDS}
+        self._walls = {kind: step_wall.labels(kind) for kind in STEP_KINDS}
 
     def begin(self) -> None:
-        """Start of a step: forget the last step's totals."""
+        """Start of a step: forget the last step's totals and kind."""
         self._totals.clear()
+        self._kind = PLAIN
         self._t_begin = time.perf_counter()
+
+    def mark_chunk(self) -> None:
+        """This step dispatched a prefill program."""
+        self._kind = CHUNK
 
     def phase(self, name: str, child: bool = False) -> _Phase:
         if child:
-            head = name.partition(".")[0]
-            return _Phase(
-                self, head if head in _DERIVED_FROM_CHILDREN else None,
-                self._annotation(name))
+            key = _CHILD_LABEL.get(name) or _CHILD_LABEL.get(
+                name.partition(".")[0])
+            return _Phase(self, key, self._annotation(name))
         return _Phase(self, name, self._annotation("engine." + name))
 
     def seconds(self, name: str) -> float:
@@ -441,14 +471,17 @@ class PhaseClock:
 
     def end(self, worked: bool) -> None:
         """End of a step: one sample per phase of the step's
-        population (a step that ran the ``device`` phase decoded); an
-        idle step observes nothing."""
+        population, under the step's kind (a step that ran the
+        ``device`` phase decoded, and its wall is one sample of
+        ``step_wall``); an idle step observes nothing."""
         totals = self._totals
+        samples = self._samples[self._kind]
         if "device" in totals:
             wall = time.perf_counter() - self._t_begin
+            self._walls[self._kind].observe(wall)
             totals["host"] = max(wall - totals.get("device", 0.0), 0.0)
             for name in DECODE_STEP_PHASES:
-                self._samples[name].observe(totals.get(name, 0.0))
+                samples[name].observe(totals.get(name, 0.0))
         if worked:
             for name in WORKING_STEP_PHASES:
-                self._samples[name].observe(totals.get(name, 0.0))
+                samples[name].observe(totals.get(name, 0.0))

@@ -233,11 +233,21 @@ class _EngineLoop:
         self.engine = engine
         self._wake = threading.Event()
         self._stop = False
+        # where the engine thread's time goes, over the whole run: the
+        # loop's share without work tells "no work" from "host busy"
+        seconds = engine.registry.counter(
+            "bigdl_tpu_engine_loop_seconds_total",
+            "Engine thread wall time: state=step inside engine.step(), "
+            "state=wait blocked for work after a step that did none.",
+            labelnames=("state",))
+        self._m_step_s = seconds.labels("step")
+        self._m_wait_s = seconds.labels("wait")
         self._thread = threading.Thread(target=self._run, daemon=True)
         self._thread.start()
 
     def _run(self):
         while not self._stop:
+            t0 = time.perf_counter()
             try:
                 did = self.engine.step()
             except Exception:   # a dead loop thread would hang every client
@@ -245,12 +255,15 @@ class _EngineLoop:
 
                 traceback.print_exc()
                 did = False
+            t1 = time.perf_counter()
+            self._m_step_s.inc(t1 - t0)
             if not did:
                 # a span of its own, so that a profiler capture tells
                 # "no work" from "host busy" on the engine thread
                 with annotate("engine_loop.wait"):
                     self._wake.wait(timeout=0.01)
                 self._wake.clear()
+                self._m_wait_s.inc(time.perf_counter() - t1)
 
     def notify(self):
         self._wake.set()

@@ -66,10 +66,13 @@ from bigdl_tpu.observability.flight import (FlightRecorder, build_postmortem,
 from bigdl_tpu.observability.flight import write_postmortem as \
     _write_postmortem_file
 from bigdl_tpu.observability.memory import MemoryLedger, tree_nbytes
-from bigdl_tpu.observability.metrics import RATIO_BUCKETS, default_registry
+from bigdl_tpu.observability.metrics import (RATIO_BUCKETS,
+                                             STEP_WALL_BUCKETS_S,
+                                             default_registry)
 from bigdl_tpu.observability.slo import SLOTracker
 from bigdl_tpu.observability.stats import ewma as stats_ewma
-from bigdl_tpu.observability.tracing import PhaseClock, RequestTracer
+from bigdl_tpu.observability.tracing import (ADMISSION_KIND, PhaseClock,
+                                             RequestTracer)
 from bigdl_tpu.observability.usage import UsageLedger
 from bigdl_tpu.ops.eva import rows_read
 from bigdl_tpu.ops.kvcache import (SNAPSHOT_REFUSAL, KVCache, cache_nbytes,
@@ -958,35 +961,47 @@ class LLMEngine:
         self._m_phase = m.histogram(
             "bigdl_tpu_request_phase_seconds",
             "Per-request phase latency: ingest (API server: request "
-            "line read to add_request returned), queue wait, prefill, "
-            "decode.",
+            "line read to add_request returned), decode (first token "
+            "to finish). Queue wait and prefill are "
+            "bigdl_tpu_step_phase_seconds{phase=queue_wait|prefill}.",
             labelnames=("phase",))
-        for ph in ("ingest", "queue", "prefill", "decode"):
+        for ph in ("ingest", "decode"):
             # render from scrape 1
             self._m_phase.labels(ph)
-        self._m_step_phase = m.histogram(
+        m_step_phase = m.histogram(
             "bigdl_tpu_step_phase_seconds",
-            "Engine step critical-path decomposition. Per request: "
-            "queue_wait, prefill. One sample per working step: sweep, "
-            "admission, observe, cache (the step's cache.* spans). One "
-            "per step that decoded: dispatch (host, to the decode "
-            "call's return), device (blocked block_until_ready on the "
-            "decode result), sample, emit, host (step wall less "
-            "device).",
-            labelnames=("phase",))
-        for ph in ("queue_wait", "prefill"):
-            self._m_step_phase.labels(ph)   # render from scrape 1
+            "Engine step critical-path decomposition. Per request "
+            "(kind=admission): queue_wait, prefill. Per step, kind=chunk "
+            "when the step dispatched a prefill chunk, else plain. One "
+            "sample per working step: sweep, admission, observe, cache "
+            "(the step's cache.* spans), h2d (its host-to-device puts: "
+            "the *.h2d spans), fetch (its device-to-host reads: "
+            "sample.fetch, admission.wait). One per step that "
+            "decoded: dispatch (host, to the decode call's return), "
+            "device (blocked block_until_ready on the decode result), "
+            "sample, emit, host (step wall less device).",
+            labelnames=("phase", "kind"))
+        # render from scrape 1
+        self._m_queue_wait = m_step_phase.labels("queue_wait",
+                                                 ADMISSION_KIND)
+        self._m_prefill = m_step_phase.labels("prefill", ADMISSION_KIND)
         # the step's phase clock: every part of step() is a span on the
         # profiler's clock (engine.<phase>, cache.*, observe.*,
-        # admission.wait) and a share of one histogram sample per step
-        self.phases = PhaseClock(self._m_step_phase, annotate)
+        # admission.*, dispatch.h2d, sample.fetch) and a share of one
+        # histogram sample per step
+        self.phases = PhaseClock(
+            m_step_phase,
+            m.histogram(
+                "bigdl_tpu_tpot_seconds",
+                "Time per output token: wall of a step that decoded, "
+                "step() entry to return (every active stream advances "
+                "one token per step), by kind: chunk when the step "
+                "dispatched a prefill chunk, else plain.",
+                labelnames=("kind",), buckets=STEP_WALL_BUCKETS_S),
+            annotate)
         self._m_ttft = m.histogram(
             "bigdl_tpu_ttft_seconds",
             "Time to first token: arrival to first sampled token.")
-        self._m_tpot = m.histogram(
-            "bigdl_tpu_tpot_seconds",
-            "Time per output token: batched decode step wall time "
-            "(every active stream advances one token per step).")
         self._m_occupancy = m.gauge(
             "bigdl_tpu_slot_occupancy", "Active decode slots.")
         self._m_queue_depth = m.gauge(
@@ -1767,8 +1782,13 @@ class LLMEngine:
         part = a.req.prompt_token_ids[a.consumed:a.consumed + chunk]
         padded[0, :len(part)] = part
         self.faults.raise_point("prefill", self._step_idx)
-        logits, a.cache1 = self._prefill(
-            self.params, jnp.asarray(padded), a.cache1)
+        with self.phases.phase("admission.h2d", child=True):
+            padded_dev = jnp.asarray(padded)
+        logits, a.cache1 = self._prefill(self.params, padded_dev, a.cache1)
+        # a put's buffer is let go where the call's own temporary was:
+        # while the program runs, not at the frame's exit after the wait
+        del padded_dev
+        self.phases.mark_chunk()
         self._m_prefill_chunks.inc()
         self._m_prefill_tokens.labels("prompt").inc(len(part))
         self._m_prefill_tokens.labels("padding").inc(chunk - len(part))
@@ -2704,13 +2724,14 @@ class LLMEngine:
         p = s.req.params
         if s.counts is None and s.n_logprobs < 0:
             pos = s.req.generated_offset     # position 0 of this resume
-            tok_dev = self._sample_device(
-                lg_dev,
-                jnp.asarray([p.temperature], jnp.float32),
-                jnp.asarray([p.top_k], jnp.int32),
-                jnp.asarray([p.top_p], jnp.float32),
-                jnp.asarray([s.dev_seed], jnp.int32),
-                jnp.asarray([pos], jnp.int32))
+            with self.phases.phase("admission.h2d", child=True):
+                sampling = (jnp.asarray([p.temperature], jnp.float32),
+                            jnp.asarray([p.top_k], jnp.int32),
+                            jnp.asarray([p.top_p], jnp.float32),
+                            jnp.asarray([s.dev_seed], jnp.int32),
+                            jnp.asarray([pos], jnp.int32))
+            tok_dev = self._sample_device(lg_dev, *sampling)
+            del sampling
             # the one blocking fetch of admission: the host waits here
             # for every prefill chunk queued ahead of the sampler
             with self.phases.phase("admission.wait", child=True):
@@ -2858,11 +2879,9 @@ class LLMEngine:
         if span is not None and span.t_admitted is not None:
             qw = span.queue_wait_s
             if qw is not None and qw >= 0:
-                self._m_phase.labels("queue").observe(qw)
-                self._m_step_phase.labels("queue_wait").observe(qw)
+                self._m_queue_wait.observe(qw)
             pf = max(now - span.t_admitted, 0.0)
-            self._m_phase.labels("prefill").observe(pf)
-            self._m_step_phase.labels("prefill").observe(pf)
+            self._m_prefill.observe(pf)
             self._obs_prefill_perf(span.prompt_len, pf)
             if (span.trace_id is not None and just_first
                     and span.t_enqueued is not None):
@@ -3634,6 +3653,7 @@ class LLMEngine:
                 self.params, self.cfg, jnp.asarray(padded), adm.cache,
                 adm.consumed, min(plen - 1, adm.consumed + c - 1),
                 self._cp_mesh, self._cp_axis)
+            self.phases.mark_chunk()
             adm.consumed += len(part)
             if adm.consumed < plen:
                 return True
@@ -4195,24 +4215,35 @@ class LLMEngine:
                 all_greedy = all(
                     self.slots[i].req.params.temperature <= 0.0
                     for i in active)
+                with ph("dispatch.h2d", child=True):
+                    puts = [jnp.asarray(a) for a in (
+                        tokens, temps, top_ks, top_ps, seeds, poss)]
                 toks_dev, finite_dev, self.cache, qrows_dev = \
                     self._decode_resident(
-                        self.params, jnp.asarray(tokens), self.cache,
-                        jnp.asarray(temps), jnp.asarray(top_ks),
-                        jnp.asarray(top_ps), jnp.asarray(seeds),
-                        jnp.asarray(poss), all_greedy=all_greedy,
+                        self.params, puts[0], self.cache, *puts[1:],
+                        all_greedy=all_greedy,
                         with_quality=self._use_quality)
+                # let go inside the phase, while the program runs, as
+                # the call's own temporaries were (not at the frame's
+                # exit, after the wait, where the device idles)
+                del puts
             elif self._paged:
                 # CoW barrier first (shared write pages get private
                 # copies), then one block-table-driven decode dispatch
                 with ph("cache.cow", child=True):
                     self._cow_step(active)
+                with ph("dispatch.h2d", child=True):
+                    tokens_dev = jnp.asarray(tokens)
+                    bt_dev = self._bt()
                 logits_dev, self.cache = self._decode_paged(
-                    self.params, jnp.asarray(tokens), self.cache,
-                    self._bt())
+                    self.params, tokens_dev, self.cache, bt_dev)
+                del tokens_dev, bt_dev
             else:
+                with ph("dispatch.h2d", child=True):
+                    tokens_dev = jnp.asarray(tokens)
                 logits_dev, self.cache = self._decode(
-                    self.params, jnp.asarray(tokens), self.cache)
+                    self.params, tokens_dev, self.cache)
+                del tokens_dev
         with ph("device"):
             jax.block_until_ready(  # graftlint: disable=step-host-sync
                 toks_dev if resident else logits_dev)
@@ -4221,10 +4252,11 @@ class LLMEngine:
 
         with ph("sample"):
             if resident:
-                toks = np.asarray(toks_dev)
-                finite_host = np.asarray(finite_dev)
-                if qrows_dev is not None:
-                    qrows = np.asarray(qrows_dev)
+                with ph("sample.fetch", child=True):
+                    toks = np.asarray(toks_dev)
+                    finite_host = np.asarray(finite_dev)
+                    if qrows_dev is not None:
+                        qrows = np.asarray(qrows_dev)
             else:
                 # fault injection: poison selected rows with NaN AFTER
                 # the decode — other rows' values are untouched, so
@@ -4250,8 +4282,11 @@ class LLMEngine:
             # the batch keeps decoding — blast-radius isolation for
             # numeric blowups
             if ce.logits_health_check:
-                finite = (finite_host if finite_host is not None
-                          else np.asarray(self._health(logits_dev)))
+                finite = finite_host
+                if finite is None:
+                    finite_dev = self._health(logits_dev)
+                    with ph("sample.fetch", child=True):
+                        finite = np.asarray(finite_dev)
                 sick = [i for i in active if not bool(finite[i])]
                 if sick:
                     for i in sick:
@@ -4261,6 +4296,7 @@ class LLMEngine:
             simple_rows = [i for i in active if simple(self.slots[i])]
             complex_rows = [i for i in active
                             if not simple(self.slots[i])]
+            picked_dev = None
             if resident or not active:
                 pass      # tokens already sampled inside the fused step
             elif simple_rows and all(
@@ -4269,23 +4305,29 @@ class LLMEngine:
                 # all-greedy fast path: one fused argmax, no
                 # sampling-param transfers (the default-traffic hot
                 # path)
-                toks = np.asarray(self._argmax(logits_dev))
+                picked_dev = self._argmax(logits_dev)
             elif simple_rows:
-                temps, top_ks, top_ps, seeds, poss = gather_params(
-                    simple_rows)
                 # runs for EVERY batch containing a simple slot (not
                 # only all-simple ones): a seeded request must sample
                 # from the same stream whether or not a
                 # penalties/logprobs request happens to share the batch
-                toks = np.asarray(self._sample_device(
-                    logits_dev, jnp.asarray(temps), jnp.asarray(top_ks),
-                    jnp.asarray(top_ps), jnp.asarray(seeds),
-                    jnp.asarray(poss)))
-            logits = np.asarray(logits_dev) if complex_rows else None
+                with ph("sample.h2d", child=True):
+                    puts = [jnp.asarray(a)
+                            for a in gather_params(simple_rows)]
+                picked_dev = self._sample_device(logits_dev, *puts)
+                del puts
+            logits = None
+            if picked_dev is not None or complex_rows:
+                with ph("sample.fetch", child=True):
+                    if picked_dev is not None:
+                        toks = np.asarray(picked_dev)
+                    if complex_rows:
+                        logits = np.asarray(logits_dev)
             # the step's device outputs end here, inside a phase: the
             # release of their buffers is host time with an owner (left
             # to the frame's exit it fell between two phases)
             toks_dev = finite_dev = qrows_dev = logits_dev = None
+            picked_dev = None
         if not active:          # every row was sick
             with ph("observe"):
                 self._observe_step("decode", 0)
@@ -4344,8 +4386,7 @@ class LLMEngine:
                 # token, so step wall time IS each stream's
                 # time-per-output-token
                 dt = time.perf_counter() - t_decode0
-                self._m_tpot.observe(dt)
-                # ... and each stream's TPOT sample for its QoS class
+                # each stream's TPOT sample for its QoS class
                 for q in step_qos:
                     self.slo.observe_tpot(q, dt)
                 # the queue-wait admission test's estimate: every step

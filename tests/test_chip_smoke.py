@@ -268,7 +268,7 @@ def test_unknown_device_kind_has_no_roofline():
         max_batch=2, max_seq=64), registry=reg)
     eng.generate([[1, 2, 3]])
     text = reg.render()
-    assert "bigdl_tpu_tpot_seconds" in text
+    assert 'bigdl_tpu_tpot_seconds_count{kind="plain"}' in text
     assert "bigdl_tpu_roofline_util" not in text
     assert "bigdl_tpu_decode_ideal_ms" not in text
     perf = eng.perf_snapshot()

@@ -300,8 +300,10 @@ def test_engine_spans_and_phase_decomposition(model):
     # the step-phase histograms and the dispatch EWMA populate without
     # any trace attached
     summ = eng.registry.summary()
-    for ph in ("queue_wait", "prefill", "dispatch", "device"):
-        key = 'bigdl_tpu_step_phase_seconds{phase="%s"}' % ph
+    for ph, kind in (("queue_wait", "admission"), ("prefill", "admission"),
+                     ("dispatch", "plain"), ("device", "plain")):
+        key = ('bigdl_tpu_step_phase_seconds{phase="%s",kind="%s"}'
+               % (ph, kind))
         assert summ[key]["count"] >= 1, (ph, sorted(summ))
     assert eng.stats_snapshot()["dispatch_overhead_ms"] > 0.0
 

@@ -356,15 +356,21 @@ def test_engine_metrics_end_to_end(model):
     assert s['bigdl_tpu_requests_finished_total{reason="length"}'] == 3
     assert s["bigdl_tpu_tokens_generated_total"] == 15
     assert s["bigdl_tpu_ttft_seconds"]["count"] == 3
-    assert s['bigdl_tpu_request_phase_seconds{phase="queue"}']["count"] \
-        == 3
-    assert s['bigdl_tpu_request_phase_seconds{phase="prefill"}'][
-        "count"] == 3
+    # queue wait and prefill are the phase clock's per-request samples
+    assert s['bigdl_tpu_step_phase_seconds{phase="queue_wait",'
+             'kind="admission"}']["count"] == 3
+    assert s['bigdl_tpu_step_phase_seconds{phase="prefill",'
+             'kind="admission"}']["count"] == 3
+    assert not [k for k in s if k.startswith(
+        "bigdl_tpu_request_phase_seconds") and (
+            'phase="queue"' in k or 'phase="prefill"' in k)]
     assert s['bigdl_tpu_request_phase_seconds{phase="decode"}'][
         "count"] == 3
     # 5 tokens per request -> 4 decode steps each; batching makes the
     # exact step count scheduling-dependent, but >= 4 must have run
-    assert s["bigdl_tpu_tpot_seconds"]["count"] >= 4
+    assert sum(s.get('bigdl_tpu_tpot_seconds{kind="%s"}' % kd,
+                     {"count": 0})["count"]
+               for kd in ("plain", "chunk")) >= 4
     assert s["bigdl_tpu_engine_steps_total"] >= 4
     # drained engine: gauges back to zero
     assert s["bigdl_tpu_slot_occupancy"] == 0
@@ -387,9 +393,13 @@ def test_engine_metrics_end_to_end(model):
             "# TYPE bigdl_tpu_queue_depth gauge",
             "# TYPE bigdl_tpu_kernel_probe_total counter",
             "# TYPE bigdl_tpu_spec_accept_ratio histogram",
-            'bigdl_tpu_request_phase_seconds_bucket{phase="queue",le=',
-            'bigdl_tpu_request_phase_seconds_bucket{phase="prefill",le=',
+            'bigdl_tpu_step_phase_seconds_bucket{phase="queue_wait",'
+            'kind="admission",le=',
+            'bigdl_tpu_step_phase_seconds_bucket{phase="prefill",'
+            'kind="admission",le=',
             'bigdl_tpu_request_phase_seconds_bucket{phase="decode",le=',
+            'bigdl_tpu_tpot_seconds_bucket{kind="plain",le=',
+            'bigdl_tpu_tpot_seconds_bucket{kind="chunk",le=',
     ):
         assert needle in text, needle
 

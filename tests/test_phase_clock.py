@@ -10,6 +10,7 @@ import glob
 import json
 import time
 import urllib.request
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +19,9 @@ import pytest
 
 from bigdl_tpu.observability import MetricsRegistry, RequestTracer
 from bigdl_tpu.observability.disttrace import new_span_id, new_trace_id
+from bigdl_tpu.observability.metrics import STEP_WALL_BUCKETS_S
 from bigdl_tpu.observability.tracing import (DECODE_STEP_PHASES,
+                                             ADMISSION_KIND, STEP_KINDS,
                                              WORKING_STEP_PHASES,
                                              PhaseClock)
 from bigdl_tpu.serving import EngineConfig, LLMEngine, SamplingParams
@@ -39,10 +42,20 @@ def _engine(kind: str, **kw) -> LLMEngine:
                      tracer=RequestTracer(event_log_path=""))
 
 
-def _phase(eng, name: str, field: str = "count") -> float:
-    s = eng.registry.summary().get(
-        'bigdl_tpu_step_phase_seconds{phase="%s"}' % name)
-    return s[field] if s else 0
+def _labelled(summ, series: str, **labels) -> list:
+    """The summary's entries of ``series`` whose labels include
+    ``labels`` (what the benchmark's readers sum)."""
+    want = ['%s="%s"' % kv for kv in labels.items()]
+    return [v for k, v in summ.items() if k.startswith(series + "{")
+            and all(w in k for w in want)]
+
+
+def _phase(eng, name: str, field: str = "count", **labels) -> float:
+    """One label of the step histogram, over its kinds: what the
+    parent's single label held."""
+    return sum(v[field] for v in _labelled(
+        eng.registry.summary(), "bigdl_tpu_step_phase_seconds",
+        phase=name, **labels))
 
 
 def _counter(eng, series: str) -> float:
@@ -104,11 +117,33 @@ def test_host_plus_device_is_the_wall_of_the_steps_that_decoded(driven):
     assert parts <= _phase(eng, "host", "sum")
 
 
+def test_every_step_has_one_kind_and_a_chunk_makes_it_chunk(driven):
+    """At most one chunk a step: the working steps under ``chunk`` are
+    the chunks dispatched, and the walls in bigdl_tpu_tpot_seconds are
+    the steps that decoded, kind for kind."""
+    eng = driven["eng"]
+    chunks = _counter(eng, "bigdl_tpu_prefill_chunks_total")
+    steps = _counter(eng, "bigdl_tpu_engine_steps_total")
+    summ = eng.registry.summary()
+    for phase in WORKING_STEP_PHASES:
+        assert _phase(eng, phase, kind="chunk") == chunks
+        assert _phase(eng, phase, kind="plain") == steps - chunks
+    for kind in STEP_KINDS:
+        wall = summ['bigdl_tpu_tpot_seconds{kind="%s"}' % kind]
+        assert wall["count"] == _phase(eng, "device", kind=kind) > 0
+        assert wall["sum"] == pytest.approx(
+            _phase(eng, "host", "sum", kind=kind)
+            + _phase(eng, "device", "sum", kind=kind))
+    # the first request's chunks ran with no slot decoding yet
+    assert _phase(eng, "device", kind="chunk") < chunks
+
+
 def test_request_phases_count_as_before(driven):
     eng = driven["eng"]
     n = len(PROMPT_LENS)
-    assert _phase(eng, "queue_wait") == n
-    assert _phase(eng, "prefill") == n
+    assert _phase(eng, "queue_wait", kind=ADMISSION_KIND) == n
+    assert _phase(eng, "prefill", kind=ADMISSION_KIND) == n
+    assert _phase(eng, "queue_wait") == _phase(eng, "prefill") == n
     assert _counter(eng, "bigdl_tpu_admissions_total") == n
     # every request decodes 5 tokens after its admission's first
     assert _counter(eng, "bigdl_tpu_tokens_generated_total") == 6 * n
@@ -209,11 +244,27 @@ class _Spans:
         return Span()
 
 
-def test_clock_names_spans_and_sums_children_into_derived_labels():
+def _hand_clock(spans=None):
+    """A clock over a registry of its own: ``(registry, clock)``."""
     reg = MetricsRegistry()
-    hist = reg.histogram("t_phase_seconds", "x", labelnames=("phase",))
+    clock = PhaseClock(
+        reg.histogram("t_phase_seconds", "x",
+                      labelnames=("phase", "kind")),
+        reg.histogram("t_wall_seconds", "x", labelnames=("kind",),
+                      buckets=STEP_WALL_BUCKETS_S),
+        spans or _Spans())
+    return reg, clock
+
+
+def _hand_phase(reg, name: str, kind: str) -> dict:
+    return reg.summary().get(
+        't_phase_seconds{phase="%s",kind="%s"}' % (name, kind),
+        {"count": 0, "sum": 0.0})
+
+
+def test_clock_names_spans_and_sums_children_into_derived_labels():
     spans = _Spans()
-    clock = PhaseClock(hist, spans)
+    reg, clock = _hand_clock(spans)
     clock.begin()
     with clock.phase("admission"):
         with clock.phase("cache.radix_match", child=True):
@@ -232,23 +283,164 @@ def test_clock_names_spans_and_sums_children_into_derived_labels():
         "admission") + clock.seconds("dispatch")
     assert clock.seconds("admission.wait") == 0.0     # trace-only
     clock.end(worked=True)
-    summ = reg.summary()
     for name in WORKING_STEP_PHASES + DECODE_STEP_PHASES:
-        assert summ['t_phase_seconds{phase="%s"}' % name]["count"] == 1
-    host = summ['t_phase_seconds{phase="host"}']["sum"]
+        assert _hand_phase(reg, name, "plain")["count"] == 1
+    host = _hand_phase(reg, "host", "plain")["sum"]
     assert host >= clock.seconds("admission") + clock.seconds("dispatch")
     # the next step starts from nothing; an idle one observes nothing
     clock.begin()
     assert clock.seconds("cache") == 0.0
     clock.end(worked=False)
-    assert reg.summary()['t_phase_seconds{phase="sweep"}']["count"] == 1
+    assert _hand_phase(reg, "sweep", "plain")["count"] == 1
+
+
+class _Ticks:
+    """The clock's clock, by hand: time passes only where a test says."""
+
+    def __init__(self):
+        self.now = 100.0
+
+    def perf_counter(self) -> float:
+        return self.now
+
+    def sleep(self, seconds: float) -> None:
+        self.now += seconds
+
+
+@pytest.fixture
+def ticks(monkeypatch):
+    from bigdl_tpu.observability import tracing
+
+    fake = _Ticks()
+    monkeypatch.setattr(tracing, "time", fake)
+    return fake
+
+
+def _hand_step(clock, ticks, chunk: bool, decode: bool = True) -> None:
+    """One step driven by hand, the way ``LLMEngine.step`` drives it:
+    6 ms of admission with a chunk (1 ms of puts, 2 ms of blocked fetch),
+    3 ms without; 1 ms of puts, 2 ms of device and 1 ms of fetch in a
+    decode."""
+    clock.begin()
+    with clock.phase("sweep"):
+        pass
+    with clock.phase("admission"):
+        ticks.sleep(0.003)              # ahead of the decode's start
+        if chunk:
+            with clock.phase("admission.h2d", child=True):
+                ticks.sleep(0.001)
+            clock.mark_chunk()
+            with clock.phase("admission.wait", child=True):
+                ticks.sleep(0.002)
+    if decode:
+        with clock.phase("dispatch"):
+            with clock.phase("dispatch.h2d", child=True):
+                ticks.sleep(0.001)
+        with clock.phase("device"):
+            ticks.sleep(0.002)
+        with clock.phase("sample"):
+            with clock.phase("sample.fetch", child=True):
+                ticks.sleep(0.001)
+        with clock.phase("emit"):
+            pass
+    with clock.phase("observe"):
+        pass
+    clock.end(worked=True)
+
+
+def test_a_step_marked_chunk_lands_under_chunk_and_the_next_under_plain(
+        ticks):
+    reg, clock = _hand_clock()
+    _hand_step(clock, ticks, chunk=True)
+    for name in WORKING_STEP_PHASES + DECODE_STEP_PHASES:
+        assert _hand_phase(reg, name, "chunk")["count"] == 1, name
+        assert _hand_phase(reg, name, "plain")["count"] == 0, name
+    _hand_step(clock, ticks, chunk=False)       # begin() forgot the mark
+    _hand_step(clock, ticks, chunk=True, decode=False)
+    for name in DECODE_STEP_PHASES:
+        assert _hand_phase(reg, name, "chunk")["count"] == 1, name
+        assert _hand_phase(reg, name, "plain")["count"] == 1, name
+    for name in WORKING_STEP_PHASES:
+        assert _hand_phase(reg, name, "chunk")["count"] == 2, name
+        assert _hand_phase(reg, name, "plain")["count"] == 1, name
+    summ = reg.summary()
+    assert summ['t_wall_seconds{kind="chunk"}']["count"] == 1
+    assert summ['t_wall_seconds{kind="plain"}']["count"] == 1
+
+
+def test_h2d_and_fetch_are_the_sums_of_their_child_spans(ticks):
+    reg, clock = _hand_clock()
+    _hand_step(clock, ticks, chunk=True)
+    _hand_step(clock, ticks, chunk=False)
+    # admission.h2d + dispatch.h2d; admission.wait + sample.fetch
+    assert _hand_phase(reg, "h2d", "chunk")["sum"] == pytest.approx(0.002)
+    assert _hand_phase(reg, "fetch", "chunk")["sum"] == pytest.approx(0.003)
+    assert _hand_phase(reg, "h2d", "plain")["sum"] == pytest.approx(0.001)
+    assert _hand_phase(reg, "fetch", "plain")["sum"] == pytest.approx(0.001)
+    # the children stay inside their parents' totals
+    assert _hand_phase(reg, "admission", "chunk")["sum"] \
+        == pytest.approx(0.006)
+    assert _hand_phase(reg, "dispatch", "chunk")["sum"] \
+        == pytest.approx(0.001)
+
+
+def test_step_wall_is_from_begin_to_end_not_from_the_decodes_start(ticks):
+    reg, clock = _hand_clock()
+    _hand_step(clock, ticks, chunk=True)
+    _hand_step(clock, ticks, chunk=False)
+    _hand_step(clock, ticks, chunk=True, decode=False)     # no wall
+    summ = reg.summary()
+    # the decode alone (dispatch, device, sample) is 4 ms of each
+    assert summ['t_wall_seconds{kind="chunk"}']["sum"] \
+        == pytest.approx(0.006 + 0.004)
+    assert summ['t_wall_seconds{kind="plain"}']["sum"] \
+        == pytest.approx(0.003 + 0.004)
+    for kind in STEP_KINDS:
+        wall = summ['t_wall_seconds{kind="%s"}' % kind]
+        assert wall["count"] == 1
+        assert wall["sum"] == pytest.approx(
+            _hand_phase(reg, "host", kind)["sum"]
+            + _hand_phase(reg, "device", kind)["sum"])
+        assert _hand_phase(reg, "device", kind)["sum"] \
+            == pytest.approx(0.002)
+
+
+def test_the_fine_buckets_keep_a_percentile_within_three_percent(
+        monkeypatch):
+    """Two clusters, the plain steps and the chunk-carrying ones of a
+    byte-level cell: the 95th percentile interpolated inside its bucket,
+    as the benchmark's reader does, against the exact one."""
+    monkeypatch.syspath_prepend(
+        str(Path(__file__).resolve().parents[1] / "benchmark"))
+    from harness import promtext
+
+    b = STEP_WALL_BUCKETS_S
+    inner = [x for x in b if 0.004 <= x <= 1.0]
+    assert b[3] <= 0.004 and b[-6] == 1.0
+    assert max(y / x for x, y in zip(inner, inner[1:])) <= 1.06
+    rng = np.random.default_rng(41)
+    # (no share puts a tested percentile in the gap between the clusters,
+    # where the exact one is itself an interpolation across the gap)
+    for chunk_share in (0.03, 0.12, 0.4):
+        n = 4000
+        n_chunk = int(n * chunk_share)
+        sample = np.concatenate([rng.uniform(0.024, 0.029, n - n_chunk),
+                                 rng.uniform(0.200, 0.210, n_chunk)])
+        reg = MetricsRegistry()
+        h = reg.histogram("w_seconds", "x", buckets=b)
+        for v in sample:
+            h.observe(v)
+        end = promtext.parse(reg.render())
+        for q in (0.5, 0.9, 0.95, 0.99):
+            got = promtext.histogram_quantile({}, end, "w_seconds", q)
+            assert got == pytest.approx(
+                float(np.percentile(sample, 100 * q)), rel=0.03), (
+                    chunk_share, q)
 
 
 def test_clock_closes_its_span_when_the_phase_raises():
-    reg = MetricsRegistry()
     spans = _Spans()
-    clock = PhaseClock(reg.histogram("t2_phase_seconds", "x",
-                                     labelnames=("phase",)), spans)
+    _, clock = _hand_clock(spans)
     clock.begin()
     with pytest.raises(KeyError):
         with clock.phase("sweep"):
@@ -293,7 +485,8 @@ def test_a_profiler_capture_shows_the_phases_on_the_engine_thread(tmp_path):
             evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
                    for e in line.events
                    if e.name.startswith(("engine.", "cache.", "observe.",
-                                         "admission."))]
+                                         "admission.", "dispatch.",
+                                         "sample."))]
             if evs:
                 threads.append(evs)
     assert len(threads) == 1, "the engine's spans lie on one thread"
@@ -305,12 +498,15 @@ def test_a_profiler_capture_shows_the_phases_on_the_engine_thread(tmp_path):
         assert a[2] <= b[1], (a, b)             # no two overlap
     parents = {"cache": ("engine.admission", "engine.dispatch"),
                "observe": ("engine.observe",),
-               "admission": ("engine.admission",)}
+               "admission": ("engine.admission",),
+               "dispatch": ("engine.dispatch",),
+               "sample": ("engine.sample",)}
     children = [e for e in evs if e[0] not in top]
     assert {e[0] for e in children} >= {
         "cache.radix_match", "cache.page_alloc", "cache.block_table",
         "cache.cow", "observe.slo", "observe.spans", "observe.flight",
-        "observe.gauges", "observe.perf", "observe.probe"}
+        "observe.gauges", "observe.perf", "observe.probe",
+        "dispatch.h2d", "admission.h2d", "sample.fetch"}
     for name, start, end in children:
         inside = [p for p in tops
                   if p[0] in parents[name.partition(".")[0]]
