@@ -69,7 +69,7 @@ class RuntimeFlags:
     default_max_seq: int = 2048
     # paged KV cache: positions per arena page. 0 = off (per-slot slab);
     # otherwise a power of two that divides max_seq. 128 matches the TPU
-    # lane tile (one page == one S-block in the paged Pallas kernel);
+    # lane tile (a run of whole pages is the paged Pallas kernel's S-block);
     # smaller values are legal on the XLA fallback path (tests use 16).
     kv_page_size: int = 0
     # paged KV cache: total physical pages in the arena. 0 = auto-size

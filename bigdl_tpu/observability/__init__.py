@@ -97,6 +97,9 @@ bigdl_tpu_prefill_tokens_total{kind}        LLMEngine._admission_step
 bigdl_tpu_decode_attn_blocks_total{kind}    LLMEngine._decode_step (slab K/V
                                             cache): decode_attention's
                                             blocks_read / slab_blocks
+bigdl_tpu_paged_attn_pages_total{kind}      LLMEngine._decode_step (paged K/V
+                                            cache): paged_decode_attention's
+                                            pages_read / every table column
 bigdl_tpu_stream_delivery_seconds           api_server stream handler
 bigdl_tpu_ttft_seconds                      RequestSpan.ttft_s
 bigdl_tpu_tpot_seconds{kind}                tracing.PhaseClock.end: wall of a

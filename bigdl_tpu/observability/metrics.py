@@ -31,6 +31,7 @@ module and observability/__init__ for the field mapping):
     bigdl_tpu_prefill_chunks_total                               counter
     bigdl_tpu_prefill_tokens_total{kind=prompt|padding}          counter
     bigdl_tpu_decode_attn_blocks_total{kind=read|slab}           counter
+    bigdl_tpu_paged_attn_pages_total{kind=read|table}            counter
     bigdl_tpu_stream_delivery_seconds (serving/api_server.py)    histogram
     bigdl_tpu_ttft_seconds                                       histogram
     bigdl_tpu_tpot_seconds{kind=plain|chunk} (wall of a step that

@@ -303,9 +303,10 @@ def sdp_attention_paged(
     """Causal SDP reading K/V through a block table (paged cache, the
     layout of `ops/paged.py`).
 
-    Decode (Sq=1) on TPU dispatches to the paged Pallas kernel, whose
-    BlockSpec index_maps dereference the prefetched layer index and
-    block table — neither the layer nor the gather materializes.
+    Decode (Sq=1) on TPU dispatches to the paged Pallas kernel, which
+    copies the pages the prefetched layer index and block table name
+    out of the arena where it lies, the live ones only — neither the
+    layer nor the gather materializes.
     Everywhere else the fallback runs: ONE XLA gather over
     ``stack[layer, block_tables]`` reassembles the dense
     ``[B, NP * ps, Hkv, D]`` view (shape-identical to the slab

@@ -18,9 +18,10 @@ head count, and this module is the only one that knows it:
 - codes ``[L * H_kv, P, page_size, D]``: a plane a (layer, head), pages
   of ``[page_size, D]``, positions in the sublanes and ``D`` in the
   lanes. A head of a page is ONE contiguous window of the array as it
-  lies, which is the kernel's block, whole tiles for every dtype; a
-  layer is ``H_kv`` consecutive planes (`code_plane`), a split of the
-  major axis, which is free. Two things were learned by compiling for
+  lies, which is what the kernel copies (one DMA a live page: its
+  heads' windows), whole tiles for every dtype; a layer is ``H_kv``
+  consecutive planes (`code_plane`), a split of the major axis, which
+  is free. Two things were learned by compiling for
   the v5e (tests/test_aot_tpu.py; PERF.md 6, PR 40). With one head's
   ``D`` in the lanes a gather of whole pages (seeding, copy-on-write,
   export) moves those pages; with ``H_kv * D`` in the lanes XLA splits

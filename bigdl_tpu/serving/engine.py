@@ -80,6 +80,7 @@ from bigdl_tpu.ops.kvcache import (SNAPSHOT_REFUSAL, KVCache, cache_nbytes,
                                    kv_cache_bytes, publish_kv_cache_bytes,
                                    resolve_kv_cache_dtype)
 from bigdl_tpu.ops.pallas.decode_attention import blocks_read, slab_blocks
+from bigdl_tpu.ops.pallas.paged_decode_attention import pages_read
 from bigdl_tpu.ops.paged import (NULL_PAGE, cow_copy_pages,
                                  gather_pages_dense, paged_cache_bytes,
                                  publish_paged_cache_bytes, splice_pages)
@@ -1032,6 +1033,15 @@ class LLMEngine:
             "each live slot's position, the first of an empty slot), "
             "kind=slab those the whole slab holds.",
             labelnames=("kind",))
+        if self._paged:
+            self._m_paged_pages = m.counter(
+                "bigdl_tpu_paged_attn_pages_total",
+                "Pages of K behind the block tables in a decode step, "
+                "all layers: kind=read those the block-table kernel "
+                "copies (a live slot's, up to the one that holds its "
+                "position; none of an empty slot), kind=table every "
+                "column of every table.",
+                labelnames=("kind",))
         # (layers, blocks of one layer's slab, S, kv heads) of a K/V slab
         # cache; None for a paged or a latent one, whose kernels have
         # block rules of their own
@@ -4200,6 +4210,15 @@ class LLMEngine:
                 self._m_attn_blocks.labels("read").inc(
                     layers * blocks_read(at, s_max, hkv))
                 self._m_attn_blocks.labels("slab").inc(layers * slab)
+            elif self._paged:
+                # what the block-table kernel copies this step, by its
+                # own rule
+                layers = self.cache.num_layers
+                self._m_paged_pages.labels("read").inc(layers * pages_read(
+                    [d - 1 for d in depths], self.cache.page_size,
+                    self._pages_per_seq))
+                self._m_paged_pages.labels("table").inc(
+                    layers * len(tokens) * self._pages_per_seq)
 
             # resident fast path: when every active slot is
             # device-samplable and no fault clause is live (poison_rows

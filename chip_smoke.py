@@ -214,9 +214,10 @@ def phase_serve(args, model_dir: str, paged: bool) -> dict:
     phase = "serve_paged_int8" if paged else "serve"
     log = os.path.join(LOG_DIR, f"{phase}.log")
     max_seq = 256 if args.tiny else 2048
-    # one page == one S-block of the paged Pallas kernel (128 lanes):
-    # the dispatch rule sends smaller pages through the XLA gather, so
-    # a 16-position page would leave the block-table kernel unrun
+    # a page's positions are whole lane tiles (128) of the paged Pallas
+    # kernel's scores: the dispatch rule sends smaller pages through the
+    # XLA gather, so a 16-position page would leave the block-table
+    # kernel unrun
     page = 16 if args.tiny else 128
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))

@@ -348,16 +348,49 @@ def test_decode_attention_over_stack_in_place(v5e, aot_flags, layers, b, s,
 def test_paged_decode_attention_compiles(v5e, aot_flags, b, kvdt):
     """The block-table kernel (ops/pallas/paged_decode_attention) at
     Mistral-7B GQA 32/8, hd 128, 128-position pages over a max_seq-2048
-    arena, on the cache's whole stack as `ops/paged.py` keeps it: K/V
-    index_maps dereference the prefetched layer index and block table,
-    and nothing of the arena is moved on the way in."""
+    arena, on the cache's whole stack as `ops/paged.py` keeps it: the
+    kernel copies the pages the prefetched layer index and block table
+    name out of the arena where it lies, and nothing of the arena is
+    moved on the way in."""
+    _compile_paged_kernel(v5e, b, kvdt, layers=4, hkv=8, np_=16,
+                          pages=8 * 16 + 1)
+
+
+def test_paged_decode_attention_compiles_at_the_docqa_cell(v5e, aot_flags):
+    """The same at the docqa cell's own geometry (ChatGLM2's 2 KV
+    groups, int8 pages, all 28 layers' stack of 1280 pages, 32 slots
+    behind 64 table columns): 32 grid steps where a step a (slot, head,
+    column) made 4,096 (PERF.md 6, PR 42), and still nothing over the
+    arena."""
+    import math
+
+    from bigdl_tpu.ops.pallas.paged_decode_attention import (
+        paged_attention_grid)
+
+    cache = _compile_paged_kernel(v5e, 32, "int8", layers=28, hkv=2,
+                                  np_=64, pages=1280)
+    grid = paged_attention_grid(32, 64, 2, cache.k)
+    assert grid == (32, 1) and math.prod(grid) <= 512
+
+
+def test_paged_decode_attention_compiles_in_head_groups(v5e, aot_flags):
+    """32 KV heads of int8 pages: a grid step takes 16 of them, and the
+    scales' rows at the group's offset."""
+    from bigdl_tpu.ops.pallas.paged_decode_attention import (
+        paged_attention_grid)
+
+    cache = _compile_paged_kernel(v5e, 4, "int8", layers=2, hkv=32,
+                                  np_=16, pages=65)
+    assert paged_attention_grid(4, 16, 32, cache.k) == (4, 2)
+
+
+def _compile_paged_kernel(v5e, b, kvdt, layers, hkv, np_, pages):
     from bigdl_tpu.ops.pallas.paged_decode_attention import (
         paged_decode_attention_pallas)
     from bigdl_tpu.ops.paged import init_paged_cache
 
     dev = v5e.devices[0]
-    layers, h, hkv, hd, ps, np_ = 4, 32, 8, 128, 128, 16
-    pages = 8 * np_ + 1
+    h, hd, ps = 32, 128, 128
     q = jax.ShapeDtypeStruct((b, 1, h, hd), jnp.bfloat16)
     cache = _sds(jax.eval_shape(lambda: init_paged_cache(
         layers, pages, ps, hkv, hd, b, kv_cache_dtype={
@@ -377,6 +410,7 @@ def test_paged_decode_attention_compiles(v5e, aot_flags, b, kvdt):
         (hkv,) + cache.k.shape[1:], cache.k.shape[1:]} | {
             p[1:] for p in planes})
     assert comp.memory_analysis().temp_size_in_bytes < 4 << 20
+    return cache
 
 
 def _arena_shapes(layers, pages, ps, hkv, hd):

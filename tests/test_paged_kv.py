@@ -16,6 +16,8 @@ The load-bearing claims of the paged path, each pinned here:
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -353,6 +355,208 @@ def test_block_table_kernel_agrees_with_the_xla_gather(kv, hkv):
         assert np.linalg.norm(got - want) <= 0.01 * np.linalg.norm(want)
 
 
+@functools.lru_cache(maxsize=1)
+def _random_arena(kv, hkv, pages, layers=2, ps=128, hd=128):
+    """An arena of the layout at rest with seeded codes and scales on
+    every page but the null one, which holds the largest codes: what a
+    masked or skipped position would show if it were read. The last one
+    made is kept: the cases of a layout follow each other."""
+    import jax.numpy as jnp
+
+    from bigdl_tpu.ops.paged import init_paged_cache
+
+    rng = np.random.default_rng(5)
+    cache = init_paged_cache(layers, pages, ps, hkv, hd, 1,
+                             kv_cache_dtype=kv)
+
+    def codes(x):
+        if jnp.issubdtype(x.dtype, jnp.integer):
+            lim = 7 if x.dtype == jnp.int4 else 127
+            a = rng.integers(-lim, lim + 1, x.shape).astype(np.int8)
+            a[:, NULL_PAGE] = lim
+        else:
+            a = rng.standard_normal(x.shape).astype(np.float32)
+            a[:, NULL_PAGE] = 50.0
+        return jnp.asarray(a).astype(x.dtype)
+
+    def scales(x):
+        if x is None:
+            return None
+        a = rng.uniform(0.005, 0.02, x.shape).astype(np.float32)
+        a[:, NULL_PAGE] = 1.0
+        return jnp.asarray(a)
+
+    return (codes(cache.k), codes(cache.v), scales(cache.k_scale),
+            scales(cache.v_scale))
+
+
+def _live_tables(rng, positions, np_, ps, pages, empty=(), shared=None):
+    """Block tables in permuted order for slots at `positions`: a page a
+    live column, the null page behind it; `empty` slots keep a null
+    table; `shared` = (a, b) gives slot b slot a's first page."""
+    free = list(1 + rng.permutation(pages - 1))
+    bt = np.full((len(positions), np_), NULL_PAGE, np.int32)
+    for i, p in enumerate(positions):
+        if i not in empty:
+            for j in range(p // ps + 1):
+                bt[i, j] = free.pop()
+    if shared is not None:
+        bt[shared[1], 0] = bt[shared[0], 0]
+    return bt
+
+
+def _kernel_against_gather(kv, hkv, positions, empty=(), shared=None,
+                           np_=11, pages=32, h=32):
+    """The kernel (interpret mode) and the XLA gather over layer 1 of
+    one seeded arena, slots at `positions(pages a run, page size, table
+    columns)`: the empty slots' rows are zeros whatever the null page
+    holds, the live ones agree to 1 % of the norm."""
+    import jax.numpy as jnp
+
+    from bigdl_tpu.ops.attention import sdp_attention_paged
+    from bigdl_tpu.ops.pallas.paged_decode_attention import (_page_bytes,
+                                                             run_block)
+
+    ps, hd = 128, 128
+    k, v, ks, vs = _random_arena(kv, hkv, pages)
+    hh, n = run_block(hkv, np_, _page_bytes(k))
+    assert hh == hkv
+    pos = positions(n, ps, np_)
+    rng = np.random.default_rng(len(pos) + sum(pos))
+    bt = _live_tables(rng, pos, np_, ps, pages, empty, shared)
+    live = [i for i in range(len(pos)) if i not in empty]
+    q = jnp.asarray(rng.standard_normal((len(pos), 1, h, hd)), jnp.bfloat16)
+    got, want = (np.asarray(sdp_attention_paged(
+        q, k, v, jnp.asarray(bt), jnp.asarray(pos, jnp.int32), hkv,
+        backend=be, k_scale=ks, v_scale=vs,
+        layer=jnp.asarray(1, jnp.int32)), np.float32)
+        for be in ("pallas", "xla"))
+    assert got.shape == want.shape == (len(pos), 1, h, hd)
+    assert np.isfinite(got).all()
+    assert not got[list(empty)].any()
+    if live:
+        assert np.linalg.norm(got[live] - want[live]) <= 0.01 * (
+            np.linalg.norm(want[live]))
+    return n
+
+
+# four slots behind 11 table columns (no multiple of 2, 4 or 8 pages a
+# run): (name, positions, empty slots, (a, b): b shares a's first page)
+_KERNEL_CASES = [
+    # a page's last and first row, and a multi-page run's
+    ("page_edges", lambda n, ps, np_: [
+        3 * ps - 1, 3 * ps, n * ps - 1, min(n, np_ - 1) * ps], (), None),
+    # an empty slot (null table, position 0) between live ones
+    ("empty_between", lambda n, ps, np_: [200, 0, 5 * ps + 7, 0],
+     (1, 3), None),
+    ("every_slot_empty", lambda n, ps, np_: [0, 0, 0, 0],
+     (0, 1, 2, 3), None),
+    # every column of a table the pages-a-run does not divide
+    ("full_table", lambda n, ps, np_: [np_ * ps - 1, 17,
+                                       (np_ - 1) * ps, ps], (), None),
+    ("shared_page", lambda n, ps, np_: [2 * ps + 5, 6 * ps + 100,
+                                        ps - 1, 0], (3,), (0, 1)),
+]
+
+
+@pytest.mark.parametrize("case", _KERNEL_CASES, ids=lambda c: c[0])
+@pytest.mark.parametrize("kv,hkv,pages_a_run", [
+    ("int8", 2, 8),          # the docqa cell's: G = 16, both heads a step
+    ("bf16", 2, 4),
+    ("fp8_e5m2", 8, 2),      # G = 4, all 8 heads a step
+    ("int4", 8, 4),
+])
+def test_block_table_kernel_follows_what_is_live(kv, hkv, pages_a_run, case):
+    """The kernel's copies, semaphores and its loop over a slot's live
+    runs, run as written by interpret mode, against the XLA gather:
+    positions at the edges of a page and of a run, empty slots, a full
+    table, a page two slots share; the layouts of
+    `test_block_table_kernel_agrees_with_the_xla_gather` that take 8, 4
+    and 2 pages a run (1 and the whole table are that test's)."""
+    _, positions, empty, shared = case
+    assert _kernel_against_gather(kv, hkv, positions, empty,
+                                  shared) == pages_a_run
+
+
+@pytest.mark.parametrize("kv,hkv", [("int8", 2), ("bf16", 8)])
+def test_block_table_kernel_serves_one_slot(kv, hkv):
+    """The benchmark's after-window check (a): one slot, pages in
+    seeded order."""
+    _kernel_against_gather(kv, hkv, lambda n, ps, np_: [4 * ps + 39])
+
+
+def test_block_table_kernel_takes_head_groups_past_the_budget():
+    """32 KV heads of int8 pages outgrow a run's budget: a grid step
+    takes 16 of them (one page a run), the scales' rows by the group's
+    offset, and the result is still the XLA gather's."""
+    import jax.numpy as jnp
+
+    from bigdl_tpu.ops.attention import sdp_attention_paged
+    from bigdl_tpu.ops.pallas.paged_decode_attention import (_page_bytes,
+                                                             run_block)
+
+    hkv, ps, hd, np_, pages = 32, 128, 128, 3, 8
+    rng = np.random.default_rng(6)
+    k, v, ks, vs = _random_arena("int8", hkv, pages, layers=1)
+    assert run_block(hkv, np_, _page_bytes(k)) == (16, 1)
+    pos = [2 * ps + 3, 0, ps - 1]
+    bt = _live_tables(rng, pos, np_, ps, pages, empty=(1,))
+    q = jnp.asarray(rng.standard_normal((3, 1, 64, hd)), jnp.bfloat16)
+    got, want = (np.asarray(sdp_attention_paged(
+        q, k, v, jnp.asarray(bt), jnp.asarray(pos, jnp.int32), hkv,
+        backend=be, k_scale=ks, v_scale=vs), np.float32)
+        for be in ("pallas", "xla"))
+    assert not got[1].any()
+    live = [0, 2]
+    assert np.linalg.norm(got[live] - want[live]) <= 0.01 * (
+        np.linalg.norm(want[live]))
+
+
+def test_pages_read_is_the_kernels_rule():
+    """`pages_read`: a live slot's pages up to the one that holds its
+    position, nothing of an empty slot (position below 0), never more
+    than the table has."""
+    from bigdl_tpu.ops.pallas.paged_decode_attention import pages_read
+
+    ps, np_ = 128, 64
+    assert pages_read([], ps, np_) == 0
+    assert pages_read([-1, -1, -1], ps, np_) == 0
+    assert pages_read([0], ps, np_) == 1
+    # a position on a page's boundary: its last row, its first
+    assert pages_read([ps - 1], ps, np_) == 1
+    assert pages_read([ps], ps, np_) == 2
+    assert pages_read([-1, 3 * ps - 1, -1, 3 * ps], ps, np_) == 3 + 4
+    # a full table, and a position past it
+    assert pages_read([np_ * ps - 1] * 4, ps, np_) == 4 * np_
+    assert pages_read([np_ * ps + 5], ps, np_) == np_
+
+
+@pytest.mark.parametrize("kv,hkv,b,np_,want", [
+    ("int8", 2, 32, 64, (32, 1)),      # the docqa cell: 32 steps, 8 pages
+    ("bf16", 8, 8, 16, (8, 1)),
+    ("int4", 8, 8, 16, (8, 1)),
+    ("bf16", 32, 4, 16, (4, 4)),       # a page's heads outgrow a run
+])
+def test_block_table_kernel_grid_is_slots_by_head_groups(kv, hkv, b, np_,
+                                                         want):
+    """The grid is (slots, groups of KV heads) whatever the table's
+    width: a table column is no grid step, so none is spent on a column
+    past a slot's position."""
+    import jax
+
+    from bigdl_tpu.ops.paged import init_paged_cache
+    from bigdl_tpu.ops.pallas.paged_decode_attention import (
+        _RUN_BYTES, _page_bytes, paged_attention_grid, run_block)
+
+    k = jax.eval_shape(lambda: init_paged_cache(
+        1, 4, 128, hkv, 128, b, kv_cache_dtype=kv)).k
+    assert paged_attention_grid(b, np_, hkv, k) == want
+    assert paged_attention_grid(b, 4 * np_, hkv, k) == want
+    hh, n = run_block(hkv, np_, _page_bytes(k))
+    assert hkv % hh == 0 and 1 <= n <= np_
+    assert hh * n * _page_bytes(k) <= _RUN_BYTES
+
+
 # ---------------------------------------------------------------------------
 # engine-level byte-identity (paged vs slab)
 
@@ -388,8 +592,10 @@ def _mk_engine(kv_dtype=None, **kw):
                prefill_chunk=8, prefix_cache_entries=0)
     if kv_dtype:
         cfg["kv_cache_dtype"] = kv_dtype
+    registry = kw.pop("registry", None)
     cfg.update(kw)
-    return LLMEngine(tiny_random_model(seed=0), EngineConfig(**cfg))
+    return LLMEngine(tiny_random_model(seed=0), EngineConfig(**cfg),
+                     registry=registry)
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "int4"])
@@ -426,6 +632,50 @@ def test_prefix_sharing_stays_byte_identical_and_hits():
     assert snap["radix"]["hits"] == 3
     assert snap["radix"]["hit_tokens"] == 3 * 32
     assert snap["pool_exhausted_total"] == 0
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "slab"])
+def test_metrics_carry_the_pages_counter_only_for_a_paged_cache(paged):
+    """Every decode step of a paged engine adds to
+    `bigdl_tpu_paged_attn_pages_total` what the block-table kernel's own
+    rule gives for the live slots' positions (`pages_read`, times
+    layers) beside every column of every table; `/metrics` of a slab
+    engine has no such series (and a paged one none of the slab's)."""
+    from bigdl_tpu.observability.metrics import MetricsRegistry
+    from bigdl_tpu.ops.pallas.paged_decode_attention import pages_read
+    from bigdl_tpu.serving import SamplingParams
+
+    ps = 16
+    # a registry of its own: the default one is every engine's
+    eng = _mk_engine(registry=MetricsRegistry(),
+                     **(dict(kv_page_size=ps) if paged else {}))
+    eng.add_request("short", [7, 3, 99, 5], SamplingParams(max_tokens=3))
+    eng.add_request("long", list(range(1, 30)), SamplingParams(max_tokens=9))
+    layers, b, np_ = eng.cache.num_layers, 4, 64 // ps
+    steps = 0
+    while eng.has_unfinished() and steps < 100:
+        live = [len(sl.req.prompt_token_ids) + len(sl.generated) - 1
+                for sl in eng.slots if sl.active]
+        settled = not eng.waiting and eng._admitting is None
+        before = ({k: eng._m_paged_pages.labels(k).value
+                   for k in ("read", "table")} if paged else None)
+        eng.step()
+        steps += 1
+        if paged and settled and live:
+            assert (eng._m_paged_pages.labels("read").value - before["read"]
+                    == layers * pages_read(live, ps, np_))
+            assert (eng._m_paged_pages.labels("table").value
+                    - before["table"] == layers * b * np_)
+    text = eng.registry.render()
+    for kind in ("read", "table"):
+        series = 'bigdl_tpu_paged_attn_pages_total{kind="%s"}' % kind
+        assert (series in text) == paged
+    assert ('bigdl_tpu_decode_attn_blocks_total{kind="read"}'
+            in text) != paged
+    if paged:
+        read, table = (eng._m_paged_pages.labels(k).value
+                       for k in ("read", "table"))
+        assert 0 < read < table
 
 
 def test_finish_releases_pages_and_reset_clears_radix():
