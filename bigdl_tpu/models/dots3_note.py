@@ -481,18 +481,20 @@ def _window_chunk(kind, q_nope, q_pe, new, ring, p, w_uk, w_uv):
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
 
 
-def _index_queries(y, c_q, lp, cfg, cos, sin):
+def _index_queries(y, c_q, lp, cfg, cos, sin, interleaved: bool = True):
     """The indexer's projections of the normed `y`: `q_I` `[B, T, Hi,
     Di]`, the new index keys `[B, T, Di]` and the head weights `[B, T,
-    Hi]` float32 (scaled)."""
+    Hi]` float32 (scaled). `interleaved`: the rope channels rotate as
+    pairs (2i, 2i + 1), this family's reading; False rotates them as two
+    halves (i, i + rd / 2), as DeepSeek-V3.2's published indexer does."""
     b, t, _ = y.shape
     hi, di = cfg.index_n_heads, cfg.index_head_dim
     q_i = apply_rope(linear(c_q, lp["index_q_proj"]).reshape(b, t, hi, di),
-                     cos, sin, interleaved=True)
+                     cos, sin, interleaved=interleaved)
     k_i = layer_norm(linear(y, lp["index_k_proj"])[..., :di],
                      lp["index_k_norm"], lp["index_k_norm_bias"],
                      INDEX_NORM_EPS)
-    k_i = apply_rope(k_i, cos, sin, interleaved=True)
+    k_i = apply_rope(k_i, cos, sin, interleaved=interleaved)
     w_i = (linear(y, lp["index_w_proj"])[..., :hi].astype(jnp.float32)
            * (hi ** -0.5 * di ** -0.5))
     return q_i, k_i, w_i

@@ -43,8 +43,9 @@ class FamilyAdapter:
     @property
     def rewindable(self) -> bool:
         """Whether the cache can hold pad tokens past a prompt and be
-        rewound to an earlier position: what the Generator's padding and
-        both kinds of speculation ask. Not a recurrent family (absorbed
+        rewound to an earlier position: what the Generator's padding,
+        both kinds of offline speculation and the serving engine's
+        `speculative_tokens` (a rejected draft's row is disowned) ask. Not a recurrent family (absorbed
         state), and not one whose forward module says `CACHE_REWINDABLE
         = False` (a cache that REDUCES positions as it grows: chunked
         linearized attention). The serving engine batches the latter
@@ -67,6 +68,24 @@ class FamilyAdapter:
     @property
     def new_paged_cache(self) -> Optional[Callable]:
         return self._forward_module("new_paged_cache")
+
+    @property
+    def speculative_depth(self) -> Optional[Callable]:
+        """`cfg -> int`: tokens the family's own draft module (multi-token
+        prediction) runs ahead, for a family that has one; with it the
+        forward module has `forward_hidden` (the forward that also
+        returns the hidden rows) and `mtp_forward` (the module over
+        hidden rows and their next tokens). The serving engine's
+        `speculative_tokens` asks for these."""
+        return self._forward_module("speculative_depth")
+
+    @property
+    def forward_hidden(self) -> Optional[Callable]:
+        return self._forward_module("forward_hidden")
+
+    @property
+    def mtp_forward(self) -> Optional[Callable]:
+        return self._forward_module("mtp_forward")
 
     @property
     def cache_spec(self) -> Optional[Callable]:
@@ -185,6 +204,23 @@ def _register_builtin() -> None:
             prefill=dots3_mod.forward_last_token,
             forward_train=None,
             new_cache=dots3_mod.new_cache,
+        ))
+
+    from bigdl_tpu.models import deepseek_v32 as v32_mod
+
+    # latent attention with learned sparse attention in every layer,
+    # noaux_tc routing over groups, and an MTP module the serving engine
+    # drafts with (speculative_depth); slab only, bf16 planes only
+    register_family(
+        ["DeepseekV32ForCausalLM"],
+        FamilyAdapter(
+            name="deepseek_v32",
+            config_from_hf=v32_mod.DeepseekV32Config.from_hf,
+            convert_params=v32_mod.convert_hf_params,
+            forward=v32_mod.forward,
+            prefill=v32_mod.forward_last_token,
+            forward_train=None,
+            new_cache=v32_mod.new_cache,
         ))
 
     from bigdl_tpu.models import evabyte as evabyte_mod

@@ -115,7 +115,17 @@ bigdl_tpu_requests_finished_total{reason}   LLMEngine._finish
 bigdl_tpu_engine_steps_total                LLMEngine.step
 bigdl_tpu_tokens_generated_total            LLMEngine._emit
 bigdl_tpu_kernel_probe_total{kernel,...}    ops/probing.record_probe_result
-bigdl_tpu_spec_accept_ratio{mode}           speculative._spec_observe
+bigdl_tpu_spec_accept_ratio{mode}           speculative._spec_observe (mode=
+                                            draft | lookup, offline rounds);
+                                            LLMEngine._decode_step (mode=mtp:
+                                            accepted over judged drafts of a
+                                            verify step)
+bigdl_tpu_mtp_drafts_total{outcome}         LLMEngine._decode_step: drafts of
+                                            the family's MTP module a verify
+                                            step judged (n_emit 2 / 1)
+bigdl_tpu_mtp_slot_steps_total{kind}        LLMEngine._decode_step of a
+                                            speculating engine: verify (two
+                                            rows a slot) | plain (one row)
 bigdl_tpu_spec_round_seconds{mode}          speculative._spec_observe
 bigdl_tpu_spec_tokens_total{mode,kind}      speculative._spec_observe
 bigdl_tpu_kv_cache_bytes{dtype,component}   ops/kvcache.publish_kv_cache_bytes

@@ -44,7 +44,9 @@ module and observability/__init__ for the field mapping):
     bigdl_tpu_requests_finished_total{reason=...}                counter
     bigdl_tpu_engine_steps_total / bigdl_tpu_tokens_generated_total
     bigdl_tpu_kernel_probe_total{kernel=...,outcome=...}         counter
-    bigdl_tpu_spec_accept_ratio{mode=draft|lookup}               histogram
+    bigdl_tpu_spec_accept_ratio{mode=draft|lookup|mtp}           histogram
+    bigdl_tpu_mtp_drafts_total{outcome=accepted|rejected}        counter
+    bigdl_tpu_mtp_slot_steps_total{kind=verify|plain}            counter
     bigdl_tpu_spec_round_seconds{mode=...}                       histogram
     bigdl_tpu_spec_tokens_total{mode=...,kind=drafted|accepted}  counter
     bigdl_tpu_requests_quarantined_total{reason=nan_logits|crash_loop}

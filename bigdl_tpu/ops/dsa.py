@@ -97,6 +97,11 @@ def masked_mla_decode_xla(q_c, q_pe, latent_layer, live, scale):
     """Absorbed latent decode attention over the columns `live` `[B, S]`
     marks, on ONE layer `[B, C + R, S]`: fallback and oracle of the
     sparse and the window kernel."""
+    if q_c.ndim == 4:       # R rows a slot, each with its own `live`
+        return jax.vmap(
+            lambda qc, qp, lv: masked_mla_decode_xla(qc, qp, latent_layer,
+                                                     lv, scale),
+            in_axes=1, out_axes=1)(q_c, q_pe, live)
     c = q_c.shape[-1]
     ckv = latent_layer[:, :c, :].astype(jnp.bfloat16)
     kpe = latent_layer[:, c:, :].astype(jnp.bfloat16)
@@ -156,25 +161,32 @@ def _sds(shape, dtype=jnp.bfloat16):
 
 def dsa_index_scores_decode(q_i, w, index, layer, pos, backend=None):
     """Index scores of one decoded row a slot over layer `layer` of the
-    index-key stack `[L, B, Di, S]`: `[B, S]` float32."""
+    index-key stack `[L, B, Di, S]`: `[B, S]` float32. `q_i` `[B, R, Hi,
+    Di]` and `w` `[B, R, Hi]`: the R rows of a verify step at positions
+    `pos .. pos + R - 1`, `[B, R, S]`."""
     from bigdl_tpu.config import target_is_tpu
 
-    b, hi, di = q_i.shape
+    rows = q_i.shape[1] if q_i.ndim == 4 else 0
+    b, hi, di = q_i.shape[0], q_i.shape[-2], q_i.shape[-1]
     s = index.shape[-1]
+    lead = (1, rows) if rows else (1,)
 
     def probe():
         return (lambda q, ww, ix, p: kernels.dsa_index_score_pallas(
             q, ww, ix, p),
-            (_sds((1, hi, di)), _sds((1, hi), jnp.float32),
+            (_sds(lead + (hi, di)), _sds(lead + (hi,), jnp.float32),
              _sds((1, 1, di, s)), _sds((1,), jnp.int32)))
 
     if _kernel_wanted(kernels.INDEX_NAME,
                       kernels.index_score_supported(q_i, index),
-                      (hi, di, s), probe, backend, q_i, index):
+                      (hi, di, s) + ((rows,) if rows else ()), probe,
+                      backend, q_i, index):
         return kernels.dsa_index_score_pallas(
             q_i, w, index, pos, layer=layer, interpret=not target_is_tpu())
     one = lax.dynamic_index_in_dim(index, layer, 0, keepdims=False)
     posv = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
+    if rows:
+        return index_scores_xla(q_i, w, one, posv)
     return index_scores_xla(q_i[:, None], w[:, None], one, posv)[:, 0]
 
 
@@ -184,6 +196,10 @@ def dsa_select_decode(scores, k: int, backend=None):
     the selected positions."""
     from bigdl_tpu.config import target_is_tpu
 
+    if scores.ndim == 3:    # R rows a slot: each row is a selection
+        flat = dsa_select_decode(scores.reshape(-1, scores.shape[-1]), k,
+                                 backend)
+        return flat.reshape(scores.shape)
     s = scores.shape[-1]
 
     def probe():
@@ -206,20 +222,26 @@ def _sweep_supported(q_c, q_pe, latent) -> bool:
 def sparse_mla_decode(q_c, q_pe, latent, layer, pos, sel, scale: float,
                       backend=None):
     """Absorbed decode attention over the selected positions `sel`
-    `[B, S]` of layer `layer` of the latent stack."""
+    `[B, S]` of layer `layer` of the latent stack. `q_c` `[B, R, H, C]`,
+    `q_pe` `[B, R, H, R_]` and `sel` `[B, R, S]`: the R rows of a verify
+    step at positions `pos .. pos + R - 1`."""
     from bigdl_tpu.config import target_is_tpu
 
-    _, h, c = q_c.shape
+    rows = q_c.shape[1] if q_c.ndim == 4 else 0
+    h, c = q_c.shape[-2:]
     r, s = q_pe.shape[-1], latent.shape[-1]
+    lead = (1, rows) if rows else (1,)
 
     def probe():
         return (lambda qc, qp, lat, p, m: kernels.sparse_mla_decode_pallas(
             qc, qp, lat, p, m, (c + r) ** -0.5),
-            (_sds((1, h, c)), _sds((1, h, r)), _sds((1, 1, c + r, s)),
-             _sds((1,), jnp.int32), _sds((1, s), jnp.int32)))
+            (_sds(lead + (h, c)), _sds(lead + (h, r)),
+             _sds((1, 1, c + r, s)), _sds((1,), jnp.int32),
+             _sds(lead + (s,), jnp.int32)))
 
     if _kernel_wanted(kernels.SPARSE_NAME, _sweep_supported(q_c, q_pe, latent),
-                      (h, c, r, s), probe, backend, q_c, latent):
+                      (h, c, r, s) + ((rows,) if rows else ()), probe,
+                      backend, q_c, latent):
         return kernels.sparse_mla_decode_pallas(
             q_c, q_pe, latent, pos, sel, float(scale), layer=layer,
             interpret=not target_is_tpu())

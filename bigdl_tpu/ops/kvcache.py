@@ -534,11 +534,16 @@ def update_latent(stack: jax.Array, layer, new: jax.Array,
         if target_is_tpu():
             # the chip keeps the stack's positions in the lanes; XLA's
             # scatter of columns re-lays the whole stack out, twice
-            if s_new == 1 and stack.shape[-1] % 128 == 0:
+            # (a speculative verify step appends two rows a slot: the
+            # append kernel once a row)
+            if s_new <= 2 and stack.shape[-1] % 128 == 0:
                 from bigdl_tpu.ops.pallas.mla_attention import (
                     latent_append_pallas)
 
-                return latent_append_pallas(stack, layer, new[:, 0], pos)
+                for r in range(s_new):
+                    stack = latent_append_pallas(stack, layer, new[:, r],
+                                                 pos + r)
+                return stack
 
             def one(i, st):
                 return jax.lax.dynamic_update_slice(

@@ -17,10 +17,12 @@ expert, the best `topk_group` groups stay, and the top-k is taken among
 their experts. Weights are the chosen scores, renormalised
 (`norm_topk_prob`) or times `routed_scaling_factor`. With `scoring`
 "sigmoid" the scores are `sigmoid(logits)`, and `noaux_tc` (DeepSeek-V3's
-bias-corrected choice, one routing group) takes the top-k of `scores +
-bias`, a per-expert correction that chooses and does not weigh: the
-weights are the chosen experts' own scores, renormalised
-(`norm_topk_prob`) and times `routed_scaling_factor`.
+bias-corrected choice) takes the top-k of `scores + bias`, a per-expert
+correction that chooses and does not weigh: the weights are the chosen
+experts' own scores, renormalised (`norm_topk_prob`) and times
+`routed_scaling_factor`. Over several groups (`n_group` > 1) a group
+scores the sum of its two largest biased scores, the best `topk_group`
+groups stay and the top-k is taken among their experts.
 
 Two strategies by token count, both one Pallas kernel
 (`ops/pallas/moe_routed.py`), no host sync, no data-dependent shape:
@@ -77,9 +79,19 @@ def route(logits: jax.Array, top_k: int, *, n_group: int = 1,
         raise NotImplementedError(f"scoring_func {scoring!r}")
     n, e = scores.shape
     if method == "noaux_tc":
+        choice = scores + bias.astype(jnp.float32)
         if n_group != 1:
-            raise NotImplementedError("noaux_tc over several groups")
-        _, topi = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+            # DeepSeek-V3's node-limited choice: a group scores the sum
+            # of its two best biased scores, the best `topk_group` groups
+            # stay, and the top-k is taken among their experts
+            per = choice.reshape(n, n_group, e // n_group)
+            group_score = jnp.sum(lax.top_k(per, 2)[0], axis=-1)
+            _, gi = lax.top_k(group_score, topk_group)          # [N, tg]
+            keep = jnp.zeros((n, n_group), bool).at[
+                jnp.arange(n)[:, None], gi].set(True)
+            choice = jnp.where(jnp.repeat(keep, e // n_group, axis=1),
+                               choice, -jnp.inf)
+        _, topi = lax.top_k(choice, top_k)
         topv = jnp.take_along_axis(scores, topi, axis=-1)
         if norm_topk_prob and top_k > 1:
             topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)

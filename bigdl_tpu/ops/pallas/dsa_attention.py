@@ -1,6 +1,8 @@
 """Pallas TPU kernels of learned sparse attention (DeepSeek-V3.2's
 lightning indexer) over a latent cache, and of latent attention over a
-window kept in a ring. Decode only: one query row a slot.
+window kept in a ring. Decode only: one query row a slot, or (index
+score and sparse sweep) the R rows of a speculative verify step folded
+beside the heads, over ONE fetch of the slot's keys.
 
 `dsa_index_score`: the indexer's score of every cached position,
 
@@ -84,6 +86,32 @@ def _index_kernel(layer_ref, pos_ref, q_ref, w_ref, k_ref, out_ref, *, sb):
         out_ref[...] = jnp.full(out_ref.shape, -jnp.inf, out_ref.dtype)
 
 
+def _index_kernel_rows(layer_ref, pos_ref, q_ref, w_ref, k_ref, out_ref, *,
+                       sb, rows, hp):
+    """`_index_kernel` for `rows` query rows a slot folded beside the
+    heads (`[rows * hp, Di]`): the block of keys is fetched once, row i
+    is live up to `pos + i`."""
+    del layer_ref
+    sj = pl.program_id(1)
+    pos = pos_ref[pl.program_id(0)]
+
+    @pl.when(sj * sb <= pos + (rows - 1))
+    def _():
+        r = jnp.maximum(jax.lax.dot_general(
+            q_ref[...], k_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32), 0.0)     # [rows * hp, sb]
+        rw = r * w_ref[...]
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, sb), 1)
+        for i in range(rows):
+            tot = jnp.sum(rw[i * hp:(i + 1) * hp], axis=0, keepdims=True)
+            out_ref[i:i + 1, :] = jnp.where(col <= pos + i - sj * sb, tot,
+                                            -jnp.inf)
+
+    @pl.when(sj * sb > pos + (rows - 1))
+    def _():
+        out_ref[...] = jnp.full(out_ref.shape, -jnp.inf, out_ref.dtype)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def dsa_index_score_pallas(
     q_i: jax.Array,        # [B, Hi, Di] index queries (roped)
@@ -93,7 +121,11 @@ def dsa_index_score_pallas(
     layer=0,
     interpret: bool = False,
 ) -> jax.Array:
-    """`[B, S]` float32 index scores, `-inf` past `pos`."""
+    """`[B, S]` float32 index scores, `-inf` past `pos`. With `q_i` `[B,
+    R, Hi, Di]` and `w` `[B, R, Hi]` (R rows a slot at positions `pos ..
+    pos + R - 1`, one fetch of the slot's keys for all): `[B, R, S]`."""
+    if q_i.ndim == 4:
+        return _index_score_rows(q_i, w, index, pos, layer, interpret)
     b, hi, di = q_i.shape
     s = index.shape[-1]
     sb = _index_block(s)
@@ -133,6 +165,50 @@ def dsa_index_score_pallas(
         interpret=interpret,
     )(lyr, posv, q, wf, index)
     return out[:, 0, :]
+
+
+def _index_score_rows(q_i, w, index, pos, layer, interpret):
+    b, rows, hi, di = q_i.shape
+    s = index.shape[-1]
+    sb = _index_block(s)
+    if not sb or index.shape[-2] != di:
+        raise NotImplementedError(
+            f"index score kernel: index plane {index.shape} against "
+            f"Di={di} is not a geometry it handles")
+    hp = -(-hi // 16) * 16
+    q = jnp.pad(q_i.astype(jnp.bfloat16),
+                ((0, 0), (0, 0), (0, hp - hi), (0, 0)))
+    wf = jnp.pad(w.astype(jnp.float32), ((0, 0), (0, 0), (0, hp - hi)))
+    q = q.reshape(b, rows * hp, di)
+    wf = wf.reshape(b, rows * hp, 1)
+    posv = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def k_index(bi, sj, lyr_ref, pos_ref):
+        last = jnp.minimum(pos_ref[bi] + (rows - 1), s - 1) // sb
+        return (lyr_ref[0], bi, 0, jnp.minimum(sj, last))
+
+    return pl.pallas_call(
+        functools.partial(_index_kernel_rows, sb=sb, rows=rows, hp=hp),
+        name=INDEX_NAME,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, s // sb),
+            in_specs=[
+                pl.BlockSpec((None, rows * hp, di),
+                             lambda bi, sj, *_: (bi, 0, 0)),
+                pl.BlockSpec((None, rows * hp, 1),
+                             lambda bi, sj, *_: (bi, 0, 0)),
+                pl.BlockSpec((None, None, di, sb), k_index),
+            ],
+            out_specs=pl.BlockSpec((None, rows, sb),
+                                   lambda bi, sj, *_: (bi, 0, sj)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, rows, s), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(lyr, posv, q, wf, index)
 
 
 def _select_kernel(s_ref, out_ref, *, k, nbits):
@@ -205,6 +281,36 @@ def _sparse_kernel(layer_ref, pos_ref, qc_ref, qpe_ref, sel_ref, lat_ref,
         def live(shape):
             col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
             return (col <= pos - sj * sb) & (sel_ref[...] != 0)
+
+        sweep_block(qc_ref, qpe_ref, lat_ref, m_ref, l_ref, acc_ref, live,
+                    scale=scale, c=c)
+
+    sweep_finish(sj, ns, out_ref, l_ref, acc_ref)
+
+
+def _sparse_kernel_rows(layer_ref, pos_ref, qc_ref, qpe_ref, sel_ref,
+                        lat_ref, out_ref, m_ref, l_ref, acc_ref, *, scale,
+                        sb, ns, c, rows, hp):
+    """`_sparse_kernel` for `rows` query rows a slot folded beside the
+    heads: one fetch of a latent block serves every row; row i (heads
+    `i * hp ..`) is live up to `pos + i` under ITS selection `sel[i]`."""
+    del layer_ref
+    sj = pl.program_id(1)
+    pos = pos_ref[pl.program_id(0)]
+    sweep_init(sj, m_ref, l_ref, acc_ref)
+
+    @pl.when(sj * sb <= pos + (rows - 1))
+    def _():
+        def live(shape):
+            col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            head = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            out = None
+            for i in range(rows):
+                mine = ((head >= i * hp) & (head < (i + 1) * hp)
+                        & (col <= pos + i - sj * sb)
+                        & (sel_ref[i:i + 1, :] != 0))
+                out = mine if out is None else out | mine
+            return out
 
         sweep_block(qc_ref, qpe_ref, lat_ref, m_ref, l_ref, acc_ref, live,
                     scale=scale, c=c)
@@ -306,11 +412,74 @@ def _sweep_call(kernel, name, q_c, q_pe, latent, pos, layer, sel, last_block,
     return out[:, :h, :]
 
 
+def _sparse_rows(q_c, q_pe, latent, pos, sel, scale, layer, interpret):
+    """The sparse sweep for `q_c` `[B, R, H, C]`, `q_pe` `[B, R, H, R_]`
+    and `sel` `[B, R, S]`: `[B, R, H, C]`."""
+    b, rows, h, c = q_c.shape
+    r = q_pe.shape[-1]
+    s = latent.shape[-1]
+    sb = _s_block(s)
+    if not sb or latent.shape[-2] != c + r:
+        raise NotImplementedError(
+            f"{SPARSE_NAME} kernel: latent {latent.shape} against C={c} "
+            f"R={r} is not a geometry it handles")
+    ns = s // sb
+    hp = -(-h // 16) * 16
+    pad = ((0, 0), (0, 0), (0, hp - h), (0, 0))
+    qc = jnp.pad(q_c.astype(jnp.bfloat16), pad).reshape(b, rows * hp, c)
+    qpe = jnp.pad(q_pe.astype(jnp.bfloat16), pad).reshape(b, rows * hp, r)
+    posv = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def blk(bi, sj, pos_ref):
+        return jnp.minimum(
+            sj, jnp.minimum(pos_ref[bi] + (rows - 1), s - 1) // sb)
+
+    out = pl.pallas_call(
+        functools.partial(_sparse_kernel_rows, scale=scale, sb=sb, ns=ns,
+                          c=c, rows=rows, hp=hp),
+        name=SPARSE_NAME,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, ns),
+            in_specs=[
+                pl.BlockSpec((None, rows * hp, c),
+                             lambda bi, sj, *_: (bi, 0, 0)),
+                pl.BlockSpec((None, rows * hp, r),
+                             lambda bi, sj, *_: (bi, 0, 0)),
+                pl.BlockSpec((None, rows, sb),
+                             lambda bi, sj, lyr_ref, pos_ref: (
+                                 bi, 0, blk(bi, sj, pos_ref))),
+                pl.BlockSpec((None, None, c + r, sb),
+                             lambda bi, sj, lyr_ref, pos_ref: (
+                                 lyr_ref[0], bi, 0, blk(bi, sj, pos_ref))),
+            ],
+            out_specs=pl.BlockSpec((None, rows * hp, c),
+                                   lambda bi, sj, *_: (bi, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((rows * hp, 128), jnp.float32),
+                pltpu.VMEM((rows * hp, 128), jnp.float32),
+                pltpu.VMEM((rows * hp, c), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, rows * hp, c), q_c.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(lyr, posv, qc, qpe, sel.astype(jnp.int32), latent)
+    return out.reshape(b, rows, hp, c)[:, :, :h, :]
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def sparse_mla_decode_pallas(q_c, q_pe, latent, pos, sel, scale: float,
                              layer=0, interpret: bool = False) -> jax.Array:
     """`mla_decode_attention_pallas` over the positions `sel` `[B, S]`
-    marks (nonzero) among those up to `pos`."""
+    marks (nonzero) among those up to `pos`. With `q_c` `[B, R, H, C]`
+    (R rows a slot at `pos .. pos + R - 1`, each with its own `sel[:,
+    i]`): one fetch of a slot's latent blocks for all its rows."""
+    if q_c.ndim == 4:
+        return _sparse_rows(q_c, q_pe, latent, pos, sel, scale, layer,
+                            interpret)
     return _sweep_call(
         functools.partial(_sparse_kernel, scale=scale), SPARSE_NAME,
         q_c, q_pe, latent, pos, layer, sel,

@@ -346,12 +346,19 @@ class EngineConfig:
     # tests are deterministic); None defers to
     # $BIGDL_TPU_QUALITY_PROBE_STEPS (default 0 = probe off)
     quality_probe_steps: Optional[int] = None
+    # speculative decoding inside the resident step: tokens a step
+    # drafts ahead with the FAMILY'S OWN draft module (multi-token
+    # prediction) and verifies in the same dispatch, so a step yields
+    # 1..1+n tokens a slot. 0 = the one-token step (every family's
+    # default); 1 only for a family that declares such a module
+    # (registry `speculative_depth`), refused at start-up otherwise.
+    speculative_tokens: int = 0
 
 
 class _Slot:
     __slots__ = ("req", "generated", "last_token", "active", "counts",
                  "counts_out", "rng", "cum_logprob", "n_logprobs",
-                 "dev_seed")
+                 "dev_seed", "drafted")
 
     def __init__(self):
         self.req: Optional[Request] = None
@@ -369,6 +376,8 @@ class _Slot:
         # 31-bit seed for the DEVICE sampler stream (SamplingParams.seed
         # folded down, or a per-admission nonce when unseeded)
         self.dev_seed: int = 0
+        # a standing draft of the family's MTP module waits on the device
+        self.drafted: bool = False
 
 
 @dataclasses.dataclass
@@ -430,14 +439,17 @@ class _Admission:
     # shared data through this row.
     shared_pages: Optional[List[int]] = None
     new_pages: Optional[List[int]] = None
+    # speculative_tokens: the main stack's hidden row of the position
+    # before the next chunk ([1, D], on the device), which the MTP
+    # block's lagged pass over that chunk starts from
+    carry: Any = None
 
 
-@jax.named_scope("sampler")
-def _device_sample_rows(lg, temps, top_ks, top_ps, seeds, poss):
-    """Batched on-device sampler body: temperature / top-k / top-p via
-    gumbel-max, one seeded stream per row. Shared by the standalone
-    ``engine_sample_device`` jit and the fused resident decode step so
-    the two paths are numerically identical token-for-token."""
+def _transform_rows(lg, temps, top_ks, top_ps):
+    """A slot's temperature / top-k / top-p transform of its logits:
+    ``(t, greedy)``, ``t`` ``[B, V]`` float32 with ``-inf`` at the
+    masked tokens (softmax of it is the distribution a sampled slot
+    draws from), ``greedy`` ``[B]`` bool."""
     lg = lg.astype(jnp.float32)                      # [B, V]
     v = lg.shape[-1]
     greedy = temps <= 0.0
@@ -463,7 +475,17 @@ def _device_sample_rows(lg, temps, top_ks, top_ps, seeds, poss):
     # it to mean greedy; all-False keep would mask every token)
     keep = keep | (jnp.arange(v)[None, :] == 0)
     cutoff = jnp.min(jnp.where(keep, sd, jnp.inf), axis=-1)
-    t = jnp.where(t < cutoff[:, None], -jnp.inf, t)
+    return jnp.where(t < cutoff[:, None], -jnp.inf, t), greedy
+
+
+@jax.named_scope("sampler")
+def _device_sample_rows(lg, temps, top_ks, top_ps, seeds, poss):
+    """Batched on-device sampler body: temperature / top-k / top-p via
+    gumbel-max, one seeded stream per row. Shared by the standalone
+    ``engine_sample_device`` jit and the fused resident decode step so
+    the two paths are numerically identical token-for-token."""
+    t, greedy = _transform_rows(lg, temps, top_ks, top_ps)
+    lg = lg.astype(jnp.float32)
 
     def row(row_t, row_lg, g, seed, pos):
         key = jax.random.fold_in(jax.random.PRNGKey(seed), pos)
@@ -830,6 +852,12 @@ class LLMEngine:
 
         self._prefill = prefill_chunk
 
+        # -- speculative_tokens: the family's MTP module drafts one token
+        # a slot and the main stack verifies it in the same dispatch
+        self._mtp = bool(ce.speculative_tokens)
+        if ce.speculative_tokens:
+            self._init_speculative()
+
         # -- paged-mode executables. Prefill stays on the slab path (a
         # private 1-row cache1 per admission); only the splice into the
         # batched store, the cross-request page machinery, and the
@@ -1107,9 +1135,10 @@ class LLMEngine:
             "Generated-so-far tokens preserved across committed "
             "migrations (decode work NOT thrown away by a drain, "
             "rolling restart, or scale-down).")
-        # pre-register the families fed by ops/probing.py and
-        # speculative.py so /metrics exposes them before the first
-        # probe or speculative round runs in this process
+        # pre-register the families fed by ops/probing.py and by
+        # speculation (speculative.py's offline rounds, mode=draft |
+        # lookup; this engine's verify step, mode=mtp) so /metrics
+        # exposes them before the first probe or round in this process
         m.counter("bigdl_tpu_kernel_probe_total",
                   "Kernel compile-probe outcomes "
                   "(compiled vs XLA fallback) per kernel.",
@@ -1674,6 +1703,307 @@ class LLMEngine:
         return cache_nbytes(self._cache_spec.unrolled(), 1, alloc,
                             self.kv_cache_dtype)["total"]
 
+    def _init_speculative(self) -> None:
+        """The programs and the device state of `speculative_tokens`: a
+        slot's standing draft `d` and the distribution `q` it was drawn
+        from stay on the device between steps (`_mtp_draft` `[B]`, -1
+        where a slot has none; `_mtp_q` `[B, V]`) and are never fetched.
+
+        The state a slot is in between steps, with `n` rows in the cache
+        and `x` its last token (position `n`, not yet cached): the MTP
+        module's own rows `0 .. n - 1` are written (row i from the main
+        stack's hidden row i and token i + 1) and its row `n - 1` gave
+        `q`, the distribution of token `n + 1`, and `d ~ q`. Every path
+        below keeps it: the verify step, the plain step (brownout, or a
+        slot that needs the host sampler) followed by `engine_mtp_row`,
+        and admission (the last chunk's hidden row and the first sampled
+        token through `engine_mtp_row`). A slot with no draft (-1: a
+        sequence that arrived by migration) is verified as a rejection
+        with `q = 0`, which is the plain step's draw from `p`.
+
+        A verify step takes all it needs from what the step before it
+        left on the device, so while nobody waits for a slot it goes out
+        BEFORE that step's tokens are read (`_mtp_ahead`, `_may_lead`):
+        the device runs step k + 1 while the host fetches, emits and
+        observes step k. The host's view of a slot stays the truth
+        (export, preemption and `_finish` go by the host's count and set
+        the slot's `pos`); what the step ahead computed for a request
+        that step k ended is never read."""
+        from bigdl_tpu.speculative import accept_and_resample
+
+        ce, fam, cfg = self.cfg_engine, self.family, self.cfg
+        depth_of = getattr(fam, "speculative_depth", None)
+        depth = int(depth_of(cfg)) if depth_of is not None else 0
+        if ce.speculative_tokens < 0 or ce.speculative_tokens > depth:
+            raise ValueError(
+                f"speculative_tokens={ce.speculative_tokens}: the "
+                f"{getattr(fam, 'name', '?')!r} family drafts "
+                f"{depth} token(s) ahead (its own multi-token-prediction "
+                "module is what the engine's step drafts with)")
+        if not getattr(fam, "rewindable", True):
+            raise ValueError(
+                "speculative_tokens needs a cache that can disown a "
+                "written row (CACHE_REWINDABLE)")
+        if self._paged or ce.prefix_cache_entries > 0:
+            raise ValueError(
+                "speculative_tokens runs on the slab without the host "
+                "prefix cache: a page table or a prefix snapshot carries "
+                "no MTP rows' hidden state")
+        fwd_hidden, mtp_forward = fam.forward_hidden, fam.mtp_forward
+        b, s_max = ce.max_batch, ce.max_seq
+        # (who holds which slot, the next verify step's `ints`, its
+        # `floats`): the device's own view of every slot's last token
+        # and token index, good while no slot changes hands
+        self._mtp_io = None
+        # a verify step that went out BEFORE the last one's tokens were
+        # read (`_decode_step`): ((slot, request) of every slot in it,
+        # its `[B, 4]` block, the `ints` after it, its `floats`). The
+        # host's view of a slot is the truth; the device's may be this
+        # one step further on
+        self._mtp_ahead = None
+        self._mtp_draft = jnp.full((b,), -1, jnp.int32)
+        self._mtp_q = jnp.zeros((b, int(cfg.vocab_size)), jnp.float32)
+
+        def transform(lg, temps, top_ks, top_ps):
+            """`_transform_rows`, its two sorts skipped while no slot
+            asks for a top-k or a top-p."""
+            plain = jnp.all((top_ks <= 0) & (top_ps >= 1.0))
+            return jax.lax.cond(
+                plain,
+                lambda: lg.astype(jnp.float32)
+                / jnp.maximum(temps, 1e-6)[:, None],
+                lambda: _transform_rows(lg, temps, top_ks, top_ps)[0])
+
+        def keys_at(seeds, poss, stream):
+            """One key a slot for the draw of the token at absolute
+            index `poss`: stream 0 is the sampler's own gumbel
+            (`_device_sample_rows`' key), 1 the accept test's uniform, 2
+            the draft's gumbel."""
+            def one(seed, pos):
+                key = jax.random.fold_in(jax.random.PRNGKey(seed), pos)
+                return key if stream == 0 else jax.random.fold_in(key,
+                                                                  stream)
+            return jax.vmap(one)(seeds, poss)
+
+        def gumbel_argmax(t, keys):
+            gum = jax.vmap(lambda k: jax.random.gumbel(
+                k, t.shape[1:], jnp.float32))(keys)
+            return jnp.argmax(t + gum, axis=-1).astype(jnp.int32)
+
+        def next_draft(mt_lg, temps, top_ks, top_ps, seeds, poss):
+            """The draft of the token at index `poss` and the
+            distribution it is drawn from, off the MTP module's logits
+            `[B, V]`."""
+            t = transform(mt_lg, temps, top_ks, top_ps)
+            drawn = gumbel_argmax(t, keys_at(seeds, poss, 2))
+            d = jnp.where(temps <= 0.0,
+                          jnp.argmax(mt_lg, axis=-1).astype(jnp.int32),
+                          drawn)
+            return d, jax.nn.softmax(t, axis=-1)
+
+        @functools.partial(tracked_jit, "engine_decode_resident_mtp",
+                           registry=self.registry,
+                           donate_argnums=(1, 3, 4, 5))
+        def decode_resident_mtp(params, ints, floats, drafts, q, cache):
+            """One verify step: the main stack over `[x, d]` a slot,
+            health of both rows, accept / resample / bonus, the MTP
+            module over the rows that became final, the next drafts,
+            and `pos` advanced by what each slot kept.
+
+            What the host gives and takes is PACKED, because a transfer
+            of a few bytes costs the step about a millisecond each way:
+            `ints` `[4, B]` (each slot's last token, -1 for none; top-k;
+            seed; the absolute index of the token it samples next) and
+            `floats` `[2, B]` (temperature, top-p) go in; out come ONE
+            `[B, 4]` block (the two tokens, how many were kept, health)
+            and the NEXT step's `ints` (the last kept token, the index
+            moved on), which the host hands back untouched while the
+            same requests hold the same slots (`_mtp_io`)."""
+            tokens, top_ks, seeds, poss = ints
+            temps, top_ps = floats
+            live = tokens >= 0
+            has = live & (drafts >= 0)
+            d = jnp.maximum(drafts, 0)
+            both = jnp.stack([jnp.maximum(tokens, 0), d], axis=1)
+            pos0 = jnp.where(live, cache.pos, 0)
+            lg, hid, cache = fwd_hidden(
+                params, cfg, both,
+                cache.replace(pos=jnp.where(live, cache.pos, -1)))
+            finite = jnp.isfinite(lg).all(axis=(-2, -1))
+            greedy = temps <= 0.0
+            with jax.named_scope("sampler"):
+                rep = lambda a: jnp.repeat(a, 2)            # noqa: E731
+                v = lg.shape[-1]
+                p = jax.nn.softmax(transform(
+                    lg.reshape(-1, v), rep(temps), rep(top_ks),
+                    rep(top_ps)).reshape(lg.shape), axis=-1)
+                u = jax.vmap(jax.random.uniform)(keys_at(seeds, poss, 1))
+                n_acc, dist = accept_and_resample(
+                    p, jnp.where(has[:, None], q, 0.0)[:, None],
+                    d[:, None], u[:, None], has[:, None])
+                acc_s = n_acc > 0
+                # the resampled token is token `poss`; the bonus token
+                # after an accept is token `poss + 1`
+                y_s = gumbel_argmax(
+                    jnp.log(jnp.maximum(dist, 1e-30)),
+                    keys_at(seeds, poss + acc_s.astype(jnp.int32), 0))
+                am = jnp.argmax(lg, axis=-1).astype(jnp.int32)  # [B, 2]
+                acc_g = has & (am[:, 0] == d)
+                acc = jnp.where(greedy, acc_g, acc_s)
+                y = jnp.where(greedy, jnp.where(acc_g, am[:, 1], am[:, 0]),
+                              y_s)
+            first = jnp.where(acc, d, y)
+            toks = jnp.stack([first, y], axis=1)
+            n_emit = jnp.where(acc, 2, 1).astype(jnp.int32)
+            # the MTP module over the rows that became final: (h_n,
+            # first) and, where the draft was accepted, (h_{n+1}, bonus);
+            # a rejected slot's second row is dead (overwritten next step)
+            mt_lg, cache = mtp_forward(params, cfg, hid, toks, cache, pos0)
+            last = jnp.take_along_axis(
+                mt_lg, (n_emit - 1)[:, None, None], axis=1)[:, 0]
+            with jax.named_scope("sampler"):
+                d_new, q_new = next_draft(last, temps, top_ks, top_ps, seeds,
+                                          poss + n_emit)
+            out = jnp.concatenate(
+                [toks, n_emit[:, None], finite.astype(jnp.int32)[:, None]],
+                axis=1)
+            # the last kept token is `y` either way: the bonus token
+            # after an accept, the resampled one after a reject
+            ints = jnp.stack([jnp.where(live, y, -1), top_ks, seeds,
+                              poss + n_emit])
+            return (out, ints, jnp.where(live, d_new, -1), q_new,
+                    cache.replace(pos=jnp.where(live, pos0 + n_emit, 0)))
+
+        self._decode_resident_mtp = decode_resident_mtp
+
+        @functools.partial(tracked_jit, "engine_mtp_row",
+                           registry=self.registry, donate_argnums=(1, 2, 3))
+        def mtp_row(params, cache, drafts, q, hidden, tokens, temps, top_ks,
+                    top_ps, seeds, poss):
+            """The MTP module's row of each slot's LAST cached position
+            (`hidden` `[B, D]` its main-stack hidden row, `tokens` `[B]`
+            the token that follows it, -1 for a slot that takes no
+            part: nothing of it is written), and so the slot's draft of
+            token `poss`. Admission and the plain step end with it."""
+            take = tokens >= 0
+            at = jnp.where(take, cache.pos - 1, 0)
+            mt_lg, cache = mtp_forward(
+                params, cfg, hidden[:, None], jnp.maximum(tokens, 0)[:, None],
+                cache, at, wpos=jnp.where(take, at, s_max))
+            with jax.named_scope("sampler"):
+                d_new, q_new = next_draft(mt_lg[:, 0], temps, top_ks, top_ps,
+                                          seeds, poss)
+            return (cache, jnp.where(take, d_new, drafts),
+                    jnp.where(take[:, None], q_new, q))
+
+        self._mtp_row = mtp_row
+
+        @functools.partial(tracked_jit, "engine_decode_hidden",
+                           registry=self.registry, donate_argnums=(2,))
+        def decode_hidden(params, tokens, cache):
+            """`engine_decode` with the hidden rows beside the logits:
+            the plain step of a speculating engine (`engine_mtp_row`
+            follows it, once the tokens are sampled)."""
+            live = tokens >= 0
+            lg, hid, out = fwd_hidden(
+                params, cfg, jnp.maximum(tokens, 0)[:, None],
+                cache.replace(pos=jnp.where(live, cache.pos, -1)))
+            return lg[:, -1, :], hid[:, -1, :], out.replace(
+                pos=jnp.where(live, out.pos, 0))
+
+        self._decode_hidden = decode_hidden
+
+        @functools.partial(tracked_jit, "engine_prefill_mtp",
+                           registry=self.registry, donate_argnums=(2,))
+        def prefill_chunk_mtp(params, tokens, cache1, carry, row):
+            """`engine_prefill` with the MTP module's lagged pass over
+            the chunk; also returns the hidden row `row` of the chunk
+            (the last prompt position's starts the slot's first draft)
+            and the next chunk's carry."""
+            lg, hid, cache1 = fwd_hidden(params, cfg, tokens, cache1,
+                                         carry=carry)
+            return (lg, jax.lax.dynamic_index_in_dim(hid, row, 1, False),
+                    hid[:, -1], cache1)
+
+        self._prefill_mtp = prefill_chunk_mtp
+        m = self.registry
+        self._m_mtp_drafts = m.counter(
+            "bigdl_tpu_mtp_drafts_total",
+            "Drafts of the family's multi-token-prediction module that a "
+            "verify step judged, by outcome.", labelnames=("outcome",))
+        self._m_mtp_slot_steps = m.counter(
+            "bigdl_tpu_mtp_slot_steps_total",
+            "Slot-steps of a speculating engine: kind=verify two rows a "
+            "slot (one or two tokens kept), kind=plain one row and one "
+            "token (brownout, or a slot that needs the host sampler).",
+            labelnames=("kind",))
+        for oc in ("accepted", "rejected"):     # render from scrape 1
+            self._m_mtp_drafts.labels(oc)
+        for kd in ("verify", "plain"):
+            self._m_mtp_slot_steps.labels(kd)
+        self._m_spec_accept = m.histogram(
+            "bigdl_tpu_spec_accept_ratio",
+            "Speculative decoding acceptance ratio per "
+            "verify round.", labelnames=("mode",),
+            buckets=RATIO_BUCKETS).labels("mtp")
+
+    def _sampling_arrays(self, rows):
+        """The device sampler's per-slot arguments for the slots `rows`
+        (`[max_batch]` each): temperature, top-k, top-p, seed and the
+        absolute index of the token each slot samples next."""
+        b = self.cfg_engine.max_batch
+        temps = np.zeros((b,), np.float32)
+        top_ks = np.zeros((b,), np.int32)
+        top_ps = np.ones((b,), np.float32)
+        seeds = np.zeros((b,), np.int32)
+        poss = np.zeros((b,), np.int32)
+        for i in rows:
+            s = self.slots[i]
+            p = s.req.params
+            temps[i] = p.temperature
+            top_ks[i] = p.top_k
+            top_ps[i] = p.top_p
+            seeds[i] = s.dev_seed
+            poss[i] = s.req.generated_offset + len(s.generated)
+        return temps, top_ks, top_ps, seeds, poss
+
+    def _may_lead(self, active) -> bool:
+        """Whether the verify step AFTER the one just sent may go out
+        before its tokens are read: nobody waits for a slot (an admission
+        takes the next step), and no slot of `active` can end this step
+        by its length, so that only a stop token, an abort or a deadline
+        leaves a step computed in vain, and no step writes a row past
+        the slab."""
+        if self._admitting is not None or self.waiting:
+            return False
+        s_max = self.cfg_engine.max_seq
+        for i in active:
+            s = self.slots[i]
+            r = s.req
+            if r.params.max_tokens - (r.generated_offset
+                                      + len(s.generated)) <= 2:
+                return False
+            if len(r.prompt_token_ids) + len(s.generated) + 4 >= s_max:
+                return False
+        return True
+
+    def _mtp_rows_after(self, rows, hidden_dev) -> None:
+        """`engine_mtp_row` for the slots `rows`, each with its last
+        token: writes the MTP module's row of the position before it
+        and leaves the slot's standing draft. `hidden_dev` `[B, D]`."""
+        b = self.cfg_engine.max_batch
+        self._mtp_io = None       # a slot changed hands, or a plain step
+        tokens = np.full((b,), -1, np.int32)
+        for i in rows:
+            tokens[i] = self.slots[i].last_token
+            self.slots[i].drafted = True
+        puts = [jnp.asarray(a) for a in
+                (tokens,) + self._sampling_arrays(rows)]
+        self.cache, self._mtp_draft, self._mtp_q = self._mtp_row(
+            self.params, self.cache, self._mtp_draft, self._mtp_q,
+            hidden_dev, *puts)
+        del puts
+
     def _admission_step(self) -> None:
         """Advance chunked admission by AT MOST one chunk (bounds the
         decode gap a long prompt can cause). Starts a new admission when
@@ -1794,7 +2124,20 @@ class LLMEngine:
         self.faults.raise_point("prefill", self._step_idx)
         with self.phases.phase("admission.h2d", child=True):
             padded_dev = jnp.asarray(padded)
-        logits, a.cache1 = self._prefill(self.params, padded_dev, a.cache1)
+        hidden = None
+        if self._mtp:
+            if a.carry is None:
+                a.carry = jnp.zeros((1, int(self.cfg.hidden_size)),
+                                    jnp.bfloat16)
+            # the hidden row that comes back: the last prompt position's
+            # where this chunk holds it
+            logits, hidden, a.carry, a.cache1 = self._prefill_mtp(
+                self.params, padded_dev, a.cache1, a.carry,
+                jnp.asarray(min(max(plen - 1 - a.consumed, 0), chunk - 1),
+                            jnp.int32))
+        else:
+            logits, a.cache1 = self._prefill(self.params, padded_dev,
+                                             a.cache1)
         # a put's buffer is let go where the call's own temporary was:
         # while the program runs, not at the frame's exit after the wait
         del padded_dev
@@ -1822,7 +2165,14 @@ class LLMEngine:
             s.active = True
             self._obs_admission_complete(a.req.request_id)
             self._emit(s, lp)
-            self._check_done(a.slot_idx)
+            s.drafted = False
+            if not self._check_done(a.slot_idx) and self._mtp:
+                # the MTP row of the last prompt position waited for
+                # this token: with it the slot's first draft stands
+                self._mtp_rows_after(
+                    [a.slot_idx], jnp.broadcast_to(
+                        hidden,
+                        (self.cfg_engine.max_batch, hidden.shape[-1])))
             self._admitting = None
 
     # -- paged KV bookkeeping (kv_page_size > 0) ----------------------------
@@ -3540,6 +3890,9 @@ class LLMEngine:
         self._obs_finish(s.req.request_id, reason, n_generated=gen_len)
         s.req = None
         s.active = False
+        s.drafted = False
+        if self._mtp:
+            self._mtp_io = None
         s.generated = []
         s.counts = None
         s.counts_out = None
@@ -4141,29 +4494,50 @@ class LLMEngine:
             # it (any temperature / top-k / top-p / seed)
             return s.counts is None and s.n_logprobs < 0
 
-        def gather_params(rows):
-            b = self.cfg_engine.max_batch
-            temps = np.zeros((b,), np.float32)
-            top_ks = np.zeros((b,), np.int32)
-            top_ps = np.ones((b,), np.float32)
-            seeds = np.zeros((b,), np.int32)
-            poss = np.zeros((b,), np.int32)
-            for i in rows:
-                s = self.slots[i]
-                p = s.req.params
-                temps[i] = p.temperature
-                top_ks[i] = p.top_k
-                top_ps[i] = p.top_p
-                seeds[i] = s.dev_seed
-                poss[i] = s.req.generated_offset + len(s.generated)
-            return temps, top_ks, top_ps, seeds, poss
-
+        # resident fast path: when every active slot is
+        # device-samplable and no fault clause is live (poison_rows
+        # edits logits on the host side), forward + health +
+        # sampling run as ONE dispatch — the [B, V] logits never
+        # exist outside the executable
+        resident = (decode_resident_enabled()
+                    and not self._paged
+                    and not self.faults.enabled
+                    and all(simple(self.slots[i]) for i in active))
+        # a speculating engine's resident step is the verify step (two
+        # rows a slot); it falls to the plain one-row step, followed by
+        # the MTP module's row, under brownout and with any slot that
+        # needs the host sampler
+        verify = self._mtp and resident and self.speculative_allowed
+        ahead = None
+        if self._mtp:
+            resident = False
+            ahead, self._mtp_ahead = self._mtp_ahead, None
+        # this step may send the next one out before it reads its own
+        # tokens: only from a verify step of its own choosing, over the
+        # slots that step held
+        lead = verify
+        if ahead is not None:
+            # the step that went out during the last one IS this step for
+            # the slots whose requests it held and that are still here (a
+            # slot admitted since waits a step; one that ended since is
+            # read no further); brownout or a host-sampled newcomer take
+            # effect with the next step
+            held = dict(ahead[0])
+            stay = [i for i in active if held.get(i) is self.slots[i].req]
+            if stay:
+                lead = lead and len(stay) == len(active) == len(held)
+                active, verify = stay, True
+            else:
+                ahead = None
+        rows_a_slot = 2 if verify else 1
         if self._dsa is not None:
             # what the selection keeps this step, by its own rule from
-            # the positions the host already knows (outside the phases)
+            # the positions the host already knows (outside the phases):
+            # every computed row counts, both rows of a verify step
             n_full, topk = self._dsa
             held = [len(self.slots[i].req.prompt_token_ids)
-                    + len(self.slots[i].generated) for i in active]
+                    + len(self.slots[i].generated) + r for i in active
+                    for r in range(rows_a_slot)]
             self._m_dsa_positions.labels("live").inc(n_full * sum(held))
             self._m_dsa_positions.labels("selected").inc(
                 n_full * sum(min(d, topk) for d in held))
@@ -4180,6 +4554,8 @@ class LLMEngine:
                 self._m_eva_rows.labels(kd).inc(n)
         toks = None
         finite_host = None
+        n_emit = None       # verify step: tokens each slot kept, [B]
+        hidden_dev = mtp_next = None
         toks_dev = finite_dev = qrows_dev = logits_dev = None
         qrows = None        # [B, 3] chosen_lp/entropy/top1_margin (f32)
         t_decode0 = time.perf_counter()
@@ -4220,17 +4596,56 @@ class LLMEngine:
                 self._m_paged_pages.labels("table").inc(
                     layers * len(tokens) * self._pages_per_seq)
 
-            # resident fast path: when every active slot is
-            # device-samplable and no fault clause is live (poison_rows
-            # edits logits on the host side), forward + health +
-            # sampling run as ONE dispatch — the [B, V] logits never
-            # exist outside the executable
-            resident = (decode_resident_enabled()
-                        and not self._paged
-                        and not self.faults.enabled
-                        and all(simple(self.slots[i]) for i in active))
-            if resident:
-                temps, top_ks, top_ps, seeds, poss = gather_params(active)
+            if verify:
+                # nothing goes to the device while the same requests
+                # hold the same slots: the last step left the next one's
+                # tokens and token indices there
+                holders = tuple((i, id(self.slots[i].req)) for i in active)
+                io, self._mtp_io = self._mtp_io, None
+                if ahead is not None:
+                    _, toks_dev, ints_dev, floats_dev = ahead
+                else:
+                    if io is not None and io[0] == holders:
+                        _, ints_dev, floats_dev = io
+                    else:
+                        temps, top_ks, top_ps, seeds, poss = \
+                            self._sampling_arrays(active)
+                        with ph("dispatch.h2d", child=True):
+                            ints_dev = jnp.asarray(
+                                np.stack([tokens, top_ks, seeds, poss]))
+                            floats_dev = jnp.asarray(
+                                np.stack([temps, top_ps]))
+                    (toks_dev, ints_dev, self._mtp_draft, self._mtp_q,
+                     self.cache) = self._decode_resident_mtp(
+                        self.params, ints_dev, floats_dev, self._mtp_draft,
+                        self._mtp_q, self.cache)
+                if lead and self._may_lead(active):
+                    # the NEXT verify step goes out now, on what this one
+                    # leaves on the device, so the device does not wait
+                    # while the host reads and emits this step's tokens.
+                    # What it holds of a slot that this step ends is
+                    # never read (`_finish` sets the slot's `pos` back)
+                    (toks_next, ints_next, self._mtp_draft, self._mtp_q,
+                     self.cache) = self._decode_resident_mtp(
+                        self.params, ints_dev, floats_dev, self._mtp_draft,
+                        self._mtp_q, self.cache)
+                    self._mtp_ahead = (
+                        tuple((i, self.slots[i].req) for i in active),
+                        toks_next, ints_next, floats_dev)
+                    del toks_next, ints_next
+                elif ahead is None or lead:
+                    mtp_next = (holders, ints_dev, floats_dev)
+                del io, ints_dev, floats_dev
+                ahead = None
+            elif self._mtp:
+                with ph("dispatch.h2d", child=True):
+                    tokens_dev = jnp.asarray(tokens)
+                logits_dev, hidden_dev, self.cache = self._decode_hidden(
+                    self.params, tokens_dev, self.cache)
+                del tokens_dev
+            elif resident:
+                temps, top_ks, top_ps, seeds, poss = \
+                    self._sampling_arrays(active)
                 all_greedy = all(
                     self.slots[i].req.params.temperature <= 0.0
                     for i in active)
@@ -4265,12 +4680,18 @@ class LLMEngine:
                 del tokens_dev
         with ph("device"):
             jax.block_until_ready(  # graftlint: disable=step-host-sync
-                toks_dev if resident else logits_dev)
+                toks_dev if resident or verify else logits_dev)
         dispatch_s = self.phases.seconds("dispatch")
         device_s = self.phases.seconds("device")
 
         with ph("sample"):
-            if resident:
+            if verify:
+                with ph("sample.fetch", child=True):
+                    packed = np.asarray(toks_dev)      # the one fetch
+                toks = np.asarray(packed[:, :2])
+                n_emit = np.asarray(packed[:, 2])
+                finite_host = packed[:, 3] != 0
+            elif resident:
                 with ph("sample.fetch", child=True):
                     toks = np.asarray(toks_dev)
                     finite_host = np.asarray(finite_dev)
@@ -4316,7 +4737,7 @@ class LLMEngine:
             complex_rows = [i for i in active
                             if not simple(self.slots[i])]
             picked_dev = None
-            if resident or not active:
+            if resident or verify or not active:
                 pass      # tokens already sampled inside the fused step
             elif simple_rows and all(
                     self.slots[i].req.params.temperature <= 0.0
@@ -4332,7 +4753,7 @@ class LLMEngine:
                 # penalties/logprobs request happens to share the batch
                 with ph("sample.h2d", child=True):
                     puts = [jnp.asarray(a)
-                            for a in gather_params(simple_rows)]
+                            for a in self._sampling_arrays(simple_rows)]
                 picked_dev = self._sample_device(logits_dev, *puts)
                 del puts
             logits = None
@@ -4352,10 +4773,16 @@ class LLMEngine:
                 self._observe_step("decode", 0)
             return True
 
-        def pick(i):
-            if simple(self.slots[i]):
-                return int(toks[i]), None
-            return self._sample_host(logits[i], self.slots[i])
+        def picks(i):
+            """The token(s) this step gives slot i, in order: one, or
+            the two a verify step kept."""
+            if n_emit is not None:
+                for j in range(int(n_emit[i])):
+                    yield int(toks[i, j]), None
+            elif simple(self.slots[i]):
+                yield int(toks[i]), None
+            else:
+                yield self._sample_host(logits[i], self.slots[i])
 
         # collect traced requests BEFORE _check_done: a finishing
         # request's slot is freed (req=None, tracer entry closed)
@@ -4366,27 +4793,66 @@ class LLMEngine:
         # (slot, tok, is_repeat, qos) captured BEFORE _check_done can
         # free the slot — the quality-telemetry feed for this step
         q_meta: List[Tuple[int, int, bool, str]] = []
+        # tokens each stream was given this step, beside its QoS class
+        step_tokens: List[int] = []
         with ph("emit"):
             for i in active:
                 s = self.slots[i]
-                tok, lp = pick(i)
-                repeat = bool(s.generated) and s.generated[-1] == tok
-                s.last_token = tok
-                s.generated.append(tok)
-                r = s.req
-                if r is not None:
-                    step_qos.append(r.params.qos or "standard")
-                    if self._use_quality:
+                had_draft = s.drafted
+                given = 0
+                # a stop token or max_tokens on the first token of two
+                # drops the second (the slot is released with it)
+                for tok, lp in picks(i):
+                    repeat = bool(s.generated) and s.generated[-1] == tok
+                    s.last_token = tok
+                    s.generated.append(tok)
+                    r = s.req
+                    if given == 0 and r is not None:
+                        step_qos.append(r.params.qos or "standard")
+                    if r is not None and self._use_quality \
+                            and n_emit is None:
                         q_meta.append((i, tok, repeat,
                                        r.params.qos or "standard"))
-                if r is not None and r.trace is not None:
-                    sp = self.tracer.get(r.request_id)
-                    traced.setdefault(
-                        r.trace[0],
-                        (r.request_id,
-                         sp.trace_span if sp is not None else None))
-                self._emit(s, lp)
-                self._check_done(i)
+                    if r is not None and r.trace is not None:
+                        sp = self.tracer.get(r.request_id)
+                        traced.setdefault(
+                            r.trace[0],
+                            (r.request_id,
+                             sp.trace_span if sp is not None else None))
+                    given += 1
+                    self._emit(s, lp)
+                    if self._check_done(i):
+                        break
+                step_tokens.append(given)
+                if n_emit is not None:
+                    s.drafted = True
+                    if had_draft:
+                        self._m_mtp_drafts.labels(
+                            "accepted" if n_emit[i] == 2
+                            else "rejected").inc()
+            if self._mtp:
+                self._m_mtp_slot_steps.labels(
+                    "verify" if verify else "plain").inc(len(active))
+                if verify:
+                    judged = [int(n_emit[i]) - 1 for i in active]
+                    if judged:
+                        self._m_spec_accept.observe(
+                            sum(judged) / len(judged))
+                    if mtp_next is not None and len(active) == len(
+                            mtp_next[0]) and all(
+                            self.slots[i].active for i in active):
+                        # every slot goes on: the device's view of the
+                        # next step stands
+                        self._mtp_io = mtp_next
+                    mtp_next = None
+                else:
+                    # the plain step of a speculating engine: the MTP
+                    # module's row of each slot that goes on, now that
+                    # its token is known (one more dispatch a step)
+                    going = [i for i in active if self.slots[i].active]
+                    if going:
+                        self._mtp_rows_after(going, hidden_dev)
+                hidden_dev = None
         with ph("observe"):
             # live quality telemetry: resident steps hand over the
             # fused [B, 3] block (zero extra dispatches); host-sampled
@@ -4401,13 +4867,16 @@ class LLMEngine:
                     if qrows is not None:
                         self._quality_observe(qrows, q_meta)
             with ph("observe.slo", child=True):
-                # one batched step advances EVERY active stream one
-                # token, so step wall time IS each stream's
-                # time-per-output-token
+                # one batched step advances every active stream by the
+                # tokens it was given (one; one or two of a verify
+                # step), so a stream's time-per-output-token is the
+                # step's wall time over them
                 dt = time.perf_counter() - t_decode0
-                # each stream's TPOT sample for its QoS class
-                for q in step_qos:
-                    self.slo.observe_tpot(q, dt)
+                # each stream's TPOT samples for its QoS class, one a
+                # token
+                for q, n in zip(step_qos, step_tokens):
+                    for _ in range(n):
+                        self.slo.observe_tpot(q, dt / n)
                 # the queue-wait admission test's estimate: every step
                 self._tpot_ewma = stats_ewma(self._tpot_ewma or None, dt)
                 # the brownout latency-inflation signal: EWMA over its
@@ -4433,7 +4902,9 @@ class LLMEngine:
             # stage the roofline/sentinel sample for step() to finalize
             # with the FULL step wall time (fault sleeps happen before
             # this method's timing bracket)
-            self._pending_perf = (len(active), perf_seq_len)
+            # rows the step computed (both of a verify step's), not
+            # tokens given
+            self._pending_perf = (len(active) * rows_a_slot, perf_seq_len)
             # one decode_step span per distinct trace among active slots
             with ph("observe.spans", child=True):
                 for tid, (rid, parent_sid) in traced.items():

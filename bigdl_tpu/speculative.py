@@ -29,6 +29,13 @@ restructured for XLA:
   draft forwards; the threshold is a traced scalar, so the host can adapt
   it between rounds (auto_th_stop_draft) with NO recompilation.
 
+The serving engine speculates too (`EngineConfig.speculative_tokens`,
+for a family with its own draft module: a multi-token-prediction block
+that drafts one token a slot and a two-row verify inside the resident
+step). It shares `accept_and_resample` with the offline round here, so
+there is one accept rule to test; the paths below stay the offline
+`generate` forms for a separate draft model or a prompt lookup.
+
 The draft is typically the same checkpoint at sym_int4 (self-speculation,
 reference model.py:323-331) and the target bf16/fp8 — both share one
 tokenizer, so only token ids cross model boundaries.
@@ -102,6 +109,46 @@ def _spec_observe(mode: str, n_accept: int, n_draft: int,
         tok.labels(mode, "accepted").inc(n_accept)
     except Exception:
         pass  # telemetry must never break the decode loop
+
+
+def accept_and_resample(p: jax.Array, q: jax.Array, drafts: jax.Array,
+                        u: jax.Array, valid=None):
+    """The exact speculative-sampling rule, once, for every path that
+    speculates (this module's offline round and the serving engine's
+    verify step, `serving/engine.py`).
+
+    `p` `[B, G + 1, V]`: the target's distributions at the G drafted
+    positions and the one after; `q` `[B, G, V]`: the distributions the
+    drafts `drafts` `[B, G]` were drawn from (under the SAME temperature
+    / top-k / top-p transform as `p`); `u` `[B, G]` uniforms; `valid`
+    `[B or 1, G]` bool, False where no draft was made.
+
+    Draft i is accepted with probability `min(1, p_i(d_i) / q_i(d_i))`,
+    and only while every earlier one was. Returns `n_accept` `[B]` and
+    `dist` `[B, V]`, the distribution of the token at position
+    `n_accept`: `normalize(max(p - q, 0))` after a true rejection, the
+    target's own after a full accept (the bonus token). The emitted
+    stream's distribution is the target's exactly, whatever `q` is."""
+    g = drafts.shape[1]
+    p_tok = jnp.take_along_axis(p[:, :g], drafts[..., None], axis=-1)[..., 0]
+    q_tok = jnp.take_along_axis(q, drafts[..., None], axis=-1)[..., 0]
+    accepted = u < jnp.minimum(1.0, p_tok / jnp.maximum(q_tok, 1e-20))
+    n_draft = g
+    if valid is not None:
+        accepted = accepted & valid
+        n_draft = jnp.sum(valid, axis=1)
+    n_accept = jnp.sum(jnp.cumprod(accepted.astype(jnp.int32), axis=1),
+                       axis=1)
+    p_n = jnp.take_along_axis(p, n_accept[:, None, None], axis=1)[:, 0]
+    q_pad = jnp.concatenate([q, jnp.zeros_like(q[:, :1])], axis=1)
+    q_n = jnp.take_along_axis(q_pad, n_accept[:, None, None], axis=1)[:, 0]
+    resid = jnp.maximum(p_n - q_n, 0.0)
+    resid_sum = jnp.sum(resid, axis=-1, keepdims=True)
+    true_reject = n_accept < n_draft
+    dist = jnp.where(
+        (true_reject & (resid_sum[:, 0] > 1e-9))[:, None],
+        resid / jnp.maximum(resid_sum, 1e-20), p_n)
+    return n_accept, dist
 
 
 def make_spec_round(
@@ -206,31 +253,14 @@ def make_spec_round(
             p = jax.nn.softmax(filter_logits(
                 logits_t.astype(jnp.float32) / temperature, top_k, top_p),
                 axis=-1)                            # [B, gamma+1, V]
-            p_tok = jnp.take_along_axis(p[:, :-1], draft_toks[..., None],
-                                        axis=-1)[..., 0]     # [B, gamma]
-            q_tok = jnp.take_along_axis(draft_q, draft_toks[..., None],
-                                        axis=-1)[..., 0]
             key, uk, rk = jax.random.split(key, 3)
-            u = jax.random.uniform(uk, p_tok.shape)
-            accepted = (u < jnp.minimum(1.0, p_tok /
-                                        jnp.maximum(q_tok, 1e-20))) & valid
-            n_accept = jnp.sum(
-                jnp.cumprod(accepted.astype(jnp.int32), axis=1), axis=1)
+            u = jax.random.uniform(uk, draft_toks.shape)
             # token at position n: residual (p - q)+ on a true rejection
             # (n < n_draft); the target distribution itself on a full
             # accept (bonus token)
-            p_n = jnp.take_along_axis(
-                p, n_accept[:, None, None], axis=1)[:, 0]    # [B, V]
-            q_pad = jnp.concatenate(
-                [draft_q, jnp.zeros_like(draft_q[:, :1])], axis=1)
-            q_n = jnp.take_along_axis(
-                q_pad, n_accept[:, None, None], axis=1)[:, 0]
-            resid = jnp.maximum(p_n - q_n, 0.0)
-            resid_sum = jnp.sum(resid, axis=-1, keepdims=True)
-            true_reject = n_accept < n_draft
-            dist = jnp.where(
-                (true_reject & (resid_sum[:, 0] > 1e-9))[:, None],
-                resid / jnp.maximum(resid_sum, 1e-20), p_n)
+            n_accept, dist = accept_and_resample(
+                p, draft_q, draft_toks, u,
+                jnp.broadcast_to(valid, draft_toks.shape))
             correction = jax.random.categorical(
                 rk, jnp.log(jnp.maximum(dist, 1e-20)), axis=-1
             ).astype(jnp.int32)                     # [B]

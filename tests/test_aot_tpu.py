@@ -1456,3 +1456,108 @@ def test_evabyte_engine_decode_step_compiles_and_fits(v5e, aot_flags):
         r"= \w+\[(?:\d+,)?6,(?:2048|512),32,128\]\S* "
         r"(?:copy|dynamic-slice)\(", txt)
     assert not moved, f"a plane of the cache is materialized: {moved}"
+
+
+# -- DeepSeek-V3.2 (PR 43): MTP drafting, a two-row verify step -----------
+
+V32 = dict(b=8, s=8192, bodies=8)
+
+
+@pytest.mark.parametrize("kernel", ["dsa_index_score", "sparse_mla_decode"])
+def test_two_row_verify_kernels_compile_at_published_widths(v5e, aot_flags,
+                                                            kernel):
+    """The two kernels that take the R = 2 rows of a verify step folded
+    beside the heads, for one v5e at the published widths over the cell's
+    slab (8 slots x 8192, 8 bodies): a Mosaic call, no copy of a plane."""
+    from bigdl_tpu.ops.pallas import dsa_attention as K
+
+    dev = v5e.devices[0]
+    b, s = V32["b"], V32["s"]
+
+    def sd(shape, dt=jnp.bfloat16):
+        return _sds(jax.ShapeDtypeStruct(shape, dt), dev)
+
+    pos, lyr = sd((b,), jnp.int32), sd((), jnp.int32)
+    if kernel == "dsa_index_score":
+        comp = _compile(
+            lambda q, w, ix, p, ly: K.dsa_index_score_pallas(q, w, ix, p,
+                                                             layer=ly),
+            sd((b, 2, 64, 128)), sd((b, 2, 64), jnp.float32),
+            sd((V32["bodies"], b, 128, s)), pos, lyr)
+    else:
+        comp = _compile(
+            lambda qc, qp, lat, p, m, ly: K.sparse_mla_decode_pallas(
+                qc, qp, lat, p, m, 192 ** -0.5, layer=ly),
+            sd((b, 2, 128, 512)), sd((b, 2, 128, 64)),
+            sd((V32["bodies"], b, 576, s)), pos, sd((b, 2, s), jnp.bool_),
+            lyr)
+    assert _has_mosaic_call(comp)
+    assert comp.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
+
+
+def _v32_engine():
+    import json
+    import sys
+    from pathlib import Path
+
+    bench = Path(__file__).resolve().parents[1] / "benchmark"
+    sys.path[:0] = [str(bench)]
+    from harness import weights_deepseek_v32 as weights
+    from harness.weights import _family_config
+
+    from bigdl_tpu.models import deepseek_v32
+    from bigdl_tpu.ops.quant import prepack_tree
+    from bigdl_tpu.serving import EngineConfig, LLMEngine
+
+    doc = json.loads(
+        (bench / "configs" / "deepseek-v32-ep8-int4.json").read_text())
+    family, cfg, hf = _family_config(doc)
+
+    class Model:
+        params = jax.eval_shape(lambda: prepack_tree(
+            deepseek_v32.prepare_params(
+                weights.build_params(cfg, "sym_int4", 1), cfg), "on")[0])
+        config, hf_config, qtype = cfg, hf, "sym_int4"
+
+    Model.family = family
+    e = doc["engine"]
+    return LLMEngine(Model, EngineConfig(
+        max_batch=e["max_batch"], max_seq=e["max_seq"],
+        prefill_chunk=e["prefill_chunk"],
+        speculative_tokens=e["speculative_tokens"], sentinel=False,
+        quality=False)), cfg
+
+
+def test_deepseek_v32_verify_step_compiles_and_fits(v5e, aot_flags):
+    """`engine_decode_resident_mtp` for the cell's configuration (7
+    layers and the MTP module at published widths, 8 slots x 8192, shapes
+    only): the two-row kernels, the append and the routed kernel are in
+    it, no instruction materializes a layer of a plane, and arguments
+    plus temporaries stay under 11 GB of the chip's 16 (8.4 GB of weights
+    as AOT counts the served tree, 0.74 GB of slab)."""
+    import re
+
+    eng, cfg = _v32_engine()
+    dev = v5e.devices[0]
+    b = eng.cfg_engine.max_batch
+    i32 = _sds(jax.ShapeDtypeStruct((b,), jnp.int32), dev)
+    ints = _sds(jax.ShapeDtypeStruct((4, b), jnp.int32), dev)
+    floats = _sds(jax.ShapeDtypeStruct((2, b), jnp.float32), dev)
+    q = _sds(jax.ShapeDtypeStruct((b, cfg.vocab_size), jnp.float32), dev)
+    comp = eng._decode_resident_mtp.lower(
+        _sds(eng.params, dev), ints, floats, i32, q,
+        _sds(jax.eval_shape(lambda: eng.cache), dev)).compile()
+    txt = comp.as_text()
+    for name in ("dsa_index_score", "dsa_select", "sparse_mla_decode",
+                 "mla_latent_append", "moe_routed_decode"):
+        assert name in txt, name
+    ma = comp.memory_analysis()
+    live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    print(f"engine_decode_resident_mtp: {live / 1e9:.2f} GB live, "
+          f"{ma.argument_size_in_bytes / 1e9:.2f} GB of arguments")
+    assert 8.0e9 < live < 11e9, live / 1e9
+    moved = re.findall(
+        r"= \w+\[(?:\d+,)?8,(?:576|128),8192\]\S* "
+        r"(?:copy|fusion|dynamic-slice)\(", txt)
+    assert not moved, f"a layer of a cache plane is materialized: {moved}"
