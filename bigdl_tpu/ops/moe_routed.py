@@ -180,50 +180,30 @@ def _decode(xf, comb, hit, gate, up, down, layer, act, interpret):
 def _prefill(xf, topi, topw, mine, share, gate, up, down, layer, act,
              interpret):
     """Sorted ragged dispatch over the held experts (the scheme of
-    `ops/pallas/moe_dispatch.moe_mlp_ragged`): choices of experts held
-    elsewhere sort last and take no row."""
+    `ops/pallas/moe_dispatch.moe_mlp_ragged`, and its plan): choices of
+    experts held elsewhere sort last and take no row. Token rows reach
+    the buffer, and expert outputs the tokens, by gather."""
+    from bigdl_tpu.ops.pallas.moe_dispatch import (ragged_plan,
+                                                   ragged_rows_in,
+                                                   ragged_rows_out)
     from bigdl_tpu.ops.pallas.moe_routed import (PREFILL_NAME,
                                                  PREFILL_TOKEN_TILE,
                                                  routed_expert_matmul)
 
-    n, k = topi.shape
     held, t = share.held, PREFILL_TOKEN_TILE
-    nk_tot = n * k
-    np_ = -(-(nk_tot + held * (t - 1)) // t) * t    # static worst case
     flat_e = jnp.where(mine, topi - share.first_held, held).reshape(-1)
-    flat_tok = jnp.repeat(jnp.arange(n, dtype=jnp.int32), k)
-    flat_w = jnp.where(mine, topw, 0.0).reshape(-1)
-
-    order = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[order]
-    counts = jnp.bincount(flat_e, length=held + 1)[:held]
-    padded = -(-counts // t) * t
-    region_end = jnp.cumsum(padded)
-    starts = region_end - padded
-    group_start = jnp.cumsum(counts) - counts
-    is_mine = sorted_e < held
-    e_safe = jnp.minimum(sorted_e, held - 1)
-    dest = jnp.where(is_mine, starts[e_safe] + jnp.arange(nk_tot)
-                     - group_start[e_safe], np_)                # np_: dropped
-    xbuf = jnp.zeros((np_, xf.shape[1]), xf.dtype).at[dest].set(
-        xf[flat_tok[order]], mode="drop")
-    tile_first = jnp.arange(np_ // t, dtype=jnp.int32) * t
-    tile_expert = jnp.minimum(
-        jnp.searchsorted(region_end, tile_first, side="right"),
-        held - 1).astype(jnp.int32)
-    n_active = (region_end[-1] // t).astype(jnp.int32)
-    xt = xbuf.reshape(np_ // t, t, -1)
+    plan = ragged_plan(flat_e, topi.shape[1], held, t)
+    xt = ragged_rows_in(xf, plan).reshape(-1, t, xf.shape[1])
     mm = lambda x, w: routed_expert_matmul(                     # noqa: E731
-        x, w, tile_expert, n_active, layer, name=PREFILL_NAME,
+        x, w, plan.tile_expert, plan.n_active, layer, name=PREFILL_NAME,
         interpret=interpret)
-    live = (jnp.arange(np_ // t) < n_active)[:, None, None]
+    live = (jnp.arange(xt.shape[0]) < plan.n_active)[:, None, None]
     h = jnp.where(live, act(mm(xt, gate).astype(jnp.float32))
                   * mm(xt, up).astype(jnp.float32), 0.0).astype(xf.dtype)
-    y = jnp.where(live, mm(h, down), 0).reshape(np_, -1)
-    contrib = (y[jnp.minimum(dest, np_ - 1)].astype(jnp.float32)
-               * flat_w[order][:, None])
-    out = jnp.zeros(xf.shape, jnp.float32).at[flat_tok[order]].add(contrib)
-    return out.astype(xf.dtype)
+    # rows of tiles past `n_active` are never written, and never read:
+    # a choice held here has its row in an active tile
+    y = mm(h, down).reshape(-1, xf.shape[1])
+    return ragged_rows_out(y, plan, topw, mine).astype(xf.dtype)
 
 
 def _dense(xf, comb, gate, up, down, layer, act):

@@ -1247,6 +1247,56 @@ def test_routed_expert_kernel_compiles_on_the_layer_stack(
     assert comp.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
 
 
+def _row_scatters(txt, d):
+    """Scatters of a compiled program (alone or as the root of a fusion:
+    either way the instruction is in the text) whose result, hence whose
+    updates, are rows of `d` elements."""
+    import re
+
+    return [f"{name} = {dt}[{dims}]" for name, dt, dims in re.findall(
+        r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+)\[([\d,]*)\]\S* scatter\(",
+        txt, re.M) if str(d) in dims.split(",")]
+
+
+@pytest.mark.parametrize("n,k,held,total,d,f", [
+    (1024, 8, 32, 256, 5120, 1536),       # dots3-ep8-longdoc-closed
+    (256, 6, 20, 160, 5120, 1536),        # deepseekv2-ep8-reason-closed
+    (1024, 8, 32, 256, 7168, 2048),       # deepseekv32-ep8-reason-closed
+], ids=["dots3", "deepseek_v2", "deepseek_v32"])
+def test_routed_prefill_dispatch_scatters_no_rows(v5e, aot_flags, n, k, held,
+                                                  total, d, f):
+    """`routed_experts` for a prefill chunk of the three routed cells
+    (`sym_int4` stacks with a layer axis, this chip's share of the
+    experts): token rows reach the expert buffer and expert outputs the
+    tokens by GATHER, so the compiled program holds no scatter whose
+    updates or result are `[*, D]` rows; what it may scatter are the
+    `N*k` int32 scalars of the inverse permutation and the bincount. On
+    the parent of PR 44 all three cases fail: `bf16[12288, D]` /
+    `bf16[4096, D]` `scatter` (the rows in) and `f32[N, D]` `scatter`
+    (the scatter-add back), 73 + 34 ms of the dots3 cell's 315 ms chunk
+    on the chip (PERF.md 6, PR 44)."""
+    from bigdl_tpu.ops import moe_routed
+    from bigdl_tpu.ops.probing import quant_struct, stacked_struct
+
+    dev = v5e.devices[0]
+    st = lambda kk, nn: stacked_struct(stacked_struct(          # noqa: E731
+        quant_struct(kk, nn, "sym_int4"), held), 2)
+    stacks = {"experts_gate": st(d, f), "experts_up": st(d, f),
+              "experts_down": st(f, d)}
+    share = moe_routed.Share(total, held, held)
+    comp = _compile(
+        lambda x, lg, sk, lyr: moe_routed.routed_experts(
+            x, lg, sk, share, top_k=k, act=jax.nn.silu, layer=lyr),
+        _sds(jax.ShapeDtypeStruct((n, d), jnp.bfloat16), dev),
+        _sds(jax.ShapeDtypeStruct((n, total), jnp.float32), dev),
+        _sds(stacks, dev), _sds(jax.ShapeDtypeStruct((), jnp.int32), dev))
+    txt = comp.as_text()
+    assert "moe_routed_prefill" in txt and _has_mosaic_call(comp)
+    assert " gather(" in txt
+    rows = _row_scatters(txt, d)
+    assert not rows, f"hidden-size rows are scattered: {rows}"
+
+
 # -- dots3-note (PR 33): sparse attention over a latent cache, a ring ------
 
 DOTS3 = dict(b=8, s=16384, full=5, win=9, ring=640)

@@ -235,6 +235,80 @@ def test_routed_kernels_agree_with_the_dense_combine(n):
     assert 0 < int(s1[2]) <= held
 
 
+def _choices(case):
+    """`[N, 2]` expert choices over 16 experts of which 8..11 are held
+    here, built so that the dispatch's index arithmetic binds."""
+    t = 128
+    away = lambda n: np.stack([np.arange(n) % 8,                # noqa: E731
+                               12 + np.arange(n) % 4], axis=1)
+    if case == "an_expert_with_no_assignment":      # expert 10: no row
+        topi = away(100)
+        topi[:, 0] = np.array([8, 9, 11])[np.arange(100) % 3]
+    elif case == "a_count_that_is_a_whole_tile":    # expert 9: t rows
+        topi = away(t)
+        topi[:, 0] = 9
+        topi[::5, 1] = 8
+    elif case == "every_assignment_held_elsewhere":
+        topi = away(80)
+    elif case == "every_assignment_held_here":      # counts 129,129,1,1
+        topi = np.tile([8, 9], (t + 2, 1))
+        topi[-1] = [10, 11]
+    elif case == "a_token_twice_in_one_tile":       # (9, 9): two rows
+        topi = away(70)
+        topi[:, 0] = 8 + np.arange(70) % 4
+        topi[::3] = 9
+    return jnp.asarray(topi, jnp.int32)
+
+
+@pytest.mark.parametrize("case,tiles_active", [
+    ("an_expert_with_no_assignment", 3),
+    ("a_count_that_is_a_whole_tile", 2),
+    ("every_assignment_held_elsewhere", 0),
+    ("every_assignment_held_here", 6),      # all the buffer has
+    ("a_token_twice_in_one_tile", 4),
+])
+def test_prefill_dispatch_rows_by_gather_against_the_dense_combine(
+        case, tiles_active):
+    """`_prefill` on hand-made choices (interpret mode) against `_dense`:
+    every buffer row names its sorted pair and every choice its buffer
+    row by index arithmetic (`moe_dispatch.ragged_plan`), so the edges of
+    that arithmetic are tried one by one: an empty expert between two
+    full ones, a group that ends on a tile's edge, no held choice at all
+    (no active tile: zeros), the static worst case filled to its last
+    row (`held` groups of one row past a tile), one token twice in one
+    expert's tile."""
+    from bigdl_tpu.ops.pallas.moe_dispatch import ragged_plan
+    from bigdl_tpu.ops.quant import quantize
+
+    d, f, e, held, first = 256, 128, 16, 4, 8
+    k = jax.random.split(jax.random.PRNGKey(2), 5)
+
+    def stack(key, kd, nd):
+        return jax.vmap(jax.vmap(lambda kk: quantize(
+            jax.random.normal(kk, (kd, nd)) * 0.05, "sym_int4")))(
+                jax.random.split(key, 2 * held).reshape(2, held, 2))
+
+    gate, up, down = stack(k[0], d, f), stack(k[1], d, f), stack(k[2], f, d)
+    topi = _choices(case)
+    n = topi.shape[0]
+    x = jax.random.normal(k[3], (n, d), jnp.bfloat16)
+    topw = jax.random.uniform(k[4], (n, 2), jnp.float32, 0.2, 2.0)
+    share = moe_routed.Share(e, first, held)
+    comb, mine = moe_routed._combine(topi, topw, share)
+    plan = ragged_plan(jnp.where(mine, topi - first, held).reshape(-1), 2,
+                       held, 128)
+    assert plan.tile_expert.shape == (6,)
+    assert int(plan.n_active) == tiles_active
+    assert int(jnp.sum(plan.row_token < n)) == int(jnp.sum(mine))
+    want = moe_routed._dense(x, comb, gate, up, down, 1, jax.nn.silu)
+    got = moe_routed._prefill(x, topi, topw, mine, share, gate, up, down, 1,
+                              jax.nn.silu, True)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=0.02)
+    if not tiles_active:
+        assert not np.asarray(got, np.float32).any()
+
+
 def test_whole_layer_share_is_plain_top_k_softmax_scaling():
     """held == total: nothing is left out, every choice is held."""
     logits = jax.random.normal(jax.random.PRNGKey(3), (7, 8))
