@@ -373,11 +373,12 @@ def _routing(cfg) -> Dict[str, Any]:
 
 
 def moe_block(hidden, lp, experts, layer, cfg):
-    """Shared experts plus this chip's share of the routed sum;
-    `experts` holds the `[L, held, ...]` stacks, read at `layer`; `cfg`
-    any config with the routing keys and `share` (this family's, or
-    `models/dots3_note.py`'s). Returns `[B, sq, D]` and the `STATS`
-    increments."""
+    """Shared experts (where the layer has any: `shared_gate` among its
+    leaves) plus this chip's share of the routed sum; `experts` holds
+    the `[L, held, ...]` stacks, read at `layer`; `cfg` any config with
+    the routing keys and `share` (this family's, `models/
+    dots3_note.py`'s, or `models/mimo_v2.py`'s, which shares nothing).
+    Returns `[B, sq, D]` and the `STATS` increments."""
     b, sq, d = hidden.shape
     xf = hidden.reshape(-1, d)
     with jax.named_scope("moe.router"):
@@ -392,9 +393,10 @@ def moe_block(hidden, lp, experts, layer, cfg):
             routing["bias"] = lp["router_bias"]
         y, stats = routed_experts(xf, logits, experts, cfg.share,
                                   act=jax.nn.silu, layer=layer, **routing)
-    with jax.named_scope("moe.shared"):
-        y = y + swiglu(xf, lp["shared_gate"], lp["shared_up"],
-                       lp["shared_down"])
+    if "shared_gate" in lp:
+        with jax.named_scope("moe.shared"):
+            y = y + swiglu(xf, lp["shared_gate"], lp["shared_up"],
+                           lp["shared_down"])
     return y.reshape(b, sq, d), stats
 
 

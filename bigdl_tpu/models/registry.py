@@ -240,6 +240,24 @@ def _register_builtin() -> None:
             new_cache=evabyte_mod.new_cache,
         ))
 
+    from bigdl_tpu.models import mimo_v2 as mimo_mod
+
+    # grouped-query attention of two kinds (full planes beside K/V
+    # rings of the window layers, keys wider than values, a sink in the
+    # window layers' softmax), sigmoid bias-corrected router with no
+    # shared expert; slab only, bf16 planes only
+    register_family(
+        ["MiMoV2ForCausalLM", "MiMoV2FlashForCausalLM"],
+        FamilyAdapter(
+            name="mimo_v2",
+            config_from_hf=mimo_mod.MimoV2Config.from_hf,
+            convert_params=mimo_mod.convert_hf_params,
+            forward=mimo_mod.forward,
+            prefill=mimo_mod.forward_last_token,
+            forward_train=None,
+            new_cache=mimo_mod.new_cache,
+        ))
+
     from bigdl_tpu.models import rwkv as rwkv_mod
 
     def rwkv_adapter(version: int) -> FamilyAdapter:

@@ -115,7 +115,9 @@ def _live_window(sliding_window, skv: int):
     """A static window at least as long as the whole cache masks
     nothing (key j is cut only when j <= q_pos - window < 0): drop it,
     so e.g. Mistral's published 4096 window served at max_seq 2048
-    keeps the Pallas kernels, which implement no window."""
+    keeps the Pallas kernels of this dispatch, which implement no
+    window (a live window takes the XLA ops here; the one window
+    kernel, `ops/swa.window_decode`'s, reads a K/V ring instead)."""
     if isinstance(sliding_window, int) and sliding_window >= skv:
         return None
     return sliding_window

@@ -68,6 +68,18 @@ multiples of the window only: `seeded` and the host prefix cache refuse
 it (`CacheSpec.has_strided`). `KVCache.stride` (static) is the stride of
 its strided planes, which is how `max_seq` is read off them.
 
+K/V planes of two kinds of layer (`models/mimo_v2.py`): the full
+layers' `full_k` `[Lf, B, S, Hkv x hd_k]` and `full_v` `[Lf, B, S, Hkv x
+hd_v]`, K and V of DIFFERENT widths, and the window layers' `ring_k` /
+`ring_v` `[Lw, B, ring, Hkv' x hd]`, RINGS written at `pos % ring` whose
+length does not follow `max_seq`. These four keep a position's heads
+SIDE BY SIDE in the lanes (one row of `Hkv x hd` values, a multiple of
+128): a `[.., Hkv, 192]` plane is padded to 256 lanes wherever a kernel
+reads it (AOT for v5e: a copy of the whole K stack a call, PERF.md 6
+PR 45), rows of 768 tile as they lie. They are rings or full planes
+under the rules above: spliced, exported, counted and refused a
+snapshot exactly as the latent ring is.
+
 Layout: [num_layers, batch, max_seq, kv_heads, head_dim] — the whole stack is
 one array per K/V so a `lax.scan` over layers can carry it. In place means
 addressed on the stack: `update_layer` writes its rows at `[layer, ...]` and
@@ -155,10 +167,11 @@ def kv_dtype_name(storage_dtype) -> str:
 # planes a cache may hold, in the order `planes()` lists them; every one
 # is [L, B, ...] and its positions run along `plane_seq_axis(name)`
 PLANE_NAMES = ("k", "v", "k_scale", "v_scale", "latent", "index", "window",
-               "sum_k", "sum_v", "win_k", "win_v")
+               "sum_k", "sum_v", "win_k", "win_v",
+               "full_k", "full_v", "ring_k", "ring_v")
 _SEQ_AXIS = {"latent": 3, "index": 3, "window": 3}
 # planes that are rings (module docstring)
-RING_PLANES = ("window",)
+RING_PLANES = ("window", "ring_k", "ring_v")
 # planes with one column every `stride` positions
 STRIDED_PLANES = ("sum_k", "sum_v")
 # K/V planes of one window, refilled from column 0
@@ -202,9 +215,11 @@ class CacheSpec:
     family hands the cache manager (`cache_spec(cfg)` of the module that
     owns its forward; a family without one keeps K and V of
     `num_key_value_heads x hd`). kind "kv": K and V planes of
-    `[L, B, S, kv_heads, head_dim]`; kind "latent": one plane of
-    `[L, B, latent_dim, S]`, or the planes `planes` lists (bf16, as a
-    latent plane is)."""
+    `[L, B, S, kv_heads, head_dim]`, or the planes `planes` lists (bf16
+    only: window and summary planes, or K/V planes of layers of two
+    kinds, full-length and rings, K and V of their own widths); kind
+    "latent": one plane of `[L, B, latent_dim, S]`, or the planes
+    `planes` lists (bf16, as a latent plane is)."""
     kind: str
     num_layers: int
     kv_heads: int = 0
@@ -306,17 +321,19 @@ def reject_non_bf16_latent(spec) -> str:
 
 
 def reject_non_bf16_strided(spec) -> str:
-    """A cache of window and summary planes is bf16 only; says so
-    instead of storing codes no kernel of the family reads."""
+    """A K/V cache that lists its planes (window and summary planes,
+    or full planes beside K/V rings) is bf16 only; says so instead of
+    storing codes no kernel of the family reads."""
     name = resolve_kv_cache_dtype(spec)
     if name != "bf16":
         raise NotImplementedError(
             f"kv_cache_dtype {name!r} is not supported for a cache of "
-            f"window and summary planes (chunked linearized attention): "
+            f"window and summary planes (chunked linearized attention) or "
+            f"of full K/V planes beside K/V rings (window layers): "
             f"they are stored in bf16 only (a summary is a softmax-weighted "
             f"mean of 16 keys, and an fp8 or int8 plane fails the layer "
-            f"check, PERF.md 6 PR 39; a quantized window or summary plane "
-            f"is a later issue)")
+            f"check, PERF.md 6 PR 39; the ring kernel reads bf16 rows; a "
+            f"quantized window, summary or ring plane is a later issue)")
     return name
 
 
@@ -345,13 +362,21 @@ class KVCache:
     sum_v: Optional[jax.Array] = None
     win_k: Optional[jax.Array] = None     # [L, B, window, H, D]
     win_v: Optional[jax.Array] = None
+    # K/V of layers of two kinds, a position's heads side by side in
+    # the lanes: the full layers' planes and the window layers' rings
+    full_k: Optional[jax.Array] = None    # [Lf, B, S_max, H_kv * D_k]
+    full_v: Optional[jax.Array] = None    # [Lf, B, S_max, H_kv * D_v]
+    ring_k: Optional[jax.Array] = None    # [Lw, B, ring, H_kv' * D_k]
+    ring_v: Optional[jax.Array] = None
     # static: positions a column of the strided planes reduces
     stride: int = 1
 
     def tree_flatten(self):
         return (self.k, self.v, self.pos, self.k_scale, self.v_scale,
                 self.latent, self.stats, self.index, self.window,
-                self.sum_k, self.sum_v, self.win_k, self.win_v), self.stride
+                self.sum_k, self.sum_v, self.win_k, self.win_v,
+                self.full_k, self.full_v, self.ring_k,
+                self.ring_v), self.stride
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -583,6 +608,33 @@ def update_ring(stack: jax.Array, layer, new: jax.Array,
         new[:, s_new - keep:].astype(stack.dtype), unique_indices=True)
 
 
+def update_rows(stack: jax.Array, layer, new: jax.Array, pos: jax.Array,
+                ring: bool = False) -> jax.Array:
+    """Write `new` `[B, S_new, W]` into layer `layer` of a stack `[L, B,
+    S, W]` whose rows are positions: row i of slot b lands at `pos[b] +
+    i` (`pos` a scalar or one a slot), or with `ring` in column `(pos[b]
+    + i) % S`, where of more rows than the ring holds only the last `S`
+    are written. One `dynamic_update_slice` for a scalar `pos` on a
+    plane that keeps every position, else one scatter of the new rows;
+    both address the stack itself, as `update_layer` does."""
+    new = new.astype(stack.dtype)
+    n = stack.shape[2]
+    if getattr(pos, "ndim", 0) == 0 and not ring:
+        return jax.lax.dynamic_update_slice(stack, new[None],
+                                            (layer, 0, pos, 0))
+    b, s_new = new.shape[:2]
+    posv = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
+    keep = min(s_new, n) if ring else s_new
+    at = (jnp.maximum(posv, 0)[:, None] + (s_new - keep)
+          + jnp.arange(keep, dtype=jnp.int32)[None, :])
+    if ring:
+        at = at % n
+    slot = jnp.arange(b, dtype=jnp.int32)[:, None]
+    return stack.at[layer, slot, at].set(
+        new[:, s_new - keep:], indices_are_sorted=not ring,
+        unique_indices=True, mode="drop")
+
+
 def quantize_kv(x: jax.Array, storage_dtype) -> Tuple[jax.Array, jax.Array]:
     """Symmetric absmax quantization of the trailing [D] vectors.
 
@@ -749,15 +801,17 @@ def publish_kv_cache_bytes(cache: KVCache, registry=None) -> Dict[str, int]:
             "bigdl_tpu_kv_cache_bytes",
             "KV cache storage bytes by dtype and component "
             "(codes | scales | total, and latent | index | window | "
-            "window_kv | summary for such planes); int4 counted at two "
-            "codes per byte",
+            "window_kv | summary | full_kv | ring_kv for such planes); "
+            "int4 counted at two codes per byte",
             labelnames=("dtype", "component"))
         for comp, val in sizes.items():
             g.labels(cache.kv_dtype, comp).set(float(val))
         for comp, names in (("latent", ("latent",)), ("index", ("index",)),
                             ("window", ("window",)),
                             ("window_kv", ("win_k", "win_v")),
-                            ("summary", ("sum_k", "sum_v"))):
+                            ("summary", ("sum_k", "sum_v")),
+                            ("full_kv", ("full_k", "full_v")),
+                            ("ring_kv", ("ring_k", "ring_v"))):
             held = [getattr(cache, n) for n in names
                     if getattr(cache, n) is not None]
             if held:

@@ -304,8 +304,14 @@ def decode_attention_pallas(
 def attention_geometry_ok(q, k, logits_soft_cap, sliding_window,
                           alibi_slopes, k_scale=None) -> bool:
     """Shared feature/geometry gate for BOTH Pallas attention kernels
-    (decode + blockwise prefill): plain softmax attention only, aligned
-    shapes, KV dtypes the kernels upcast (or dequantize) in-register."""
+    (decode + blockwise prefill): plain softmax attention only (no
+    window, no soft cap, no alibi, no sink), K and V of one `(kv_heads,
+    head_dim)`, aligned shapes, KV dtypes the kernels upcast (or
+    dequantize) in-register. A window over a K/V ring, a sink in the
+    softmax and K wider than V are `ops/pallas/swa_attention.py`'s, on
+    planes that keep a position's heads in the lanes (a head of 192
+    values is no whole lane tile: this kernel's `[.., Hkv, hd]` blocks
+    would be padded to 256 by a copy of the stack)."""
     if alibi_slopes is not None:
         return False
     if logits_soft_cap is not None or sliding_window is not None:

@@ -84,6 +84,7 @@ from bigdl_tpu.ops.pallas.paged_decode_attention import pages_read
 from bigdl_tpu.ops.paged import (NULL_PAGE, cow_copy_pages,
                                  gather_pages_dense, paged_cache_bytes,
                                  publish_paged_cache_bytes, splice_pages)
+from bigdl_tpu.ops.swa import rows_read as swa_rows_read
 from bigdl_tpu.robustness import (resolve_drain_timeout_sec,
                                   resolve_request_deadline_ms)
 from bigdl_tpu.robustness.faults import FaultInjector
@@ -1105,6 +1106,22 @@ class LLMEngine:
                 "kind=summary one row a chunk of every earlier window, "
                 "kind=context the positions full attention would read.",
                 labelnames=("kind",))
+        # a family of full and window K/V layers (ring planes): (window
+        # layers, full layers, the window); else None
+        self._swa = None
+        if not self._paged and self.cache.ring_k is not None:
+            self._swa = (int(self.cache.ring_k.shape[0]),
+                         int(self.cache.full_k.shape[0])
+                         if self.cache.full_k is not None else 0,
+                         int(self.cfg.sliding_window_size))
+            self._m_swa_rows = m.counter(
+                "bigdl_tpu_swa_rows_total",
+                "Rows of K a decode step's queries read, all layers and "
+                "live slots: kind=window those of the window layers' "
+                "rings (the last sliding_window positions, the query's "
+                "own counted), kind=full those of the full layers' "
+                "planes, kind=context those a model of as many layers, "
+                "all full, would read.", labelnames=("kind",))
         self._m_prefill_chunks = m.counter(
             "bigdl_tpu_prefill_chunks_total",
             "Prefill chunks dispatched by admission (at most one per "
@@ -4552,6 +4569,18 @@ class LLMEngine:
                 window, stride)
             for kd, n in rows.items():
                 self._m_eva_rows.labels(kd).inc(n)
+        if self._swa is not None:
+            # what the two decode kernels read this step, by their own
+            # rule from the positions the host already knows (outside
+            # the phases)
+            n_win, n_full, window = self._swa
+            rows = swa_rows_read(
+                [len(self.slots[i].req.prompt_token_ids)
+                 + len(self.slots[i].generated) - 1 for i in active], window)
+            self._m_swa_rows.labels("window").inc(n_win * rows["window"])
+            self._m_swa_rows.labels("full").inc(n_full * rows["full"])
+            self._m_swa_rows.labels("context").inc(
+                (n_win + n_full) * rows["full"])
         toks = None
         finite_host = None
         n_emit = None       # verify step: tokens each slot kept, [B]

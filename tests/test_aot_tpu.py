@@ -1611,3 +1611,149 @@ def test_deepseek_v32_verify_step_compiles_and_fits(v5e, aot_flags):
         r"= \w+\[(?:\d+,)?8,(?:576|128),8192\]\S* "
         r"(?:copy|fusion|dynamic-slice)\(", txt)
     assert not moved, f"a layer of a cache plane is materialized: {moved}"
+
+
+# -- MiMo-V2 (PR 45): full K/V planes beside K/V rings, a sink ------------
+
+MIMO = dict(b=16, s=16384, full=3, win=9, ring=128)
+
+
+@pytest.mark.parametrize("kernel", ["decode_attention_lanes",
+                                    "swa_decode_attention"])
+def test_mimo_v2_decode_kernels_compile_at_published_widths(v5e, aot_flags,
+                                                            kernel):
+    """The two decode kernels for one v5e at the published widths over
+    the cell's slab (16 slots; 64 query heads of 192 on 4 KV heads over
+    rows of 768 / 512 values and 16384 positions; on 8 KV heads over
+    rings of 128 rows of 1,536 / 1,024 with the sink), the stack as
+    operand and the layer a prefetched scalar: a Mosaic call and no copy
+    of a plane (a `[.., 4, 192]` stack is copied whole at 256 lanes,
+    1.61 GB a call: PERF.md 6, PR 45)."""
+    from bigdl_tpu.ops.pallas import swa_attention as K
+
+    dev = v5e.devices[0]
+    b, s, ring = MIMO["b"], MIMO["s"], MIMO["ring"]
+    bf, i32 = jnp.bfloat16, jnp.int32
+
+    def sd(shape, dt=bf):
+        return _sds(jax.ShapeDtypeStruct(shape, dt), dev)
+
+    pos, lyr, q = sd((b,), i32), sd((), i32), sd((b, 64, 192))
+    if kernel == "decode_attention_lanes":
+        comp = _compile(
+            lambda q_, k, v, p, ly: K.decode_attention_lanes_pallas(
+                q_, k, v, p, 192 ** -0.5, 4, layer=ly),
+            q, sd((MIMO["full"], b, s, 768)), sd((MIMO["full"], b, s, 512)),
+            pos, lyr)
+    else:
+        comp = _compile(
+            lambda q_, k, v, p, ly, sk: K.swa_decode_attention_pallas(
+                q_, k, v, p, 192 ** -0.5, 8, 128, sink=sk, layer=ly),
+            q, sd((MIMO["win"], b, ring, 1536)),
+            sd((MIMO["win"], b, ring, 1024)), pos, lyr,
+            sd((64,), jnp.float32))
+    assert _has_mosaic_call(comp)
+    assert comp.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
+
+
+def _mimo_v2_engine():
+    import json
+    import sys
+    from pathlib import Path
+
+    bench = Path(__file__).resolve().parents[1] / "benchmark"
+    sys.path[:0] = [str(bench)]
+    from harness import weights_mimo_v2 as weights
+    from harness.weights import _family_config
+
+    from bigdl_tpu.models import mimo_v2
+    from bigdl_tpu.ops.quant import prepack_tree
+    from bigdl_tpu.serving import EngineConfig, LLMEngine
+
+    doc = json.loads(
+        (bench / "configs" / "mimo-v25-ep8-int4.json").read_text())
+    family, cfg, hf = _family_config(doc)
+
+    class Model:
+        params = jax.eval_shape(lambda: prepack_tree(
+            mimo_v2.prepare_params(
+                weights.build_params(cfg, "sym_int4", 1), cfg), "on")[0])
+        config, hf_config, qtype = cfg, hf, "sym_int4"
+
+    Model.family = family
+    eng = doc["engine"]
+    return LLMEngine(Model, EngineConfig(
+        max_batch=eng["max_batch"], max_seq=eng["max_seq"],
+        prefill_chunk=eng["prefill_chunk"],
+        prefill_bucket=eng["prefill_bucket"], sentinel=False,
+        quality=False))
+
+
+def test_mimo_v2_engine_decode_step_compiles_and_fits(v5e, aot_flags):
+    """The engine's resident decode step for the cell's configuration (12
+    layers at published widths, 16 slots x 16384, shapes only): both
+    decode kernels and the routed kernel are in it, no instruction
+    materializes a layer of a plane, and arguments plus temporaries stay
+    under 9.5 GB of the chip's 16."""
+    import re
+
+    dev = v5e.devices[0]
+    eng = _mimo_v2_engine()
+    b = eng.cfg_engine.max_batch
+    i32 = _sds(jax.ShapeDtypeStruct((b,), jnp.int32), dev)
+    f32 = _sds(jax.ShapeDtypeStruct((b,), jnp.float32), dev)
+    comp = eng._decode_resident.lower(
+        _sds(eng.params, dev), i32,
+        _sds(jax.eval_shape(lambda: eng.cache), dev),
+        f32, i32, f32, i32, i32, all_greedy=True,
+        with_quality=False).compile()
+    txt = comp.as_text()
+    for name in ("decode_attention_lanes", "swa_decode_attention",
+                 "moe_routed_decode", "qmatmul_gemv_sym_int4"):
+        assert name in txt, name
+    ma = comp.memory_analysis()
+    live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert 7.5e9 < live < 9.5e9, live / 1e9    # 6 GB weights + 2.1 GB slab
+    moved = re.findall(
+        r"= \w+\[(?:1,)?16,(?:16384|128),(?:768|512|1536|1024)\]\S* "
+        r"(?:copy|fusion|dynamic-slice)\(", txt)
+    assert not moved, f"a layer of a cache plane is materialized: {moved}"
+    assert not re.findall(
+        r"= \w+\[(?:3|9),16,(?:16384|128),(?:768|512|1536|1024)\]\S* copy\(",
+        txt)
+
+
+@pytest.mark.parametrize("alloc", [4096, 16384])
+def test_mimo_v2_prefill_chunk_keeps_no_rows_by_keys_scores(v5e, aot_flags,
+                                                            alloc):
+    """One 1024-row prefill chunk AS THE ENGINE BUILDS IT into a private
+    cache of 4096 and of 16384 positions: no float32 `[heads, rows, S]`
+    temporary (the full layers sweep 512-key blocks, the window layers a
+    band of 256 + 127 keys), the int4 GEMM in every linear, and
+    temporaries that do not grow with the cache's length but for the
+    full layers' one layer of planes."""
+    import re
+
+    from bigdl_tpu.ops.kvcache import init_cache_spec
+
+    dev = v5e.devices[0]
+    eng = _mimo_v2_engine()
+    chunk = eng._chunk
+    assert chunk == 1024
+    cache1 = jax.eval_shape(lambda: init_cache_spec(
+        eng._cache_spec.unrolled(), 1, alloc,
+        kv_cache_dtype=eng.kv_cache_dtype))
+    tokens = jax.ShapeDtypeStruct((1, chunk), jnp.int32)
+    comp = eng._prefill.lower(_sds(eng.params, dev), _sds(tokens, dev),
+                              _sds(cache1, dev)).compile()
+    txt = comp.as_text()
+    assert "qmatmul_gemm_sym_int4" in txt and "moe_routed_prefill" in txt
+    wide = re.findall(rf"f32\[(?:1,)?(?:64|4,16|8,8),1024,{alloc}\]", txt)
+    assert not wide, f"[heads, rows, S] in float32: {wide[:3]}"
+    ma = comp.memory_analysis()
+    # scores of one key block [64, 1024, 512] f32 are 134 MB
+    assert ma.temp_size_in_bytes < 1.0e9, ma.temp_size_in_bytes / 1e9
+    print("mimo_v2 prefill chunk", alloc, "temp GB",
+          ma.temp_size_in_bytes / 1e9, "args GB",
+          ma.argument_size_in_bytes / 1e9)

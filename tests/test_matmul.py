@@ -146,6 +146,16 @@ _EVABYTE_CHOSE = {
     (11008, 4096): (('gemv_mxu', (256, 512)), ('gemm', (256, 512))),
     (4096, 2560): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
 }
+# MiMo-V2.5's linears (PR 45): merged q/k/v of a full and of a window
+# layer, W_o, the dense layer's gate / up and down, the head's slice
+_MIMO_CHOSE = {
+    (4096, 13568): (('gemv_mxu', (4096, 256)), ('gemm', (4096, 256))),
+    (4096, 14848): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
+    (8192, 4096): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
+    (4096, 16384): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
+    (16384, 4096): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
+    (4096, 19072): (('gemv_mxu', (4096, 128)), ('gemm', (4096, 128))),
+}
 _TPU = dict(int4_layout=True, spmd=False, tpu=True)
 _CANON = dict(_TPU, int4_layout=False)
 # the rules, one case each: (qtype, rows, K, N, what the call sees, plan)
@@ -202,7 +212,10 @@ _RULES = [
     for rows, plan in zip((8, 256, 8192), (*at, ("xla", None)))] + [
     ("sym_int4", rows, k, n, _TPU, plan)
     for (k, n), at in _EVABYTE_CHOSE.items()
-    for rows, plan in zip((6, 256, 1024), (*at, at[1]))] + _RULES)
+    for rows, plan in zip((6, 256, 1024), (*at, at[1]))] + [
+    ("sym_int4", rows, k, n, _TPU, plan)
+    for (k, n), at in _MIMO_CHOSE.items()
+    for rows, plan in zip((16, 1024), at)] + _RULES)
 def test_kernel_selection_table(qtype, rows, k, n, sees, want):
     """`select_matmul` is the one place a quantized linear's plan is
     chosen, from what the call can see; at every linear of the three
