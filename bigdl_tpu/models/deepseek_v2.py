@@ -68,7 +68,7 @@ from jax import lax
 from bigdl_tpu.models.llama import embedding_lookup
 from bigdl_tpu.ops.kvcache import (CacheSpec, KVCache, init_cache_spec,
                                    update_latent)
-from bigdl_tpu.ops.matmul import linear
+from bigdl_tpu.ops.matmul import hold_stacks, layer_params, linear
 from bigdl_tpu.ops.moe_routed import STATS, Share, routed_experts
 from bigdl_tpu.ops.norms import rms_norm
 from bigdl_tpu.ops.pallas.mla_attention import mla_decode_attention
@@ -427,17 +427,22 @@ def forward(
 
     lat = cache.latent
     n_dense = cfg.n_dense
+    # the quantized stacks stay whole in both scans: a linear's kernel
+    # reads its layer where it lies (`ops/matmul.hold_stacks`)
     if n_dense:
+        held, scanned = hold_stacks(params["dense_layers"])
+
         def dense_step(carry, xs):
             x, lat = carry
             lp, lidx = xs
+            lp = layer_params(held, lp, lidx)
             x, lat, hid = attn_part(x, lat, lp, lidx)
             return (x + swiglu(hid, lp["gate_proj"], lp["up_proj"],
                                lp["down_proj"]), lat), None
 
         (x, lat), _ = lax.scan(
             dense_step, (x, lat),
-            (params["dense_layers"], jnp.arange(n_dense, dtype=jnp.int32)))
+            (scanned, jnp.arange(n_dense, dtype=jnp.int32)))
 
     stats = cache.stats
     n_moe = cfg.num_hidden_layers - n_dense
@@ -447,13 +452,15 @@ def forward(
         # layer where it lies, a per-layer slice would copy every held
         # expert every step
         experts = {k: moe[k] for k in _EXPERT_KEYS}
-        scanned = {k: v for k, v in moe.items() if k not in _EXPERT_KEYS}
+        held, scanned = hold_stacks(
+            {k: v for k, v in moe.items() if k not in _EXPERT_KEYS})
         tally = (jnp.zeros((len(STATS),), jnp.int32) if stats is None
                  else stats)
 
         def moe_step(carry, xs):
             x, lat, tally = carry
             lp, i = xs
+            lp = layer_params(held, lp, i)
             x, lat, hid = attn_part(x, lat, lp, i + n_dense)
             y, st = moe_block(hid, lp, experts, i, cfg)
             return (x + y, lat, tally + st), None

@@ -37,7 +37,7 @@ from bigdl_tpu.ops.attention import sdp_attention, sdp_attention_paged
 from bigdl_tpu.ops.kvcache import KVCache, init_cache, update_layer
 from bigdl_tpu.ops.paged import (PagedKVCache, init_paged_cache,
                                  paged_update_layer)
-from bigdl_tpu.ops.matmul import linear
+from bigdl_tpu.ops.matmul import hold_stacks, layer_params, linear
 from bigdl_tpu.ops.embedding import embedding_lookup
 from bigdl_tpu.ops.norms import layer_norm, rms_norm
 from bigdl_tpu.ops.rope import (apply_rope, rope_cos_sin, rope_freqs,
@@ -691,12 +691,17 @@ def _decoder_layer(x, lp, cfg: LlamaConfig, cos, sin, slopes,
     return x, cache_out
 
 
-def _layer_step(cfg: LlamaConfig, slopes, carry, xs):
+def _layer_step(cfg: LlamaConfig, slopes, held, carry, xs,
+                block_tables=None):
+    """The body of `forward`'s and `forward_paged`'s layer scan. The
+    quantized `[L, K, N]` stacks (`held`) are closed over and read at
+    `lidx` where they lie; `xs` scans the small leaves by value."""
     x, ck, cv, cks, cvs, pos, cos, sin = carry
     lp, lidx = xs
     x, (ck, cv, cks, cvs) = _decoder_layer(
-        x, lp, cfg, cos, sin, slopes,
-        cache_ctx=(ck, cv, cks, cvs, lidx, pos), lidx=lidx)
+        x, layer_params(held, lp, lidx), cfg, cos, sin, slopes,
+        cache_ctx=(ck, cv, cks, cvs, lidx, pos), lidx=lidx,
+        block_tables=block_tables)
     return (x, ck, cv, cks, cvs, pos, cos, sin), None
 
 
@@ -743,12 +748,13 @@ def forward(
     slopes = _model_slopes(cfg)
 
     lidx = jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32)
+    held, scanned = hold_stacks(params["layers"])
     # scale planes are None for bf16/fp8 storage — None is an empty
     # pytree, so the scan carry structure stays consistent either way
     (x, ck, cv, cks, cvs, _, _, _), _ = lax.scan(
-        lambda c, xs: _layer_step(cfg, slopes, c, xs),
+        lambda c, xs: _layer_step(cfg, slopes, held, c, xs),
         (x, cache.k, cache.v, cache.k_scale, cache.v_scale, pos, cos, sin),
-        (params["layers"], lidx),
+        (scanned, lidx),
     )
 
     if last_only:
@@ -769,16 +775,6 @@ def forward_last_token(
     """Prefill variant of `forward` with lm_head on the final position only."""
     return forward(params, cfg, tokens, cache, compute_dtype=compute_dtype,
                    last_only=True, visual=visual)
-
-
-def _paged_layer_step(cfg: LlamaConfig, slopes, block_tables, carry, xs):
-    x, ck, cv, cks, cvs, pos, cos, sin = carry
-    lp, lidx = xs
-    x, (ck, cv, cks, cvs) = _decoder_layer(
-        x, lp, cfg, cos, sin, slopes,
-        cache_ctx=(ck, cv, cks, cvs, lidx, pos), lidx=lidx,
-        block_tables=block_tables)
-    return (x, ck, cv, cks, cvs, pos, cos, sin), None
 
 
 def forward_paged(
@@ -808,10 +804,11 @@ def forward_paged(
     slopes = _model_slopes(cfg)
 
     lidx = jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32)
+    held, scanned = hold_stacks(params["layers"])
     (x, ck, cv, cks, cvs, _, _, _), _ = lax.scan(
-        lambda c, xs: _paged_layer_step(cfg, slopes, block_tables, c, xs),
+        lambda c, xs: _layer_step(cfg, slopes, held, c, xs, block_tables),
         (x, cache.k, cache.v, cache.k_scale, cache.v_scale, pos, cos, sin),
-        (params["layers"], lidx),
+        (scanned, lidx),
     )
 
     if last_only:

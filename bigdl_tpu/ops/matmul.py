@@ -20,11 +20,14 @@ Which of them a call takes, and at which tiles, is `select_matmul`'s
 answer: one function of what the call can see.
 
 The public entry is `q_matmul(x, w)` where `w` is a QTensor of logical shape
-[K, N] (contraction-major; see ops/quant.py) and x is [..., K].
+[K, N] (contraction-major; see ops/quant.py) and x is [..., K], or a
+`StackedQ`: one layer of a scanned model's `[L, K, N]` stack, which a
+kernel plan reads where it lies.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import NamedTuple, Optional, Tuple
 
@@ -33,6 +36,51 @@ import jax.numpy as jnp
 
 from bigdl_tpu.ops.quant import (QTensor, dequantize_impl as dequantize,
                                  get_qtype)
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class StackedQ:
+    """Layer `layer` of `stack`, a QTensor whose planes are the `[L,
+    ...]` stacks of a scanned model's layers: what a layer scan hands to
+    `linear()` in place of a per-layer slice. A kernel plan addresses
+    the layer in its index maps; an XLA plan takes it with `take()`,
+    which is the slice the scan made itself."""
+
+    stack: QTensor
+    layer: jax.Array          # int32 scalar
+
+    def tree_flatten(self):
+        return (self.stack, self.layer), None
+
+    @classmethod
+    def tree_unflatten(cls, _, children):
+        return cls(*children)
+
+    def take(self) -> QTensor:
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, self.layer, 0,
+                                                   keepdims=False),
+            self.stack)
+
+    def apply_linear(self, x, bias=None, *, backend=None):
+        return q_linear(x, self, bias, backend=backend)
+
+
+def hold_stacks(layers: dict):
+    """`(held, scanned)` of a scanned model's stacked layers: the plain
+    QTensor leaves `[L, K, N]` stay whole, for the scan to close over;
+    every other leaf (norms, biases, dense or adapter-wrapped weights,
+    expert stacks) is scanned by value."""
+    held = {k: v for k, v in layers.items()
+            if isinstance(v, QTensor) and v.data.ndim == 3}
+    return held, {k: v for k, v in layers.items() if k not in held}
+
+
+def layer_params(held: dict, lp: dict, layer) -> dict:
+    """One layer's leaves inside the scan: the scanned `lp` plus each
+    held stack presented at `layer` (`hold_stacks`)."""
+    return {**lp, **{k: StackedQ(v, layer) for k, v in held.items()}}
+
 
 # qtypes the Pallas dequant-matmul kernels cover (sym / asym / codebook
 # codes of 4 bits, sym codes of 8)
@@ -317,14 +365,19 @@ def select_matmul(qtype: str, rows: int, kp: int, n: int, *,
     return _xla_plan(qtype, rows, kp, n)
 
 
-def _q_matmul_dispatch(x: jax.Array, w: QTensor, be: str,
+def _q_matmul_dispatch(x: jax.Array, w, be: str,
                        interpret: bool = False) -> jax.Array:
     """Ask `select_matmul`, probe the kernel it chose (auto on a live
     TPU: a kernel the compiler refuses raises, ops/probing.py) and run
-    the plan."""
+    the plan. A `StackedQ` goes to a kernel as the stack and the layer;
+    for an XLA plan its layer is taken first."""
     from bigdl_tpu.config import target_is_tpu, under_spmd
     from bigdl_tpu.ops.pallas import dequant_matmul as dq
+    from bigdl_tpu.ops.probing import record_dispatch_rule, record_stacked
 
+    view, layer = None, None
+    if isinstance(w, StackedQ):
+        view, w, layer = w, w.stack, w.layer
     rows, (k, n) = _rows(x), w.shape
     block = get_qtype(w.qtype).block_size
     kp = -(-k // block) * block
@@ -337,18 +390,21 @@ def _q_matmul_dispatch(x: jax.Array, w: QTensor, be: str,
         if be == "auto":
             if plan.kind == GEMM:
                 dq.matmul_kernel_compiles(w.qtype, rows, kp, n, plan.tiles,
-                                          mxu=int4)
+                                          mxu=int4, stacked=view is not None)
             else:
                 dq.gemv_kernel_compiles(w.qtype, kp, plan.tiles, m=rows,
-                                        mxu=int4)
+                                        mxu=int4, stacked=view is not None)
+        if view is not None:
+            record_stacked("matmul", in_place=True)
         return dq.q_matmul_kernel(x, w, plan.kind != GEMM, plan.tiles,
-                                  interpret=interpret)
+                                  layer=layer, interpret=interpret)
+    if view is not None:
+        record_stacked("matmul", in_place=False)
+        w = view.take()
     if be == "auto" and tpu:
         # XLA by design (rows past the crossover, GSPMD-sharded
         # operands, a qtype or tiling the kernels do not cover): a
         # dispatch rule, counted apart from probe outcomes
-        from bigdl_tpu.ops.probing import record_dispatch_rule
-
         record_dispatch_rule("matmul")
     if plan.kind == XLA_FUSED:
         return _q_matmul_xla_fused(x, w)
@@ -435,6 +491,8 @@ def _q_matmul_bwd(be, w, dy):
     # its cotangent is zero. This also makes the non-differentiable Pallas
     # forward transparently trainable-through.
     dw = jax.tree.map(_zero_cotangent, w)
+    if isinstance(w, StackedQ):
+        w = w.take()
     if w.qtype in _HEAVY_DECODE_QTYPES:
         dx = _q_matmul_bwd_chunked(dy, w)
         if dx is not None:
@@ -476,8 +534,9 @@ def _q_matmul_bwd_chunked(dy: jax.Array, w: QTensor,
 _q_matmul_vjp.defvjp(_q_matmul_fwd, _q_matmul_bwd)
 
 
-def q_matmul(x: jax.Array, w: QTensor, *, backend: Optional[str] = None) -> jax.Array:
-    """Compute x @ W for a quantized W of logical shape [K, N].
+def q_matmul(x: jax.Array, w, *, backend: Optional[str] = None) -> jax.Array:
+    """Compute x @ W for a quantized W of logical shape [K, N] (a
+    QTensor, or a `StackedQ`).
 
     x: [..., K] float array. Returns [..., N] in x.dtype. Differentiable
     w.r.t. x (dequant-matmul backward); the weight gets zero cotangent.

@@ -17,6 +17,13 @@ Layout contract (see ops/quant.py):
   zero  f16   [Kp/B, N]  — asym only
   int8: data int8 [Kp, N]
 
+A scanned model hands over the `[L, ...]` STACKS of its layers' planes
+and the layer index, which the grid prefetches: the weight's index maps
+address the layer where it lies, no per-layer slice is written (a slice
+read and wrote the bytes the GEMV then reads once: PERF.md 6, PR 46).
+A single `[K, N]` weight takes the same bodies through the plain grid
+(`_weight_call`).
+
 Grid: (M/bm, N/bn, K/bk), K innermost, f32 accumulation in VMEM scratch.
 At a prefill chunk's 256 rows (one row tile) each weight tile is read
 from HBM once, as packed codes, and dequantized once: XLA's plan for the
@@ -345,13 +352,22 @@ _gemv_probe_cache: set = set()
 _matmul_probe_cache: set = set()
 
 
+def _probe_weight(kp: int, n: int, qtype: str, mxu: bool, stacked: bool):
+    """The probes' stand-in weight: `[kp, n]`, or a stack of two."""
+    from bigdl_tpu.ops.probing import quant_struct, stacked_struct
+
+    w = quant_struct(kp, n, qtype, mxu=mxu)
+    return stacked_struct(w, 2) if stacked else w
+
+
 def gemv_kernel_compiles(qtype: str, kp: int, tiles, m: int = 1,
-                         mxu: bool = False) -> bool:
+                         mxu: bool = False, stacked: bool = False) -> bool:
     """Compile probe of the decode GEMV at one geometry (contract in
     ops/probing.py: True, or `KernelProbeError`): compiles the REAL tile
     classes `tiles` = (bk, bn) on a stand-in sized (kp, bn). `mxu` says
     the codes are in the int4-dtype layout (the body follows from it);
-    `m` only selects the padded row class (16 or 32)."""
+    `m` only selects the padded row class (16 or 32); `stacked` says the
+    call reads a layer of a stack (`_weight_call`'s second form)."""
     from bigdl_tpu.config import flags as _flags
 
     if _flags().aot_target == "tpu":   # AOT lowering: the caller compiles
@@ -360,19 +376,19 @@ def gemv_kernel_compiles(qtype: str, kp: int, tiles, m: int = 1,
     mp = _gemv_mp(m)
     bk, bn = tiles
     variant = "mxu" if mxu else "std"
-    from bigdl_tpu.ops.probing import probe_kernel, quant_struct
+    from bigdl_tpu.ops.probing import probe_kernel
 
     return probe_kernel(
         f"gemv_{variant}", _gemv_probe_cache,
-        (qtype, kp, bn, bk, variant, mp),
+        (qtype, kp, bn, bk, variant, mp, stacked),
         lambda xx, ww: _q_gemv_pallas(xx, ww, qt, mp, kp, bn, tiles, False,
-                                      jnp.bfloat16),
+                                      jnp.bfloat16, layer=0),
         jax.ShapeDtypeStruct((mp, kp), jnp.bfloat16),
-        quant_struct(kp, bn, qtype, mxu=mxu))
+        _probe_weight(kp, bn, qtype, mxu, stacked))
 
 
 def matmul_kernel_compiles(qtype: str, m: int, kp: int, n: int, tiles,
-                           mxu: bool = False) -> bool:
+                           mxu: bool = False, stacked: bool = False) -> bool:
     """Compile probe of the GEMM (same contract as
     `gemv_kernel_compiles`). Keyed by the padded bm class, not the raw
     M."""
@@ -382,19 +398,56 @@ def matmul_kernel_compiles(qtype: str, m: int, kp: int, n: int, tiles,
         return True
     qt = get_qtype(qtype)
     bm = _generic_bm(m)[0]
-    from bigdl_tpu.ops.probing import probe_kernel, quant_struct
+    from bigdl_tpu.ops.probing import probe_kernel
 
     return probe_kernel(
         "matmul_generic", _matmul_probe_cache,
-        (qtype, bm, kp, n, bool(mxu)),
+        (qtype, bm, kp, n, bool(mxu), stacked),
         lambda xx, ww: _q_matmul_generic(xx, ww, qt, bm, kp, n, tiles,
-                                         False, jnp.bfloat16),
+                                         False, jnp.bfloat16, layer=0),
         jax.ShapeDtypeStruct((bm, kp), jnp.bfloat16),
-        quant_struct(kp, n, qtype, mxu=mxu))
+        _probe_weight(kp, n, qtype, mxu, stacked))
+
+
+def _weight_call(kernel, w: QTensor, layer, grid, in_specs, out_spec,
+                 scratch, weight_specs):
+    """`(call, body, prefetch)`: the `pallas_call` keyword arguments,
+    the body and the operands that lead the call, for a single `[K, N]`
+    weight or for the `[L, K, N]` stacks of a scanned model read at
+    `layer`.
+
+    `weight_specs` = [(block, index map)] of the weight's planes
+    (`_planes`), which follow `in_specs` among the operands. One weight:
+    the plain grid, nothing prefetched. A stack: the layer is prefetched
+    as `int32[1]`, the planes' blocks gain a squeezed leading dimension
+    that the index map fills from it, and the body is the same one,
+    called without the prefetched ref. By the weight's rank: a stack of
+    one through the stacked form read 0.3-1.6 % slower than the plain
+    call, kernel alone (my chip run, PR 46)."""
+    if w.data.ndim == 2:
+        specs = [pl.BlockSpec(blk, idx) for blk, idx in weight_specs]
+        return dict(grid_spec=pl.GridSpec(
+            grid=grid, in_specs=in_specs + specs, out_specs=out_spec,
+            scratch_shapes=scratch)), kernel, []
+
+    def stacked(blk, idx):
+        return pl.BlockSpec((None, *blk),
+                            lambda *g: (g[-1][0], *idx(*g[:-1])))
+
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
+    return dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=grid,
+        in_specs=in_specs + [stacked(*ws) for ws in weight_specs],
+        out_specs=out_spec, scratch_shapes=scratch)), \
+        (lambda l_ref, *refs: kernel(*refs)), [lyr]
+
+
+def _planes(w: QTensor):
+    return [p for p in (w.data, w.scale, w.zero) if p is not None]
 
 
 def _q_gemv_pallas(x2: jax.Array, w: QTensor, qt, m: int, kp: int, n: int,
-                   tiles, interpret: bool, out_dtype=None):
+                   tiles, interpret: bool, out_dtype=None, layer=None):
     """bs<=GEMV_MAX_M decode GEMV (the reference's `linear_fp16_esimd`
     decode GEMV role, low_bit_linear.py:744-745). M pads to one 16-row
     sublane tile (two for bs 17-32); x [mp, K] is VMEM-resident for the
@@ -402,7 +455,8 @@ def _q_gemv_pallas(x2: jax.Array, w: QTensor, qt, m: int, kp: int, n: int,
     maximize the streaming tile. FLOP overhead of the pad is irrelevant
     — decode is HBM-bound. The body follows from the codes' dtype:
     int4-dtype codes take `_gemv_kernel_mxu`, the canonical packing (and
-    int8) `_gemv_kernel`."""
+    int8) `_gemv_kernel`. `w` is one weight, or the `[L, ...]` stacks of
+    a scanned model read at `layer` (`_weight_call`)."""
     mp = _gemv_mp(m)
     if x2.shape[0] != mp:
         x2 = jax.lax.pad(x2, jnp.zeros((), x2.dtype),
@@ -410,16 +464,19 @@ def _q_gemv_pallas(x2: jax.Array, w: QTensor, qt, m: int, kp: int, n: int,
     b = qt.block_size
     bk, bn = tiles
     nk = kp // bk
-    scale_spec = pl.BlockSpec((bk // b, bn), lambda j, k: (k, j))
+
+    def tile(j, k):
+        return k, j
+
+    scale_spec = ((bk // b, bn), tile)
     if w.data.dtype == jnp.int4:
         kernel = functools.partial(
             _gemv_kernel_mxu, block=b, bk=bk, bn=bn, nk=nk)
         # x pre-split per scale block OUTSIDE the kernel, blocks leading
         # (see the body's docstring)
-        operands = [x2.reshape(mp, kp // b, b).transpose(1, 0, 2),
-                    w.data, w.scale]
-        in_specs = [pl.BlockSpec((bk // b, mp, b), lambda j, k: (k, 0, 0)),
-                    pl.BlockSpec((bk, bn), lambda j, k: (k, j)), scale_spec]
+        x2 = x2.reshape(mp, kp // b, b).transpose(1, 0, 2)
+        x_spec = pl.BlockSpec((bk // b, mp, b), lambda j, k, *_: (k, 0, 0))
+        weight_specs = [((bk, bn), tile), scale_spec]
     else:
         codebook = None
         if qt.kind == "codebook":
@@ -428,36 +485,36 @@ def _q_gemv_pallas(x2: jax.Array, w: QTensor, qt, m: int, kp: int, n: int,
         kernel = functools.partial(
             _gemv_kernel, block=b, kind=qt.kind, codebook=codebook,
             bk=bk, bn=bn, nk=nk, bits=bits)
-        operands = [x2, w.data, w.scale]
-        in_specs = [pl.BlockSpec((mp, kp), lambda j, k: (0, 0)),  # resident
-                    pl.BlockSpec((bk // 2 if bits == 4 else bk, bn),
-                                 lambda j, k: (k, j)), scale_spec]
+        x_spec = pl.BlockSpec((mp, kp), lambda j, k, *_: (0, 0))  # resident
+        weight_specs = [((bk // 2 if bits == 4 else bk, bn), tile),
+                        scale_spec]
         if qt.kind == "asym":
-            operands.append(w.zero)
-            in_specs.append(scale_spec)
+            weight_specs.append(scale_spec)
+    call, kernel, prefetch = _weight_call(
+        kernel, w, layer, (n // bn, nk), [x_spec],
+        pl.BlockSpec((mp, bn), lambda j, k, *_: (0, j)),
+        [pltpu.VMEM((mp, bn), jnp.float32)], weight_specs)
     y = pl.pallas_call(
         kernel,
         name=f"qmatmul_gemv_{w.qtype}",
-        grid=(n // bn, nk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((mp, bn), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((mp, n), out_dtype or x2.dtype),
-        scratch_shapes=[pltpu.VMEM((mp, bn), jnp.float32)],
         interpret=interpret,
         # N tiles are independent; only the K sweep carries the
         # accumulator — telling Mosaic lets it software-pipeline the
         # packed-data stream across j boundaries
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-    )(*operands)
+        **call,
+    )(*prefetch, x2, *_planes(w))
     return y[:m]
 
 
 def _q_matmul_generic(x2: jax.Array, w: QTensor, qt, m: int, kp: int,
                       n: int, tiles, interpret: bool,
-                      out_dtype) -> jax.Array:
+                      out_dtype, layer=None) -> jax.Array:
     """The GEMM: x2 [m, kp] bf16 (already K-padded) against quantized W
-    — grid (M/bm, N/bn, K/bk) at `tiles` = (bk, bn)."""
+    — grid (M/bm, N/bn, K/bk) at `tiles` = (bk, bn); `w` and `layer` as
+    in `_q_gemv_pallas`."""
     # pad M up to a bf16-tileable multiple (min sublane 16)
     bm, mp = _generic_bm(m)
     if mp != m:
@@ -465,22 +522,17 @@ def _q_matmul_generic(x2: jax.Array, w: QTensor, qt, m: int, kp: int,
                          ((0, mp - m, 0), (0, 0, 0)))
     bk, bn = tiles
     nk = kp // bk
-    grid = (mp // bm, n // bn, nk)
     b = qt.block_size
 
-    x_spec = pl.BlockSpec((bm, bk), lambda i, j, k: (i, k))
-    scale_spec = pl.BlockSpec((bk // b, bn), lambda i, j, k: (k, j))
-    out_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
-    out_shape = jax.ShapeDtypeStruct((mp, n), out_dtype)
-    scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
+    def tile(i, j, k):
+        return k, j
 
-    operands = [x2, w.data, w.scale]
-    in_specs = [x_spec, None, scale_spec]
+    scale_spec = ((bk // b, bn), tile)
     if w.data.dtype in (jnp.int4, jnp.int8):    # integer codes, unpacked
-        in_specs[1] = pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))
+        weight_specs = [((bk, bn), tile), scale_spec]
         kernel = functools.partial(_kernel_int, block=b, bk=bk, bn=bn, nk=nk)
     else:                                       # split-block nibbles
-        in_specs[1] = pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j))
+        weight_specs = [((bk // 2, bn), tile), scale_spec]
         codebook = None
         if qt.kind == "codebook":
             codebook = [float(v) for v in CODEBOOKS[qt.codebook]]
@@ -488,19 +540,20 @@ def _q_matmul_generic(x2: jax.Array, w: QTensor, qt, m: int, kp: int,
             _kernel_4bit, block=b, kind=qt.kind, codebook=codebook,
             bk=bk, bn=bn, nk=nk)
         if qt.kind == "asym":
-            operands.append(w.zero)
-            in_specs.append(scale_spec)
+            weight_specs.append(scale_spec)
+    call, kernel, prefetch = _weight_call(
+        kernel, w, layer, (mp // bm, n // bn, nk),
+        [pl.BlockSpec((bm, bk), lambda i, j, k, *_: (i, k))],
+        pl.BlockSpec((bm, bn), lambda i, j, k, *_: (i, j)),
+        [pltpu.VMEM((bm, bn), jnp.float32)], weight_specs)
     y = pl.pallas_call(
         kernel,
         name=f"qmatmul_gemm_{w.qtype}",    # the kernel's trace name
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
+        out_shape=jax.ShapeDtypeStruct((mp, n), out_dtype),
         interpret=interpret,
         compiler_params=_GENERIC_SEMANTICS,
-    )(*operands)
+        **call,
+    )(*prefetch, x2, *_planes(w))
 
     if mp != m:
         y = y[:m]
@@ -508,17 +561,18 @@ def _q_matmul_generic(x2: jax.Array, w: QTensor, qt, m: int, kp: int,
 
 
 def q_matmul_kernel(x: jax.Array, w: QTensor, gemv: bool, tiles, *,
-                    interpret: bool = False) -> jax.Array:
+                    layer=None, interpret: bool = False) -> jax.Array:
     """x [..., K] @ quantized W [K, N] -> [..., N] through the kernel
     that `ops/matmul.select_matmul` chose: the decode GEMV (`gemv`) or
-    the GEMM, at its `tiles`. Unjitted: model forwards call this inside
-    their own jit (a nested jit's closed_call fails to lower inside
-    shard_map's Manual-mesh trace — caught by the explicit-TP AOT
-    test)."""
+    the GEMM, at its `tiles`. With `layer`, `w` holds the `[L, ...]`
+    stacks of a scanned model's layers and the kernel reads that layer
+    where it lies. Unjitted: model forwards call this inside their own
+    jit (a nested jit's closed_call fails to lower inside shard_map's
+    Manual-mesh trace — caught by the explicit-TP AOT test)."""
     qt = get_qtype(w.qtype)
     batch_shape = x.shape[:-1]
     klog, n = w.shape
-    kp = w.scale.shape[0] * qt.block_size
+    kp = w.scale.shape[-2] * qt.block_size
     m = 1
     for d in batch_shape:
         m *= d
@@ -527,5 +581,5 @@ def q_matmul_kernel(x: jax.Array, w: QTensor, gemv: bool, tiles, *,
         x2 = jax.lax.pad(x2, jnp.zeros((), x2.dtype),
                          ((0, 0, 0), (0, kp - klog, 0)))
     call = _q_gemv_pallas if gemv else _q_matmul_generic
-    y = call(x2, w, qt, m, kp, n, tiles, interpret, x.dtype)
+    y = call(x2, w, qt, m, kp, n, tiles, interpret, x.dtype, layer)
     return y.reshape(*batch_shape, n)

@@ -44,7 +44,10 @@ def _probe_counter():
         "bigdl_tpu_kernel_probe_total",
         "Kernel dispatch outcomes per kernel: compile probe passed "
         "(compiled), probe refused by the compiler (fallback; raises), "
-        "XLA chosen by a dispatch rule (xla_by_rule).",
+        "XLA chosen by a dispatch rule (xla_by_rule); a stacked linear "
+        "of a layer scan whose kernel reads the layer where it lies "
+        "(stack_in_place) or that took an XLA plan and sliced the layer "
+        "out (stack_by_value).",
         labelnames=("kernel", "outcome"))
 
 
@@ -61,6 +64,16 @@ def record_dispatch_rule(kernel: str) -> None:
     probe outcome, so `outcome="fallback"` keeps meaning "the compiler
     refused a kernel". Trace-time counts, like the probes."""
     _probe_counter().labels(kernel, "xla_by_rule").inc()
+
+
+def record_stacked(kernel: str, in_place: bool) -> None:
+    """Count one linear of a layer scan over stacked weights
+    (`ops/matmul.StackedQ`): `stack_in_place` where a kernel plan
+    addresses the layer inside the stack, `stack_by_value` where an XLA
+    plan took the layer out first. Trace-time counts, like the probes:
+    once per linear per traced program."""
+    _probe_counter().labels(
+        kernel, "stack_in_place" if in_place else "stack_by_value").inc()
 
 
 def probe_compile(fn, *arg_structs) -> None:

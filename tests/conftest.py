@@ -45,3 +45,24 @@ import jax  # noqa: E402
 # the env var is too late — force the CPU via config too.
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", False)
+
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture()
+def forced_pallas(monkeypatch):
+    """`matmul_backend="pallas"` with the dequant-matmul kernels in
+    interpret mode: a model's forward runs them on the CPU. Every
+    linear's N must be a multiple of 128 (a forced plan raises where
+    the kernel has no tiling)."""
+    import bigdl_tpu.ops.pallas.dequant_matmul as dq
+    from bigdl_tpu.config import set_flags
+
+    real = dq.q_matmul_kernel
+    monkeypatch.setattr(
+        dq, "q_matmul_kernel",
+        lambda *a, **kw: real(*a, **{**kw, "interpret": True}))
+    set_flags(matmul_backend="pallas")
+    yield
+    set_flags(matmul_backend="auto")

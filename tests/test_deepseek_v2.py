@@ -457,3 +457,37 @@ def test_cost_models_and_ledger_count_the_latent_plane(model):
     both = kvcache.init_cache_spec(lspec, 2, 64, "int8", per_slot_pos=True)
     assert set(both.planes()) == {"k", "v", "k_scale", "v_scale"}
     assert both.max_seq == 64 and both.kv_dtype == "int8"
+
+
+def test_both_scans_read_the_quantized_stacks_in_place(forced_pallas,
+                                                       monkeypatch):
+    """The dense and the expert layers' scans close over their `[L, K,
+    N]` stacks and hand `linear` the stack and the layer: logits of a
+    34-row prefill through the interpret-mode kernels equal the by-value
+    scan's (every leaf sliced by `lax.scan`, the form before PR 46) bit
+    for bit. Widths at which every linear has a Pallas tiling; two
+    layers of each kind."""
+    hf = dict(HF, hidden_size=128, moe_intermediate_size=128,
+              q_lora_rank=128, qk_nope_head_dim=48, num_hidden_layers=4,
+              first_k_dense_replace=2)
+    family = get_family("DeepseekV2ForCausalLM", hf)
+    cfg = family.config_from_hf(hf)
+    params = family.convert_params(_hf_tensors(hf), cfg, "sym_int4")
+    toks = jnp.asarray(np.arange(34, dtype=np.int32)[None] % 256)
+
+    def run():
+        f = jax.jit(lambda p, t, c: deepseek_v2.forward(
+            p, cfg, t, c, compute_dtype=jnp.float32))
+        return np.asarray(
+            f(params, toks, deepseek_v2.new_cache(cfg, 1, 64))[0])
+
+    held = [deepseek_v2.hold_stacks(params[k])[0]
+            for k in ("dense_layers", "moe_layers")]
+    assert "down_proj" in held[0] and "shared_down" in held[1]
+    assert not set(held[1]) & set(deepseek_v2._EXPERT_KEYS)
+    in_place = run()
+    monkeypatch.setattr(deepseek_v2, "hold_stacks",
+                        lambda layers: ({}, layers))
+    by_value = run()
+    assert np.isfinite(in_place).all() and np.abs(in_place).max() > 0
+    np.testing.assert_array_equal(in_place, by_value)

@@ -236,3 +236,123 @@ def test_rope_scaling_modes():
         cfg = LlamaConfig.from_hf(
             {**base_hf, "rope_scaling": {"type": "longrope"}})
         model_rope_freqs(cfg)
+
+
+# --- the layer scan hands `linear` the stack and the layer (PR 46) ---
+
+def _kernel_sized_llama():
+    """A 3-layer model whose every linear has a Pallas tiling (N a
+    multiple of 128), merged like a `from_pretrained` load."""
+    from bigdl_tpu.models.llama import LlamaConfig, merge_projections
+    from bigdl_tpu.utils.testing import random_llama_params
+
+    cfg = LlamaConfig(vocab_size=256, hidden_size=128, intermediate_size=256,
+                      num_hidden_layers=3, num_attention_heads=2,
+                      num_key_value_heads=1, max_position_embeddings=128)
+    return cfg, merge_projections(random_llama_params(cfg, "sym_int4"), cfg)
+
+
+def _probe_counts():
+    from bigdl_tpu.observability.metrics import default_registry
+
+    series = default_registry().summary()
+    return {o: series.get('bigdl_tpu_kernel_probe_total{kernel="matmul",'
+                          f'outcome="{o}"}}', 0)
+            for o in ("stack_in_place", "stack_by_value")}
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
+def test_stack_addressed_in_place_equals_by_value_scan(forced_pallas,
+                                                       monkeypatch, paged):
+    """`forward` / `forward_paged` with the quantized stacks closed over
+    and read at the layer index give the logits of the by-value scan
+    (the parent's form: every leaf sliced by `lax.scan`) bit for bit,
+    over a 40-row prefill (GEMM) and a decode step (GEMV)."""
+    from bigdl_tpu.models import llama as M
+    from bigdl_tpu.ops.paged import init_paged_cache
+
+    cfg, params = _kernel_sized_llama()
+    toks = jnp.asarray(np.arange(40, dtype=np.int32)[None] % 256)
+
+    def run():
+        if paged:
+            cache = init_paged_cache(cfg.num_hidden_layers, 5, 16,
+                                     cfg.num_key_value_heads, cfg.hd, 1)
+            tables = jnp.arange(1, 5, dtype=jnp.int32)[None]
+            f = jax.jit(lambda p, t, c: M.forward_paged(p, cfg, t, c,
+                                                        tables))
+        else:
+            cache = M.new_cache(cfg, 1, 64)
+            f = jax.jit(lambda p, t, c: M.forward(p, cfg, t, c))
+        pre, cache = f(params, toks, cache)
+        dec, _ = f(params, toks[:, :1], cache)
+        return np.asarray(pre, np.float32), np.asarray(dec, np.float32)
+
+    before = _probe_counts()
+    in_place = run()
+    after = _probe_counts()
+    # qkv, o, gate_up, down: once in the prefill's trace, once in decode's
+    assert after["stack_in_place"] - before["stack_in_place"] == 8
+    assert after["stack_by_value"] == before["stack_by_value"]
+    monkeypatch.setattr(M, "hold_stacks", lambda layers: ({}, layers))
+    by_value = run()
+    assert _probe_counts() == after      # the by-value scan has no stack
+    for a, b in zip(in_place, by_value):
+        assert np.isfinite(a).all() and np.abs(a).max() > 0
+        np.testing.assert_array_equal(a, b)
+
+
+def test_decode_scan_slices_no_quantized_layer(forced_pallas):
+    """The jaxpr of the scanned `forward` at decode rows: the quantized
+    stacks enter the scan whole (as constants of its body, not as
+    scanned operands), each kernel call's weight operand is the `[L, K,
+    N]` stack, and no `dynamic_slice` in the body produces a layer of
+    it."""
+    from bigdl_tpu.models import llama as M
+    from bigdl_tpu.ops.quant import QTensor
+
+    cfg, params = _kernel_sized_llama()
+    cache = M.new_cache(cfg, 2, 64)
+    toks = jnp.zeros((2, 1), jnp.int32)
+    before = _probe_counts()
+    jaxpr = jax.make_jaxpr(
+        lambda p, t, c: M.forward(p, cfg, t, c))(params, toks, cache)
+    after = _probe_counts()
+    assert after["stack_in_place"] - before["stack_in_place"] == 4
+    assert after["stack_by_value"] == before["stack_by_value"]
+
+    layers = cfg.num_hidden_layers
+    planes = {tuple(a.shape) for q in params["layers"].values()
+              if isinstance(q, QTensor) for a in (q.data, q.scale)}
+    assert len(planes) == 8 and all(s[0] == layers for s in planes)
+    (scan,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+    n_consts = scan.params["num_consts"]
+    n_carry = scan.params["num_carry"]
+    consts = {tuple(v.aval.shape) for v in scan.invars[:n_consts]}
+    scanned = {tuple(v.aval.shape) for v in scan.invars[n_consts + n_carry:]}
+    assert planes <= consts and not planes & scanned
+
+    def walk(jp):
+        for e in jp.eqns:
+            yield e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from walk(sub)
+
+    body = list(walk(scan.params["jaxpr"].jaxpr))
+    kernels = [e for e in body if e.primitive.name == "pallas_call"
+               and "qmatmul" in str(e.params.get("name", ""))]
+    assert len(kernels) == 4
+    for e in kernels:
+        assert {tuple(v.aval.shape) for v in e.invars} & planes, e
+    # `lm_head`, a single weight, takes the plain grid: 2-D planes
+    (head,) = [e for e in walk(jaxpr.jaxpr)
+               if e.primitive.name == "pallas_call" and e not in kernels
+               and "qmatmul" in str(e.params.get("name", ""))]
+    assert tuple(params["lm_head"].data.shape) in {
+        tuple(v.aval.shape) for v in head.invars}
+    sliced = [tuple(v.aval.shape) for e in body
+              if e.primitive.name in ("dynamic_slice", "gather", "slice")
+              for v in e.outvars
+              if tuple(v.aval.shape) in {s[1:] for s in planes}
+              | {(1,) + s[1:] for s in planes}]
+    assert not sliced, f"a layer of a quantized stack is sliced: {sliced}"

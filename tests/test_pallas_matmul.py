@@ -178,3 +178,63 @@ def test_mxu_layout_layer_stacked():
         qt, data=jnp.stack([jnp.stack([qt.data] * 2)] * 3),
         scale=jnp.stack([jnp.stack([qt.scale] * 2)] * 3))
     assert to_mxu_layout(experts) is experts
+
+
+@pytest.mark.parametrize("qtype,m,k", [
+    ("sym_int4", 2, 256),          # GEMV, canonical body
+    ("sym_int4:mxu", 2, 256),      # GEMV, int4-dtype body
+    ("asym_int4", 2, 256),         # GEMV with a zero plane
+    ("sym_int4:mxu", 2, 200),      # GEMV, K padded to 224
+    ("sym_int4:mxu", 64, 256),     # GEMM, integer codes
+    ("asym_int4", 64, 256),        # GEMM, nibbles and a zero plane
+    ("nf4", 64, 200),              # GEMM, codebook, K padded to 256
+])
+def test_stack_read_in_place_equals_each_layers_slice(qtype, m, k):
+    """A `StackedQ` inside a `lax.scan` over the layer index (what a
+    scanned model hands to `linear`): the kernel reads layer 0, 1, 2 of
+    the `[3, K, N]` stack where it lies, and the result equals the 2-D
+    call on that layer's slice bit for bit."""
+    from bigdl_tpu.ops.matmul import StackedQ, q_matmul_pallas_impl
+    from bigdl_tpu.ops.quant import to_mxu_layout
+
+    qtype, _, layout = qtype.partition(":")
+    n = 128
+    x = _rand((m, k), seed=21) * 0.3
+    layers = [quantize(_rand((k, n), seed=30 + i) * 0.1, qtype)
+              for i in range(3)]
+    if layout == "mxu":
+        layers = [to_mxu_layout(q) for q in layers]
+    stack = jax.tree.map(lambda *a: jnp.stack(a), *layers)
+    assert stack.data.ndim == 3 and stack.shape == (k, n)
+
+    @jax.jit
+    def scanned(x, stack):
+        def step(_, i):
+            return None, q_matmul_pallas_impl(x, StackedQ(stack, i),
+                                              interpret=True)
+        return jax.lax.scan(step, None, jnp.arange(3, dtype=jnp.int32))[1]
+
+    got = np.asarray(scanned(x, stack), np.float32)
+    for i, q in enumerate(layers):
+        want = np.asarray(q_matmul_pallas(x, q, interpret=True), np.float32)
+        np.testing.assert_array_equal(got[i], want)
+    assert not np.array_equal(got[0], got[1])
+
+
+def test_stacked_xla_plan_takes_the_layer_and_is_differentiable():
+    """Off a kernel plan a `StackedQ` is the layer taken out of the
+    stack (what the scan did itself), forward and backward."""
+    from bigdl_tpu.ops.matmul import StackedQ, q_matmul
+
+    k, n = 64, 128
+    x = _rand((4, k), seed=41) * 0.3
+    layers = [quantize(_rand((k, n), seed=50 + i) * 0.1, "sym_int4")
+              for i in range(2)]
+    stack = jax.tree.map(lambda *a: jnp.stack(a), *layers)
+    view = StackedQ(stack, jnp.int32(1))
+    np.testing.assert_array_equal(
+        np.asarray(q_matmul(x, view, backend="xla")),
+        np.asarray(q_matmul(x, layers[1], backend="xla")))
+    grad = jax.grad(lambda x, w: jnp.sum(q_matmul(x, w, backend="xla") ** 2))
+    got, want = jax.jit(lambda x: (grad(x, view), grad(x, layers[1])))(x)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
