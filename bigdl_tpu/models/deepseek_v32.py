@@ -75,7 +75,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from bigdl_tpu.models.deepseek_v2 import (_pad_n, _rope, _rope_tables,
                                           mla_project, moe_block,
@@ -279,14 +278,8 @@ def _attention(y, lp, cfg, lat, idx, li, pos, wpos, cos, sin, selected=None,
         if t == 1:
             o, scores, sel = o[:, None], scores[:, None], sel[:, None]
     else:
-        lat_l = lax.dynamic_index_in_dim(lat, li, 0, keepdims=False)
-        idx_l = lax.dynamic_index_in_dim(idx, li, 0, keepdims=False)
-        o, scores, sel = jax.vmap(
-            lambda qn, qp, qi, wi, la, ix, p, se: _sparse_chunk(
-                cfg, cfg, qn, qp, qi, wi, la, ix, p, w_uk, w_uv, se),
-            in_axes=(0, 0, 0, 0, 0, 0, 0, None if selected is None else 0))(
-            q_nope, q_pe, q_i, w_i, lat_l, idx_l, _positions(pos, b),
-            selected)
+        o, scores, sel = _sparse_chunk(cfg, cfg, q_nope, q_pe, q_i, w_i, lat,
+                                       idx, li, pos, w_uk, w_uv, selected)
     if probe is not None:
         probe["index_scores"], probe["selected"] = scores, sel
     with jax.named_scope("mla.out"):

@@ -40,12 +40,18 @@ written at `pos % ring` (`ops/kvcache.py`).
 Decode (one row a slot) absorbs `W_uk` into the query and runs the
 kernels of `ops/pallas/dsa_attention.py`: index scores, the exact
 selection as a mask, the masked sweep; the ring sweep in a window layer.
-A chunk of rows runs per sequence in blocks of keys with an online
-softmax, absorbed or expanded by `deepseek_v2._absorb`'s count at the
-kind's sizes (absorbed below 170 rows in a full layer, below 190 in a
-window layer), over the live blocks only; `[heads, rows, S]` never
-exists in float32, and the indexer's per-head products are reduced over
-heads a tile at a time.
+A chunk of rows runs in blocks of keys with an online softmax, absorbed
+or expanded by `deepseek_v2._absorb`'s count at the kind's sizes
+(absorbed below 170 rows in a full layer, below 190 in a window layer),
+over the live blocks only. A full layer's expanded sweep (the cells'
+1024-row chunks) is ONE kernel over the latent stack where it lies,
+`ops/pallas/mla_chunk_attention.py` by `ops/dsa.mla_chunk_attention`'s
+rule: K and V of a key block expanded once a head and every score tile
+in VMEM. Its XLA form (`_sweep_chunk`: the CPU's path, the absorbed
+form's only one) and the window layers' band (`_window_chunk`) sweep per
+sequence in `jnp` ops, where `[heads, rows, S]` never exists in float32
+but `[heads, rows, block]` does, in HBM. The indexer's per-head products
+are reduced over heads a tile at a time.
 
 Parameter tree (linears contraction-major `[K, N]`, QTensor or dense):
 {
@@ -334,6 +340,13 @@ def _key_block(s: int) -> int:
     return 1024 if s % 1024 == 0 else s
 
 
+def _live_blocks(p, t: int, s: int):
+    """`(kb, n)`: the key block of a plane of `s` positions and how many
+    of its blocks hold a key of a chunk of `t` rows at `p ..`."""
+    kb = _key_block(s)
+    return kb, jnp.minimum((p + t + kb - 1) // kb, s // kb)
+
+
 def _online_softmax_step(carry, s_, live, pv_of):
     """One block of keys through the online softmax: `s_` `[H, T, n]`
     scaled scores, `live` `[T, n]`, `pv_of(p)` the block's weighted
@@ -393,21 +406,15 @@ def _sweep_state(kind: MlaKind, absorb: bool, t: int):
             jnp.zeros((t, h, width), jnp.float32))
 
 
-def _sparse_chunk(cfg, kind, q_nope, q_pe, q_i, w_i, lat, idx, p, w_uk, w_uv,
-                  selected=None):
-    """A chunk of `T` rows of ONE sequence at positions `p .. p + T - 1`
-    through a full layer's planes `lat` `[C + R, S]`, `idx` `[Di, S]`
-    (the chunk's own rows already written): index scores and the exact
-    selection over the live blocks of keys, then attention over the
-    selected positions. `selected` `[T, S]` bool, where given, takes the
+def _select_chunk(cfg, q_i, w_i, idx, p, selected=None):
+    """Index scores `[T, S]` float32 of a chunk of `T` rows of ONE
+    sequence at positions `p .. p + T - 1` over the live blocks of its
+    index keys `idx` `[Di, S]` (the chunk's own already written), and the
+    exact selection `[T, S]` bool; `selected`, where given, takes the
     selection's place (a check that holds the attention apart from the
-    selection). Returns `[T, H, v]` float32, the index scores and the
-    selection."""
-    t, s = q_nope.shape[0], lat.shape[-1]
-    kb = _key_block(s)
-    n_live = jnp.minimum((p + t + kb - 1) // kb, s // kb)
-    absorb = _absorb(kind, t)
-
+    selection)."""
+    t, s = q_i.shape[0], idx.shape[-1]
+    kb, n_live = _live_blocks(p, t, s)
     with jax.named_scope("dsa.index"):
         def score_block(j, acc):
             blk = lax.dynamic_slice(idx, (0, j * kb), (idx.shape[0], kb))
@@ -420,25 +427,68 @@ def _sparse_chunk(cfg, kind, q_nope, q_pe, q_i, w_i, lat, idx, p, w_uk, w_uv,
     with jax.named_scope("dsa.select"):
         sel = (dsa.select_topk_mask(scores, cfg.index_topk)
                if selected is None else selected)
+    return scores, sel
+
+
+def _sweep_chunk(kind, q_nope, q_pe, lat, sel, p, w_uk, w_uv):
+    """Attention of a chunk of `T` rows of ONE sequence at positions `p
+    ..` over the positions `sel` `[T, S]` marks of its latent plane `lat`
+    `[C + R, S]`, in XLA ops: the live blocks of keys through an online
+    softmax, absorbed or expanded by `_absorb`'s count. `[T, H, v]`
+    float32. The CPU's path, the oracle of `ops/pallas/
+    mla_chunk_attention.py` and the absorbed form's only one."""
+    t = q_nope.shape[0]
+    kb, n_live = _live_blocks(p, t, lat.shape[-1])
+    absorb = _absorb(kind, t)
+    if absorb:
+        with jax.named_scope("mla.absorb"):
+            q_main = _ein("qhd,hdc->qhc", q_nope, w_uk).astype(q_nope.dtype)
+    else:
+        q_main = q_nope
+    score_of, value_of = _block_attention(kind, absorb, q_main, q_pe, w_uk,
+                                          w_uv)
+
+    def attend(j, carry):
+        blk = lax.dynamic_slice(lat, (0, j * kb), (lat.shape[0], kb))
+        live = lax.dynamic_slice(sel, (0, j * kb), (t, kb))
+        return _online_softmax_step(carry, score_of(blk), live, value_of(blk))
+
+    carry = lax.fori_loop(0, n_live, attend, _sweep_state(kind, absorb, t))
+    return _finish(absorb, carry, w_uv, q_nope.dtype)
+
+
+def _sparse_chunk(cfg, kind, q_nope, q_pe, q_i, w_i, lat, idx, li, pos, w_uk,
+                  w_uv, selected=None):
+    """A chunk of `T` rows a sequence (`q_nope` `[B, T, H, nope]`, ...)
+    at positions `pos ..` through layer `li` of a full layer's stacks
+    `lat` `[L, B, C + R, S]`, `idx` `[L, B, Di, S]` (the chunk's own rows
+    already written): index scores and the exact selection over the live
+    blocks of keys, then attention over the selected positions: the
+    expanded form in `mla_chunk_attention`'s kernel on the stack where it
+    lies, the absorbed one (and the CPU) in `_sweep_chunk`. Returns `[B,
+    T, H, v]` float32, the index scores and the selection `[B, T, S]`;
+    `selected`: `_select_chunk`'s."""
+    b, t = q_nope.shape[:2]
+    p = _positions(pos, b)
+    idx_l = lax.dynamic_index_in_dim(idx, li, 0, keepdims=False)
+    scores, sel = jax.vmap(
+        lambda qi, wi, ix, pp, se: _select_chunk(cfg, qi, wi, ix, pp, se),
+        in_axes=(0, 0, 0, 0, None if selected is None else 0))(
+        q_i, w_i, idx_l, p, selected)
+
+    def sweep():
+        lat_l = lax.dynamic_index_in_dim(lat, li, 0, keepdims=False)
+        return jax.vmap(lambda qn, qp, la, se, pp: _sweep_chunk(
+            kind, qn, qp, la, se, pp, w_uk, w_uv))(q_nope, q_pe, lat_l, sel,
+                                                   p)
+
     with jax.named_scope("mla.sparse"):
-        if absorb:
-            with jax.named_scope("mla.absorb"):
-                q_main = _ein("qhd,hdc->qhc", q_nope, w_uk).astype(
-                    q_nope.dtype)
+        if _absorb(kind, t):
+            o = sweep()
         else:
-            q_main = q_nope
-        score_of, value_of = _block_attention(kind, absorb, q_main, q_pe,
-                                              w_uk, w_uv)
-
-        def attend(j, carry):
-            blk = lax.dynamic_slice(lat, (0, j * kb), (lat.shape[0], kb))
-            live = lax.dynamic_slice(sel, (0, j * kb), (t, kb))
-            return _online_softmax_step(carry, score_of(blk), live,
-                                        value_of(blk))
-
-        carry = lax.fori_loop(0, n_live, attend,
-                              _sweep_state(kind, absorb, t))
-        return _finish(absorb, carry, w_uv, q_nope.dtype), scores, sel
+            o = dsa.mla_chunk_attention(q_nope, q_pe, lat, li, p, sel, w_uk,
+                                        w_uv, kind.scale, sweep)
+    return o, scores, sel
 
 
 _WINDOW_ROWS = 256
@@ -535,14 +585,8 @@ def _full_attention(y, lp, cfg, lat, idx, li, pos, cos, sin, selected=None,
             o = _ein("bhc,hcd->bhd", o_lat, w_uv)[:, None]
         scores, sel = scores[:, None], sel[:, None]
     else:
-        lat_l = lax.dynamic_index_in_dim(lat, li, 0, keepdims=False)
-        idx_l = lax.dynamic_index_in_dim(idx, li, 0, keepdims=False)
-        o, scores, sel = jax.vmap(
-            lambda qn, qp, qi, wi, la, ix, p, se: _sparse_chunk(
-                cfg, kind, qn, qp, qi, wi, la, ix, p, w_uk, w_uv, se),
-            in_axes=(0, 0, 0, 0, 0, 0, 0, None if selected is None else 0))(
-            q_nope, q_pe, q_i, w_i, lat_l, idx_l, _positions(pos, b),
-            selected)
+        o, scores, sel = _sparse_chunk(cfg, kind, q_nope, q_pe, q_i, w_i, lat,
+                                       idx, li, pos, w_uk, w_uv, selected)
     if probe is not None:
         probe["index_scores"], probe["selected"] = scores, sel
     return o, lat, idx

@@ -1,7 +1,8 @@
 """Learned sparse attention over a latent cache (DeepSeek-V3.2's
 lightning indexer, as `models/dots3_note.py` runs it) and latent
 attention over a ring window: the exact selection, the XLA forms, and
-the dispatch to the decode kernels of `ops/pallas/dsa_attention.py`.
+the dispatch to the decode kernels of `ops/pallas/dsa_attention.py` and
+to the chunk kernel of `ops/pallas/mla_chunk_attention.py`.
 
 A full-attention layer keeps, beside its latent rows, one index key of
 `index_dim` values a position. A query scores every cached position
@@ -27,6 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from bigdl_tpu.ops.pallas import dsa_attention as kernels
+from bigdl_tpu.ops.pallas import mla_chunk_attention as chunk_kernel
 
 
 def select_topk_mask(scores: jax.Array, k: int) -> jax.Array:
@@ -275,3 +277,37 @@ def window_mla_decode(q_c, q_pe, ring_stack, layer, pos, scale: float,
     posv = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     return masked_mla_decode_xla(q_c, q_pe, one,
                                  ring_live(posv, ring, window), scale)
+
+
+def mla_chunk_attention(q_nope, q_pe, latent, layer, pos, sel, w_uk, w_uv,
+                        scale: float, xla, backend=None):
+    """Expanded attention of a chunk of rows `q_nope` `[B, T, H, nope]`,
+    `q_pe` `[B, T, H, R]` at positions `pos ..` `[B]` over layer `layer`
+    of the latent stack, under the causal bound and the selection `sel`
+    `[B, T, S]` (None: the bound alone): `[B, T, H, v]` float32. The
+    kernel where the rule wants it; elsewhere `xla()`, the caller's
+    sweep in XLA ops (it owns the absorbed form too)."""
+    from bigdl_tpu.config import target_is_tpu
+
+    t, h, nope = q_nope.shape[1:]
+    r, s = q_pe.shape[-1], latent.shape[-1]
+    c, vd = w_uv.shape[-2:]
+    masked = sel is not None
+
+    def probe():
+        return (lambda *a: chunk_kernel.mla_chunk_attention_pallas(
+            *a, (nope + r) ** -0.5),
+            (_sds((1, t, h, nope)), _sds((1, t, h, r)),
+             _sds((1, 1, c + r, s)), _sds((1,), jnp.int32),
+             _sds((1, t, s), jnp.bool_) if masked else None,
+             _sds((h, nope, c)), _sds((h, c, vd))))
+
+    if _kernel_wanted(chunk_kernel.NAME,
+                      chunk_kernel.mla_chunk_supported(q_nope, q_pe, latent,
+                                                       w_uk, w_uv),
+                      (t, h, nope, r, c, vd, s, masked), probe, backend,
+                      q_nope, latent):
+        return chunk_kernel.mla_chunk_attention_pallas(
+            q_nope, q_pe, latent, pos, sel, w_uk, w_uv, float(scale),
+            layer=layer, interpret=not target_is_tpu())
+    return xla()

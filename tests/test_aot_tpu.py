@@ -14,6 +14,8 @@ HLO actually CONTAINS Mosaic custom-calls — guarding against the silent
 Compiled-memory figures are recorded in PARITY.md.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -1413,6 +1415,83 @@ def test_dots3_note_engine_programs_compile_and_fit(v5e, aot_flags):
         r"= \w+\[(?:\d+,)?8,(?:576|128|1088),(?:16384|640)\]\S* "
         r"(?:copy|fusion|dynamic-slice)\(", txt)
     assert not moved, f"a layer of a cache plane is materialized: {moved}"
+
+
+@pytest.mark.parametrize("s", [8192, 16384])
+def test_mla_chunk_attention_compiles_at_the_cells_geometry(v5e, aot_flags,
+                                                            s):
+    """The latent chunk kernel for one v5e as the dots3 and DeepSeek-V3.2
+    cells call it: 1024 rows of 128 heads x (128 + 64) with a selection
+    over a private cache of 8192 / 16384 positions, the five-layer stack
+    as operand and the layer a prefetched scalar: a Mosaic call, and no
+    temporary the size of a layer's plane or of a head's scores."""
+    from bigdl_tpu.ops.pallas import mla_chunk_attention as K
+
+    dev = v5e.devices[0]
+    bf, i32 = jnp.bfloat16, jnp.int32
+
+    def sd(shape, dt=bf):
+        return _sds(jax.ShapeDtypeStruct(shape, dt), dev)
+
+    comp = _compile(
+        lambda qn, qp, lat, p, m, wk, wv, ly: K.mla_chunk_attention_pallas(
+            qn, qp, lat, p, m, wk, wv, 192 ** -0.5, layer=ly),
+        sd((1, 1024, 128, 128)), sd((1, 1024, 128, 64)),
+        sd((DOTS3["full"], 1, 576, s)), sd((1,), i32),
+        sd((1, 1024, s), jnp.bool_), sd((128, 128, 512)),
+        sd((128, 512, 128)), sd((), i32))
+    txt = comp.as_text()
+    assert "tpu_custom_call" in txt and K.NAME in txt
+    # q in head order 50 MB, W_uv transposed 17 MB, the int8 selection
+    assert comp.memory_analysis().temp_size_in_bytes < 128 * 2 ** 20
+
+
+def test_dots3_note_full_layer_chunk_keeps_its_scores_in_vmem(v5e,
+                                                              aot_flags):
+    """One full layer's attention of a 1024-row chunk AS `forward` RUNS
+    IT (`attention_block`, published widths, int4 linears) into a
+    private cache of 8192 positions: the sweep is the chunk kernel, and
+    no `[heads, rows, 1024]` float32 score block of the XLA sweep (512
+    MB a key block: PERF.md 6, PR 47) is left in the program."""
+    import json
+    import re
+    import sys
+    from pathlib import Path
+
+    bench = Path(__file__).resolve().parents[1] / "benchmark"
+    sys.path[:0] = [str(bench)]
+    from harness import weights_dots3_note as weights
+    from harness.weights import _family_config
+
+    from bigdl_tpu.models import dots3_note
+    from bigdl_tpu.ops.kvcache import init_cache_spec
+    from bigdl_tpu.ops.quant import prepack_tree
+
+    doc = json.loads(
+        (bench / "configs" / "dots3-note-ep8-int4.json").read_text())
+    _, cfg, _ = _family_config(doc)
+    li = cfg.layer_types.index(dots3_note.FULL)
+    cfg1 = dataclasses.replace(
+        cfg, num_hidden_layers=1, layer_types=(dots3_note.FULL,),
+        first_k_dense_replace=1)
+    lp = jax.eval_shape(lambda: prepack_tree(dots3_note.prepare_params(
+        weights.build_params(cfg1, "sym_int4", 1), cfg1), "on")[0])[
+        "layers"][0]
+    assert cfg.layer_types[li] == dots3_note.FULL and "index_q_proj" in lp
+    dev = v5e.devices[0]
+    cache = jax.eval_shape(lambda: init_cache_spec(
+        dots3_note.cache_spec(cfg1).unrolled(), 1, 8192))
+    y = jax.ShapeDtypeStruct((1, 1024, cfg.hidden_size), jnp.bfloat16)
+    comp = _compile(
+        lambda yy, pp, cc: dots3_note.attention_block(
+            yy, pp, cfg1, cc, dots3_note.FULL),
+        _sds(y, dev), _sds(lp, dev), _sds(cache, dev))
+    txt = comp.as_text()
+    # the operation's own name, which the trace group reads
+    assert re.search(r"^\s*%?mla_chunk_attention\S* = f32\[1,1024,16384\]\S* "
+                     r"custom-call\(", txt, re.M)
+    wide = re.findall(r"f32\[(?:1,)?128,1024,1024\]", txt)
+    assert not wide, f"[heads, rows, keys] in float32: {wide[:3]}"
 
 
 @pytest.mark.parametrize("kernel", ["eva_decode_attention", "eva_summarize"])

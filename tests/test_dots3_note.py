@@ -189,6 +189,125 @@ def test_window_decode_kernel_over_the_ring_across_its_wrap(pos):
                                np.asarray(want, np.float32), atol=0.02)
 
 
+def _chunk_case(case):
+    """`(B, T, S, positions, selection)` of one case of the chunk
+    kernel's test; S = 384 sweeps in 128-key blocks."""
+    rng = np.random.default_rng(11)
+    b, t, s, pos = {
+        "p0": (1, 128, 256, [0]),
+        "inside_a_block": (1, 128, 384, [70]),
+        "across_three_blocks": (1, 128, 384, [200]),
+        "selection_drops_keys": (1, 128, 384, [256]),
+        "a_row_without_a_key": (1, 128, 256, [128]),
+        "no_selection": (1, 128, 256, [128]),
+        "two_sequences": (2, 128, 384, [0, 250]),
+        "row_blocks_of_256": (1, 512, 1024, [512]),
+    }[case]
+    at = np.asarray(pos)[:, None, None] + np.arange(t)[None, :, None]
+    sel = np.arange(s)[None, None, :] <= at
+    if case in ("selection_drops_keys", "two_sequences"):
+        # a third of the keys, before the chunk and inside it
+        sel = sel & (rng.random((b, t, s)) < 0.33)
+        before = sel[-1, :, :pos[-1]]
+        assert before.any() and not before.all()
+    elif case == "a_row_without_a_key":
+        sel = sel.copy()
+        sel[0, 5] = False
+    elif case == "no_selection":
+        sel = None
+    return b, t, s, pos, sel
+
+
+@pytest.mark.parametrize("case", [
+    "p0", "inside_a_block", "across_three_blocks", "selection_drops_keys",
+    "a_row_without_a_key", "no_selection", "two_sequences",
+    "row_blocks_of_256"])
+def test_chunk_kernel_against_the_expanded_sweep_in_xla_ops(case,
+                                                            monkeypatch):
+    """`mla_chunk_attention` interpreted, on layer 1 of a stack, against
+    `_sweep_chunk` held to the expanded form: the same rows whatever the
+    chunk's first position, the blocks it crosses and the selection."""
+    from bigdl_tpu.ops.pallas import mla_chunk_attention as chunk
+
+    monkeypatch.setattr(dots3_note, "_absorb", lambda kind, t: False)
+    h, c, r, nope, vd = 4, 128, 64, 128, 128
+    kind = dots3_note.MlaKind(h, 64, c, nope, r, vd, 1e4, 1e-5, None, None)
+    b, t, s, pos, sel = _chunk_case(case)
+    rng = np.random.default_rng(12)
+
+    def bf(*shape, scale=1.0):
+        return jnp.asarray(scale * rng.standard_normal(shape), jnp.bfloat16)
+
+    qn, qp, lat = bf(b, t, h, nope), bf(b, t, h, r), bf(2, b, c + r, s)
+    w_uk, w_uv = bf(h, nope, c, scale=0.1), bf(h, c, vd, scale=0.1)
+    p = jnp.asarray(pos, jnp.int32)
+    got = chunk.mla_chunk_attention_pallas(
+        qn, qp, lat, p, None if sel is None else jnp.asarray(sel), w_uk,
+        w_uv, kind.scale, layer=1, interpret=True)
+    if sel is None:
+        sel = np.arange(s)[None, None, :] <= (
+            np.asarray(pos)[:, None, None] + np.arange(t)[None, :, None])
+    want = jax.vmap(lambda a, b_, la, se, pp: dots3_note._sweep_chunk(
+        kind, a, b_, la, se, pp, w_uk, w_uv))(qn, qp, lat[1],
+                                              jnp.asarray(sel), p)
+    assert got.shape == want.shape == (b, t, h, vd)
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=0.03)
+    if case == "a_row_without_a_key":
+        assert not np.asarray(got)[0, 5].any()
+        assert np.abs(np.asarray(got)[0, 6]).max() > 0.1
+
+
+@pytest.mark.parametrize("t,absorbed", [(256, False), (128, True)])
+def test_a_full_layers_chunk_goes_through_the_kernel_by_its_form(
+        t, absorbed, monkeypatch):
+    """`_sparse_chunk` on the stacks, the selection made from the index
+    scores: the expanded form (256 rows at these widths) through the
+    kernel where the backend says so, the absorbed form (128 rows)
+    never; either way what the XLA sweep gives, which is what the CPU
+    runs when nothing is forced."""
+    from bigdl_tpu.config import set_flags
+    from bigdl_tpu.ops.pallas import mla_chunk_attention as chunk
+
+    seen = []
+    real = chunk.mla_chunk_attention_pallas
+    monkeypatch.setattr(chunk, "mla_chunk_attention_pallas",
+                        lambda *a, **kw: seen.append(1) or real(*a, **kw))
+    cfg = dataclasses.replace(
+        dots3_note.Dots3NoteConfig(), num_attention_heads=2, kv_lora_rank=256,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        index_n_heads=2, index_head_dim=32, index_topk=96)
+    kind = cfg.full
+    assert dots3_note._absorb(kind, t) == absorbed
+    rng = np.random.default_rng(13)
+
+    def bf(*shape, scale=1.0):
+        return jnp.asarray(scale * rng.standard_normal(shape), jnp.bfloat16)
+
+    b, s = 2, 512
+    args = (bf(b, t, 2, 128), bf(b, t, 2, 64), bf(b, t, 2, 32),
+            jnp.asarray(rng.random((b, t, 2)), jnp.float32),
+            bf(2, b, kind.latent_dim, s), bf(2, b, 32, s), jnp.int32(1),
+            jnp.asarray([0, 200], jnp.int32), bf(2, 128, 256, scale=0.1),
+            bf(2, 256, 128, scale=0.1))
+
+    def run(backend):
+        set_flags(attention_backend=backend)
+        try:
+            return jax.jit(lambda *a: dots3_note._sparse_chunk(
+                cfg, kind, *a))(*args)
+        finally:
+            set_flags(attention_backend="auto")
+
+    o, _, sel = run("pallas")
+    assert len(seen) == (0 if absorbed else 1)
+    want, _, sel_x = run("auto")
+    assert len(seen) == (0 if absorbed else 1)
+    np.testing.assert_array_equal(np.asarray(sel), np.asarray(sel_x))
+    assert np.asarray(sel).sum(axis=-1).max() == 96
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want), atol=0.03)
+
+
 @pytest.mark.parametrize("plen", [5, 16, 17, 40, 75])
 def test_a_private_cache_is_spliced_into_the_slabs_ring_mid_ring(plen):
     """`engine_insert`'s splice: the private cache keeps its window rows
