@@ -6,7 +6,11 @@ multi-chip-simulatable test layer the reference lacks (SURVEY.md §4):
 pjit/shard_map collectives execute for real, single-host.
 """
 
+import glob
 import os
+import shutil
+import tempfile
+import time
 
 # Unit tests run on the virtual CPU mesh, unconditionally.
 os.environ["JAX_PLATFORMS"] = "cpu"
@@ -35,9 +39,49 @@ os.environ.setdefault("BIGDL_TPU_COMPILE_MEMORY", "0")
 # TPU worker, so skip the query outright.
 os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
 
-# Entry points the tests call in-process (the CLIs, examples) turn on
-# the persistent compilation cache; tests compile everything fresh.
-os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "0")
+# One compile cache a run. An `LLMEngine` makes its jitted closures anew
+# and most tests build one, so without a cache every engine, every xdist
+# worker and every child process a test starts (router replicas,
+# chip_smoke's phases, `benchmark/run.py --tiny`) compiles the same tiny
+# programs again: compile time was most of the suite's. The process that
+# starts the run (the xdist controller, or the only process) makes an
+# EMPTY directory and exports it before jax is imported; workers and
+# children inherit the environment, so a program is compiled once a run,
+# by whoever needs it first. A run starts cold and removes its directory
+# at session finish: no run sees another's state. A test that needs a
+# cold compile, or whose executables the cache cannot read back, turns
+# the cache off for its module with `no_compile_cache` below.
+_RUN_CACHE_PREFIX = "bigdl_tpu_test_jax_cache_"
+_run_cache_dir = None   # set only in the process that made it
+
+
+def _start_run_cache():
+    global _run_cache_dir
+    if "PYTEST_XDIST_WORKER" in os.environ:
+        return          # the controller made it; the variables are set
+    # a run killed by a time limit cannot clean up after itself
+    for old in glob.glob(os.path.join(tempfile.gettempdir(),
+                                      _RUN_CACHE_PREFIX + "*")):
+        try:
+            stale = time.time() - os.path.getmtime(old) > 86400
+        except OSError:
+            continue    # another run removed it first
+        if stale:
+            shutil.rmtree(old, ignore_errors=True)
+    _run_cache_dir = tempfile.mkdtemp(prefix=_RUN_CACHE_PREFIX)
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = _run_cache_dir
+    os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "1"
+    os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+    os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "0"
+
+
+_start_run_cache()
+
+
+def pytest_sessionfinish(session):
+    if _run_cache_dir is not None:
+        shutil.rmtree(_run_cache_dir, ignore_errors=True)
+
 
 import jax  # noqa: E402
 
@@ -48,6 +92,31 @@ jax.config.update("jax_enable_x64", False)
 
 
 import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def _run_cache_write_threshold():
+    """An entry point called in-process (the CLIs, the examples) raises
+    the cache's write threshold for its process to a deployment's 1 s
+    (`config.enable_compilation_cache`); left so, that worker would
+    share only its slow compiles for the rest of the run."""
+    yield
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """The run's compile cache off for one module: for tests that need
+    a cold compile, and for `tests/test_aot_tpu.py`, whose executables
+    the cache can write and not read back. JAX decides once a process
+    whether the cache is used, hence the resets."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
 
 
 @pytest.fixture()

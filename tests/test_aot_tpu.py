@@ -24,7 +24,11 @@ from jax.sharding import SingleDeviceSharding
 
 from bigdl_tpu.config import set_flags
 
-pytestmark = pytest.mark.aot
+# The run's compile cache is off here: an executable for the offline
+# topology is written to it and cannot be read back on a CPU host
+# ("DeserializeLoadedExecutable not implemented"), so the cache would
+# cost every case a write and a warning and save it nothing.
+pytestmark = [pytest.mark.aot, pytest.mark.usefixtures("no_compile_cache")]
 
 
 @pytest.fixture(scope="module")

@@ -770,6 +770,64 @@ def test_e2e_fleet_profiler_capture(cluster, tmp_path):
         assert rep["roofline_util_decode"] is None
 
 
+def _start_canary_pair(drift_spec):
+    """A healthy replica 0 behind a canary-probing router; replica 1
+    joins with `drift_spec` only once replica 0 has defined EVERY
+    golden. The first successful probe of a (prompt, kind) is the
+    golden, and the drift is armed from replica 1's second step: were
+    both started together, a late first probe of replica 0 would let
+    the drifting one define the golden and the healthy one would be
+    quarantined, so the result would ride on how fast a replica
+    comes up."""
+    _FAULT_SPECS.clear()
+    router = Router(spawn=_spawn_replica, config=RouterConfig(
+        replicas=1, health_sec=0.2, backoff_base_sec=0.2,
+        crash_budget=20, crash_window_sec=5.0, unhealthy_after=4,
+        spawn_timeout_sec=240.0, drain_exit_timeout_sec=90.0,
+        canary_sec=0.3))
+    router.start(wait_healthy=True)
+    try:
+        keys = len(router.canary._probe_specs(router.replicas[0]))
+        want = {"goldens_recorded": keys}
+        if router.canary.nll_tol > 0:
+            want["nll_goldens_recorded"] = keys
+        deadline = time.monotonic() + 60.0
+        while time.monotonic() < deadline:
+            snap = router.canary.snapshot()
+            if all(snap[k] >= n for k, n in want.items()):
+                break
+            time.sleep(0.05)
+        else:
+            pytest.fail(f"goldens never recorded: {snap}")
+        _FAULT_SPECS[1] = drift_spec
+        joined = router.add_replica()
+        assert joined.idx == 1
+        # HEALTHY may last one sweep only: the quarantine under test
+        # can come before this loop looks again
+        deadline = time.monotonic() + 240.0
+        while joined.state not in (HEALTHY, QUARANTINED):
+            assert time.monotonic() < deadline, joined.snapshot()
+            time.sleep(0.02)
+    except BaseException:
+        _FAULT_SPECS.clear()
+        router.shutdown()
+        raise
+    return router
+
+
+def _wait_quarantined(router, who):
+    """Bounded by the quarantine itself (a sweep or two of 0.3 s once
+    replica 1 answers), not by this limit."""
+    deadline = time.monotonic() + 60.0
+    while time.monotonic() < deadline:
+        if router.replicas[1].state == QUARANTINED:
+            return
+        time.sleep(0.05)
+    pytest.fail(f"{who} never quarantined the drifting replica: "
+                f"{router.canary.snapshot()} "
+                f"{router.stats_snapshot()['counters']}")
+
+
 def test_e2e_canary_quarantines_drifting_replica():
     """The ISSUE-18 acceptance chaos run: replica 1 carries a sticky
     ``logit_drift`` fault — finite additive logit bias, so it stays
@@ -778,33 +836,17 @@ def test_e2e_canary_quarantines_drifting_replica():
     exactly that replica, the healthy neighbor must keep reproducing
     its answers byte-identically, and no request may be lost across
     the quarantine transition."""
-    _FAULT_SPECS.clear()
-    _FAULT_SPECS[1] = "logit_drift@after_step=1,bias=8"
-    router = Router(spawn=_spawn_replica, config=RouterConfig(
-        replicas=2, health_sec=0.2, backoff_base_sec=0.2,
-        crash_budget=20, crash_window_sec=5.0, unhealthy_after=4,
-        spawn_timeout_sec=240.0, drain_exit_timeout_sec=90.0,
-        canary_sec=0.3))
-    router.start(wait_healthy=True)
+    router = _start_canary_pair("logit_drift@after_step=1,bias=8")
     httpd = router.serve(port=0, background=True)
     base = f"http://127.0.0.1:{httpd.server_address[1]}"
     try:
-        _wait_all_healthy(router)
         # client burst racing the canary sweep: a request in flight on
         # the drifting replica when it is terminated must fail over
         prompts = [[i + 1, i + 4, 2, 3] for i in range(8)]
         results = _completion_burst(base, prompts)
         assert [s for s, _ in results] == [200] * 8
 
-        deadline = time.monotonic() + 90.0
-        while time.monotonic() < deadline:
-            if router.replicas[1].state == QUARANTINED:
-                break
-            time.sleep(0.05)
-        else:
-            pytest.fail("canary never quarantined the drifting "
-                        f"replica: {router.canary.snapshot()} "
-                        f"{router.stats_snapshot()['counters']}")
+        _wait_quarantined(router, "canary")
         # exactly the drifting replica is isolated; goldens came from
         # the byte-correct neighbor, which stays in rotation
         assert router.replicas[0].state == HEALTHY
@@ -849,30 +891,14 @@ def test_e2e_nll_canary_quarantines_byte_identical_drift(monkeypatch):
     With BIGDL_TPU_CANARY_NLL_TOL set below that, the NLL-tolerance
     mode must quarantine exactly the drifting replica, with
     kind='nll' mismatches and zero byte mismatches."""
-    _FAULT_SPECS.clear()
-    _FAULT_SPECS[1] = "logit_drift@after_step=1,bias=-8"
     # healthy replicas are bit-deterministic twins (same seed, greedy)
     # so their NLLs agree exactly; 1e-3 sits well under the ~4e-3
     # drift and well over float noise
     monkeypatch.setenv("BIGDL_TPU_CANARY_NLL_TOL", "0.001")
-    router = Router(spawn=_spawn_replica, config=RouterConfig(
-        replicas=2, health_sec=0.2, backoff_base_sec=0.2,
-        crash_budget=20, crash_window_sec=5.0, unhealthy_after=4,
-        spawn_timeout_sec=240.0, drain_exit_timeout_sec=90.0,
-        canary_sec=0.3))
+    router = _start_canary_pair("logit_drift@after_step=1,bias=-8")
     assert router.canary.nll_tol == 0.001
-    router.start(wait_healthy=True)
     try:
-        _wait_all_healthy(router)
-        deadline = time.monotonic() + 90.0
-        while time.monotonic() < deadline:
-            if router.replicas[1].state == QUARANTINED:
-                break
-            time.sleep(0.05)
-        else:
-            pytest.fail("NLL canary never quarantined the drifting "
-                        f"replica: {router.canary.snapshot()} "
-                        f"{router.stats_snapshot()['counters']}")
+        _wait_quarantined(router, "NLL canary")
         assert router.replicas[0].state == HEALTHY
         # every mismatch was an NLL verdict — the bytes never differed
         events = router.flight.snapshot()
