@@ -106,6 +106,16 @@ class GqaKind:
     rope_theta: float
     sink: bool
     window: int = 0          # positions attended, the query's own counted
+    value_scale: float = 1.0
+    rotary: bool = True      # False: the kind's q and k take no rotary
+    # what `models/afmoe.py`'s layers add: an RMSNorm over each head of
+    # q and k before the rotary (`q_norm` / `k_norm` [head_dim], eps
+    # `norm_eps`), and sigmoid(y W_g), a value a channel, on the
+    # concatenated heads before `o_proj` (W_g the last columns of
+    # `qkv_proj`)
+    qk_norm: bool = False
+    gate: bool = False
+    norm_eps: float = 1e-5
 
     @property
     def q_width(self) -> int:
@@ -207,7 +217,8 @@ class MimoV2Config:
     def full(self) -> GqaKind:
         return GqaKind(self.num_attention_heads, self.num_key_value_heads,
                        self.head_dim, self.v_head_dim, self.rope_theta,
-                       self.add_full_attention_sink_bias)
+                       self.add_full_attention_sink_bias,
+                       value_scale=self.attention_value_scale)
 
     @property
     def swa(self) -> GqaKind:
@@ -215,7 +226,8 @@ class MimoV2Config:
                        self.swa_num_key_value_heads, self.swa_head_dim,
                        self.swa_v_head_dim, self.swa_rope_theta,
                        self.add_swa_attention_sink_bias,
-                       window=self.sliding_window_size)
+                       window=self.sliding_window_size,
+                       value_scale=self.attention_value_scale)
 
     def kind_name(self, layer: int) -> str:
         return WINDOW if self.hybrid_layer_pattern[layer] else FULL
@@ -289,7 +301,7 @@ class MimoV2Config:
         return self.n_full * 2 * k.heads * (k.head_dim + k.v_head_dim)
 
 
-def cache_spec(cfg: MimoV2Config) -> CacheSpec:
+def cache_spec(cfg) -> CacheSpec:
     planes = []
     if cfg.n_full:
         planes += [PlaneSpec("full_k", cfg.n_full, (cfg.full.k_width,)),
@@ -305,7 +317,7 @@ def cache_spec(cfg: MimoV2Config) -> CacheSpec:
                      planes=tuple(planes))
 
 
-def new_cache(cfg: MimoV2Config, batch: int, max_seq: int,
+def new_cache(cfg, batch: int, max_seq: int,
               quantized=False) -> KVCache:
     """The four planes with the window layers' rows in position order
     (`CacheSpec.unrolled`: `generate()` right-pads its prompt, and the
@@ -318,8 +330,7 @@ def new_cache(cfg: MimoV2Config, batch: int, max_seq: int,
                            kv_cache_dtype=quantized)
 
 
-def _attention(y, lp, cfg: MimoV2Config, kind: GqaKind, k_stack, v_stack, li,
-               pos, cos, sin):
+def attention(y, lp, kind: GqaKind, k_stack, v_stack, li, pos, cos, sin):
     """One layer's attention on the normed `y` `[B, T, D]` through layer
     `li` of its kind's stacks at `pos`: the output (before the residual)
     and the two stacks with this layer's rows written."""
@@ -327,15 +338,21 @@ def _attention(y, lp, cfg: MimoV2Config, kind: GqaKind, k_stack, v_stack, li,
     h, g, dk, dv = kind.heads, kind.kv_heads, kind.head_dim, kind.v_head_dim
     scale = dk ** -0.5
     sink = lp.get("sink") if kind.sink else None
+    v_end = kind.q_width + kind.k_width + kind.v_width
     with jax.named_scope("gqa.qkv"):
         qkv = linear(y, lp["qkv_proj"])
-        q = apply_rope(qkv[..., :kind.q_width].reshape(b, t, h, dk), cos, sin)
-        k = apply_rope(qkv[..., kind.q_width:kind.q_width + kind.k_width]
-                       .reshape(b, t, g, dk), cos, sin).reshape(
-            b, t, kind.k_width)
-        v = (qkv[..., kind.q_width + kind.k_width:
-                 kind.q_width + kind.k_width + kind.v_width].astype(
-            jnp.float32) * cfg.attention_value_scale).astype(y.dtype)
+        q = qkv[..., :kind.q_width].reshape(b, t, h, dk)
+        k = qkv[..., kind.q_width:kind.q_width + kind.k_width].reshape(
+            b, t, g, dk)
+        if kind.qk_norm:
+            q = rms_norm(q, lp["q_norm"], kind.norm_eps)
+            k = rms_norm(k, lp["k_norm"], kind.norm_eps)
+        if kind.rotary:
+            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        k = k.reshape(b, t, kind.k_width)
+        v = qkv[..., kind.q_width + kind.k_width:v_end]
+        if kind.value_scale != 1.0:
+            v = (v.astype(jnp.float32) * kind.value_scale).astype(y.dtype)
     if not kind.window:
         with jax.named_scope("gqa.full"):
             k_stack = update_rows(k_stack, li, k, pos)
@@ -364,8 +381,13 @@ def _attention(y, lp, cfg: MimoV2Config, kind: GqaKind, k_stack, v_stack, li,
                     q, k, v, pk, pv, posv)
                 k_stack = update_rows(k_stack, li, k, pos, ring=True)
                 v_stack = update_rows(v_stack, li, v, pos, ring=True)
+    o = o.reshape(b, t, h * dv)
+    if kind.gate:
+        with jax.named_scope("gqa.gate"):
+            o = o.astype(jnp.float32) * jax.nn.sigmoid(
+                qkv[..., v_end:].astype(jnp.float32))
     with jax.named_scope("gqa.out"):
-        out = linear(o.astype(y.dtype).reshape(b, t, h * dv), lp["o_proj"])
+        out = linear(o.astype(y.dtype), lp["o_proj"])
     return out, k_stack, v_stack
 
 
@@ -377,9 +399,9 @@ def _layer(x, lp, experts, k_stack, v_stack, li, ei, pos, cos, sin, tally, *,
     stacks) traced, so every layer of a kind is a call of one body."""
     eps = cfg.layernorm_epsilon
     kind = cfg.swa if window else cfg.full
-    a, k_stack, v_stack = _attention(
-        rms_norm(x, lp["input_layernorm"], eps), lp, cfg, kind, k_stack,
-        v_stack, li, pos, cos, sin)
+    a, k_stack, v_stack = attention(
+        rms_norm(x, lp["input_layernorm"], eps), lp, kind, k_stack, v_stack,
+        li, pos, cos, sin)
     x = x + a
     hid = rms_norm(x, lp["post_attention_layernorm"], eps)
     if routed:
@@ -391,34 +413,39 @@ def _layer(x, lp, experts, k_stack, v_stack, li, ei, pos, cos, sin, tally, *,
     return x, k_stack, v_stack, tally
 
 
+def row_positions(pos, sq: int):
+    """`[B or 1, sq]`: the positions `pos .. pos + sq - 1` of the rows of
+    a call at `pos` (a scalar, or one a slot)."""
+    if getattr(pos, "ndim", 0) == 1:
+        return pos[:, None] + jnp.arange(sq, dtype=jnp.int32)[None, :]
+    return (pos + jnp.arange(sq, dtype=jnp.int32))[None, :]
+
+
 def _tables(cfg: MimoV2Config, pos, sq: int):
     """cos and sin `[B or 1, sq, rd / 2]` of the positions `pos .. pos +
     sq - 1` for the two kinds' rope."""
-    if getattr(pos, "ndim", 0) == 1:
-        positions = pos[:, None] + jnp.arange(sq, dtype=jnp.int32)[None, :]
-    else:
-        positions = (pos + jnp.arange(sq, dtype=jnp.int32))[None, :]
-    return rope_tables(positions, {
+    return rope_tables(row_positions(pos, sq), {
         FULL: (cfg.rotary_dim, cfg.rope_theta),
         WINDOW: (cfg.rotary_dim, cfg.swa_rope_theta)})
 
 
-def attention_block(y, lp, cfg: MimoV2Config, cache: KVCache, kind: str):
+def attention_block(y, lp, cfg, cache: KVCache, kind: str, tables=None):
     """One layer's attention alone, as `forward` runs it: the normed `y`
     `[B, sq, D]` through layer 0 of the planes of `kind` in `cache` at
     `cache.pos`. Returns the attention output (before the residual) and
     the cache with the new rows written and `pos` advanced. For a check
-    that holds a single layer to a reference on the same input."""
+    that holds a single layer to a reference on the same input.
+    `tables`: the family's `(cfg, pos, sq) -> {kind: (cos, sin)}`."""
     sq = y.shape[1]
-    cos, sin = _tables(cfg, cache.pos, sq)[kind]
+    cos, sin = (tables or _tables)(cfg, cache.pos, sq)[kind]
     li = jnp.int32(0)
     if kind == FULL:
-        out, k, v = _attention(y, lp, cfg, cfg.full, cache.full_k,
-                               cache.full_v, li, cache.pos, cos, sin)
+        out, k, v = attention(y, lp, cfg.full, cache.full_k, cache.full_v,
+                              li, cache.pos, cos, sin)
         cache = cache.replace(full_k=k, full_v=v)
     else:
-        out, k, v = _attention(y, lp, cfg, cfg.swa, cache.ring_k,
-                               cache.ring_v, li, cache.pos, cos, sin)
+        out, k, v = attention(y, lp, cfg.swa, cache.ring_k, cache.ring_v,
+                              li, cache.pos, cos, sin)
         cache = cache.replace(ring_k=k, ring_v=v)
     return out, cache.replace(pos=cache.pos + sq)
 

@@ -258,6 +258,25 @@ def _register_builtin() -> None:
             new_cache=mimo_mod.new_cache,
         ))
 
+    from bigdl_tpu.models import afmoe as afmoe_mod
+
+    # the window-and-full planes and kernels of mimo_v2 with a per-head
+    # QK norm, a sigmoid gate on the attention output, no rotary in the
+    # full layers, two norms a sublayer and a shared expert beside the
+    # routed ones; the periods after the leading layers run as one scan;
+    # slab only, bf16 planes only
+    register_family(
+        ["AfmoeForCausalLM"],
+        FamilyAdapter(
+            name="afmoe",
+            config_from_hf=afmoe_mod.AfmoeConfig.from_hf,
+            convert_params=afmoe_mod.convert_hf_params,
+            forward=afmoe_mod.forward,
+            prefill=afmoe_mod.forward_last_token,
+            forward_train=None,
+            new_cache=afmoe_mod.new_cache,
+        ))
+
     from bigdl_tpu.models import rwkv as rwkv_mod
 
     def rwkv_adapter(version: int) -> FamilyAdapter:

@@ -26,14 +26,19 @@ times the needed work and is not the bound: the time follows the bytes.
 
 `decode_attention_lanes` sweeps a slot's S-blocks up to the one that
 holds its `pos` (the steps past it name that block again and fetch
-nothing, `decode_attention._named_block`). `swa_decode_attention` reads
-a slot's whole ring: column `c` holds the position `pos - ((pos - c) mod
-ring)`, live while it is no more than `window - 1` behind `pos` and not
-below 0; once the ring is full and as long as the window every column
-is live, and softmax does not care about their order. The sink is one
-learned scalar a query head: a column of the softmax with no value,
-which is what the online softmax starts from (`m = b`, `l = 1`, `acc =
-0`).
+nothing, `decode_attention._named_block`). `swa_decode_attention` sweeps
+a slot's ring in the same blocks (`s_block`: a ring of 128 columns is
+one block, one of 2048 x 512 lanes two): column `c` holds the position
+`pos - ((pos - c) mod ring)`, live while it is no more than `window - 1`
+behind `pos` and not below 0. While a ring is still filling (`pos <
+ring - 1`) only the columns `0 .. pos` hold anything, so the blocks past
+the one that holds `pos` are dead and are named again, not fetched, by
+the full plane's own rule (`ring_blocks` counts them); once the ring is
+full and as long as the window every column is live, and softmax does
+not care about their order. The sink is one learned scalar a query
+head: a column of the softmax with no value, which is what the online
+softmax starts from (`m = b`, `l = 1`, `acc = 0`); with none it starts
+from nothing.
 """
 
 from __future__ import annotations
@@ -51,18 +56,31 @@ FULL_NAME = "decode_attention_lanes"
 WINDOW_NAME = "swa_decode_attention"
 # bytes of one K block: 1024 positions x 768 bf16 values
 _BLOCK_BYTES = 1536 * 1024
-# a ring is read whole, as one block
-MAX_RING = 1024
 
 
 def s_block(s: int, width: int) -> int:
     """Positions of one block: the largest power-of-two multiple of 128
     that divides `s` and keeps a `[sb, width]` bf16 block within
-    `_BLOCK_BYTES`."""
+    `_BLOCK_BYTES`; an `s` that is no multiple of 128 (which the kernels
+    refuse, `lanes_supported`) is one block."""
+    if s % 128:
+        return s
     sb = 128
     while s % (sb * 2) == 0 and sb * 2 * width * 2 <= _BLOCK_BYTES:
         sb *= 2
     return sb
+
+
+def ring_blocks(positions, ring: int, width: int):
+    """`(live, dead)` blocks of ONE window layer's K ring (as many of V)
+    in a decode step whose queries sit at `positions` (plain ints, < 0
+    an empty slot): the kernel's own rule, a block is fetched while it
+    starts at or before `pos`."""
+    sb = s_block(ring, width)
+    ns = ring // sb
+    held = [int(p) for p in positions if p >= 0]
+    live = sum(min(p // sb, ns - 1) + 1 for p in held)
+    return live, len(held) * ns - live
 
 
 def block_diagonal(q: jax.Array, hkv: int) -> jax.Array:
@@ -109,9 +127,10 @@ def _kernel(layer_ref, pos_ref, q_ref, *rest, scale, sb, ns, window, sink):
             l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # an empty slot (pos < 0) runs no block and writes zeros; a block of
-    # a full plane wholly past pos would add nothing
-    @pl.when((pos >= 0) if window else (sj * sb <= pos))
+    # an empty slot (pos < 0) runs no block and writes zeros; a block
+    # wholly past pos would add nothing (of a ring too: a position past
+    # the ring's end has wrapped, and every block starts before it)
+    @pl.when(sj * sb <= pos)
     def _():
         s_ = jax.lax.dot_general(
             q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
@@ -147,7 +166,7 @@ def _kernel(layer_ref, pos_ref, q_ref, *rest, scale, sb, ns, window, sink):
 def _call(name, q, k, v, q_pos, scale, hkv, layer, window, sink, interpret):
     b, h, _ = q.shape
     s, wk, wv = k.shape[2], k.shape[3], v.shape[3]
-    sb = s if window else s_block(s, wk)
+    sb = s_block(s, wk)
     ns = s // sb
     qd = block_diagonal(q.astype(jnp.bfloat16), hkv)
     hp = qd.shape[1]
@@ -155,8 +174,6 @@ def _call(name, q, k, v, q_pos, scale, hkv, layer, window, sink, interpret):
     lyr = jnp.asarray(layer, jnp.int32).reshape(1)
 
     def kv_index(bi, sj, lyr_ref, pos_ref):
-        if window:
-            return lyr_ref[0], bi, sj, 0
         return lyr_ref[0], bi, _named_block(pos_ref, bi, sj, sb, ns), 0
 
     in_specs = [pl.BlockSpec((None, hp, wk), lambda bi, sj, *_: (bi, 0, 0))]

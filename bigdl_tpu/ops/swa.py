@@ -146,8 +146,7 @@ def window_decode(q, ring_k, ring_v, layer, pos, scale: float, hkv: int,
             args + (_sds((h,), jnp.float32),))
 
     if _kernel_wanted(kernels.WINDOW_NAME,
-                      ring <= kernels.MAX_RING
-                      and kernels.lanes_supported(q, ring_k, ring_v, hkv),
+                      kernels.lanes_supported(q, ring_k, ring_v, hkv),
                       (h, hkv, dk, wv, ring, window, sink is None), probe,
                       backend, q, ring_k):
         return kernels.swa_decode_attention_pallas(

@@ -84,6 +84,7 @@ from bigdl_tpu.ops.pallas.paged_decode_attention import pages_read
 from bigdl_tpu.ops.paged import (NULL_PAGE, cow_copy_pages,
                                  gather_pages_dense, paged_cache_bytes,
                                  publish_paged_cache_bytes, splice_pages)
+from bigdl_tpu.ops.pallas.swa_attention import ring_blocks
 from bigdl_tpu.ops.swa import rows_read as swa_rows_read
 from bigdl_tpu.robustness import (resolve_drain_timeout_sec,
                                   resolve_request_deadline_ms)
@@ -1122,6 +1123,17 @@ class LLMEngine:
                 "own counted), kind=full those of the full layers' "
                 "planes, kind=context those a model of as many layers, "
                 "all full, would read.", labelnames=("kind",))
+            self._m_swa_blocks = m.counter(
+                "bigdl_tpu_swa_ring_blocks_total",
+                "Blocks of the window layers' K rings in a decode step, "
+                "all window layers and live slots, by the ring kernel's "
+                "own rule: state=live those it fetches (up to the one "
+                "that holds the query's position while a ring is still "
+                "filling, all of them once it has wrapped), state=dead "
+                "those it names again and does not fetch.",
+                labelnames=("state",))
+            for st in ("live", "dead"):  # render from scrape 1
+                self._m_swa_blocks.labels(st)
         self._m_prefill_chunks = m.counter(
             "bigdl_tpu_prefill_chunks_total",
             "Prefill chunks dispatched by admission (at most one per "
@@ -4574,13 +4586,16 @@ class LLMEngine:
             # rule from the positions the host already knows (outside
             # the phases)
             n_win, n_full, window = self._swa
-            rows = swa_rows_read(
-                [len(self.slots[i].req.prompt_token_ids)
-                 + len(self.slots[i].generated) - 1 for i in active], window)
+            at = [len(self.slots[i].req.prompt_token_ids)
+                  + len(self.slots[i].generated) - 1 for i in active]
+            rows = swa_rows_read(at, window)
             self._m_swa_rows.labels("window").inc(n_win * rows["window"])
             self._m_swa_rows.labels("full").inc(n_full * rows["full"])
             self._m_swa_rows.labels("context").inc(
                 (n_win + n_full) * rows["full"])
+            live, dead = ring_blocks(at, *self.cache.ring_k.shape[2:])
+            self._m_swa_blocks.labels("live").inc(n_win * live)
+            self._m_swa_blocks.labels("dead").inc(n_win * dead)
         toks = None
         finite_host = None
         n_emit = None       # verify step: tokens each slot kept, [B]

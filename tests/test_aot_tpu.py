@@ -1840,3 +1840,138 @@ def test_mimo_v2_prefill_chunk_keeps_no_rows_by_keys_scores(v5e, aot_flags,
     print("mimo_v2 prefill chunk", alloc, "temp GB",
           ma.temp_size_in_bytes / 1e9, "args GB",
           ma.argument_size_in_bytes / 1e9)
+
+
+# -- AFMoE / Trinity-Mini (PR 49): 2048-position rings swept in blocks, ----
+# -- all 32 layers, seven periods under one scan ---------------------------
+
+def test_afmoe_ring_kernel_sweeps_a_2048_ring_in_blocks(v5e, aot_flags):
+    """`swa_decode_attention` for one v5e over the cell's rings (24
+    layers x 16 slots x 2048 columns of 4 x 128 values, no sink): two
+    blocks of 1024 a slot under the online softmax, the stack as operand
+    and the layer a prefetched scalar; a Mosaic call and no copy of a
+    plane."""
+    from bigdl_tpu.ops.pallas import swa_attention as K
+
+    dev = v5e.devices[0]
+    bf, i32 = jnp.bfloat16, jnp.int32
+
+    def sd(shape, dt=bf):
+        return _sds(jax.ShapeDtypeStruct(shape, dt), dev)
+
+    assert K.s_block(2048, 512) == 1024
+    comp = _compile(
+        lambda q_, k, v, p, ly: K.swa_decode_attention_pallas(
+            q_, k, v, p, 128 ** -0.5, 4, 2048, layer=ly),
+        sd((16, 32, 128)), sd((24, 16, 2048, 512)), sd((24, 16, 2048, 512)),
+        sd((16,), i32), sd((), i32))
+    assert _has_mosaic_call(comp)
+    assert comp.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
+
+
+def _afmoe_engine():
+    import json
+    import sys
+    from pathlib import Path
+
+    bench = Path(__file__).resolve().parents[1] / "benchmark"
+    sys.path[:0] = [str(bench)]
+    from harness import weights_afmoe as weights
+    from harness.weights import _family_config
+
+    from bigdl_tpu.models import afmoe
+    from bigdl_tpu.ops.quant import prepack_tree
+    from bigdl_tpu.serving import EngineConfig, LLMEngine
+
+    doc = json.loads(
+        (bench / "configs" / "trinity-mini-ep4-int4.json").read_text())
+    family, cfg, hf = _family_config(doc)
+
+    class Model:
+        params = jax.eval_shape(lambda: prepack_tree(
+            afmoe.prepare_params(
+                weights.build_params(cfg, "sym_int4", 1), cfg), "on")[0])
+        config, hf_config, qtype = cfg, hf, "sym_int4"
+
+    Model.family = family
+    eng = doc["engine"]
+    return LLMEngine(Model, EngineConfig(
+        max_batch=eng["max_batch"], max_seq=eng["max_seq"],
+        prefill_chunk=eng["prefill_chunk"],
+        prefill_bucket=eng["prefill_bucket"], sentinel=False,
+        quality=False))
+
+
+def test_afmoe_engine_decode_step_compiles_and_fits(v5e, aot_flags):
+    """The engine's resident decode step for the cell's configuration
+    (all 32 layers at published widths, 16 slots x 8192, shapes only):
+    both decode kernels (the ring kernel, no XLA fallback), the routed
+    kernel and the int4 GEMV are in it, the seven periods are ONE loop,
+    no instruction materializes a layer of a plane or of a quantized
+    stack, and arguments plus temporaries stay under 9.5 GB."""
+    import re
+
+    dev = v5e.devices[0]
+    eng = _afmoe_engine()
+    b = eng.cfg_engine.max_batch
+    i32 = _sds(jax.ShapeDtypeStruct((b,), jnp.int32), dev)
+    f32 = _sds(jax.ShapeDtypeStruct((b,), jnp.float32), dev)
+    lowered = eng._decode_resident.lower(
+        _sds(eng.params, dev), i32,
+        _sds(jax.eval_shape(lambda: eng.cache), dev),
+        f32, i32, f32, i32, i32, all_greedy=True,
+        with_quality=False)
+    comp = lowered.compile()
+    txt = comp.as_text()
+    for name in ("decode_attention_lanes", "swa_decode_attention",
+                 "moe_routed_decode", "qmatmul_gemv_sym_int4"):
+        assert name in txt, name
+    ma = comp.memory_analysis()
+    live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    print("afmoe decode step: args GB", ma.argument_size_in_bytes / 1e9,
+          "temp GB", ma.temp_size_in_bytes / 1e9, "live GB", live / 1e9,
+          "lowered chars", len(lowered.as_text()))
+    assert 7.0e9 < live < 9.5e9, live / 1e9   # 4.3 GB weights + 3.8 GB slab
+    moved = re.findall(
+        r"= \w+\[(?:1,)?16,(?:8192|2048),512\]\S* "
+        r"(?:copy|fusion|dynamic-slice)\(", txt)
+    assert not moved, f"a layer of a cache plane is materialized: {moved}"
+    assert not re.findall(r"= \w+\[(?:8|24),16,(?:8192|2048),512\]\S* copy\(",
+                          txt)
+    # no layer of a quantized stack is sliced out: the kernels read it
+    # where it lies
+    assert not re.findall(r"dynamic-slice\S*\(s4\[(?:32|30),", txt)
+
+
+@pytest.mark.parametrize("alloc", [4096, 8192])
+def test_afmoe_prefill_chunk_keeps_no_rows_by_keys_scores(v5e, aot_flags,
+                                                          alloc):
+    """One 1024-row prefill chunk AS THE ENGINE BUILDS IT into a private
+    cache of 4096 and of 8192 positions: no float32 `[heads, rows, S]`
+    temporary (the full layers sweep 512-key blocks, the window layers a
+    band of 256 + 2047 keys a row block), the int4 GEMM in every linear."""
+    import re
+
+    from bigdl_tpu.ops.kvcache import init_cache_spec
+
+    dev = v5e.devices[0]
+    eng = _afmoe_engine()
+    chunk = eng._chunk
+    assert chunk == 1024
+    cache1 = jax.eval_shape(lambda: init_cache_spec(
+        eng._cache_spec.unrolled(), 1, alloc,
+        kv_cache_dtype=eng.kv_cache_dtype))
+    tokens = jax.ShapeDtypeStruct((1, chunk), jnp.int32)
+    comp = eng._prefill.lower(_sds(eng.params, dev), _sds(tokens, dev),
+                              _sds(cache1, dev)).compile()
+    txt = comp.as_text()
+    assert "qmatmul_gemm_sym_int4" in txt and "moe_routed_prefill" in txt
+    wide = re.findall(rf"f32\[(?:1,)?(?:32|4,8),1024,{alloc}\]", txt)
+    assert not wide, f"[heads, rows, S] in float32: {wide[:3]}"
+    ma = comp.memory_analysis()
+    # a row block's band scores [32, 256, 2303] f32 are 75 MB
+    assert ma.temp_size_in_bytes < 1.0e9, ma.temp_size_in_bytes / 1e9
+    print("afmoe prefill chunk", alloc, "temp GB",
+          ma.temp_size_in_bytes / 1e9, "args GB",
+          ma.argument_size_in_bytes / 1e9)

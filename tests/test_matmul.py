@@ -156,6 +156,18 @@ _MIMO_CHOSE = {
     (16384, 4096): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
     (4096, 19072): (('gemv_mxu', (4096, 128)), ('gemm', (4096, 128))),
 }
+# Trinity-Mini's linears (PR 49): q / k / v and the gate merged, W_o, a
+# dense layer's gate / up and down, the shared expert's, the head's slice:
+# every one a kernel plan, none falls to XLA
+_AFMOE_CHOSE = {
+    (2048, 9216): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
+    (4096, 2048): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
+    (2048, 6144): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
+    (6144, 2048): (('gemv_mxu', (2048, 512)), ('gemm', (6144, 256))),
+    (2048, 1024): (('gemv_mxu', (2048, 512)), ('gemm', (2048, 512))),
+    (1024, 2048): (('gemv_mxu', (1024, 512)), ('gemm', (1024, 512))),
+    (2048, 50048): (('gemv_mxu', (2048, 128)), ('gemm', (2048, 128))),
+}
 _TPU = dict(int4_layout=True, spmd=False, tpu=True)
 _CANON = dict(_TPU, int4_layout=False)
 # the rules, one case each: (qtype, rows, K, N, what the call sees, plan)
@@ -214,7 +226,7 @@ _RULES = [
     for (k, n), at in _EVABYTE_CHOSE.items()
     for rows, plan in zip((6, 256, 1024), (*at, at[1]))] + [
     ("sym_int4", rows, k, n, _TPU, plan)
-    for (k, n), at in _MIMO_CHOSE.items()
+    for (k, n), at in {**_MIMO_CHOSE, **_AFMOE_CHOSE}.items()
     for rows, plan in zip((16, 1024), at)] + _RULES)
 def test_kernel_selection_table(qtype, rows, k, n, sees, want):
     """`select_matmul` is the one place a quantized linear's plan is
