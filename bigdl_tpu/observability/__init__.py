@@ -126,6 +126,12 @@ bigdl_tpu_mtp_drafts_total{outcome}         LLMEngine._decode_step: drafts of
 bigdl_tpu_mtp_slot_steps_total{kind}        LLMEngine._decode_step of a
                                             speculating engine: verify (two
                                             rows a slot) | plain (one row)
+bigdl_tpu_decode_steps_total{sent}          LLMEngine._decode_step, where a
+                                            decode program is dispatched:
+                                            ahead of the last step's read |
+                                            in_step
+bigdl_tpu_decode_steps_vain_total           LLMEngine._decode_step: a step sent
+                                            ahead that no slot was read from
 bigdl_tpu_spec_round_seconds{mode}          speculative._spec_observe
 bigdl_tpu_spec_tokens_total{mode,kind}      speculative._spec_observe
 bigdl_tpu_kv_cache_bytes{dtype,component}   ops/kvcache.publish_kv_cache_bytes

@@ -27,7 +27,7 @@ module and observability/__init__ for the field mapping):
         kind=admission), sweep|admission|observe|cache|h2d|fetch (per
         working step), dispatch|device|sample|emit|host (per step that
         decoded); kind=plain|chunk: whether the step dispatched a
-        prefill chunk}                                           histogram
+        prefill chunk, or waited for one}                        histogram
     bigdl_tpu_prefill_chunks_total                               counter
     bigdl_tpu_prefill_tokens_total{kind=prompt|padding}          counter
     bigdl_tpu_decode_attn_blocks_total{kind=read|slab}           counter
@@ -47,6 +47,8 @@ module and observability/__init__ for the field mapping):
     bigdl_tpu_spec_accept_ratio{mode=draft|lookup|mtp}           histogram
     bigdl_tpu_mtp_drafts_total{outcome=accepted|rejected}        counter
     bigdl_tpu_mtp_slot_steps_total{kind=verify|plain}            counter
+    bigdl_tpu_decode_steps_total{sent=ahead|in_step}             counter
+    bigdl_tpu_decode_steps_vain_total                            counter
     bigdl_tpu_spec_round_seconds{mode=...}                       histogram
     bigdl_tpu_spec_tokens_total{mode=...,kind=drafted|accepted}  counter
     bigdl_tpu_requests_quarantined_total{reason=nan_logits|crash_loop}

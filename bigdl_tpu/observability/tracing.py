@@ -455,7 +455,8 @@ class PhaseClock:
         self._t_begin = time.perf_counter()
 
     def mark_chunk(self) -> None:
-        """This step dispatched a prefill program."""
+        """This step dispatched a prefill program, or waits for one
+        that went out behind a decode step in flight."""
         self._kind = CHUNK
 
     def phase(self, name: str, child: bool = False) -> _Phase:

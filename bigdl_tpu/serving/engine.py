@@ -445,6 +445,11 @@ class _Admission:
     # before the next chunk ([1, D], on the device), which the MTP
     # block's lagged pass over that chunk starts from
     carry: Any = None
+    # the last chunk is out and its first token not yet read: (the
+    # last prompt position's logits, its hidden row where the engine
+    # speculates). Behind a decode step in flight the admission ends at
+    # the next step's start, so that the step is read and emitted first
+    last: Any = None
 
 
 def _transform_rows(lg, temps, top_ks, top_ps):
@@ -800,11 +805,23 @@ class LLMEngine:
         # no fault clauses are live (poison_rows needs the logits on
         # the host side of the dispatch).
         @functools.partial(tracked_jit, "engine_decode_resident",
-                           registry=self.registry, donate_argnums=(2,),
+                           registry=self.registry, donate_argnums=(1, 3),
                            static_argnames=("all_greedy", "with_quality"))
-        def decode_resident(params, tokens, cache, temps, top_ks,
-                            top_ps, seeds, poss, *, all_greedy,
+        def decode_resident(params, ints, floats, cache, *, all_greedy,
                             with_quality=False):
+            """What the host gives and takes is PACKED, because a
+            transfer of a few bytes costs the step about a millisecond
+            each way: `ints` `[4, B]` (each slot's last token, -1 for
+            none; top-k; seed; the absolute index of the token it
+            samples next) and `floats` `[2, B]` (temperature, top-p) go
+            in; out come ONE int32 block `[B, 2]` (token, health; `[B,
+            5]` with the bits of the three quality values where
+            `with_quality`) and the NEXT step's `ints` (the sampled
+            token, the index moved on), which the host hands back
+            untouched while the same requests hold the same slots
+            (`_io`)."""
+            tokens, top_ks, seeds, poss = ints
+            temps, top_ps = floats
             lg, cache = decode_forward(params, tokens, cache)
             finite = jnp.isfinite(lg).all(axis=-1)
             if all_greedy:
@@ -812,21 +829,25 @@ class LLMEngine:
             else:
                 toks = _device_sample_rows(lg, temps, top_ks, top_ps,
                                            seeds, poss)
-            qrows = None
+            cols = [toks, finite.astype(jnp.int32)]
             if with_quality:
                 # live decode-quality telemetry, fused into the SAME
                 # executable so the single-dispatch invariant survives:
                 # per-slot chosen-token logprob, full-softmax entropy,
-                # and top-1 margin, returned as one [B, 3] f32 block
-                # the host pulls alongside toks/finite
+                # and top-1 margin, three f32 columns of the one block
+                # the host pulls (their bits, read back as f32)
                 lp = jax.nn.log_softmax(lg.astype(jnp.float32), axis=-1)
                 chosen = jnp.take_along_axis(
                     lp, toks[:, None].astype(jnp.int32), axis=-1)[:, 0]
                 entropy = -jnp.sum(jnp.exp(lp) * lp, axis=-1)
                 top2, _ = jax.lax.top_k(lg.astype(jnp.float32), 2)
                 margin = top2[:, 0] - top2[:, 1]
-                qrows = jnp.stack([chosen, entropy, margin], axis=-1)
-            return toks, finite, cache, qrows
+                cols += [jax.lax.bitcast_convert_type(q, jnp.int32)
+                         for q in (chosen, entropy, margin)]
+            live = tokens >= 0
+            ints = jnp.stack([jnp.where(live, toks, -1), top_ks, seeds,
+                              poss + 1])
+            return jnp.stack(cols, axis=1), ints, cache
 
         self._decode_resident = decode_resident
 
@@ -853,6 +874,32 @@ class LLMEngine:
             return fwd(params, self.cfg, tokens, cache1)
 
         self._prefill = prefill_chunk
+
+        # A resident step (`engine_decode_resident`, or the verify step
+        # of a speculating engine) takes all it needs from what the step
+        # before it left on the device, so it goes out BEFORE that step's
+        # tokens are read (`_decode_step`, `_may_lead`): the device runs
+        # step k + 1 while the host fetches, emits and observes step k.
+        # The host's view of a slot stays the truth (export, preemption
+        # and `_finish` go by the host's count and set the slot's `pos`);
+        # what the step ahead computed for a request that step k ended
+        # is never read.
+        # `_io`: (who holds which slot, the next step's `ints`, its
+        # `floats`): the device's own view of every slot's last token
+        # and token index, good while no slot changes hands
+        self._io = None
+        # `_ahead`: a step that is out and not read yet, sent before the
+        # last one's tokens were read or at that step's close: ((slot,
+        # request) of every slot in it, its block, the `ints` after it,
+        # its `floats`). The device's view may be this one step further
+        # on than the host's
+        self._ahead = None
+        # a prefill chunk went out beside live streams and no decode
+        # program has followed it yet: the next chunk waits for one
+        # (no stream waits behind two chunks)
+        self._chunk_unanswered = False
+        # when the last decode step's tokens were timed (perf_counter)
+        self._t_step_timed = 0.0
 
         # -- speculative_tokens: the family's MTP module drafts one token
         # a slot and the main stack verifies it in the same dispatch
@@ -1134,6 +1181,18 @@ class LLMEngine:
                 labelnames=("state",))
             for st in ("live", "dead"):  # render from scrape 1
                 self._m_swa_blocks.labels(st)
+        self._m_decode_steps = m.counter(
+            "bigdl_tpu_decode_steps_total",
+            "Decode programs dispatched: sent=ahead before the step "
+            "before it was read (the device runs it while the host reads, "
+            "emits and observes that one), sent=in_step by the step that "
+            "waits for it.", labelnames=("sent",))
+        for st in ("ahead", "in_step"):     # render from scrape 1
+            self._m_decode_steps.labels(st)
+        self._m_vain_steps = m.counter(
+            "bigdl_tpu_decode_steps_vain_total",
+            "Decode programs sent ahead of which no slot was read: every "
+            "request in them ended, or left its slot, in the step before.")
         self._m_prefill_chunks = m.counter(
             "bigdl_tpu_prefill_chunks_total",
             "Prefill chunks dispatched by admission (at most one per "
@@ -1751,13 +1810,8 @@ class LLMEngine:
         with `q = 0`, which is the plain step's draw from `p`.
 
         A verify step takes all it needs from what the step before it
-        left on the device, so while nobody waits for a slot it goes out
-        BEFORE that step's tokens are read (`_mtp_ahead`, `_may_lead`):
-        the device runs step k + 1 while the host fetches, emits and
-        observes step k. The host's view of a slot stays the truth
-        (export, preemption and `_finish` go by the host's count and set
-        the slot's `pos`); what the step ahead computed for a request
-        that step k ended is never read."""
+        left on the device, so it goes out one step ahead as the plain
+        resident step does (`_ahead`, `_may_lead`)."""
         from bigdl_tpu.speculative import accept_and_resample
 
         ce, fam, cfg = self.cfg_engine, self.family, self.cfg
@@ -1780,16 +1834,6 @@ class LLMEngine:
                 "no MTP rows' hidden state")
         fwd_hidden, mtp_forward = fam.forward_hidden, fam.mtp_forward
         b, s_max = ce.max_batch, ce.max_seq
-        # (who holds which slot, the next verify step's `ints`, its
-        # `floats`): the device's own view of every slot's last token
-        # and token index, good while no slot changes hands
-        self._mtp_io = None
-        # a verify step that went out BEFORE the last one's tokens were
-        # read (`_decode_step`): ((slot, request) of every slot in it,
-        # its `[B, 4]` block, the `ints` after it, its `floats`). The
-        # host's view of a slot is the truth; the device's may be this
-        # one step further on
-        self._mtp_ahead = None
         self._mtp_draft = jnp.full((b,), -1, jnp.int32)
         self._mtp_q = jnp.zeros((b, int(cfg.vocab_size)), jnp.float32)
 
@@ -1847,7 +1891,7 @@ class LLMEngine:
             `[B, 4]` block (the two tokens, how many were kept, health)
             and the NEXT step's `ints` (the last kept token, the index
             moved on), which the host hands back untouched while the
-            same requests hold the same slots (`_mtp_io`)."""
+            same requests hold the same slots (`_io`)."""
             tokens, top_ks, seeds, poss = ints
             temps, top_ps = floats
             live = tokens >= 0
@@ -1996,23 +2040,129 @@ class LLMEngine:
             poss[i] = s.req.generated_offset + len(s.generated)
         return temps, top_ks, top_ps, seeds, poss
 
-    def _may_lead(self, active) -> bool:
-        """Whether the verify step AFTER the one just sent may go out
-        before its tokens are read: nobody waits for a slot (an admission
-        takes the next step), and no slot of `active` can end this step
-        by its length, so that only a stop token, an abort or a deadline
+    @staticmethod
+    def _simple(s: _Slot) -> bool:
+        # no penalty counts, no logprobs: the device sampler covers it
+        # (any temperature / top-k / top-p / seed)
+        return s.counts is None and s.n_logprobs < 0
+
+    def _packed_step(self, active) -> Optional[str]:
+        """The packed step that serves the slots `active`, "plain" or
+        "verify", or None. Resident fast path: when every active slot
+        is device-samplable and no fault clause is live (poison_rows
+        edits logits on the host side), forward + health + sampling
+        run as ONE dispatch, and the [B, V] logits never exist outside
+        the executable. A speculating engine's is the verify step (two
+        rows a slot); it falls to the plain one-row step, followed by
+        the MTP module's row, under brownout and with any slot that
+        needs the host sampler."""
+        if not (decode_resident_enabled() and not self._paged
+                and not self.faults.enabled
+                and all(self._simple(self.slots[i]) for i in active)):
+            return None
+        if not self._mtp:
+            return "plain"
+        return "verify" if self.speculative_allowed else None
+
+    def _sent_decode(self, sent: str) -> None:
+        """A decode program went out: `sent` "ahead" of the step that
+        reads it, or "in_step"."""
+        self._m_decode_steps.labels(sent).inc()
+        self._chunk_unanswered = False
+
+    def _step_args(self, active):
+        """`(holders, ints, floats)` of a packed step over the slots
+        `active`: the device's own where `_io` stands for them (nothing
+        goes to the device while the same requests hold the same
+        slots), else one put each from the host's view."""
+        holders = tuple((i, self.slots[i].req) for i in active)
+        io, self._io = self._io, None
+        if io is not None and len(io[0]) == len(holders) and all(
+                i == j and r is q for (i, r), (j, q) in zip(io[0], holders)):
+            return io
+        temps, top_ks, top_ps, seeds, poss = self._sampling_arrays(active)
+        with self.phases.phase("dispatch.h2d", child=True):
+            ints = jnp.asarray(np.stack(
+                [self._token_row(active), top_ks, seeds, poss]))
+            floats = jnp.asarray(np.stack([temps, top_ps]))
+        return holders, ints, floats
+
+    def _token_row(self, active) -> np.ndarray:
+        """Each slot's last token, `[max_batch]` int32; -1: the slot
+        holds no request (decode_forward)."""
+        tokens = np.full((self.cfg_engine.max_batch,), -1, np.int32)
+        for i in active:
+            tokens[i] = self.slots[i].last_token
+        return tokens
+
+    def _send_step(self, verify: bool, active, ints, floats,
+                   ahead: bool = False):
+        """Dispatch one packed step over the slots `active`: the block
+        the host will fetch and the `ints` after it."""
+        if verify:
+            (out, ints, self._mtp_draft, self._mtp_q,
+             self.cache) = self._decode_resident_mtp(
+                self.params, ints, floats, self._mtp_draft, self._mtp_q,
+                self.cache)
+        else:
+            out, ints, self.cache = self._decode_resident(
+                self.params, ints, floats, self.cache,
+                all_greedy=all(
+                    self.slots[i].req.params.temperature <= 0.0
+                    for i in active),
+                with_quality=self._use_quality)
+        self._sent_decode("ahead" if ahead else "in_step")
+        return out, ints
+
+    @property
+    def _joining(self) -> bool:
+        """An admission's last chunk is out and its first token not yet
+        read: its slot joins the next step, which goes out once the
+        token is known."""
+        a = self._admitting
+        return a is not None and a.last is not None
+
+    def _send_ahead(self) -> None:
+        """The close of a decode step that leaves no step in flight (it
+        read one that went out ahead and the device's `ints` no longer
+        serve: a request ended, a newcomer joined, a slot is at its
+        end): the next one goes out now, on the host's view of the
+        slots that go on, so that the device does not wait for the next
+        step's sweep and admission, and a chunk that step dispatches
+        follows a decode step and not the last chunk. Not while a
+        prompt's last chunk awaits its first token (`_may_lead`)."""
+        going = [i for i, s in enumerate(self.slots) if s.active]
+        packed = self._packed_step(going) if going else None
+        if packed is None or self._joining:
+            return
+        with self.phases.phase("dispatch"):
+            holders, ints, floats = self._step_args(going)
+            out, ints = self._send_step(packed == "verify", going, ints,
+                                        floats, ahead=True)
+        self._ahead = (holders, out, ints, floats)
+
+    def _may_lead(self, active, rows_a_slot: int) -> bool:
+        """Whether the step AFTER the one just sent may go out before
+        its tokens are read: no slot of `active` can end this step by
+        its length, so that only a stop token, an abort or a deadline
         leaves a step computed in vain, and no step writes a row past
-        the slab."""
-        if self._admitting is not None or self.waiting:
+        the slab. A step computes `rows_a_slot` rows a slot (two of a
+        verify step). An admission does not stop it: the device
+        alternates chunk and step as it did, and a slot admitted
+        meanwhile waits one step. But not behind a prompt's LAST chunk:
+        the program that samples its first token would queue behind the
+        step, and the first token wait a step longer."""
+        if self._joining:
             return False
         s_max = self.cfg_engine.max_seq
         for i in active:
             s = self.slots[i]
             r = s.req
             if r.params.max_tokens - (r.generated_offset
-                                      + len(s.generated)) <= 2:
+                                      + len(s.generated)) <= rows_a_slot:
                 return False
-            if len(r.prompt_token_ids) + len(s.generated) + 4 >= s_max:
+            if (len(r.prompt_token_ids) + len(s.generated)
+                    + 2 * rows_a_slot >= s_max):
                 return False
         return True
 
@@ -2020,14 +2170,11 @@ class LLMEngine:
         """`engine_mtp_row` for the slots `rows`, each with its last
         token: writes the MTP module's row of the position before it
         and leaves the slot's standing draft. `hidden_dev` `[B, D]`."""
-        b = self.cfg_engine.max_batch
-        self._mtp_io = None       # a slot changed hands, or a plain step
-        tokens = np.full((b,), -1, np.int32)
+        self._io = None           # a slot changed hands, or a plain step
         for i in rows:
-            tokens[i] = self.slots[i].last_token
             self.slots[i].drafted = True
         puts = [jnp.asarray(a) for a in
-                (tokens,) + self._sampling_arrays(rows)]
+                (self._token_row(rows),) + self._sampling_arrays(rows)]
         self.cache, self._mtp_draft, self._mtp_q = self._mtp_row(
             self.params, self.cache, self._mtp_draft, self._mtp_q,
             hidden_dev, *puts)
@@ -2040,10 +2187,14 @@ class LLMEngine:
         self._drain_migrations()    # before handoffs: slab-mode imports
         self._drain_handoffs()      # ride the handoff staging inbox
         a = self._admitting
+        # the last chunk went out beside live streams and no decode
+        # program has followed it (a step that read a step sent ahead
+        # and could send none): this step's goes first
+        hold = self._chunk_unanswered and any(s.active for s in self.slots)
         if a is None:
             free = next((i for i, s in enumerate(self.slots)
                          if not s.active), None)
-            if free is None:
+            if free is None or hold:
                 return
             # overload-aware scheduling replaces pure FCFS: strict QoS
             # priority with aging promotion, then least-served tenant
@@ -2144,6 +2295,14 @@ class LLMEngine:
             self._abort.discard(a.req.request_id)
             self._finish_admission_abort(a)
             return
+        if a.last is not None:
+            # the last chunk went out a step ago, behind a decode step
+            # that has been read since: this step waits for the chunk
+            self.phases.mark_chunk()
+            self._finish_admission(a)
+            return
+        if hold:
+            return
 
         plen = len(a.req.prompt_token_ids)
         chunk = a.chunk
@@ -2170,7 +2329,7 @@ class LLMEngine:
         # a put's buffer is let go where the call's own temporary was:
         # while the program runs, not at the frame's exit after the wait
         del padded_dev
-        self.phases.mark_chunk()
+        self._chunk_unanswered = True
         self._m_prefill_chunks.inc()
         self._m_prefill_tokens.labels("prompt").inc(len(part))
         self._m_prefill_tokens.labels("padding").inc(chunk - len(part))
@@ -2178,31 +2337,47 @@ class LLMEngine:
         a.consumed += chunk
 
         if a.consumed >= plen:
-            if self._paged:
-                self.cache = self._paged_insert(a, plen)
-            else:
-                self._remember_prefix(a.req.prompt_token_ids, a.cache1)
-                self.cache = self._insert(self.cache, a.cache1,
-                                          a.slot_idx, plen)
-            s = self.slots[a.slot_idx]
-            s.req = a.req
-            self._setup_slot_sampler(s)
-            first, lp = self._sample_admission(
-                logits[:, plen - 1 - start], s)
-            s.generated = [int(first)]
-            s.last_token = int(first)
-            s.active = True
-            self._obs_admission_complete(a.req.request_id)
-            self._emit(s, lp)
-            s.drafted = False
-            if not self._check_done(a.slot_idx) and self._mtp:
-                # the MTP row of the last prompt position waited for
-                # this token: with it the slot's first draft stands
-                self._mtp_rows_after(
-                    [a.slot_idx], jnp.broadcast_to(
-                        hidden,
-                        (self.cfg_engine.max_batch, hidden.shape[-1])))
-            self._admitting = None
+            a.last = (logits[:, plen - 1 - start], hidden)
+            if self._ahead is not None:
+                # a decode step is on the device whose tokens are not
+                # read yet, and this chunk is queued behind it: the wait
+                # for the first token would hold them back by a chunk, so
+                # the admission ends at the next step's start, and that
+                # step is the one this chunk makes a chunk step
+                return
+            self._finish_admission(a)
+        self.phases.mark_chunk()
+
+    def _finish_admission(self, a: _Admission) -> None:
+        """The end of an admission whose last chunk is out (`a.last`):
+        its rows into the batched cache at the slot, the first token
+        sampled, waited for and emitted, the slot live."""
+        logits, hidden = a.last
+        plen = len(a.req.prompt_token_ids)
+        if self._paged:
+            self.cache = self._paged_insert(a, plen)
+        else:
+            self._remember_prefix(a.req.prompt_token_ids, a.cache1)
+            self.cache = self._insert(self.cache, a.cache1,
+                                      a.slot_idx, plen)
+        s = self.slots[a.slot_idx]
+        s.req = a.req
+        self._setup_slot_sampler(s)
+        first, lp = self._sample_admission(logits, s)
+        s.generated = [int(first)]
+        s.last_token = int(first)
+        s.active = True
+        self._obs_admission_complete(a.req.request_id)
+        self._emit(s, lp)
+        s.drafted = False
+        if not self._check_done(a.slot_idx) and self._mtp:
+            # the MTP row of the last prompt position waited for
+            # this token: with it the slot's first draft stands
+            self._mtp_rows_after(
+                [a.slot_idx], jnp.broadcast_to(
+                    hidden,
+                    (self.cfg_engine.max_batch, hidden.shape[-1])))
+        self._admitting = None
 
     # -- paged KV bookkeeping (kv_page_size > 0) ----------------------------
 
@@ -3920,8 +4095,7 @@ class LLMEngine:
         s.req = None
         s.active = False
         s.drafted = False
-        if self._mtp:
-            self._mtp_io = None
+        self._io = None
         s.generated = []
         s.counts = None
         s.counts_out = None
@@ -4518,47 +4692,33 @@ class LLMEngine:
         ce = self.cfg_engine
         ph = self.phases.phase
 
-        def simple(s: _Slot) -> bool:
-            # no penalty counts, no logprobs: the device sampler covers
-            # it (any temperature / top-k / top-p / seed)
-            return s.counts is None and s.n_logprobs < 0
-
-        # resident fast path: when every active slot is
-        # device-samplable and no fault clause is live (poison_rows
-        # edits logits on the host side), forward + health +
-        # sampling run as ONE dispatch — the [B, V] logits never
-        # exist outside the executable
-        resident = (decode_resident_enabled()
-                    and not self._paged
-                    and not self.faults.enabled
-                    and all(simple(self.slots[i]) for i in active))
-        # a speculating engine's resident step is the verify step (two
-        # rows a slot); it falls to the plain one-row step, followed by
-        # the MTP module's row, under brownout and with any slot that
-        # needs the host sampler
-        verify = self._mtp and resident and self.speculative_allowed
-        ahead = None
-        if self._mtp:
-            resident = False
-            ahead, self._mtp_ahead = self._mtp_ahead, None
+        simple = self._simple
+        # the packed step that serves these slots, if one does: the
+        # resident step, or a speculating engine's verify step
+        packed = self._packed_step(active)
+        verify, resident = packed == "verify", packed == "plain"
         # this step may send the next one out before it reads its own
-        # tokens: only from a verify step of its own choosing, over the
+        # tokens: only from a packed step of its own choosing, over the
         # slots that step held
-        lead = verify
+        lead = resident or verify
+        ahead, self._ahead = self._ahead, None
         if ahead is not None:
             # the step that went out during the last one IS this step for
             # the slots whose requests it held and that are still here (a
             # slot admitted since waits a step; one that ended since is
-            # read no further); brownout or a host-sampled newcomer take
-            # effect with the next step
+            # read no further); brownout, a fault clause or a host-sampled
+            # newcomer take effect with the next step
             held = dict(ahead[0])
             stay = [i for i in active if held.get(i) is self.slots[i].req]
             if stay:
                 lead = lead and len(stay) == len(active) == len(held)
-                active, verify = stay, True
+                active = stay
+                verify, resident = self._mtp, not self._mtp
             else:
+                self._m_vain_steps.inc()
                 ahead = None
         rows_a_slot = 2 if verify else 1
+        read_ahead = ahead is not None
         if self._dsa is not None:
             # what the selection keeps this step, by its own rule from
             # the positions the host already knows (outside the phases):
@@ -4599,8 +4759,8 @@ class LLMEngine:
         toks = None
         finite_host = None
         n_emit = None       # verify step: tokens each slot kept, [B]
-        hidden_dev = mtp_next = None
-        toks_dev = finite_dev = qrows_dev = logits_dev = None
+        hidden_dev = io_next = None
+        out_dev = finite_dev = logits_dev = None
         qrows = None        # [B, 3] chosen_lp/entropy/top1_margin (f32)
         t_decode0 = time.perf_counter()
         t_wall0 = time.time()
@@ -4608,10 +4768,6 @@ class LLMEngine:
         # return is pure host work (trace + transfer enqueue); the
         # blocked wait on the step result is device compute
         with ph("dispatch"):
-            # -1: the slot holds no request (decode_forward)
-            tokens = np.full((self.cfg_engine.max_batch,), -1, np.int32)
-            for i in active:
-                tokens[i] = self.slots[i].last_token
             # tokens each active slot will hold after this step (its
             # query sits one below), captured while every slot's request
             # is still attached (_check_done frees finishing slots
@@ -4624,7 +4780,7 @@ class LLMEngine:
                 # what the slab decode kernel fetches this step, by its
                 # own rule from the positions the host already knows
                 layers, slab, s_max, hkv = self._attn_blocks
-                at = [-1] * len(tokens)          # -1: an empty slot
+                at = [-1] * ce.max_batch         # -1: an empty slot
                 for i, d in zip(active, depths):
                     at[i] = d - 1
                 self._m_attn_blocks.labels("read").inc(
@@ -4638,109 +4794,78 @@ class LLMEngine:
                     [d - 1 for d in depths], self.cache.page_size,
                     self._pages_per_seq))
                 self._m_paged_pages.labels("table").inc(
-                    layers * len(tokens) * self._pages_per_seq)
+                    layers * ce.max_batch * self._pages_per_seq)
 
-            if verify:
-                # nothing goes to the device while the same requests
-                # hold the same slots: the last step left the next one's
-                # tokens and token indices there
-                holders = tuple((i, id(self.slots[i].req)) for i in active)
-                io, self._mtp_io = self._mtp_io, None
+            if not (verify or resident):
+                # another decode program, sent and waited for here
+                self._sent_decode("in_step")
+            if verify or resident:
                 if ahead is not None:
-                    _, toks_dev, ints_dev, floats_dev = ahead
+                    holders, out_dev, ints_dev, floats_dev = ahead
                 else:
-                    if io is not None and io[0] == holders:
-                        _, ints_dev, floats_dev = io
-                    else:
-                        temps, top_ks, top_ps, seeds, poss = \
-                            self._sampling_arrays(active)
-                        with ph("dispatch.h2d", child=True):
-                            ints_dev = jnp.asarray(
-                                np.stack([tokens, top_ks, seeds, poss]))
-                            floats_dev = jnp.asarray(
-                                np.stack([temps, top_ps]))
-                    (toks_dev, ints_dev, self._mtp_draft, self._mtp_q,
-                     self.cache) = self._decode_resident_mtp(
-                        self.params, ints_dev, floats_dev, self._mtp_draft,
-                        self._mtp_q, self.cache)
-                if lead and self._may_lead(active):
-                    # the NEXT verify step goes out now, on what this one
+                    holders, ints_dev, floats_dev = self._step_args(active)
+                    out_dev, ints_dev = self._send_step(
+                        verify, active, ints_dev, floats_dev)
+                if lead and self._may_lead(active, rows_a_slot):
+                    # the NEXT step goes out now, on what this one
                     # leaves on the device, so the device does not wait
                     # while the host reads and emits this step's tokens.
                     # What it holds of a slot that this step ends is
                     # never read (`_finish` sets the slot's `pos` back)
-                    (toks_next, ints_next, self._mtp_draft, self._mtp_q,
-                     self.cache) = self._decode_resident_mtp(
-                        self.params, ints_dev, floats_dev, self._mtp_draft,
-                        self._mtp_q, self.cache)
-                    self._mtp_ahead = (
-                        tuple((i, self.slots[i].req) for i in active),
-                        toks_next, ints_next, floats_dev)
-                    del toks_next, ints_next
+                    out_next, ints_next = self._send_step(
+                        verify, active, ints_dev, floats_dev, ahead=True)
+                    self._ahead = (holders, out_next, ints_next, floats_dev)
+                    del out_next, ints_next
                 elif ahead is None or lead:
-                    mtp_next = (holders, ints_dev, floats_dev)
-                del io, ints_dev, floats_dev
+                    io_next = (holders, ints_dev, floats_dev)
+                # let go inside the phase, while the program runs (not
+                # at the frame's exit, after the wait, where the device
+                # idles)
+                del ints_dev, floats_dev
                 ahead = None
             elif self._mtp:
                 with ph("dispatch.h2d", child=True):
-                    tokens_dev = jnp.asarray(tokens)
+                    tokens_dev = jnp.asarray(self._token_row(active))
                 logits_dev, hidden_dev, self.cache = self._decode_hidden(
                     self.params, tokens_dev, self.cache)
                 del tokens_dev
-            elif resident:
-                temps, top_ks, top_ps, seeds, poss = \
-                    self._sampling_arrays(active)
-                all_greedy = all(
-                    self.slots[i].req.params.temperature <= 0.0
-                    for i in active)
-                with ph("dispatch.h2d", child=True):
-                    puts = [jnp.asarray(a) for a in (
-                        tokens, temps, top_ks, top_ps, seeds, poss)]
-                toks_dev, finite_dev, self.cache, qrows_dev = \
-                    self._decode_resident(
-                        self.params, puts[0], self.cache, *puts[1:],
-                        all_greedy=all_greedy,
-                        with_quality=self._use_quality)
-                # let go inside the phase, while the program runs, as
-                # the call's own temporaries were (not at the frame's
-                # exit, after the wait, where the device idles)
-                del puts
             elif self._paged:
                 # CoW barrier first (shared write pages get private
                 # copies), then one block-table-driven decode dispatch
                 with ph("cache.cow", child=True):
                     self._cow_step(active)
                 with ph("dispatch.h2d", child=True):
-                    tokens_dev = jnp.asarray(tokens)
+                    tokens_dev = jnp.asarray(self._token_row(active))
                     bt_dev = self._bt()
                 logits_dev, self.cache = self._decode_paged(
                     self.params, tokens_dev, self.cache, bt_dev)
                 del tokens_dev, bt_dev
             else:
                 with ph("dispatch.h2d", child=True):
-                    tokens_dev = jnp.asarray(tokens)
+                    tokens_dev = jnp.asarray(self._token_row(active))
                 logits_dev, self.cache = self._decode(
                     self.params, tokens_dev, self.cache)
                 del tokens_dev
         with ph("device"):
             jax.block_until_ready(  # graftlint: disable=step-host-sync
-                toks_dev if resident or verify else logits_dev)
+                out_dev if resident or verify else logits_dev)
         dispatch_s = self.phases.seconds("dispatch")
         device_s = self.phases.seconds("device")
 
         with ph("sample"):
-            if verify:
+            if verify or resident:
                 with ph("sample.fetch", child=True):
-                    packed = np.asarray(toks_dev)      # the one fetch
+                    packed = np.asarray(out_dev)       # the one fetch
+            if verify:
                 toks = np.asarray(packed[:, :2])
                 n_emit = np.asarray(packed[:, 2])
                 finite_host = packed[:, 3] != 0
             elif resident:
-                with ph("sample.fetch", child=True):
-                    toks = np.asarray(toks_dev)
-                    finite_host = np.asarray(finite_dev)
-                    if qrows_dev is not None:
-                        qrows = np.asarray(qrows_dev)
+                toks = packed[:, 0]
+                finite_host = packed[:, 1] != 0
+                if packed.shape[1] > 2:
+                    qrows = np.ascontiguousarray(
+                        packed[:, 2:]).view(np.float32)
             else:
                 # fault injection: poison selected rows with NaN AFTER
                 # the decode — other rows' values are untouched, so
@@ -4810,7 +4935,7 @@ class LLMEngine:
             # the step's device outputs end here, inside a phase: the
             # release of their buffers is host time with an owner (left
             # to the frame's exit it fell between two phases)
-            toks_dev = finite_dev = qrows_dev = logits_dev = None
+            out_dev = finite_dev = logits_dev = None
             picked_dev = None
         if not active:          # every row was sick
             with ph("observe"):
@@ -4882,13 +5007,6 @@ class LLMEngine:
                     if judged:
                         self._m_spec_accept.observe(
                             sum(judged) / len(judged))
-                    if mtp_next is not None and len(active) == len(
-                            mtp_next[0]) and all(
-                            self.slots[i].active for i in active):
-                        # every slot goes on: the device's view of the
-                        # next step stands
-                        self._mtp_io = mtp_next
-                    mtp_next = None
                 else:
                     # the plain step of a speculating engine: the MTP
                     # module's row of each slot that goes on, now that
@@ -4897,6 +5015,15 @@ class LLMEngine:
                     if going:
                         self._mtp_rows_after(going, hidden_dev)
                 hidden_dev = None
+            if io_next is not None and len(active) == len(
+                    io_next[0]) and all(
+                    self.slots[i].active for i in active):
+                # every slot goes on: the device's view of the next
+                # step stands
+                self._io = io_next
+            io_next = None
+        if self._ahead is None:
+            self._send_ahead()
         with ph("observe"):
             # live quality telemetry: resident steps hand over the
             # fused [B, 3] block (zero extra dispatches); host-sampled
@@ -4914,8 +5041,14 @@ class LLMEngine:
                 # one batched step advances every active stream by the
                 # tokens it was given (one; one or two of a verify
                 # step), so a stream's time-per-output-token is the
-                # step's wall time over them
-                dt = time.perf_counter() - t_decode0
+                # step's wall time over them. A step that went out
+                # ahead ran under the last step's emit and observe: what
+                # its tokens took is the time since that step's were
+                # timed, not the rest of the wait this step saw
+                now = time.perf_counter()
+                span_s = now - t_decode0
+                dt = now - self._t_step_timed if read_ahead else span_s
+                self._t_step_timed = now
                 # each stream's TPOT samples for its QoS class, one a
                 # token
                 for q, n in zip(step_qos, step_tokens):
@@ -4929,8 +5062,9 @@ class LLMEngine:
                 # still in flight measures the chunk too, i.e. how long
                 # the prompt is (a chunk is 3 decodes at 7B, PERF.md PR
                 # 26); a last chunk was waited for before the decode
-                # went out. A sample counts for no more than the ratio
-                # at which the signal saturates: one step of many
+                # went out (no step is sent ahead behind a last chunk:
+                # `_may_lead`). A sample counts for no more than the
+                # ratio at which the signal saturates: one step of many
                 # floors (an executable's first load) is not inflation,
                 # a run of them still fills the signal
                 if self._admitting is None:
@@ -4955,7 +5089,7 @@ class LLMEngine:
                     self.spans.record(
                         "decode_step", tid,
                         parent_id=parent_sid,
-                        t_start=t_wall0, t_end=t_wall0 + dt,
+                        t_start=t_wall0, t_end=t_wall0 + span_s,
                         step=self._step_idx, request_id=rid,
                         dispatch_ms=round(dispatch_s * 1000.0, 3),
                         device_ms=round(device_s * 1000.0, 3))

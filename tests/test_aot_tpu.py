@@ -56,6 +56,26 @@ def _sds(tree, dev):
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), tree)
 
 
+def _lower_resident(eng, dev):
+    """`engine_decode_resident` lowered at the engine's own geometry on
+    its packed arguments: `ints` `[4, B]`, `floats` `[2, B]`."""
+    b = eng.cfg_engine.max_batch
+    return eng._decode_resident.lower(
+        _sds(eng.params, dev),
+        _sds(jax.ShapeDtypeStruct((4, b), jnp.int32), dev),
+        _sds(jax.ShapeDtypeStruct((2, b), jnp.float32), dev),
+        _sds(jax.eval_shape(lambda: eng.cache), dev),
+        all_greedy=True, with_quality=False)
+
+
+def _two_in_flight_bytes(ma) -> int:
+    """What two resident steps in flight hold: the arguments once (the
+    cache is donated from one to the next, the weights are shared), the
+    temporaries and the small results of each."""
+    return (ma.argument_size_in_bytes + 2 * ma.temp_size_in_bytes
+            + 2 * (ma.output_size_in_bytes - ma.alias_size_in_bytes))
+
+
 def _compile(fn, *abstract_args):
     return jax.jit(fn).lower(*abstract_args).compile()
 
@@ -715,13 +735,7 @@ def test_engine_decode_resident_step_compiles(v5e, aot_flags, b):
     dev = v5e.devices[0]
     s = 2048
     eng = _mistral7b_engine(b, s)
-    i32 = _sds(jax.ShapeDtypeStruct((b,), jnp.int32), dev)
-    f32 = _sds(jax.ShapeDtypeStruct((b,), jnp.float32), dev)
-    comp = eng._decode_resident.lower(
-        _sds(eng.params, dev), i32,
-        _sds(jax.eval_shape(lambda: eng.cache), dev),
-        f32, i32, f32, i32, i32, all_greedy=True,
-        with_quality=False).compile()
+    comp = _lower_resident(eng, dev).compile()
     assert _has_mosaic_call(comp), (
         "engine decode step compiled WITHOUT any Mosaic kernel")
     txt = comp.as_text()
@@ -1399,13 +1413,7 @@ def test_dots3_note_engine_programs_compile_and_fit(v5e, aot_flags):
         max_batch=b, max_seq=doc["engine"]["max_seq"],
         prefill_chunk=doc["engine"]["prefill_chunk"], sentinel=False,
         quality=False))
-    i32 = _sds(jax.ShapeDtypeStruct((b,), jnp.int32), dev)
-    f32 = _sds(jax.ShapeDtypeStruct((b,), jnp.float32), dev)
-    comp = eng._decode_resident.lower(
-        _sds(eng.params, dev), i32,
-        _sds(jax.eval_shape(lambda: eng.cache), dev),
-        f32, i32, f32, i32, i32, all_greedy=True,
-        with_quality=False).compile()
+    comp = _lower_resident(eng, dev).compile()
     txt = comp.as_text()
     for name in ("dsa_index_score", "dsa_select", "sparse_mla_decode",
                  "window_mla_decode", "mla_latent_append",
@@ -1570,13 +1578,7 @@ def test_evabyte_engine_decode_step_compiles_and_fits(v5e, aot_flags):
         quality=False))
     assert cache_nbytes(eng._cache_spec, b, 8192)["total"] == 8_053_063_680
     assert eng._admission_cost(6144) == 32 * (2048 + 512) * 16384
-    i32 = _sds(jax.ShapeDtypeStruct((b,), jnp.int32), dev)
-    f32 = _sds(jax.ShapeDtypeStruct((b,), jnp.float32), dev)
-    comp = eng._decode_resident.lower(
-        _sds(eng.params, dev), i32,
-        _sds(jax.eval_shape(lambda: eng.cache), dev),
-        f32, i32, f32, i32, i32, all_greedy=True,
-        with_quality=False).compile()
+    comp = _lower_resident(eng, dev).compile()
     txt = comp.as_text()
     for name in ("eva_decode_attention", "eva_summarize"):
         assert name in txt, name
@@ -1585,6 +1587,11 @@ def test_evabyte_engine_decode_step_compiles_and_fits(v5e, aot_flags):
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert 11.5e9 < live < 12.2e9, live / 1e9
     assert ma.temp_size_in_bytes < 64 * 2 ** 20
+    # the step goes out one ahead: two of it in flight hold the
+    # arguments once and the temporaries twice, inside the chip's 16 GB
+    print("evabyte decode step: args GB", ma.argument_size_in_bytes / 1e9,
+          "temp GB", ma.temp_size_in_bytes / 1e9)
+    assert _two_in_flight_bytes(ma) < 12.3e9, _two_in_flight_bytes(ma) / 1e9
     moved = re.findall(
         r"= \w+\[(?:\d+,)?6,(?:2048|512),32,128\]\S* "
         r"(?:copy|dynamic-slice)\(", txt)
@@ -1783,13 +1790,7 @@ def test_mimo_v2_engine_decode_step_compiles_and_fits(v5e, aot_flags):
     dev = v5e.devices[0]
     eng = _mimo_v2_engine()
     b = eng.cfg_engine.max_batch
-    i32 = _sds(jax.ShapeDtypeStruct((b,), jnp.int32), dev)
-    f32 = _sds(jax.ShapeDtypeStruct((b,), jnp.float32), dev)
-    comp = eng._decode_resident.lower(
-        _sds(eng.params, dev), i32,
-        _sds(jax.eval_shape(lambda: eng.cache), dev),
-        f32, i32, f32, i32, i32, all_greedy=True,
-        with_quality=False).compile()
+    comp = _lower_resident(eng, dev).compile()
     txt = comp.as_text()
     for name in ("decode_attention_lanes", "swa_decode_attention",
                  "moe_routed_decode", "qmatmul_gemv_sym_int4"):
@@ -1914,13 +1915,7 @@ def test_afmoe_engine_decode_step_compiles_and_fits(v5e, aot_flags):
     dev = v5e.devices[0]
     eng = _afmoe_engine()
     b = eng.cfg_engine.max_batch
-    i32 = _sds(jax.ShapeDtypeStruct((b,), jnp.int32), dev)
-    f32 = _sds(jax.ShapeDtypeStruct((b,), jnp.float32), dev)
-    lowered = eng._decode_resident.lower(
-        _sds(eng.params, dev), i32,
-        _sds(jax.eval_shape(lambda: eng.cache), dev),
-        f32, i32, f32, i32, i32, all_greedy=True,
-        with_quality=False)
+    lowered = _lower_resident(eng, dev)
     comp = lowered.compile()
     txt = comp.as_text()
     for name in ("decode_attention_lanes", "swa_decode_attention",
@@ -1933,6 +1928,10 @@ def test_afmoe_engine_decode_step_compiles_and_fits(v5e, aot_flags):
           "temp GB", ma.temp_size_in_bytes / 1e9, "live GB", live / 1e9,
           "lowered chars", len(lowered.as_text()))
     assert 7.0e9 < live < 9.5e9, live / 1e9   # 4.3 GB weights + 3.8 GB slab
+    # the step goes out one ahead: two of it in flight (arguments 8.07 GB
+    # once, 0.004 GB of temporaries each) are far inside the chip's 16 GB
+    assert ma.temp_size_in_bytes < 64 * 2 ** 20
+    assert _two_in_flight_bytes(ma) < 9.5e9, _two_in_flight_bytes(ma) / 1e9
     moved = re.findall(
         r"= \w+\[(?:1,)?16,(?:8192|2048),512\]\S* "
         r"(?:copy|fusion|dynamic-slice)\(", txt)

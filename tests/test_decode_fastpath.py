@@ -374,6 +374,8 @@ def test_engine_empty_slot_stays_at_zero(resident, attention, monkeypatch):
                 for i, sl in enumerate(eng.slots) if sl.active}
         before = {k: _attn_blocks_counter(eng, k) for k in ("read", "slab")}
         pos_before = np.asarray(eng.cache.pos)
+        # slots of a resident step that is out ahead of the host's read
+        led = {i for i, _ in eng._ahead[0]} if eng._ahead else set()
         # a step that admits decodes slots this loop did not see live
         settled = not eng.waiting and eng._admitting is None
         eng.step()
@@ -383,9 +385,10 @@ def test_engine_empty_slot_stays_at_zero(resident, attention, monkeypatch):
             if sl.req is not None and sl.req.request_id == "short":
                 short_slot = i
         if settled:
-            # the host's positions are the cache's own
+            # the host's positions are the cache's own, or one behind
+            # them where the next step is out already
             for i, p in live.items():
-                assert pos_before[i] == p
+                assert pos_before[i] == p + (i in led)
             assert (_attn_blocks_counter(eng, "read") - before["read"]
                     == layers * blocks_read(
                         [live.get(i, -1) for i in range(b)], s, hkv))
