@@ -132,6 +132,11 @@ bigdl_tpu_decode_steps_total{sent}          LLMEngine._decode_step, where a
                                             in_step
 bigdl_tpu_decode_steps_vain_total           LLMEngine._decode_step: a step sent
                                             ahead that no slot was read from
+bigdl_tpu_sampler_steps_total{path}         LLMEngine._sent_decode, once a
+                                            decode program: greedy | topk
+                                            (sampled, no row sorted) |
+                                            nucleus (a live top_p < 1: the
+                                            sorted branch ran)
 bigdl_tpu_spec_round_seconds{mode}          speculative._spec_observe
 bigdl_tpu_spec_tokens_total{mode,kind}      speculative._spec_observe
 bigdl_tpu_kv_cache_bytes{dtype,component}   ops/kvcache.publish_kv_cache_bytes

@@ -49,6 +49,7 @@ module and observability/__init__ for the field mapping):
     bigdl_tpu_mtp_slot_steps_total{kind=verify|plain}            counter
     bigdl_tpu_decode_steps_total{sent=ahead|in_step}             counter
     bigdl_tpu_decode_steps_vain_total                            counter
+    bigdl_tpu_sampler_steps_total{path=greedy|topk|nucleus}      counter
     bigdl_tpu_spec_round_seconds{mode=...}                       histogram
     bigdl_tpu_spec_tokens_total{mode=...,kind=drafted|accepted}  counter
     bigdl_tpu_requests_quarantined_total{reason=nan_logits|crash_loop}

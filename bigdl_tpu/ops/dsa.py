@@ -15,7 +15,9 @@ is `lax.top_k`'s rule).
 
 `select_topk_mask` is that selection as a MASK, exact and without a
 sort: the k-th largest score is found by bisection on the bits of the
-scores' order-preserving integer keys (32 counts), the ties at that
+scores' order-preserving integer keys (32 counts; `kth_largest` is that
+search alone, which the engine's sampler takes its top-k threshold from,
+on 16-bit keys where the logits are bfloat16), the ties at that
 score are taken from the lowest position up by a second bisection on
 the position (log2 S counts). `jax.lax.approx_max_k` is not this
 selection.
@@ -31,24 +33,49 @@ from bigdl_tpu.ops.pallas import dsa_attention as kernels
 from bigdl_tpu.ops.pallas import mla_chunk_attention as chunk_kernel
 
 
+def _kth_key(x: jax.Array, k):
+    """`(key, t)`: unsigned integer keys in the order of the floats `x`
+    `[..., V]` (as wide as x's own dtype), and `t` `[..., 1]` the `k`-th
+    largest key of each row, counted with multiplicity. `k` an int or an
+    int32 array `[...]`, held to `1 .. V`. No sort: bisection on the
+    key's bits from the top, one count a bit, unrolled (16 for
+    bfloat16, 32 for float32)."""
+    nbits = x.dtype.itemsize * 8
+    uint = {16: jnp.uint16, 32: jnp.uint32}[nbits]
+    bits = lax.bitcast_convert_type(x, uint)
+    sign = uint(1 << (nbits - 1))
+    key = jnp.where(bits >= sign, ~bits, bits | sign)
+    k = jnp.clip(jnp.asarray(k, jnp.int32), 1, x.shape[-1])[..., None]
+    t = jnp.zeros(x.shape[:-1] + (1,), uint)
+    for i in range(nbits - 1, -1, -1):
+        cand = t | uint(1 << i)
+        n = jnp.sum(key >= cand, axis=-1, keepdims=True, dtype=jnp.int32)
+        t = jnp.where(n >= k, cand, t)
+    return key, t
+
+
+def kth_largest(x: jax.Array, k) -> jax.Array:
+    """The `k`-th largest VALUE of each row of `x` `[..., V]`, in x's own
+    dtype and counted with multiplicity: entry `k - 1` of the row sorted
+    downwards, found with no sort (`_kth_key`). `k` an int or an int32
+    array `[...]`, held to `1 .. V`."""
+    _, t = _kth_key(x, k)
+    sign = t.dtype.type(1 << (x.dtype.itemsize * 8 - 1))
+    return lax.bitcast_convert_type(
+        jnp.where(t >= sign, t ^ sign, ~t), x.dtype)[..., 0]
+
+
 def select_topk_mask(scores: jax.Array, k: int) -> jax.Array:
     """`[..., S]` float32 scores (`-inf`: not a candidate) -> bool mask
     of the `k` largest of each row, ties to the lower position; every
     candidate where a row has no more than `k`."""
     s = scores.shape[-1]
-    bits = lax.bitcast_convert_type(scores.astype(jnp.float32), jnp.uint32)
-    # unsigned keys in the order of the floats
-    key = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(0x80000000))
+    key, t = _kth_key(scores.astype(jnp.float32), k)
     lead = scores.shape[:-1] + (1,)
 
     def count(m):
         return jnp.sum(m, axis=-1, keepdims=True, dtype=jnp.int32)
 
-    def kth(i, t):
-        cand = t | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
-        return jnp.where(count(key >= cand) >= k, cand, t)
-
-    t = lax.fori_loop(0, 32, kth, jnp.zeros(lead, jnp.uint32))
     above = key > t
     tie = key == t
     need = k - count(above)                      # >= 1: t is the k-th

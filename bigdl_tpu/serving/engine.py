@@ -74,6 +74,7 @@ from bigdl_tpu.observability.stats import ewma as stats_ewma
 from bigdl_tpu.observability.tracing import (ADMISSION_KIND, PhaseClock,
                                              RequestTracer)
 from bigdl_tpu.observability.usage import UsageLedger
+from bigdl_tpu.ops.dsa import kth_largest
 from bigdl_tpu.ops.eva import rows_read
 from bigdl_tpu.ops.kvcache import (SNAPSHOT_REFUSAL, KVCache, cache_nbytes,
                                    cache_spec_of, init_cache_spec,
@@ -456,33 +457,42 @@ def _transform_rows(lg, temps, top_ks, top_ps):
     """A slot's temperature / top-k / top-p transform of its logits:
     ``(t, greedy)``, ``t`` ``[B, V]`` float32 with ``-inf`` at the
     masked tokens (softmax of it is the distribution a sampled slot
-    draws from), ``greedy`` ``[B]`` bool."""
-    lg = lg.astype(jnp.float32)                      # [B, V]
+    draws from), ``greedy`` ``[B]`` bool. Nothing here sorts a row
+    unless a sampled row asks for a nucleus."""
     v = lg.shape[-1]
     greedy = temps <= 0.0
-    t = lg / jnp.maximum(temps, 1e-6)[:, None]
-    # top-k: per-row threshold from the sorted copy (k=0 -> all;
-    # greedy rows keep all, their argmax ignores masking anyway)
-    k = jnp.where(greedy | (top_ks <= 0), v, top_ks)
-    sd = -jnp.sort(-t, axis=-1)
-    kth = jnp.take_along_axis(
-        sd, jnp.clip(k - 1, 0, v - 1)[:, None], axis=-1)
-    t = jnp.where(t < kth, -jnp.inf, t)
-    # top-p (nucleus) on the post-top-k distribution: keep the
-    # smallest sorted prefix whose mass reaches p (first always)
-    p = jnp.where(greedy, 1.0, top_ps)[:, None]
-    sd = -jnp.sort(-t, axis=-1)
-    probs = jax.nn.softmax(sd, axis=-1)
-    # p >= 1.0 keeps ALL tokens (matching _sample_host's
-    # `top_p < 1.0` gate): without it, f32 cumsum rounding can
-    # push the pre-token mass to 1.0 and mask real tail tokens
-    # on temperature-only requests
-    keep = ((jnp.cumsum(probs, axis=-1) - probs) < p) | (p >= 1.0)
-    # the top token survives even top_p=0.0 (OpenAI clients send
-    # it to mean greedy; all-False keep would mask every token)
-    keep = keep | (jnp.arange(v)[None, :] == 0)
-    cutoff = jnp.min(jnp.where(keep, sd, jnp.inf), axis=-1)
-    return jnp.where(t < cutoff[:, None], -jnp.inf, t), greedy
+    scale = jnp.maximum(temps, 1e-6)[:, None]
+    t = lg.astype(jnp.float32) / scale                  # [B, V]
+    # top-k: per-row threshold, the k-th largest with ties kept, by
+    # counts on the logits' own bits (the division is monotone, so it
+    # maps the k-th logit to the k-th of `t`); k=0 -> all, and greedy
+    # rows keep all, their argmax ignores masking anyway
+    kth = kth_largest(lg, top_ks).astype(jnp.float32)[:, None] / scale
+    keep_all = greedy | (top_ks <= 0) | (top_ks >= v)
+    t = jnp.where(t < jnp.where(keep_all[:, None], -jnp.inf, kth),
+                  -jnp.inf, t)
+
+    def nucleus(t):
+        # top-p on the post-top-k distribution: keep the smallest
+        # sorted prefix whose mass reaches p (first always)
+        p = jnp.where(greedy, 1.0, top_ps)[:, None]
+        sd = -jnp.sort(-t, axis=-1)
+        probs = jax.nn.softmax(sd, axis=-1)
+        # p >= 1.0 keeps ALL tokens (matching _sample_host's
+        # `top_p < 1.0` gate): without it, f32 cumsum rounding can
+        # push the pre-token mass to 1.0 and mask real tail tokens
+        # on temperature-only requests
+        keep = ((jnp.cumsum(probs, axis=-1) - probs) < p) | (p >= 1.0)
+        # the top token survives even top_p=0.0 (OpenAI clients send
+        # it to mean greedy; all-False keep would mask every token)
+        keep = keep | (jnp.arange(v)[None, :] == 0)
+        cutoff = jnp.min(jnp.where(keep, sd, jnp.inf), axis=-1)
+        return jnp.where(t < cutoff[:, None], -jnp.inf, t)
+
+    # the one sort left, run only while a sampled row asks for a
+    # nucleus (a slot nobody holds is packed greedy at top_p 1.0)
+    return jax.lax.cond(jnp.any(~greedy & (top_ps < 1.0)), nucleus,
+                        lambda t: t, t), greedy
 
 
 @jax.named_scope("sampler")
@@ -840,8 +850,14 @@ class LLMEngine:
                 chosen = jnp.take_along_axis(
                     lp, toks[:, None].astype(jnp.int32), axis=-1)[:, 0]
                 entropy = -jnp.sum(jnp.exp(lp) * lp, axis=-1)
-                top2, _ = jax.lax.top_k(lg.astype(jnp.float32), 2)
-                margin = top2[:, 0] - top2[:, 1]
+                # the two largest by two maxima (`lax.top_k` is a sort
+                # of the whole row); a tie at the top gives margin 0
+                first = jnp.argmax(lg, axis=-1)      # the greedy token
+                second = jnp.max(jnp.where(
+                    jnp.arange(lg.shape[-1])[None, :] == first[:, None],
+                    -jnp.inf, lg), axis=-1)
+                margin = (jnp.max(lg, axis=-1).astype(jnp.float32)
+                          - second.astype(jnp.float32))
                 cols += [jax.lax.bitcast_convert_type(q, jnp.int32)
                          for q in (chosen, entropy, margin)]
             live = tokens >= 0
@@ -1189,6 +1205,16 @@ class LLMEngine:
             "waits for it.", labelnames=("sent",))
         for st in ("ahead", "in_step"):     # render from scrape 1
             self._m_decode_steps.labels(st)
+        self._m_sampler_steps = m.counter(
+            "bigdl_tpu_sampler_steps_total",
+            "Decode programs dispatched, by what their sampler does with "
+            "a vocabulary-wide row: path=greedy every slot takes the "
+            "argmax, path=topk some slot samples and none asks for a "
+            "nucleus (thresholds by counts, no row is sorted), "
+            "path=nucleus a sampled slot has top_p < 1 (the rows are "
+            "sorted once).", labelnames=("path",))
+        for pt in ("greedy", "topk", "nucleus"):    # render from scrape 1
+            self._m_sampler_steps.labels(pt)
         self._m_vain_steps = m.counter(
             "bigdl_tpu_decode_steps_vain_total",
             "Decode programs sent ahead of which no slot was read: every "
@@ -1838,8 +1864,8 @@ class LLMEngine:
         self._mtp_q = jnp.zeros((b, int(cfg.vocab_size)), jnp.float32)
 
         def transform(lg, temps, top_ks, top_ps):
-            """`_transform_rows`, its two sorts skipped while no slot
-            asks for a top-k or a top-p."""
+            """`_transform_rows`, its counts and its nucleus branch
+            skipped while no slot asks for a top-k or a top-p."""
             plain = jnp.all((top_ks <= 0) & (top_ps >= 1.0))
             return jax.lax.cond(
                 plain,
@@ -2064,10 +2090,21 @@ class LLMEngine:
             return "plain"
         return "verify" if self.speculative_allowed else None
 
-    def _sent_decode(self, sent: str) -> None:
+    def _sampler_path(self, active) -> str:
+        """What the device sampler does for the slots `active`, by the
+        predicate it evaluates itself (`_transform_rows`): "greedy",
+        "topk" (a slot samples, no row is sorted) or "nucleus"."""
+        sampled = [p for p in (self.slots[i].req.params for i in active)
+                   if p.temperature > 0.0]
+        if not sampled:
+            return "greedy"
+        return "nucleus" if any(p.top_p < 1.0 for p in sampled) else "topk"
+
+    def _sent_decode(self, sent: str, path: str) -> None:
         """A decode program went out: `sent` "ahead" of the step that
-        reads it, or "in_step"."""
+        reads it, or "in_step"; `path` its `_sampler_path`."""
         self._m_decode_steps.labels(sent).inc()
+        self._m_sampler_steps.labels(path).inc()
         self._chunk_unanswered = False
 
     def _step_args(self, active):
@@ -2099,6 +2136,7 @@ class LLMEngine:
                    ahead: bool = False):
         """Dispatch one packed step over the slots `active`: the block
         the host will fetch and the `ints` after it."""
+        path = self._sampler_path(active)
         if verify:
             (out, ints, self._mtp_draft, self._mtp_q,
              self.cache) = self._decode_resident_mtp(
@@ -2107,11 +2145,9 @@ class LLMEngine:
         else:
             out, ints, self.cache = self._decode_resident(
                 self.params, ints, floats, self.cache,
-                all_greedy=all(
-                    self.slots[i].req.params.temperature <= 0.0
-                    for i in active),
+                all_greedy=path == "greedy",
                 with_quality=self._use_quality)
-        self._sent_decode("ahead" if ahead else "in_step")
+        self._sent_decode("ahead" if ahead else "in_step", path)
         return out, ints
 
     @property
@@ -4798,7 +4834,7 @@ class LLMEngine:
 
             if not (verify or resident):
                 # another decode program, sent and waited for here
-                self._sent_decode("in_step")
+                self._sent_decode("in_step", self._sampler_path(active))
             if verify or resident:
                 if ahead is not None:
                     holders, out_dev, ints_dev, floats_dev = ahead

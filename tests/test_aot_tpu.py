@@ -56,7 +56,7 @@ def _sds(tree, dev):
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), tree)
 
 
-def _lower_resident(eng, dev):
+def _lower_resident(eng, dev, all_greedy=True, with_quality=False):
     """`engine_decode_resident` lowered at the engine's own geometry on
     its packed arguments: `ints` `[4, B]`, `floats` `[2, B]`."""
     b = eng.cfg_engine.max_batch
@@ -65,7 +65,7 @@ def _lower_resident(eng, dev):
         _sds(jax.ShapeDtypeStruct((4, b), jnp.int32), dev),
         _sds(jax.ShapeDtypeStruct((2, b), jnp.float32), dev),
         _sds(jax.eval_shape(lambda: eng.cache), dev),
-        all_greedy=True, with_quality=False)
+        all_greedy=all_greedy, with_quality=with_quality)
 
 
 def _two_in_flight_bytes(ma) -> int:
@@ -769,6 +769,65 @@ def test_engine_decode_resident_step_compiles(v5e, aot_flags, b):
         assert op != "copy" and not fed, (
             f"{name} ({op}) writes a layer-sized operand {fed} into the "
             f"stack")
+
+
+def _sorts_by_place(txt):
+    """`(outside, inside)`: the `sort(` instructions of a compiled
+    program's text that run whenever the program does, and those that
+    lie in a branch of a `conditional` (or in a computation only a
+    branch calls)."""
+    import re
+
+    comps, name, entry = {}, None, None
+    for line in txt.splitlines():
+        m = re.match(r"(ENTRY )?(%[\w.\-]+) \(.*\{\s*$", line)
+        if m:
+            name = m.group(2)
+            comps[name] = []
+            entry = name if m.group(1) else entry
+        elif name is not None:
+            comps[name].append(line)
+    called = (r"\b(%s)=(\{[^}]*\}|%%[\w.\-]+)" % "|".join((
+        "to_apply", "calls", "body", "condition", "branch_computations",
+        "true_computation", "false_computation")))
+    branches = ("branch_computations", "true_computation",
+                "false_computation")
+    always, todo = set(), [entry]
+    while todo:         # what ENTRY reaches through no conditional's branch
+        c = todo.pop()
+        if c in always:
+            continue
+        always.add(c)
+        for line in comps[c]:
+            for attr, names in re.findall(called, line):
+                if attr not in branches:
+                    todo += re.findall(r"%[\w.\-]+", names)
+
+    def count(cs):
+        return sum(len(re.findall(r"\bsort\(", ln))
+                   for c in cs for ln in comps[c])
+    return count(always), count(set(comps) - always)
+
+
+def test_engine_decode_resident_sampled_step_sorts_no_vocabulary(v5e,
+                                                                  aot_flags):
+    """The resident step with its sampler and its quality columns
+    (`all_greedy=False, with_quality=True`) for the same Mistral-7B:
+    the one `sort` of the program is the nucleus branch's, inside the
+    sampler's `conditional`, and nothing that runs every step sorts a
+    `[B, 32000]` row. In this program `jnp.sort` and `lax.top_k(lg, 2)`
+    were both compiled to a full stable key-value sort (0.09 s of a 3 s
+    trace each, three a step, before PR 51): this is the guard against
+    one creeping back."""
+    dev = v5e.devices[0]
+    eng = _mistral7b_engine(8, 2048)
+    txt = _lower_resident(eng, dev, all_greedy=False,
+                          with_quality=True).compile().as_text()
+    assert "tpu_custom_call" in txt
+    assert txt.count(" conditional(") == 1
+    outside, inside = _sorts_by_place(txt)
+    assert (outside, inside) == (0, 1), (
+        f"{outside} sort(s) run every step, {inside} under a conditional")
 
 
 def test_engine_prefill_chunk_dequantizes_in_vmem(v5e, aot_flags):

@@ -522,3 +522,83 @@ def test_the_counter_counts_ahead_in_step_and_vain_steps(model, kind,
     sent = _sent(eng)
     assert sent["ahead"] + sent["in_step"] == len(calls)
     assert sent["ahead"] >= 10 and sent["in_step"] >= 2
+
+
+SAMPLER_BATCHES = {
+    # path: the sampling parameters of the two requests of a batch
+    "greedy": (GREEDY, GREEDY),
+    "topk": (GREEDY, dataclasses.replace(GREEDY, temperature=0.8, top_k=32,
+                                         seed=3)),
+    "nucleus": (dataclasses.replace(GREEDY, temperature=0.8, top_k=32,
+                                    seed=3),
+                dataclasses.replace(GREEDY, temperature=1.0, top_p=0.9,
+                                    seed=4)),
+}
+
+
+def _sampler_steps(eng):
+    return {pt: _counter(eng,
+                         'bigdl_tpu_sampler_steps_total{path="%s"}' % pt)
+            for pt in ("greedy", "topk", "nucleus")}
+
+
+@pytest.mark.parametrize("path", sorted(SAMPLER_BATCHES))
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_sampler_counter_names_the_path_of_every_decode_program(
+        model, kind, path):
+    """`bigdl_tpu_sampler_steps_total{path}` is bumped once a decode
+    program, beside `bigdl_tpu_decode_steps_total`, by the predicate the
+    device evaluates: all three values render before traffic, and a
+    batch counts under its own path alone while both requests run."""
+    eng = _engine(model, kind, max_batch=2)
+    assert _sampler_steps(eng) == {"greedy": 0, "topk": 0, "nucleus": 0}
+    prompts = _prompts(2, seed=71, lo=20, hi=30)
+    got = {}
+    for i, (p, sp) in enumerate(zip(prompts, SAMPLER_BATCHES[path])):
+        eng.add_request(f"s{i}", p, sp)
+        got[f"s{i}"] = []
+    _drive(eng, got)
+    assert all(len(t) == GREEDY.max_tokens for t in got.values())
+    steps, sent = _sampler_steps(eng), _sent(eng)
+    assert sum(steps.values()) == sent["ahead"] + sent["in_step"]
+    # the first request decodes alone while the second is admitted, and
+    # a verify step may end the two at different steps: those programs
+    # count under the path of whoever is live (greedy for s0 of the
+    # greedy and topk batches, topk for s0 of the nucleus batch)
+    assert steps[path] >= 10
+    alone = {"greedy": "greedy", "topk": "greedy", "nucleus": "topk"}[path]
+    assert {pt for pt, n in steps.items() if n} <= {path, alone}, steps
+
+
+def test_sampler_sortfree_share_reduces_a_scrape_pair_to_the_share():
+    """`layer_metrics/sampler_sortfree_share.json`: decode programs that
+    sampled and sorted nothing over all decode programs of the window;
+    nothing on a program without the counter (the parent of PR 51)."""
+    from harness import layer_metrics, promtext
+
+    path = ROOT / "benchmark" / "layer_metrics" / "sampler_sortfree_share.json"
+    series = "bigdl_tpu_sampler_steps_total"
+    start = "\n".join(f'{series}{{path="{p}"}} {n}' for p, n in
+                      (("greedy", 10), ("topk", 5), ("nucleus", 0)))
+    end = "\n".join(f'{series}{{path="{p}"}} {n}' for p, n in
+                    (("greedy", 30), ("topk", 185), ("nucleus", 0)))
+    obs = {"counters_start": promtext.parse(start),
+           "counters_end": promtext.parse(end)}
+    assert layer_metrics.read_metric(path, obs) == pytest.approx(90.0)
+    assert promtext.delta(obs["counters_start"], obs["counters_end"],
+                          series, {"path": "nucleus"}) == 0
+    other = "bigdl_tpu_decode_steps_total"
+    old = {"counters_start": promtext.parse(f'{other}{{sent="ahead"}} 10'),
+           "counters_end": promtext.parse(f'{other}{{sent="ahead"}} 40')}
+    assert layer_metrics.read_metric(path, old) is None
+    doc = json.loads((ROOT / "BENCHMARK.json").read_text())
+    (entry,) = [m for m in doc["per_layer"]
+                if m["name"] == "sampler_sortfree_share"]
+    file = json.loads(path.read_text())
+    assert {k: entry[k] for k in ("unit", "layer", "moves", "source")} == {
+        k: file[k] for k in ("unit", "layer", "moves", "source")}
+    assert entry["better"] == "higher" and len(entry["workloads"]) == 8
+    greedy_cells = {"mistral7b-batch-closed", "chatglm2-6b-docqa-shared",
+                    "mistral7b-qlora-alpaca"}
+    assert not greedy_cells & set(entry["workloads"])
+    assert set(entry["workloads"]) <= {w["name"] for w in doc["workloads"]}

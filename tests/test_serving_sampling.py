@@ -335,3 +335,143 @@ def test_top_p_zero_is_greedy(model):
     z, _ = run_one(eng, "z", [2, 4, 6], SamplingParams(
         max_tokens=10, temperature=1.0, top_p=0.0))
     assert z[0] == g[0]
+
+
+# -- the sampler sorts no vocabulary (PR 51) -------------------------------
+
+def _two_sort_transform(lg, temps, top_ks, top_ps):
+    """`engine._transform_rows` as it stood before PR 51, the plain
+    reference: the k-th entry of a sorted copy, then the nucleus prefix
+    of a second sorted copy, for every row."""
+    import jax
+    import jax.numpy as jnp
+
+    lg = lg.astype(jnp.float32)
+    v = lg.shape[-1]
+    greedy = temps <= 0.0
+    t = lg / jnp.maximum(temps, 1e-6)[:, None]
+    k = jnp.where(greedy | (top_ks <= 0), v, top_ks)
+    sd = -jnp.sort(-t, axis=-1)
+    kth = jnp.take_along_axis(
+        sd, jnp.clip(k - 1, 0, v - 1)[:, None], axis=-1)
+    t = jnp.where(t < kth, -jnp.inf, t)
+    p = jnp.where(greedy, 1.0, top_ps)[:, None]
+    sd = -jnp.sort(-t, axis=-1)
+    probs = jax.nn.softmax(sd, axis=-1)
+    keep = ((jnp.cumsum(probs, axis=-1) - probs) < p) | (p >= 1.0)
+    keep = keep | (jnp.arange(v)[None, :] == 0)
+    cutoff = jnp.min(jnp.where(keep, sd, jnp.inf), axis=-1)
+    return jnp.where(t < cutoff[:, None], -jnp.inf, t), greedy
+
+
+def _tied_logits(v, rows, dtype, seed):
+    """`[rows, v]` logits in `dtype` whose 21st to 36th largest of every
+    row are one value: ties that straddle rank 32 and lie inside the top
+    V - 1."""
+    import jax.numpy as jnp
+
+    x = np.random.default_rng(seed).standard_normal(
+        (rows, v)).astype(np.float32) * 3.0
+    top = np.argsort(-x, axis=-1)
+    for r in range(rows):
+        x[r, top[r, 20:36]] = x[r, top[r, 20]]
+    return jnp.asarray(x).astype(dtype)
+
+
+@pytest.mark.parametrize("top_p", [1.0, 0.9, 0.0])
+@pytest.mark.parametrize("v", [320, 32000, 50048])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_transform_rows_is_the_two_sort_reference_bit_for_bit(dtype, v,
+                                                               top_p):
+    """Greedy and sampled rows in one batch, every `top_k` of {0, 1, 2,
+    32, V - 1, V, > V}: the masked set and every kept value are the
+    sorted reference's, ties at the threshold kept."""
+    import jax
+    import jax.numpy as jnp
+    from bigdl_tpu.serving.engine import _transform_rows
+
+    ks = [0, 1, 2, 32, v - 1, v, v + 7, 32, 2]
+    lg = _tied_logits(v, len(ks), dtype, seed=v)
+    temps = jnp.asarray([0.8, 0.05, 1.0, 0.8, 0.7, 1.3, 0.8, 0.0, 0.0],
+                        jnp.float32)
+    args = (lg, temps, jnp.asarray(ks, jnp.int32),
+            jnp.full((len(ks),), top_p, jnp.float32))
+    got, greedy = jax.jit(_transform_rows)(*args)
+    want, greedy_ref = jax.jit(_two_sort_transform)(*args)
+    assert got.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(greedy), np.asarray(greedy_ref))
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(want).view(np.uint32))
+    if top_p >= 1.0:    # rank 32 falls among the ties: all 16 are kept
+        assert int(np.isfinite(np.asarray(got)[3]).sum()) == 36
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_kth_largest_is_the_sorted_rows_entry_with_multiplicity(dtype):
+    import jax.numpy as jnp
+    from bigdl_tpu.ops.dsa import kth_largest
+
+    v = 1000
+    lg = _tied_logits(v, 8, dtype, seed=5)
+    lg = lg.at[0, :3].set(jnp.asarray([-jnp.inf, jnp.inf, -0.0], lg.dtype))
+    ks = np.asarray([1, 2, 21, 32, 36, 37, v, v + 5], np.int32)
+    got = np.asarray(kth_largest(lg, jnp.asarray(ks)).astype(jnp.float32))
+    sd = -np.sort(-np.asarray(lg.astype(jnp.float32)), axis=-1)
+    want = sd[np.arange(8), np.clip(ks, 1, v) - 1]
+    np.testing.assert_array_equal(got, want)
+    # a scalar k (the DSA caller's) is every row's
+    np.testing.assert_array_equal(
+        np.asarray(kth_largest(lg, 32).astype(jnp.float32)), sd[:, 31])
+
+
+def test_select_topk_mask_goes_through_the_shared_search(monkeypatch):
+    """`ops/dsa.select_topk_mask` takes its k-th key from `_kth_key`, the
+    search `kth_largest` returns the value of, and keeps `lax.top_k`'s
+    selection (ties to the lower position)."""
+    import jax
+    import jax.numpy as jnp
+    from bigdl_tpu.ops import dsa
+
+    calls = []
+    inner = dsa._kth_key
+    monkeypatch.setattr(dsa, "_kth_key",
+                        lambda x, k: calls.append(k) or inner(x, k))
+    sc = np.array(_tied_logits(512, 4, "float32", seed=9))
+    sc[1, 100:] = -np.inf                       # 100 candidates
+    sc[2, 5:] = -np.inf                         # fewer than k
+    k = 32
+    got = np.asarray(dsa.select_topk_mask(jnp.asarray(sc), k))
+    assert calls == [k]
+    _, idx = jax.lax.top_k(jnp.asarray(sc), k)
+    want = np.zeros_like(got)
+    np.put_along_axis(want, np.asarray(idx), True, axis=-1)
+    want &= sc > -np.inf
+    np.testing.assert_array_equal(got, want)
+
+
+# what the engine emitted for these requests before PR 51 (commit
+# ec2ae14, the two-sort sampler): the masked sets and the gumbel draws
+# are unchanged, so a seeded stream replays token for token
+_PARENT_STREAMS = {
+    "topk": (dict(temperature=0.8, top_k=32, seed=11),
+             [116, 105, 22, 85, 36, 26, 29, 146, 196, 34, 62, 35, 241, 99,
+              241, 97, 186, 213, 128, 12, 232, 217, 233, 193]),
+    "topk_p": (dict(temperature=0.8, top_k=32, top_p=0.9, seed=11),
+               [116, 145, 22, 85, 36, 26, 137, 236, 77, 64, 101, 39, 223,
+                164, 58, 10, 161, 17, 10, 119, 57, 16, 251, 75]),
+    "temp": (dict(temperature=1.3, seed=5),
+             [255, 122, 80, 74, 15, 204, 183, 166, 92, 30, 189, 59, 76, 202,
+              160, 115, 142, 180, 153, 196, 65, 73, 215, 60]),
+    "nucleus": (dict(temperature=1.0, top_p=0.7, seed=3),
+                [134, 212, 196, 42, 20, 238, 76, 44, 83, 121, 232, 169, 182,
+                 81, 143, 123, 170, 9, 204, 181, 0, 26, 228, 130]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_PARENT_STREAMS))
+def test_seeded_sampled_stream_replays_the_parents_tokens(model, name):
+    kw, want = _PARENT_STREAMS[name]
+    eng = LLMEngine(model, EngineConfig(max_batch=2, max_seq=128))
+    got, _ = run_one(eng, name, [5, 6, 7, 11],
+                     SamplingParams(max_tokens=24, **kw))
+    assert got[0] == want
