@@ -13,6 +13,13 @@ bounded drain after the window), repeats the probe request, writes the
 records (with every request's streamed token ids) to ``--out`` and
 prints ``{"event": "done"}``.
 
+A record may say which step of the program committed each token: where
+a stream event's ``choices[0]`` holds ``steps`` (integers, one a token
+of that event) the record keeps them in ``steps``, parallel to
+``tokens``; ``steps`` is None for a request none of whose events had
+the key, and a request where only some had it has failed. What a number
+means is the configuration's ``generation`` module's business.
+
 Clock: ``time.monotonic()``, which on Linux is one clock for every
 process of the machine.
 """
@@ -46,8 +53,9 @@ def send_request(port: int, req: Dict[str, Any], deadline: float,
         "expected": req["max_tokens"], "received": 0, "ok": False,
         "error": None, "prompt_tokens": len(req["prompt"]),
         "tokens": [], "greedy": req["temperature"] == 0.0,
-        "request": req.get("index"),
+        "request": req.get("index"), "steps": None,
     }
+    steps: List[int] = []
     body = json.dumps({
         "prompt": req["prompt"], "max_tokens": req["max_tokens"],
         "temperature": req["temperature"], "top_k": req["top_k"],
@@ -89,8 +97,16 @@ def send_request(port: int, req: Dict[str, Any], deadline: float,
             if "choices" not in obj:
                 rec["error"] = f"unexpected event {sorted(obj)}"
                 return rec
-            text = obj["choices"][0].get("text") or ""
-            ids = text.split()
+            choice = obj["choices"][0]
+            ids = (choice.get("text") or "").split()
+            said = choice.get("steps")
+            if said is not None:
+                if not (isinstance(said, list) and len(said) == len(ids)
+                        and all(type(x) is int for x in said)):
+                    rec["error"] = (f"steps {said!r} beside {len(ids)} "
+                                    "tokens: one integer a token")
+                    return rec
+                steps.extend(said)
             if ids:
                 if rec["first"] is None:
                     rec["first"] = now
@@ -102,8 +118,13 @@ def send_request(port: int, req: Dict[str, Any], deadline: float,
         elif rec["received"] != rec["expected"]:
             rec["error"] = (f"{rec['received']} tokens, "
                             f"{rec['expected']} asked")
+        elif steps and len(steps) != rec["received"]:
+            rec["error"] = (f"steps beside {len(steps)} of "
+                            f"{rec['received']} tokens: on every event "
+                            "or on none")
         else:
             rec["ok"] = True
+            rec["steps"] = steps or None
     except (OSError, http.client.HTTPException, ValueError) as e:
         rec["error"] = f"{type(e).__name__}: {e}"
     finally:
@@ -181,7 +202,7 @@ def run_closed(port: int, clients: List[List[Dict[str, Any]]], t0: float,
                 "chunks": [], "expected": 0, "received": 0, "ok": False,
                 "error": "client ran out of requests inside the window",
                 "prompt_tokens": 0, "client": c, "tokens": [],
-                "greedy": False, "request": None})
+                "greedy": False, "request": None, "steps": None})
 
     threads = [threading.Thread(target=client, args=(c,))
                for c in range(len(clients))]

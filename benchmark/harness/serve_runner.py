@@ -9,23 +9,33 @@ from the child's records, on the client side; per-layer numbers from the
 server's ``/metrics`` text at the window's edges and from a profiler
 trace of a short stretch inside the window (``--trace 1`` only).
 
-The configuration's ``reference``, ``weights`` and ``costs`` modules
-come from the cell (``cell.modules``); none is imported here by name.
-``correct`` rests on the reference twice, both once the window has
-closed and the peak is read, on weights made anew from the seed after
-the program's state is freed. (1) The program's logits of one seeded
-sequence: the family's prefill into a new cache of the cell's kind,
-type and length, then ``DECODE_POSITIONS`` forced tokens one at a time
-through that cache; a relative L2 against the reference's one full
-forward pass, the prefill's position and the decoded ones apart. It is
-what sees a cache of lower precision or an accumulation in bfloat16.
-(2) The tokens the ENGINE streamed for a sample of the window's own
-requests (``served.py``): its prefill programs, its decode step and
-its cache at the timed batch. It is what sees a wrong row, page,
-position or token of the timed path. The first is a forward pass of
-the check's own and costs no set-up; the engine's own logits cannot be
-had without leaving the resident decode step (a request with
-``logprobs`` falls back to host sampling).
+The configuration's ``reference``, ``weights``, ``costs`` and
+``generation`` modules come from the cell (``cell.modules``); none is
+imported here by name. ``correct`` rests on the reference twice, both
+once the window has closed and the peak is read, on weights made anew
+from the seed after the program's state is freed, and both through the
+``generation`` module, which knows what one step of the family yields.
+(1) The program's logits of one seeded sequence through a new cache of
+the cell's kind, type and length (``program_rows``), a relative L2
+name by name against the reference's one full forward pass
+(``reference_rows``): the same names and shapes on both sides, and a
+row of the prefill and one for every id that goes through the cache
+after it at the least (``rows_fault``). It is what sees a cache of
+lower precision or an accumulation in bfloat16. (2) The tokens the ENGINE streamed for a
+sample of the window's own requests (``served.py``, ``served_gaps``):
+its prefill programs, its decode step and its cache at the timed
+batch. It is what sees a wrong row, page, position or token of the
+timed path. The first is a forward pass of the check's own and costs
+no set-up; the engine's own logits cannot be had without leaving the
+resident decode step (a request with ``logprobs`` falls back to host
+sampling).
+
+The helpers below (``prefill_into_cache``, ``decode_through_cache``,
+``reference_logits``) are what the default module,
+``harness/generation.py``, is made of: the family's prefill of
+``REF_PROMPT_TOKENS``, then ``DECODE_POSITIONS`` forced tokens one at a
+time through that cache, the prefill's position and the decoded ones
+apart.
 """
 
 from __future__ import annotations
@@ -38,7 +48,7 @@ import sys
 import threading
 import time
 from pathlib import Path
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 from harness import common, promtext, served, stats
 from harness import traffic as traffic_mod
@@ -50,13 +60,35 @@ BROWNOUT = "bigdl_tpu_brownout_level"
 LOADGEN = Path(__file__).resolve().parent / "loadgen.py"
 
 
-def check_ids(seed: int, vocab: int):
+def check_ids(seed: int, vocab: int,
+              n: int = REF_PROMPT_TOKENS + DECODE_POSITIONS):
     """The seeded sequence of the logits comparison: a prompt of
-    ``REF_PROMPT_TOKENS`` and ``DECODE_POSITIONS`` forced tokens."""
+    ``REF_PROMPT_TOKENS`` and ``DECODE_POSITIONS`` forced tokens, or the
+    ``n`` ids of a generation module that states its own lengths
+    (``sequence_of``)."""
     import numpy as np
 
     return np.random.default_rng([seed & 0xFFFFFFFF, 13]).integers(
-        1, vocab, REF_PROMPT_TOKENS + DECODE_POSITIONS)
+        1, vocab, n)
+
+
+def sequence_of(generation) -> Tuple[int, int]:
+    """How many ids of the seeded sequence are the prompt and how many
+    then go through the cache: the module's ``SEQUENCE = (prompt,
+    later)`` where it has one (a family that prefills whole blocks and
+    then steps a whole block needs a multiple of its block), else
+    ``REF_PROMPT_TOKENS`` and ``DECODE_POSITIONS``. A module may
+    lengthen either part and shorten neither."""
+    prompt, later = getattr(generation, "SEQUENCE",
+                            (REF_PROMPT_TOKENS, DECODE_POSITIONS))
+    if not (type(prompt) is int and type(later) is int
+            and prompt >= REF_PROMPT_TOKENS and later >= DECODE_POSITIONS):
+        raise ValueError(
+            f"{getattr(generation, '__name__', generation)}.SEQUENCE = "
+            f"{(prompt, later)!r}: two "
+            f"whole numbers, at least {REF_PROMPT_TOKENS} ids of prompt "
+            f"and {DECODE_POSITIONS} after it")
+    return prompt, later
 
 
 def prefill_into_cache(model, eng_cfg: Dict[str, Any], prompt, seed: int):
@@ -103,18 +135,69 @@ def decode_through_cache(state, forced):
     return np.stack(rows)
 
 
+def reference_logits(reference, canonical, arch, quant, ids,
+                     n_prompt: int) -> Dict[str, Any]:
+    """The reference's ONE full forward pass over ``ids``, as the rows
+    ``prefill_into_cache`` over the first ``n_prompt`` and
+    ``decode_through_cache`` over the rest give: the prompt's last
+    position, and every position after it."""
+    import numpy as np
+
+    ref = np.asarray(reference.all_logits(
+        canonical, arch, quant, [int(x) for x in ids], first=n_prompt - 1))
+    return {"prefill": ref[0], "decode": ref[1:]}
+
+
+def _shapes(rows: Dict[str, Any]) -> Dict[str, Tuple[int, ...]]:
+    import numpy as np
+
+    return {k: tuple(np.shape(v)) for k, v in rows.items()}
+
+
+def rows_errors(program: Dict[str, Any], reference: Dict[str, Any]
+                ) -> Dict[str, Optional[float]]:
+    """Relative L2 of the program's rows from the reference's, name by
+    name; None under a name that only one side has, or whose rows the
+    two sides shape differently."""
+    ps, rs = _shapes(program), _shapes(reference)
+    names = list(program) + [k for k in reference if k not in program]
+    return {k: (common.relative_l2(program[k], reference[k])
+                if k in ps and ps[k] == rs.get(k) else None)
+            for k in names}
+
+
+def rows_fault(program: Dict[str, Any], reference: Dict[str, Any],
+               owed: int) -> Optional[str]:
+    """What keeps the two sides' rows from being the comparison the
+    contract asks for, in one line; None where nothing does. The names
+    and each name's shape are the same on both sides, and the rows of
+    all names together (a name's first dimension, 1 for a single row)
+    are ``owed`` or more: one of the prefill and one for every id that
+    goes through the cache after it, so that a module cannot be
+    ``correct`` on an easy row alone."""
+    ps, rs = _shapes(program), _shapes(reference)
+    if set(ps) != set(rs):
+        return (f"generation rows named apart: program_rows {sorted(ps)}, "
+                f"reference_rows {sorted(rs)}")
+    apart = {k: (ps[k], rs[k]) for k in ps if ps[k] != rs[k]}
+    if apart:
+        return f"generation rows shaped apart (program, reference): {apart}"
+    rows = sum(shape[0] if len(shape) > 1 else 1 for shape in ps.values())
+    if rows < owed:
+        return (f"generation rows compared: {rows} in {ps}, {owed} owed "
+                f"(one of the prefill, one for every id after the prompt)")
+    return None
+
+
 def logits_errors(reference, canonical, arch, quant, ids, prefill_row,
                   decode_rows) -> Dict[str, float]:
     """Relative L2 of the program's logits from the reference's ONE full
     forward pass over ``ids``: the prefill's position, and the decoded
     positions together."""
-    import numpy as np
-
-    n_prompt = len(ids) - len(decode_rows)
-    ref = np.asarray(reference.all_logits(
-        canonical, arch, quant, [int(x) for x in ids], first=n_prompt - 1))
-    return {"prefill": common.relative_l2(prefill_row, ref[0]),
-            "decode": common.relative_l2(decode_rows, ref[1:])}
+    return rows_errors(
+        {"prefill": prefill_row, "decode": decode_rows},
+        reference_logits(reference, canonical, arch, quant, ids,
+                         len(ids) - len(decode_rows)))
 
 
 def _gauge(registry, name: str) -> float:
@@ -243,21 +326,21 @@ def _sweep(engine, port: int, traffic, rates, seed: int, seconds: float,
 def _reference_checks(cell, model, records, seed: int, seconds: float,
                       dims, clock: common.WallClock) -> Dict[str, Any]:
     """Both comparisons with the reference, once the window has closed
-    and the peak is read: the program's logits of one seeded sequence,
-    prefill and then decode through a cache of the cell's kind; then
-    the program's state goes and the reference gets the device to
-    itself, on weights made anew from the seed ONCE, for that sequence,
-    for the configuration's own layer check where it has one, and for
-    what the window served. ``clock`` is charged each part."""
+    and the peak is read, each through the configuration's
+    ``generation`` module: the program's logits of one seeded sequence
+    through a cache of the cell's kind; then the program's state goes
+    and the reference gets the device to itself, on weights made anew
+    from the seed ONCE, for that sequence, for the configuration's own
+    layer check where it has one, and for what the window served.
+    ``clock`` is charged each part."""
     t_check = time.monotonic()
     config, traffic = cell.config, cell.traffic
     reference, weights = cell.modules["reference"], cell.modules["weights"]
+    generation = cell.modules["generation"]
     kv_dtype = config["engine"].get("kv_cache_dtype", "bf16")
-    ids = check_ids(seed, dims.vocab_size)
-    prefill_row, state = prefill_into_cache(
-        model, config["engine"], ids[:REF_PROMPT_TOKENS], seed)
-    decode_rows = decode_through_cache(state, ids[REF_PROMPT_TOKENS:])
-    del state
+    n_prompt, n_later = sequence_of(generation)
+    ids = check_ids(seed, dims.vocab_size, n_prompt + n_later)
+    program = generation.program_rows(model, config["engine"], ids, seed)
     common.free_device()
     clock.lap("check_a_program")
     quant = {"qtype": config["quant"], "block": config["quant_block"]}
@@ -267,21 +350,30 @@ def _reference_checks(cell, model, records, seed: int, seconds: float,
     # leaves its seconds and its compared numbers on the tree
     own = canonical.get("layer_check") or {}
     clock.move(own.get("seconds", 0.0), "canonical_tree", "layer_check")
-    rel = logits_errors(reference, canonical, config["reference"], quant,
-                        ids, prefill_row, decode_rows)
+    expected = generation.reference_rows(
+        reference, canonical, config["reference"], quant, ids)
+    rel = rows_errors(program, expected)
     clock.lap("check_a_reference")
     plan = traffic_mod.all_requests(traffic_mod.window_plan(
         traffic, seed, seconds, dims.vocab_size))
+    # ``steps``: which step committed each token, where the program's
+    # stream said so (None where it did not); what a number means is
+    # the generation module's and its family's business
     samples = [{"prompt": plan[r["request"]]["prompt"],
-                "tokens": [int(x) for x in r["tokens"]]}
+                "tokens": [int(x) for x in r["tokens"]],
+                "steps": r.get("steps")}
                for r in served.pick_sample(records, seed)]
     found = served.compare(
         reference, canonical, config["reference"], quant, samples,
-        longest=max(len(p["prompt"]) + int(p["max_tokens"]) for p in plan))
+        longest=max(len(p["prompt"]) + int(p["max_tokens"]) for p in plan),
+        gaps=generation.served_gaps)
     del canonical
     common.free_device()
     clock.lap("check_b_served")
     return {"rel": rel, "tolerance": reference.tolerance(config, kv_dtype),
+            # rows that are not the comparison the contract asks for:
+            # the run says why and is not ``correct``
+            "rows_fault": rows_fault(program, expected, n_later + 1),
             "served": found,
             "limits": reference.served_gap_limits(config, kv_dtype),
             "own_compared": list(own.get("compared", [])),
@@ -432,8 +524,9 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
         "no_brownout_no_shed": brownout == 0 and shed == 0,
         "probe_repeats": bool(pb["ok"] and pa["ok"]
                               and pb["tokens"] == pa["tokens"]),
-        "reference_within_tolerance":
-            max(ref["rel"].values()) <= ref["tolerance"],
+        "reference_within_tolerance": ref["rows_fault"] is None and all(
+            v is not None and v <= ref["tolerance"]
+            for v in ref["rel"].values()),
         "served_tokens_within_reference_gap":
             served.within(ref["served"], ref["limits"]),
         "configuration_layer_check": ref["own_within"],
@@ -499,6 +592,8 @@ def run(cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
             for k, v in (stats_json.get("compile_table") or {}).items()
             if v.get("compiles")})
 
+    if ref["rows_fault"]:
+        print(ref["rows_fault"], file=sys.stderr, flush=True)
     result["compared"] = common.report_compared(
         ref["own_compared"]
         + [(f"reference_rel_l2.{k}", v, ref["tolerance"])

@@ -16,7 +16,9 @@ The first served token of a request is the prefill's (through the
 prefix cache where the configuration shares prefixes), every later one
 a decode step's through the cache the cell times, in the batch the
 window happened to hold. Nothing here is architecture: the reference
-module is the cell's.
+module is the cell's, and so is the function that turns one sampled
+request into gaps (the ``generation`` role's ``served_gaps``; what is
+said above is its default, ``next_token_gaps``).
 """
 
 from __future__ import annotations
@@ -91,26 +93,48 @@ def request_gaps(reference, canonical, arch: Dict[str, Any],
     return (lg.max(axis=-1) - chosen) / np.maximum(lg.std(axis=-1), 1e-30)
 
 
+def next_token_gaps(reference, canonical, arch: Dict[str, Any],
+                    quant: Dict[str, Any], sample: Dict[str, Any],
+                    padded: int) -> Dict[str, List[float]]:
+    """``served_gaps`` of a family whose step gives each sequence the
+    tokens that follow its last one: ``request_gaps`` over the sample's
+    ``prompt`` and ``tokens``, the first token's gap (the prefill's)
+    apart from the later ones (decode steps'). ``sample["steps"]`` is
+    not read: the served prefix is all the state such a token came
+    from."""
+    g = request_gaps(reference, canonical, arch, quant, sample["prompt"],
+                     sample["tokens"], padded)
+    return {"first": [float(g[0])], "later": [float(x) for x in g[1:]]}
+
+
 def compare(reference, canonical, arch: Dict[str, Any],
             quant: Dict[str, Any], samples: List[Dict[str, Any]],
-            longest: int = 0) -> Dict[str, Any]:
-    """The numbers that decide, over ``samples`` (each with ``prompt``
-    and ``tokens``): the widest gap of a first token (prefill), the
-    widest and the mean gap of the later ones (decode). ``longest`` is
-    the longest request the traffic can ask for, prompt and answer:
-    the one length comes from it, not from which requests a run
-    happened to finish (a traced run whose longest request ended past
-    the window compiled a second length: my chip run, PR 38)."""
+            longest: int = 0, gaps=None) -> Dict[str, Any]:
+    """The numbers that decide, over ``samples`` (each with ``prompt``,
+    ``tokens`` and, where the program said them, ``steps``): the widest
+    gap of a first token (prefill), the widest and the mean gap of the
+    later ones (decode). ``gaps`` is the configuration's ``served_gaps``
+    (``harness/__init__.py`` has its contract; ``next_token_gaps``
+    where none is given). ``longest`` is the longest request the
+    traffic can ask for, prompt and answer: the one length comes from
+    it, not from which requests a run happened to finish (a traced run
+    whose longest request ended past the window compiled a second
+    length: my chip run, PR 38)."""
+    gaps = gaps or next_token_gaps
     first, later, seconds = [], [], []
     lengths = [len(s["prompt"]) + len(s["tokens"]) for s in samples]
     padded = pad_length(max(lengths + [int(longest), 1]))
     for s in samples:
         t = time.monotonic()
-        g = request_gaps(reference, canonical, arch, quant, s["prompt"],
-                         s["tokens"], padded)
+        g = gaps(reference, canonical, arch, quant, s, padded)
         seconds.append(round(time.monotonic() - t, 3))
-        first.append(float(g[0]))
-        later.extend(float(x) for x in g[1:])
+        if len(g["first"]) + len(g["later"]) != len(s["tokens"]):
+            raise ValueError(
+                f"{gaps.__module__}.{gaps.__name__}: "
+                f"{len(g['first'])} + {len(g['later'])} gaps for "
+                f"{len(s['tokens'])} served tokens: every token has one")
+        first.extend(float(x) for x in g["first"])
+        later.extend(float(x) for x in g["later"])
     out: Dict[str, Any] = {
         "requests": len(samples),
         # what the reference's pass over each request cost, beside its

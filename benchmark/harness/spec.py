@@ -5,10 +5,10 @@ itself. Each resolves to a file of that name under ``benchmark/``. A
 name with no file is an error that says which file is missing, so that a
 later PR adds a cell by adding files and entries only.
 
-A configuration's file names the three modules that know its
-architecture, ``"harness": {"reference": <stem>, "weights": <stem>,
-"costs": <stem>}``: stems of files under ``benchmark/harness/``, each
-defaulting to its role's name. The runners take them from the cell and
+A configuration's file names the four modules that know its
+architecture and how it generates, ``"harness": {"reference": <stem>,
+"weights": <stem>, "costs": <stem>, "generation": <stem>}``: stems of
+files under ``benchmark/harness/``, each defaulting to its role's name. The runners take them from the cell and
 import none by name. ``MODULE_CONTRACT`` below is what each must define;
 ``harness/__init__.py`` says what each function is given and returns.
 """
@@ -34,6 +34,7 @@ MODULE_CONTRACT = {
     "weights": ("build_model", "canonical_params"),
     "costs": ("Dims.from_config", "kv_bytes_per_token", "serving_work",
               "training_work"),
+    "generation": ("program_rows", "reference_rows", "served_gaps"),
 }
 
 
@@ -105,8 +106,8 @@ class Cell:
         self.modules = self._load_modules()
 
     def _load_modules(self) -> Dict[str, Any]:
-        """The configuration's reference, weights and costs modules,
-        loaded from ``benchmark/harness/<stem>.py`` of this tree and
+        """The configuration's reference, weights, costs and generation
+        modules, loaded from ``benchmark/harness/<stem>.py`` of this tree and
         held to ``MODULE_CONTRACT``."""
         named = self.config.get("harness", {})
         unknown = sorted(set(named) - set(MODULE_CONTRACT))

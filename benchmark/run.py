@@ -21,8 +21,9 @@ A cell is files plus entries: ``configs/<config>.json``,
 ``harness/<runner>_runner.py``), one reader per per-layer metric under
 ``layer_metrics/``, and the entries in ``BENCHMARK.json``. A
 configuration of another architecture also brings its own reference,
-weights and costs modules, which its file names under ``"harness"``
-(``harness/__init__.py`` has the contract of each). The last lines of
+weights and costs modules, and one whose step is not one next token a
+sequence its own generation module, which its file names under
+``"harness"`` (``harness/__init__.py`` has the contract of each). The last lines of
 standard error give every number ``correct`` compared beside its limit,
 and then the run's wall time, process start to the result line, beside
 the harness's budget (``common.RUN_BUDGET_S``): ``wall_s = <n> budget

@@ -78,4 +78,3 @@ def test_benchmark_json_lists_the_metric_for_the_two_sparse_latent_cells():
         "kernels", "device_trace", "ms", "lower", "itl_p95_ms")
     assert m["workloads"] == ["dots3-ep8-longdoc-closed",
                               "deepseekv32-ep8-reason-closed"]
-    assert doc["per_layer"][-1] is m      # appended: nothing before it moved

@@ -438,13 +438,11 @@ def test_the_ring_blocks_reader_reads_its_counter_and_the_cell_is_on_its_lists()
         "counters_start": promtext.parse(""),
         "counters_end": promtext.parse("")}) is None
     bench = json.loads((_paths.ROOT / "BENCHMARK.json").read_text())
-    # the reader has no entry in `per_layer` yet: a test of the
-    # benchmark's pins that list's LAST entry, so only a `benchmark` PR
-    # can append one (PERF.md 7, 32 c)
     lists = {m["name"] for m in bench["per_layer"]
              if CELL in m.get("workloads", ())}
     assert {"decode_attn_roofline", "swa_decode_attn_roofline",
-            "swa_rows_read_share", "moe_routed_roofline",
+            "swa_rows_read_share", "swa_ring_blocks_read_share",
+            "moe_routed_roofline",
             "moe_experts_hit_share", "moe_held_assignment_share",
             "plain_step_ms", "step_device_ms"} <= lists
     assert not lists & {"prefill_chunk_device_ms",

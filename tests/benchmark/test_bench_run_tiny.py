@@ -194,12 +194,13 @@ WIDE_READER = (
 
 def test_a_configuration_of_another_architecture_is_files_and_entries_only(
         tmp_path):
-    """A configuration that names three modules of its own, with a
-    ``.py`` reader of a ``work`` key only its costs module returns and a
-    hidden size that is not 4096 (5120 over 40 heads of 128), joins a
-    copy of the tree by new files and new entries and runs ``--tiny``;
-    no file that was there is touched. It rides on a traffic mix that
-    is there. The guard that the next configuration's PR edits nothing
+    """A configuration that names three modules of its own (the fourth,
+    ``generation``: ``test_bench_generation.py``), with a ``.py`` reader
+    of a ``work`` key only its costs module returns and a hidden size
+    that is not 4096 (5120 over 40 heads of 128), joins a copy of the
+    tree by new files and new entries and runs ``--tiny``; no file that
+    was there is touched. It rides on a traffic mix that is there. The
+    guard that the next configuration's PR edits nothing
     under ``paths``."""
     root = _copy_tree(tmp_path)
     before = _digest(root)
@@ -243,9 +244,10 @@ def test_a_configuration_of_another_architecture_is_files_and_entries_only(
     cell = spec.Cell("wide-cell", root)
     assert cell.config["reference"]["hidden"] == 5120 != 4096
     assert cell.layer_metric_file("wide_tokens_served").suffix == ".py"
+    # the fourth role, which the file leaves out, takes the default
     assert {m.__name__.split("@")[0] for m in cell.modules.values()} == {
         "harness.wide_reference", "harness.wide_weights",
-        "harness.wide_costs"}
+        "harness.wide_costs", "harness.generation"}
 
     r = _run(root, "--workload", "wide-cell", "--seed", str(2 ** 31 + 27),
              "--seconds", "2", "--trace", "1", "--tiny")

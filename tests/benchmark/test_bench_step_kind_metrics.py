@@ -8,6 +8,7 @@ the relabelled histogram, which read what they read before it had a
 checked, no time is asserted)."""
 
 import json
+import time
 import urllib.request
 
 import pytest
@@ -23,10 +24,37 @@ ACCEPTED = ("step_device_ms", "step_host_ms", "step_dispatch_ms",
 PHASES = "bigdl_tpu_step_phase_seconds"
 WALL = "bigdl_tpu_tpot_seconds"
 KINDS = ("plain", "chunk")
+LOOP = "bigdl_tpu_engine_loop_seconds_total"
 
 
 def _file(metric):
     return _paths.BENCH / "layer_metrics" / f"{metric}.json"
+
+
+def _settled(eng, limit_s=60.0):
+    """``/metrics`` once the engine's thread has gone idle. A client
+    reads ``[DONE]`` from INSIDE the step that emitted its last token:
+    the step observes itself into the histograms after that, so a scrape
+    taken as soon as a response ends holds that step or not (a window
+    between two such scrapes counted 58, 59 or 60 decoding steps,
+    whichever side each edge's step fell on). With nothing unfinished,
+    the loop's ``wait`` counter moves only after a step that found no
+    work, which the last working step precedes."""
+    def waited():
+        return promtext.total(promtext.parse(eng.registry.render()), LOOP,
+                              {"state": "wait"})
+
+    deadline = time.monotonic() + limit_s
+    seen = None
+    while time.monotonic() < deadline:
+        if eng.has_unfinished():
+            seen = None
+        elif seen is None:
+            seen = waited()
+        elif waited() > seen:
+            return promtext.parse(eng.registry.render())
+        time.sleep(0.005)
+    raise AssertionError(f"the engine was not idle within {limit_s} s")
 
 
 @pytest.fixture(scope="module")
@@ -34,7 +62,9 @@ def window():
     """``/metrics`` of a tiny paged engine behind the in-process server
     before and after three streamed requests: the second and the third
     are admitted while the first decodes, so their chunks ride decoding
-    steps."""
+    steps. Both scrapes wait for the engine to go idle (``_settled``),
+    so the window holds every step of its three requests and none of
+    the warm-up's."""
     from bigdl_tpu.observability import MetricsRegistry, RequestTracer
     from bigdl_tpu.serving import EngineConfig, LLMEngine
     from bigdl_tpu.serving.api_server import OpenAIServer
@@ -61,7 +91,7 @@ def window():
         # every program compiled before the window, as in a cell
         with post(list(range(1, 20)), 4) as r:
             r.read()
-        start = promtext.parse(eng.registry.render())
+        start = _settled(eng)
         with post(list(range(30, 49)), 60) as long:
             long.readline()             # decoding: its first token is out
             for prompt in (list(range(60, 79)), list(range(90, 100))):
@@ -69,7 +99,7 @@ def window():
                     assert r.read().decode().rstrip().endswith(
                         "data: [DONE]")
             assert long.read().decode().rstrip().endswith("data: [DONE]")
-        end = promtext.parse(eng.registry.render())
+        end = _settled(eng)
     finally:
         server.shutdown()
     return {"counters_start": start, "counters_end": end, "first": first}
@@ -115,7 +145,10 @@ def test_a_chunk_that_rode_a_decoding_step_is_a_chunk_step(window):
     assert _delta(window, PHASES + "_count", phase="admission",
                   kind="chunk") == chunks
     decoded = _delta(window, PHASES + "_count", phase="device")
-    assert decoded == _delta(window, WALL + "_count") >= 59
+    # 60 tokens: the first is the prefill's, a decoding step each for the
+    # rest, and the short requests' tokens rode those; exact, because
+    # both edges of the window are settled
+    assert decoded == _delta(window, WALL + "_count") == 59
     assert _delta(window, WALL + "_count", kind="plain") == decoded - 6
 
 
