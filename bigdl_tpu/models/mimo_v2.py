@@ -116,6 +116,11 @@ class GqaKind:
     qk_norm: bool = False
     gate: bool = False
     norm_eps: float = 1e-5
+    # what `models/sdar_moe.py`'s layers add: positions come in blocks
+    # of `block`, causal between blocks, and every row of a block sees
+    # its whole block (1: the causal mask). A call of exactly `block`
+    # rows a slot is one block under one limit (`ops/swa.full_block`)
+    block: int = 1
 
     @property
     def q_width(self) -> int:
@@ -360,11 +365,14 @@ def attention(y, lp, kind: GqaKind, k_stack, v_stack, li, pos, cos, sin):
             if t == 1:
                 o = swa.full_decode(q[:, 0], k_stack, v_stack, li, pos,
                                     scale, g)[:, None]
+            elif kind.block > 1 and t == kind.block:
+                o = swa.full_block(q, k_stack, v_stack, li, pos, scale, g)
             else:
                 kl, vl = (lax.dynamic_index_in_dim(x, li, 0, keepdims=False)
                           for x in (k_stack, v_stack))
                 o = jax.vmap(lambda q_, k_, v_, p_: swa.full_chunk(
-                    q_, k_, v_, p_, scale, g))(q, kl, vl, _positions(pos, b))
+                    q_, k_, v_, p_, scale, g, kind.block))(
+                    q, kl, vl, _positions(pos, b))
     else:
         with jax.named_scope("gqa.window"):
             if t == 1:

@@ -88,6 +88,16 @@ class FamilyAdapter:
         return self._forward_module("mtp_forward")
 
     @property
+    def block_spec(self) -> Optional[Callable]:
+        """`cfg -> BlockSpec` (`models/sdar_moe.py`): a family that
+        generates by diffusion over blocks. Its step is a BLOCK: a call
+        of `forward` with `length` rows a slot is one pass over a block,
+        MASK ids where nothing is committed, and the serving engine's
+        step commits the tokens the pass's confidences choose; None for
+        a family whose step is one next token."""
+        return self._forward_module("block_spec")
+
+    @property
     def cache_spec(self) -> Optional[Callable]:
         """`cfg -> ops.kvcache.CacheSpec`: what the family's layers keep
         per position, where that is not K and V of `num_key_value_heads
@@ -275,6 +285,26 @@ def _register_builtin() -> None:
             prefill=afmoe_mod.forward_last_token,
             forward_train=None,
             new_cache=afmoe_mod.new_cache,
+        ))
+
+    from bigdl_tpu.models import sdar_moe as sdar_mod
+
+    # generation by diffusion over blocks: mimo_v2's full planes and
+    # kernels under a block-causal mask with a per-head QK norm, every
+    # layer routed (no shared expert), one scan over all the layers; the
+    # engine's step is a block pass (`block_spec`); slab only, bf16
+    # planes only. The catalog's row states no `architectures`: the one
+    # name is ISSUE 53's (the benchmark's file says so under `assumed`)
+    register_family(
+        ["SdarMoeForCausalLM"],
+        FamilyAdapter(
+            name="sdar_moe",
+            config_from_hf=sdar_mod.SdarMoeConfig.from_hf,
+            convert_params=sdar_mod.convert_hf_params,
+            forward=sdar_mod.forward,
+            prefill=sdar_mod.forward_last_token,
+            forward_train=None,
+            new_cache=sdar_mod.new_cache,
         ))
 
     from bigdl_tpu.models import rwkv as rwkv_mod

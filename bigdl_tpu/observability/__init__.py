@@ -137,6 +137,14 @@ bigdl_tpu_sampler_steps_total{path}         LLMEngine._sent_decode, once a
                                             (sampled, no row sorted) |
                                             nucleus (a live top_p < 1: the
                                             sorted branch ran)
+bigdl_tpu_block_passes_total{kind}          LLMEngine._block_step (a family
+                                            that generates by diffusion over
+                                            blocks), a slot's pass as the
+                                            host reads it: denoise | store
+bigdl_tpu_block_tokens_committed_total      LLMEngine._block_step: rows a
+                                            denoise pass committed
+bigdl_tpu_blocks_total                      LLMEngine._block_step: blocks
+                                            stored
 bigdl_tpu_spec_round_seconds{mode}          speculative._spec_observe
 bigdl_tpu_spec_tokens_total{mode,kind}      speculative._spec_observe
 bigdl_tpu_kv_cache_bytes{dtype,component}   ops/kvcache.publish_kv_cache_bytes

@@ -50,6 +50,9 @@ module and observability/__init__ for the field mapping):
     bigdl_tpu_decode_steps_total{sent=ahead|in_step}             counter
     bigdl_tpu_decode_steps_vain_total                            counter
     bigdl_tpu_sampler_steps_total{path=greedy|topk|nucleus}      counter
+    bigdl_tpu_block_passes_total{kind=denoise|store}             counter
+    bigdl_tpu_block_tokens_committed_total                       counter
+    bigdl_tpu_blocks_total                                       counter
     bigdl_tpu_spec_round_seconds{mode=...}                       histogram
     bigdl_tpu_spec_tokens_total{mode=...,kind=drafted|accepted}  counter
     bigdl_tpu_requests_quarantined_total{reason=nan_logits|crash_loop}

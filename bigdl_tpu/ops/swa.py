@@ -159,12 +159,38 @@ def window_decode(q, ring_k, ring_v, layer, pos, scale: float, hkv: int,
                       sink)
 
 
-def full_chunk(q, k_layer, v_layer, p, scale: float, hkv: int):
+def full_block(q, k_stack, v_stack, layer, pos, scale: float, hkv: int,
+               backend=None):
+    """A pass of `R` rows a slot under ONE limit (`q` `[B, R, H, dk]`,
+    the rows at `pos .. pos + R - 1`, `pos` `[B]` or a scalar) over layer
+    `layer` of a full layer's stacks, the rows' own K and V already
+    written: every row reads the same keys, the positions `0 .. pos + R
+    - 1` (a block of a model that generates by diffusion over blocks:
+    what lies below the block, and the whole block). The `R` rows of a
+    slot are `R x H` query heads over those keys, laid out KV head by KV
+    head, so `full_decode` serves as it is at `H' = R H`. Returns `[B,
+    R, H, dv]` in q.dtype."""
+    b, r, h, dk = q.shape
+    g = h // hkv
+    qh = q.reshape(b, r, hkv, g, dk).transpose(0, 2, 1, 3, 4).reshape(
+        b, r * h, dk)
+    o = full_decode(qh, k_stack, v_stack, layer,
+                    jnp.asarray(pos, jnp.int32) + (r - 1), scale, hkv,
+                    backend)
+    return o.reshape(b, hkv, r, g, -1).transpose(0, 2, 1, 3, 4).reshape(
+        b, r, h, -1)
+
+
+def full_chunk(q, k_layer, v_layer, p, scale: float, hkv: int,
+               block: int = 1):
     """A chunk of `T` rows of ONE sequence at positions `p .. p + T - 1`
     through a full layer's planes `k_layer` `[S, Hkv * dk]`, `v_layer`
     `[S, Hkv * dv]` (the chunk's own rows already written): the live
-    blocks of keys under an online softmax. Returns `[T, H, dv]`
-    float32."""
+    blocks of keys under an online softmax. Key `j` is live for the row
+    at `i` while `j // block <= i // block`: causal at `block` 1, causal
+    between blocks of `block` positions whose rows see their whole block
+    above it (the chunk then starts and ends on a block's edge). Returns
+    `[T, H, dv]` float32."""
     t, h, dk = q.shape
     s, g = k_layer.shape[0], h // hkv
     dv = v_layer.shape[1] // hkv
@@ -172,6 +198,9 @@ def full_chunk(q, k_layer, v_layer, p, scale: float, hkv: int):
     n_live = jnp.minimum((p + t + kb - 1) // kb, s // kb)
     qg = q.reshape(t, hkv, g, dk)
     at = p + jnp.arange(t, dtype=jnp.int32)
+    if block > 1:
+        # the last position of each row's block
+        at = at // block * block + (block - 1)
 
     def attend(j, carry):
         m, l, acc = carry

@@ -551,6 +551,9 @@ class OpenAIServer:
         # has carried yet / of the newest output (a held-back tail)
         oldest_push: dict = {}
         newest_push: dict = {}
+        # index -> the pass counts (`RequestOutput.steps`, a family whose
+        # step is a block) of the tokens no chunk has carried yet
+        owed_steps: dict = {}
         if seed_ids:
             # a resumed (migrated-in) request: the engine only emits
             # tokens generated since the claim, but the client is owed
@@ -589,7 +592,12 @@ class OpenAIServer:
             upto = min(upto, len(full))
             if upto > start:
                 try:
-                    stream_cb(full[start:upto], idx)
+                    if idx in owed_steps:
+                        # several tokens an event, one integer a token
+                        stream_cb(full[start:upto], idx,
+                                  steps=owed_steps.pop(idx))
+                    else:
+                        stream_cb(full[start:upto], idx)
                     emitted[idx] = upto
                     t_push = (oldest_push.pop(idx, None)
                               or newest_push.get(idx))
@@ -675,6 +683,8 @@ class OpenAIServer:
                     out_ids.setdefault(idx, []).extend(o.new_token_ids)
                     if o.logprobs:
                         out_lps.setdefault(idx, []).extend(o.logprobs)
+                    if o.steps is not None and o.new_token_ids:
+                        owed_steps.setdefault(idx, []).extend(o.steps)
                 if live_decode and o.new_token_ids and idx not in stopped:
                     oldest_push.setdefault(idx, o.t_push)
                     newest_push[idx] = o.t_push
@@ -1441,6 +1451,14 @@ class OpenAIServer:
                 if isinstance(stops, str):
                     stops = (stops,)
                 stops = tuple(s for s in stops if s)
+                if stops and getattr(server.engine.family, "block_spec",
+                                     None) is not None:
+                    # (ValueError: a 400, as the engine's own refusals)
+                    raise ValueError(
+                        "stop strings hold text back from an event, and a "
+                        "block family's event says `steps`, one integer a "
+                        "token it delivers: the two would part. Cut the "
+                        "text at the client, or end at `max_tokens` / EOS")
                 created = int(time.time())
                 # trace context: router/client header, or the staged
                 # _traceparent a kv_handoff relay carries in its body
@@ -1518,7 +1536,11 @@ class OpenAIServer:
                     self.send_header("Cache-Control", "no-cache")
                     self.end_headers()
 
-                    def cb(text, index):
+                    def cb(text, index, steps=None):
+                        # `steps`: a family whose step is a block says,
+                        # one integer a token of this event, the count
+                        # of passes the request had been given when the
+                        # token was committed
                         delta = ({"role": "assistant", "content": text}
                                  if chat else None)
                         chunk = {
@@ -1530,6 +1552,8 @@ class OpenAIServer:
                                 "index": index,
                                 **({"delta": delta} if chat
                                    else {"text": text}),
+                                **({} if steps is None
+                                   else {"steps": steps}),
                                 "finish_reason": None}],
                         }
                         self.wfile.write(

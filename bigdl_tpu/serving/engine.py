@@ -194,6 +194,9 @@ class Request:
     # _paged_admit claims the imported arena pages stashed under it
     # (one-shot; None after the claim, or for ordinary requests)
     resume_id: Optional[str] = None
+    # a block family's preempt-resume: passes the request was given
+    # before this (re-)admission (`RequestOutput.steps` counts on)
+    block_passes: int = 0
 
 
 @dataclasses.dataclass
@@ -218,6 +221,11 @@ class RequestOutput:
     # time.perf_counter() when _push_output took it: the start of the
     # API server's bigdl_tpu_stream_delivery_seconds
     t_push: float = 0.0
+    # a family whose step is a block: beside each of `new_token_ids`,
+    # the count of passes the request had been given when the token was
+    # committed (storing passes counted, prefill chunks not); None for a
+    # family whose step is one next token
+    steps: Optional[List[int]] = None
 
 
 @dataclasses.dataclass
@@ -361,7 +369,7 @@ class EngineConfig:
 class _Slot:
     __slots__ = ("req", "generated", "last_token", "active", "counts",
                  "counts_out", "rng", "cum_logprob", "n_logprobs",
-                 "dev_seed", "drafted")
+                 "dev_seed", "drafted", "block")
 
     def __init__(self):
         self.req: Optional[Request] = None
@@ -381,6 +389,21 @@ class _Slot:
         self.dev_seed: int = 0
         # a standing draft of the family's MTP module waits on the device
         self.drafted: bool = False
+        # a block family's block in flight (`_BlockState`)
+        self.block: Optional["_BlockState"] = None
+
+
+@dataclasses.dataclass
+class _BlockState:
+    """The host's view of the block a slot of a block family denoises:
+    what it has READ of the passes (the device's own view, the packed
+    state, may be one pass further on)."""
+    pos: int                 # position of the block's first row
+    ids: List[int]           # a row's final token; -1 while it is MASK
+    steps: List[int]         # the request's pass count at a row's commit
+    sent: int                # rows streamed, or given by the prompt
+    s: int = 0               # denoise passes this block has been given
+    passes: int = 0          # passes the REQUEST has been given
 
 
 @dataclasses.dataclass
@@ -496,11 +519,16 @@ def _transform_rows(lg, temps, top_ks, top_ps):
 
 
 @jax.named_scope("sampler")
-def _device_sample_rows(lg, temps, top_ks, top_ps, seeds, poss):
+def _sample_rows(lg, temps, top_ks, top_ps, seeds, poss):
     """Batched on-device sampler body: temperature / top-k / top-p via
-    gumbel-max, one seeded stream per row. Shared by the standalone
-    ``engine_sample_device`` jit and the fused resident decode step so
-    the two paths are numerically identical token-for-token."""
+    gumbel-max, one seeded stream per row: `(token, confidence)`, the
+    confidence the probability of the token under the distribution it
+    was drawn from (a greedy row: the softmax of its raw logits; else of
+    the row after temperature, top-k and top-p). Shared by the
+    standalone ``engine_sample_device`` jit, the fused resident decode
+    step and the block step, so the paths are numerically identical
+    token-for-token; a program that reads no confidence computes
+    none."""
     t, greedy = _transform_rows(lg, temps, top_ks, top_ps)
     lg = lg.astype(jnp.float32)
 
@@ -508,9 +536,69 @@ def _device_sample_rows(lg, temps, top_ks, top_ps, seeds, poss):
         key = jax.random.fold_in(jax.random.PRNGKey(seed), pos)
         gum = jax.random.gumbel(key, row_t.shape, row_t.dtype)
         z = jnp.where(g, row_lg, row_t + gum)
-        return jnp.argmax(z).astype(jnp.int32)
+        tok = jnp.argmax(z).astype(jnp.int32)
+        dist = jnp.where(g, row_lg, row_t)
+        return tok, jnp.exp(dist[tok] - jax.nn.logsumexp(dist))
 
     return jax.vmap(row)(t, lg, greedy, seeds, poss)
+
+
+def _device_sample_rows(lg, temps, top_ks, top_ps, seeds, poss):
+    """`_sample_rows`' tokens."""
+    return _sample_rows(lg, temps, top_ks, top_ps, seeds, poss)[0]
+
+
+def _block_sample(logits, spec, temps, top_ks, top_ps, seeds, first,
+                  all_greedy: bool):
+    """What a denoise pass samples for every row of every slot's block
+    and how sure it is: `logits` `[N, B, V]` -> `(x0, conf)` `[N, B]`,
+    the token and its probability under the distribution it was drawn
+    from (`_sample_rows`), the MASK id no candidate (its logit -inf
+    before both). `temps`, `top_ks`, `top_ps`, `seeds` `[N]` are a
+    slot's; row j of a slot draws from its seed's stream at `first + j`,
+    its index among the request's generated tokens. `all_greedy`: the
+    program of a pass that samples no row."""
+    b = spec.length
+    lg = logits.reshape(-1, logits.shape[-1]).at[:, spec.mask_id].set(
+        -jnp.inf)
+    if all_greedy:
+        x0 = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+        conf = jnp.exp(jnp.max(lg, axis=-1) - jax.nn.logsumexp(lg, axis=-1))
+    else:
+        rows = lambda a: jnp.repeat(a, b)                   # noqa: E731
+        x0, conf = _sample_rows(
+            lg, rows(temps), rows(top_ks), rows(top_ps), rows(seeds),
+            jnp.maximum(first[:, None] + jnp.arange(b)[None, :],
+                        0).reshape(-1))
+    return x0.reshape(-1, b), conf.reshape(-1, b)
+
+
+@jax.named_scope("block.transfer")
+def _block_transfer(conf, masked, s, spec):
+    """Which rows of each block a denoise pass commits: `conf` `[N, B]`
+    float32 (a row's `x0_p`), `masked` `[N, B]` bool (rows still MASK),
+    `s` `[N]` the pass's index within its block, `spec` the family's
+    `BlockSpec` -> `[N, B]` bool. `min(spec.owed(s), rows still MASK)`
+    rows at the least, and never a row that is not MASK: the most
+    confident (a tie goes to the lower row), every MASK row over the
+    threshold where those are at least as many (`low_confidence_
+    dynamic`), or the first MASK rows (`sequential`)."""
+    b = spec.length
+    k = jnp.minimum(spec.owed(s).astype(jnp.int32),
+                    masked.sum(axis=1).astype(jnp.int32))[:, None]
+    if spec.rule == "sequential":
+        return masked & (jnp.cumsum(masked, axis=1) <= k)
+    c = jnp.where(masked, conf, -jnp.inf)
+    row = jnp.arange(b)
+    # [N, j, i]: row i goes before row j
+    before = (c[:, None, :] > c[:, :, None]) | (
+        (c[:, None, :] == c[:, :, None])
+        & (row[None, None, :] < row[None, :, None]))
+    top = masked & (before.sum(axis=-1) < k)
+    if spec.rule == "low_confidence_static":
+        return top
+    over = masked & (conf > spec.threshold)
+    return jnp.where(over.sum(axis=1, keepdims=True) >= k, over, top)
 
 
 class LLMEngine:
@@ -600,6 +688,13 @@ class LLMEngine:
             else flags().prefix_sharing)
         self._paged = page_size > 0
         self._page_size = page_size
+        # a family that generates by diffusion over blocks: its step is a
+        # BLOCK (`_block_step`), and what one is, is the model's
+        # configuration (`BlockSpec`); None: one next token a step
+        spec_of = getattr(self.family, "block_spec", None)
+        self._block = spec_of(self.cfg) if spec_of is not None else None
+        if self._block is not None:
+            self._refuse_for_block_family()
         self.pool: Optional[PagePool] = None
         self.radix: Optional[RadixCache] = None
         if self._paged:
@@ -866,6 +961,8 @@ class LLMEngine:
             return jnp.stack(cols, axis=1), ints, cache
 
         self._decode_resident = decode_resident
+        if self._block is not None:
+            self._init_block_step(fwd)
 
         # prefill one sequence on a private 1-row cache, then splice its K/V
         # (and, for scaled dtypes, the per-token scale planes) and position
@@ -881,13 +978,17 @@ class LLMEngine:
 
         self._insert = insert
 
+        # a block family's prefill yields no token: its chunks run the
+        # family's last-row forward, and the head meets one row
+        fwd_chunk = fwd if self._block is None else self.family.prefill
+
         @functools.partial(tracked_jit, "engine_prefill",
                            registry=self.registry, donate_argnums=(2,))
         def prefill_chunk(params, tokens, cache1):
             # one tracked fn; XLA caches an executable per (chunk width,
             # cache bucket, kv dtype) shape tuple — the compile table's
             # per-signature rows ARE the engine's prefill executables
-            return fwd(params, self.cfg, tokens, cache1)
+            return fwd_chunk(params, self.cfg, tokens, cache1)
 
         self._prefill = prefill_chunk
 
@@ -1215,6 +1316,24 @@ class LLMEngine:
             "sorted once).", labelnames=("path",))
         for pt in ("greedy", "topk", "nucleus"):    # render from scrape 1
             self._m_sampler_steps.labels(pt)
+        self._m_block_passes = m.counter(
+            "bigdl_tpu_block_passes_total",
+            "Passes the slots of a family that generates by diffusion "
+            "over blocks were given and the host read (a program holds "
+            "one a live slot): kind=denoise a pass over a block that "
+            "still held a MASK (it commits the rows its confidences "
+            "choose), kind=store the pass over a block's final ids that "
+            "leaves its K/V in the cache and commits nothing.",
+            labelnames=("kind",))
+        for kd in ("denoise", "store"):     # render from scrape 1
+            self._m_block_passes.labels(kd)
+        self._m_block_tokens = m.counter(
+            "bigdl_tpu_block_tokens_committed_total",
+            "Rows the denoise passes committed (MASK to a final token), "
+            "streamed or not.")
+        self._m_blocks = m.counter(
+            "bigdl_tpu_blocks_total",
+            "Blocks stored: every row final and their K/V in the cache.")
         self._m_vain_steps = m.counter(
             "bigdl_tpu_decode_steps_vain_total",
             "Decode programs sent ahead of which no slot was read: every "
@@ -1546,6 +1665,8 @@ class LLMEngine:
         best_of = params.best_of or params.n
         if best_of < params.n:
             raise ValueError(f"best_of ({best_of}) < n ({params.n})")
+        if self._block is not None:
+            self._refuse_request_for_block_family(params, best_of)
         if resume is not None and best_of > 1:
             # migration exports only simple (non-fanout) slots; a
             # resume of a fan-out parent has no single sampler stream
@@ -2046,6 +2167,178 @@ class LLMEngine:
             "verify round.", labelnames=("mode",),
             buckets=RATIO_BUCKETS).labels("mtp")
 
+    # -- a family whose step is a block ------------------------------------
+
+    def _refuse_for_block_family(self) -> None:
+        """What a family that generates by diffusion over blocks cannot
+        be served with, each said at construction with its reason."""
+        ce, blk = self.cfg_engine, self._block
+        name = getattr(self.family, "name", "?")
+        b = blk.length
+        if ce.speculative_tokens:
+            raise ValueError(
+                f"speculative_tokens={ce.speculative_tokens}: the {name!r} "
+                "family's step denoises a block and has no next token to "
+                "draft; serve it with speculative_tokens=0")
+        if ce.prefix_cache_entries > 0:
+            raise ValueError(
+                f"prefix_cache_entries={ce.prefix_cache_entries}: the "
+                f"{name!r} family's rows see their whole block, so a "
+                "snapshot is a valid prefix at a block's edge only "
+                "(prefix reuse at block edges is not built); serve it "
+                "with prefix_cache_entries=0")
+        if self._paged:
+            raise ValueError(
+                f"kv_page_size={self._page_size}: the {name!r} family's "
+                "block pass reads and writes the slab's planes (no paged "
+                "forward writes a block's rows pass after pass); serve it "
+                "with kv_page_size=0")
+        if self.kv_cache_dtype != "bf16":
+            raise ValueError(
+                f"kv_cache_dtype={self.kv_cache_dtype!r}: the {name!r} "
+                "family's planes are bf16 only (a denoise pass's rows are "
+                "overwritten by every later pass: no scale plane follows "
+                "them); serve it with kv_cache_dtype='bf16'")
+        chunk = 1 << (max(1, ce.prefill_chunk).bit_length() - 1)
+        if chunk % b or ce.prefill_bucket % b or ce.max_seq % b:
+            raise ValueError(
+                f"block_length {b}: prefill_chunk {chunk}, prefill_bucket "
+                f"{ce.prefill_bucket} and max_seq {ce.max_seq} must be "
+                "multiples of it (a chunk starts and ends on a block's "
+                "edge)")
+
+    def _refuse_request_for_block_family(self, params, best_of) -> None:
+        """What a request cannot ask of a block family: its tokens are
+        sampled on the device inside the block pass, which carries no
+        penalty counts and returns no logits."""
+        if params.logprobs is not None or best_of > params.n:
+            raise ValueError(
+                "logprobs (and best_of > n, which ranks by them) need a "
+                "row's logits on the host; a block pass returns the "
+                "tokens its confidences committed, no logits")
+        if params.needs_counts:
+            raise ValueError(
+                "repetition / presence / frequency penalties count the "
+                "tokens before a row; the rows of a block are final out "
+                "of sequence order")
+
+    def _init_block_step(self, fwd) -> None:
+        """The resident program of a block family: ONE pass over the
+        block of every live slot. A slot's block (its ids, which rows
+        are MASK, the pass's index) stays ON the device between passes,
+        packed as `_io` packs the plain step's: `state` `[slots, 2 B +
+        5]` int32 (B ids, B MASK flags, the denoise passes the block has
+        had, top-k, seed, the index among the request's generated tokens
+        of the block's first row, live) and `floats` `[2, slots]`
+        (temperature, top-p) go in; out come ONE int32 block `[slots, B
+        + 2]` (a row's token where it is final after the pass, -1 where
+        it is still MASK; whether the pass STORED; health) and the next
+        pass's `state`.
+
+        A pass runs the family's forward on the block's `B` rows at the
+        slot's `pos` (the rows' K/V land in the planes at the block's
+        positions, over those of the pass before). Where a MASK is left
+        it is a DENOISE pass: every row samples `x0` with its
+        probability under the distribution it was drawn from, the MASK
+        id's logit at -inf, `_block_transfer` commits rows, `pos`
+        stays. Where none is left it is the STORING pass: the K/V just
+        written are those of the final ids and stand, `pos` moves by
+        `B`, and the state is the next block, all MASK. One program
+        serves a batch whose slots are at different passes."""
+        blk = self._block
+        b, mask_id = blk.length, blk.mask_id
+
+        @functools.partial(tracked_jit, "engine_block_resident",
+                           registry=self.registry, donate_argnums=(1, 3),
+                           static_argnames=("all_greedy",))
+        def block_decode_resident(params, state, floats, cache, *,
+                                  all_greedy):
+            # (the XLA module is `jit_block_decode_resident`: this
+            # family's DECODE program, which is how a trace finds one)
+            ids, masked = state[:, :b], state[:, b:2 * b] != 0
+            s, top_ks, seeds, first = (state[:, 2 * b + j] for j in range(4))
+            live = state[:, 2 * b + 4] != 0
+            temps, top_ps = floats
+            store = ~jnp.any(masked, axis=1)
+            pos0 = cache.pos
+            with jax.named_scope("block.denoise"):
+                logits, cache = fwd(
+                    params, self.cfg, jnp.where(masked, mask_id, ids),
+                    cache.replace(pos=jnp.where(live, pos0, -1)))
+                finite = jnp.isfinite(logits).all(axis=(1, 2))
+                x0, conf = _block_sample(logits, blk, temps, top_ks, top_ps,
+                                         seeds, first, all_greedy)
+            commit = _block_transfer(conf, masked, s, blk)
+            with jax.named_scope("block.store"):
+                after = jnp.where(commit, x0, ids)
+                left = masked & ~commit
+                out = jnp.concatenate(
+                    [jnp.where(left, -1, after),
+                     store.astype(jnp.int32)[:, None],
+                     finite.astype(jnp.int32)[:, None]], axis=1)
+                nxt = store[:, None]
+                state = jnp.concatenate(
+                    [jnp.where(nxt, mask_id, after),
+                     (nxt | left).astype(jnp.int32),
+                     jnp.stack([jnp.where(store, 0, s + 1), top_ks, seeds,
+                                jnp.where(store, first + b, first),
+                                live.astype(jnp.int32)], axis=1)], axis=1)
+                cache = cache.replace(pos=jnp.where(
+                    live, jnp.where(store, pos0 + b, pos0), 0))
+            return out, state, cache
+
+        self._block_resident = block_decode_resident
+
+    def _block_args(self, active):
+        """`(holders, state, floats)` of a block pass over the slots
+        `active` from the host's view of their blocks (`_step_args`)."""
+        blk, n = self._block, self.cfg_engine.max_batch
+        b = blk.length
+        state = np.zeros((n, 2 * b + 5), np.int32)
+        state[:, :b] = blk.mask_id
+        state[:, b:2 * b] = 1
+        temps, top_ks, top_ps, seeds, _ = self._sampling_arrays(active)
+        for i in active:
+            s = self.slots[i]
+            at = s.block
+            state[i, :b] = [t if t >= 0 else blk.mask_id for t in at.ids]
+            state[i, b:2 * b] = [t < 0 for t in at.ids]
+            # the index among the request's generated tokens of the
+            # block's first row: what seeds a row's draw, the same
+            # before and after a preempt-resume
+            first = at.pos - (len(s.req.prompt_token_ids)
+                              - s.req.generated_offset)
+            state[i, 2 * b:] = (at.s, top_ks[i], seeds[i], first, 1)
+        with self.phases.phase("dispatch.h2d", child=True):
+            state_dev = jnp.asarray(state)
+            floats = jnp.asarray(np.stack([temps, top_ps]))
+        return (tuple((i, self.slots[i].req) for i in active), state_dev,
+                floats)
+
+    def _block_may_lead(self, active) -> bool:
+        """`_may_lead` of a block family: the pass just sent can end no
+        slot of `active` by its length (it streams `B` tokens at most),
+        and the pass after it writes no row past the slab."""
+        b, s_max = self._block.length, self.cfg_engine.max_seq
+        for i in active:
+            s = self.slots[i]
+            r = s.req
+            if r.params.max_tokens - (r.generated_offset
+                                      + len(s.generated)) <= b:
+                return False
+            if s.block.pos + 3 * b > s_max:
+                return False
+        return True
+
+    def _prefill_len(self, req: Request) -> int:
+        """Prompt tokens an admission prefills: all of them, or of a
+        block family the prompt's WHOLE blocks (its tail opens the first
+        generated block as given tokens)."""
+        n = len(req.prompt_token_ids)
+        if self._block is None:
+            return n
+        return n // self._block.length * self._block.length
+
     def _sampling_arrays(self, rows):
         """The device sampler's per-slot arguments for the slots `rows`
         (`[max_batch]` each): temperature, top-k, top-p, seed and the
@@ -2082,6 +2375,8 @@ class LLMEngine:
         rows a slot); it falls to the plain one-row step, followed by
         the MTP module's row, under brownout and with any slot that
         needs the host sampler."""
+        if self._block is not None:
+            return "block"
         if not (decode_resident_enabled() and not self._paged
                 and not self.faults.enabled
                 and all(self._simple(self.slots[i]) for i in active)):
@@ -2117,6 +2412,8 @@ class LLMEngine:
         if io is not None and len(io[0]) == len(holders) and all(
                 i == j and r is q for (i, r), (j, q) in zip(io[0], holders)):
             return io
+        if self._block is not None:
+            return self._block_args(active)
         temps, top_ks, top_ps, seeds, poss = self._sampling_arrays(active)
         with self.phases.phase("dispatch.h2d", child=True):
             ints = jnp.asarray(np.stack(
@@ -2137,7 +2434,11 @@ class LLMEngine:
         """Dispatch one packed step over the slots `active`: the block
         the host will fetch and the `ints` after it."""
         path = self._sampler_path(active)
-        if verify:
+        if self._block is not None:
+            out, ints, self.cache = self._block_resident(
+                self.params, ints, floats, self.cache,
+                all_greedy=path == "greedy")
+        elif verify:
             (out, ints, self._mtp_draft, self._mtp_q,
              self.cache) = self._decode_resident_mtp(
                 self.params, ints, floats, self._mtp_draft, self._mtp_q,
@@ -2177,6 +2478,61 @@ class LLMEngine:
                                         floats, ahead=True)
         self._ahead = (holders, out, ints, floats)
 
+    def _take_ahead(self, active):
+        """Takes up the step that went out during the last one, if one
+        did: it IS this step for the slots whose requests it held and
+        that are still here (a slot admitted since waits a step; one that
+        ended since is read no further). Returns that step (None where
+        none serves a slot of `active`: sent in vain), the slots this
+        step serves, and whether they are all of `active` and all the
+        step sent ahead held, so that this step may lead in its turn."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is None:
+            return None, active, True
+        held = dict(ahead[0])
+        stay = [i for i in active if held.get(i) is self.slots[i].req]
+        if not stay:
+            self._m_vain_steps.inc()
+            return None, active, True
+        return ahead, stay, len(stay) == len(active) == len(held)
+
+    def _dispatch_packed(self, verify: bool, active, ahead, lead: bool,
+                         rows_a_slot: int):
+        """A packed step's dispatch, inside its `dispatch` phase: this
+        step's output block (of `ahead`, the step `_take_ahead` took up,
+        or sent here from `_step_args`), and, where `lead` and
+        `_may_lead` allow, the NEXT step sent out now on what this one
+        leaves on the device, so that the device does not wait while
+        the host reads and emits this step's tokens (what it holds of a
+        slot that this step ends is never read: `_finish` sets the
+        slot's `pos` back). Returns `(out_dev, io_next)`: `io_next` is
+        the device's view of the next step where none went out, for
+        `_keep_io`. The caller lets go of `ahead` with the call: the
+        arguments are released inside the phase, while the program
+        runs, not after the wait, where the device idles."""
+        io_next = None
+        if ahead is not None:
+            holders, out_dev, ints_dev, floats_dev = ahead
+        else:
+            holders, ints_dev, floats_dev = self._step_args(active)
+            out_dev, ints_dev = self._send_step(verify, active, ints_dev,
+                                                floats_dev)
+        if lead and self._may_lead(active, rows_a_slot):
+            out_next, ints_next = self._send_step(
+                verify, active, ints_dev, floats_dev, ahead=True)
+            self._ahead = (holders, out_next, ints_next, floats_dev)
+        elif ahead is None or lead:
+            io_next = (holders, ints_dev, floats_dev)
+        return out_dev, io_next
+
+    def _keep_io(self, io_next, active) -> None:
+        """At a packed step's emit: where no step went out ahead and
+        every slot the step served goes on, the device's view of the
+        next step (`_dispatch_packed`'s `io_next`) stands."""
+        if io_next is not None and len(active) == len(io_next[0]) and all(
+                self.slots[i].active for i in active):
+            self._io = io_next
+
     def _may_lead(self, active, rows_a_slot: int) -> bool:
         """Whether the step AFTER the one just sent may go out before
         its tokens are read: no slot of `active` can end this step by
@@ -2190,6 +2546,8 @@ class LLMEngine:
         step, and the first token wait a step longer."""
         if self._joining:
             return False
+        if self._block is not None:
+            return self._block_may_lead(active)
         s_max = self.cfg_engine.max_seq
         for i in active:
             s = self.slots[i]
@@ -2285,7 +2643,8 @@ class LLMEngine:
             # >= 2 shrinks the chunk (still a power of two) so admission
             # work yields to in-flight decodes sooner under pressure.
             bucket = self._bucket(len(req.prompt_token_ids))
-            chunk = min(max(1, self._chunk
+            chunk = min(max(1 if self._block is None
+                            else self._block.length, self._chunk
                             >> self.overload.chunk_shift()), bucket)
             alloc = -(-bucket // chunk) * chunk
             shared_pages = new_pages = None
@@ -2307,6 +2666,10 @@ class LLMEngine:
                         cache1, self.cache,
                         jnp.asarray(np.asarray(shared_pages, np.int32)),
                         jnp.asarray(consumed, jnp.int32))
+            elif self._block is not None:
+                # `seeded` is refused with the prefix cache: a staged
+                # snapshot (a handoff's) is no prefix of a block family
+                consumed = 0
             else:
                 consumed, seed_kv = self._seed_from_prefix_cache(
                     req.prompt_token_ids, chunk)
@@ -2340,7 +2703,13 @@ class LLMEngine:
         if hold:
             return
 
-        plen = len(a.req.prompt_token_ids)
+        plen = self._prefill_len(a.req)
+        if plen == 0:
+            # a block family's prompt shorter than a block: all of it
+            # opens the first block, and there is nothing to prefill
+            a.last = (None, None)
+            self._finish_admission(a)
+            return
         chunk = a.chunk
         padded = np.zeros((1, chunk), np.int32)
         part = a.req.prompt_token_ids[a.consumed:a.consumed + chunk]
@@ -2373,8 +2742,11 @@ class LLMEngine:
         a.consumed += chunk
 
         if a.consumed >= plen:
-            a.last = (logits[:, plen - 1 - start], hidden)
-            if self._ahead is not None:
+            # a block family's prefill yields no token: nothing of the
+            # chunk is read or waited for, and the slot joins at once
+            a.last = ((None, None) if self._block is not None
+                      else (logits[:, plen - 1 - start], hidden))
+            if self._ahead is not None and self._block is None:
                 # a decode step is on the device whose tokens are not
                 # read yet, and this chunk is queued behind it: the wait
                 # for the first token would hold them back by a chunk, so
@@ -2389,7 +2761,27 @@ class LLMEngine:
         its rows into the batched cache at the slot, the first token
         sampled, waited for and emitted, the slot live."""
         logits, hidden = a.last
-        plen = len(a.req.prompt_token_ids)
+        plen = self._prefill_len(a.req)
+        if self._block is not None:
+            # the prompt's whole blocks into the slab; its tail opens the
+            # first block as given rows beside MASK, and the first pass
+            # is the next step's
+            self.cache = self._insert(self.cache, a.cache1, a.slot_idx,
+                                      plen)
+            s = self.slots[a.slot_idx]
+            s.req = a.req
+            self._setup_slot_sampler(s)
+            s.generated = []
+            s.active = True
+            b = self._block.length
+            tail = [int(t) for t in a.req.prompt_token_ids[plen:]]
+            s.block = _BlockState(plen, tail + [-1] * (b - len(tail)),
+                                  [0] * b, len(tail),
+                                  passes=a.req.block_passes)
+            self._obs_admission_complete(a.req.request_id,
+                                         first_token=False)
+            self._admitting = None
+            return
         if self._paged:
             self.cache = self._paged_insert(a, plen)
         else:
@@ -2955,9 +3347,11 @@ class LLMEngine:
                     (i for i, s in enumerate(self.slots)
                      if s.active and s.req is not None
                      and s.req.request_id == rid), None)
-                if idx is None:
+                if idx is None or self._block is not None:
                     # not mid-decode here (queued, admitting, CP lane,
-                    # already finished, unknown): nothing to move —
+                    # already finished, unknown), or a block family's
+                    # (refused: the planes hold a block in flight, which
+                    # no resume takes up): nothing to move —
                     # tell the sender so it leaves the request alone
                     self._mig_inc("unexportable")
                     with self._lock:
@@ -3469,10 +3863,13 @@ class LLMEngine:
 
     # -- observability hooks ------------------------------------------------
 
-    def _obs_admission_complete(self, rid: str) -> None:
+    def _obs_admission_complete(self, rid: str,
+                                first_token: bool = True) -> None:
         """First token of an admission just sampled: close out the queue
         and prefill phases, record TTFT (first admission only — a
-        preempt-resume already streamed its first token)."""
+        preempt-resume already streamed its first token). A block
+        family's prefill yields no token (`first_token` False): its
+        first token is its first pass's (`_obs_first_token`)."""
         span = self.tracer.get(rid)
         now = time.time()
         just_first = span is not None and span.t_first_token is None
@@ -3495,15 +3892,22 @@ class LLMEngine:
                     parent_id=span.trace_span,
                     t_start=span.t_admitted, t_end=now,
                     request_id=rid)
+        if first_token:
+            self._obs_first_token(rid)
+        self._m_admissions.inc()
+        self.flight.record("admit_complete", step=self._step_idx,
+                           request_id=rid)
+
+    def _obs_first_token(self, rid: str) -> None:
+        """A request's first token: TTFT, once a request."""
+        span = self.tracer.get(rid)
+        just_first = span is not None and span.t_first_token is None
         self.tracer.first_token(rid)
         if just_first and span.ttft_s is not None:
             self._m_ttft.observe(span.ttft_s)
             meta = self._usage_meta.get(rid)
             if meta is not None:
                 self.slo.observe_ttft(meta[1], span.ttft_s)
-        self._m_admissions.inc()
-        self.flight.record("admit_complete", step=self._step_idx,
-                           request_id=rid)
 
     def _obs_finish(self, rid: str, reason: str,
                     n_generated: int = 0) -> None:
@@ -4131,6 +4535,7 @@ class LLMEngine:
         s.req = None
         s.active = False
         s.drafted = False
+        s.block = None
         self._io = None
         s.generated = []
         s.counts = None
@@ -4154,25 +4559,60 @@ class LLMEngine:
         self.overload.note_generated(s.req.params.tenant or "default",
                                      1, time.monotonic())
 
-    def _check_done(self, idx: int) -> bool:
+    def _done_reason(self, idx: int) -> Optional[str]:
+        """Why the slot's request ends with its last token, or None."""
         s = self.slots[idx]
         p = s.req.params
         tok = s.last_token
         if (not p.ignore_eos and self.eos_token_id is not None
                 and tok == self.eos_token_id):
-            self._finish(idx, "stop")
-            return True
+            return "stop"
         if tok in p.stop_token_ids:
-            self._finish(idx, "stop")
-            return True
+            return "stop"
         if s.req.generated_offset + len(s.generated) >= p.max_tokens:
-            self._finish(idx, "length")
-            return True
+            return "length"
         plen = len(s.req.prompt_token_ids)
-        if plen + len(s.generated) + 1 >= self.cfg_engine.max_seq:
-            self._finish(idx, "length")
-            return True
-        return False
+        # (a block family ends at the slab's end by blocks: `_block_step`)
+        if self._block is None and (plen + len(s.generated) + 1
+                                    >= self.cfg_engine.max_seq):
+            return "length"
+        return None
+
+    def _check_done(self, idx: int) -> bool:
+        reason = self._done_reason(idx)
+        if reason is not None:
+            self._finish(idx, reason)
+        return reason is not None
+
+    def _emit_run(self, idx: int, toks: List[int], steps: List[int]
+                  ) -> int:
+        """A block family's event: the run of final tokens `toks` that
+        continues what the slot's stream was sent, each beside the pass
+        count that committed it, in ONE output; cut after the first
+        token that ends the request (a stop token, `max_tokens`), the
+        rest of the block dropped. Returns the tokens streamed."""
+        s = self.slots[idx]
+        rid = s.req.request_id
+        reason = None
+        n = 0
+        for tok in toks:
+            s.last_token = tok
+            s.generated.append(tok)
+            n += 1
+            reason = self._done_reason(idx)
+            if reason is not None:
+                break
+        span = self.tracer.get(rid)
+        if span is not None and span.t_first_token is None:
+            self._obs_first_token(rid)
+        self._push_output(rid, RequestOutput(
+            rid, list(toks[:n]), False, steps=list(steps[:n])))
+        self._m_tokens.inc(n)
+        self.overload.note_generated(s.req.params.tenant or "default", n,
+                                     time.monotonic())
+        if reason is not None:
+            self._finish(idx, reason)
+        return n
 
     # -- context-parallel overflow lane -------------------------------------
 
@@ -4308,9 +4748,15 @@ class LLMEngine:
             req,
             prompt_token_ids=list(req.prompt_token_ids) + list(s.generated),
             generated_offset=req.generated_offset + len(s.generated),
-            resumed_cum_logprob=s.cum_logprob)
+            resumed_cum_logprob=s.cum_logprob,
+            # a block family: the block in flight is dropped (what it
+            # streamed opens the resumed request's first block as given
+            # rows, the rest is generated again), the pass count goes on
+            block_passes=s.block.passes if s.block is not None else 0)
         s.req = None
         s.active = False
+        s.block = None
+        self._io = None
         s.generated = []
         s.counts = None
         s.counts_out = None
@@ -4631,6 +5077,8 @@ class LLMEngine:
                     ("admit" if self._admitting is not None else "cp")
                     if did else None, 0)
             return did
+        if self._block is not None:
+            return self._block_step(active)
         return self._decode_step(active)
 
     def _sweep_step(self) -> Tuple[bool, bool]:
@@ -4737,22 +5185,12 @@ class LLMEngine:
         # tokens: only from a packed step of its own choosing, over the
         # slots that step held
         lead = resident or verify
-        ahead, self._ahead = self._ahead, None
+        ahead, active, whole = self._take_ahead(active)
         if ahead is not None:
-            # the step that went out during the last one IS this step for
-            # the slots whose requests it held and that are still here (a
-            # slot admitted since waits a step; one that ended since is
-            # read no further); brownout, a fault clause or a host-sampled
-            # newcomer take effect with the next step
-            held = dict(ahead[0])
-            stay = [i for i in active if held.get(i) is self.slots[i].req]
-            if stay:
-                lead = lead and len(stay) == len(active) == len(held)
-                active = stay
-                verify, resident = self._mtp, not self._mtp
-            else:
-                self._m_vain_steps.inc()
-                ahead = None
+            # brownout, a fault clause or a host-sampled newcomer take
+            # effect with the next step
+            lead = lead and whole
+            verify, resident = self._mtp, not self._mtp
         rows_a_slot = 2 if verify else 1
         read_ahead = ahead is not None
         if self._dsa is not None:
@@ -4836,28 +5274,8 @@ class LLMEngine:
                 # another decode program, sent and waited for here
                 self._sent_decode("in_step", self._sampler_path(active))
             if verify or resident:
-                if ahead is not None:
-                    holders, out_dev, ints_dev, floats_dev = ahead
-                else:
-                    holders, ints_dev, floats_dev = self._step_args(active)
-                    out_dev, ints_dev = self._send_step(
-                        verify, active, ints_dev, floats_dev)
-                if lead and self._may_lead(active, rows_a_slot):
-                    # the NEXT step goes out now, on what this one
-                    # leaves on the device, so the device does not wait
-                    # while the host reads and emits this step's tokens.
-                    # What it holds of a slot that this step ends is
-                    # never read (`_finish` sets the slot's `pos` back)
-                    out_next, ints_next = self._send_step(
-                        verify, active, ints_dev, floats_dev, ahead=True)
-                    self._ahead = (holders, out_next, ints_next, floats_dev)
-                    del out_next, ints_next
-                elif ahead is None or lead:
-                    io_next = (holders, ints_dev, floats_dev)
-                # let go inside the phase, while the program runs (not
-                # at the frame's exit, after the wait, where the device
-                # idles)
-                del ints_dev, floats_dev
+                out_dev, io_next = self._dispatch_packed(
+                    verify, active, ahead, lead, rows_a_slot)
                 ahead = None
             elif self._mtp:
                 with ph("dispatch.h2d", child=True):
@@ -5051,12 +5469,7 @@ class LLMEngine:
                     if going:
                         self._mtp_rows_after(going, hidden_dev)
                 hidden_dev = None
-            if io_next is not None and len(active) == len(
-                    io_next[0]) and all(
-                    self.slots[i].active for i in active):
-                # every slot goes on: the device's view of the next
-                # step stands
-                self._io = io_next
+            self._keep_io(io_next, active)
             io_next = None
         if self._ahead is None:
             self._send_ahead()
@@ -5073,62 +5486,175 @@ class LLMEngine:
                         qrows = self._host_quality_rows(logits, q_meta)
                     if qrows is not None:
                         self._quality_observe(qrows, q_meta)
-            with ph("observe.slo", child=True):
-                # one batched step advances every active stream by the
-                # tokens it was given (one; one or two of a verify
-                # step), so a stream's time-per-output-token is the
-                # step's wall time over them. A step that went out
-                # ahead ran under the last step's emit and observe: what
-                # its tokens took is the time since that step's were
-                # timed, not the rest of the wait this step saw
-                now = time.perf_counter()
-                span_s = now - t_decode0
-                dt = now - self._t_step_timed if read_ahead else span_s
-                self._t_step_timed = now
-                # each stream's TPOT samples for its QoS class, one a
-                # token
-                for q, n in zip(step_qos, step_tokens):
-                    for _ in range(n):
-                        self.slo.observe_tpot(q, dt / n)
-                # the queue-wait admission test's estimate: every step
-                self._tpot_ewma = stats_ewma(self._tpot_ewma or None, dt)
-                # the brownout latency-inflation signal: EWMA over its
-                # observed floor, of the steps that measure the decode
-                # alone. A decode dispatched behind an admission chunk
-                # still in flight measures the chunk too, i.e. how long
-                # the prompt is (a chunk is 3 decodes at 7B, PERF.md PR
-                # 26); a last chunk was waited for before the decode
-                # went out (no step is sent ahead behind a last chunk:
-                # `_may_lead`). A sample counts for no more than the
-                # ratio at which the signal saturates: one step of many
-                # floors (an executable's first load) is not inflation,
-                # a run of them still fills the signal
-                if self._admitting is None:
-                    self._decode_ewma = stats_ewma(
-                        self._decode_ewma or None,
-                        min(dt, _INFLATION_SATURATES
-                            * (self._decode_floor or dt)))
-                    if (self._decode_floor is None
-                            or self._decode_ewma < self._decode_floor):
-                        self._decode_floor = self._decode_ewma
-                self._dispatch_ewma = stats_ewma(
-                    self._dispatch_ewma or None, dispatch_s)
-            # stage the roofline/sentinel sample for step() to finalize
-            # with the FULL step wall time (fault sleeps happen before
-            # this method's timing bracket)
-            # rows the step computed (both of a verify step's), not
-            # tokens given
-            self._pending_perf = (len(active) * rows_a_slot, perf_seq_len)
-            # one decode_step span per distinct trace among active slots
-            with ph("observe.spans", child=True):
-                for tid, (rid, parent_sid) in traced.items():
-                    self.spans.record(
-                        "decode_step", tid,
-                        parent_id=parent_sid,
-                        t_start=t_wall0, t_end=t_wall0 + span_s,
-                        step=self._step_idx, request_id=rid,
-                        dispatch_ms=round(dispatch_s * 1000.0, 3),
-                        device_ms=round(device_s * 1000.0, 3))
+            self._observe_tokens(
+                read_ahead, t_decode0, t_wall0, dispatch_s, device_s,
+                step_qos, step_tokens, traced,
+                len(active) * rows_a_slot, perf_seq_len)
+            self._observe_step("decode", len(active))
+        return True
+
+    def _observe_tokens(self, read_ahead: bool, t_decode0: float,
+                        t_wall0: float, dispatch_s: float, device_s: float,
+                        step_qos: List[str], step_tokens: List[int],
+                        traced, rows: int, perf_seq_len: int) -> None:
+        """The close of a step that decoded (a plain or a verify step,
+        or a block family's pass), inside its observe phase: the SLO's
+        time per output token for each stream's tokens, the step-time
+        averages, the staged roofline sample over the `rows` the step
+        computed, and one `decode_step` span a traced request."""
+        ph = self.phases.phase
+        with ph("observe.slo", child=True):
+            # one batched step advances every active stream by the
+            # tokens it was given (one; one or two of a verify
+            # step), so a stream's time-per-output-token is the
+            # step's wall time over them. A step that went out
+            # ahead ran under the last step's emit and observe: what
+            # its tokens took is the time since that step's were
+            # timed, not the rest of the wait this step saw
+            now = time.perf_counter()
+            span_s = now - t_decode0
+            dt = now - self._t_step_timed if read_ahead else span_s
+            self._t_step_timed = now
+            # each stream's TPOT samples for its QoS class, one a
+            # token
+            for q, n in zip(step_qos, step_tokens):
+                for _ in range(n):
+                    self.slo.observe_tpot(q, dt / n)
+            # the queue-wait admission test's estimate: every step
+            self._tpot_ewma = stats_ewma(self._tpot_ewma or None, dt)
+            # the brownout latency-inflation signal: EWMA over its
+            # observed floor, of the steps that measure the decode
+            # alone. A decode dispatched behind an admission chunk
+            # still in flight measures the chunk too, i.e. how long
+            # the prompt is (a chunk is 3 decodes at 7B, PERF.md PR
+            # 26); a last chunk was waited for before the decode
+            # went out (no step is sent ahead behind a last chunk:
+            # `_may_lead`). A sample counts for no more than the
+            # ratio at which the signal saturates: one step of many
+            # floors (an executable's first load) is not inflation,
+            # a run of them still fills the signal
+            if self._admitting is None:
+                self._decode_ewma = stats_ewma(
+                    self._decode_ewma or None,
+                    min(dt, _INFLATION_SATURATES
+                        * (self._decode_floor or dt)))
+                if (self._decode_floor is None
+                        or self._decode_ewma < self._decode_floor):
+                    self._decode_floor = self._decode_ewma
+            self._dispatch_ewma = stats_ewma(
+                self._dispatch_ewma or None, dispatch_s)
+        # stage the roofline/sentinel sample for step() to finalize
+        # with the FULL step wall time (fault sleeps happen before
+        # this method's timing bracket)
+        # rows the step computed (both of a verify step's), not
+        # tokens given
+        self._pending_perf = (rows, perf_seq_len)
+        # one decode_step span per distinct trace among active slots
+        with ph("observe.spans", child=True):
+            for tid, (rid, parent_sid) in traced.items():
+                self.spans.record(
+                    "decode_step", tid,
+                    parent_id=parent_sid,
+                    t_start=t_wall0, t_end=t_wall0 + span_s,
+                    step=self._step_idx, request_id=rid,
+                    dispatch_ms=round(dispatch_s * 1000.0, 3),
+                    device_ms=round(device_s * 1000.0, 3))
+
+    def _block_step(self, active: List[int]) -> bool:
+        """One pass over the block of each of the ``active`` slots of a
+        family that generates by diffusion over blocks: this family's
+        plain step on the phase clock. The pass is `engine_block_
+        resident` (`_init_block_step`); like the plain resident step it
+        takes all it needs from what the pass before left on the device
+        and goes out one pass ahead (`_ahead`, `_may_lead`). The host
+        reads which rows became final, streams the longest run of final
+        tokens that continues what each stream was sent (zero to `B`
+        tokens an event, in sequence order whatever order they were
+        committed in), and counts a slot's block on when the pass
+        stored it."""
+        ce = self.cfg_engine
+        ph = self.phases.phase
+        b = self._block.length
+        ahead, active, lead = self._take_ahead(active)
+        read_ahead = ahead is not None
+        t_decode0 = time.perf_counter()
+        t_wall0 = time.time()
+        with ph("dispatch"):
+            # mean live cache depth for the roofline sample: a pass
+            # reads up to its block's last row
+            perf_seq_len = max(1, sum(self.slots[i].block.pos + b
+                                      for i in active) // len(active))
+            out_dev, io_next = self._dispatch_packed(False, active, ahead,
+                                                     lead, b)
+            ahead = None
+        with ph("device"):
+            jax.block_until_ready(  # graftlint: disable=step-host-sync
+                out_dev)
+        dispatch_s = self.phases.seconds("dispatch")
+        device_s = self.phases.seconds("device")
+        with ph("sample"):
+            with ph("sample.fetch", child=True):
+                packed = np.asarray(out_dev)           # the one fetch
+            out_dev = None
+            if ce.logits_health_check:
+                sick = [i for i in active if packed[i, b + 1] == 0]
+                for i in sick:
+                    self._quarantine_slot(i, "nan_logits")
+                active = [i for i in active if i not in sick]
+        if not active:          # every row was sick
+            with ph("observe"):
+                self._observe_step("decode", 0)
+            return True
+        traced: Dict[str, Tuple[str, Optional[str]]] = {}
+        step_qos: List[str] = []
+        step_tokens: List[int] = []
+        with ph("emit"):
+            for i in active:
+                s = self.slots[i]
+                at, r = s.block, s.req
+                at.passes += 1
+                if r.trace is not None:
+                    sp = self.tracer.get(r.request_id)
+                    traced.setdefault(
+                        r.trace[0],
+                        (r.request_id,
+                         sp.trace_span if sp is not None else None))
+                if packed[i, b]:
+                    # the storing pass: the block's K/V stand, the next
+                    # block begins all MASK
+                    self._m_block_passes.labels("store").inc()
+                    self._m_blocks.inc()
+                    s.block = _BlockState(at.pos + b, [-1] * b, [0] * b, 0,
+                                          passes=at.passes)
+                    if at.pos + 2 * b > ce.max_seq:
+                        self._finish(i, "length")   # the slab's end
+                    continue
+                self._m_block_passes.labels("denoise").inc()
+                at.s += 1
+                new = [j for j in range(b)
+                       if at.ids[j] < 0 and packed[i, j] >= 0]
+                for j in new:
+                    at.ids[j] = int(packed[i, j])
+                    at.steps[j] = at.passes
+                self._m_block_tokens.inc(len(new))
+                run = at.sent
+                while run < b and at.ids[run] >= 0:
+                    run += 1
+                if run > at.sent:
+                    lo, at.sent = at.sent, run
+                    n = self._emit_run(i, at.ids[lo:run], at.steps[lo:run])
+                    step_qos.append(r.params.qos or "standard")
+                    step_tokens.append(n)
+            self._keep_io(io_next, active)
+            io_next = None
+        if self._ahead is None:
+            self._send_ahead()
+        with ph("observe"):
+            # (a pass advances a stream by the tokens its event carried)
+            self._observe_tokens(
+                read_ahead, t_decode0, t_wall0, dispatch_s, device_s,
+                step_qos, step_tokens, traced, len(active) * b,
+                perf_seq_len)
             self._observe_step("decode", len(active))
         return True
 

@@ -1303,6 +1303,10 @@ def test_latent_append_compiles_in_place(v5e, aot_flags):
     ("moe_routed_decode", 16, False, 1536, 5120),
     ("moe_routed_prefill", 128, False, 5120, 1536),
     ("moe_routed_prefill", 128, False, 1536, 5120),
+    # a block family's pass: 16 slots x 4 rows = DECODE_MAX_TOKENS, at
+    # SDAR-30B-A3B's expert widths (PR 53)
+    ("moe_routed_decode", 64, True, 2048, 768),
+    ("moe_routed_decode", 64, False, 768, 2048),
 ])
 def test_routed_expert_kernel_compiles_on_the_layer_stack(
         v5e, aot_flags, name, t, shared, k, n):
@@ -2033,3 +2037,113 @@ def test_afmoe_prefill_chunk_keeps_no_rows_by_keys_scores(v5e, aot_flags,
     print("afmoe prefill chunk", alloc, "temp GB",
           ma.temp_size_in_bytes / 1e9, "args GB",
           ma.argument_size_in_bytes / 1e9)
+
+
+def _sdar_engine():
+    import json
+    import sys
+    from pathlib import Path
+
+    bench = Path(__file__).resolve().parents[1] / "benchmark"
+    sys.path[:0] = [str(bench)]
+    from harness import weights_sdar_moe as weights
+    from harness.weights import _family_config
+
+    from bigdl_tpu.models import sdar_moe
+    from bigdl_tpu.ops.quant import prepack_tree
+    from bigdl_tpu.serving import EngineConfig, LLMEngine
+
+    doc = json.loads(
+        (bench / "configs" / "sdar-30b-a3b-ep4-int4.json").read_text())
+    family, cfg, hf = _family_config(doc)
+
+    class Model:
+        params = jax.eval_shape(lambda: prepack_tree(
+            sdar_moe.prepare_params(
+                weights.build_params(cfg, "sym_int4", 1), cfg), "on")[0])
+        config, hf_config, qtype = cfg, hf, "sym_int4"
+
+    Model.family = family
+    eng = doc["engine"]
+    return LLMEngine(Model, EngineConfig(
+        max_batch=eng["max_batch"], max_seq=eng["max_seq"],
+        prefill_chunk=eng["prefill_chunk"],
+        prefill_bucket=eng["prefill_bucket"], sentinel=False,
+        quality=False))
+
+
+@pytest.mark.parametrize("all_greedy", [True, False])
+def test_sdar_engine_block_pass_compiles_and_fits(v5e, aot_flags,
+                                                  all_greedy):
+    """The engine's resident BLOCK pass for the cell's configuration
+    (all 48 layers at published widths, 16 slots x 3072, four rows a
+    slot, shapes only): the four rows of a slot are 128 query heads of
+    `decode_attention_lanes`, 64 rows take the routed DECODE kernel, the
+    48 layers are ONE loop, no instruction materializes a layer of a
+    plane or of a quantized stack, no row of the vocabulary is sorted,
+    and arguments plus temporaries, two passes in flight, stay under
+    11 GB."""
+    import re
+
+    dev = v5e.devices[0]
+    eng = _sdar_engine()
+    b, blk = eng.cfg_engine.max_batch, eng._block.length
+    assert (b, blk, b * blk) == (16, 4, 64)
+    lowered = eng._block_resident.lower(
+        _sds(eng.params, dev),
+        _sds(jax.ShapeDtypeStruct((b, 2 * blk + 5), jnp.int32), dev),
+        _sds(jax.ShapeDtypeStruct((2, b), jnp.float32), dev),
+        _sds(jax.eval_shape(lambda: eng.cache), dev),
+        all_greedy=all_greedy)
+    comp = lowered.compile()
+    txt = comp.as_text()
+    for name in ("decode_attention_lanes", "moe_routed_decode",
+                 "qmatmul_"):
+        assert name in txt, name
+    assert "moe_routed_prefill" not in txt
+    ma = comp.memory_analysis()
+    live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    print("sdar block pass: args GB", ma.argument_size_in_bytes / 1e9,
+          "temp GB", ma.temp_size_in_bytes / 1e9, "live GB", live / 1e9,
+          "two in flight GB", _two_in_flight_bytes(ma) / 1e9)
+    assert 8.5e9 < live < 11.0e9, live / 1e9    # 4.8 GB weights + 4.8 slab
+    assert ma.temp_size_in_bytes < 256 * 2 ** 20
+    assert _two_in_flight_bytes(ma) < 11.0e9
+    moved = re.findall(
+        r"= \w+\[(?:1,)?16,3072,512\]\S* (?:copy|fusion|dynamic-slice)\(",
+        txt)
+    assert not moved, f"a layer of a cache plane is materialized: {moved}"
+    assert not re.findall(r"= \w+\[48,16,3072,512\]\S* copy\(", txt)
+    assert not re.findall(r"dynamic-slice\S*\(s4\[48,", txt)
+    assert not re.findall(r"sort\S*\([^)]*\[64,37984\]", txt)
+
+
+def test_sdar_prefill_chunk_is_block_causal_and_keeps_no_wide_scores(
+        v5e, aot_flags):
+    """One 1024-row prefill chunk AS THE ENGINE BUILDS IT into the
+    cell's private cache of 2048 positions: no float32 `[heads, rows,
+    S]` temporary (512-key blocks under the block-causal `live`), the
+    int4 GEMM in every linear and the routed PREFILL kernel."""
+    import re
+
+    from bigdl_tpu.ops.kvcache import init_cache_spec
+
+    dev = v5e.devices[0]
+    eng = _sdar_engine()
+    chunk, alloc = eng._chunk, eng.cfg_engine.prefill_bucket
+    assert (chunk, alloc) == (1024, 2048)
+    cache1 = jax.eval_shape(lambda: init_cache_spec(
+        eng._cache_spec.unrolled(), 1, alloc,
+        kv_cache_dtype=eng.kv_cache_dtype))
+    tokens = jax.ShapeDtypeStruct((1, chunk), jnp.int32)
+    comp = eng._prefill.lower(_sds(eng.params, dev), _sds(tokens, dev),
+                              _sds(cache1, dev)).compile()
+    txt = comp.as_text()
+    assert "qmatmul_gemm_sym_int4" in txt and "moe_routed_prefill" in txt
+    wide = re.findall(rf"f32\[(?:1,)?(?:32|4,8),1024,{alloc}\]", txt)
+    assert not wide, f"[heads, rows, S] in float32: {wide[:3]}"
+    ma = comp.memory_analysis()
+    assert ma.temp_size_in_bytes < 1.0e9, ma.temp_size_in_bytes / 1e9
+    print("sdar prefill chunk temp GB", ma.temp_size_in_bytes / 1e9,
+          "args GB", ma.argument_size_in_bytes / 1e9)

@@ -197,10 +197,12 @@ def test_latent_append_kernel_writes_one_column_a_slot():
     assert sorted(map(tuple, changed)) == [(1, 0, 0), (1, 1, 130)]
 
 
-@pytest.mark.parametrize("n", [5, 32, 200], ids=["few", "batch", "chunk"])
+@pytest.mark.parametrize("n", [5, 32, 64, 200],
+                         ids=["few", "batch", "block_pass", "chunk"])
 def test_routed_kernels_agree_with_the_dense_combine(n):
     """Interpret mode: the decode kernel (n <= 64: tiles are held
-    experts, idle ones skipped) and the sorted prefill kernel (n > 64)
+    experts, idle ones skipped; 64 is a block family's pass of 16 slots
+    x 4 rows) and the sorted prefill kernel (n > 64)
     against every held expert on every token in XLA ops; a share of 4
     of 16 experts starting at expert 8, on a stack of 2 layers."""
     from bigdl_tpu.ops.quant import quantize

@@ -597,7 +597,7 @@ def test_sampler_sortfree_share_reduces_a_scrape_pair_to_the_share():
     file = json.loads(path.read_text())
     assert {k: entry[k] for k in ("unit", "layer", "moves", "source")} == {
         k: file[k] for k in ("unit", "layer", "moves", "source")}
-    assert entry["better"] == "higher" and len(entry["workloads"]) == 8
+    assert entry["better"] == "higher" and len(entry["workloads"]) == 9
     greedy_cells = {"mistral7b-batch-closed", "chatglm2-6b-docqa-shared",
                     "mistral7b-qlora-alpaca"}
     assert not greedy_cells & set(entry["workloads"])

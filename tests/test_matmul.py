@@ -168,6 +168,17 @@ _AFMOE_CHOSE = {
     (1024, 2048): (('gemv_mxu', (1024, 512)), ('gemm', (1024, 512))),
     (2048, 50048): (('gemv_mxu', (2048, 128)), ('gemm', (2048, 128))),
 }
+# SDAR-30B-A3B's linears at a block pass's 64 rows (16 slots x 4) and a
+# 1024-row chunk (PR 53): q / k / v merged, W_o, and the head's slice as
+# SERVED, padded from 37,984 columns to 38,016 (`sdar_moe.pad_head`):
+# every one the GEMM kernel; the unpadded head has no tiling and would
+# take the XLA plan, which dequantizes it whole every pass
+_SDAR_CHOSE = {
+    (2048, 5120): (('gemm', (2048, 512)), ('gemm', (2048, 512))),
+    (4096, 2048): (('gemm', (4096, 512)), ('gemm', (2048, 512))),
+    (2048, 38016): (('gemm', (2048, 128)), ('gemm', (2048, 128))),
+    (2048, 37984): (('xla', None), ('xla', None)),
+}
 _TPU = dict(int4_layout=True, spmd=False, tpu=True)
 _CANON = dict(_TPU, int4_layout=False)
 # the rules, one case each: (qtype, rows, K, N, what the call sees, plan)
@@ -227,7 +238,10 @@ _RULES = [
     for rows, plan in zip((6, 256, 1024), (*at, at[1]))] + [
     ("sym_int4", rows, k, n, _TPU, plan)
     for (k, n), at in {**_MIMO_CHOSE, **_AFMOE_CHOSE}.items()
-    for rows, plan in zip((16, 1024), at)] + _RULES)
+    for rows, plan in zip((16, 1024), at)] + [
+    ("sym_int4", rows, k, n, _TPU, plan)
+    for (k, n), at in _SDAR_CHOSE.items()
+    for rows, plan in zip((64, 1024), at)] + _RULES)
 def test_kernel_selection_table(qtype, rows, k, n, sees, want):
     """`select_matmul` is the one place a quantized linear's plan is
     chosen, from what the call can see; at every linear of the three
