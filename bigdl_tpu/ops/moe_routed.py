@@ -24,14 +24,18 @@ experts' own scores, renormalised (`norm_topk_prob`) and times
 scores the sum of its two largest biased scores, the best `topk_group`
 groups stay and the top-k is taken among their experts.
 
-Two strategies by token count, both one Pallas kernel
-(`ops/pallas/moe_routed.py`), no host sync, no data-dependent shape:
+Two strategies by token count (`ops/pallas/moe_routed.py`), no host
+sync, no data-dependent shape:
 
 - up to `DECODE_MAX_TOKENS` tokens: every hit expert multiplies all the
-  tokens (`moe_routed_decode`); an expert no token chose is skipped,
-  bytes and all;
+  tokens, in two calls: `moe_routed_decode_gate_up` (gate and up of an
+  expert in one sweep, `act(g) * u` times the combine weights from the
+  float32 accumulators) and `moe_routed_decode_down` (the down
+  projection, summed over the experts inside the call); an expert no
+  token chose is skipped, bytes and all, and a hit expert is a grid step
+  or a few (`decode_tiles`: tiles by bytes under a stated VMEM budget);
 - above: the sorted ragged dispatch over the held experts
-  (`moe_routed_prefill`).
+  (`moe_routed_prefill`, one call a matrix).
 
 Where the kernel is not the designed choice (no TPU, a shape that does
 not tile, operands sharded under GSPMD) the dense combine in XLA ops
@@ -125,11 +129,14 @@ def _combine(topi, topw, share: Share):
     return jnp.sum(onehot * topw[..., None], axis=1), mine
 
 
-def _kernel_ok(name, stacks, d, ff, t, shared_x) -> bool:
-    """Whether the Pallas kernel is the designed choice here, probing
-    each (K, N) it would run."""
+def _kernel_ok(name, stacks, d, ff, t) -> bool:
+    """Whether the Pallas kernels are the designed choice here, probing
+    what they would run: the decode pair, or the prefill call at each
+    (K, N)."""
     from bigdl_tpu.config import flags, target_is_tpu, under_spmd
-    from bigdl_tpu.ops.pallas.moe_routed import routed_kernel_compiles
+    from bigdl_tpu.ops.pallas.moe_routed import (DECODE_NAME,
+                                                 routed_decode_compiles,
+                                                 routed_kernel_compiles)
 
     leaves = [a for s in stacks for a in jax.tree_util.tree_leaves(s)]
     if flags().moe_dispatch == "dense" or under_spmd(*leaves):
@@ -142,10 +149,13 @@ def _kernel_ok(name, stacks, d, ff, t, shared_x) -> bool:
         return flags().moe_dispatch == "ragged"     # forced: interpret
     gate, up, down = stacks
     qn = lambda s: s.qtype if isinstance(s, QTensor) else None  # noqa: E731
-    ok = all(routed_kernel_compiles(name, qn(s), kk, nn, t, sx)
-             for s, kk, nn, sx in ((gate, d, ff, shared_x),
-                                   (up, d, ff, shared_x),
-                                   (down, ff, d, False)))
+    if name == DECODE_NAME:
+        ok = (qn(gate) == qn(up)
+              and routed_decode_compiles(qn(gate), qn(down), d, ff, t))
+    else:
+        ok = all(routed_kernel_compiles(name, qn(s), kk, nn, t)
+                 for s, kk, nn in ((gate, d, ff), (up, d, ff),
+                                   (down, ff, d)))
     if not ok:
         from bigdl_tpu.ops.probing import record_dispatch_rule
 
@@ -155,26 +165,21 @@ def _kernel_ok(name, stacks, d, ff, t, shared_x) -> bool:
 
 def _decode(xf, comb, hit, gate, up, down, layer, act, interpret):
     """Every hit expert over all the tokens; tiles are held experts,
-    hit ones first."""
-    from bigdl_tpu.ops.pallas.moe_routed import (DECODE_NAME,
-                                                 routed_expert_matmul)
+    hit ones first. Two calls: gate and up with the activation and the
+    combine weights, then down summed over the experts."""
+    from bigdl_tpu.ops.pallas.moe_routed import (routed_down_sum,
+                                                 routed_gate_up)
 
-    n, d = xf.shape
-    held = comb.shape[1]
+    n = xf.shape[0]
     order = jnp.argsort(~hit, stable=True).astype(jnp.int32)
     n_hit = jnp.sum(hit).astype(jnp.int32)
     npad = -(-n // 16) * 16
     x1 = jnp.pad(xf, ((0, npad - n), (0, 0)))[None]             # [1, Np, D]
-    mm = lambda x, w, shared: routed_expert_matmul(             # noqa: E731
-        x, w, order, n_hit, layer, name=DECODE_NAME, shared_x=shared,
-        interpret=interpret)
-    live = (jnp.arange(held) < n_hit)[:, None, None]
     cw = jnp.pad(comb.T[order], ((0, 0), (0, npad - n)))        # [held, Np]
-    h = (act(mm(x1, gate, True).astype(jnp.float32))
-         * mm(x1, up, True).astype(jnp.float32) * cw[..., None])
-    h = jnp.where(live, h, 0.0).astype(xf.dtype)                # [held,Np,F]
-    y = jnp.where(live, mm(h, down, False).astype(jnp.float32), 0.0)
-    return jnp.sum(y, axis=0)[:n].astype(xf.dtype)
+    h = routed_gate_up(x1, gate, up, cw, order, n_hit, layer, act=act,
+                       interpret=interpret)                     # [held,Np,F]
+    y = routed_down_sum(h, down, order, n_hit, layer, interpret=interpret)
+    return y[:n].astype(xf.dtype)
 
 
 def _prefill(xf, topi, topw, mine, share, gate, up, down, layer, act,
@@ -253,7 +258,7 @@ def routed_experts(xf: jax.Array, router_logits: jax.Array,
         from bigdl_tpu.ops.pallas.moe_routed import DECODE_NAME
 
         if _kernel_ok(DECODE_NAME, (gate, up, down), d, ff,
-                      -(-n // 16) * 16, True):
+                      -(-n // 16) * 16):
             return _decode(xf, comb, hit, gate, up, down, at, act,
                            interpret), stats
     else:
@@ -261,7 +266,7 @@ def routed_experts(xf: jax.Array, router_logits: jax.Array,
                                                      PREFILL_TOKEN_TILE)
 
         if _kernel_ok(PREFILL_NAME, (gate, up, down), d, ff,
-                      PREFILL_TOKEN_TILE, False):
+                      PREFILL_TOKEN_TILE):
             return _prefill(xf, topi, topw, mine, share, gate, up, down,
                             at, act, interpret), stats
     return _dense(xf, comb, gate, up, down, layer, act), stats

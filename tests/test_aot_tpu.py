@@ -1330,6 +1330,51 @@ def test_routed_expert_kernel_compiles_on_the_layer_stack(
     assert comp.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
 
 
+@pytest.mark.parametrize("held,t,d,f", [
+    (32, 64, 2048, 768),        # sdar-30b-ep4-fixedlen-closed (a block pass)
+    (32, 16, 2048, 1024),       # trinity-mini-ep4-thinking-closed
+    (32, 16, 4096, 2048),       # mimo-v25-ep8-mixedlen-closed
+    (20, 32, 5120, 1536),       # deepseekv2-ep8-reason-closed
+    (32, 16, 5120, 1536),       # dots3-ep8-longdoc-closed
+    (32, 16, 7168, 2048),       # deepseekv32-ep8-reason-closed (verify rows)
+    (20, 64, 5120, 1536),       # the decode path's most rows
+    (32, 64, 7168, 2048),
+], ids=["sdar", "trinity", "mimo", "deepseek_v2", "dots3", "deepseek_v32",
+        "deepseek_v2_64_rows", "deepseek_v32_64_rows"])
+def test_routed_decode_pair_compiles_at_the_cells_geometries(
+        v5e, aot_flags, held, t, d, f):
+    """The decode pair (`moe_routed_decode_gate_up`, `..._down`) at the
+    six routed cells' decode geometry and `decode_tiles`' plan, over
+    `[3, held, ...]` int4 stacks addressed by layer: a working set over
+    the scoped-VMEM limit the calls ask for fails HERE, not in a cell;
+    the layer's result is `[T, D]`, and XLA holds nothing `[held, T, *]`
+    but the one product between the calls."""
+    from bigdl_tpu.ops.pallas import moe_routed as kernels
+    from bigdl_tpu.ops.probing import quant_struct, stacked_struct
+
+    dev = v5e.devices[0]
+    st = lambda k, n: stacked_struct(stacked_struct(            # noqa: E731
+        quant_struct(k, n, "sym_int4"), held), 3)
+
+    def layer(x, gate, up, down, cw, te, na, lyr):
+        h = kernels.routed_gate_up(x, gate, up, cw, te, na, lyr,
+                                   act=jax.nn.silu)
+        return kernels.routed_down_sum(h, down, te, na, lyr)
+
+    comp = _compile(
+        layer, _sds(jax.ShapeDtypeStruct((1, t, d), jnp.bfloat16), dev),
+        _sds(st(d, f), dev), _sds(st(d, f), dev), _sds(st(f, d), dev),
+        _sds(jax.ShapeDtypeStruct((held, t), jnp.float32), dev),
+        _sds(jax.ShapeDtypeStruct((held,), jnp.int32), dev),
+        _sds(jax.ShapeDtypeStruct((), jnp.int32), dev),
+        _sds(jax.ShapeDtypeStruct((), jnp.int32), dev))
+    txt = comp.as_text()
+    assert kernels.GATE_UP_NAME in txt and kernels.DOWN_NAME in txt
+    assert _has_mosaic_call(comp)
+    assert comp.memory_analysis().temp_size_in_bytes <= held * t * f * 2 + 4096
+    assert comp.memory_analysis().output_size_in_bytes < t * d * 2 + 4096
+
+
 def _row_scatters(txt, d):
     """Scatters of a compiled program (alone or as the root of a fusion:
     either way the instruction is in the text) whose result, hence whose

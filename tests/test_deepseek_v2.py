@@ -197,8 +197,9 @@ def test_latent_append_kernel_writes_one_column_a_slot():
     assert sorted(map(tuple, changed)) == [(1, 0, 0), (1, 1, 130)]
 
 
-@pytest.mark.parametrize("n", [5, 32, 64, 200],
-                         ids=["few", "batch", "block_pass", "chunk"])
+@pytest.mark.parametrize("n", [5, 32, 48, 64, 200],
+                         ids=["few", "batch", "three_row_tiles", "block_pass",
+                              "chunk"])
 def test_routed_kernels_agree_with_the_dense_combine(n):
     """Interpret mode: the decode kernel (n <= 64: tiles are held
     experts, idle ones skipped; 64 is a block family's pass of 16 slots
@@ -235,6 +236,159 @@ def test_routed_kernels_agree_with_the_dense_combine(n):
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     assert int(s1[0] + s1[1]) == 3 * n and int(s1[3]) == 1
     assert 0 < int(s1[2]) <= held
+
+
+# (T, D, F) of the six routed cells' decode programs (PR 54)
+DECODE_GEOMETRIES = {
+    "sdar": (64, 2048, 768), "trinity": (16, 2048, 1024),
+    "mimo": (16, 4096, 2048), "deepseek_v2": (32, 5120, 1536),
+    "dots3": (16, 5120, 1536), "deepseek_v32": (16, 7168, 2048)}
+
+
+@pytest.mark.parametrize("cell", list(DECODE_GEOMETRIES))
+@pytest.mark.parametrize("call", ["gate_up", "down"])
+def test_decode_tile_rule_at_the_cells_geometries(cell, call):
+    """The decode plan is by bytes: a legal `(bk, bn)` under the stated
+    budget at the cell's rows AND at the most rows the decode path takes
+    (64), `bk` whole quant blocks, scale-row tiles and packed-row tiles;
+    an expert matrix of about a megabyte (SDAR's two, Trinity's) is ONE
+    grid step, every other step moves a megabyte or more of packed
+    weights, and the prefill call's tiles are the ones it had."""
+    from bigdl_tpu.ops.pallas import moe_routed as kernels
+    from bigdl_tpu.ops.quant import get_qtype
+
+    t, d, f = DECODE_GEOMETRIES[cell]
+    k, n, stacks = (d, f, 2) if call == "gate_up" else (f, d, 1)
+    b = get_qtype("sym_int4").block_size
+    for rows in (t, 64):
+        bk, bn = kernels.decode_tiles("sym_int4", k, n, rows, stacks)
+        assert k % bk == 0 and n % bn == 0 and bn % 128 == 0
+        assert bk % b == 0
+        assert bk == k or ((bk // b) % 16 == 0 and (bk // 2) % 32 == 0)
+        assert kernels._decode_step_bytes(
+            "sym_int4", k, rows, bk, bn, stacks) <= kernels.DECODE_VMEM_BUDGET
+        assert kernels.DECODE_VMEM_BUDGET < kernels.DECODE_VMEM_LIMIT
+        cn = kernels._chunk_lanes(bk, bn)
+        assert bn % cn == 0 and cn % 128 == 0
+        if k * n <= 2048 * 1024:
+            assert (bk, bn) == (k, n)
+        else:
+            assert stacks * bk * bn // 2 >= 2 ** 20
+    assert kernels.routed_tiles("sym_int4", k, n) == (
+        max(c for c in (2048, 1024, 512, 256, 128) if k % c == 0),
+        max(c for c in (512, 256, 128) if n % c == 0))
+
+
+def test_decode_tile_rule_refuses_what_does_not_tile():
+    from bigdl_tpu.ops.pallas import moe_routed as kernels
+
+    assert kernels.decode_tiles("sym_int4", 2048, 200, 16) is None
+    assert kernels.decode_tiles("sym_int4", 2040, 256, 16) is None
+    assert kernels.decode_tiles(None, 64, 128, 16, 2) == (64, 128)
+    # a K that no part of divides legally is one block, or nothing
+    assert kernels.decode_tiles("sym_int4", 96, 128, 16) == (96, 128)
+    # the probes answer a shape without a tiling by rule, compiling nothing
+    assert not kernels.routed_decode_compiles("sym_int4", "sym_int4",
+                                              2048, 200, 16)
+    assert not kernels.routed_decode_compiles(None, None, 256, 128, 8)
+    assert not kernels.routed_kernel_compiles(kernels.PREFILL_NAME,
+                                              "sym_int4", 2040, 256, 128)
+
+
+def _tiny_stacks(key, held, d, f, quantized=True):
+    from bigdl_tpu.ops.quant import quantize
+
+    def stack(kk, kd, nd):
+        w = jax.random.normal(kk, (2, held, kd, nd)) * 0.05
+        if not quantized:
+            return w.astype(jnp.bfloat16)
+        return jax.vmap(jax.vmap(lambda a: quantize(a, "sym_int4")))(w)
+
+    k = jax.random.split(key, 3)
+    return stack(k[0], d, f), stack(k[1], d, f), stack(k[2], f, d)
+
+
+@pytest.mark.parametrize("n_active", [3, 6, 0],
+                         ids=["idle_tiles", "every_tile_hit", "all_idle"])
+@pytest.mark.parametrize("quantized", [True, False],
+                         ids=["sym_int4", "dense"])
+def test_fused_gate_up_equals_the_two_call_form(n_active, quantized,
+                                                monkeypatch):
+    """`routed_gate_up` (interpret mode, layer 1 of a `[2, 6, ...]`
+    stack, the plan forced to several K and N blocks and several chunks a
+    block) against gate and up as two `routed_expert_matmul` calls with
+    the product in XLA ops: equal within bf16 rounding on the active
+    tiles; tiles past `n_active` are nobody's to read. The down call over
+    the fused result equals the masked sum of the two-call form's."""
+    from bigdl_tpu.ops.pallas import moe_routed as kernels
+
+    held, t, d, f = 6, 32, 1024, 512
+    gate, up, down = _tiny_stacks(jax.random.PRNGKey(5), held, d, f,
+                                  quantized)
+    kx, kc = jax.random.split(jax.random.PRNGKey(6))
+    x1 = jax.random.normal(kx, (1, t, d), jnp.bfloat16)
+    cw = jax.random.uniform(kc, (held, t), jnp.float32, 0.0, 2.0)
+    cw = cw * (cw > 0.7)
+    order = jnp.asarray([4, 1, 5, 0, 2, 3], jnp.int32)
+    na = jnp.int32(n_active)
+    monkeypatch.setattr(kernels, "DECODE_VMEM_BUDGET", 1300 * 1024)
+    monkeypatch.setattr(kernels, "DECODE_CHUNK_ELEMS", 64 * 1024)
+    qn = "sym_int4" if quantized else None
+    gu = kernels.decode_tiles(qn, d, f, t, 2)
+    dn = kernels.decode_tiles(qn, f, d, t)
+    assert d // gu[0] > 1 and f // gu[1] > 1 and d // dn[1] > 1
+    assert kernels._chunk_lanes(*dn) < dn[1]
+    mm = lambda x, w, shared: kernels.routed_expert_matmul(     # noqa: E731
+        x, w, order, na, 1, name=kernels.DECODE_NAME, shared_x=shared,
+        interpret=True)
+    live = (jnp.arange(held) < na)[:, None, None]
+    want_h = (jax.nn.silu(mm(x1, gate, True).astype(jnp.float32))
+              * mm(x1, up, True).astype(jnp.float32) * cw[..., None])
+    want_h = jnp.where(live, want_h, 0.0).astype(jnp.bfloat16)
+    want_y = jnp.sum(jnp.where(
+        live, mm(want_h, down, False).astype(jnp.float32), 0.0), axis=0)
+    # the jitted calls read the plan at trace time
+    h = kernels.routed_gate_up.__wrapped__(
+        x1, gate, up, cw, order, na, 1, act=jax.nn.silu, interpret=True)
+    y = kernels.routed_down_sum.__wrapped__(h, down, order, na, 1,
+                                            interpret=True)
+    got_h = jnp.where(live, h, 0.0)
+    np.testing.assert_allclose(np.asarray(got_h, np.float32),
+                               np.asarray(want_h, np.float32),
+                               atol=0.02, rtol=0.02)
+    assert h.shape == (held, t, f) and y.shape == (t, d)
+    if not n_active:
+        assert not np.asarray(y, np.float32).any()
+        return
+    # one rounding of the float32 sum against a rounding an expert
+    err = jnp.linalg.norm(y.astype(jnp.float32) - want_y)
+    assert float(err / jnp.linalg.norm(want_y)) < 0.01
+
+
+@pytest.mark.parametrize("hit", [[1, 3], [0, 1, 2, 3], []],
+                         ids=["two_hit", "all_hit", "none_hit"])
+def test_decode_pair_equals_the_dense_combine(hit):
+    """`_decode` (interpret mode) on hand-made combine weights against
+    `_dense`, within the layer tests' tolerance: experts nobody chose
+    between hit ones, every expert hit, and no held expert hit (zeros),
+    at 20 tokens (padded to 32 rows) on layer 1 of the stacks."""
+    held, n, d, f = 4, 20, 256, 128
+    gate, up, down = _tiny_stacks(jax.random.PRNGKey(7), held, d, f)
+    x = jax.random.normal(jax.random.PRNGKey(8), (n, d), jnp.bfloat16)
+    comb = np.zeros((n, held), np.float32)
+    rng = np.random.default_rng(0)
+    for e in hit:
+        rows = rng.choice(n, 7, replace=False)
+        comb[rows, e] = rng.uniform(0.2, 2.0, 7)
+    comb = jnp.asarray(comb)
+    want = moe_routed._dense(x, comb, gate, up, down, 1, jax.nn.silu)
+    got = moe_routed._decode(x, comb, jnp.any(comb != 0.0, axis=0), gate, up,
+                             down, 1, jax.nn.silu, True)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=0.02)
+    if not hit:
+        assert not np.asarray(got, np.float32).any()
 
 
 def _choices(case):
