@@ -1,0 +1,18 @@
+"""Per-layer metric ``setup_lower_s``: seconds the process spent lowering
+traced programs to MLIR modules before the window
+(``bigdl_tpu_jit_stage_seconds_total{stage="lower"}``, every ``fn``).
+
+Read at the window's start, when set-up is over (``harness/
+startup_account.py``). A program without the account reads nothing.
+"""
+
+from harness import startup_account
+
+LAYER = "start-up"
+SOURCE = "program_span"
+UNIT = "s"
+MOVES = "setup_s"
+
+
+def read(obs):
+    return startup_account.stage_seconds(obs, "lower")

@@ -34,7 +34,14 @@ standard library — tests/test_observability.py enforces it):
   compile accounting (count, wall time, abstract-shape signature per
   executable) feeding the jit metrics below, a process-wide
   ``compile_table()``, and a recompile-storm warning past
-  ``$BIGDL_TPU_RECOMPILE_WARN`` compiles per name.
+  ``$BIGDL_TPU_RECOMPILE_WARN`` compiles per name. It also keeps the
+  START-UP ACCOUNT: every first call by stage (trace, lower, compile
+  or cache load, memory analysis, first run) from JAX's own monitoring
+  events, booked to the program open on the calling thread or to
+  ``fn="untracked"``, a ``compile.<fn>`` span, the timeline
+  ``startup_snapshot()`` serves (``/v1/stats`` ``startup``, postmortem
+  dumps) and the start-up marks on the process's own clock
+  (``mark()``, ``process_age_s()``).
 - ``memory``: ``MemoryLedger`` — exact static HBM accounting
   (packed weight / KV-cache / adapter bytes registered at build and
   allocation time) plus live ``device.memory_stats()`` telemetry
@@ -151,6 +158,14 @@ bigdl_tpu_kv_cache_bytes{dtype,component}   ops/kvcache.publish_kv_cache_bytes
 bigdl_tpu_kv_dequant_path_total{dtype,path} ops/attention._note_dequant_path
 bigdl_tpu_jit_compiles_total{fn}            compile_watch.TrackedJit
 bigdl_tpu_jit_compile_seconds{fn}           compile_watch.TrackedJit
+bigdl_tpu_jit_stage_seconds_total{fn,stage} compile_watch.TrackedJit (a
+                                            first call) and its listener
+                                            (fn="untracked")
+bigdl_tpu_compile_cache_requests_total{fn,outcome}  the same
+bigdl_tpu_startup_mark_seconds{mark}        compile_watch.mark:
+                                            LLMEngine.__init__ /
+                                            add_request / _obs_first_token,
+                                            OpenAIServer.serve, TrackedJit
 bigdl_tpu_hbm_bytes{kind}                   memory.MemoryLedger.publish
 bigdl_tpu_hbm_headroom_bytes                memory.MemoryLedger.publish
 bigdl_tpu_admission_deferred_total{reason}  LLMEngine._admission_step
@@ -172,10 +187,29 @@ tracked executable name (one per new abstract shape signature — e.g.
 one per (prefill bucket, kv dtype) pair for ``engine_prefill``);
 ``bigdl_tpu_jit_compile_seconds{fn}`` holds the first-call wall time
 of each. A steadily incrementing compile counter in steady state IS the
-recompile-storm signature these exist to catch. Each first compile
-also captures ``compiled.memory_analysis()`` (temp/argument/output
-bytes) via an AOT lower+compile of the same signature; set
-``BIGDL_TPU_COMPILE_MEMORY=0`` to skip that extra compile.
+recompile-storm signature these exist to catch.
+``bigdl_tpu_jit_stage_seconds_total{fn,stage}`` takes that wall time
+apart, exclusive seconds by ``stage``: ``trace``, ``lower``,
+``compile`` (a backend compile: the persistent cache missed),
+``cache_load`` (the same bracket on a hit), ``memory_analysis`` (the
+capture below, when asked for) and ``first_run`` (the call's wall less
+the rest); ``bigdl_tpu_compile_cache_requests_total{fn,outcome=hit|
+miss}`` counts what the persistent cache did. ``fn="untracked"`` holds
+JAX's compile events on a thread with no tracked first call open
+(eager ops, a model's own ``jax.jit``; default registry only). A warm
+replica reads ``compile`` near 0 and ``hit`` for every ``engine_*``
+program. ``bigdl_tpu_startup_mark_seconds{mark}`` is the process's age
+(seconds since it started: ``CLOCK_BOOTTIME`` less ``/proc/self/stat``'s
+start time, 10 ms; else since ``bigdl_tpu`` was imported) at
+``engine_init_begin``, ``engine_init_end``, ``listening``,
+``first_request``, ``first_token`` (each set once) and
+``last_compile_end`` (moved by every first call); ``/health`` carries
+``age_s`` and ``first_token_s`` from the same clock. With
+``BIGDL_TPU_COMPILE_MEMORY=1`` each first compile also captures
+``compiled.memory_analysis()`` (temp/argument/output bytes) via an AOT
+lower+compile of the same signature: off by default since PR 55, for
+it can lower and compile or load every program a second time on the
+way to readiness (booked as ``stage="memory_analysis"``).
 
 ``bigdl_tpu_hbm_bytes{kind}`` carries both the ledger's static sums
 per kind ("weights", "kv_cache", ...) and the device telemetry rows
@@ -196,7 +230,7 @@ default 8), ``BIGDL_TPU_HBM_BUDGET_FRACTION`` (admission budget as a
 fraction of ``bytes_limit``, float in (0, 1], default 0.9),
 ``BIGDL_TPU_MEMORY_POLL_SEC`` (min seconds between live
 ``memory_stats()`` reads, default 1.0), ``BIGDL_TPU_COMPILE_MEMORY``
-(set 0 to skip per-compile memory analysis),
+(set 1 for per-compile memory analysis; default off),
 ``BIGDL_TPU_SLO_SPEC`` (JSON SLO spec override),
 ``BIGDL_TPU_SLO_ALERT_LOG`` (burn-alert JSONL sink),
 ``BIGDL_TPU_USAGE_LOG`` (per-request usage ledger). All are validated

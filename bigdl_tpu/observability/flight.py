@@ -172,9 +172,11 @@ def build_postmortem(reason: str, *, flight: Optional[FlightRecorder] = None,
         except Exception as e:
             out["metrics"] = {"error": repr(e)}
     try:
-        from bigdl_tpu.observability.compile_watch import compile_table
+        from bigdl_tpu.observability.compile_watch import (
+            compile_table, startup_snapshot)
 
         out["compile_table"] = compile_table()
+        out["startup"] = startup_snapshot()
     except Exception as e:
         out["compile_table"] = {"error": repr(e)}
     if memory is not None:

@@ -341,7 +341,8 @@ def memory_report(ledger: Optional[MemoryLedger] = None) -> dict:
     ``hbm_static_total_bytes`` (registered allocations),
     ``hbm_device_peak_bytes`` (live peak, absent on CPU), and
     ``jit_peak_temp_bytes`` (largest per-executable scratch from the
-    compile table's memory analysis)."""
+    compile table's memory analysis: 0 unless the process ran with
+    ``BIGDL_TPU_COMPILE_MEMORY=1``)."""
     led = ledger if ledger is not None else default_ledger()
     out = led.snapshot()
     out["hbm_static_total_bytes"] = out["static"]["total_bytes"]

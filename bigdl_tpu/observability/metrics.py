@@ -44,6 +44,13 @@ module and observability/__init__ for the field mapping):
     bigdl_tpu_requests_finished_total{reason=...}                counter
     bigdl_tpu_engine_steps_total / bigdl_tpu_tokens_generated_total
     bigdl_tpu_kernel_probe_total{kernel=...,outcome=...}         counter
+    bigdl_tpu_jit_stage_seconds_total{fn,stage=trace|lower|compile|
+        cache_load|memory_analysis|first_run} (compile_watch: a first
+        call's exclusive seconds; fn=untracked outside one)      counter
+    bigdl_tpu_compile_cache_requests_total{fn,outcome=hit|miss}  counter
+    bigdl_tpu_startup_mark_seconds{mark=engine_init_begin|
+        engine_init_end|listening|first_request|first_token|
+        last_compile_end} (seconds since the process started)    gauge
     bigdl_tpu_spec_accept_ratio{mode=draft|lookup|mtp}           histogram
     bigdl_tpu_mtp_drafts_total{outcome=accepted|rejected}        counter
     bigdl_tpu_mtp_slot_steps_total{kind=verify|plain}            counter

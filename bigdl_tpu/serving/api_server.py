@@ -57,7 +57,9 @@ from typing import Any, List, Optional
 
 import numpy as np
 
-from bigdl_tpu.observability.compile_watch import compiles_in_progress
+from bigdl_tpu.observability.compile_watch import (compiles_in_progress,
+                                                   startup_snapshot)
+from bigdl_tpu.observability.compile_watch import mark as startup_mark
 from bigdl_tpu.observability.disttrace import (make_traceparent,
                                                new_span_id,
                                                parse_traceparent)
@@ -1108,20 +1110,25 @@ class OpenAIServer:
                     # not wedged, or every cold replica gets killed
                     # mid-compile by its supervisor.
                     age = server.engine.step_heartbeat_age()
+                    # every body says how old the process is and when
+                    # it emitted its first token (None before it): an
+                    # operator's cold start, read where readiness is
+                    started = startup_snapshot(programs=False)
+                    ages = {"age_s": started["process_age_s"],
+                            "first_token_s":
+                            started["marks"].get("first_token")}
                     if server.engine.draining:
-                        self._json(503, {"status": "draining"})
+                        self._json(503, {"status": "draining", **ages})
                     elif server.engine.has_unfinished() \
                             and age > server.wedge_sec:
+                        ages["heartbeat_age_sec"] = round(age, 3)
                         if compiles_in_progress():
                             self._json(200, {"status": "compiling",
-                                             "heartbeat_age_sec":
-                                             round(age, 3)})
+                                             **ages})
                         else:
-                            self._json(503, {"status": "wedged",
-                                             "heartbeat_age_sec":
-                                             round(age, 3)})
+                            self._json(503, {"status": "wedged", **ages})
                     else:
-                        self._json(200, {"status": "ok"})
+                        self._json(200, {"status": "ok", **ages})
                 elif self.path == "/metrics":
                     body = server.engine.registry.render().encode()
                     self.send_response(200)
@@ -1678,6 +1685,7 @@ class OpenAIServer:
     def serve(self, host: str = "127.0.0.1", port: int = 8000,
               background: bool = False) -> ThreadingHTTPServer:
         self._httpd = ThreadingHTTPServer((host, port), self.make_handler())
+        startup_mark("listening", self.engine.registry)     # bound
         if background:
             t = threading.Thread(target=self._httpd.serve_forever,
                                  daemon=True)

@@ -52,8 +52,10 @@ from bigdl_tpu.config import (decode_resident_enabled, flags,
 from bigdl_tpu.observability import roofline
 from bigdl_tpu.observability.compile_watch import (annotate_costs,
                                                    compiles_in_progress,
+                                                   declare_startup_metrics,
                                                    top_offenders,
                                                    tracked_jit)
+from bigdl_tpu.observability.compile_watch import mark as startup_mark
 from bigdl_tpu.observability.quality import (GOLDEN_PROBE_PROMPTS,
                                              QUALITY_METRICS,
                                              QualitySentinel,
@@ -615,6 +617,7 @@ class LLMEngine:
                  ledger: Optional[MemoryLedger] = None,
                  memory_stats_provider: Optional[Callable[[], dict]] = None,
                  faults: Optional[FaultInjector] = None):
+        t_init = time.perf_counter()    # the engine_init_begin mark
         self.cfg_engine = config or EngineConfig()
         self.params = model.params
         self.cfg = model.config
@@ -750,6 +753,12 @@ class LLMEngine:
         # sites is safe.
         self.registry = registry if registry is not None \
             else default_registry()
+        # the start-up account (observability/compile_watch.py): its
+        # families render from scrape 1, its marks as they are reached
+        declare_startup_metrics(self.registry)
+        startup_mark("engine_init_begin", self.registry, t_init)
+        # each set once, by a flag a request checks, none read in a step
+        self._mark_first_request = self._mark_first_token = True
         self.tracer = tracer if tracer is not None else RequestTracer()
         # distributed-trace span store (observability/disttrace.py):
         # per-request queue_wait/prefill/decode spans and per-step
@@ -1626,11 +1635,15 @@ class LLMEngine:
             prefix_sharing=self.radix is not None,
             prefill_chunk=self._chunk, family=getattr(
                 self.family, "name", type(self.family).__name__))
+        startup_mark("engine_init_end", self.registry)
 
     # -- public api ---------------------------------------------------------
 
     def add_request(self, request_id: str, prompt_token_ids, params=None,
                     trace=None, resume=None):
+        if self._mark_first_request:
+            self._mark_first_request = False
+            startup_mark("first_request", self.registry)
         if self._draining:
             raise EngineDraining(
                 "engine is draining (admission stopped); retry against "
@@ -3903,6 +3916,9 @@ class LLMEngine:
         span = self.tracer.get(rid)
         just_first = span is not None and span.t_first_token is None
         self.tracer.first_token(rid)
+        if self._mark_first_token:
+            self._mark_first_token = False
+            startup_mark("first_token", self.registry)
         if just_first and span.ttft_s is not None:
             self._m_ttft.observe(span.ttft_s)
             meta = self._usage_meta.get(rid)
@@ -4083,7 +4099,8 @@ class LLMEngine:
         """JSON-ready engine state for `GET /v1/stats`: live occupancy,
         queue depths, metric summaries, recent request spans, and the
         jit compile table."""
-        from bigdl_tpu.observability.compile_watch import compile_table
+        from bigdl_tpu.observability.compile_watch import (
+            compile_table, startup_snapshot)
 
         return {
             "slots": {"total": len(self.slots),
@@ -4139,6 +4156,8 @@ class LLMEngine:
             "metrics": self.registry.summary(),
             "requests": self.tracer.snapshot(),
             "compile_table": compile_table(),
+            # marks and first calls by stage, on one clock
+            "startup": startup_snapshot(),
             "memory": self.memory_snapshot(),
             "overload": self._overload_snapshot(),
             "slo": self.slo.snapshot(),

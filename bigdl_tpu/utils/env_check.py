@@ -216,6 +216,18 @@ def collect() -> dict:
             info["recompile_warn"] = {
                 "value": rw, "valid": False, "error": str(e)}
 
+    # per-compile memory analysis: off unless asked for (default off
+    # since PR 55: the AOT capture can lower and compile or load every
+    # program a second time on the way to readiness); say what a set
+    # value resolves to
+    cm = os.environ.get("BIGDL_TPU_COMPILE_MEMORY")
+    if cm:
+        from bigdl_tpu.observability.compile_watch import \
+            memory_capture_enabled
+
+        info["compile_memory"] = {"value": cm,
+                                  "enabled": memory_capture_enabled()}
+
     # HBM admission budget fraction (the memory ledger falls back to
     # the default on a bad value; surface it here instead)
     bf = os.environ.get("BIGDL_TPU_HBM_BUDGET_FRACTION")

@@ -28,7 +28,8 @@ if "xla_force_host_platform_device_count" not in flags:
 # compiler under the accumulated load — so CI runs with capture off,
 # keeping the compile count identical to an uninstrumented run. The
 # capture path itself is exercised by tests that explicitly opt in
-# (tests/test_memory_ledger.py sets BIGDL_TPU_COMPILE_MEMORY=1).
+# (tests/test_memory_ledger.py sets BIGDL_TPU_COMPILE_MEMORY=1). The
+# program's own default is off too since PR 55; said here all the same.
 os.environ.setdefault("BIGDL_TPU_COMPILE_MEMORY", "0")
 
 # The AOT suite builds offline TPU topologies via libtpu, which by default
